@@ -1,49 +1,12 @@
-"""Version-bridging JAX imports.
+"""Trace-time view of the mesh a step program is compiled for.
 
-This is the ONE module allowed to touch deprecated or moved JAX API
-paths (aphrocheck SHARD003 exempts it, exactly like the flag registry
-is the one module allowed raw os.environ reads). Every accessor
-probes the CURRENT spelling first and falls back to the legacy path
-only when the running JAX predates the move, so nothing here emits a
-deprecation warning on either side of the fence.
+`ModelRunner` enters `jax.set_mesh(mesh)` around every jitted dispatch,
+so layer code can ask which mesh it is being traced under without the
+mesh being threaded through every call.
 """
 from __future__ import annotations
 
 import jax
-
-
-def get_shard_map():
-    """`jax.shard_map` (jax >= 0.6 spelling), falling back to
-    `jax.experimental.shard_map.shard_map` on jax 0.4.x/0.5.x where
-    the symbol has not moved yet (VERDICT r5 item #9: the experimental
-    path is deprecated and removed upstream)."""
-    sm = getattr(jax, "shard_map", None)
-    if callable(sm):    # a module here would mean the old layout
-        return sm
-    from jax.experimental import shard_map as _legacy
-    return _legacy.shard_map
-
-
-def get_context_mesh():
-    """The Mesh the caller is tracing under (`with mesh:`), or None.
-
-    The layer code annotates activations with bare `PartitionSpec`s
-    that only resolve against a context mesh; outside any mesh the
-    annotations must vanish entirely (single-chip jit has no mesh and
-    with_sharding_constraint would raise). The thread-local lives at
-    different paths across jax versions, so the probe belongs here."""
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        try:
-            from jax._src import mesh as _mesh_lib
-            mesh = _mesh_lib.thread_resources.env.physical_mesh
-        except (ImportError, AttributeError):
-            return None
-    if mesh is None or mesh.empty:
-        return None
-    return mesh
 
 
 def context_tp() -> int:
@@ -52,10 +15,4 @@ def context_tp() -> int:
     axis. Pallas launch gates consult this (aphrocheck MESH003):
     Pallas kernels are single-device programs, so any tp>1 trace must
     take the GSPMD-partitionable jnp path instead."""
-    mesh = get_context_mesh()
-    if mesh is None:
-        return 1
-    try:
-        return int(mesh.shape.get("tp", 1))
-    except (AttributeError, TypeError):
-        return 1
+    return int(jax.sharding.get_abstract_mesh().shape.get("tp", 1))

@@ -429,7 +429,11 @@ class SchedulerConfig:
 
 
 class DeviceConfig:
-    """JAX platform selection ('auto' prefers TPU, falls back to CPU)."""
+    """The JAX platform the engine must find. "tpu" and "cpu" are
+    demands; "auto" means the TPU unless JAX itself was pinned to other
+    platforms (`JAX_PLATFORMS`), which is how the CPU tests run. The
+    executor refuses to build on any other platform than the resolved
+    one, so a missing chip is an error and never a silent CPU server."""
 
     def __init__(self, device: str = "auto") -> None:
         if device not in ("auto", "tpu", "cpu"):
@@ -441,7 +445,9 @@ class DeviceConfig:
         if self.device_type != "auto":
             return self.device_type
         import jax
-        return "tpu" if jax.default_backend() == "tpu" else "cpu"
+        pinned = jax.config.jax_platforms
+        return "cpu" if pinned and "tpu" not in pinned.split(",") \
+            else "tpu"
 
 
 class LoRAConfig:

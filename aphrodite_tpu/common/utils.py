@@ -1,15 +1,19 @@
 """Small shared utilities (reference: aphrodite/common/utils.py).
 
 `Counter` and `LRUCache` mirror the reference semantics
-(`common/utils.py:35,49`); the CUDA device probes are replaced by JAX
-platform probes.
+(`common/utils.py:35,49`).
 """
 from __future__ import annotations
 
+import functools
 import socket
 import uuid
 from collections import OrderedDict
 from typing import Generic, Hashable, Optional, TypeVar
+
+from aphrodite_tpu.common.logger import init_logger
+
+logger = init_logger(__name__)
 
 T = TypeVar("T")
 
@@ -109,42 +113,11 @@ def pad_to_multiple(x: int, multiple: int) -> int:
     return cdiv(x, multiple) * multiple
 
 
-def in_wsl() -> bool:
-    return False
-
-
-def get_device_platform() -> str:
-    """Return the JAX default backend platform ('tpu', 'cpu', ...)."""
-    import jax
-    return jax.default_backend()
-
-
-def is_tpu() -> bool:
-    try:
-        return get_device_platform() == "tpu"
-    except Exception:  # pragma: no cover - jax not importable
-        return False
-
-
-def v5e8_memory_math(tp: int = 8, batch: int = 256, ctx: int = 2048):
-    """Projected per-chip HBM for the bf16 Mistral-7B v5e-8 north star
-    (BASELINE.md: fp16 7B >= 5k out-tok/s at bs=256). One source of
-    truth for bench.py's --tp report and __graft_entry__'s dryrun
-    assertion. Returns (weights_gib_total, kv_gib_per_chip,
-    act_gib, total_gib_per_chip)."""
-    hidden, inter, layers, vocab = 4096, 14336, 32, 32000
-    heads, kv_heads, hd = 32, 8, 128
-    per_layer = (hidden * (heads + 2 * kv_heads) * hd     # qkv
-                 + heads * hd * hidden                    # o
-                 + 2 * hidden * inter                     # gate_up
-                 + inter * hidden                         # down
-                 + 2 * hidden)                            # norms
-    n_params = 2 * vocab * hidden + layers * per_layer + hidden
-    weights_gib = n_params * 2 / 2**30
-    # KV heads shard tp-ways (8 heads -> 1/chip at tp=8); token-major
-    # pages pad head_dim to the 128-lane tile.
-    kv_tok_chip = 2 * (kv_heads // min(tp, kv_heads)) * hd * 2 * layers
-    kv_gib_chip = batch * ctx * kv_tok_chip / 2**30
-    act_gib = 0.75            # 8192-token prefill round, gate_up peak
-    total = weights_gib / tp + kv_gib_chip + act_gib
-    return weights_gib, kv_gib_chip, act_gib, total
+@functools.lru_cache(maxsize=None)
+def note_kernel_path(family: str, side: str, detail: str) -> None:
+    """Log, once per distinct choice, which side of a kernel dispatcher
+    a step program was traced with: `side` is "pallas" (the compiled
+    Mosaic kernel) or "reference" (the jnp/XLA path). Dispatchers call
+    this at trace time; `chip_smoke.py` reads the lines back from the
+    server log and fails when a tp=1 family took the reference."""
+    logger.info("kernel path: %s = %s (%s)", family, side, detail)

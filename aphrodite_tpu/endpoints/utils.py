@@ -277,6 +277,7 @@ def _raise_graceful_exit() -> None:
 async def _drain_then_exit(engine) -> None:
     engine.start_drain(reason="SIGTERM")
     clean = await engine.drained()
+    engine.engine.executor.log_device_memory("at drain")
     logger.info("Drain %s; exiting.",
                 "complete" if clean
                 else "deadline-forced (stragglers got typed errors)")

@@ -99,16 +99,21 @@ class FaultClass(enum.Enum):
     FATAL = enum.auto()      # terminal: engine goes DEAD
 
 
-#: Lowercased substrings marking transient device/RPC failures (the
-#: classes a retry can plausibly clear: runtime RPC deadlines,
-#: temporary unavailability, transient resource pressure).
+#: Lowercased substrings of runtime errors a retry can plausibly clear:
+#: a time-bounded wait on the device runtime that ran out.
 _TRANSIENT_MARKERS = (
     "deadline_exceeded",
     "deadline exceeded",
-    "unavailable",
-    "connection reset",
-    "temporarily",
-    "try again",
+)
+
+#: Lowercased substrings that veto a transient marker in the same
+#: text: the compiler refused the program, or memory ran out, and a
+#: retry meets the same refusal.
+_PERMANENT_MARKERS = (
+    "resource_exhausted",
+    "out of memory",
+    "mosaic",
+    "compil",
 )
 
 
@@ -128,7 +133,8 @@ def classify_failure(exc: BaseException,
     if isinstance(exc, StepTimeoutError):
         return FaultClass.FATAL
     text = f"{type(exc).__name__}: {exc}".lower()
-    if any(marker in text for marker in _TRANSIENT_MARKERS):
+    if any(marker in text for marker in _TRANSIENT_MARKERS) and \
+            not any(marker in text for marker in _PERMANENT_MARKERS):
         return FaultClass.TRANSIENT
     return default
 

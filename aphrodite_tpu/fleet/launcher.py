@@ -45,6 +45,33 @@ def find_free_ports(n: int) -> List[int]:
     return ports
 
 
+def chip_expected(env: Dict[str, str]) -> bool:
+    """Whether replicas started with `env` will look for a TPU: JAX
+    does unless it was pinned to other platforms (the same rule as
+    `DeviceConfig("auto")`). The launcher itself never imports JAX —
+    a parent that has touched JAX holds the chips its children need."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    return not platforms or "tpu" in platforms.split(",")
+
+
+def chip_env(index: int) -> Dict[str, str]:
+    """What replica `index` must be given to own exactly one chip of a
+    multi-chip host. libtpu claims every chip it can see, so without
+    this the first replica takes the whole host and the second cannot
+    start. Each replica sees one chip and forms a 1x1x1 topology of
+    its own (established on a four-chip v5e host: four such processes
+    run side by side, each reporting one device). A replica whose chip
+    is missing or taken fails within seconds, when JAX cannot open it
+    or the executor finds another platform than the TPU, and
+    `_wait_ready` reports the exit instead of waiting out its
+    timeout."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 class ReplicaProcess:
     """One replica server subprocess bound to a fixed local port (the
     port survives restarts so the replica's URL is stable)."""
@@ -152,7 +179,10 @@ class FleetLauncher:
             ]
             log_path = (os.path.join(log_dir, f"{name}.log")
                         if log_dir else None)
-            proc = ReplicaProcess(name, ports[i], argv, env=base_env,
+            replica_env = dict(base_env)
+            if chip_expected(base_env):
+                replica_env.update(chip_env(i))
+            proc = ReplicaProcess(name, ports[i], argv, env=replica_env,
                                   log_path=log_path)
             handle = ReplicaHandle(f"http://127.0.0.1:{ports[i]}",
                                    name=name, admin_key=admin_key)

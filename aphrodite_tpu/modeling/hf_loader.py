@@ -224,8 +224,8 @@ def hf_model_weights_iterator(
                          f"{model_path}")
 
 
-def initialize_dummy_params(model, seed: int = 0,
-                            scale: float = 1e-3) -> Dict:
+def initialize_dummy_params(model, seed: int = 0, scale: float = 1e-3,
+                            mesh: Optional[Mesh] = None) -> Dict:
     """Small random weights for profiling/benchmarks without a checkpoint
     (reference `--load-format dummy`, `hf_downloader.py:377-391`).
 
@@ -234,7 +234,12 @@ def initialize_dummy_params(model, seed: int = 0,
     per-group constant, which degenerates accuracy-sensitive harnesses
     (the W4A8 drift artifact measured a near-linear model). Index-like
     integer leaves (g_idx) stay zeros: random values there would be
-    out-of-range indices, not data."""
+    out-of-range indices, not data.
+
+    With a mesh, each leaf is committed to its NamedSharding as soon as
+    it is made, so no device ever holds more than its own shards plus
+    one whole leaf (the whole model would not fit beside a KV pool)."""
+    specs = model.param_specs() if mesh is not None else {}
     shapes = jax.eval_shape(model.init_params)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     key = jax.random.PRNGKey(seed)
@@ -243,14 +248,18 @@ def initialize_dummy_params(model, seed: int = 0,
     for k, (path, leaf) in zip(keys, flat):
         name = str(path[-1].key) if path else ""
         if jnp.issubdtype(leaf.dtype, jnp.floating):
-            out.append(jax.random.uniform(k, leaf.shape, leaf.dtype,
-                                          minval=-scale, maxval=scale))
+            arr = jax.random.uniform(k, leaf.shape, leaf.dtype,
+                                     minval=-scale, maxval=scale)
         elif name in ("qweight", "qzeros", "qs", "qs8"):
             info = jnp.iinfo(leaf.dtype)
-            out.append(jax.random.randint(
-                k, leaf.shape, info.min, info.max, dtype=leaf.dtype))
+            arr = jax.random.randint(
+                k, leaf.shape, info.min, info.max, dtype=leaf.dtype)
         else:
-            out.append(jnp.zeros(leaf.shape, leaf.dtype))
+            arr = jnp.zeros(leaf.shape, leaf.dtype)
+        if mesh is not None:
+            spec = specs.get(str(path[0].key), {}).get(name, P())
+            arr = jax.device_put(arr, NamedSharding(mesh, spec))
+        out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.input_metadata import InputMetadata
 from aphrodite_tpu.ops.attention import (paged_decode_attention_ref,
                                          prefill_attention)
@@ -100,6 +101,8 @@ class PagedAttention:
                     metadata.prefill_cells is not None):
                 # Page-aligned prompt chunks: whole-page writes, no
                 # per-token read-modify-write.
+                note_kernel_path("kv_write", "pallas",
+                                 "prefill whole-page writer")
                 pid, sblk, vld = metadata.prefill_cells
                 k_pages, v_pages = write_kv_pages_prefill(
                     flat_k.reshape(-1, hd), flat_v.reshape(-1, hd),
@@ -133,6 +136,8 @@ class PagedAttention:
             # The decode kernel injects the current token's K/V into
             # its page in place and attends over it — no separate
             # page-writer pass (the page was being DMA'd in anyway).
+            note_kernel_path("kv_write", "pallas",
+                             "fused into the decode attention kernel")
             out, k_pages, v_pages = self._decode(
                 q, k_pages, v_pages, metadata,
                 knew=k.reshape(batch, self.num_kv_heads,
@@ -261,6 +266,10 @@ class PagedAttention:
         if self._pallas_decode_ok(k_pages, metadata):
             from aphrodite_tpu.ops.pallas.paged_attention import (
                 paged_decode_attention)
+            note_kernel_path(
+                "decode_attention", "pallas",
+                "paged_decode_attention, "
+                f"{'fused KV write' if knew is not None else 'read-only'}")
             slopes = None if self.alibi_slopes is None else \
                 jnp.asarray(self.alibi_slopes, dtype=jnp.float32)
             # Padded table entries hold an out-of-range page id (the XLA
@@ -297,6 +306,10 @@ class PagedAttention:
                 return out[:, None], k_pages, v_pages
             out = result
         else:
+            note_kernel_path(
+                "decode_attention", "reference",
+                f"jnp gather path: backend={jax.default_backend()}, "
+                f"tp={metadata.tp}, pages={k_pages.dtype}")
             out = paged_decode_attention_ref(
                 q3, k_pages, v_pages, metadata.block_tables,
                 metadata.context_lens, self.scale,

@@ -13,7 +13,7 @@ inserts the all-reduce that the reference performs manually in
 `RowParallelLinear.forward` (`linear.py:562-565`).
 
 Activation shardings are EXPLICIT, not inferred: when the step traces
-under a mesh context (`ModelRunner` enters `with mesh:` around every
+under a mesh context (`ModelRunner` enters `jax.set_mesh` around every
 jitted dispatch), each layer pins its output with
 `with_sharding_constraint` — column-parallel outputs sharded "tp" on
 the feature dim, row-parallel outputs replicated (which is exactly
@@ -51,9 +51,8 @@ def shard_along(x: jax.Array, axis: Optional[str]) -> jax.Array:
     """Pin x's LAST dim to mesh axis `axis` (None = fully replicated)
     when tracing under a mesh that actually partitions that axis;
     identity otherwise (single-chip jit, or a trivial 1-sized axis)."""
-    from aphrodite_tpu.common.compat import get_context_mesh
-    mesh = get_context_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     if axis is not None and mesh.shape.get(axis, 1) <= 1:
         return x

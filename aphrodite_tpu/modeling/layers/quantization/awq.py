@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -114,7 +116,6 @@ class AWQLinearMethod(LinearMethod):
         qw = params["qweight"]
         in_features, n_packed = qw.shape
         lead = x.shape[:-1]
-        from aphrodite_tpu.common.compat import context_tp
         # Pallas kernels are single-device programs: tp>1 traces take
         # the GSPMD-partitionable dequant-then-dot path (MESH003).
         if jax.default_backend() == "tpu" and context_tp() == 1:
@@ -132,6 +133,8 @@ class AWQLinearMethod(LinearMethod):
                 # ring; APHRODITE_QMM_STREAM=0 pins the classic grid.
                 mm = awq_matmul_a8 if flags.get_bool(
                     "APHRODITE_W4A8") else awq_matmul
+                note_kernel_path("quant_matmul", "pallas",
+                                 f"awq {mm.__name__}")
                 y = mm(x.reshape(-1, in_features), qw,
                        params["qzeros"], params["scales"],
                        group_size=cfg.group_size)
@@ -141,6 +144,10 @@ class AWQLinearMethod(LinearMethod):
                 return y
         # XLA fallback: dequantize the whole matrix then matmul (the
         # ~9x-HBM-traffic path — only for shapes the kernel rejects).
+        note_kernel_path("quant_matmul", "reference",
+                         "awq dequantize-then-dot: "
+                         f"backend={jax.default_backend()}, "
+                         f"tp={context_tp()}")
         w = self.dequantize(params, x.dtype)
         y = x @ w
         if "bias" in params:

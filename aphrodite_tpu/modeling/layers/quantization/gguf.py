@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -293,13 +295,14 @@ class GGUFLinearMethod(LinearMethod):
         lead = x.shape[:-1]
         # Pallas kernels are single-device programs: tp>1 traces take
         # the GSPMD-partitionable dequant-then-dot path (MESH003).
-        from aphrodite_tpu.common.compat import context_tp
         if "qs8" in params:
             K, N = params["qs8"].shape
             if jax.default_backend() == "tpu" and context_tp() == 1:
                 from aphrodite_tpu.ops.pallas.quant_matmul import (
                     gguf_w8a8_matmul, gguf_w8a8_supported)
                 if gguf_w8a8_supported(K, N):
+                    note_kernel_path("quant_matmul", "pallas",
+                                     "gguf gguf_w8a8_matmul")
                     y = gguf_w8a8_matmul(x.reshape(-1, K),
                                          params["qs8"],
                                          params["s128"])
@@ -314,6 +317,8 @@ class GGUFLinearMethod(LinearMethod):
                 from aphrodite_tpu.ops.pallas.quant_matmul import (
                     gguf_q4k_matmul, gguf_q4k_supported)
                 if gguf_q4k_supported(K, N):
+                    note_kernel_path("quant_matmul", "pallas",
+                                     "gguf gguf_q4k_matmul")
                     y = gguf_q4k_matmul(
                         x.reshape(-1, K), params["qweight"],
                         params["dl"], params["ml"])
@@ -327,6 +332,8 @@ class GGUFLinearMethod(LinearMethod):
                 from aphrodite_tpu.ops.pallas.quant_matmul import (
                     gguf_i8g_matmul, gguf_i8g_supported)
                 if gguf_i8g_supported(K, N):
+                    note_kernel_path("quant_matmul", "pallas",
+                                     "gguf gguf_i8g_matmul")
                     y = gguf_i8g_matmul(x.reshape(-1, K), params["qs"],
                                         params["d16"])
                     y = y.reshape(*lead, N)
@@ -339,12 +346,18 @@ class GGUFLinearMethod(LinearMethod):
                 from aphrodite_tpu.ops.pallas.quant_matmul import (
                     gguf_q8_matmul, gguf_q8_supported)
                 if gguf_q8_supported(K, N):
+                    note_kernel_path("quant_matmul", "pallas",
+                                     "gguf gguf_q8_matmul")
                     y = gguf_q8_matmul(x.reshape(-1, K), params["qs"],
                                        params["d"])
                     y = y.reshape(*lead, N)
                     if "bias" in params:
                         y = y + params["bias"]
                     return y
+        note_kernel_path("quant_matmul", "reference",
+                         "gguf dequantize-then-dot: "
+                         f"backend={jax.default_backend()}, "
+                         f"tp={context_tp()}")
         w = self.dequantize(params, x.dtype)
         y = x @ w
         if "bias" in params:

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -151,12 +153,18 @@ class GPTQLinearMethod(LinearMethod):
             mm = gptq_matmul_a8 if (
                 flags.get_bool("APHRODITE_W4A8") and
                 cfg.weight_bits == 4) else gptq_matmul
+            note_kernel_path("quant_matmul", "pallas",
+                             f"gptq {mm.__name__}")
             y = mm(
                 x.reshape(-1, in_features), params["qweight"],
                 params["qzeros"], params["scales"],
                 bits=cfg.weight_bits, group_size=cfg.group_size)
             y = y.reshape(*lead, out_features)
         else:
+            note_kernel_path("quant_matmul", "reference",
+                             "gptq dequantize-then-dot: "
+                             f"backend={jax.default_backend()}, "
+                             f"tp={context_tp()}")
             w = self.dequantize(params, x.dtype)
             y = x @ w
         if "bias" in params:
@@ -170,7 +178,6 @@ class GPTQLinearMethod(LinearMethod):
         from aphrodite_tpu.common import flags
         if flags.get_bool("APHRODITE_DISABLE_PALLAS_QUANT"):
             return False
-        from aphrodite_tpu.common.compat import context_tp
         from aphrodite_tpu.ops.pallas.quant_matmul import gptq_supported
         # Pallas kernels are single-device programs: tp>1 traces take
         # the GSPMD-partitionable dequant-then-dot path (MESH003).

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -62,7 +64,6 @@ class Int8LinearMethod(LinearMethod):
               x: jax.Array) -> jax.Array:
         w = params["weight"]
         in_features, out_features = w.shape
-        from aphrodite_tpu.common.compat import context_tp
         # Pallas kernels are single-device programs: tp>1 traces take
         # the GSPMD-partitionable upcast-GEMM path (MESH003).
         if jax.default_backend() == "tpu" and context_tp() == 1:
@@ -70,6 +71,8 @@ class Int8LinearMethod(LinearMethod):
                 int8_matmul, int8_supported)
             if int8_supported(in_features, out_features):
                 lead = x.shape[:-1]
+                note_kernel_path("quant_matmul", "pallas",
+                                 "int8 int8_matmul")
                 y = int8_matmul(x.reshape(-1, in_features), w,
                                 params["scales"])
                 y = y.reshape(*lead, out_features)
@@ -78,6 +81,10 @@ class Int8LinearMethod(LinearMethod):
                 return y
         # XLA fallback: upcast in the GEMM prologue; scales on the
         # output channel.
+        note_kernel_path("quant_matmul", "reference",
+                         "int8 upcast GEMM: "
+                         f"backend={jax.default_backend()}, "
+                         f"tp={context_tp()}")
         y = (x @ w.astype(x.dtype)) * params["scales"].astype(x.dtype)
         if "bias" in params:
             y = y + params["bias"]

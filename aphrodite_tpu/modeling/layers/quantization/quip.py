@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -344,7 +346,6 @@ class QuipLinearMethod(LinearMethod):
             lut = params["lookup_table"] * ws
             # Pallas kernels are single-device programs: tp>1 traces
             # take the GSPMD-partitionable LUT-gather path (MESH003).
-            from aphrodite_tpu.common.compat import context_tp
             if jax.default_backend() == "tpu" and \
                     context_tp() == 1 and \
                     squeezellm_supported(q_in, q_out):
@@ -352,9 +353,15 @@ class QuipLinearMethod(LinearMethod):
                 # path this replaces also fed f32 activations, and all
                 # 12 LUT values are exactly representable — the whole
                 # path stays numerically identical to dense dequant.
+                note_kernel_path("quant_matmul", "pallas",
+                                 "quip squeezellm_matmul")
                 out = squeezellm_matmul(xr, qw,
                                         lut).astype(jnp.float32)
             else:
+                note_kernel_path("quant_matmul", "reference",
+                                 "quip LUT gather + dot: "
+                                 f"backend={jax.default_backend()}, "
+                                 f"tp={context_tp()}")
                 # One copy of the packing convention: reuse the GPTQ
                 # row unpack (same 8-nibbles-along-K layout).
                 from aphrodite_tpu.modeling.layers.quantization.gptq \
@@ -367,13 +374,18 @@ class QuipLinearMethod(LinearMethod):
             from aphrodite_tpu.ops.pallas.quant_matmul import (
                 int8_matmul, int8_supported)
             # Same single-device constraint as the LUT path above.
-            from aphrodite_tpu.common.compat import context_tp
             if jax.default_backend() == "tpu" and \
                     context_tp() == 1 and \
                     int8_supported(q_in, q_out):
+                note_kernel_path("quant_matmul", "pallas",
+                                 "quip int8_matmul")
                 out = int8_matmul(
                     xr, w, jnp.full((q_out,), 0.25, jnp.float32) * ws)
             else:
+                note_kernel_path("quant_matmul", "reference",
+                                 "quip upcast GEMM: "
+                                 f"backend={jax.default_backend()}, "
+                                 f"tp={context_tp()}")
                 out = xr @ (w.astype(jnp.float32) * (0.25 * ws))
         else:
             out = (xr * ws) @ w.astype(jnp.float32)   # [m, q_out]

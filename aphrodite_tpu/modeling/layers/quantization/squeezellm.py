@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.layers.linear import LinearMethod
 from aphrodite_tpu.modeling.layers.quantization.base_config import (
     QuantizationConfig)
@@ -89,11 +91,17 @@ class SqueezeLLMLinearMethod(LinearMethod):
             from aphrodite_tpu.ops.pallas.quant_matmul import (
                 squeezellm_matmul)
             lead = x.shape[:-1]
+            note_kernel_path("quant_matmul", "pallas",
+                             "squeezellm squeezellm_matmul")
             y = squeezellm_matmul(
                 x.reshape(-1, in_features), params["qweight"],
                 params["lookup_table"])
             y = y.reshape(*lead, out_features)
         else:
+            note_kernel_path("quant_matmul", "reference",
+                             "squeezellm LUT gather + dot: "
+                             f"backend={jax.default_backend()}, "
+                             f"tp={context_tp()}")
             w = self.dequantize(params, x.dtype)
             y = x @ w
         if "bias" in params:
@@ -107,7 +115,6 @@ class SqueezeLLMLinearMethod(LinearMethod):
         from aphrodite_tpu.common import flags
         if flags.get_bool("APHRODITE_DISABLE_PALLAS_QUANT"):
             return False
-        from aphrodite_tpu.common.compat import context_tp
         from aphrodite_tpu.ops.pallas.quant_matmul import (
             squeezellm_supported)
         # Pallas kernels are single-device programs: tp>1 traces take

@@ -75,13 +75,8 @@ def get_model(model_config: ModelConfig,
         _mark_moe_sharded(model)
 
     if model_config.load_format == "dummy":
-        params = initialize_dummy_params(model, seed=model_config.seed)
-        if mesh is not None:
-            import numpy as np
-            host = {k: {n: np.asarray(a) for n, a in b.items()}
-                    for k, b in params.items()}
-            params = shard_params(host, model.param_specs(), mesh, dtype)
-        return model, params
+        return model, initialize_dummy_params(
+            model, seed=model_config.seed, mesh=mesh)
 
     weights_iter = hf_model_weights_iterator(
         model_config.model, model_config.load_format,

@@ -37,6 +37,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from aphrodite_tpu.common.utils import note_kernel_path
+
 
 def padded_head_size(head_size: int) -> int:
     """Cache pages store head_dim padded to the 128-lane tile: Mosaic
@@ -82,10 +84,18 @@ def write_to_kv_cache(
         from aphrodite_tpu.ops.pallas.kv_write import (
             can_use_pallas_writer, write_kv_pages)
         if can_use_pallas_writer(k_pages.dtype, page_size, hd):
+            note_kernel_path(
+                "kv_write", "pallas",
+                "pipelined page writer" if distinct_pages
+                else "slot-window writer")
             return write_kv_pages(key, value, k_pages, v_pages,
                                   slot_mapping,
                                   distinct_pages=distinct_pages)
 
+    note_kernel_path(
+        "kv_write", "reference",
+        f"XLA scatter: backend={jax.default_backend()}, tp={tp}, "
+        f"pages={k_pages.dtype}")
     k_flat = k_pages.reshape(num_pages * page_size, hd)
     v_flat = v_pages.reshape(num_pages * page_size, hd)
 

@@ -135,9 +135,8 @@ _WB_SLOTS = 8
 _RING_BUDGET_BYTES = 8 * 1024 * 1024
 
 # Ragged work-list length buckets (each distinct padded length is one
-# compiled program, and remote compiles cost ~20 s — same
-# power-of-two-and-a-half spacing rationale as the decode batch
-# buckets in executor/model_runner.py).
+# compiled program — same power-of-two-and-a-half spacing rationale
+# as the decode batch buckets in executor/model_runner.py).
 _WORK_BUCKETS = [8, 16, 24, 32, 48, 64, 96, 128, 192, 256, 384, 512,
                  768, 1024, 1536, 2048, 3072, 4096]
 

@@ -45,9 +45,9 @@ when the extra int32 planes don't fit the VMEM budget
 (`APHRODITE_QMM_DEFERRED_VMEM_MB`, default 8). The profile harness's
 `--only ab` mode measures both variants at the bench geometries.
 
-Streamed skinny-m grid (LATENCY_r05 "what remains"): at m <= 64 the
-classic (m, n, k) grid is WEIGHT-STREAMING bound — every grid cell
-re-pays a fixed compiler-managed-BlockSpec cost to fetch its
+Streamed skinny-m grid: at m <= 64 the classic (m, n, k) grid is
+WEIGHT-STREAMING bound — every grid cell re-pays a fixed
+compiler-managed-BlockSpec cost to fetch its
 qweight/zeros/scales blocks, and at tiny m that fixed cost dwarfs the
 dot (the whole 3.5 GiB int4 matrix moves at ~430 GB/s effective
 against an ~820 GB/s HBM floor). The `_stream_kernel` path therefore
@@ -94,13 +94,6 @@ from aphrodite_tpu.common import flags
 from aphrodite_tpu.common.logger import init_logger
 
 logger = init_logger(__name__)
-
-# jax 0.4.x names the TPU compiler-params dataclass TPUCompilerParams;
-# 0.5+ renames it CompilerParams. Resolve once so every kernel in this
-# file (including the CPU interpret path the tier-1 tests run) works
-# against either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 
 def _unpack_planes(q: jax.Array, bits: int) -> jax.Array:
@@ -200,7 +193,7 @@ def _resolve_deferred(deferred, m: int) -> bool:
     APHRODITE_QMM_DEFERRED env flag; the default is autotune-by-shape:
     deferred at batch/prefill geometries (m > 64) where the per-group
     scale FMAs gate the MXU, classic at small-m decode where the
-    2048-deep k-tiles' grid-cell savings dominate (LATENCY_r05)."""
+    2048-deep k-tiles' grid-cell savings dominate."""
     if deferred is not None:
         return bool(deferred)
     env = flags.get_str("APHRODITE_QMM_DEFERRED")
@@ -228,8 +221,8 @@ _STREAM_K_CAP = 4096
 _STREAM_DEF_K_CAP = 1024     # deferred: int32 planes bound the k depth
 
 # Whole-kernel scoped-VMEM budget for the _clamp_k_vmem pre-check
-# (mirrors _deferred_fits, but covers the full tile set: LATENCY_r05's
-# block_k=4096 sweep point failed to COMPILE instead of clamping).
+# (mirrors _deferred_fits, but covers the full tile set: a
+# block_k=4096 sweep point once failed to COMPILE instead of clamping).
 _QMM_VMEM_BYTES = 16 << 20
 
 
@@ -276,7 +269,7 @@ def _cell_bytes(block_k: int, *, layout: str, block_m: int,
         # per-group unpack transient.
         temp = block_k * block_n * x_bytes if a16 \
             else gs * block_n * 4
-    zs = gpt * block_n * (4 + s_bytes)
+    zs = gpt * block_n * (4 + (4 if stream_slots else s_bytes))
     planes = gpt * block_m * block_n * 4 if deferred else 0
     if stream_slots:
         acc = 2 * block_m * block_n * 4       # parity planes
@@ -491,7 +484,7 @@ def _stream_call(x, qweight, z3, s3, *, layout: str, bits: int,
     padded; RAW model dtype even for a8 — the kernel quantizes it in
     its prologue) goes resident as [k_tiles, block_m, block_k];
     qweight and the [G, 1, N] zero/scale rows stay in HBM
-    (memory_space=ANY) and stream through the ring. The f32
+    (memory_space=HBM) and stream through the ring. The f32
     accumulator is two column-parity planes (the ROOF003
     double-buffered flush). Returns [padded_m, N] (plane-major
     columns for awq — callers un-permute as usual)."""
@@ -509,12 +502,17 @@ def _stream_call(x, qweight, z3, s3, *, layout: str, bits: int,
         qw_rows, qw_cols = block_k // (32 // bits), block_n
 
     x_t = x.reshape(block_m, k_tiles, block_k).swapaxes(0, 1)
+    # Scale rows ride the ring as f32: a bf16 [G, 1, N] array is tiled
+    # (2, 128) in HBM with its unit dim padded to 2, and Mosaic refuses
+    # a 1-deep DMA slice of that. The padded bf16 rows would move the
+    # same bytes, so f32 costs the ring nothing.
+    s3 = s3.astype(jnp.float32)
     in_specs = [
         pl.BlockSpec((k_tiles, block_m, block_k),
                      lambda w: (0, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     inputs = [x_t, qweight, z3, s3]
 
@@ -545,7 +543,7 @@ def _stream_call(x, qweight, z3, s3, *, layout: str, bits: int,
                                lambda w: (0, w // k_tiles)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
@@ -706,7 +704,7 @@ def gptq_matmul(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qweight, z_all, scales3)
@@ -794,10 +792,12 @@ def _quant8_call(x, interpret: bool):
     """Launch _quant8_kernel over row blocks (whole-K rows per cell —
     the per-row reduce needs the full contraction width resident)."""
     m, K = x.shape
-    sublane = 16 if x.dtype == jnp.bfloat16 else 8
+    sublane = 32                # the int8 output's minimum row tile
     block_m = min(256, -(-m // sublane) * sublane)
-    # f32 working copy + in/out blocks must fit scoped VMEM.
-    while block_m > sublane and block_m * K * 8 > _QMM_VMEM_BYTES:
+    # Scoped VMEM per row: the input block and the int8 output block
+    # are both double-buffered, beside one f32 working copy.
+    row_bytes = K * (2 * x.dtype.itemsize + 2 + 4)
+    while block_m > sublane and block_m * row_bytes > _QMM_VMEM_BYTES:
         block_m = max(sublane, block_m // 2 // sublane * sublane)
     padded_m = -(-m // block_m) * block_m
     if padded_m != m:
@@ -810,7 +810,7 @@ def _quant8_call(x, interpret: bool):
                    pl.BlockSpec((block_m, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((padded_m, K), jnp.int8),
                    jax.ShapeDtypeStruct((padded_m, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
@@ -925,7 +925,7 @@ def awq_matmul(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qweight, z_pm, s_pm)
@@ -1103,7 +1103,7 @@ def awq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x8, xs, qweight, z_pm, s_pm)
@@ -1200,7 +1200,7 @@ def gguf_q4k_matmul(x: jax.Array, qweight: jax.Array, dl: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qweight, dl.reshape(G, 1, N), ml.reshape(G, 1, N))
@@ -1264,7 +1264,7 @@ def gguf_q8_matmul(x: jax.Array, qs: jax.Array, d: jax.Array, *,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qs, d.reshape(G, 1, N))
@@ -1466,7 +1466,7 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x8, xs, qweight, z_all, scales3)
@@ -1541,7 +1541,7 @@ def gguf_i8g_matmul(x: jax.Array, qs: jax.Array, d16: jax.Array, *,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qs, d16.reshape(G, 1, N))
@@ -1559,7 +1559,7 @@ def _gguf_w8a8_kernel(x_ref, xs_ref, qs_ref, s_ref, o_ref, acc_ref, *,
     fast path: every ggml block format requantizes into this form at
     load (see quantization/gguf.py), replacing the per-32-row
     dequant-to-bf16 kernels whose VPU work and 4-bit affine handling
-    held the GGUF bench row at 0.68x (PROFILE_r04 item 4)."""
+    held the GGUF bench row back."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -1618,7 +1618,7 @@ def gguf_w8a8_matmul(x: jax.Array, qs: jax.Array, s128: jax.Array, *,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x8, xs, qs, s128.reshape(G, 1, N))
@@ -1694,7 +1694,7 @@ def squeezellm_matmul(x: jax.Array, qweight: jax.Array,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qweight, lookup_table.T)
@@ -1755,7 +1755,7 @@ def int8_matmul(x: jax.Array, weight: jax.Array, scales: jax.Array, *,
                                lambda i, n, k: (i, n)),
         out_shape=jax.ShapeDtypeStruct((padded_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, weight, scales.reshape(1, N))

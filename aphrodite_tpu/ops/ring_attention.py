@@ -101,13 +101,7 @@ def ring_attention_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     def _varying(x):
         # The softmax state is per-device (varies over the ring axis);
         # an unvarying init would type-mismatch the loop carry.
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, axis_name, to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, (axis_name,))
-        # jax 0.4.x: no varying-axis types in shard_map — the carry
-        # needs no annotation there.
-        return x
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     init = _varying(
         (jnp.full((b, hkv, group, sq), _NEG_INF, jnp.float32),
@@ -127,11 +121,8 @@ def make_ring_fn(mesh: Mesh, scale: float, axis_name: str = "sp"):
     """shard_map-wrapped ring over `axis_name` (sequence dim): the ONE
     dispatch construction shared by the serving layer (inside jit, where
     GSPMD inserts any resharding) and the standalone wrapper below."""
-    from aphrodite_tpu.common.compat import get_shard_map
-    shard_map = get_shard_map()
-
     spec = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(ring_attention_shard, scale=scale,
                           axis_name=axis_name),
         mesh=mesh,

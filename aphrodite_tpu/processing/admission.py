@@ -2,8 +2,8 @@
 
 Under genuine overload an unbounded queue produces the classic
 goodput-collapse shape: every request eventually misses its deadline
-instead of most requests meeting it (SERVING_r05: 84% of offered
-tokens served at rate 8.0, and it only degrades from there). The fix
+instead of most requests meeting it, and it only degrades from
+there. The fix
 is to shed work we cannot finish in time AT ADMISSION — cheaply,
 predictably, and before it touches the tracker or the allocator:
 

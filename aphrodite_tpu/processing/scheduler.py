@@ -9,8 +9,7 @@ enqueue the prefill program and the decode burst back-to-back and pay
 one host<->device sync for the round. A prompt longer than the chunk
 budget is prefilled across several rounds (`self.prefilling` holds the
 in-flight ones); only its final chunk samples a token. This removes the
-dedicated per-arrival prefill round that capped low-rate serving
-(SERVING_r04: ~94 out-tok/s at request rate 2.0).
+dedicated per-arrival prefill round that capped low-rate serving.
 
 TPU notes: the prompt-token budget uses the padded cost
 (num_seqs * max_len), which is exactly what the fixed-shape prefill

@@ -320,7 +320,7 @@ async def run(args) -> dict:
         model_runner.py:654, for the same reason). Replaying the arrival
         schedule only compiles the buckets the warmup pass's own timing
         happens to walk; the measured pass (different service times)
-        walks others and pays ~10-20 s remote compiles mid-measurement
+        walks others and pays their compiles mid-measurement
         (observed as 30 s TTFT p99 tails at request rate 2.0).
         All-at-once batches at each batch bucket cover the prefill
         bucket x table-width x burst-length lattice for this workload

@@ -29,7 +29,9 @@ the checkpoint's mathematical values
 (`/root/reference/kernels/quantization/gptq/q_gemm.cu`).
 
 Usage: python benchmarks/w4a8_drift.py [--steps 96] [--batch 64]
-(runs on the real chip; ~4 min). `--child MODE` is internal.
+(runs on the real chip). `--child MODE` is internal: the parent
+never touches JAX, and the three measurements run as children one
+after another, because a chip belongs to one process at a time.
 """
 from __future__ import annotations
 
@@ -128,8 +130,8 @@ def layer_drift(args) -> dict:
 
     # One traced program per (mode, residual-presence): every layer has
     # identical structure, so layer i's params are REKEYED onto layer
-    # 0's names and run through the same compiled program (64 separate
-    # per-layer jits would cost ~64 remote compiles).
+    # 0's names and run through the same compiled program (not 64
+    # separate per-layer compiles).
     layer0 = model.layers[0]
 
     def layer_params(i):
@@ -207,16 +209,18 @@ def main() -> None:
     parser.add_argument("--batch", type=int, default=64)
     parser.add_argument("--child", default=None)
     args = parser.parse_args()
+    if args.child == "drift":
+        print("DRIFT" + json.dumps(layer_drift(args)), flush=True)
+        return
     if args.child:
         os.environ["APHRODITE_W4A8"] = \
             "1" if args.child == "w4a8" else "0"
         child_tokens(args)
         return
 
-    drift = layer_drift(args)
-
-    streams = {}
-    for mode in ("w4a16", "w4a8"):
+    def child(mode: str, tag: str):
+        """Run one measurement in its own process; returns the JSON
+        it printed after `tag`."""
         env = dict(os.environ)
         env["APHRODITE_W4A8"] = "1" if mode == "w4a8" else "0"
         r = subprocess.run(
@@ -225,8 +229,12 @@ def main() -> None:
              "--batch", str(args.batch)],
             env=env, capture_output=True, text=True, check=True)
         line = next(l for l in r.stdout.splitlines()
-                    if l.startswith("TOKENS"))
-        streams[mode] = json.loads(line[len("TOKENS"):])
+                    if l.startswith(tag))
+        return json.loads(line[len(tag):])
+
+    drift = child("drift", "DRIFT")
+    streams = {mode: child(mode, "TOKENS")
+               for mode in ("w4a16", "w4a8")}
 
     ids = sorted(streams["w4a16"])
     identical = 0

@@ -1,6 +1,5 @@
 """Seeds SHARD003: the deprecated `jax.experimental.shard_map` import
-path (removed upstream; the supported spelling is `jax.shard_map`,
-bridged for jax<0.6 by aphrodite_tpu.common.compat.get_shard_map)."""
+path (the supported spelling is `jax.shard_map`)."""
 from jax.experimental.shard_map import shard_map
 
 

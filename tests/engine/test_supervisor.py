@@ -48,15 +48,28 @@ def test_classify_timeout_is_always_fatal():
 
 def test_classify_transient_markers_and_default():
     assert classify_failure(
-        RuntimeError("UNAVAILABLE: socket closed")) is \
-        FaultClass.TRANSIENT
-    assert classify_failure(
-        RuntimeError("DEADLINE_EXCEEDED while compiling")) is \
+        RuntimeError("DEADLINE_EXCEEDED: collective timed out")) is \
         FaultClass.TRANSIENT
     assert classify_failure(ValueError("nonsense")) is FaultClass.FATAL
     assert classify_failure(
         ValueError("nonsense"),
         default=FaultClass.REQUEST) is FaultClass.REQUEST
+
+
+def test_classify_compile_and_resource_errors_are_fatal():
+    """What the compiler or the allocator refuses, it refuses again on
+    retry, even when the text also carries a transient-looking
+    marker."""
+    for text in (
+            "INTERNAL: Mosaic failed to compile TPU kernel: Slice "
+            "shape along dimension 1 must be aligned to tiling (2)",
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+            "vmem while allocating on stack; exceeded scoped vmem "
+            "limit by 1.44M",
+            "RESOURCE_EXHAUSTED: Out of memory while trying to "
+            "allocate 6442450944 bytes",
+            "DEADLINE_EXCEEDED while compiling"):
+        assert classify_failure(RuntimeError(text)) is FaultClass.FATAL
 
 
 # ---------------------------------------------------------------------

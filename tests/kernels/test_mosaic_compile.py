@@ -1,0 +1,123 @@
+"""Does Mosaic take the serving path's kernels at Mistral-7B widths?
+
+Asked of the real compiler, without a chip: libtpu compiles for a
+DESCRIBED topology (`jax.experimental.topologies`) with no device
+attached, so a kernel the compiler refuses fails here, on the CPU,
+before chip time is spent finding out. This checks acceptance only;
+numerics on the chip are `tests/kernels/tpu_smoke.py`.
+
+The interpret-mode tests cannot see these failures: both kernels
+repaired in PR 21 (the streamed W4A8 grid's bf16 scale ring and the
+one-pass activation quantizer's VMEM fit at K=14336) passed every
+interpret test while Mosaic refused them.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# libtpu logs to /tmp/tpu_logs unless told not to.
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+BF16, I32 = jnp.bfloat16, jnp.int32
+#: (K, N) of the four GPTQ linears in one Mistral-7B decoder layer.
+LAYER_SHAPES = [(4096, 6144), (4096, 4096), (4096, 28672), (14336, 4096)]
+
+
+@pytest.fixture(scope="module")
+def on_v5e():
+    """ShapeDtypeStruct factory placing operands on one v5e device of a
+    described (not attached) 2x2 topology."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    # The suite's persistent compilation cache holds CPU executables;
+    # TPU ones can be written there but not read back without a chip.
+    # JAX latches its use-the-cache decision, hence the resets.
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def _gptq_operands(sds, m, K, N):
+    return (sds((m, K), BF16), sds((K // 8, N), I32),
+            sds((K // 128, N // 8), I32), sds((K // 128, N), BF16))
+
+
+@pytest.mark.parametrize(
+    "m,K,N", [(8, K, N) for K, N in LAYER_SHAPES] + [(40, 14336, 4096)])
+def test_streamed_w4a8_compiles(on_v5e, m, K, N):
+    """Decode (m=8) and speculative verify (m=40) rows: the streamed
+    work-list grid with the in-kernel activation quantize prologue."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import gptq_matmul_a8
+    gptq_matmul_a8.lower(*_gptq_operands(on_v5e, m, K, N), bits=4,
+                         group_size=128, stream=True).compile()
+
+
+@pytest.mark.parametrize("K,N", [(4096, 28672), (14336, 4096)])
+def test_prefill_w4a8_compiles(on_v5e, K, N):
+    """Prefill rows: the one-pass activation quantizer (whole-K row
+    blocks: K=14336 is the tightest scoped-VMEM fit) and the deferred
+    W4A8 grid behind it."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import (_quant8_call,
+                                                       gptq_matmul_a8)
+    m = 4096
+    jax.jit(functools.partial(_quant8_call, interpret=False)).lower(
+        on_v5e((m, K), BF16)).compile()
+    _, qw, qz, sc = _gptq_operands(on_v5e, m, K, N)
+    gptq_matmul_a8.lower(on_v5e((m, K), jnp.int8), qw, qz, sc, bits=4,
+                         group_size=128, stream=False).compile()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_decode_attention_compiles(on_v5e, fused):
+    """The ragged decode kernel with the AMLA rescale, fused KV write
+    (decode steps) and read-only (speculative verify rounds)."""
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk,
+        paged_decode_attention)
+    B, Hq, Hkv, d, page, pps = 8, 32, 8, 128, 16, 24
+    ppc = choose_pages_per_chunk(pps, page, B)
+    work = build_decode_work_list([pps] * B, ppc)
+    pages = on_v5e((2048, page, Hkv * d), BF16)
+    new = on_v5e((B, Hkv, d), BF16)
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn if fused else None,
+            vn if fused else None, scale=d ** -0.5,
+            pages_per_chunk=ppc, work_items=work, amla=True)
+
+    jax.jit(attend, donate_argnums=(1, 2) if fused else ()).lower(
+        on_v5e((B, Hq, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
+def test_kv_writers_compile(on_v5e):
+    from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,
+                                                   write_kv_pages_prefill)
+    page, hd, tokens = 16, 1024, 40
+    pages = on_v5e((2048, page, hd), BF16)
+    rows = on_v5e((tokens, hd), BF16)
+    for distinct in (True, False):
+        jax.jit(functools.partial(write_kv_pages,
+                                  distinct_pages=distinct),
+                donate_argnums=(2, 3)).lower(
+            rows, rows, pages, pages, on_v5e((tokens,), I32)).compile()
+    cells = 8 * 512 // page
+    chunk = on_v5e((cells * page, hd), BF16)
+    ids = on_v5e((cells,), I32)
+    jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
+        chunk, chunk, pages, pages, ids, ids, ids).compile()

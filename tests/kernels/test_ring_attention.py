@@ -65,7 +65,6 @@ def test_ring_gqa_rotates_kv_heads(cpu_devices):
 def test_ring_inside_jit(cpu_devices):
     """The shard must compose under jit with mesh context (how the
     engine would call it)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from aphrodite_tpu.ops.ring_attention import ring_attention_shard
@@ -76,7 +75,7 @@ def test_ring_inside_jit(cpu_devices):
     q = jnp.asarray(rs.randn(b, seq, H, d).astype(np.float32))
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("sp",))
     spec = P(None, "sp", None, None)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_attention_shard, scale=0.35,
                           axis_name="sp"),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec))

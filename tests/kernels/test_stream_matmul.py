@@ -243,7 +243,7 @@ def test_clamp_k_vmem_steps_down():
 
 
 def test_oversized_block_k_env_clamps(monkeypatch):
-    """LATENCY_r05's sweep note: APHRODITE_QMM_BLOCK_K=4096 used to
+    """An earlier sweep's note: APHRODITE_QMM_BLOCK_K=4096 used to
     fail the Mosaic compile at the prefill geometry; the prologue's
     footprint pre-check now steps the cap down instead. Checked at
     the tile-sizing layer (the full 512x4096x2048 matmul is too slow

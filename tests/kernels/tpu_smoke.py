@@ -1,10 +1,13 @@
 """Compiled-on-TPU kernel smoke: runs the Pallas kernels NON-interpret
 on the real chip and checks numerics against the XLA references.
 
-Run directly on a TPU host (the pytest suite forces CPU):
+Run from the repo root on a machine with a chip (the pytest suite
+forces CPU, where this script refuses to run):
     python tests/kernels/tpu_smoke.py
-Exit code 0 = all kernels compiled and matched.
+Exit code 0 = all kernels compiled and matched; non-zero otherwise,
+including when there is no chip.
 """
+import contextlib
 import sys
 
 import numpy as np
@@ -16,9 +19,13 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    if jax.default_backend() not in ("tpu",):
-        print(f"SKIP: backend is {jax.default_backend()}, need tpu")
-        return 0
+    dev = jax.devices()[0]
+    print(f"platform={dev.platform} device_kind={dev.device_kind!r} "
+          f"device_count={len(jax.devices())}")
+    if dev.platform != "tpu":
+        print(f"FAIL: platform is {dev.platform!r}; the compiled "
+              "kernels need a tpu")
+        return 1
 
     from aphrodite_tpu.modeling.layers.quantization.gptq import (
         GPTQConfig, GPTQLinearMethod)
@@ -29,6 +36,17 @@ def main() -> int:
 
     rs = np.random.RandomState(0)
     failures = []
+
+    @contextlib.contextmanager
+    def section(name):
+        """A kernel the compiler refuses must not hide the ones after
+        it: record the exception as this section's failure and go on."""
+        try:
+            yield
+        except Exception as e:
+            msg = f"{type(e).__name__}: {str(e).splitlines()[0][:300]}"
+            print(f"{name}: RAISED {msg}")
+            failures.append((name, msg))
 
     # -- decode attention kernels, bf16 + int8 KV, alibi --
     Hq, Hkv, d, page, pps, pages, B = 32, 8, 128, 32, 4, 256, 24
@@ -54,334 +72,386 @@ def main() -> int:
         if not (err < tol):          # NaN-rejecting
             failures.append((name, err))
 
-    ref = oracle(q, kp, vp, bt, ctx, scale)
+    with section("decode attention (classic grid)"):
+        ref = oracle(q, kp, vp, bt, ctx, scale)
 
-    for name, ppc in (("tokenmajor", 2),
-                      ("tokenmajor single-chunk", 4)):
-        got = np.asarray(paged_decode_attention(
-            q, kp, vp, bt, ctx, scale=scale,
-            pages_per_chunk=ppc), np.float32)
-        check(f"{name} bf16", ref, got)
+        for name, ppc in (("tokenmajor", 2),
+                          ("tokenmajor single-chunk", 4)):
+            got = np.asarray(paged_decode_attention(
+                q, kp, vp, bt, ctx, scale=scale,
+                pages_per_chunk=ppc), np.float32)
+            check(f"{name} bf16", ref, got)
 
-    S = 0.05
-    kp8 = jnp.clip(jnp.round(kp.astype(jnp.float32) / S), -127,
-                   127).astype(jnp.int8)
-    vp8 = jnp.clip(jnp.round(vp.astype(jnp.float32) / S), -127,
-                   127).astype(jnp.int8)
-    ref8 = oracle(q, kp8.astype(jnp.float32) * S,
-                  vp8.astype(jnp.float32) * S, bt, ctx, scale)
-    got8 = np.asarray(paged_decode_attention(
-        q, kp8, vp8, bt, ctx, scale=scale, kv_scale=S,
-        pages_per_chunk=2), np.float32)
-    check("tokenmajor int8 KV", ref8, got8)
+        S = 0.05
+        kp8 = jnp.clip(jnp.round(kp.astype(jnp.float32) / S), -127,
+                       127).astype(jnp.int8)
+        vp8 = jnp.clip(jnp.round(vp.astype(jnp.float32) / S), -127,
+                       127).astype(jnp.int8)
+        ref8 = oracle(q, kp8.astype(jnp.float32) * S,
+                      vp8.astype(jnp.float32) * S, bt, ctx, scale)
+        got8 = np.asarray(paged_decode_attention(
+            q, kp8, vp8, bt, ctx, scale=scale, kv_scale=S,
+            pages_per_chunk=2), np.float32)
+        check("tokenmajor int8 KV", ref8, got8)
 
-    slopes = jnp.asarray([2.0 ** -(i / 4 + 1) for i in range(Hq)],
-                         jnp.float32)
-    refa = oracle(q, kp, vp, bt, ctx, scale, alibi_slopes=slopes)
-    gota = np.asarray(paged_decode_attention(
-        q, kp, vp, bt, ctx, slopes, scale=scale, pages_per_chunk=2),
-        np.float32)
-    check("tokenmajor alibi", refa, gota)
+        slopes = jnp.asarray([2.0 ** -(i / 4 + 1) for i in range(Hq)],
+                             jnp.float32)
+        refa = oracle(q, kp, vp, bt, ctx, scale, alibi_slopes=slopes)
+        gota = np.asarray(paged_decode_attention(
+            q, kp, vp, bt, ctx, slopes, scale=scale, pages_per_chunk=2),
+            np.float32)
+        check("tokenmajor alibi", refa, gota)
 
-    # -- ragged work-list grid (compiled): mixed real chunk counts,
-    #    a ctx=0 row's masked item, dead list padding --
-    from aphrodite_tpu.ops.pallas.paged_attention import (
-        build_decode_work_list)
-    pages_i = [max(1, -(-int(c) // page)) for c in ctx_np]
-    for ppcr in (2, 4):
-        workr = build_decode_work_list(pages_i, ppcr)
-        gotr = np.asarray(paged_decode_attention(
-            q, kp, vp, bt, ctx, scale=scale, pages_per_chunk=ppcr,
-            work_items=workr), np.float32)
-        check(f"ragged ppc={ppcr} bf16", ref, gotr)
-    got8r = np.asarray(paged_decode_attention(
-        q, kp8, vp8, bt, ctx, scale=scale, kv_scale=S,
-        pages_per_chunk=2,
-        work_items=build_decode_work_list(pages_i, 2)), np.float32)
-    check("ragged int8 KV", ref8, got8r)
+    with section("decode attention (ragged grid)"):
+        # -- ragged work-list grid (compiled): mixed real chunk counts,
+        #    a ctx=0 row's masked item, dead list padding --
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list)
+        pages_i = [max(1, -(-int(c) // page)) for c in ctx_np]
+        for ppcr in (2, 4):
+            workr = build_decode_work_list(pages_i, ppcr)
+            gotr = np.asarray(paged_decode_attention(
+                q, kp, vp, bt, ctx, scale=scale, pages_per_chunk=ppcr,
+                work_items=workr), np.float32)
+            check(f"ragged ppc={ppcr} bf16", ref, gotr)
+        got8r = np.asarray(paged_decode_attention(
+            q, kp8, vp8, bt, ctx, scale=scale, kv_scale=S,
+            pages_per_chunk=2,
+            work_items=build_decode_work_list(pages_i, 2)), np.float32)
+        check("ragged int8 KV", ref8, got8r)
+        # The classic online-softmax multiply (APHRODITE_ATTN_AMLA=0):
+        # the arm the default exponent-bias rescale is A/B'd against.
+        gotm = np.asarray(paged_decode_attention(
+            q, kp, vp, bt, ctx, scale=scale, pages_per_chunk=2,
+            work_items=build_decode_work_list(pages_i, 2), amla=False),
+            np.float32)
+        check("ragged bf16, classic rescale multiply", ref, gotm)
 
-    # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --
-    for d_true in (64, 80):
-        dp = 128
-        qs = jnp.asarray(rs.randn(B, Hq, d_true) * 0.1, jnp.bfloat16)
-        k4 = rs.randn(pages, page, Hkv, d_true) * 0.1
-        v4 = rs.randn(pages, page, Hkv, d_true) * 0.1
-        kps = jnp.asarray(k4.reshape(pages, page, -1), jnp.bfloat16)
-        vps = jnp.asarray(v4.reshape(pages, page, -1), jnp.bfloat16)
-        pad3 = ((0, 0), (0, 0), (0, dp - d_true))
-        pad4 = ((0, 0), (0, 0), (0, 0), (0, dp - d_true))
-        refs = oracle(qs, kps, vps, bt, ctx, scale)
-        kpp = jnp.asarray(np.pad(k4, pad4).reshape(pages, page, -1),
-                          jnp.bfloat16)
-        vpp = jnp.asarray(np.pad(v4, pad4).reshape(pages, page, -1),
-                          jnp.bfloat16)
-        got = np.asarray(paged_decode_attention(
-            jnp.pad(qs, pad3), kpp, vpp, bt, ctx, scale=scale,
-            pages_per_chunk=2), np.float32)[..., :d_true]
-        check(f"tokenmajor head{d_true} padded", refs, got)
+    with section("decode attention (padded heads)"):
+        # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --
+        for d_true in (64, 80):
+            dp = 128
+            qs = jnp.asarray(rs.randn(B, Hq, d_true) * 0.1, jnp.bfloat16)
+            k4 = rs.randn(pages, page, Hkv, d_true) * 0.1
+            v4 = rs.randn(pages, page, Hkv, d_true) * 0.1
+            kps = jnp.asarray(k4.reshape(pages, page, -1), jnp.bfloat16)
+            vps = jnp.asarray(v4.reshape(pages, page, -1), jnp.bfloat16)
+            pad3 = ((0, 0), (0, 0), (0, dp - d_true))
+            pad4 = ((0, 0), (0, 0), (0, 0), (0, dp - d_true))
+            refs = oracle(qs, kps, vps, bt, ctx, scale)
+            kpp = jnp.asarray(np.pad(k4, pad4).reshape(pages, page, -1),
+                              jnp.bfloat16)
+            vpp = jnp.asarray(np.pad(v4, pad4).reshape(pages, page, -1),
+                              jnp.bfloat16)
+            got = np.asarray(paged_decode_attention(
+                jnp.pad(qs, pad3), kpp, vpp, bt, ctx, scale=scale,
+                pages_per_chunk=2), np.float32)[..., :d_true]
+            check(f"tokenmajor head{d_true} padded", refs, got)
 
-    # -- fused-write drain protocol: page CONTENTS after multi-batch
-    #    fused decode (compiled, non-interpret) must match a host-side
-    #    slot write bit-for-bit. The cell-(i-2) writeback drain
-    #    (paged_attention.py:185-201,307-339) is the subtle part: a
-    #    dropped or mis-slotted writeback corrupts a page silently.
-    for Hq2, Hkv2, tag in ((32, 8, "n_hb=1"), (16, 16, "n_hb=2")):
-        B2, d2, page2, pps2 = 24, 128, 16, 8
-        pages2 = B2 * pps2 + 1
-        q2 = jnp.asarray(rs.randn(B2, Hq2, d2) * 0.1, jnp.bfloat16)
-        kp2 = jnp.asarray(rs.randn(pages2, page2, Hkv2 * d2) * 0.1,
-                          jnp.bfloat16)
-        vp2 = jnp.asarray(rs.randn(pages2, page2, Hkv2 * d2) * 0.1,
-                          jnp.bfloat16)
-        # Sequence-exclusive pages (the engine decode contract), in a
-        # shuffled order so page ids don't correlate with batch index.
-        perm = rs.permutation(pages2 - 1)
-        bt2 = jnp.asarray(perm[:B2 * pps2].reshape(B2, pps2), jnp.int32)
-        ctx2_np = rs.randint(1, pps2 * page2, (B2,)).astype(np.int32)
-        ctx2_np[5] = 0                     # padded row: no write
-        ctx2_np[7] = 1                     # minimum context
-        ctx2_np[11] = pps2 * page2         # full table
-        ctx2 = jnp.asarray(ctx2_np)
-        kn2 = jnp.asarray(rs.randn(B2, Hkv2, d2) * 0.1, jnp.bfloat16)
-        vn2 = jnp.asarray(rs.randn(B2, Hkv2, d2) * 0.1, jnp.bfloat16)
-        for ppc2, grid in ((2, "classic"), (pps2, "classic"),
-                           (2, "ragged"), (pps2, "ragged")):
-            # Ragged work lists come from each row's RESERVED pages
-            # (the full table width here), the runner's discipline —
-            # chunks past ctx are masked, and the write counter ring
-            # must stay correct with one writer item per row.
-            work2 = build_decode_work_list([pps2] * B2, ppc2) \
-                if grid == "ragged" else None
-            outf, kpf, vpf = paged_decode_attention(
-                q2, kp2, vp2, bt2, ctx2, knew=kn2, vnew=vn2,
-                scale=scale, pages_per_chunk=ppc2, work_items=work2)
-            ekp = np.asarray(kp2, np.float32).copy()
-            evp = np.asarray(vp2, np.float32).copy()
-            knf = np.asarray(kn2, np.float32).reshape(B2, Hkv2 * d2)
-            vnf = np.asarray(vn2, np.float32).reshape(B2, Hkv2 * d2)
-            for i in range(B2):
-                c = int(ctx2_np[i])
-                if c == 0:
-                    continue
-                pg = int(np.asarray(bt2)[i, (c - 1) // page2])
-                ekp[pg, (c - 1) % page2] = knf[i]
-                evp[pg, (c - 1) % page2] = vnf[i]
-            errk = np.abs(np.asarray(kpf, np.float32) - ekp).max()
-            errv = np.abs(np.asarray(vpf, np.float32) - evp).max()
-            name = f"fused-write contents {tag} ppc={ppc2} {grid}"
-            print(f"{name}: k err {errk:.2e} v err {errv:.2e}")
-            if not (errk == 0.0 and errv == 0.0):   # bit-for-bit
-                failures.append((name, max(errk, errv)))
-            # attention output must equal the reference computed over
-            # the POST-write pages (the injected token participates)
-            ref2 = np.asarray(paged_decode_attention_ref(
-                q2, jnp.asarray(ekp, jnp.bfloat16),
-                jnp.asarray(evp, jnp.bfloat16), bt2, ctx2, scale),
-                np.float32)
-            ref2[ctx2_np == 0] = 0.0
-            erro = np.abs(np.asarray(outf, np.float32) - ref2).max()
-            print(f"{name}: out err {erro:.2e}")
-            if not (erro < 3e-2):
-                failures.append((name + " out", erro))
+    with section("decode attention (fused KV write)"):
+        # -- fused-write drain protocol: page CONTENTS after multi-batch
+        #    fused decode (compiled, non-interpret) must match a host-side
+        #    slot write bit-for-bit. The cell-(i-2) writeback drain
+        #    (paged_attention.py:185-201,307-339) is the subtle part: a
+        #    dropped or mis-slotted writeback corrupts a page silently.
+        for Hq2, Hkv2, tag in ((32, 8, "n_hb=1"), (16, 16, "n_hb=2")):
+            B2, d2, page2, pps2 = 24, 128, 16, 8
+            pages2 = B2 * pps2 + 1
+            q2 = jnp.asarray(rs.randn(B2, Hq2, d2) * 0.1, jnp.bfloat16)
+            kp2 = jnp.asarray(rs.randn(pages2, page2, Hkv2 * d2) * 0.1,
+                              jnp.bfloat16)
+            vp2 = jnp.asarray(rs.randn(pages2, page2, Hkv2 * d2) * 0.1,
+                              jnp.bfloat16)
+            # Sequence-exclusive pages (the engine decode contract), in a
+            # shuffled order so page ids don't correlate with batch index.
+            perm = rs.permutation(pages2 - 1)
+            bt2 = jnp.asarray(perm[:B2 * pps2].reshape(B2, pps2), jnp.int32)
+            ctx2_np = rs.randint(1, pps2 * page2, (B2,)).astype(np.int32)
+            ctx2_np[5] = 0                     # padded row: no write
+            ctx2_np[7] = 1                     # minimum context
+            ctx2_np[11] = pps2 * page2         # full table
+            ctx2 = jnp.asarray(ctx2_np)
+            kn2 = jnp.asarray(rs.randn(B2, Hkv2, d2) * 0.1, jnp.bfloat16)
+            vn2 = jnp.asarray(rs.randn(B2, Hkv2, d2) * 0.1, jnp.bfloat16)
+            for ppc2, grid in ((2, "classic"), (pps2, "classic"),
+                               (2, "ragged"), (pps2, "ragged")):
+                # Ragged work lists come from each row's RESERVED pages
+                # (the full table width here), the runner's discipline —
+                # chunks past ctx are masked, and the write counter ring
+                # must stay correct with one writer item per row.
+                work2 = build_decode_work_list([pps2] * B2, ppc2) \
+                    if grid == "ragged" else None
+                outf, kpf, vpf = paged_decode_attention(
+                    q2, kp2, vp2, bt2, ctx2, knew=kn2, vnew=vn2,
+                    scale=scale, pages_per_chunk=ppc2, work_items=work2)
+                ekp = np.asarray(kp2, np.float32).copy()
+                evp = np.asarray(vp2, np.float32).copy()
+                knf = np.asarray(kn2, np.float32).reshape(B2, Hkv2 * d2)
+                vnf = np.asarray(vn2, np.float32).reshape(B2, Hkv2 * d2)
+                for i in range(B2):
+                    c = int(ctx2_np[i])
+                    if c == 0:
+                        continue
+                    pg = int(np.asarray(bt2)[i, (c - 1) // page2])
+                    ekp[pg, (c - 1) % page2] = knf[i]
+                    evp[pg, (c - 1) % page2] = vnf[i]
+                errk = np.abs(np.asarray(kpf, np.float32) - ekp).max()
+                errv = np.abs(np.asarray(vpf, np.float32) - evp).max()
+                name = f"fused-write contents {tag} ppc={ppc2} {grid}"
+                print(f"{name}: k err {errk:.2e} v err {errv:.2e}")
+                if not (errk == 0.0 and errv == 0.0):   # bit-for-bit
+                    failures.append((name, max(errk, errv)))
+                # attention output must equal the reference computed over
+                # the POST-write pages (the injected token participates)
+                ref2 = np.asarray(paged_decode_attention_ref(
+                    q2, jnp.asarray(ekp, jnp.bfloat16),
+                    jnp.asarray(evp, jnp.bfloat16), bt2, ctx2, scale),
+                    np.float32)
+                ref2[ctx2_np == 0] = 0.0
+                erro = np.abs(np.asarray(outf, np.float32) - ref2).max()
+                print(f"{name}: out err {erro:.2e}")
+                if not (erro < 3e-2):
+                    failures.append((name + " out", erro))
 
-    # -- prefill page writer (whole-page DMA, partial tail, OOB) --
-    from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,
-                                                   write_kv_pages_prefill)
-    wp, wps, whd = 16, 16, 1024
-    kpw = jnp.asarray(rs.randn(wp, wps, whd) * 0.1, jnp.bfloat16)
-    vpw = jnp.asarray(rs.randn(wp, wps, whd) * 0.1, jnp.bfloat16)
-    knw = rs.randn(4 * 32, whd).astype(np.float32) * 0.1
-    vnw = rs.randn(4 * 32, whd).astype(np.float32) * 0.1
-    pidw = np.array([1, 2, 4, 5, 7, 8, wp, wp], dtype=np.int32)
-    sblkw = np.array([0, 1, 2, 3, 4, 5, 0, 0], dtype=np.int32)
-    vldw = np.array([16, 16, 16, 5, 16, 9, 0, 0], dtype=np.int32)
-    gk, gv = write_kv_pages_prefill(
-        jnp.asarray(knw, jnp.bfloat16), jnp.asarray(vnw, jnp.bfloat16),
-        kpw, vpw, jnp.asarray(pidw), jnp.asarray(sblkw),
-        jnp.asarray(vldw))
-    ek = np.asarray(kpw, np.float32)
-    for c in range(8):
-        if pidw[c] >= wp:
-            continue
-        rows = np.asarray(jnp.asarray(knw, jnp.bfloat16), np.float32)
-        ek[pidw[c], :vldw[c]] = rows[sblkw[c] * wps:
-                                     sblkw[c] * wps + vldw[c]]
-    errw = np.abs(np.asarray(gk, np.float32) - ek).max()
-    print(f"prefill page writer: max err {errw:.2e}")
-    if not (errw < 1e-6):
-        failures.append(("prefill_writer", errw))
+    with section("prefill page writer"):
+        # -- prefill page writer (whole-page DMA, partial tail, OOB) --
+        from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,
+                                                       write_kv_pages_prefill)
+        wp, wps, whd = 16, 16, 1024
+        kpw = jnp.asarray(rs.randn(wp, wps, whd) * 0.1, jnp.bfloat16)
+        vpw = jnp.asarray(rs.randn(wp, wps, whd) * 0.1, jnp.bfloat16)
+        knw = rs.randn(4 * 32, whd).astype(np.float32) * 0.1
+        vnw = rs.randn(4 * 32, whd).astype(np.float32) * 0.1
+        pidw = np.array([1, 2, 4, 5, 7, 8, wp, wp], dtype=np.int32)
+        sblkw = np.array([0, 1, 2, 3, 4, 5, 0, 0], dtype=np.int32)
+        vldw = np.array([16, 16, 16, 5, 16, 9, 0, 0], dtype=np.int32)
+        gk, gv = write_kv_pages_prefill(
+            jnp.asarray(knw, jnp.bfloat16), jnp.asarray(vnw, jnp.bfloat16),
+            kpw, vpw, jnp.asarray(pidw), jnp.asarray(sblkw),
+            jnp.asarray(vldw))
+        ek = np.asarray(kpw, np.float32)
+        for c in range(8):
+            if pidw[c] >= wp:
+                continue
+            rows = np.asarray(jnp.asarray(knw, jnp.bfloat16), np.float32)
+            ek[pidw[c], :vldw[c]] = rows[sblkw[c] * wps:
+                                         sblkw[c] * wps + vldw[c]]
+        errw = np.abs(np.asarray(gk, np.float32) - ek).max()
+        print(f"prefill page writer: max err {errw:.2e}")
+        if not (errw < 1e-6):
+            failures.append(("prefill_writer", errw))
 
-    # decode pipelined writer on-chip
-    slots_d = jnp.asarray(np.array([3 * wps + 2, 9 * wps + 7,
-                                    11 * wps + 1, wp * wps],
-                                   dtype=np.int32))
-    kd = jnp.asarray(rs.randn(4, whd) * 0.1, jnp.bfloat16)
-    gk2, _ = write_kv_pages(kd, kd, gk, gv, slots_d,
-                            distinct_pages=True)
-    ek2 = np.asarray(gk, np.float32)
-    for i, s in enumerate(np.asarray(slots_d)[:3]):
-        ek2[s // wps, s % wps] = np.asarray(kd, np.float32)[i]
-    errd = np.abs(np.asarray(gk2, np.float32) - ek2).max()
-    print(f"decode pipelined writer: max err {errd:.2e}")
-    if not (errd < 1e-6):
-        failures.append(("decode_writer", errd))
+    with section("decode page writer"):
+        # decode pipelined writer on-chip
+        slots_d = jnp.asarray(np.array([3 * wps + 2, 9 * wps + 7,
+                                        11 * wps + 1, wp * wps],
+                                       dtype=np.int32))
+        kd = jnp.asarray(rs.randn(4, whd) * 0.1, jnp.bfloat16)
+        gk2, _ = write_kv_pages(kd, kd, gk, gv, slots_d,
+                                distinct_pages=True)
+        ek2 = np.asarray(gk, np.float32)
+        for i, s in enumerate(np.asarray(slots_d)[:3]):
+            ek2[s // wps, s % wps] = np.asarray(kd, np.float32)[i]
+        errd = np.abs(np.asarray(gk2, np.float32) - ek2).max()
+        print(f"decode pipelined writer: max err {errd:.2e}")
+        if not (errd < 1e-6):
+            failures.append(("decode_writer", errd))
 
-    # -- fused GPTQ dequant matmul --
-    bits, gs, K, N, m = 4, 128, 4096, 14336, 256
-    pack, G = 32 // bits, K // gs
-    qw = jnp.asarray(rs.randint(-2**31, 2**31, (K // pack, N),
-                                dtype=np.int32))
-    qz = jnp.asarray(rs.randint(-2**31, 2**31, (G, N // pack),
-                                dtype=np.int32))
-    sc = jnp.asarray(rs.rand(G, N) * 0.01, jnp.bfloat16)
-    x = jnp.asarray(rs.randn(m, K), jnp.bfloat16)
-    method = GPTQLinearMethod(GPTQConfig(bits, gs))
-    params = {"qweight": qw, "qzeros": qz, "scales": sc,
-              "g_idx": jnp.asarray(np.arange(K) // gs, np.int32)}
-    refq = np.asarray(x @ method.dequantize(params, jnp.bfloat16),
-                      np.float32)
-    gotq = np.asarray(gptq_matmul(x, qw, qz, sc, bits=bits,
-                                  group_size=gs), np.float32)
-    rel = np.abs(refq - gotq).max() / (np.abs(refq).max() + 1e-9)
-    print(f"gptq_matmul int4: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("gptq", rel))
+    with section("gptq_matmul"):
+        # -- fused GPTQ dequant matmul --
+        bits, gs, K, N, m = 4, 128, 4096, 14336, 256
+        pack, G = 32 // bits, K // gs
+        qw = jnp.asarray(rs.randint(-2**31, 2**31, (K // pack, N),
+                                    dtype=np.int32))
+        qz = jnp.asarray(rs.randint(-2**31, 2**31, (G, N // pack),
+                                    dtype=np.int32))
+        sc = jnp.asarray(rs.rand(G, N) * 0.01, jnp.bfloat16)
+        x = jnp.asarray(rs.randn(m, K), jnp.bfloat16)
+        method = GPTQLinearMethod(GPTQConfig(bits, gs))
+        params = {"qweight": qw, "qzeros": qz, "scales": sc,
+                  "g_idx": jnp.asarray(np.arange(K) // gs, np.int32)}
+        refq = np.asarray(x @ method.dequantize(params, jnp.bfloat16),
+                          np.float32)
+        gotq = np.asarray(gptq_matmul(x, qw, qz, sc, bits=bits,
+                                      group_size=gs), np.float32)
+        rel = np.abs(refq - gotq).max() / (np.abs(refq).max() + 1e-9)
+        print(f"gptq_matmul int4: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("gptq", rel))
 
-    # -- streamed skinny-m grid, compiled on the real chip: the
-    # decode-shaped (m<=64) work-list/DMA-ring path vs the classic
-    # grid at identical inputs, W4A16 and W4A8 (deferred on/off) --
-    from aphrodite_tpu.ops.pallas.quant_matmul import gptq_matmul_a8
-    xs16 = jnp.asarray(rs.randn(16, K), jnp.bfloat16)
-    refs16 = np.asarray(xs16 @ method.dequantize(params, jnp.bfloat16),
-                        np.float32)
-    gots16 = np.asarray(gptq_matmul(xs16, qw, qz, sc, bits=bits,
-                                    group_size=gs, stream=True),
-                        np.float32)
-    rel = np.abs(refs16 - gots16).max() / (np.abs(refs16).max() + 1e-9)
-    print(f"gptq_matmul streamed m=16: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("gptq_stream", rel))
-    a8c = np.asarray(gptq_matmul_a8(xs16, qw, qz, sc, bits=bits,
-                                    group_size=gs, stream=False),
-                     np.float32)
-    for tag, kwargs in (("stream", dict(stream=True)),
-                        ("stream+deferred",
-                         dict(stream=True, deferred=True))):
-        a8s = np.asarray(gptq_matmul_a8(xs16, qw, qz, sc, bits=bits,
-                                        group_size=gs, **kwargs),
+    with section("gptq streamed grid"):
+        # -- streamed skinny-m grid, compiled on the real chip: the
+        # decode-shaped (m<=64) work-list/DMA-ring path vs the classic
+        # grid at identical inputs, W4A16 and W4A8 (deferred on/off) --
+        from aphrodite_tpu.ops.pallas.quant_matmul import gptq_matmul_a8
+        xs16 = jnp.asarray(rs.randn(16, K), jnp.bfloat16)
+        refs16 = np.asarray(xs16 @ method.dequantize(params, jnp.bfloat16),
+                            np.float32)
+        gots16 = np.asarray(gptq_matmul(xs16, qw, qz, sc, bits=bits,
+                                        group_size=gs, stream=True),
+                            np.float32)
+        rel = np.abs(refs16 - gots16).max() / (np.abs(refs16).max() + 1e-9)
+        print(f"gptq_matmul streamed m=16: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("gptq_stream", rel))
+        a8c = np.asarray(gptq_matmul_a8(xs16, qw, qz, sc, bits=bits,
+                                        group_size=gs, stream=False),
                          np.float32)
-        rel = np.abs(a8c - a8s).max() / (np.abs(a8c).max() + 1e-9)
-        print(f"gptq_matmul_a8 {tag} m=16 vs classic: rel err {rel:.2e}")
-        if rel > 1e-3:
-            failures.append((f"gptq_a8_{tag}", rel))
+        for tag, kwargs in (("stream", dict(stream=True)),
+                            ("stream+deferred",
+                             dict(stream=True, deferred=True))):
+            a8s = np.asarray(gptq_matmul_a8(xs16, qw, qz, sc, bits=bits,
+                                            group_size=gs, **kwargs),
+                             np.float32)
+            rel = np.abs(a8c - a8s).max() / (np.abs(a8c).max() + 1e-9)
+            print(f"gptq_matmul_a8 {tag} m=16 vs classic: rel err {rel:.2e}")
+            if rel > 1e-3:
+                failures.append((f"gptq_a8_{tag}", rel))
 
-    # -- fused AWQ dequant matmul --
-    from aphrodite_tpu.modeling.layers.quantization.awq import (
-        AWQConfig, AWQLinearMethod)
-    from aphrodite_tpu.ops.pallas.quant_matmul import (awq_matmul,
-                                                       int8_matmul)
-    K, N, m = 4096, 6144, 256
-    G = K // 128
-    qwa = jnp.asarray(rs.randint(-2**31, 2**31, (K, N // 8),
-                                 dtype=np.int32))
-    qza = jnp.asarray(rs.randint(-2**31, 2**31, (G, N // 8),
-                                 dtype=np.int32))
-    sca = jnp.asarray(rs.rand(G, N) * 0.01, jnp.bfloat16)
-    xa = jnp.asarray(rs.randn(m, K), jnp.bfloat16)
-    amethod = AWQLinearMethod(AWQConfig(4, 128))
-    aparams = {"qweight": qwa, "qzeros": qza, "scales": sca}
-    refa2 = np.asarray(xa @ amethod.dequantize(aparams, jnp.bfloat16),
-                       np.float32)
-    gota2 = np.asarray(awq_matmul(xa, qwa, qza, sca, group_size=128),
-                       np.float32)
-    rel = np.abs(refa2 - gota2).max() / (np.abs(refa2).max() + 1e-9)
-    print(f"awq_matmul int4: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("awq", rel))
+    with section("gptq W4A8 at Mistral-7B layer shapes"):
+        # Every (K, N) of the 7B decoder layer at the row counts the
+        # serving path reaches: decode and speculative verify (m <= 64:
+        # streamed grid, activations quantized in the kernel prologue),
+        # a full decode batch, and prefill rounds (classic/deferred
+        # grids behind the one-pass quantize kernel, whose whole-K row
+        # block is the tightest VMEM fit at K=14336).
+        for K7, N7 in ((4096, 6144), (4096, 4096), (4096, 28672),
+                       (14336, 4096)):
+            qw7 = jnp.asarray(rs.randint(-2**31, 2**31, (K7 // 8, N7),
+                                         dtype=np.int32))
+            qz7 = jnp.asarray(rs.randint(-2**31, 2**31,
+                                         (K7 // 128, N7 // 8),
+                                         dtype=np.int32))
+            sc7 = jnp.asarray(rs.rand(K7 // 128, N7) * 0.01, jnp.bfloat16)
+            w7 = method.dequantize(
+                {"qweight": qw7, "qzeros": qz7, "scales": sc7,
+                 "g_idx": jnp.asarray(np.arange(K7) // 128, np.int32)},
+                jnp.bfloat16)
+            for m7 in (8, 40, 512, 4096):
+                x7 = jnp.asarray(rs.randn(m7, K7), jnp.bfloat16)
+                ref7 = np.asarray(x7 @ w7, np.float32)
+                got7 = np.asarray(gptq_matmul_a8(
+                    x7, qw7, qz7, sc7, bits=4, group_size=128),
+                    np.float32)
+                rel = np.abs(ref7 - got7).max() / \
+                    (np.abs(ref7).max() + 1e-9)
+                name = f"gptq_matmul_a8 K={K7} N={N7} m={m7}"
+                print(f"{name}: rel err {rel:.2e}")
+                if not rel < 3e-2:
+                    failures.append((name, rel))
 
-    # -- GGUF at-rest matmuls (Q4_K affine, Q8_0 grouped int8) --
-    from aphrodite_tpu.modeling.layers.quantization.gguf import (
-        GGUFConfig, GGUFLinearMethod, q4k_to_kernel)
-    from aphrodite_tpu.ops.pallas.quant_matmul import (gguf_q4k_matmul,
-                                                       gguf_q8_matmul)
-    Kg, Ng, mg = 4096, 4096, 256
-    nblk = Ng * Kg // 256
-    blkb = np.zeros((nblk, 144), np.uint8)
-    dscale = (rs.rand(nblk).astype(np.float16) * 0.01 + 1e-3)
-    blkb[:, 0:2] = dscale.view(np.uint8).reshape(nblk, 2)
-    blkb[:, 2:4] = dscale.view(np.uint8).reshape(nblk, 2)
-    blkb[:, 4:16] = rs.randint(0, 256, (nblk, 12), dtype=np.uint8)
-    blkb[:, 16:144] = rs.randint(0, 256, (nblk, 128), dtype=np.uint8)
-    qwg, dlg, mlg = q4k_to_kernel(blkb, Ng, Kg)
-    gmethod = GGUFLinearMethod(GGUFConfig())
-    wg = gmethod.dequantize(
-        {"qweight": jnp.asarray(qwg), "dl": jnp.asarray(dlg),
-         "ml": jnp.asarray(mlg)}, jnp.bfloat16)
-    xg = jnp.asarray(rs.randn(mg, Kg), jnp.bfloat16)
-    refg = np.asarray(xg @ wg, np.float32)
-    gotg = np.asarray(gguf_q4k_matmul(
-        xg, jnp.asarray(qwg), jnp.asarray(dlg.astype(np.float32)),
-        jnp.asarray(mlg.astype(np.float32))), np.float32)
-    rel = np.abs(refg - gotg).max() / (np.abs(refg).max() + 1e-9)
-    print(f"gguf_q4k_matmul: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("gguf_q4k", rel))
+    with section("awq_matmul"):
+        # -- fused AWQ dequant matmul --
+        from aphrodite_tpu.modeling.layers.quantization.awq import (
+            AWQConfig, AWQLinearMethod)
+        from aphrodite_tpu.ops.pallas.quant_matmul import (awq_matmul,
+                                                           int8_matmul)
+        K, N, m = 4096, 6144, 256
+        G = K // 128
+        qwa = jnp.asarray(rs.randint(-2**31, 2**31, (K, N // 8),
+                                     dtype=np.int32))
+        qza = jnp.asarray(rs.randint(-2**31, 2**31, (G, N // 8),
+                                     dtype=np.int32))
+        sca = jnp.asarray(rs.rand(G, N) * 0.01, jnp.bfloat16)
+        xa = jnp.asarray(rs.randn(m, K), jnp.bfloat16)
+        amethod = AWQLinearMethod(AWQConfig(4, 128))
+        aparams = {"qweight": qwa, "qzeros": qza, "scales": sca}
+        refa2 = np.asarray(xa @ amethod.dequantize(aparams, jnp.bfloat16),
+                           np.float32)
+        gota2 = np.asarray(awq_matmul(xa, qwa, qza, sca, group_size=128),
+                           np.float32)
+        rel = np.abs(refa2 - gota2).max() / (np.abs(refa2).max() + 1e-9)
+        print(f"awq_matmul int4: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("awq", rel))
 
-    qs8 = jnp.asarray(rs.randint(-128, 128, (Kg, Ng), dtype=np.int8))
-    dg8 = jnp.asarray(rs.rand(Kg // 32, Ng) * 0.01 + 1e-3, jnp.float32)
-    ref8m = np.asarray((xg.astype(jnp.float32) @
-                        (qs8.astype(jnp.float32) *
-                         jnp.repeat(dg8, 32, axis=0))), np.float32)
-    got8m = np.asarray(gguf_q8_matmul(xg, qs8, dg8), np.float32)
-    rel = np.abs(ref8m - got8m).max() / (np.abs(ref8m).max() + 1e-9)
-    print(f"gguf_q8_matmul: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("gguf_q8", rel))
+    with section("gguf q4k/q8 matmul"):
+        # -- GGUF at-rest matmuls (Q4_K affine, Q8_0 grouped int8) --
+        from aphrodite_tpu.modeling.layers.quantization.gguf import (
+            GGUFConfig, GGUFLinearMethod, q4k_to_kernel)
+        from aphrodite_tpu.ops.pallas.quant_matmul import (gguf_q4k_matmul,
+                                                           gguf_q8_matmul)
+        Kg, Ng, mg = 4096, 4096, 256
+        nblk = Ng * Kg // 256
+        blkb = np.zeros((nblk, 144), np.uint8)
+        dscale = (rs.rand(nblk).astype(np.float16) * 0.01 + 1e-3)
+        blkb[:, 0:2] = dscale.view(np.uint8).reshape(nblk, 2)
+        blkb[:, 2:4] = dscale.view(np.uint8).reshape(nblk, 2)
+        blkb[:, 4:16] = rs.randint(0, 256, (nblk, 12), dtype=np.uint8)
+        blkb[:, 16:144] = rs.randint(0, 256, (nblk, 128), dtype=np.uint8)
+        qwg, dlg, mlg = q4k_to_kernel(blkb, Ng, Kg)
+        gmethod = GGUFLinearMethod(GGUFConfig())
+        wg = gmethod.dequantize(
+            {"qweight": jnp.asarray(qwg), "dl": jnp.asarray(dlg),
+             "ml": jnp.asarray(mlg)}, jnp.bfloat16)
+        xg = jnp.asarray(rs.randn(mg, Kg), jnp.bfloat16)
+        refg = np.asarray(xg @ wg, np.float32)
+        gotg = np.asarray(gguf_q4k_matmul(
+            xg, jnp.asarray(qwg), jnp.asarray(dlg.astype(np.float32)),
+            jnp.asarray(mlg.astype(np.float32))), np.float32)
+        rel = np.abs(refg - gotg).max() / (np.abs(refg).max() + 1e-9)
+        print(f"gguf_q4k_matmul: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("gguf_q4k", rel))
 
-    # -- SqueezeLLM fused LUT matmul --
-    from aphrodite_tpu.modeling.layers.quantization.squeezellm import (
-        SqueezeLLMConfig)
-    from aphrodite_tpu.ops.pallas.quant_matmul import squeezellm_matmul
-    Ks, Ns, ms = 4096, 4096, 256
-    luts = jnp.asarray(rs.randn(Ns, 16) * 0.01, jnp.float32)
-    qws = jnp.asarray(rs.randint(-2**31, 2**31, (Ks // 8, Ns),
-                                 dtype=np.int32))
-    xs = jnp.asarray(rs.randn(ms, Ks), jnp.bfloat16)
-    smethod = SqueezeLLMConfig().get_linear_method()
-    refs2 = np.asarray(xs @ smethod.dequantize(
-        {"qweight": qws, "lookup_table": luts}, jnp.bfloat16),
-        np.float32)
-    gots2 = np.asarray(squeezellm_matmul(xs, qws, luts), np.float32)
-    rel = np.abs(refs2 - gots2).max() / (np.abs(refs2).max() + 1e-9)
-    print(f"squeezellm_matmul: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("squeezellm", rel))
+        qs8 = jnp.asarray(rs.randint(-128, 128, (Kg, Ng), dtype=np.int8))
+        dg8 = jnp.asarray(rs.rand(Kg // 32, Ng) * 0.01 + 1e-3, jnp.float32)
+        ref8m = np.asarray((xg.astype(jnp.float32) @
+                            (qs8.astype(jnp.float32) *
+                             jnp.repeat(dg8, 32, axis=0))), np.float32)
+        got8m = np.asarray(gguf_q8_matmul(xg, qs8, dg8), np.float32)
+        rel = np.abs(ref8m - got8m).max() / (np.abs(ref8m).max() + 1e-9)
+        print(f"gguf_q8_matmul: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("gguf_q8", rel))
 
-    # -- GGUF grouped-int8 (Q6_K-at-rest form) matmul --
-    from aphrodite_tpu.ops.pallas.quant_matmul import gguf_i8g_matmul
-    qsg = jnp.asarray(rs.randint(-128, 128, (Ks, Ns), dtype=np.int8))
-    dg16 = jnp.asarray(rs.rand(Ks // 16, Ns) * 0.01 + 1e-3, jnp.float32)
-    xg2 = jnp.asarray(rs.randn(ms, Ks), jnp.bfloat16)
-    refg2 = np.asarray(
-        (xg2.astype(jnp.float32) @
-         (qsg.astype(jnp.float32) * jnp.repeat(dg16, 16, axis=0))),
-        np.float32)
-    gotg2 = np.asarray(gguf_i8g_matmul(xg2, qsg, dg16), np.float32)
-    rel = np.abs(refg2 - gotg2).max() / (np.abs(refg2).max() + 1e-9)
-    print(f"gguf_i8g_matmul: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("gguf_i8g", rel))
+    with section("squeezellm_matmul"):
+        # -- SqueezeLLM fused LUT matmul --
+        from aphrodite_tpu.modeling.layers.quantization.squeezellm import (
+            SqueezeLLMConfig)
+        from aphrodite_tpu.ops.pallas.quant_matmul import squeezellm_matmul
+        Ks, Ns, ms = 4096, 4096, 256
+        luts = jnp.asarray(rs.randn(Ns, 16) * 0.01, jnp.float32)
+        qws = jnp.asarray(rs.randint(-2**31, 2**31, (Ks // 8, Ns),
+                                     dtype=np.int32))
+        xs = jnp.asarray(rs.randn(ms, Ks), jnp.bfloat16)
+        smethod = SqueezeLLMConfig().get_linear_method()
+        refs2 = np.asarray(xs @ smethod.dequantize(
+            {"qweight": qws, "lookup_table": luts}, jnp.bfloat16),
+            np.float32)
+        gots2 = np.asarray(squeezellm_matmul(xs, qws, luts), np.float32)
+        rel = np.abs(refs2 - gots2).max() / (np.abs(refs2).max() + 1e-9)
+        print(f"squeezellm_matmul: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("squeezellm", rel))
 
-    # -- int8 dense matmul --
-    w8 = jnp.asarray(rs.randint(-128, 128, (K, N), dtype=np.int8))
-    s8 = jnp.asarray(rs.rand(N) * 0.01 + 1e-3, jnp.float32)
-    refi = np.asarray((xa.astype(jnp.float32) @ w8.astype(jnp.float32))
-                      * s8, np.float32)
-    goti = np.asarray(int8_matmul(xa, w8, s8), np.float32)
-    rel = np.abs(refi - goti).max() / (np.abs(refi).max() + 1e-9)
-    print(f"int8_matmul: rel err {rel:.2e}")
-    if rel > 3e-2:
-        failures.append(("int8", rel))
+    with section("gguf i8g matmul"):
+        # -- GGUF grouped-int8 (Q6_K-at-rest form) matmul --
+        from aphrodite_tpu.ops.pallas.quant_matmul import gguf_i8g_matmul
+        qsg = jnp.asarray(rs.randint(-128, 128, (Ks, Ns), dtype=np.int8))
+        dg16 = jnp.asarray(rs.rand(Ks // 16, Ns) * 0.01 + 1e-3, jnp.float32)
+        xg2 = jnp.asarray(rs.randn(ms, Ks), jnp.bfloat16)
+        refg2 = np.asarray(
+            (xg2.astype(jnp.float32) @
+             (qsg.astype(jnp.float32) * jnp.repeat(dg16, 16, axis=0))),
+            np.float32)
+        gotg2 = np.asarray(gguf_i8g_matmul(xg2, qsg, dg16), np.float32)
+        rel = np.abs(refg2 - gotg2).max() / (np.abs(refg2).max() + 1e-9)
+        print(f"gguf_i8g_matmul: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("gguf_i8g", rel))
+
+    with section("int8_matmul"):
+        # -- int8 dense matmul --
+        w8 = jnp.asarray(rs.randint(-128, 128, (K, N), dtype=np.int8))
+        s8 = jnp.asarray(rs.rand(N) * 0.01 + 1e-3, jnp.float32)
+        refi = np.asarray((xa.astype(jnp.float32) @ w8.astype(jnp.float32))
+                          * s8, np.float32)
+        goti = np.asarray(int8_matmul(xa, w8, s8), np.float32)
+        rel = np.abs(refi - goti).max() / (np.abs(refi).max() + 1e-9)
+        print(f"int8_matmul: rel err {rel:.2e}")
+        if rel > 3e-2:
+            failures.append(("int8", rel))
 
     if failures:
         print("FAILURES:", failures)

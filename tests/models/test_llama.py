@@ -152,7 +152,7 @@ def test_tp_sharded_forward_matches_single_device(model_and_params,
         hidden, _ = model(p, ids, pos, None, meta)
         return model.compute_logits(p, hidden)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         logits = fwd(sharded, ids, pos, meta)
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)

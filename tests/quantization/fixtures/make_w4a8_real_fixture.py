@@ -1,6 +1,6 @@
 """Generate the checked-in tiny REAL-QUANTIZED fixture
 (w4a8_real_tiny.npz) for the round-7 real-weights drift gate
-(VERDICT r5 #6, scoped to zero-egress: everything downstream of a hub
+(round-5 review item #6, scoped to zero-egress: everything downstream of a hub
 download runs for real — genuine AutoGPTQ group-quantization math over
 LLM-shaped weight matrices, not random bit packings).
 

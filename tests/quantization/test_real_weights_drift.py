@@ -1,5 +1,5 @@
 """Real-weights drift gate for the round-7 W4A8/scale-grid changes
-(VERDICT r5 #6, the standing item): the checked-in tiny REAL-QUANTIZED
+(round-5 review item #6, the standing item): the checked-in tiny REAL-QUANTIZED
 fixture (genuine AutoGPTQ group math over LLM-shaped heavy-tailed
 weights — tests/quantization/fixtures/make_w4a8_real_fixture.py) is
 pushed through every round-7 kernel variant and the drift between the
@@ -215,8 +215,9 @@ def main() -> None:
     args = ap.parse_args()
     rep = drift_report()
     rep["comment"] = (
-        "Round-7 real-weights drift gate (VERDICT r5 #6): checked-in "
-        "tiny AutoGPTQ-math fixture through the streamed folded-"
+        "Round-7 real-weights drift gate (round-5 review item #6): "
+        "checked-in tiny AutoGPTQ-math fixture through the streamed "
+        "folded-"
         "prologue W4A8 / parity-plane flush / AMLA attention paths vs "
         "the classic references; asserted by tests/quantization/"
         "test_real_weights_drift.py (slow marker). ULP = ordered-int "

@@ -65,11 +65,6 @@ SCAN_ROOTS = ("aphrodite_tpu", "bench.py", "benchmarks")
 #: place raw os.environ reads are allowed).
 FLAGS_MODULE = os.path.join("aphrodite_tpu", "common", "flags.py")
 
-#: The version-bridge module — exempt from SHARD003 (it IS the one
-#: place deprecated/moved JAX import paths are allowed, behind a
-#: current-API-first getattr probe).
-COMPAT_MODULE = os.path.join("aphrodite_tpu", "common", "compat.py")
-
 
 @dataclasses.dataclass(frozen=True)
 class Finding:

@@ -21,10 +21,9 @@ error that names neither the spec nor the layer that owns it.
   (trailing dims replicate); a longer one raises at runtime on the
   first device_put of a multi-GB cache.
 - SHARD003: any import of `jax.experimental.shard_map` — deprecated
-  since jax 0.4.35, removed upstream; the supported spelling is
-  `jax.shard_map` (VERDICT r5 item #9). The version-bridge module
-  (aphrodite_tpu/common/compat.py) is exempt: it probes the current
-  API first and is the ONE place the legacy path may live.
+  (the installed jax 0.9.0 warns on it); the supported spelling is
+  `jax.shard_map`, called at the point of use. No module is exempt:
+  the tree supports the one installed JAX and carries no bridge.
 - SHARD004: a host transfer (`.item()`, `np.asarray`/`np.array`,
   `jax.device_get`) of a MESH-SHARDED array inside an executor-scope
   (`aphrodite_tpu/executor/`) hot-path (`execute_*`/`dispatch_*`/
@@ -48,9 +47,8 @@ import ast
 import re
 from typing import List, Optional, Set, Tuple
 
-from tools.aphrocheck.core import (COMPAT_MODULE, Finding, Module,
-                                   dotted_name, iter_calls, str_const,
-                                   tail_name)
+from tools.aphrocheck.core import (Finding, Module, dotted_name,
+                                   iter_calls, str_const, tail_name)
 
 _SPEC_NAMES = ("PartitionSpec", "P")
 _MESH_NAMES = ("Mesh", "make_mesh")
@@ -274,9 +272,6 @@ def _check_rank(module: Module, findings: List[Finding]) -> None:
 
 
 def _check_imports(module: Module, findings: List[Finding]) -> None:
-    if module.rel.replace("\\", "/") == \
-            COMPAT_MODULE.replace("\\", "/"):
-        return
     for node in module.nodes:
         if isinstance(node, ast.ImportFrom):
             if (node.module or "").startswith(
@@ -286,18 +281,14 @@ def _check_imports(module: Module, findings: List[Finding]) -> None:
                 findings.append(module.finding(
                     "SHARD003", node,
                     "deprecated jax.experimental.shard_map import; "
-                    "use jax.shard_map (via "
-                    "aphrodite_tpu.common.compat.get_shard_map for "
-                    "jax<0.6 compatibility)"))
+                    "use jax.shard_map"))
         elif isinstance(node, ast.Import):
             for alias in node.names:
                 if alias.name.startswith("jax.experimental.shard_map"):
                     findings.append(module.finding(
                         "SHARD003", node,
                         "deprecated jax.experimental.shard_map "
-                        "import; use jax.shard_map (via "
-                        "aphrodite_tpu.common.compat.get_shard_map "
-                        "for jax<0.6 compatibility)"))
+                        "import; use jax.shard_map"))
 
 
 def _executor_scope(rel: str) -> bool:
@@ -384,7 +375,7 @@ RULES = (
      "operand\'s statically-known rank",
      '`device_put(jnp.zeros((4, 8)), ... P("dp", None, "tp"))`'),
     ("SHARD003", "deprecated `jax.experimental.shard_map` import "
-     "outside the compat module",
+     "(use `jax.shard_map`)",
      "`from jax.experimental.shard_map import shard_map`"),
     ("SHARD004", "host transfer (`.item()`/`np.asarray`/`device_get`) "
      "of a mesh-sharded array (KV planes, params) in an "
