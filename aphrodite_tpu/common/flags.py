@@ -272,11 +272,6 @@ _register(Flag(
     "only — CPU test runs skip persisting)."))
 
 _register(Flag(
-    "APHRODITE_BURST_TIMING", "bool", False,
-    "Print per-burst device+sync timing lines from the scheduler/"
-    "executor hot path (profiling aid)."))
-
-_register(Flag(
     "APHRODITE_DEBUG_KV", "bool", False,
     "Enable the host-side sequence-exclusive-pages precondition check "
     "for the pipelined decode KV writer (debugging aid)."))
@@ -481,11 +476,6 @@ _register(Flag(
     "submesh, decode/burst/spec-verify on the decode submesh, and "
     "finished prefills hand their KV pages off over ICI. Unset = "
     "colocated. The --disagg-split engine arg takes precedence."))
-
-_register(Flag(
-    "APHRODITE_DISAGG_TIMING", "bool", False,
-    "Print per-flush KV handoff lines (pages, bytes, transfer+sync "
-    "time) from the disagg executor hot path (profiling aid)."))
 
 _register(Flag(
     "APHRODITE_SPEC", "bool", True,

@@ -227,7 +227,9 @@ class SequenceGroup:
         self.resumed_text: str = ""
         self.prompt_logprobs: Optional[PromptLogprobs] = None
         # Latency stamps (reference RequestMetrics): written by the
-        # engine as tokens arrive, drained by _get_stats.
+        # scheduler when it first admits the group and by the engine
+        # as tokens arrive, drained by _get_stats.
+        self.first_scheduled_time: Optional[float] = None
         self.first_token_time: Optional[float] = None
         self.last_token_time: float = arrival_time
         self.finished_time: Optional[float] = None

@@ -1,0 +1,117 @@
+"""Spans of the engine round: one instrumentation, read two ways.
+
+A `Tracer` belongs to one engine, which hands it to its scheduler,
+executor and model runners. `tracer.span(name, **facts)` times a stage
+of a round. It always adds its duration to a per-stage accumulator
+(`seconds`, `counts`: plain numbers on two dicts, written by the thread
+that runs the stage, no lock), from which `engine/metrics.py` exports
+the per-stage Prometheus counters. While the profiler runs
+(`annotate(True)`, set by `AphroditeEngine.start_profile`) it also
+enters a `jax.profiler.TraceAnnotation("aph." + name, ...)`, so the
+same span lands in the profiler's `.xplane.pb` on the device trace's
+clock, under its parent on the same thread, with the round's facts.
+The profiler's trace is the span store: there is no second buffer or
+exporter.
+
+The names are a contract (PERF.md lists them with the metric each is
+for): an unknown name raises. With the profiler off a span costs two
+clock reads and two adds.
+"""
+from __future__ import annotations
+
+import functools
+import time
+from typing import Callable, Dict
+
+from jax.profiler import TraceAnnotation
+
+#: Every span, and the two events that are counted but not timed as a
+#: span (`Tracer.add`): a request's wait from arrival to the round that
+#: first schedules it, and a preemption.
+NAMES = (
+    "async.between_steps",  # engine.step returning -> the next entering
+    "engine.step",          # one AphroditeEngine.step()
+    "sched.schedule",       # deadline expiry + Scheduler.schedule()
+    "runner.prepare",       # host batch build, up to the dispatch call
+    "sampler.plan",         # Sampler.plan (inside prepare)
+    "runner.dispatch",      # the jitted call returning
+    "runner.device_wait",   # the one blocking pull of the results
+    "sampler.finalize",     # unpacking the pulled results
+    "engine.process",       # detokenise, stop checks, outputs, stats
+    "cache.kv_handoff",     # disagg: prefill pool -> decode pool
+    "queue_wait",
+    "preemptions",
+)
+
+_clock = time.perf_counter
+
+
+class Tracer:
+    """The accumulators of one engine, and whether its spans annotate
+    the profiler's trace."""
+
+    def __init__(self) -> None:
+        #: cumulative seconds and occurrences of each name
+        self.seconds: Dict[str, float] = dict.fromkeys(NAMES, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(NAMES, 0)
+        self.annotating = False
+        #: what every annotation of the current round carries: `round`,
+        #: and once the round is scheduled `path`, `rows`,
+        #: `prompt_tokens`
+        self.facts: Dict[str, object] = {}
+
+    def annotate(self, on: bool) -> None:
+        """Switch the TraceAnnotation half of the spans on or off. A
+        span that is open at the switch ends the way it began."""
+        self.annotating = on
+
+    def set_round(self, **facts) -> None:
+        """The facts of the round the step thread is in."""
+        self.facts = facts
+
+    def add(self, name: str, secs: float = 0.0) -> None:
+        """Count one occurrence of `name` that lasted `secs`."""
+        self.seconds[name] += secs
+        self.counts[name] += 1
+
+    def span(self, name: str, **facts) -> "Span":
+        return Span(self, name, facts)
+
+
+class Span:
+    """`with tracer.span("runner.prepare"): ...`; entered and left on
+    one thread. Also usable by hand (`__enter__` returns the span)
+    where the stage does not fit a block."""
+
+    __slots__ = ("tracer", "name", "facts", "t0", "annotation")
+
+    def __init__(self, tracer: Tracer, name: str, facts: dict) -> None:
+        self.tracer = tracer
+        self.name = name
+        self.facts = facts
+
+    def __enter__(self) -> "Span":
+        self.annotation = None
+        if self.tracer.annotating:
+            self.annotation = TraceAnnotation(
+                "aph." + self.name, **self.tracer.facts, **self.facts)
+            self.annotation.__enter__()
+        self.t0 = _clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.tracer.add(self.name, _clock() - self.t0)
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+
+
+def spanned(name: str) -> Callable:
+    """Decorator for a method of an object that has a `tracer`: the
+    whole call is one span."""
+    def wrap(method: Callable) -> Callable:
+        @functools.wraps(method)
+        def inner(self, *args, **kwargs):
+            with self.tracer.span(name):
+                return method(self, *args, **kwargs)
+        return inner
+    return wrap

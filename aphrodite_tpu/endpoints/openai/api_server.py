@@ -174,15 +174,18 @@ class OpenAIServer:
     # ---- simple routes ----
 
     async def start_profile(self, request: web.Request) -> web.Response:
-        """Begin a jax.profiler trace (xprof/tensorboard viewable);
-        body: {"trace_dir": "..."} (default /tmp/aphrodite-profile)."""
+        """Begin a jax.profiler trace (xprof/tensorboard viewable):
+        the device timeline and the engine's `aph.*` spans. Body:
+        {"trace_dir": "..."} (default /tmp/aphrodite-profile) and,
+        for Python frames as well, {"python_tracer": true}."""
         try:
             body = await request.json()
         except Exception:
             body = {}
         trace_dir = body.get("trace_dir", "/tmp/aphrodite-profile")
         try:
-            self.engine.engine.start_profile(trace_dir)
+            self.engine.engine.start_profile(
+                trace_dir, python_tracer=bool(body.get("python_tracer")))
         except RuntimeError as e:
             return _error(str(e))
         return web.json_response({"status": "profiling",

@@ -22,7 +22,7 @@ from typing import (Callable, Dict, Iterable, List, Optional, Tuple,
 
 import jax
 
-from aphrodite_tpu.common import faultinject, flags
+from aphrodite_tpu.common import faultinject, flags, tracing
 from aphrodite_tpu.common.config import (CacheConfig, DeviceConfig,
                                          LoRAConfig, ModelConfig,
                                          ParallelConfig, SchedulerConfig)
@@ -136,12 +136,18 @@ class AphroditeEngine:
             self._init_tokenizer()
         self.seq_counter = Counter()
 
+        # The round's spans and per-stage accumulators: one for the
+        # engine's life, shared with every executor and scheduler it
+        # builds (a rebuild keeps the counts).
+        self.tracer = tracing.Tracer()
         self.executor = TPUExecutor(model_config, cache_config,
                                     parallel_config, scheduler_config,
-                                    device_config, lora_config)
+                                    device_config, lora_config,
+                                    tracer=self.tracer)
         self.scheduler = Scheduler(scheduler_config, cache_config,
                                    lora_config,
-                                   disagg=parallel_config.disagg)
+                                   disagg=parallel_config.disagg,
+                                   tracer=self.tracer)
         # Self-drafting speculative decoding: host-side prompt-lookup
         # drafter feeding the widened verify dispatch (_spec_round).
         # Advisory per-seq acceptance state only — it survives
@@ -159,6 +165,8 @@ class AphroditeEngine:
         self._tpot_samples: List[float] = []
         self._e2e_samples: List[float] = []
         self._profiling = False
+        # Rounds begun (step() calls): the `round` fact of every span.
+        self._round = 0
         # Fault-isolation bookkeeping: (request_id, exception) pairs
         # for requests aborted by request-scoped failures or crash-
         # barrier casualties this step; the async layer drains them and
@@ -197,20 +205,27 @@ class AphroditeEngine:
     # -- profiling (reference aux tracing; TPU-native: jax.profiler
     #    traces carry XLA/TPU timelines viewable in tensorboard/xprof) --
 
-    def start_profile(self, trace_dir: str) -> None:
-        """Begin a jax.profiler trace of engine steps (device timeline +
-        host events) into `trace_dir`."""
-        import jax
+    def start_profile(self, trace_dir: str,
+                      python_tracer: bool = False) -> None:
+        """Begin a jax.profiler trace of engine steps into `trace_dir`:
+        the device timeline, and on the same clock the engine's own
+        spans (`common/tracing.py`, `aph.*`). The Python tracer, which
+        records every frame, slows the host path it times and makes
+        the trace an order of magnitude larger, is off unless asked."""
         if self._profiling:
             raise RuntimeError("profiler already running")
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         self._profiling = True
-        logger.info("Started jax.profiler trace -> %s", trace_dir)
+        self.tracer.annotate(True)
+        logger.info("Started jax.profiler trace -> %s (python tracer "
+                    "%s)", trace_dir, "on" if python_tracer else "off")
 
     def stop_profile(self) -> None:
-        import jax
         if not self._profiling:
             raise RuntimeError("profiler not running")
+        self.tracer.annotate(False)
         try:
             jax.profiler.stop_trace()
         finally:
@@ -457,49 +472,53 @@ class AphroditeEngine:
         propagates, so a retried step neither leaks KV pages nor
         double-schedules. Requests the rollback could not restore are
         recorded in `_step_faults` (drained by `drain_step_faults`)."""
-        self._step_tls.epoch = self._epoch
-        faultinject.fire("engine.step")
-        self._inflight_rounds = []
-        self._expire_deadlines()
-        seq_group_metadata_list, scheduler_outputs = \
-            self.scheduler.schedule()
-        self._inflight_rounds.append(scheduler_outputs)
-        # Continuations resolved on arrival (emitted output already at
-        # a stop): deliver their finished outputs ahead of the round.
-        # Drained only once scheduling succeeded, so a mid-schedule
-        # crash retries with them still stashed.
-        resolved: List[SequenceGroup] = []
-        if self._arrival_finished:
-            resolved, self._arrival_finished = self._arrival_finished, []
-        try:
-            outputs = self._execute_round(seq_group_metadata_list,
-                                          scheduler_outputs)
-            if resolved:
-                outputs = [RequestOutput.from_seq_group(g)
-                           for g in resolved] + outputs
-            return outputs
-        except Exception as exc:
-            # Re-stash arrival-resolved outputs so a retried step (or
-            # the reincarnation restore) still delivers them.
-            self._arrival_finished = resolved + self._arrival_finished
-            if self._step_tls.epoch != self._epoch:
-                # The engine reincarnated under this step (a watchdog-
-                # abandoned thread waking up): the rounds it holds
-                # belong to the torn-down scheduler — rolling them
-                # back against the rebuilt one would corrupt restored
-                # requests.
-                raise StaleEngineStepError(
-                    "engine step outlived a reincarnation; its "
-                    "rollback is discarded") from exc
-            for rid in self.scheduler.crash_rollback(
-                    self._inflight_rounds):
-                err: Exception = RuntimeError(
-                    f"request {rid} aborted: its KV state could not "
-                    "be rolled back after a failed engine step "
-                    f"({type(exc).__name__}: {exc})")
-                err.__cause__ = exc
-                self._step_faults.append((rid, err))
-            raise
+        self._round += 1
+        self.tracer.set_round(round=self._round)
+        with self.tracer.span("engine.step"):
+            self._step_tls.epoch = self._epoch
+            faultinject.fire("engine.step")
+            self._inflight_rounds = []
+            with self.tracer.span("sched.schedule"):
+                self._expire_deadlines()
+                seq_group_metadata_list, scheduler_outputs = \
+                    self.scheduler.schedule()
+            self._inflight_rounds.append(scheduler_outputs)
+            # Continuations resolved on arrival (emitted output already at
+            # a stop): deliver their finished outputs ahead of the round.
+            # Drained only once scheduling succeeded, so a mid-schedule
+            # crash retries with them still stashed.
+            resolved: List[SequenceGroup] = []
+            if self._arrival_finished:
+                resolved, self._arrival_finished = self._arrival_finished, []
+            try:
+                outputs = self._execute_round(seq_group_metadata_list,
+                                              scheduler_outputs)
+                if resolved:
+                    outputs = [RequestOutput.from_seq_group(g)
+                               for g in resolved] + outputs
+                return outputs
+            except Exception as exc:
+                # Re-stash arrival-resolved outputs so a retried step (or
+                # the reincarnation restore) still delivers them.
+                self._arrival_finished = resolved + self._arrival_finished
+                if self._step_tls.epoch != self._epoch:
+                    # The engine reincarnated under this step (a watchdog-
+                    # abandoned thread waking up): the rounds it holds
+                    # belong to the torn-down scheduler — rolling them
+                    # back against the rebuilt one would corrupt restored
+                    # requests.
+                    raise StaleEngineStepError(
+                        "engine step outlived a reincarnation; its "
+                        "rollback is discarded") from exc
+                for rid in self.scheduler.crash_rollback(
+                        self._inflight_rounds):
+                    err: Exception = RuntimeError(
+                        f"request {rid} aborted: its KV state could not "
+                        "be rolled back after a failed engine step "
+                        f"({type(exc).__name__}: {exc})")
+                    err.__cause__ = exc
+                    self._step_faults.append((rid, err))
+                raise
 
     # -- reincarnation (FATAL-fault recovery) --------------------------
 
@@ -551,10 +570,12 @@ class AphroditeEngine:
         self.executor = TPUExecutor(self.model_config, self.cache_config,
                                     self.parallel_config,
                                     self.scheduler_config,
-                                    self.device_config, self.lora_config)
+                                    self.device_config, self.lora_config,
+                                    tracer=self.tracer)
         self.scheduler = Scheduler(self.scheduler_config,
                                    self.cache_config, self.lora_config,
-                                   disagg=self.parallel_config.disagg)
+                                   disagg=self.parallel_config.disagg,
+                                   tracer=self.tracer)
         for group in restorable:
             if group.prefix is not None:
                 group.prefix = self.scheduler.prefix_pool.intern(
@@ -576,9 +597,19 @@ class AphroditeEngine:
         faults, self._step_faults = self._step_faults, []
         return faults
 
+    def _mark_path(self, path: str,
+                   scheduler_outputs: SchedulerOutputs) -> None:
+        """Which way this round runs, for the spans still to come."""
+        self.tracer.set_round(
+            round=self._round, path=path,
+            rows=(len(scheduler_outputs.prompt_chunks) +
+                  len(scheduler_outputs.decode_groups)),
+            prompt_tokens=scheduler_outputs.num_prefill_tokens)
+
     def _execute_round(self, seq_group_metadata_list,
                        scheduler_outputs) -> List[RequestOutput]:
         if scheduler_outputs.is_empty():
+            self._mark_path("empty", scheduler_outputs)
             return self._process_round(None, [], scheduler_outputs)
 
         n_chunks = len(scheduler_outputs.prompt_chunks)
@@ -601,6 +632,7 @@ class AphroditeEngine:
                             if decode_mds else (1, None))
 
         if prompt_mds and decode_mds:
+            self._mark_path("combined", scheduler_outputs)
             prompt_output, decode_outputs = \
                 self.executor.execute_combined(
                     prompt_mds, decode_mds,
@@ -613,6 +645,7 @@ class AphroditeEngine:
                                        scheduler_outputs)
 
         if decode_mds and burst > 1:
+            self._mark_path("burst", scheduler_outputs)
             outputs_list = self.executor.execute_decode_burst(
                 decode_mds,
                 scheduler_outputs.blocks_to_swap_in,
@@ -622,6 +655,8 @@ class AphroditeEngine:
             return self._process_round(None, outputs_list,
                                        scheduler_outputs)
 
+        self._mark_path("prompt" if prompt_mds else "decode",
+                        scheduler_outputs)
         if prompt_mds and not scheduler_outputs.blocks_to_swap_in \
                 and not scheduler_outputs.blocks_to_swap_out \
                 and self._prompt_fast_path_ok(prompt_mds):
@@ -699,7 +734,8 @@ class AphroditeEngine:
         handles = [handle]
         all_prompt_mds = list(prompt_mds)
         while len(handles) < 4:
-            nxt = self.scheduler.schedule_prompt_only()
+            with self.tracer.span("sched.schedule"):
+                nxt = self.scheduler.schedule_prompt_only()
             if nxt is None:
                 break
             mds2, outputs2 = nxt
@@ -811,9 +847,10 @@ class AphroditeEngine:
         # sequences' block tables and satisfy the next round's
         # reservation.
         self._check_epoch()
-        granted = self.scheduler.reserve_decode_burst(
-            seq_group_metadata_list, want - 1, extra_cap,
-            groups=scheduler_outputs.decode_groups)
+        with self.tracer.span("sched.schedule"):
+            granted = self.scheduler.reserve_decode_burst(
+                seq_group_metadata_list, want - 1, extra_cap,
+                groups=scheduler_outputs.decode_groups)
         return 1 << ((1 + granted).bit_length() - 1), extra_cap
 
     # -- speculative decoding (self-drafting verify rounds) --
@@ -883,14 +920,16 @@ class AphroditeEngine:
         # admission low-watermark reserve; it shrinks the grant, never
         # evicts). A zero grant under pressure degrades to classic.
         self._check_epoch()
-        granted = self.scheduler.reserve_decode_burst(
-            decode_mds, want, extra_cap,
-            groups=scheduler_outputs.decode_groups)
+        with self.tracer.span("sched.schedule"):
+            granted = self.scheduler.reserve_decode_burst(
+                decode_mds, want, extra_cap,
+                groups=scheduler_outputs.decode_groups)
         if granted < want:
             drafts = {sid: d[:granted] for sid, d in drafts.items()}
         if not any(drafts.values()):
             return None
 
+        self._mark_path("spec", scheduler_outputs)
         results = self.executor.execute_spec_verify(
             decode_mds, drafts,
             scheduler_outputs.blocks_to_swap_in,
@@ -898,6 +937,7 @@ class AphroditeEngine:
             scheduler_outputs.blocks_to_copy)
         return self._process_spec_round(results, scheduler_outputs)
 
+    @tracing.spanned("engine.process")
     def _process_spec_round(
             self, results,
             scheduler_outputs: SchedulerOutputs) -> List[RequestOutput]:
@@ -949,6 +989,7 @@ class AphroditeEngine:
 
     # -- output processing (reference :550-752) --
 
+    @tracing.spanned("engine.process")
     def _process_round(
             self, prompt_output: Optional[SamplerOutput],
             decode_outputs_list: List[SamplerOutput],
@@ -1339,4 +1380,6 @@ class AphroditeEngine:
             sheds_total=self.admission.sheds_total,
             expired_total=self.admission.expired_total,
             ewma_prefill_tok_s=self.admission.ewma_prefill_tok_s,
-            ewma_decode_tok_s=self.admission.ewma_decode_tok_s)
+            ewma_decode_tok_s=self.admission.ewma_decode_tok_s,
+            stage_seconds=self.tracer.seconds,
+            stage_counts=self.tracer.counts)

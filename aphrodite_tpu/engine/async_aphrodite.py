@@ -52,7 +52,7 @@ import time
 from typing import (AsyncIterator, Callable, Dict, Iterable, List,
                     Optional, Set, Tuple, Type, Union)
 
-from aphrodite_tpu.common import flags
+from aphrodite_tpu.common import flags, tracing
 from aphrodite_tpu.common.config import ModelConfig
 from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.outputs import RequestOutput
@@ -324,6 +324,9 @@ class AsyncAphrodite:
         # loop; set on death so drain waiters wake.
         self._idle_event: asyncio.Event = asyncio.Event()
         self._idle_event.set()
+        # The open `async.between_steps` span, if the loop is between
+        # two steps (see _between_steps).
+        self._between: Optional[tracing.Span] = None
         # Lifecycle gauges (state code, reincarnation counters, drain
         # remaining) ride the engine's per-round Stats into Prometheus.
         self.engine.lifecycle_source = self._lifecycle_stats
@@ -379,18 +382,34 @@ class AsyncAphrodite:
         uninterruptible from Python), so timeout is terminal — the
         point is detection instead of a forever-'healthy' hang."""
         loop = asyncio.get_running_loop()
+        self._between_steps(False)
         fut = loop.run_in_executor(None, self.engine.step)
-        timeout = flags.get_float("APHRODITE_STEP_TIMEOUT_S")
-        if not timeout or timeout <= 0:
-            return await fut
-        done, _ = await asyncio.wait({fut}, timeout=timeout)
-        if done:
-            return fut.result()
+        try:
+            timeout = flags.get_float("APHRODITE_STEP_TIMEOUT_S")
+            if not timeout or timeout <= 0:
+                return await fut
+            done, _ = await asyncio.wait({fut}, timeout=timeout)
+            if done:
+                return fut.result()
+        finally:
+            self._between_steps(True)
         fut.add_done_callback(_consume_abandoned_step)
         raise StepTimeoutError(
             f"engine step exceeded APHRODITE_STEP_TIMEOUT_S="
             f"{timeout:g}s; the step thread is wedged (likely a hung "
             "compile or device call)")
+
+    def _between_steps(self, begin: bool) -> None:
+        """The `async.between_steps` span: the loop's own work from one
+        `engine.step` returning to the next entering (stream delivery,
+        request intake and `add_request`, the hop to the step thread).
+        `begin` false closes the open span, true also opens the next;
+        the loop closes it before it idles, so no wait for a request
+        is counted."""
+        if self._between is not None:
+            self._between.__exit__(None, None, None)
+        self._between = self.engine.tracer.span(
+            "async.between_steps").__enter__() if begin else None
 
     def _propagate_step_faults(self) -> None:
         """Deliver request-scoped step failures to exactly the culprit
@@ -549,7 +568,9 @@ class AsyncAphrodite:
         has_requests_in_progress = False
         while True:
             if not has_requests_in_progress:
+                self._between_steps(False)
                 await self._request_tracker.wait_for_new_requests()
+                self._between_steps(True)
             try:
                 has_requests_in_progress = await self.engine_step()
             except AsyncEngineDeadError:

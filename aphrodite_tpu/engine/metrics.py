@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 from prometheus_client import Counter, Gauge, Histogram, REGISTRY
 
@@ -31,6 +31,50 @@ def _get_or_create(cls, name, documentation, labelnames=(), **kw):
         return cls(name, documentation, labelnames=labelnames, **kw)
     except ValueError:
         return REGISTRY._names_to_collectors[name]
+
+
+#: (metric, help, cumulative total from the span accumulators
+#: `seconds` and `counts` of common/tracing.py). One metric name per
+#: quantity and no label that carries meaning: readers sum over label
+#: sets.
+_STAGE_COUNTERS = [
+    ("aphrodite:engine_rounds_total",
+     "Engine rounds (AphroditeEngine.step calls).",
+     lambda s, c: c["engine.step"]),
+    ("aphrodite:engine_step_seconds_total",
+     "Seconds inside AphroditeEngine.step.",
+     lambda s, c: s["engine.step"]),
+    ("aphrodite:host_syncs_total",
+     "Blocking pulls of step results from the device.",
+     lambda s, c: c["runner.device_wait"]),
+    ("aphrodite:host_schedule_seconds_total",
+     "Seconds scheduling rounds (deadline expiry, scheduler, block "
+     "manager).", lambda s, c: s["sched.schedule"]),
+    ("aphrodite:host_prepare_seconds_total",
+     "Seconds building a step's host batch, sampling plan included, "
+     "up to the dispatch.", lambda s, c: s["runner.prepare"]),
+    ("aphrodite:device_wait_seconds_total",
+     "Seconds from a step's dispatch entered to its result on the "
+     "host.",
+     lambda s, c: s["runner.dispatch"] + s["runner.device_wait"]),
+    ("aphrodite:host_process_seconds_total",
+     "Seconds unpacking sampled results and processing outputs "
+     "(detokenise, stop checks, stats).",
+     lambda s, c: s["sampler.finalize"] + s["engine.process"]),
+    ("aphrodite:host_between_steps_seconds_total",
+     "Seconds of the async loop between one engine step returning "
+     "and the next entering, idle waits left out.",
+     lambda s, c: s["async.between_steps"]),
+    ("aphrodite:queue_wait_seconds_total",
+     "Seconds requests waited from arrival to the round that first "
+     "scheduled them.", lambda s, c: s["queue_wait"]),
+    ("aphrodite:requests_first_scheduled_total",
+     "Requests scheduled for the first time.",
+     lambda s, c: c["queue_wait"]),
+    ("aphrodite:preemptions_total",
+     "Preemptions of running requests (recompute and swap).",
+     lambda s, c: c["preemptions"]),
+]
 
 
 class Metrics:
@@ -125,6 +169,11 @@ class Metrics:
             Counter, "aphrodite:requests_lost_on_rebuild_total",
             "Requests an engine rebuild could not restore (typed "
             "errors delivered to their streams).", labelnames)
+        # Per-stage counters of the engine round, from the span
+        # accumulators of common/tracing.py (see _STAGE_COUNTERS).
+        self.stage_counters = [
+            (_get_or_create(Counter, name, doc, labelnames), total)
+            for name, doc, total in _STAGE_COUNTERS]
 
 
 @dataclass
@@ -157,6 +206,11 @@ class Stats:
     reincarnations_total: int = 0
     restored_total: int = 0
     lost_total: int = 0
+    # The span accumulators of the engine's common/tracing.py Tracer
+    # (cumulative, live references; exported as deltas through
+    # _STAGE_COUNTERS).
+    stage_seconds: Optional[Dict[str, float]] = None
+    stage_counts: Optional[Dict[str, int]] = None
 
 
 class StatLogger:
@@ -169,13 +223,14 @@ class StatLogger:
         self.labels = labels or {}
         self.num_prompt_tokens: List[int] = []
         self.num_generation_tokens: List[int] = []
-        # Cumulative counts already exported, for counter deltas.
-        self._sheds_exported = 0
-        self._expired_exported = 0
-        self._reinc_exported = 0
-        self._restored_exported = 0
-        self._lost_exported = 0
+        # Cumulative totals already exported, by counter, for deltas.
+        self._exported: Dict[object, float] = {}
         self.metrics = Metrics(labelnames=list(self.labels.keys()))
+        for counter, _ in self.metrics.stage_counters:
+            # A labelled counter has no sample until it is first
+            # touched: one that stays at 0 (preemptions) must read 0.
+            (counter.labels(**self.labels) if self.labels
+             else counter).inc(0)
 
     def _throughput(self, tracked: List[int], now: float) -> float:
         elapsed = now - self.last_local_log
@@ -185,6 +240,15 @@ class StatLogger:
         m = self.metrics
         labeled = (lambda metric: metric.labels(**self.labels)) \
             if self.labels else (lambda metric: metric)
+
+        def export(counter, total) -> None:
+            """Raise `counter` to the cumulative `total` (a total that
+            fell, after a rebuild, exports nothing until it passes the
+            old one)."""
+            done = self._exported.get(counter, 0)
+            if total > done:
+                labeled(counter).inc(total - done)
+                self._exported[counter] = total
         labeled(m.gauge_scheduler_running).set(stats.num_running)
         labeled(m.gauge_scheduler_swapped).set(stats.num_swapped)
         labeled(m.gauge_scheduler_waiting).set(stats.num_waiting)
@@ -198,29 +262,18 @@ class StatLogger:
         labeled(m.gauge_prefix_pinned).set(stats.prefix_pinned_pages)
         labeled(m.gauge_ewma_prefill).set(stats.ewma_prefill_tok_s)
         labeled(m.gauge_ewma_decode).set(stats.ewma_decode_tok_s)
-        labeled(m.counter_requests_shed).inc(
-            max(0, stats.sheds_total - self._sheds_exported))
-        self._sheds_exported = max(self._sheds_exported,
-                                   stats.sheds_total)
-        labeled(m.counter_requests_expired).inc(
-            max(0, stats.expired_total - self._expired_exported))
-        self._expired_exported = max(self._expired_exported,
-                                     stats.expired_total)
+        export(m.counter_requests_shed, stats.sheds_total)
+        export(m.counter_requests_expired, stats.expired_total)
         labeled(m.gauge_engine_state).set(stats.state_code)
         labeled(m.gauge_inflight).set(stats.inflight)
         labeled(m.gauge_drain_remaining).set(stats.drain_remaining_s)
-        labeled(m.counter_reincarnations).inc(
-            max(0, stats.reincarnations_total - self._reinc_exported))
-        self._reinc_exported = max(self._reinc_exported,
-                                   stats.reincarnations_total)
-        labeled(m.counter_requests_restored).inc(
-            max(0, stats.restored_total - self._restored_exported))
-        self._restored_exported = max(self._restored_exported,
-                                      stats.restored_total)
-        labeled(m.counter_requests_lost).inc(
-            max(0, stats.lost_total - self._lost_exported))
-        self._lost_exported = max(self._lost_exported,
-                                  stats.lost_total)
+        export(m.counter_reincarnations, stats.reincarnations_total)
+        export(m.counter_requests_restored, stats.restored_total)
+        export(m.counter_requests_lost, stats.lost_total)
+        if stats.stage_seconds is not None:
+            for counter, total in m.stage_counters:
+                export(counter, total(stats.stage_seconds,
+                                      stats.stage_counts))
         for t in stats.time_to_first_tokens:
             labeled(m.histogram_time_to_first_token).observe(t)
         for t in stats.time_per_output_tokens:

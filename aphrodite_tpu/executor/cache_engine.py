@@ -128,13 +128,10 @@ class CacheEngine:
         if self.prefill_mesh is not None:
             self.prefill_kv_caches = self._allocate_prefill_pool()
         # Handoff accounting (read by benchmarks / DISAGG capture):
-        # totals survive for the engine lifetime, last_* cover the most
-        # recent flush.
+        # totals survive for the engine lifetime.
         self.handoff_pages_total = 0
         self.handoff_bytes_total = 0
         self.handoff_flushes = 0
-        self.last_handoff_pages = 0
-        self.last_handoff_bytes = 0
         # Host swap pool: per layer [2, pages, page, heads_i*dim] numpy
         # — token-major like the device pages, indexed by page on axis 1
         # (list because DeciLM-style models vary heads per layer).
@@ -239,8 +236,6 @@ class CacheEngine:
         self.handoff_pages_total += n
         self.handoff_bytes_total += moved
         self.handoff_flushes += 1
-        self.last_handoff_pages = n
-        self.last_handoff_bytes = moved
         return moved
 
     def kv_shardings(self) -> Optional[List[NamedSharding]]:

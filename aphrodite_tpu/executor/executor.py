@@ -18,7 +18,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 import jax
 
-from aphrodite_tpu.common import faultinject, flags
+from aphrodite_tpu.common import faultinject, tracing
 from aphrodite_tpu.common.config import (CacheConfig, DeviceConfig,
                                          ModelConfig, ParallelConfig,
                                          SchedulerConfig)
@@ -99,7 +99,10 @@ class TPUExecutor:
         scheduler_config: SchedulerConfig,
         device_config: DeviceConfig,
         lora_config=None,
+        tracer: Optional[tracing.Tracer] = None,
     ) -> None:
+        # The engine's span accumulators (its own, when built alone).
+        self.tracer = tracer or tracing.Tracer()
         self.model_config = model_config
         self.cache_config = cache_config
         self.parallel_config = parallel_config
@@ -161,7 +164,8 @@ class TPUExecutor:
             mesh=self.mesh,
             kv_scale=self.cache_engine.kv_scale,
             sp=sp,
-            kv_cache_dtype=self.cache_engine.dtype)
+            kv_cache_dtype=self.cache_engine.dtype,
+            tracer=self.tracer)
         self.prefill_runner = self.model_runner
         if self.prefill_mesh is not None:
             self.prefill_runner = ModelRunner(
@@ -172,7 +176,8 @@ class TPUExecutor:
                 mesh=self.prefill_mesh,
                 kv_scale=self.cache_engine.kv_scale,
                 sp=None,
-                kv_cache_dtype=self.cache_engine.dtype)
+                kv_cache_dtype=self.cache_engine.dtype,
+                tracer=self.tracer)
 
         self.lora_manager = None
         if lora_config is not None:
@@ -239,22 +244,8 @@ class TPUExecutor:
         race)."""
         if not self.disagg or not pages:
             return 0
-        timing = flags.get_bool("APHRODITE_DISAGG_TIMING")
-        t0 = 0.0
-        if timing:
-            import time
-            t0 = time.perf_counter()
-        moved = self.cache_engine.kv_handoff(pages)
-        if timing:
-            jax.block_until_ready(
-                [plane for kv in self.cache_engine.kv_caches
-                 for plane in kv])
-            dt = (time.perf_counter() - t0) * 1e3
-            print(f"[kv-handoff pages="
-                  f"{self.cache_engine.last_handoff_pages} "
-                  f"bytes={moved}] transfer+sync {dt:.2f} ms",
-                  flush=True)
-        return moved
+        with self.tracer.span("cache.kv_handoff", pages=len(pages)):
+            return self.cache_engine.kv_handoff(pages)
 
     # -- sizing --
 
@@ -453,11 +444,13 @@ class TPUExecutor:
 
     def finalize_prompt_rounds(self, handles):
         """One transfer for every pending round's packed results."""
-        pulled = jax.device_get([h.packed for h in handles])
-        return [
-            self.prefill_runner.finalize_step(h, np.asarray(p))
-            for h, p in zip(handles, pulled)
-        ]
+        with self.tracer.span("runner.device_wait"):
+            pulled = jax.device_get([h.packed for h in handles])
+        with self.tracer.span("sampler.finalize"):
+            return [
+                self.prefill_runner.finalize_step(h, np.asarray(p))
+                for h, p in zip(handles, pulled)
+            ]
 
     def execute_combined(
         self,
@@ -491,24 +484,11 @@ class TPUExecutor:
             handle, kv = self.model_runner.dispatch_prompt(
                 prompt_metadata, kv)
         if handle is not None:
-            import time
-            timing = flags.get_bool("APHRODITE_BURST_TIMING")
-            t0 = time.perf_counter() if timing else 0.0
             bhandle, kv = self.model_runner.dispatch_burst(
                 decode_metadata, kv, num_steps, extra_cap)
             self.cache_engine.kv_caches = kv
-            p_np, b_np = jax.device_get((handle.packed, bhandle.packed))
-            t1 = time.perf_counter() if timing else 0.0
-            prompt_out = self.model_runner.finalize_step(
-                handle, np.asarray(p_np))
-            decode_outs = self.model_runner.finalize_burst(
-                bhandle, np.asarray(b_np))
-            if timing:
-                print(f"[combined prompts={len(prompt_metadata)} "
-                      f"burst={num_steps}x{len(decode_metadata)}] "
-                      f"device+sync {(t1 - t0) * 1e3:.0f} ms",
-                      flush=True)
-            return prompt_out, decode_outs
+            return self._finalize_combined(self.model_runner, handle,
+                                           bhandle)
 
         # Sequential fallback (two syncs): raw-logits prompt sampling
         # and/or a burst-ineligible decode batch.
@@ -522,6 +502,19 @@ class TPUExecutor:
             decode_outs = [out]
         self.cache_engine.kv_caches = kv
         return prompt_out, decode_outs
+
+    def _finalize_combined(
+            self, prompt_runner: ModelRunner, handle, bhandle,
+    ) -> Tuple[SamplerOutput, List[SamplerOutput]]:
+        """The one host sync of a fused combined round: pull the prompt
+        step's and the burst's packed results together, then unpack
+        each (the prompt's by the runner that dispatched it)."""
+        with self.tracer.span("runner.device_wait"):
+            p_np, b_np = jax.device_get((handle.packed, bhandle.packed))
+        with self.tracer.span("sampler.finalize"):
+            return (prompt_runner.finalize_step(handle, np.asarray(p_np)),
+                    self.model_runner.finalize_burst(bhandle,
+                                                     np.asarray(b_np)))
 
     def _execute_combined_disagg(
         self,
@@ -550,25 +543,12 @@ class TPUExecutor:
             handle, pkv = self.prefill_runner.dispatch_prompt(
                 prompt_metadata, pkv)
         if handle is not None:
-            import time
-            timing = flags.get_bool("APHRODITE_BURST_TIMING")
-            t0 = time.perf_counter() if timing else 0.0
             bhandle, dkv = self.model_runner.dispatch_burst(
                 decode_metadata, dkv, num_steps, extra_cap)
             self.cache_engine.prefill_kv_caches = pkv
             self.cache_engine.kv_caches = dkv
-            p_np, b_np = jax.device_get((handle.packed, bhandle.packed))
-            t1 = time.perf_counter() if timing else 0.0
-            prompt_out = self.prefill_runner.finalize_step(
-                handle, np.asarray(p_np))
-            decode_outs = self.model_runner.finalize_burst(
-                bhandle, np.asarray(b_np))
-            if timing:
-                print(f"[disagg-combined prompts={len(prompt_metadata)} "
-                      f"burst={num_steps}x{len(decode_metadata)}] "
-                      f"overlapped device+sync {(t1 - t0) * 1e3:.0f} ms",
-                      flush=True)
-            return prompt_out, decode_outs
+            return self._finalize_combined(self.prefill_runner, handle,
+                                           bhandle)
 
         # Sequential fallback — still pool-separated, two syncs.
         prompt_out, pkv = self.prefill_runner.execute_model(

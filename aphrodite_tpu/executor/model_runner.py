@@ -26,7 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from aphrodite_tpu.common import flags
+from aphrodite_tpu.common import flags, tracing
 from aphrodite_tpu.common.config import (ModelConfig, ParallelConfig,
                                          SchedulerConfig)
 from aphrodite_tpu.common.logger import init_logger
@@ -118,7 +118,10 @@ class ModelRunner:
         kv_scale: float = 1.0,
         sp: Optional[tuple] = None,         # (Mesh, threshold) or None
         kv_cache_dtype=jnp.bfloat16,
+        tracer: Optional[tracing.Tracer] = None,
     ) -> None:
+        # The engine's span accumulators (its own, when built alone).
+        self.tracer = tracer or tracing.Tracer()
         self.model = model
         self.params = params
         self.model_config = model_config
@@ -693,33 +696,32 @@ class ModelRunner:
         kv_caches: List[Tuple[jax.Array, jax.Array]],
         blocks_to_copy: Optional[Dict[int, List[int]]] = None,
     ) -> Tuple[SamplerOutput, List[Tuple[jax.Array, jax.Array]]]:
-        import time as _time
-        timing = flags.get_bool("APHRODITE_BURST_TIMING")
-        t0 = _time.perf_counter() if timing else 0.0
-        kv_caches = self._apply_block_copies(kv_caches, blocks_to_copy)
+        with self.tracer.span("runner.prepare"):
+            kv_caches = self._apply_block_copies(kv_caches,
+                                                 blocks_to_copy)
+            if not seq_group_metadata_list:
+                return [], kv_caches
 
-        if not seq_group_metadata_list:
-            return [], kv_caches
+            is_prompt = seq_group_metadata_list[0].is_prompt
+            if is_prompt:
+                inputs, sampling = self._prepare_prompt(
+                    seq_group_metadata_list)
+                rows_per_group = [1] * len(seq_group_metadata_list)
+            else:
+                inputs, sampling = self._prepare_decode(
+                    seq_group_metadata_list)
+                rows_per_group = [
+                    len(md.seq_data) for md in seq_group_metadata_list
+                ]
 
-        is_prompt = seq_group_metadata_list[0].is_prompt
-        if is_prompt:
-            inputs, sampling = self._prepare_prompt(seq_group_metadata_list)
-            rows_per_group = [1] * len(seq_group_metadata_list)
-        else:
-            inputs, sampling = self._prepare_decode(seq_group_metadata_list)
-            rows_per_group = [
-                len(md.seq_data) for md in seq_group_metadata_list
-            ]
+            params = self._params_with_lora(
+                seq_group_metadata_list, inputs["input_ids"].shape[0],
+                rows_per_group)
 
-        params = self._params_with_lora(
-            seq_group_metadata_list, inputs["input_ids"].shape[0],
-            rows_per_group)
-        t1 = _time.perf_counter() if timing else 0.0
-
-        has_processors = any(
-            p.logits_processors for _, p in sampling.seq_groups)
-        plan = None if has_processors else \
-            self.sampler.plan(sampling, pad_to=inputs["sel"].shape[0])
+            has_processors = any(
+                p.logits_processors for _, p in sampling.seq_groups)
+            plan = None if has_processors else \
+                self._plan(sampling, inputs["sel"].shape[0])
 
         # The fused program's sampler statics stay PINNED at the
         # serving default (best_of=1, no top-k logprobs): a varying
@@ -731,7 +733,7 @@ class ModelRunner:
             # Raw-logits routes: host logits processors need the
             # logits mid-pipeline; logprob requests need the full
             # log-softmax rows. Two device programs.
-            with self._mesh_ctx():
+            with self.tracer.span("runner.dispatch"), self._mesh_ctx():
                 logits, kv_caches = self._step_fn(
                     params, inputs["input_ids"], inputs["positions"],
                     kv_caches, inputs["metadata"], inputs["sel"],
@@ -739,10 +741,14 @@ class ModelRunner:
                     use_prefix=inputs["use_prefix"])
             self._mark_prefixes(inputs)
             if has_processors:
-                output = self.sampler(logits[:inputs["num_rows"]],
-                                      sampling)
+                # The host processors pull the logits: the wait, the
+                # sampler's own small program and its unpacking are
+                # one blocking call.
+                with self.tracer.span("runner.device_wait"):
+                    output = self.sampler(logits[:inputs["num_rows"]],
+                                          sampling)
                 return output, kv_caches
-            with self._mesh_ctx():
+            with self.tracer.span("runner.dispatch"), self._mesh_ctx():
                 packed, logprobs_dev = _fused_sample_jit(
                     logits, self._dev_tree(plan.tensors),
                     self._dev(np.asarray(plan.bases)),
@@ -751,22 +757,16 @@ class ModelRunner:
                     max_best_of=plan.max_best_of,
                     num_topk=plan.num_topk,
                     need_logprobs=plan.need_logprobs)
-            packed_np = np.asarray(packed)
-            t4 = _time.perf_counter() if timing else 0.0
-            output = self.sampler.finalize(sampling, plan, packed_np,
-                                           logprobs_dev)
-            if timing:
-                print(f"[step split prompt={is_prompt} "
-                      f"rows={inputs['sel'].shape[0]}] prep "
-                      f"{(t1 - t0) * 1e3:.0f} ms, dispatch+sync "
-                      f"{(t4 - t1) * 1e3:.0f} ms, finalize "
-                      f"{(_time.perf_counter() - t4) * 1e3:.0f} ms",
-                      flush=True)
+            with self.tracer.span("runner.device_wait"):
+                packed_np = np.asarray(packed)
+            with self.tracer.span("sampler.finalize"):
+                output = self.sampler.finalize(sampling, plan, packed_np,
+                                               logprobs_dev)
             return output, kv_caches
 
         # Fast path: model + fused sampler as ONE device program; the
         # only blocking transfer per round is the packed result pull.
-        with self._mesh_ctx():
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
@@ -778,19 +778,16 @@ class ModelRunner:
                 use_prefix=inputs["use_prefix"],
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
         self._mark_prefixes(inputs)
-        t2 = _time.perf_counter() if timing else 0.0
-        packed_np = np.asarray(packed)                     # ONE sync
-        t4 = _time.perf_counter() if timing else 0.0
-        output = self.sampler.finalize(sampling, plan, packed_np, None)
-        if timing:
-            t5 = _time.perf_counter()
-            print(f"[step prompt={is_prompt} "
-                  f"rows={inputs['sel'].shape[0]}] "
-                  f"prep {(t1 - t0) * 1e3:.0f} ms, dispatch "
-                  f"{(t2 - t1) * 1e3:.0f} ms, sync "
-                  f"{(t4 - t2) * 1e3:.0f} ms, finalize "
-                  f"{(t5 - t4) * 1e3:.0f} ms", flush=True)
+        with self.tracer.span("runner.device_wait"):
+            packed_np = np.asarray(packed)                 # ONE sync
+        with self.tracer.span("sampler.finalize"):
+            output = self.sampler.finalize(sampling, plan, packed_np,
+                                           None)
         return output, kv_caches
+
+    def _plan(self, sampling: SamplingMetadata, pad_to: int):
+        with self.tracer.span("sampler.plan"):
+            return self.sampler.plan(sampling, pad_to=pad_to)
 
     def dispatch_prompt(
         self,
@@ -801,17 +798,19 @@ class ModelRunner:
         only). Returns (None, kv_caches untouched) when the batch needs
         the raw-logits route (host logits processors, logprobs,
         best_of>1) — the caller falls back to synced steps."""
-        inputs, sampling = self._prepare_prompt(seq_group_metadata_list)
-        if any(p.logits_processors for _, p in sampling.seq_groups):
-            return None, kv_caches
-        plan = self.sampler.plan(sampling, pad_to=inputs["sel"].shape[0])
-        if plan.need_logprobs or plan.max_best_of != 1 or \
-                plan.num_topk != 0:
-            return None, kv_caches
-        params = self._params_with_lora(
-            seq_group_metadata_list, inputs["input_ids"].shape[0],
-            [1] * len(seq_group_metadata_list))
-        with self._mesh_ctx():
+        with self.tracer.span("runner.prepare"):
+            inputs, sampling = self._prepare_prompt(
+                seq_group_metadata_list)
+            if any(p.logits_processors for _, p in sampling.seq_groups):
+                return None, kv_caches
+            plan = self._plan(sampling, inputs["sel"].shape[0])
+            if plan.need_logprobs or plan.max_best_of != 1 or \
+                    plan.num_topk != 0:
+                return None, kv_caches
+            params = self._params_with_lora(
+                seq_group_metadata_list, inputs["input_ids"].shape[0],
+                [1] * len(seq_group_metadata_list))
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
@@ -853,18 +852,10 @@ class ModelRunner:
         kv_caches = self._apply_block_copies(kv_caches, blocks_to_copy)
         handle, kv_caches = self.dispatch_burst(
             seq_group_metadata_list, kv_caches, num_steps, extra_cap)
-        import time as _time
-        timing = flags.get_bool("APHRODITE_BURST_TIMING")
-        t1 = _time.perf_counter() if timing else 0.0
-        all_packed = np.asarray(handle.packed)             # ONE sync
-        t2 = _time.perf_counter() if timing else 0.0
-        outputs = self.finalize_burst(handle, all_packed)
-        if timing:
-            t3 = _time.perf_counter()
-            print(f"[burst {num_steps} steps] device+sync "
-                  f"{(t2 - t1) * 1e3:.0f} ms "
-                  f"({(t2 - t1) / num_steps * 1e3:.1f}/step), finalize "
-                  f"{(t3 - t2) * 1e3:.0f} ms", flush=True)
+        with self.tracer.span("runner.device_wait"):
+            all_packed = np.asarray(handle.packed)         # ONE sync
+        with self.tracer.span("sampler.finalize"):
+            outputs = self.finalize_burst(handle, all_packed)
         return outputs, kv_caches
 
     def dispatch_burst(
@@ -875,43 +866,44 @@ class ModelRunner:
         extra_cap: Optional[Dict[int, int]] = None,
     ) -> Tuple[StepHandle, List[Tuple[jax.Array, jax.Array]]]:
         """Enqueue the K-step decode burst without syncing."""
-        inputs, sampling = self._prepare_decode(seq_group_metadata_list)
-        padded = inputs["input_ids"].shape[0]
-        rows_per_group = [
-            len(md.seq_data) for md in seq_group_metadata_list
-        ]
-        params = self._params_with_lora(seq_group_metadata_list, padded,
-                                        rows_per_group)
-        plan = self.sampler.plan(sampling, pad_to=padded)
+        with self.tracer.span("runner.prepare"):
+            inputs, sampling = self._prepare_decode(seq_group_metadata_list)
+            padded = inputs["input_ids"].shape[0]
+            rows_per_group = [
+                len(md.seq_data) for md in seq_group_metadata_list
+            ]
+            params = self._params_with_lora(seq_group_metadata_list, padded,
+                                            rows_per_group)
+            plan = self._plan(sampling, padded)
 
-        greedy = np.zeros((padded,), dtype=bool)
-        # Per-row last reserved position: pos + the engine's per-seq
-        # useful-step cap (tokens remaining / model-len room — ONE
-        # source of truth, computed in AphroditeEngine._burst_steps and
-        # used for the page reservation), clamped to the burst length.
-        # Overshot rows pin here instead of walking the block table
-        # past their reservation (advisor r3); pad rows pin at their
-        # pad slot.
-        pos_cap = np.zeros((padded, 1), dtype=np.int32)
-        cap_of = extra_cap or {}
-        row = 0
-        for md in seq_group_metadata_list:
-            n = len(md.seq_data)
-            if md.sampling_params.sampling_type == SamplingType.GREEDY:
-                greedy[row:row + n] = True
-            for seq_id, data in md.seq_data.items():
-                r = min(cap_of.get(seq_id, num_steps), num_steps)
-                pos_cap[row, 0] = data.get_len() - 1 + r
-                row += 1
-        greedy_mask = self._dev(greedy)
-        tensors = self._dev_tree(plan.tensors)
-        bases = self._dev(np.asarray(plan.bases))
-        salt1 = self._dev(np.asarray(plan.salt1))
-        salt2 = self._dev(np.asarray(plan.salt2))
+            greedy = np.zeros((padded,), dtype=bool)
+            # Per-row last reserved position: pos + the engine's per-seq
+            # useful-step cap (tokens remaining / model-len room — ONE
+            # source of truth, computed in AphroditeEngine._burst_steps and
+            # used for the page reservation), clamped to the burst length.
+            # Overshot rows pin here instead of walking the block table
+            # past their reservation (advisor r3); pad rows pin at their
+            # pad slot.
+            pos_cap = np.zeros((padded, 1), dtype=np.int32)
+            cap_of = extra_cap or {}
+            row = 0
+            for md in seq_group_metadata_list:
+                n = len(md.seq_data)
+                if md.sampling_params.sampling_type == SamplingType.GREEDY:
+                    greedy[row:row + n] = True
+                for seq_id, data in md.seq_data.items():
+                    r = min(cap_of.get(seq_id, num_steps), num_steps)
+                    pos_cap[row, 0] = data.get_len() - 1 + r
+                    row += 1
+            greedy_mask = self._dev(greedy)
+            tensors = self._dev_tree(plan.tensors)
+            bases = self._dev(np.asarray(plan.bases))
+            salt1 = self._dev(np.asarray(plan.salt1))
+            salt2 = self._dev(np.asarray(plan.salt2))
 
-        ids, pos, meta = (inputs["input_ids"], inputs["positions"],
-                          inputs["metadata"])
-        with self._mesh_ctx():
+            ids, pos, meta = (inputs["input_ids"], inputs["positions"],
+                              inputs["metadata"])
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._burst_scan_fn(
                 params, ids, pos, kv_caches, meta, tensors, bases,
                 salt1, salt2, greedy_mask, self._dev(pos_cap),
@@ -1054,25 +1046,28 @@ class ModelRunner:
         seeded streams are bit-equal to `APHRODITE_SPEC=0`. Eligibility
         (single-seq groups, fused-sampler statics pinned at best_of=1 /
         no logprobs, no penalties) is enforced by the engine."""
-        kv_caches = self._apply_block_copies(kv_caches, blocks_to_copy)
-        inputs, sampling, row_offsets, rows_per_group = \
-            self._prepare_spec_verify(seq_group_metadata_list, drafts)
-        padded = inputs["input_ids"].shape[0]
-        params = self._params_with_lora(seq_group_metadata_list, padded,
-                                        rows_per_group)
-        plan = self.sampler.plan(sampling, pad_to=padded)
-        assert plan.max_best_of == 1 and plan.num_topk == 0 and \
-            not plan.need_logprobs, "spec verify eligibility broken"
+        with self.tracer.span("runner.prepare"):
+            kv_caches = self._apply_block_copies(kv_caches,
+                                                 blocks_to_copy)
+            inputs, sampling, row_offsets, rows_per_group = \
+                self._prepare_spec_verify(seq_group_metadata_list,
+                                          drafts)
+            padded = inputs["input_ids"].shape[0]
+            params = self._params_with_lora(seq_group_metadata_list,
+                                            padded, rows_per_group)
+            plan = self._plan(sampling, padded)
+            assert plan.max_best_of == 1 and plan.num_topk == 0 and \
+                not plan.need_logprobs, "spec verify eligibility broken"
 
-        # The acceptance rule consumes salts per OUTPUT POSITION: row j
-        # of a sequence gets salt1 = output_len + j, exactly the salt
-        # the classic path uses when it reaches that position (plan
-        # salts are host numpy until this point, so the offset is a
-        # plain in-place add).
-        salt1 = np.asarray(plan.salt1, dtype=np.int32).copy()
-        salt1[:len(row_offsets)] += np.asarray(row_offsets,
-                                               dtype=np.int32)
-        with self._mesh_ctx():
+            # The acceptance rule consumes salts per OUTPUT POSITION:
+            # row j of a sequence gets salt1 = output_len + j, exactly
+            # the salt the classic path uses when it reaches that
+            # position (plan salts are host numpy until this point, so
+            # the offset is a plain in-place add).
+            salt1 = np.asarray(plan.salt1, dtype=np.int32).copy()
+            salt1[:len(row_offsets)] += np.asarray(row_offsets,
+                                                   dtype=np.int32)
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
@@ -1082,8 +1077,11 @@ class ModelRunner:
                 self._dev(np.asarray(plan.salt2)),
                 is_prompt=False, use_prefix=False,
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
-        packed_np = np.asarray(packed)                     # ONE sync
-        per_row = self.sampler.finalize(sampling, plan, packed_np, None)
+        with self.tracer.span("runner.device_wait"):
+            packed_np = np.asarray(packed)                 # ONE sync
+        with self.tracer.span("sampler.finalize"):
+            per_row = self.sampler.finalize(sampling, plan, packed_np,
+                                            None)
 
         results: List[SpecVerifyResult] = []
         row = 0

@@ -25,7 +25,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, Iterable, List, Optional, Tuple, Union
 
-from aphrodite_tpu.common import faultinject, flags
+from aphrodite_tpu.common import faultinject, flags, tracing
 from aphrodite_tpu.common.config import (CacheConfig, LoRAConfig,
                                          SchedulerConfig)
 from aphrodite_tpu.common.logger import init_logger
@@ -119,7 +119,11 @@ class Scheduler:
         cache_config: CacheConfig,
         lora_config: Optional[LoRAConfig] = None,
         disagg: bool = False,
+        tracer: Optional[tracing.Tracer] = None,
     ) -> None:
+        # The engine's span accumulators (its own, when built alone):
+        # queue waits and preemptions are counted here.
+        self.tracer = tracer or tracing.Tracer()
         self.scheduler_config = scheduler_config
         self.cache_config = cache_config
         self.lora_config = lora_config
@@ -429,6 +433,11 @@ class Scheduler:
             # retry re-admits it instead of losing the request.
             self._allocate(group)
             self.waiting.popleft()
+            if group.first_scheduled_time is None:
+                group.first_scheduled_time = time.monotonic()
+                self.tracer.add("queue_wait",
+                                group.first_scheduled_time -
+                                group.arrival_time)
             num_curr_seqs += num_new_seqs
             seq = group.get_seqs(status=SequenceStatus.RUNNING)[0]
             chunks.append(PromptChunk(group, ctx, n, final))
@@ -886,15 +895,6 @@ class Scheduler:
             if needed > free:
                 break
             granted = t
-        if granted < max_extra and \
-                flags.get_bool("APHRODITE_BURST_TIMING"):
-            need_full = sum(
-                self.block_manager.burst_blocks_needed(
-                    seq, cap(seq, max_extra))
-                for seq in seqs)
-            print(f"[burst reserve] want {max_extra} granted {granted}: "
-                  f"free {free} needed(full) {need_full} seqs "
-                  f"{len(seqs)} len0 {seqs[0].get_len()}", flush=True)
         if granted:
             for seq in seqs:
                 self.block_manager.reserve_slots(seq, cap(seq, granted))
@@ -954,6 +954,7 @@ class Scheduler:
         # Single-sequence groups recompute (cheaper than staging pages to
         # host over PCIe); multi-sequence groups (beam/parallel) must swap
         # because recompute cannot reproduce forked KV state.
+        self.tracer.add("preemptions")
         if preemption_mode is None:
             if seq_group.get_max_num_running_seqs() == 1:
                 preemption_mode = PreemptionMode.RECOMPUTE

@@ -57,6 +57,10 @@ class Window:
     #: requests that failed before the window opened (closed loop: the
     #: callers' joining is part of the same stream)
     failed_before: int = 0
+    #: requests that failed after the sample's last one had ended: the
+    #: load goes on while `after_close` runs (a `--trace 2` run traces
+    #: then), and those replies enter no metric, but a failure is one
+    failed_after: int = 0
 
     @property
     def attempted(self) -> int:
@@ -90,14 +94,16 @@ async def wait_idle(target: Target, timeout: float = 300.0) -> None:
 async def open_loop(target: Target, seed: int, seconds: float,
                     on_open: Optional[Callable] = None,
                     inflight_cap: Optional[int] = None,
-                    tail: bool = True) -> Window:
+                    tail: bool = True,
+                    after_close: Optional[Callable] = None) -> Window:
     """Offer the traffic at its fixed rate. Block 0 of the seed's
     stream, due in [t0, t0 + seconds), is the sample; the last
     `lead_seconds` of block -1 run before it so that the window opens
     on a loaded server, and blocks 1, 2, ... go on at the same rate
     until the sample's last request has ended, so that the tail is
-    measured under load. With an `inflight_cap` (warm-up only) a send
-    waits while that many requests are open."""
+    measured under load, and on through `after_close()`. With an
+    `inflight_cap` (warm-up only) a send waits while that many
+    requests are open."""
     loop = target.traffic["loop"]
     n, span = max(1, round(loop["rate_per_s"] * seconds)), float(seconds)
     lead = min(float(loop.get("lead_seconds", 0.0)), span)
@@ -129,17 +135,24 @@ async def open_loop(target: Target, seed: int, seconds: float,
     sender = asyncio.ensure_future(keep_sending()) if tail else None
     try:
         replies = await asyncio.gather(*tasks)
+        t_end = max(r.ended for r in replies)
+        if after_close is not None:
+            await after_close()
+        later = [t.result() for t in others
+                 if t.done() and not t.cancelled()]
     finally:
         await _cancel(([sender] if sender else []) + others)
     if opened is not None:
         await opened
-    return Window(t0=t0, seconds=span, replies=list(replies),
-                  t_end=max(r.ended for r in replies))
+    return Window(t0=t0, seconds=span, replies=list(replies), t_end=t_end,
+                  failed_after=sum(1 for r in later
+                                   if not r.ok and r.ended >= t_end))
 
 
 async def closed_loop(target: Target, seed: int, seconds: float,
                       on_open: Optional[Callable] = None,
-                      settled: Optional[Callable] = None) -> Window:
+                      settled: Optional[Callable] = None,
+                      after_close: Optional[Callable] = None) -> Window:
     """`clients` callers in a closed loop, which is its own warm-up.
 
     The callers join in groups of `ramp_groups` (cycled). A group's
@@ -154,8 +167,9 @@ async def closed_loop(target: Target, seed: int, seconds: float,
     as it gets) and `settled()` has returned (the server has compiled
     nothing for a while), and lasts `seconds`. The callers go on until
     every request that was open when it closed has ended, so that the
-    work the window did on them can be counted; the sample is every
-    request that was open at some time inside the window. Requests
+    work the window did on them can be counted, and on through
+    `after_close()`; the sample is every request that was open at some
+    time inside the window. Requests
     sent before it opens may wait for compiles and get
     `warm_timeout_s`. Every block of `clients` requests holds the same
     lengths, so what is open at any time is the same work in every
@@ -210,6 +224,8 @@ async def closed_loop(target: Target, seed: int, seconds: float,
         t1 = t0 + seconds
         await asyncio.wait_for(
             asyncio.gather(*(e.wait() for e in closed)), target.timeout)
+        if after_close is not None:
+            await after_close()
     finally:
         await _cancel(callers)
     if opened is not None:
@@ -218,7 +234,9 @@ async def closed_loop(target: Target, seed: int, seconds: float,
     return Window(t0=t0, seconds=float(seconds), replies=sample,
                   t_end=max([t1] + [r.ended for r in sample]),
                   failed_before=sum(1 for r in replies
-                                    if r.ended < t0 and not r.ok))
+                                    if r.ended < t0 and not r.ok),
+                  failed_after=sum(1 for r in replies
+                                   if r.sent >= t1 and not r.ok))
 
 
 LOOPS = {"open": open_loop, "closed": closed_loop}

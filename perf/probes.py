@@ -11,11 +11,13 @@ from perf.client import clock, get_text, post_json, sleep_until
 
 _SAMPLE = re.compile(r"^([A-Za-z_:][\w:]*)(?:\{[^}]*\})? ([-+.\deEinfa]+)$")
 #: seconds of device trace taken. The program's `stop_profile` then
-#: stalls the server for some 15 s while the trace (Python tracer on)
-#: is written. In an open loop the trace is the window's last seconds,
-#: so that the stall falls behind the window and no arrival piles up
-#: in it; a closed loop's callers leave when the window closes, so
-#: its trace ends `CLOSED_LOOP_MARGIN` seconds earlier, under load.
+#: stalls the server while the trace is written (some 15 s with the
+#: Python tracer on). In a `--trace 1` run of an open loop the trace
+#: is the window's last seconds, so that the stall falls behind the
+#: window and no arrival piles up in it; a closed loop's callers leave
+#: when the window closes, so its trace ends `CLOSED_LOOP_MARGIN`
+#: seconds earlier, under load. A `--trace 2` run traces after the
+#: window (`trace_after`), with the load still going.
 TRACE_SECONDS = 2.0
 CLOSED_LOOP_MARGIN = 3.0
 SAMPLE_PERIOD = 0.25
@@ -40,6 +42,7 @@ class Probe:
     server: object                    # .log_size()
     trace_dir: Optional[str] = None   # set: take a device trace
     trace_at: float = 0.0             # seconds after t0 to start it
+    python_tracer: bool = False       # ask for Python frames as well
     samples: List[Tuple[float, Dict[str, float]]] = dataclasses.field(
         default_factory=list)
     log_open: Optional[int] = None
@@ -62,18 +65,38 @@ class Probe:
 
     async def _trace(self, t0: float) -> None:
         await sleep_until(t0 + self.trace_at)
+        await self._profile(self.trace_dir, TRACE_SECONDS)
+
+    async def _profile(self, trace_dir: str, seconds: float) -> float:
+        """Trace `seconds` into `trace_dir`; returns how long the
+        server took to answer `/stop_profile` (it writes the trace
+        before it does)."""
+        body = {"trace_dir": trace_dir}
+        if self.python_tracer:
+            body["python_tracer"] = True
         status, text = await post_json(
-            self.session, self.url + "/start_profile",
-            {"trace_dir": self.trace_dir})
+            self.session, self.url + "/start_profile", body)
         if status != 200:
             raise RuntimeError(f"/start_profile: HTTP {status}: {text}")
         started = clock()
-        await asyncio.sleep(TRACE_SECONDS)
+        await asyncio.sleep(seconds)
+        stopping = clock()
         status, text = await post_json(
             self.session, self.url + "/stop_profile", {}, timeout=120.0)
         if status != 200:
             raise RuntimeError(f"/stop_profile: HTTP {status}: {text}")
         self.trace_span = (started, clock())
+        return clock() - stopping
+
+    async def trace_after(self, trace_dir: str) -> float:
+        """A `--trace 2` run's traced seconds, taken when the window's
+        numbers are complete and the load still goes on. The profiler
+        is first started and stopped once into a directory that is
+        thrown away, so that what its first start costs falls into no
+        number; then `TRACE_SECONDS` are traced into `trace_dir`.
+        Returns the seconds the second `/stop_profile` took."""
+        await self._profile(trace_dir + ".first", 0.0)
+        return await self._profile(trace_dir, TRACE_SECONDS)
 
     async def close(self) -> None:
         """Take the last sample, stop sampling, and wait for the trace

@@ -1,11 +1,15 @@
 #!/usr/bin/env python3
 """Run one cell of the benchmark, once.
 
-    python perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 Starts the program's OpenAI server as the one child that holds the
 chip, warms up the cell's own traffic, measures for `--seconds`, drains
-the server, and prints one JSON object as the last line of its output.
+the server, and prints one JSON object as the last line of its output:
+the end-to-end metrics (`--trace 0`), the per-layer metrics of a run
+whose window is traced (`--trace 1`), or both (`--trace 2`: a
+`--trace 0` run that, once its window's numbers are complete, traces a
+few seconds of the same traffic).
 It exits non-zero, and prints no result, when the server does not come
 up on `tpu` with the chips the cell asks for. `--rehearse` runs a tiny
 cell of `perf/rehearse/` on the CPU, says so in `device`, and is named
@@ -56,8 +60,8 @@ class Run:
     #: from the window's opening to its close
     samples: list
     #: the samples that count end here: at the window's close or, in a
-    #: traced run, when the profiler was started (it slows the host,
-    #: and writing the trace stalls the server for seconds)
+    #: `--trace 1` run, when the profiler was started (it slows the
+    #: host, and writing the trace stalls the server for seconds)
     steady_until: float
     log_setup: str                   # server log before the window
     log_window: str                  # server log while it was open
@@ -158,10 +162,13 @@ async def settled(server, quiet: float) -> None:
 
 async def measure(cell: cells.Cell, server, session, seed: int,
                   seconds: float, trace_dir: Optional[str],
-                  model: str) -> Run:
+                  model: str, trace_mode: int = 1,
+                  python_tracer: bool = False) -> Run:
     """Warm-up, canary, window, canary, health: everything between the
     server being ready and its drain. `server` needs `.url`,
-    `.log_size()` and `.read_log(start, end)`."""
+    `.log_size()` and `.read_log(start, end)`. With a `trace_dir` the
+    profiler's trace goes there: taken inside the window
+    (`trace_mode` 1) or after it, the load still going (2)."""
     traffic = cell.traffic
     target = loops.Target(
         session=session, url=server.url, model=model,
@@ -175,11 +182,13 @@ async def measure(cell: cells.Cell, server, session, seed: int,
         await warm_up(target, server, seed)
     before = await loops.canary(target, seed)
 
+    in_window = trace_dir if trace_mode == 1 else None
     probe = probes.Probe(
         session=session, url=server.url, server=server,
-        trace_dir=trace_dir, trace_at=max(0.0, seconds - (
+        trace_dir=in_window, trace_at=max(0.0, seconds - (
             probes.TRACE_SECONDS if is_open else
-            probes.TRACE_SECONDS + probes.CLOSED_LOOP_MARGIN)))
+            probes.TRACE_SECONDS + probes.CLOSED_LOOP_MARGIN)),
+        python_tracer=python_tracer)
     if is_open:
         # Writing the trace stalls the server for seconds. In an open
         # loop the arrivals of that time would pile into batch shapes
@@ -187,12 +196,29 @@ async def measure(cell: cells.Cell, server, session, seed: int,
         # window; so a traced window holds sends back as the warm-up
         # does. Its end-to-end numbers are not reported.
         how = dict(inflight_cap=traffic.get("warm_inflight")) \
-            if trace_dir else {}
+            if in_window else {}
     else:
         # A closed loop warms itself up: its callers join, and the
         # window opens when the server has stopped compiling.
         how = dict(settled=lambda: settled(
             server, float(traffic["warm_seconds"])))
+    if trace_dir and trace_mode == 2:
+        async def trace_after() -> None:
+            t = clock()
+            stall = await probe.trace_after(trace_dir)
+            say(f"traced {probes.TRACE_SECONDS:g} s after the window, "
+                f"the load still going: {clock() - t:.1f} s in all, "
+                f"{stall:.1f} s of it the server writing the trace")
+            # What the profiler costs the host: the engine's rounds
+            # while it ran, against `round_ms` of the window.
+            began, name = probe.trace_span[0], "aphrodite:engine_rounds_total"
+            seen = [(at, s[name]) for at, s in probe.samples if name in s
+                    and began <= at <= began + probes.TRACE_SECONDS]
+            if len(seen) > 1 and seen[-1][1] > seen[0][1]:
+                say("a round under the profiler: %.1f ms (%d rounds)" % (
+                    (seen[-1][0] - seen[0][0]) * 1e3 /
+                    (seen[-1][1] - seen[0][1]), seen[-1][1] - seen[0][1]))
+        how["after_close"] = trace_after
     window = await loops.LOOPS[traffic["loop"]["kind"]](
         target, seed, seconds, on_open=probe.open, **how)
     await probe.close()
@@ -206,6 +232,9 @@ async def measure(cell: cells.Cell, server, session, seed: int,
     if window.failed_before:
         faults.append(f"{window.failed_before} requests failed while the "
                       "callers joined")
+    if trace_mode == 2 and window.failed_after:
+        faults.append(f"{window.failed_after} requests failed in the "
+                      "traced seconds after the window")
 
     await loops.wait_idle(target)
     after = await loops.canary(target, seed)
@@ -225,7 +254,7 @@ async def measure(cell: cells.Cell, server, session, seed: int,
 
     return Run(cell=cell, window=window, t_start=T_START,
                samples=probe.samples,
-               steady_until=window.t0 + (probe.trace_at if trace_dir
+               steady_until=window.t0 + (probe.trace_at if in_window
                                          else seconds),
                log_setup=server.read_log(0, probe.log_open),
                log_window=server.read_log(probe.log_open, probe.log_close),
@@ -300,7 +329,8 @@ async def serve_and_measure(cell: cells.Cell, args):
         run = await measure(
             cell, server, session, args.seed, args.seconds,
             os.path.join(os.path.dirname(server.log_path), "trace")
-            if args.trace else None, server.args[1])
+            if args.trace else None, server.args[1],
+            trace_mode=args.trace, python_tracer=args.python_tracer)
         # Drain while the session is still open: its connections are idle.
         code = server.drain(180.0)
         log = server.read_log()
@@ -315,7 +345,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
-    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
+    p.add_argument("--python-tracer", action="store_true",
+                   help="ask the server's profiler for Python frames "
+                        "as well (slows the host it times)")
     p.add_argument("--rehearse", action="store_true",
                    help="run a tiny cell of perf/rehearse/ on the CPU")
     p.add_argument("--keep-log", default=None, metavar="PATH",
@@ -363,8 +396,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             f"GB; KV pool {pool[0]} pages, {pool[1]:.2f} GiB reserved, "
             f"{used * 100:.1f}% of it live on average over the window")
     if args.trace:
-        path = trace.find_xplane(os.path.join(
-            root, "perf", ".work", cell.name, "trace"))
+        trace_dir = os.path.join(root, "perf", ".work", cell.name, "trace")
+        path, t = trace.find_xplane(trace_dir), clock()
         try:
             planes = trace.load(path, args.rehearse)
             if args.keep_trace:
@@ -378,20 +411,34 @@ def main(argv: Optional[List[str]] = None) -> int:
             return 1
         device["busy_s"] = run.trace["busy_s"]
         device["window_s"] = run.trace["window_s"]
+        say(f"trace {os.path.getsize(path) / 1e6:.1f} MB, read and "
+            f"reduced in {clock() - t:.1f} s")
+        if args.trace == 2:
+            # The trace is reduced: a run keeps none (a `--trace 1`
+            # run's is overwritten by the cell's next run).
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            shutil.rmtree(trace_dir + ".first", ignore_errors=True)
     for fault in run.faults:
         say(f"FAULT: {fault}")
-    result = dict(
-        correct=not run.faults, attempted=run.window.attempted,
-        failed=run.window.failed,
-        metrics=read_metrics(run, cell.per_layer, "layers") if args.trace
-        else read_metrics(run, cell.end_to_end, "end_to_end"),
-        device=device)
-    if args.trace:
+    say(f"total {clock() - T_START:.1f} s")
+    print(json.dumps(result_line(run, args.trace, device)), flush=True)
+    return 0
+
+
+def result_line(run: Run, trace_mode: int, device: dict) -> dict:
+    """The run's one line: the end-to-end metrics (`--trace 0`), the
+    per-layer metrics (1), or both side by side (2)."""
+    cell, metrics = run.cell, {}
+    if trace_mode != 1:
+        metrics.update(read_metrics(run, cell.end_to_end, "end_to_end"))
+    if trace_mode:
+        metrics.update(read_metrics(run, cell.per_layer, "layers"))
+    result = dict(correct=not run.faults, attempted=run.window.attempted,
+                  failed=run.window.failed, metrics=metrics, device=device)
+    if trace_mode:
         result["breakdown"] = dict(device_ops=run.trace["device_ops"],
                                    idle_gaps=run.trace["idle_gaps"])
-    say(f"total {clock() - T_START:.1f} s")
-    print(json.dumps(result), flush=True)
-    return 0
+    return result
 
 
 if __name__ == "__main__":

@@ -1,0 +1,552 @@
+"""The engine round's spans and per-stage counters
+(`aphrodite_tpu/common/tracing.py`): every path of a round goes through
+the same span names, nested as PERF.md's table says; with the profiler
+off a span is two clock reads and no annotation; the counters ride
+through `Stats` into Prometheus."""
+import asyncio
+import glob
+import os
+import time
+
+import pytest
+from prometheus_client import REGISTRY
+
+from aphrodite_tpu.common import flags, tracing
+from aphrodite_tpu.common.sampling_params import SamplingParams
+from aphrodite_tpu.engine.metrics import Stats, StatLogger
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+GREEDY = SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True)
+#: the stages a round's wall time is made of, as the counters sum them
+STAGES = ("sched.schedule", "runner.prepare", "runner.dispatch",
+          "runner.device_wait", "sampler.finalize", "engine.process")
+
+
+class Recorder:
+    """Stands in for `jax.profiler.TraceAnnotation`: keeps what the
+    profiler's trace would hold, each span with its depth and the name
+    of the span it opened under."""
+
+    def __init__(self):
+        self.spans, self._open = [], []
+
+    def __call__(self, name, **facts):
+        recorder = self
+
+        class Annotation:
+            def __enter__(self):
+                parent = recorder._open[-1] if recorder._open else None
+                recorder.spans.append((name, parent, dict(facts)))
+                recorder._open.append(name)
+
+            def __exit__(self, *exc):
+                assert recorder._open.pop() == name
+
+        return Annotation()
+
+    def names(self):
+        return [name for name, _, _ in self.spans]
+
+    def rounds(self):
+        """The spans of each `aph.engine.step`, in order."""
+        out = []
+        for span in self.spans:
+            if span[0] == "aph.engine.step":
+                out.append([])
+            if out:
+                out[-1].append(span)
+        return out
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    """What the profiler's trace would hold of the engines whose
+    tracers a test switches on (`_annotating`)."""
+    rec = Recorder()
+    monkeypatch.setattr(tracing, "TraceAnnotation", rec)
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    return rec
+
+
+@pytest.fixture
+def _annotating(monkeypatch):
+    def on(engine):
+        monkeypatch.setattr(engine.tracer, "annotating", True)
+        return engine
+    return on
+
+
+def _prompt(i, n=20):
+    return [(i * 7 + j * 3) % 90 + 5 for j in range(n)]
+
+
+def _drain(engine):
+    while engine.has_unfinished_requests():
+        engine.step()
+
+
+def _tree(round_spans):
+    """[(name, parent)] of one round, without the `aph.` prefix."""
+    return [(name[4:], parent and parent[4:])
+            for name, parent, _ in round_spans]
+
+
+# ---- the module ----
+
+def test_a_span_accumulates_and_an_unknown_name_raises():
+    tracer = tracing.Tracer()
+    with tracer.span("runner.prepare"):
+        time.sleep(0.01)
+    assert tracer.counts["runner.prepare"] == 1
+    assert 0.01 <= tracer.seconds["runner.prepare"] < 0.5
+    with pytest.raises(KeyError):
+        with tracer.span("runner.prepair"):
+            pass
+    tracer.add("preemptions")
+    assert tracer.counts["preemptions"] == 1
+    assert set(tracer.seconds) == set(tracer.counts) == set(tracing.NAMES)
+    # an engine's tracer is its own
+    assert tracing.Tracer().counts["runner.prepare"] == 0
+
+
+def test_profiler_off_enters_no_annotation_and_on_names_the_facts(
+        monkeypatch):
+    rec = Recorder()
+    monkeypatch.setattr(tracing, "TraceAnnotation", rec)
+    tracer = tracing.Tracer()
+    tracer.set_round(round=7, path="decode", rows=3, prompt_tokens=0)
+    with tracer.span("runner.dispatch"):
+        pass
+    assert rec.spans == []
+    tracer.annotate(True)
+    with tracer.span("engine.step"):
+        with tracer.span("cache.kv_handoff", pages=5):
+            tracer.annotate(False)      # a span ends as it began
+    with tracer.span("engine.step"):
+        pass
+    assert rec.spans == [
+        ("aph.engine.step", None,
+         dict(round=7, path="decode", rows=3, prompt_tokens=0)),
+        ("aph.cache.kv_handoff", "aph.engine.step",
+         dict(round=7, path="decode", rows=3, prompt_tokens=0, pages=5))]
+    assert rec._open == [] and tracer.counts["engine.step"] == 2
+
+
+def test_spanned_wraps_the_whole_call_of_a_method():
+    class Stage:
+        tracer = tracing.Tracer()
+
+        @tracing.spanned("sampler.plan")
+        def plan(self, x, y=1):
+            """doc"""
+            return x + y
+    assert Stage().plan(2, y=3) == 5 and Stage.plan.__doc__ == "doc"
+    assert Stage.tracer.counts["sampler.plan"] == 1
+
+
+# ---- the spans of a round, by path ----
+
+def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
+        tiny_llm, recorder, _annotating):
+    engine = _annotating(tiny_llm.engine)
+    engine.add_request("a", None, GREEDY, prompt_token_ids=_prompt(1))
+    engine.step()                       # prompt
+    engine.step()                       # decode
+    engine.add_request("b", None, GREEDY, prompt_token_ids=_prompt(2))
+    engine.step()                       # combined: b's prompt, a's decode
+    _drain(engine)
+    rounds = recorder.rounds()
+    paths = [r[-1][2]["path"] for r in rounds]
+    assert paths[:3] == ["prompt", "decode", "combined"]
+    assert set(paths[3:]) == {"decode"}
+
+    step = [("sched.schedule", "engine.step"),
+            ("runner.prepare", "engine.step"),
+            ("sampler.plan", "runner.prepare"),
+            ("runner.dispatch", "engine.step"),
+            ("runner.device_wait", "engine.step"),
+            ("sampler.finalize", "engine.step")]
+    # a decode round: every span of its path exactly once
+    for r in rounds[1:2] + rounds[3:]:
+        assert _tree(r) == [("engine.step", None)] + step + \
+            [("engine.process", "engine.step")]
+    # a prompt round is dispatched without a sync, and the scheduler is
+    # asked for a further prompt-only round to chain behind it
+    assert _tree(rounds[0]) == [("engine.step", None)] + step[:4] + \
+        [("sched.schedule", "engine.step")] + step[4:] + \
+        [("engine.process", "engine.step")]
+    # a combined round off the fused path (multi_step 1) is two synced
+    # steps, prompt then decode, and one processing of both
+    assert _tree(rounds[2]) == [("engine.step", None)] + step + \
+        step[1:] + [("engine.process", "engine.step")]
+    # the facts: `round` counts up; the scheduled round's spans say what
+    # ran (the step and the schedule open before that is known)
+    numbers = [r[0][2]["round"] for r in rounds]
+    assert numbers == list(range(numbers[0], numbers[0] + len(rounds)))
+    assert rounds[2][0][2] == dict(round=numbers[2])
+    assert rounds[2][-1][2] == dict(round=numbers[2], path="combined",
+                                    rows=2, prompt_tokens=20)
+
+
+@pytest.fixture(scope="module")
+def burst_llm(tiny_model_dir):
+    from aphrodite_tpu.endpoints.llm import LLM
+    return LLM(model=tiny_model_dir, load_format="dummy", dtype="float32",
+               block_size=16, max_model_len=256, max_num_seqs=4,
+               multi_step=4, swap_space=0.01)
+
+
+def test_burst_and_fused_combined_rounds_use_the_same_names(
+        burst_llm, recorder, _annotating):
+    engine = _annotating(burst_llm.engine)
+    sp = SamplingParams(temperature=0.0, max_tokens=9, ignore_eos=True)
+    engine.add_request("a", None, sp, prompt_token_ids=_prompt(3))
+    engine.step()                       # prompt
+    engine.step()                       # burst of 4
+    engine.add_request("b", None, sp, prompt_token_ids=_prompt(4))
+    engine.step()                       # fused: prompt + burst, one sync
+    _drain(engine)
+    rounds = recorder.rounds()
+    paths = [r[-1][2]["path"] for r in rounds]
+    assert paths[:3] == ["prompt", "burst", "combined"]
+    burst = _tree(rounds[1])
+    # the page reservation of the burst is scheduler work
+    assert burst.count(("sched.schedule", "engine.step")) == 2
+    assert [n for n, _ in burst if n != "sched.schedule"] == [
+        "engine.step", "runner.prepare", "sampler.plan",
+        "runner.dispatch", "runner.device_wait", "sampler.finalize",
+        "engine.process"]
+    fused = [n for n, _ in _tree(rounds[2]) if n != "sched.schedule"]
+    assert fused == ["engine.step",
+                     "runner.prepare", "sampler.plan", "runner.dispatch",
+                     "runner.prepare", "sampler.plan", "runner.dispatch",
+                     "runner.device_wait", "sampler.finalize",
+                     "engine.process"]
+    assert {n for r in rounds for n in recorder.names()} <= {
+        "aph." + n for n in tracing.NAMES}
+
+
+def test_a_speculative_round_uses_the_same_names(
+        tiny_llm, recorder, _annotating, monkeypatch):
+    monkeypatch.setenv("APHRODITE_SPEC", "1")
+    engine = _annotating(tiny_llm.engine)
+    # a prompt that repeats itself, so that the n-gram drafter proposes
+    sp = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
+    engine.add_request("s", None, sp,
+                       prompt_token_ids=[5, 6, 7, 8, 9] * 8)
+    _drain(engine)
+    spec = [r for r in recorder.rounds()
+            if r[-1][2].get("path") == "spec"]
+    if not spec:
+        pytest.skip("the dummy weights never repeated a token: no draft")
+    assert [n for n, _ in _tree(spec[0]) if n != "sched.schedule"] == [
+        "engine.step", "runner.prepare", "sampler.plan",
+        "runner.dispatch", "runner.device_wait", "sampler.finalize",
+        "engine.process"]
+
+
+def test_a_rebuilt_engine_keeps_its_tracer_and_its_counts(
+        tiny_model_dir, monkeypatch):
+    from aphrodite_tpu.endpoints.llm import LLM
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = LLM(model=tiny_model_dir, load_format="dummy",
+                 dtype="float32", block_size=16, max_model_len=256,
+                 max_num_seqs=4, swap_space=0.01).engine
+    engine.add_request("r", None, GREEDY, prompt_token_ids=_prompt(6))
+    engine.step()
+    rounds = engine.tracer.counts["engine.step"]
+    assert rounds == 1 and engine.tracer.counts["queue_wait"] == 1
+    engine.reincarnate()
+    for part in (engine.scheduler, engine.executor,
+                 engine.executor.model_runner):
+        assert part.tracer is engine.tracer
+    _drain(engine)
+    assert engine.tracer.counts["engine.step"] > rounds
+    # the restored request is admitted again, not scheduled for the
+    # first time again
+    assert engine.tracer.counts["queue_wait"] == 1
+
+
+# ---- cost and completeness ----
+
+def test_stage_seconds_sum_to_the_steps_wall_time(tiny_llm, monkeypatch):
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    for i in range(4):
+        engine.add_request(f"w{i}", None, GREEDY,
+                           prompt_token_ids=_prompt(i))
+    _drain(engine)                      # every shape is compiled now
+    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+    for i in range(4):
+        engine.add_request(f"m{i}", None, sp, prompt_token_ids=_prompt(i))
+    before = dict(engine.tracer.seconds)
+    t0 = time.perf_counter()
+    _drain(engine)
+    wall = time.perf_counter() - t0
+    grew = {k: engine.tracer.seconds[k] - before[k] for k in before}
+    stages = sum(grew[k] for k in STAGES)
+    assert grew["engine.step"] <= wall
+    # self times cover the step: what lies between the spans is glue
+    assert 0.85 * grew["engine.step"] <= stages <= grew["engine.step"]
+    # the plan is inside prepare, not beside it
+    assert grew["sampler.plan"] < grew["runner.prepare"]
+
+
+def test_profiler_off_a_round_costs_two_clock_reads_a_span(
+        tiny_llm, monkeypatch):
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    engine.add_request("c", None, GREEDY, prompt_token_ids=_prompt(5))
+    engine.step()
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return time.perf_counter()
+
+    monkeypatch.setattr(tracing, "_clock", clock)
+    monkeypatch.setattr(
+        tracing, "TraceAnnotation",
+        lambda *a, **k: pytest.fail("annotation with the profiler off"))
+    before = sum(engine.tracer.counts.values())
+    engine.step()                       # one decode round
+    spans = sum(engine.tracer.counts.values()) - before
+    monkeypatch.undo()
+    _drain(engine)
+    assert spans == 8 and len(reads) == 2 * spans
+    # the budget: under 50 us of added host time a round
+    tracer = tracing.Tracer()
+
+    def cost(n=2000):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with tracer.span("runner.dispatch"):
+                pass
+        return (time.perf_counter() - t0) / n
+    assert min(cost() for _ in range(5)) * spans < 50e-6
+
+
+# ---- the counted events ----
+
+def test_queue_wait_and_preemptions_count_on_a_forced_case():
+    from aphrodite_tpu.common.config import CacheConfig, SchedulerConfig
+    from aphrodite_tpu.common.sequence import (Sequence, SequenceGroup,
+                                               SequenceStatus)
+    from aphrodite_tpu.processing.scheduler import Scheduler
+    cache_config = CacheConfig(block_size=4)
+    # 4 pages hold two 7-token prompts; the next page of either evicts
+    # the other
+    cache_config.num_gpu_blocks, cache_config.num_cpu_blocks = 4, 16
+    tracer = tracing.Tracer()
+    sched = Scheduler(SchedulerConfig(
+        max_num_batched_tokens=256, max_num_seqs=8, max_model_len=256,
+        max_paddings=1024), cache_config, None, tracer=tracer)
+    now = time.monotonic()
+
+    def make_group(i, waited):
+        seq = Sequence(900_000 + i, "x", list(range(7)), 4)
+        return SequenceGroup(f"q{i}", [seq], SamplingParams(),
+                             arrival_time=now - waited)
+
+    def append_tokens(group, n):
+        for seq in group.get_seqs(status=SequenceStatus.RUNNING):
+            for _ in range(n):
+                seq.append_token_id(seq.get_len(), {seq.get_len(): 0.0})
+
+    g1, g2 = make_group(1, 2.0), make_group(2, 1.0)
+
+    def grew(name):
+        return tracer.seconds[name], tracer.counts[name]
+
+    assert g1.first_scheduled_time is None
+    sched.add_seq_group(g1)
+    sched.add_seq_group(g2)
+    sched.schedule()
+    assert grew("queue_wait")[1] == 2
+    assert 3.0 <= grew("queue_wait")[0] < 3.5
+    assert now <= g1.first_scheduled_time <= time.monotonic()
+    assert grew("preemptions") == (0.0, 0)
+    append_tokens(g1, 2)
+    append_tokens(g2, 2)
+    sched.schedule()
+    assert grew("preemptions") == (0.0, 1)
+    (preempted,) = sched.waiting
+    stamp = preempted.first_scheduled_time
+    # the preempted group is admitted again once the other has gone: it
+    # is not a request scheduled for the first time
+    sched.abort_seq_group(g2.request_id if preempted is g1
+                          else g1.request_id)
+    _, out = sched.schedule()
+    assert [c.group for c in out.prompt_chunks] == [preempted]
+    assert grew("queue_wait")[1] == 2
+    assert preempted.first_scheduled_time == stamp
+
+
+# ---- the control ----
+
+def test_start_stop_start_in_one_process_and_the_spans_are_in_the_trace(
+        tiny_llm, tmp_path, monkeypatch):
+    from jax.profiler import ProfileData
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    with pytest.raises(RuntimeError):
+        engine.stop_profile()
+    for i, python_tracer in enumerate((False, True)):
+        # (a directory each: the profiler names a trace by the second)
+        engine.start_profile(str(tmp_path / str(i)),
+                             python_tracer=python_tracer)
+        with pytest.raises(RuntimeError):
+            engine.start_profile(str(tmp_path))
+        engine.add_request(f"p{i}", None, GREEDY,
+                           prompt_token_ids=_prompt(i))
+        _drain(engine)
+        engine.stop_profile()
+        engine.add_request(f"o{i}", None, GREEDY,
+                           prompt_token_ids=_prompt(i))
+        _drain(engine)                  # off again: nothing is recorded
+    paths = sorted(glob.glob(str(
+        tmp_path / "*" / "plugins" / "profile" / "*" / "*.xplane.pb")))
+    assert len(paths) == 2
+    python_frames = []
+    for path in paths:
+        events = [e.name for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events]
+        steps = events.count("aph.engine.step")
+        assert steps == 4               # p's rounds, not o's
+        for name in STAGES:
+            assert events.count("aph." + name) >= steps, name
+        python_frames.append(sum(1 for n in events if n.startswith("$")))
+    # Python frames only when asked for
+    assert python_frames[0] == 0 and python_frames[1] > 0
+
+
+def test_the_two_print_flags_are_gone():
+    for name in ("APHRODITE_BURST_TIMING", "APHRODITE_DISAGG_TIMING"):
+        assert name not in flags.registry()
+        with pytest.raises(flags.FlagError):
+            flags.get_bool(name)
+    with open(os.path.join(ROOT, "README.md")) as f:
+        readme = f.read()
+    assert "BURST_TIMING" not in readme and "DISAGG_TIMING" not in readme
+    # and no print on the step path
+    for rel in ("engine/aphrodite_engine.py", "executor/executor.py",
+                "executor/model_runner.py", "processing/scheduler.py",
+                "common/tracing.py"):
+        with open(os.path.join(ROOT, "aphrodite_tpu", rel)) as f:
+            assert "print(" not in f.read(), rel
+
+
+# ---- the exporter ----
+
+def _value(name, labels):
+    return REGISTRY.get_sample_value(name, labels)
+
+
+def _stats(**kw):
+    return Stats(now=0.0, num_running=0, num_waiting=0, num_swapped=0,
+                 gpu_cache_usage=0.0, cpu_cache_usage=0.0,
+                 num_prompt_tokens=0, num_generation_tokens=0,
+                 time_to_first_tokens=[], time_per_output_tokens=[],
+                 time_e2e_requests=[], **kw)
+
+
+def test_cumulative_totals_are_exported_as_deltas_by_one_helper():
+    labels = dict(model_name="tracing-test-a")
+    log = StatLogger(labels=labels)
+    shed = "aphrodite:num_requests_shed_total"
+    base = _value(shed, labels) or 0.0
+    for total, want in ((3, 3), (5, 5), (4, 5), (6, 6)):
+        # a total that fell (a rebuilt engine) exports nothing until it
+        # has passed what was exported
+        log.log(_stats(sheds_total=total, reincarnations_total=total))
+        assert _value(shed, labels) == base + want
+        assert _value("aphrodite:reincarnations_total", labels) == want
+
+
+def test_stage_counters_are_exported_from_the_tracers_totals():
+    tracer = tracing.Tracer()
+    labels = dict(model_name="tracing-test-b")
+    log = StatLogger(labels=labels)
+    names = {
+        "aphrodite:engine_rounds_total", "aphrodite:host_syncs_total",
+        "aphrodite:host_schedule_seconds_total",
+        "aphrodite:host_prepare_seconds_total",
+        "aphrodite:device_wait_seconds_total",
+        "aphrodite:host_process_seconds_total",
+        "aphrodite:host_between_steps_seconds_total",
+        "aphrodite:queue_wait_seconds_total",
+        "aphrodite:requests_first_scheduled_total",
+        "aphrodite:preemptions_total",
+        "aphrodite:engine_step_seconds_total"}
+    # every one reads 0 from the start, not "absent"
+    assert {n: _value(n, labels) for n in names} == dict.fromkeys(names,
+                                                                 0.0)
+    tracer.add("runner.dispatch", 0.25)
+    tracer.add("runner.device_wait", 0.5)
+    tracer.add("sampler.finalize", 0.125)
+    tracer.add("engine.process", 0.125)
+    tracer.add("queue_wait", 2.0)
+    tracer.add("preemptions")
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    assert _value("aphrodite:device_wait_seconds_total", labels) == \
+        pytest.approx(0.75)
+    assert _value("aphrodite:host_process_seconds_total", labels) == \
+        pytest.approx(0.25)
+    assert _value("aphrodite:host_syncs_total", labels) == 1
+    assert _value("aphrodite:queue_wait_seconds_total", labels) == \
+        pytest.approx(2.0)
+    assert _value("aphrodite:requests_first_scheduled_total", labels) == 1
+    assert _value("aphrodite:preemptions_total", labels) == 1
+    log.log(_stats())                   # a Stats without them: no-op
+    assert _value("aphrodite:preemptions_total", labels) == 1
+
+
+# ---- the async loop ----
+
+def test_between_steps_is_counted_once_a_round_and_never_while_idle(
+        tiny_model_dir, monkeypatch):
+    from aphrodite_tpu.engine.args_tools import AsyncEngineArgs
+    from aphrodite_tpu.engine.async_aphrodite import AsyncAphrodite
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    labels = dict(model_name=tiny_model_dir)
+    sp = SamplingParams(temperature=0.0, max_tokens=6, ignore_eos=True)
+
+    async def _generate_all(engine, prompts, tag):
+        async def one(i, p):
+            final = None
+            async for out in engine.generate(None, sp, f"{tag}-{i}",
+                                             prompt_token_ids=p):
+                final = out
+            return final
+        return await asyncio.gather(*(one(i, p)
+                                      for i, p in enumerate(prompts)))
+
+    async def go():
+        engine = AsyncAphrodite.from_engine_args(AsyncEngineArgs(
+            model=tiny_model_dir, load_format="dummy", dtype="float32",
+            block_size=16, max_model_len=256, max_num_seqs=8,
+            swap_space=0.01, disable_log_requests=True))
+        await _generate_all(engine, [_prompt(0)], "warm")
+        tracer = engine.engine.tracer
+        before = {k: (tracer.seconds[k], tracer.counts[k])
+                  for k in ("async.between_steps", "engine.step")}
+        await asyncio.sleep(0.4)        # idle: the loop waits
+        outs = await _generate_all(engine, [_prompt(1), _prompt(2)], "m")
+        assert all(len(o.outputs[0].token_ids) == 6 for o in outs)
+        return {k: (tracer.seconds[k] - s, tracer.counts[k] - c)
+                for k, (s, c) in before.items()}
+
+    grew = asyncio.run(go())
+    rounds = grew["engine.step"][1]
+    assert rounds >= 6
+    # one span between two steps, and one for the intake that ends an
+    # idle wait; the 0.4 s of idling are in none of them
+    assert rounds <= grew["async.between_steps"][1] <= rounds + 2
+    assert grew["async.between_steps"][0] < 0.3
+    # the engine's own exporter carried them to Prometheus
+    assert _value("aphrodite:engine_rounds_total", labels) >= rounds - 1
+    assert _value("aphrodite:host_between_steps_seconds_total",
+                  labels) > 0

@@ -1,6 +1,7 @@
 """A stub of the program's OpenAI server for the harness's tests: it
 speaks `/v1/completions` (JSON and SSE with journal records),
-`/health`, `/metrics` and the profile routes, and writes a log with
+`/health`, `/metrics` and the profile routes (which it only records),
+and writes a log with
 JAX_LOG_COMPILES-style lines the first time it meets a prompt bucket.
 """
 from __future__ import annotations
@@ -14,11 +15,18 @@ from aiohttp import web
 
 class Stub:
     def __init__(self, log_path: str, token_delay: float = 0.002,
-                 chunk: int = 1, fail_every: int = 0) -> None:
+                 chunk: int = 1, fail_every: int = 0,
+                 fail_while_profiling: bool = False) -> None:
         self.log_path = log_path
         self.token_delay = token_delay
         self.chunk = chunk              # tokens per streamed chunk
         self.fail_every = fail_every    # every n-th request gets a 500
+        #: requests that arrive while the profiler runs get a 500
+        self.fail_while_profiling = fail_while_profiling
+        self.profiling = False
+        #: one (route, request body, requests open, requests served so
+        #: far) per call of a profile route
+        self.profile_calls = []
         self.inflight = 0
         #: streamed requests that wait for their first token, and the
         #: most there were at once
@@ -49,8 +57,8 @@ class Stub:
         app.router.add_get("/health", self.health)
         app.router.add_get("/metrics", self.metrics)
         app.router.add_post("/v1/completions", self.completions)
-        app.router.add_post("/start_profile", self.ok)
-        app.router.add_post("/stop_profile", self.ok)
+        app.router.add_post("/start_profile", self.profile)
+        app.router.add_post("/stop_profile", self.profile)
         self._runner = web.AppRunner(app)
         await self._runner.setup()
         site = web.TCPSite(self._runner, "127.0.0.1", 0)
@@ -61,7 +69,10 @@ class Stub:
     async def stop(self) -> None:
         await self._runner.cleanup()
 
-    async def ok(self, request):
+    async def profile(self, request):
+        self.profiling = request.path == "/start_profile"
+        self.profile_calls.append((request.path, await request.json(),
+                                   self.inflight, self.served))
         return web.json_response({"status": "ok"})
 
     async def health(self, request):
@@ -93,7 +104,8 @@ class Stub:
     async def completions(self, request):
         body = await request.json()
         self.served += 1
-        if self.fail_every and self.served % self.fail_every == 0:
+        if (self.fail_every and self.served % self.fail_every == 0) or \
+                (self.fail_while_profiling and self.profiling):
             return web.json_response({"message": "stub fault"}, status=500)
         prompt, n = body["prompt"], body["max_tokens"]
         bucket = max(16, 1 << (len(prompt) - 1).bit_length())
