@@ -216,4 +216,8 @@ class SamplingParams:
         return SamplingType.RANDOM
 
     def clone(self) -> "SamplingParams":
-        return copy.deepcopy(self)
+        new = copy.deepcopy(self)
+        # The sampler's cached knob row (sampling_metadata.knob_row)
+        # belongs to the object it was computed from.
+        new.__dict__.pop("_knob_row", None)
+        return new

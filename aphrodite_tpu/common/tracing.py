@@ -25,9 +25,10 @@ from typing import Callable, Dict
 
 from jax.profiler import TraceAnnotation
 
-#: Every span, and the two events that are counted but not timed as a
-#: span (`Tracer.add`): a request's wait from arrival to the round that
-#: first schedules it, and a preemption.
+#: Every span, and the three events that are counted but not timed as
+#: a span (`Tracer.add`): a request's wait from arrival to the round
+#: that first schedules it, a preemption, and a sampling plan that
+#: built and sent nothing because the batch had not changed.
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
     "engine.step",          # one AphroditeEngine.step()
@@ -41,6 +42,7 @@ NAMES = (
     "cache.kv_handoff",     # disagg: prefill pool -> decode pool
     "queue_wait",
     "preemptions",
+    "sampler.plan_reuse",
 )
 
 _clock = time.perf_counter

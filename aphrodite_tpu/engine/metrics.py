@@ -74,6 +74,16 @@ _STAGE_COUNTERS = [
     ("aphrodite:preemptions_total",
      "Preemptions of running requests (recompute and swap).",
      lambda s, c: c["preemptions"]),
+    ("aphrodite:sampler_plan_seconds_total",
+     "Seconds building the steps' sampling plans (inside the prepare "
+     "seconds).", lambda s, c: s["sampler.plan"]),
+    ("aphrodite:sampler_plans_total",
+     "Sampling plans built, one a step program enqueued.",
+     lambda s, c: c["sampler.plan"]),
+    ("aphrodite:sampler_plan_reuses_total",
+     "Sampling plans that built and sent nothing: the batch and its "
+     "parameters were the step before's.",
+     lambda s, c: c["sampler.plan_reuse"]),
 ]
 
 

@@ -157,7 +157,8 @@ class ModelRunner:
             (mesh is None or mesh.size == 1) and
             kv_cache_dtype in (jnp.bfloat16, jnp.float32) and
             page_size % 8 == 0)
-        self.sampler = Sampler(model_config.get_vocab_size())
+        self.sampler = Sampler(model_config.get_vocab_size(),
+                               put=self._dev)
         # Block-table width granularity: 8 pages at the default page 16
         # (the Pallas chunk unit), half that for 32-token pages so a
         # short context isn't rounded up to 2x its KV (decode attention
@@ -171,6 +172,9 @@ class ModelRunner:
         from aphrodite_tpu.lora.layers import LORA_A
         self.lora_buckets = [k for k, b in params.items() if LORA_A in b]
         self.lora_slot_of = None
+        # The last decode work list sent: (what it was built from, its
+        # device copy); see _send_decode_batch.
+        self._decode_work = (None, None)
 
         # One jitted program per (is_prompt, use_prefix); shape buckets
         # land in XLA's compile cache keyed by array shapes.
@@ -208,12 +212,6 @@ class ModelRunner:
             return jnp.asarray(arr)
         return jax.device_put(arr, self._input_sharding)
 
-    def _dev_tree(self, tree):
-        """Commit every leaf of a pytree (sampler knob tensors)."""
-        if self._input_sharding is None:
-            return tree
-        return jax.device_put(tree, self._input_sharding)
-
     def _mesh_ctx(self):
         """Context every jitted dispatch runs under: the mesh (so the
         layer annotations' bare PartitionSpecs resolve at trace time),
@@ -223,39 +221,56 @@ class ModelRunner:
 
     # ---- jitted bodies ----
 
+    @staticmethod
+    def _unpacked(input_ids, positions, metadata):
+        """A decode batch reaches the program as ONE int32 array
+        (`_send_decode_batch`), in the place of its block tables: each
+        row's token, position, slot, context length, then its table.
+        The program slices the columns."""
+        if metadata.slot_mapping is not None:
+            return input_ids, positions, metadata
+        rows = metadata.block_tables
+        return rows[:, 0:1], rows[:, 1:2], metadata.replace(
+            slot_mapping=rows[:, 2], context_lens=rows[:, 3],
+            block_tables=rows[:, 4:])
+
+    def _logits(self, params, input_ids, positions, kv_caches, metadata,
+                sel_indices):
+        """The model step and the logits of the sampled rows (every row
+        of a decode batch: it sends no `sel_indices`)."""
+        input_ids, positions, metadata = self._unpacked(
+            input_ids, positions, metadata)
+        hidden, new_caches = self.model(params, input_ids, positions,
+                                        kv_caches, metadata)
+        rows = hidden.reshape(-1, hidden.shape[-1])
+        if sel_indices is not None:
+            rows = jnp.take(rows, sel_indices, axis=0)
+        return self.model.compute_logits(params, rows), new_caches
+
     def _step(self, params, input_ids, positions, kv_caches, metadata,
               sel_indices, *, is_prompt: bool, use_prefix: bool):
         meta = metadata.replace(is_prompt=is_prompt, use_prefix=use_prefix)
-        hidden, new_caches = self.model(params, input_ids, positions,
-                                        kv_caches, meta)
-        flat = hidden.reshape(-1, hidden.shape[-1])
-        rows = jnp.take(flat, sel_indices, axis=0)
-        logits = self.model.compute_logits(params, rows)
-        return logits, new_caches
+        return self._logits(params, input_ids, positions, kv_caches, meta,
+                            sel_indices)
 
     def _step_sample(self, params, input_ids, positions, kv_caches,
-                     metadata, sel_indices, tensors, bases, salt1,
-                     salt2, *, is_prompt: bool, use_prefix: bool,
-                     max_best_of: int, num_topk: int):
+                     metadata, sel_indices, tensors, key_parts, *,
+                     is_prompt: bool, use_prefix: bool, max_best_of: int,
+                     num_topk: int):
         """_step with the fused sampler in the same program (fast path:
         no host logits processors, no logprob requests)."""
         meta = metadata.replace(is_prompt=is_prompt,
                                 use_prefix=use_prefix)
-        hidden, new_caches = self.model(params, input_ids, positions,
-                                        kv_caches, meta)
-        flat = hidden.reshape(-1, hidden.shape[-1])
-        rows = jnp.take(flat, sel_indices, axis=0)
-        logits = self.model.compute_logits(params, rows)
+        logits, new_caches = self._logits(params, input_ids, positions,
+                                          kv_caches, meta, sel_indices)
         packed, _ = fused_sample(
-            logits, tensors, bases, salt1, salt2,
-            max_best_of=max_best_of, num_topk=num_topk,
-            need_logprobs=False)
+            logits, tensors, key_parts, max_best_of=max_best_of,
+            num_topk=num_topk, need_logprobs=False)
         return packed, new_caches
 
     def _burst_step(self, params, input_ids, positions, kv_caches,
-                    metadata, tensors, bases, salt1, salt2, greedy_mask,
-                    pos_cap, step_salt, *, max_best_of: int,
-                    num_topk: int):
+                    metadata, tensors, key_parts, greedy_mask, pos_cap,
+                    step_salt, *, max_best_of: int, num_topk: int):
         """One multi-step-decode iteration, fully on device: model step,
         fused sampling, and next-step input computation (token feedback,
         advanced positions/slots from the block table) — so K iterations
@@ -272,7 +287,7 @@ class ModelRunner:
         flat = hidden.reshape(-1, hidden.shape[-1])
         logits = self.model.compute_logits(params, flat)
         packed, _ = fused_sample(
-            logits, tensors, bases, salt1 + step_salt, salt2,
+            logits, tensors, key_parts.at[:, 1].add(step_salt),
             max_best_of=max_best_of, num_topk=num_topk,
             need_logprobs=False)
         next_tok = jnp.where(greedy_mask, packed[:, 0], packed[:, 1])
@@ -293,9 +308,8 @@ class ModelRunner:
         return packed, next_ids, next_pos, next_meta, new_caches
 
     def _burst_scan(self, params, input_ids, positions, kv_caches,
-                    metadata, tensors, bases, salt1, salt2, greedy_mask,
-                    pos_cap, *, num_steps: int, max_best_of: int,
-                    num_topk: int):
+                    metadata, tensors, key_parts, greedy_mask, pos_cap,
+                    *, num_steps: int, max_best_of: int, num_topk: int):
         """The whole K-step decode burst as ONE compiled program
         (lax.scan over _burst_step): K separate step dispatches each
         pay a dispatch and a host sync, one scan dispatch pays them
@@ -303,14 +317,14 @@ class ModelRunner:
         def body(carry, t):
             ids, pos, meta, kv = carry
             packed, ids, pos, meta, kv = self._burst_step(
-                params, ids, pos, kv, meta, tensors, bases, salt1,
-                salt2, greedy_mask, pos_cap, t,
+                params, ids, pos, kv, meta, tensors, key_parts,
+                greedy_mask, pos_cap, t,
                 max_best_of=max_best_of, num_topk=num_topk)
             return (ids, pos, meta, kv), packed
 
         (_, _, _, kv_caches), packed = jax.lax.scan(
-            body, (input_ids, positions, metadata, kv_caches),
-            jnp.arange(num_steps, dtype=jnp.int32))
+            body, self._unpacked(input_ids, positions, metadata) +
+            (kv_caches,), jnp.arange(num_steps, dtype=jnp.int32))
         return packed, kv_caches
 
     def _copy_blocks(self, kv_caches, src, dst):
@@ -516,8 +530,6 @@ class ModelRunner:
             seq_groups=seq_groups,
             seq_data=seq_data_map,
             prompt_lens=prompt_lens,
-            selected_token_indices=jnp.asarray(selected, dtype=jnp.int32),
-            categorized_sample_indices={},
             prompt_offsets=prompt_offsets,
         )
         # Pad sel to a bucket so the jitted step's shape is stable
@@ -528,6 +540,7 @@ class ModelRunner:
         sel[:num_rows] = selected
         inputs = dict(input_ids=self._dev(ids), positions=self._dev(pos),
                       metadata=metadata, sel=self._dev(sel),
+                      padded_batch=padded_batch, sample_rows=padded_rows,
                       num_rows=num_rows,
                       is_prompt=True, use_prefix=use_prefix,
                       newly_computed=newly_computed)
@@ -577,27 +590,6 @@ class ModelRunner:
                 ctx_list.append(ctx)
                 tables_list.append(table)
 
-        batch = len(tokens)
-        padded_batch = _bucket(batch, _DECODE_BATCH_BUCKETS)
-        max_pages = max(len(t) for t in tables_list)
-        max_pages = -(-max_pages // self.pages_bucket) * \
-            self.pages_bucket
-
-        ids = np.zeros((padded_batch, 1), dtype=np.int32)
-        pos_arr = np.zeros((padded_batch, 1), dtype=np.int32)
-        slots = np.full((padded_batch,), self.num_slots, dtype=np.int32)
-        ctx_lens = np.zeros((padded_batch,), dtype=np.int32)
-        num_pages_oob = self.num_slots // self.page_size
-        tables = np.full((padded_batch, max_pages), num_pages_oob,
-                         dtype=np.int32)
-
-        ids[:batch, 0] = tokens
-        pos_arr[:batch, 0] = positions
-        slots[:batch] = slot_list
-        ctx_lens[:batch] = ctx_list
-        for i, t in enumerate(tables_list):
-            tables[i, :len(t)] = t
-
         # The pipelined decode page-writer (kv_write.py distinct_pages)
         # prefetches cell i+1's page before cell i's writeback lands, so
         # two tokens on one page would silently lose a write. CoW in
@@ -610,6 +602,41 @@ class ModelRunner:
                 "decode slots share a page — sequence-exclusive-pages "
                 f"precondition violated: {sorted(written)}")
 
+        inputs = self._send_decode_batch(tokens, positions, slot_list,
+                                         ctx_list, tables_list)
+        sampling = SamplingMetadata(
+            seq_groups=seq_groups,
+            seq_data=seq_data_map,
+            prompt_lens=[],
+            persistent_metadata=PersistentMetadata(persistent),
+        )
+        return inputs, sampling
+
+    def _send_decode_batch(self, tokens, positions, slot_list, ctx_list,
+                           tables_list, spec_verify: bool = False) -> dict:
+        """Pad a decode (or verify) batch to its buckets and send it:
+        ONE [padded_batch, 4 + pages] int32 array (each row's token,
+        position, slot, context length, then its block table; pad rows
+        hold the out-of-range slot and page, so the cache scatter drops
+        them; `_unpacked` slices it inside the program) and the ragged
+        work list, whose device copy is kept while no row's chunk count
+        changes. Every row is sampled, so there is no `sel`."""
+        batch = len(tokens)
+        padded_batch = _bucket(batch, _DECODE_BATCH_BUCKETS)
+        max_pages = max(len(t) for t in tables_list)
+        max_pages = -(-max_pages // self.pages_bucket) * \
+            self.pages_bucket
+
+        rows = np.zeros((padded_batch, 4 + max_pages), dtype=np.int32)
+        rows[:, 2] = self.num_slots
+        rows[:, 4:] = self.num_slots // self.page_size
+        rows[:batch, 0] = tokens
+        rows[:batch, 1] = positions
+        rows[:batch, 2] = slot_list
+        rows[:batch, 3] = ctx_list
+        for i, t in enumerate(tables_list):
+            rows[i, 4:4 + len(t)] = t
+
         # Ragged decode work list: flatten (sequence, chunk) pairs over
         # each row's REAL reserved pages so the attention grid has no
         # padded cells for short contexts (the classic grid pads every
@@ -621,7 +648,7 @@ class ModelRunner:
                                      padded_batch)
         page_counts = [len(t) for t in tables_list] + \
             [0] * (padded_batch - batch)
-        nw_real = sum(max(1, -(-c // ppc)) for c in page_counts)
+        chunks = tuple(max(1, -(-c // ppc)) for c in page_counts)
         # Pad the list to padded_batch * 2^k (clamped to the dense cell
         # count): each (batch, pages) bucket then exposes only a few
         # possible work-list lengths, so a fluctuating serving mix
@@ -629,38 +656,29 @@ class ModelRunner:
         # without issuing DMAs.
         chunks_cap = -(-max_pages // ppc)
         mix = 1
-        while padded_batch * mix < nw_real:
+        while padded_batch * mix < sum(chunks):
             mix *= 2
-        wi_seq, wi_chunk = build_decode_work_list(
-            page_counts, ppc,
-            pad_to=padded_batch * min(mix, chunks_cap))
+        work_key = (chunks, ppc, padded_batch * min(mix, chunks_cap))
+        if self._decode_work[0] != work_key:
+            wi_seq, wi_chunk = build_decode_work_list(
+                page_counts, ppc, pad_to=work_key[2])
+            self._decode_work = (work_key, (self._dev(wi_seq),
+                                            self._dev(wi_chunk)))
 
         metadata = InputMetadata(
-            slot_mapping=self._dev(slots),
-            block_tables=self._dev(tables),
-            context_lens=self._dev(ctx_lens),
+            slot_mapping=None,
+            block_tables=self._dev(rows),
+            context_lens=None,
             kv_scale=self.kv_scale,
             tp=self._tp,
-            decode_work=(self._dev(wi_seq), self._dev(wi_chunk)),
+            decode_work=self._decode_work[1],
             decode_ppc=ppc,
+            spec_verify=spec_verify,
         )
-        sampling = SamplingMetadata(
-            seq_groups=seq_groups,
-            seq_data=seq_data_map,
-            prompt_lens=[],
-            selected_token_indices=jnp.arange(batch, dtype=jnp.int32),
-            categorized_sample_indices={},
-            persistent_metadata=PersistentMetadata(persistent),
-        )
-        # sel covers the whole padded batch (stable shape per bucket);
-        # pad rows are sliced off before sampling.
-        inputs = dict(input_ids=self._dev(ids),
-                      positions=self._dev(pos_arr), metadata=metadata,
-                      sel=self._dev(np.arange(padded_batch,
-                                              dtype=np.int32)),
-                      num_rows=batch,
-                      is_prompt=False, use_prefix=False)
-        return inputs, sampling
+        return dict(input_ids=None, positions=None, metadata=metadata,
+                    sel=None, padded_batch=padded_batch,
+                    sample_rows=padded_batch, num_rows=batch,
+                    is_prompt=False, use_prefix=False)
 
     # ---- public API ----
 
@@ -715,13 +733,13 @@ class ModelRunner:
                 ]
 
             params = self._params_with_lora(
-                seq_group_metadata_list, inputs["input_ids"].shape[0],
+                seq_group_metadata_list, inputs["padded_batch"],
                 rows_per_group)
 
             has_processors = any(
                 p.logits_processors for _, p in sampling.seq_groups)
             plan = None if has_processors else \
-                self._plan(sampling, inputs["sel"].shape[0])
+                self._plan(sampling, inputs["sample_rows"])
 
         # The fused program's sampler statics stay PINNED at the
         # serving default (best_of=1, no top-k logprobs): a varying
@@ -750,10 +768,7 @@ class ModelRunner:
                 return output, kv_caches
             with self.tracer.span("runner.dispatch"), self._mesh_ctx():
                 packed, logprobs_dev = _fused_sample_jit(
-                    logits, self._dev_tree(plan.tensors),
-                    self._dev(np.asarray(plan.bases)),
-                    self._dev(np.asarray(plan.salt1)),
-                    self._dev(np.asarray(plan.salt2)),
+                    logits, plan.tensors, plan.key_parts,
                     max_best_of=plan.max_best_of,
                     num_topk=plan.num_topk,
                     need_logprobs=plan.need_logprobs)
@@ -770,10 +785,7 @@ class ModelRunner:
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
-                self._dev_tree(plan.tensors),
-                self._dev(np.asarray(plan.bases)),
-                self._dev(np.asarray(plan.salt1)),
-                self._dev(np.asarray(plan.salt2)),
+                plan.tensors, plan.key_parts,
                 is_prompt=inputs["is_prompt"],
                 use_prefix=inputs["use_prefix"],
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
@@ -785,9 +797,14 @@ class ModelRunner:
                                            None)
         return output, kv_caches
 
-    def _plan(self, sampling: SamplingMetadata, pad_to: int):
+    def _plan(self, sampling: SamplingMetadata, pad_to: int,
+              salt_offsets: Optional[np.ndarray] = None):
         with self.tracer.span("sampler.plan"):
-            return self.sampler.plan(sampling, pad_to=pad_to)
+            plan = self.sampler.plan(sampling, pad_to=pad_to,
+                                     salt_offsets=salt_offsets)
+        if plan.reused:
+            self.tracer.add("sampler.plan_reuse")
+        return plan
 
     def dispatch_prompt(
         self,
@@ -803,21 +820,18 @@ class ModelRunner:
                 seq_group_metadata_list)
             if any(p.logits_processors for _, p in sampling.seq_groups):
                 return None, kv_caches
-            plan = self._plan(sampling, inputs["sel"].shape[0])
+            plan = self._plan(sampling, inputs["sample_rows"])
             if plan.need_logprobs or plan.max_best_of != 1 or \
                     plan.num_topk != 0:
                 return None, kv_caches
             params = self._params_with_lora(
-                seq_group_metadata_list, inputs["input_ids"].shape[0],
+                seq_group_metadata_list, inputs["padded_batch"],
                 [1] * len(seq_group_metadata_list))
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
-                self._dev_tree(plan.tensors),
-                self._dev(np.asarray(plan.bases)),
-                self._dev(np.asarray(plan.salt1)),
-                self._dev(np.asarray(plan.salt2)), is_prompt=True,
+                plan.tensors, plan.key_parts, is_prompt=True,
                 use_prefix=inputs["use_prefix"],
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
         self._mark_prefixes(inputs)
@@ -868,7 +882,7 @@ class ModelRunner:
         """Enqueue the K-step decode burst without syncing."""
         with self.tracer.span("runner.prepare"):
             inputs, sampling = self._prepare_decode(seq_group_metadata_list)
-            padded = inputs["input_ids"].shape[0]
+            padded = inputs["padded_batch"]
             rows_per_group = [
                 len(md.seq_data) for md in seq_group_metadata_list
             ]
@@ -896,17 +910,13 @@ class ModelRunner:
                     pos_cap[row, 0] = data.get_len() - 1 + r
                     row += 1
             greedy_mask = self._dev(greedy)
-            tensors = self._dev_tree(plan.tensors)
-            bases = self._dev(np.asarray(plan.bases))
-            salt1 = self._dev(np.asarray(plan.salt1))
-            salt2 = self._dev(np.asarray(plan.salt2))
 
             ids, pos, meta = (inputs["input_ids"], inputs["positions"],
                               inputs["metadata"])
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._burst_scan_fn(
-                params, ids, pos, kv_caches, meta, tensors, bases,
-                salt1, salt2, greedy_mask, self._dev(pos_cap),
+                params, ids, pos, kv_caches, meta, plan.tensors,
+                plan.key_parts, greedy_mask, self._dev(pos_cap),
                 num_steps=num_steps, max_best_of=plan.max_best_of,
                 num_topk=plan.num_topk)
         return StepHandle(packed, sampling, plan,
@@ -961,27 +971,6 @@ class ModelRunner:
                 tables_list.append(table)
                 row_offsets.append(j)
 
-        batch = len(tokens)
-        padded_batch = _bucket(batch, _DECODE_BATCH_BUCKETS)
-        max_pages = max(len(t) for t in tables_list)
-        max_pages = -(-max_pages // self.pages_bucket) * \
-            self.pages_bucket
-
-        ids = np.zeros((padded_batch, 1), dtype=np.int32)
-        pos_arr = np.zeros((padded_batch, 1), dtype=np.int32)
-        slots = np.full((padded_batch,), self.num_slots, dtype=np.int32)
-        ctx_lens = np.zeros((padded_batch,), dtype=np.int32)
-        num_pages_oob = self.num_slots // self.page_size
-        tables = np.full((padded_batch, max_pages), num_pages_oob,
-                         dtype=np.int32)
-
-        ids[:batch, 0] = tokens
-        pos_arr[:batch, 0] = positions
-        slots[:batch] = slot_list
-        ctx_lens[:batch] = ctx_list
-        for i, t in enumerate(tables_list):
-            tables[i, :len(t)] = t
-
         # Verify rows legitimately SHARE pages (consecutive positions
         # of one sequence); the decode invariant that still holds is
         # slot-exclusivity, which the XLA scatter needs.
@@ -990,43 +979,15 @@ class ModelRunner:
                 "spec verify rows share a KV slot: "
                 f"{sorted(slot_list)}")
 
-        ppc = choose_pages_per_chunk(max_pages, self.page_size,
-                                     padded_batch)
-        page_counts = [len(t) for t in tables_list] + \
-            [0] * (padded_batch - batch)
-        nw_real = sum(max(1, -(-c // ppc)) for c in page_counts)
-        chunks_cap = -(-max_pages // ppc)
-        mix = 1
-        while padded_batch * mix < nw_real:
-            mix *= 2
-        wi_seq, wi_chunk = build_decode_work_list(
-            page_counts, ppc,
-            pad_to=padded_batch * min(mix, chunks_cap))
-
-        metadata = InputMetadata(
-            slot_mapping=self._dev(slots),
-            block_tables=self._dev(tables),
-            context_lens=self._dev(ctx_lens),
-            kv_scale=self.kv_scale,
-            tp=self._tp,
-            decode_work=(self._dev(wi_seq), self._dev(wi_chunk)),
-            decode_ppc=ppc,
-            spec_verify=True,
-        )
+        inputs = self._send_decode_batch(tokens, positions, slot_list,
+                                         ctx_list, tables_list,
+                                         spec_verify=True)
         sampling = SamplingMetadata(
             seq_groups=seq_groups,
             seq_data=seq_data_map,
             prompt_lens=[],
-            selected_token_indices=jnp.arange(batch, dtype=jnp.int32),
-            categorized_sample_indices={},
             persistent_metadata=PersistentMetadata(persistent),
         )
-        inputs = dict(input_ids=self._dev(ids),
-                      positions=self._dev(pos_arr), metadata=metadata,
-                      sel=self._dev(np.arange(padded_batch,
-                                              dtype=np.int32)),
-                      num_rows=batch,
-                      is_prompt=False, use_prefix=False)
         return inputs, sampling, row_offsets, rows_per_group
 
     def execute_spec_verify(
@@ -1052,29 +1013,22 @@ class ModelRunner:
             inputs, sampling, row_offsets, rows_per_group = \
                 self._prepare_spec_verify(seq_group_metadata_list,
                                           drafts)
-            padded = inputs["input_ids"].shape[0]
+            padded = inputs["padded_batch"]
             params = self._params_with_lora(seq_group_metadata_list,
                                             padded, rows_per_group)
-            plan = self._plan(sampling, padded)
-            assert plan.max_best_of == 1 and plan.num_topk == 0 and \
-                not plan.need_logprobs, "spec verify eligibility broken"
-
             # The acceptance rule consumes salts per OUTPUT POSITION:
             # row j of a sequence gets salt1 = output_len + j, exactly
             # the salt the classic path uses when it reaches that
-            # position (plan salts are host numpy until this point, so
-            # the offset is a plain in-place add).
-            salt1 = np.asarray(plan.salt1, dtype=np.int32).copy()
-            salt1[:len(row_offsets)] += np.asarray(row_offsets,
-                                                   dtype=np.int32)
+            # position.
+            plan = self._plan(sampling, padded, salt_offsets=np.asarray(
+                row_offsets, dtype=np.int32))
+            assert plan.max_best_of == 1 and plan.num_topk == 0 and \
+                not plan.need_logprobs, "spec verify eligibility broken"
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
                 kv_caches, inputs["metadata"], inputs["sel"],
-                self._dev_tree(plan.tensors),
-                self._dev(np.asarray(plan.bases)),
-                self._dev(salt1),
-                self._dev(np.asarray(plan.salt2)),
+                plan.tensors, plan.key_parts,
                 is_prompt=False, use_prefix=False,
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
         with self.tracer.span("runner.device_wait"):

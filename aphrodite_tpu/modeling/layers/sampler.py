@@ -15,6 +15,19 @@ reproducible regardless of batch composition. The only host work is
 ragged per-group assembly of SequenceGroupOutputs (beam search included),
 as in the reference.
 
+A step's plan (`Sampler.plan`) is what reaches the device for it: the
+packed knobs (`SamplingTensors.knobs`, one transfer), the PRNG key parts
+(`[rows, 3]` int32: base, output-position salt, sibling salt; one
+transfer), and token histories and bans only when a gate reads them.
+The sampler keeps the last plan. While the next step has the same rows
+(sequence ids, the same `SamplingParams` objects, prompt lengths) under
+the same `pad_to`, the knobs on the device are handed to the program
+again, and only what changes from step to step is built and sent: the
+`miro_mus` column of a batch with mirostat rows (so such a batch sends
+its knobs each step), histories and bans, and the key parts of a batch
+with rows that draw. A batch with none of these (all greedy: a greedy
+row's draw is discarded, so its key is one constant) sends nothing.
+
 Numerical notes: the pipeline runs in float32; stage formulas match the
 reference exactly (mirostat surprise in bits, eta/epsilon scaled by 1e-4,
 dynatemp entropy normalization).
@@ -33,9 +46,12 @@ from aphrodite_tpu.common.sampling_params import (SamplingParams,
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupOutput,
                                            SequenceOutput)
-from aphrodite_tpu.modeling.sampling_metadata import (SamplingMetadata,
+from aphrodite_tpu.modeling.sampling_metadata import (MU_COLUMN,
+                                                      SamplingMetadata,
                                                       SamplingTensors,
-                                                      build_sampling_tensors)
+                                                      build_knobs,
+                                                      build_token_lists,
+                                                      gates_of)
 
 _NEG_INF = float("-inf")
 
@@ -100,7 +116,7 @@ def _apply_alphabet_soup(logits, t: SamplingTensors) -> jax.Array:
     mask = probs_sort < threshold
     mask |= probs_cum > t.top_ps[:, None]
     positions = jnp.arange(logits.shape[-1])[None, :]
-    mask |= positions >= t.top_ks[:, None]
+    mask |= positions >= t.top_ks.astype(jnp.int32)[:, None]
     mask = mask.at[:, 0].set(False)     # always keep the argmax
 
     sorted_logits = jnp.where(mask, _NEG_INF, sorted_logits)
@@ -282,9 +298,8 @@ def _make_row_keys(bases: jax.Array, salt1: jax.Array,
     return make(bases, salt1, salt2)
 
 
-def fused_sample(logits: jax.Array, t: SamplingTensors, bases: jax.Array,
-                 salt1: jax.Array, salt2: jax.Array, *, max_best_of: int,
-                 num_topk: int, need_logprobs: bool):
+def fused_sample(logits: jax.Array, t: SamplingTensors, key_parts: jax.Array,
+                 *, max_best_of: int, num_topk: int, need_logprobs: bool):
     """The whole device-side sampling step — key building, the logits
     pipeline, and token selection — packed into ONE int32 result array so
     the host needs exactly one blocking transfer per engine step (the
@@ -299,11 +314,12 @@ def fused_sample(logits: jax.Array, t: SamplingTensors, bases: jax.Array,
       [W+1+B : W+1+B+K]  top-k logprob values }
       [-1]               updated mirostat mu  }
 
-    with W = 1+B+K. Full [rows, vocab] logprobs are returned only when
+    with W = 1+B+K. `key_parts` [rows, 3] holds each row's (base, salt1,
+    salt2). Full [rows, vocab] logprobs are returned only when
     `need_logprobs` (beam search / prompt_logprobs), and stay on device.
     Callable inside an outer jit or via `_fused_sample_jit`.
     """
-    keys = _make_row_keys(bases, salt1, salt2)
+    keys = _make_row_keys(key_parts[:, 0], key_parts[:, 1], key_parts[:, 2])
     processed, new_mus = _process_logits(logits, t, keys)
     greedy, random, lp_greedy, lp_random, topk_vals, topk_idx, logprobs = \
         _sample_tokens(processed, keys, max_best_of, num_topk)
@@ -329,34 +345,54 @@ _fused_sample_jit = jax.jit(
 
 class SamplePlan:
     """Host-side bookkeeping for one sampling step, shared between the
-    device dispatch (`fused_sample` args) and `finalize`."""
+    device dispatch (`fused_sample` args: `tensors` and `key_parts`, on
+    the device already) and `finalize`. `reused` says that the step
+    built and sent nothing."""
 
-    __slots__ = ("tensors", "bases", "salt1", "salt2", "max_best_of",
-                 "num_topk", "need_logprobs", "num_rows", "row_to_seq",
-                 "group_of")
+    __slots__ = ("tensors", "key_parts", "max_best_of", "num_topk",
+                 "need_logprobs", "num_rows", "miro_rows", "reused")
 
-    def __init__(self, tensors, bases, salt1, salt2, max_best_of,
-                 num_topk, need_logprobs, num_rows, row_to_seq, group_of):
+    def __init__(self, tensors, key_parts, max_best_of, num_topk,
+                 need_logprobs, num_rows, miro_rows, reused):
         self.tensors = tensors
-        self.bases = bases
-        self.salt1 = salt1
-        self.salt2 = salt2
+        self.key_parts = key_parts
         self.max_best_of = max_best_of
         self.num_topk = num_topk
         self.need_logprobs = need_logprobs
         self.num_rows = num_rows
-        self.row_to_seq = row_to_seq
-        self.group_of = group_of
+        self.miro_rows = miro_rows
+        self.reused = reused
+
+
+class _RowsPlan:
+    """What `Sampler.plan` keeps of the last step: all of a plan that
+    is a function of the step's rows alone, on the host and on the
+    device. `signature` says which rows; `groups` keeps their
+    `SamplingParams` alive, so that an identity in the signature stays
+    that object's."""
+
+    __slots__ = ("signature", "groups", "knobs", "mask", "row_info",
+                 "tensors", "key_parts", "draws", "seeded", "miro_rows",
+                 "max_best_of", "num_topk", "need_logprobs")
 
 
 class Sampler:
     """Host orchestrator: tensorize knobs, run the jitted pipeline, and
     assemble per-group outputs (greedy/random/beam) like the reference
-    `_sample` + `_get_logprobs` (`sampler.py:545-650`)."""
+    `_sample` + `_get_logprobs` (`sampler.py:545-650`). `put` is the
+    host-to-device transfer (the model runner's, which commits to its
+    mesh)."""
 
-    def __init__(self, vocab_size: int) -> None:
+    def __init__(self, vocab_size: int, put=jnp.asarray) -> None:
+        if vocab_size >= 1 << 24:
+            raise ValueError("top_k rides in a float32 knob column, exact "
+                             f"below 2**24; vocabulary {vocab_size}")
         self.vocab_size = vocab_size
+        self._put = put
         self._step = 0
+        self._last: Optional[_RowsPlan] = None
+        # The key parts of a batch in which no row draws, by row count.
+        self._zero_keys: Dict[int, jax.Array] = {}
         # Process entropy so unseeded sampling differs across restarts
         # (seeded requests are unaffected: their keys derive from the
         # request seed only).
@@ -370,43 +406,104 @@ class Sampler:
         logits = self._apply_logits_processors(logits, metadata)
         plan = self.plan(metadata)
         packed, logprobs = _fused_sample_jit(
-            logits, plan.tensors, jnp.asarray(plan.bases),
-            jnp.asarray(plan.salt1), jnp.asarray(plan.salt2),
+            logits, plan.tensors, plan.key_parts,
             max_best_of=plan.max_best_of, num_topk=plan.num_topk,
             need_logprobs=plan.need_logprobs)
         return self.finalize(metadata, plan, np.asarray(packed), logprobs)
 
     def plan(self, metadata: SamplingMetadata,
-             pad_to: Optional[int] = None) -> SamplePlan:
-        """Build the host-side step plan: device knob tensors (padded to
-        the program's row bucket), PRNG key parts, and static shapes."""
-        tensors, row_to_seq = build_sampling_tensors(
-            metadata, self.vocab_size, pad_to=pad_to)
-        num_rows = len(row_to_seq)
-        rows = tensors.temperatures.shape[0]
+             pad_to: Optional[int] = None,
+             salt_offsets: Optional[np.ndarray] = None) -> SamplePlan:
+        """Build the step plan: the knob tensors (padded to the
+        program's row bucket) and PRNG key parts on the device, and the
+        static shapes. What the rows alone decide is kept from the step
+        before while the rows are the same; see the module docstring
+        for what is sent when. `salt_offsets` [n] is added to the
+        output-position salt of the first n rows (speculative verify:
+        row j of a sequence samples for position output_len + j)."""
         self._step += 1
-        group_of = self._seq_to_group(metadata)
-        bases, salt1, salt2 = self._key_parts(metadata, rows, row_to_seq,
-                                              group_of)
-        max_best_of = max([1] + [
-            p.best_of for (_, p) in metadata.seq_groups
+        signature = (pad_to, tuple(metadata.prompt_lens), tuple(
+            (tuple(seq_ids), id(p)) for seq_ids, p in metadata.seq_groups))
+        last = self._last
+        same_rows = last is not None and last.signature == signature
+        if not same_rows:
+            last = self._last = self._rows_plan(metadata, pad_to,
+                                                signature)
+        tensors, key_parts = last.tensors, last.key_parts
+        if last.miro_rows:
+            knobs = last.knobs.copy()
+            for row, seq_id, first_mu in last.miro_rows:
+                knobs[row, MU_COLUMN] = metadata.persistent_metadata.get(
+                    seq_id).get("miro_mu", first_mu)
+            tensors = tensors.replace(knobs=self._put(knobs))
+        lists = build_token_lists(metadata, self.vocab_size, last.mask,
+                                  len(last.knobs), last.row_info)
+        if lists:
+            tensors = tensors.replace(
+                **{name: self._put(arr) for name, arr in lists.items()})
+        if last.draws:
+            key_parts = self._put(
+                self._key_parts(metadata, last, salt_offsets))
+        reused = same_rows and tensors is last.tensors and \
+            key_parts is last.key_parts
+        return SamplePlan(tensors, key_parts, last.max_best_of,
+                          last.num_topk, last.need_logprobs,
+                          len(last.row_info), last.miro_rows, reused)
+
+    def _rows_plan(self, metadata: SamplingMetadata,
+                   pad_to: Optional[int], signature: tuple) -> _RowsPlan:
+        """The part of a plan that the rows decide, built from the
+        cached knob rows of their `SamplingParams`; the knobs go to the
+        device here unless a mirostat row makes them the step's."""
+        last = _RowsPlan()
+        last.signature, last.groups = signature, metadata.seq_groups
+        last.knobs, last.mask, last.row_info = build_knobs(
+            metadata, self.vocab_size, pad_to)
+        rows = len(last.knobs)
+        params = [p for _, p in metadata.seq_groups]
+        # tau/eta/mu are per row; a first step starts mu at 2 tau.
+        last.miro_rows = [
+            (row, seq_id, 2.0 * p.mirostat_tau)
+            for row, (seq_id, p, _) in enumerate(last.row_info)
+            if p.mirostat_mode == 2]
+        # A greedy or beam row's draw is discarded by `_assemble`; a
+        # mirostat row samples inside the pipeline whatever its type.
+        last.draws = bool(last.miro_rows) or any(
+            p.sampling_type == SamplingType.RANDOM for p in params)
+        seeded = [(row, seq_id, p.seed, sibling)
+                  for row, (seq_id, p, sibling) in enumerate(last.row_info)
+                  if p.seed is not None]
+        last.seeded = None
+        if seeded and last.draws:
+            at, seq_ids, seeds, siblings = zip(*seeded)
+            # wrapped to 32 bits, as a 64-bit seed always reached the
+            # device
+            last.seeded = (np.asarray(at), seq_ids, np.asarray(
+                seeds, dtype=np.int64).astype(np.int32), siblings)
+        last.tensors = SamplingTensors(
+            knobs=None if last.miro_rows else self._put(last.knobs),
+            **gates_of(last.mask))
+        last.key_parts = None
+        if not last.draws:
+            if rows not in self._zero_keys:
+                self._zero_keys[rows] = self._put(
+                    np.zeros((rows, 3), dtype=np.int32))
+            last.key_parts = self._zero_keys[rows]
+        last.max_best_of = max([1] + [
+            p.best_of for p in params
             if p.sampling_type == SamplingType.RANDOM
         ])
-        max_logprobs = max([0] + [
-            min(p.logprobs or 0, self.vocab_size - 1)
-            for (_, p) in metadata.seq_groups
+        last.num_topk = max([0] + [
+            min(p.logprobs or 0, self.vocab_size - 1) for p in params
         ] + [
             min(p.prompt_logprobs or 0, self.vocab_size - 1)
-            for (_, p) in metadata.seq_groups
+            for p in params
         ])
-        need_logprobs = any(
+        last.need_logprobs = any(
             p.sampling_type == SamplingType.BEAM or
-            (p.prompt_logprobs is not None and
-             metadata.prompt_lens)
-            for (_, p) in metadata.seq_groups)
-        return SamplePlan(tensors, bases, salt1, salt2, max_best_of,
-                          max_logprobs, need_logprobs, num_rows,
-                          row_to_seq, group_of)
+            (p.prompt_logprobs is not None and metadata.prompt_lens)
+            for p in params)
+        return last
 
     def finalize(self, metadata: SamplingMetadata, plan: SamplePlan,
                  packed: np.ndarray,
@@ -425,31 +522,20 @@ class Sampler:
         lp_greedy = floats[:, 0]
         lp_random = floats[:, 1:1 + B]
         topk_vals = floats[:, 1 + B:1 + B + K]
-        if plan.tensors.do_mirostat:
+        if plan.miro_rows:
             new_mus = floats[:, 1 + B + K]
-            for row, seq_id in plan.row_to_seq.items():
-                _, params = plan.group_of.get(seq_id, (None, None))
-                if params is not None and params.mirostat_mode == 2:
-                    metadata.output_metadata.add(seq_id, "miro_mu",
-                                                 float(new_mus[row]))
+            for row, seq_id, _ in plan.miro_rows:
+                metadata.output_metadata.add(seq_id, "miro_mu",
+                                             float(new_mus[row]))
         return self._assemble(metadata, greedy, random, lp_greedy,
                               lp_random, topk_vals, topk_idx, logprobs_dev)
 
     # -- helpers --
 
-    @staticmethod
-    def _seq_to_group(metadata: SamplingMetadata) -> Dict[int, tuple]:
-        """seq_id -> (seq_ids, params), built once per step."""
-        return {
-            seq_id: (seq_ids, params)
-            for seq_ids, params in metadata.seq_groups
-            for seq_id in seq_ids
-        }
-
-    def _key_parts(self, metadata: SamplingMetadata, rows: int,
-                   row_to_seq: Dict[int, int],
-                   group_of: Dict[int, tuple]):
-        """Per-row PRNG key ingredients (folded together on device).
+    def _key_parts(self, metadata: SamplingMetadata, last: _RowsPlan,
+                   salt_offsets: Optional[np.ndarray]) -> np.ndarray:
+        """Per-row PRNG key ingredients [rows, 3] (folded together on
+        device by `_make_row_keys`).
 
         Seeded rows: base=request seed, salts=(output_len, sibling index)
         — reproducible regardless of batch composition or restarts.
@@ -457,25 +543,21 @@ class Sampler:
         the per-step salt1 offset added by decode bursts (+t) never
         collides across (row, step) diagonals.
         """
-        bases = np.empty((rows,), dtype=np.int64)
-        salt1 = np.empty((rows,), dtype=np.int32)
-        salt2 = np.empty((rows,), dtype=np.int32)
+        rows = len(last.knobs)
         step_mix = (self._base_seed ^ (self._step * 0x9E3779B1)) \
             & 0x7FFFFFFF
-        for row in range(rows):
-            seq_id = row_to_seq.get(row)
-            entry = group_of.get(seq_id) if seq_id is not None else None
-            if entry is not None and entry[1].seed is not None:
-                seq_ids, params = entry
-                bases[row] = params.seed
-                salt1[row] = len(
-                    metadata.seq_data[seq_id].output_token_ids)
-                salt2[row] = seq_ids.index(seq_id)
-            else:
-                bases[row] = (step_mix ^ (row * 0x85EBCA77)) & 0x7FFFFFFF
-                salt1[row] = 0
-                salt2[row] = 0
-        return bases, salt1, salt2
+        parts = np.zeros((rows, 3), dtype=np.int32)
+        parts[:, 0] = (step_mix ^ (np.arange(rows, dtype=np.int64) *
+                                   0x85EBCA77)) & 0x7FFFFFFF
+        if last.seeded is not None:
+            at, seq_ids, seeds, siblings = last.seeded
+            parts[at, 0] = seeds
+            parts[at, 1] = [len(metadata.seq_data[s].output_token_ids)
+                            for s in seq_ids]
+            parts[at, 2] = siblings
+        if salt_offsets is not None:
+            parts[:len(salt_offsets), 1] += salt_offsets
+        return parts
 
     def _apply_logits_processors(self, logits, metadata):
         """Host-side per-request callables (logit_bias, grammar, min-tokens

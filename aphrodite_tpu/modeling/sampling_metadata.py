@@ -1,15 +1,22 @@
-"""Per-batch sampling bookkeeping and vectorized sampler knobs.
+"""Per-batch sampling bookkeeping and the packed sampler knobs.
 
 Reference: `aphrodite/modeling/sampling_metadata.py` (SamplingMetadata
 `:30`, SamplingTensors.from_sampling_metadata `:108`, Persistent/Output
 metadata `:13-28`).
 
-Host side builds `SamplingMetadata` (Python lists, ragged); it is
-flattened once per step into `SamplingTensors` — a fixed-width struct of
-device arrays, padded to the logits row count — which the jitted sampler
-consumes. The `do_*` flags are static gates: each disables a whole
-pipeline stage at trace time when no sequence in the batch uses it, the
-same fast-path elision the reference does dynamically.
+The host builds `SamplingMetadata` (Python lists, ragged). What the
+jitted sampler consumes is `SamplingTensors`: ONE `[rows, 19]` float32
+array of scalar knobs (a column per name of `KNOB_COLUMNS`, rows padded
+to the program's row bucket with `_NEUTRAL_ROW`) and the static `do_*`
+gates, each of which removes a whole pipeline stage at trace time when
+no sequence of the batch uses it. A row of knobs and its gates are a
+function of one `SamplingParams` and the vocabulary size: `knob_row`
+computes them once per object and keeps them on it, so a step's knobs
+are a stack of cached rows (`build_knobs`). Token histories and bans
+are built (`build_token_lists`) only for a batch whose gate reads them;
+otherwise they are `None`, not arguments of the program at all.
+`Sampler.plan` (layers/sampler.py) sends the arrays and keeps the
+device copy while the batch does not change.
 
 Mirostat state (`mu`) persists across steps host-side in
 `PersistentMetadata`, round-tripping through `OutputMetadata` exactly as
@@ -17,16 +24,15 @@ the reference (`sampling_metadata.py:13-28`).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 from flax import struct
 
-from aphrodite_tpu.common.sampling_params import (SamplingParams,
-                                                  SamplingType)
+from aphrodite_tpu.common.sampling_params import SamplingParams
 from aphrodite_tpu.common.sequence import SequenceData
 
 _SAMPLING_EPS = 1e-5
@@ -56,17 +62,10 @@ class SamplingMetadata:
     seq_groups: per scheduled group, (seq_ids, sampling_params).
     seq_data: seq id -> SequenceData (for penalties' token histories).
     prompt_lens: per prompt group, the prompt length (empty for decode).
-    selected_token_indices: flat indices into the [rows, vocab] logits for
-        the tokens we sample from (last token of each prompt / each decode
-        row), reference `_prepare_sample` (`model_runner.py:372-451`).
-    categorized_sample_indices: SamplingType -> row indices within the
-        selected logits, post-selection.
     """
     seq_groups: List[Tuple[List[int], SamplingParams]]
     seq_data: Dict[int, SequenceData]
     prompt_lens: List[int]
-    selected_token_indices: jax.Array
-    categorized_sample_indices: Dict[SamplingType, List[int]]
     persistent_metadata: PersistentMetadata = field(
         default_factory=PersistentMetadata)
     output_metadata: OutputMetadata = field(default_factory=OutputMetadata)
@@ -75,36 +74,36 @@ class SamplingMetadata:
     prompt_offsets: List[int] = field(default_factory=list)
 
 
+#: The columns of `SamplingTensors.knobs`, in order. `top_ks` rides as
+#: exact small integers: float32 holds every integer up to 2**24, far
+#: past any vocabulary (`Sampler` refuses a larger one).
+KNOB_COLUMNS = (
+    "temperatures", "dynatemp_mins", "dynatemp_maxs", "dynatemp_exps",
+    "top_ps", "top_ks", "top_as", "min_ps", "tfss", "eta_cutoffs",
+    "epsilon_cutoffs", "typical_ps", "miro_taus", "miro_etas", "miro_mus",
+    "smoothing_factors", "presence_penalties", "frequency_penalties",
+    "repetition_penalties")
+MU_COLUMN = KNOB_COLUMNS.index("miro_mus")
+#: The static gates; bit i of a gate mask is GATES[i].
+GATES = ("do_penalties", "do_temperatures", "do_top_p_top_k", "do_top_as",
+         "do_min_p", "do_tfss", "do_eta_cutoffs", "do_epsilon_cutoffs",
+         "do_typical_ps", "do_quadratic", "do_mirostat", "do_token_bans")
+
+
 @struct.dataclass
 class SamplingTensors:
     """Fixed-shape device-side sampler knobs, one row per sampled token.
 
-    All arrays are [rows] or [rows, k]; token-history tensors are padded
-    with vocab_size (an out-of-range id scatter-dropped by the penalty
-    stage).
+    `knobs` is [rows, len(KNOB_COLUMNS)]; each column reads as an
+    attribute of its name (`t.top_ps`: a slice inside the program). The
+    token-history tensors are [rows, k], padded with vocab_size (an
+    out-of-range id scatter-dropped by the stage that reads them), and
+    None unless their gate is on.
     """
-    temperatures: jax.Array
-    dynatemp_mins: jax.Array
-    dynatemp_maxs: jax.Array
-    dynatemp_exps: jax.Array
-    top_ps: jax.Array
-    top_ks: jax.Array
-    top_as: jax.Array
-    min_ps: jax.Array
-    tfss: jax.Array
-    eta_cutoffs: jax.Array
-    epsilon_cutoffs: jax.Array
-    typical_ps: jax.Array
-    miro_taus: jax.Array
-    miro_etas: jax.Array
-    miro_mus: jax.Array
-    smoothing_factors: jax.Array
-    presence_penalties: jax.Array
-    frequency_penalties: jax.Array
-    repetition_penalties: jax.Array
-    prompt_tokens: jax.Array      # [rows, max_prompt_len] padded w/ vocab
-    output_tokens: jax.Array      # [rows, max_output_len] padded w/ vocab
-    banned_tokens: jax.Array      # [rows, max_bans] padded w/ vocab
+    knobs: jax.Array
+    prompt_tokens: Optional[jax.Array] = None     # do_penalties
+    output_tokens: Optional[jax.Array] = None     # do_penalties
+    banned_tokens: Optional[jax.Array] = None     # do_token_bans
     # Static gates (trace-time):
     do_penalties: bool = struct.field(pytree_node=False, default=False)
     do_temperatures: bool = struct.field(pytree_node=False, default=False)
@@ -121,207 +120,139 @@ class SamplingTensors:
     do_token_bans: bool = struct.field(pytree_node=False, default=False)
 
 
-def _pad_2d(rows: List[List[int]], pad_value: int,
-            width: Optional[int] = None) -> np.ndarray:
-    if width is None:
-        width = max(1, max((len(r) for r in rows), default=1))
-    out = np.full((len(rows), width), pad_value, dtype=np.int32)
-    for i, r in enumerate(rows):
-        n = min(len(r), width)
-        out[i, :n] = r[:n]
-    return out
+for _i, _name in enumerate(KNOB_COLUMNS):
+    setattr(SamplingTensors, _name,
+            property(lambda self, _i=_i: self.knobs[:, _i]))
 
 
-def _pow2_width(rows: List[List[int]], lo: int) -> int:
+def gates_of(mask: int) -> Dict[str, bool]:
+    return {name: bool(mask >> i & 1) for i, name in enumerate(GATES)}
+
+
+def knob_row(p: SamplingParams, vocab_size: int) -> Tuple[np.ndarray, int]:
+    """The knobs of one request as the device sees them, and the gates
+    it turns on: computed once per `SamplingParams` object and kept on
+    it (`clone()` drops the copy; the knob fields of a request are not
+    written after it is made)."""
+    cached = p.__dict__.get("_knob_row")
+    if cached is not None and cached[0] == vocab_size:
+        return cached[1], cached[2]
+    temperature = p.temperature
+    if temperature < _SAMPLING_EPS:
+        temperature = 1.0      # zero temp == greedy: no-op scaling
+    # tau/eta are zeroed unless mode==2 so the device row gate (tau > 0)
+    # agrees with the host mu write-back gate; mu is the step's.
+    is_miro = p.mirostat_mode == 2
+    row = np.array([
+        temperature, max(temperature - p.dynatemp_range, 0.0),
+        temperature + p.dynatemp_range, p.dynatemp_exponent, p.top_p,
+        vocab_size if p.top_k == -1 else min(p.top_k, vocab_size),
+        p.top_a, p.min_p, p.tfs, p.eta_cutoff, p.epsilon_cutoff,
+        p.typical_p, p.mirostat_tau if is_miro else 0.0,
+        p.mirostat_eta if is_miro else 0.0, 0.0, p.smoothing_factor,
+        p.presence_penalty, p.frequency_penalty, p.repetition_penalty,
+    ], dtype=np.float32)
+    on = dict(
+        do_penalties=abs(p.presence_penalty) >= _SAMPLING_EPS or
+        abs(p.frequency_penalty) >= _SAMPLING_EPS or
+        abs(p.repetition_penalty - 1.0) >= _SAMPLING_EPS,
+        do_temperatures=p.dynatemp_range > 0 or (
+            p.temperature >= _SAMPLING_EPS and p.temperature != 1.0),
+        do_top_p_top_k=p.top_p < 1.0 - _SAMPLING_EPS or
+        p.top_k not in (-1, vocab_size),
+        do_top_as=p.top_a > 0.0,
+        do_min_p=p.min_p > _SAMPLING_EPS,
+        do_tfss=p.tfs < 1.0 - _SAMPLING_EPS,
+        do_eta_cutoffs=p.eta_cutoff > _SAMPLING_EPS,
+        do_epsilon_cutoffs=p.epsilon_cutoff > _SAMPLING_EPS,
+        do_typical_ps=p.typical_p < 1.0 - _SAMPLING_EPS,
+        do_quadratic=p.smoothing_factor > _SAMPLING_EPS,
+        do_mirostat=is_miro,
+        do_token_bans=bool(p.custom_token_bans))
+    mask = sum(1 << i for i, name in enumerate(GATES) if on[name])
+    p.__dict__["_knob_row"] = (vocab_size, row, mask)
+    return row, mask
+
+
+@functools.lru_cache(maxsize=None)
+def _neutral_row(vocab_size: int) -> np.ndarray:
+    """A padding row: no stage changes its logits (its sampled result is
+    sliced off host-side)."""
+    neutral = dict.fromkeys(KNOB_COLUMNS, 0.0)
+    neutral.update(temperatures=1.0, dynatemp_exps=1.0, top_ps=1.0,
+                   top_ks=vocab_size, tfss=1.0, typical_ps=1.0,
+                   repetition_penalties=1.0)
+    return np.array([neutral[c] for c in KNOB_COLUMNS], dtype=np.float32)
+
+
+def build_knobs(
+    metadata: SamplingMetadata, vocab_size: int,
+    pad_to: Optional[int] = None,
+) -> Tuple[np.ndarray, int, List[Tuple[int, SamplingParams, int]]]:
+    """Stack the cached knob rows of a step's sampled rows.
+
+    Mirrors `SamplingTensors.from_sampling_metadata`
+    (`sampling_metadata.py:108-261`) incl. the prompt-logprobs row
+    expansion: when a prompt group requests prompt_logprobs, its row is
+    replicated for every prompt position.
+
+    Returns (knobs [max(rows, pad_to), K] with `miro_mus` zero, the
+    batch's gate mask, and per real row its (sequence id,
+    SamplingParams, index among its group's sequences)).
+    """
+    stack, row_info, mask = [], [], 0
+    for group_idx, (seq_ids, p) in enumerate(metadata.seq_groups):
+        row, gates = knob_row(p, vocab_size)
+        mask |= gates
+        if group_idx < len(metadata.prompt_lens) and \
+                p.prompt_logprobs is not None:
+            row_info.extend([(seq_ids[0], p, 0)] *
+                            (metadata.prompt_lens[group_idx] - 1))
+        row_info.extend((seq_id, p, sibling)
+                        for sibling, seq_id in enumerate(seq_ids))
+        stack.extend([row] * (len(row_info) - len(stack)))
+    stack.extend([_neutral_row(vocab_size)] *
+                 max(0, (pad_to or 0) - len(stack)))
+    knobs = np.stack(stack) if stack else \
+        np.zeros((0, len(KNOB_COLUMNS)), np.float32)
+    return knobs, mask, row_info
+
+
+def _pow2_width(lists: Sequence[Sequence[int]], lo: int) -> int:
     """Bucket the ragged width to a power of two so the compiled sampler
     program's shape is stable as histories grow step to step."""
-    need = max((len(r) for r in rows), default=1)
+    need = max((len(r) for r in lists), default=1)
     w = lo
     while w < need:
         w *= 2
     return w
 
 
-def build_sampling_tensors(
-    metadata: SamplingMetadata,
-    vocab_size: int,
-    dtype=jnp.float32,
-    pad_to: Optional[int] = None,
-) -> Tuple[SamplingTensors, Dict[int, int]]:
-    """Flatten SamplingMetadata into SamplingTensors.
+def _pad_2d(lists: Sequence[Sequence[int]], pad_value: int, width: int,
+            rows: int) -> np.ndarray:
+    out = np.full((rows, width), pad_value, dtype=np.int32)
+    for i, r in enumerate(lists):
+        out[i, :len(r)] = r
+    return out
 
-    Mirrors `SamplingTensors.from_sampling_metadata`
-    (`sampling_metadata.py:108-261`) incl. the prompt-logprobs row
-    expansion: when a prompt group requests prompt_logprobs, the penalty/
-    temperature rows are replicated for every prompt position.
 
-    Returns (tensors, row_to_seq_id) where row_to_seq_id maps sampled rows
-    to sequence ids (for mirostat state round-trip).
-    """
-    temperatures, top_ps, top_ks, top_as, min_ps = [], [], [], [], []
-    tfss, eta, eps, typical, smoothing = [], [], [], [], []
-    dynatemp_mins, dynatemp_maxs, dynatemp_exps = [], [], []
-    miro_taus, miro_etas, miro_mus = [], [], []
-    pres_pen, freq_pen, rep_pen = [], [], []
-    prompt_tokens: List[List[int]] = []
-    output_tokens: List[List[int]] = []
-    banned_tokens: List[List[int]] = []
-    row_to_seq: Dict[int, int] = {}
-
-    do = dict(penalties=False, temperatures=False, top_p_top_k=False,
-              top_as=False, min_p=False, tfss=False, eta=False,
-              epsilon=False, typical=False, quadratic=False,
-              mirostat=False, bans=False)
-
-    prompt_idx = 0
-    for group_idx, (seq_ids, p) in enumerate(metadata.seq_groups):
-        temperature = p.temperature
-        if temperature < _SAMPLING_EPS:
-            temperature = 1.0      # zero temp == greedy: no-op scaling
-        else:
-            if temperature != 1.0 or p.dynatemp_range > 0:
-                do["temperatures"] = True
-        if p.dynatemp_range > 0:
-            do["temperatures"] = True
-        if p.top_p < 1.0 - _SAMPLING_EPS or p.top_k not in (-1, vocab_size):
-            do["top_p_top_k"] = True
-        if p.top_a > 0.0:
-            do["top_as"] = True
-        if p.min_p > _SAMPLING_EPS:
-            do["min_p"] = True
-        if p.tfs < 1.0 - _SAMPLING_EPS:
-            do["tfss"] = True
-        if p.eta_cutoff > _SAMPLING_EPS:
-            do["eta"] = True
-        if p.epsilon_cutoff > _SAMPLING_EPS:
-            do["epsilon"] = True
-        if p.typical_p < 1.0 - _SAMPLING_EPS:
-            do["typical"] = True
-        if p.smoothing_factor > _SAMPLING_EPS:
-            do["quadratic"] = True
-        if p.mirostat_mode == 2:
-            do["mirostat"] = True
-        if p.custom_token_bans:
-            do["bans"] = True
-        if abs(p.presence_penalty) >= _SAMPLING_EPS or \
-                abs(p.frequency_penalty) >= _SAMPLING_EPS or \
-                abs(p.repetition_penalty - 1.0) >= _SAMPLING_EPS:
-            do["penalties"] = True
-
-        is_prompt = group_idx < len(metadata.prompt_lens)
-        rows: List[int] = []
-        if is_prompt and p.prompt_logprobs is not None:
-            rows.extend([seq_ids[0]] * (metadata.prompt_lens[group_idx] - 1))
-        rows.extend(seq_ids)
-        if is_prompt:
-            prompt_idx += 1
-
-        for seq_id in rows:
-            data = metadata.seq_data[seq_id]
-            temperatures.append(temperature)
-            dyn_range = p.dynatemp_range
-            dynatemp_mins.append(max(temperature - dyn_range, 0.0))
-            dynatemp_maxs.append(temperature + dyn_range)
-            dynatemp_exps.append(p.dynatemp_exponent)
-            top_ps.append(p.top_p)
-            top_ks.append(vocab_size if p.top_k == -1
-                          else min(p.top_k, vocab_size))
-            top_as.append(p.top_a)
-            min_ps.append(p.min_p)
-            tfss.append(p.tfs)
-            eta.append(p.eta_cutoff)
-            eps.append(p.epsilon_cutoff)
-            typical.append(p.typical_p)
-            smoothing.append(p.smoothing_factor)
-            # tau/eta/mu are zeroed unless mode==2 so the device row gate
-            # (tau > 0) agrees with the host mu write-back gate.
-            is_miro = p.mirostat_mode == 2
-            miro_taus.append(p.mirostat_tau if is_miro else 0.0)
-            miro_etas.append(p.mirostat_eta if is_miro else 0.0)
-            mu = metadata.persistent_metadata.get(seq_id).get(
-                "miro_mu", 2.0 * p.mirostat_tau) if is_miro else 0.0
-            miro_mus.append(mu)
-            pres_pen.append(p.presence_penalty)
-            freq_pen.append(p.frequency_penalty)
-            rep_pen.append(p.repetition_penalty)
-            prompt_tokens.append(list(data.prompt_token_ids))
-            output_tokens.append(list(data.output_token_ids))
-            banned_tokens.append(list(p.custom_token_bans))
-            row_to_seq[len(temperatures) - 1] = seq_id
-
-    # Pad to the jitted program's row bucket with neutral knob rows
-    # (sampled results for pad rows are sliced off host-side).
-    num_rows = len(temperatures)
-    n_pad = max(0, (pad_to or 0) - num_rows)
-    if n_pad:
-        temperatures += [1.0] * n_pad
-        dynatemp_mins += [0.0] * n_pad
-        dynatemp_maxs += [0.0] * n_pad
-        dynatemp_exps += [1.0] * n_pad
-        top_ps += [1.0] * n_pad
-        top_ks += [vocab_size] * n_pad
-        top_as += [0.0] * n_pad
-        min_ps += [0.0] * n_pad
-        tfss += [1.0] * n_pad
-        eta += [0.0] * n_pad
-        eps += [0.0] * n_pad
-        typical += [1.0] * n_pad
-        smoothing += [0.0] * n_pad
-        miro_taus += [0.0] * n_pad
-        miro_etas += [0.0] * n_pad
-        miro_mus += [0.0] * n_pad
-        pres_pen += [0.0] * n_pad
-        freq_pen += [0.0] * n_pad
-        rep_pen += [1.0] * n_pad
-        prompt_tokens += [[]] * n_pad
-        output_tokens += [[]] * n_pad
-        banned_tokens += [[]] * n_pad
-
-    # Token-history tensors only exist when a stage reads them: a
-    # zero-width array otherwise, a pow2-bucketed width when used, so
-    # growing output histories don't recompile the sampler every step.
-    hist_width = _pow2_width(prompt_tokens + output_tokens, 32) \
-        if do["penalties"] else 0
-    bans_width = _pow2_width(banned_tokens, 8) if do["bans"] else 0
-
-    f = lambda x: jnp.asarray(np.asarray(x, dtype=np.float32), dtype=dtype)
-    tensors = SamplingTensors(
-        temperatures=f(temperatures),
-        dynatemp_mins=f(dynatemp_mins),
-        dynatemp_maxs=f(dynatemp_maxs),
-        dynatemp_exps=f(dynatemp_exps),
-        top_ps=f(top_ps),
-        top_ks=jnp.asarray(np.asarray(top_ks, dtype=np.int32)),
-        top_as=f(top_as),
-        min_ps=f(min_ps),
-        tfss=f(tfss),
-        eta_cutoffs=f(eta),
-        epsilon_cutoffs=f(eps),
-        typical_ps=f(typical),
-        miro_taus=f(miro_taus),
-        miro_etas=f(miro_etas),
-        miro_mus=f(miro_mus),
-        smoothing_factors=f(smoothing),
-        presence_penalties=f(pres_pen),
-        frequency_penalties=f(freq_pen),
-        repetition_penalties=f(rep_pen),
-        prompt_tokens=jnp.asarray(
-            _pad_2d(prompt_tokens, vocab_size, hist_width)),
-        output_tokens=jnp.asarray(
-            _pad_2d(output_tokens, vocab_size, hist_width)),
-        banned_tokens=jnp.asarray(
-            _pad_2d(banned_tokens, vocab_size, bans_width)),
-        do_penalties=do["penalties"],
-        do_temperatures=do["temperatures"],
-        do_top_p_top_k=do["top_p_top_k"],
-        do_top_as=do["top_as"],
-        do_min_p=do["min_p"],
-        do_tfss=do["tfss"],
-        do_eta_cutoffs=do["eta"],
-        do_epsilon_cutoffs=do["epsilon"],
-        do_typical_ps=do["typical"],
-        do_quadratic=do["quadratic"],
-        do_mirostat=do["mirostat"],
-        do_token_bans=do["bans"],
-    )
-    return tensors, row_to_seq
+def build_token_lists(
+    metadata: SamplingMetadata, vocab_size: int, mask: int, rows: int,
+    row_info: List[Tuple[int, SamplingParams, int]],
+) -> Dict[str, np.ndarray]:
+    """The token-history and ban arrays of a step, for the gates of
+    `mask` that read them (nothing is read or built for a gate that is
+    off)."""
+    out = {}
+    if mask & 1 << GATES.index("do_penalties"):
+        data = [metadata.seq_data[s] for s, _, _ in row_info]
+        prompts = [d.prompt_token_ids for d in data]
+        outputs = [d.output_token_ids for d in data]
+        width = _pow2_width(prompts + outputs, 32)
+        out["prompt_tokens"] = _pad_2d(prompts, vocab_size, width, rows)
+        out["output_tokens"] = _pad_2d(outputs, vocab_size, width, rows)
+    if mask & 1 << GATES.index("do_token_bans"):
+        bans = [p.custom_token_bans for _, p, _ in row_info]
+        out["banned_tokens"] = _pad_2d(bans, vocab_size,
+                                       _pow2_width(bans, 8), rows)
+    return out

@@ -674,20 +674,15 @@ def main() -> None:
         sampling = SamplingMetadata(
             seq_groups=[([i], sp) for i in range(B)],
             seq_data={i: SequenceData([1, 2, 3]) for i in range(B)},
-            prompt_lens=[],
-            selected_token_indices=jnp.arange(B, dtype=jnp.int32),
-            categorized_sample_indices={})
+            prompt_lens=[])
         sampler = Sampler(VOCAB)
         plan = sampler.plan(sampling, pad_to=B)
         logits = jax.random.normal(key, (B, VOCAB), dtype=jnp.float32)
-        bases = jnp.asarray(plan.bases)
-        salt1 = jnp.asarray(plan.salt1)
-        salt2 = jnp.asarray(plan.salt2)
 
         def sstep(c, i):
             lg = c
-            packed, _ = fused_sample(lg, plan.tensors, bases, salt1 + i,
-                                     salt2,
+            packed, _ = fused_sample(lg, plan.tensors,
+                                     plan.key_parts.at[:, 1].add(i),
                                      max_best_of=plan.max_best_of,
                                      num_topk=plan.num_topk,
                                      need_logprobs=False)
@@ -929,14 +924,9 @@ def main() -> None:
         sampling = SamplingMetadata(
             seq_groups=[([i], sp) for i in range(B)],
             seq_data={i: SequenceData([1, 2, 3]) for i in range(B)},
-            prompt_lens=[],
-            selected_token_indices=jnp.arange(B, dtype=jnp.int32),
-            categorized_sample_indices={})
+            prompt_lens=[])
         splr = Sampler(VOCAB)
         plan = splr.plan(sampling, pad_to=B)
-        sbases = jnp.asarray(plan.bases)
-        ssalt1 = jnp.asarray(plan.salt1)
-        ssalt2 = jnp.asarray(plan.salt2)
         gmask = jnp.ones((B,), bool)
 
         def advance(meta, pos):
@@ -968,7 +958,7 @@ def main() -> None:
             flat = hidden.reshape(-1, hidden.shape[-1])
             logits = model.compute_logits(prm, flat)
             packed, _ = fused_sample(
-                logits, plan.tensors, sbases, ssalt1 + t, ssalt2,
+                logits, plan.tensors, plan.key_parts.at[:, 1].add(t),
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk,
                 need_logprobs=False)
             next_tok = jnp.where(gmask, packed[:, 0], packed[:, 1])

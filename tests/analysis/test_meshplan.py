@@ -160,8 +160,9 @@ def test_ledger_domain_map_and_kv_handoff():
     domains = baseline["domains"]
     runner = "aphrodite_tpu/executor/model_runner.py::ModelRunner"
     assert domains[f"{runner}._prepare_prompt"] == "prefill"
-    assert domains[f"{runner}._prepare_decode"] == "decode"
-    assert domains[f"{runner}.execute_spec_verify"] == "decode"
+    # a decode and a speculative-verify batch are sent by one function
+    assert domains[f"{runner}._send_decode_batch"] == "decode"
+    assert domains[f"{runner}.dispatch_burst"] == "decode"
     assert domains[f"{runner}._apply_block_copies"] == "maintenance"
     assert domains[f"{runner}._params_with_lora"] == "shared"
     handoff = baseline["kv_handoff"]

@@ -504,6 +504,51 @@ def test_stage_counters_are_exported_from_the_tracers_totals():
     assert _value("aphrodite:preemptions_total", labels) == 1
 
 
+def test_the_sampling_plan_counters_are_exported_and_a_zero_reads_zero():
+    tracer = tracing.Tracer()
+    labels = dict(model_name="tracing-test-c")
+    log = StatLogger(labels=labels)
+    names = ("aphrodite:sampler_plan_seconds_total",
+             "aphrodite:sampler_plans_total",
+             "aphrodite:sampler_plan_reuses_total")
+    assert [_value(n, labels) for n in names] == [0.0, 0.0, 0.0]
+    tracer.add("sampler.plan", 0.004)
+    tracer.add("sampler.plan", 0.002)
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    # two plans, none reused: the reuse counter reads 0, not nothing
+    assert [_value(n, labels) for n in names] == [
+        pytest.approx(0.006), 2.0, 0.0]
+    tracer.add("sampler.plan", 0.00001)
+    tracer.add("sampler.plan_reuse")
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    assert [_value(n, labels) for n in names][1:] == [3.0, 1.0]
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_a_steady_decode_batch_reuses_its_plan_and_a_sampled_one_never(
+        tiny_llm, monkeypatch, sampled):
+    """Reuse is decided by what the plan observes: the same rows and
+    the same `SamplingParams` objects, and no row that draws."""
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    counts = engine.tracer.counts
+    before = dict(counts)
+    params = SamplingParams(temperature=0.8 if sampled else 0.0,
+                            max_tokens=6, ignore_eos=True)
+    for i in range(2):
+        engine.add_request(f"plan-{sampled}-{i}", None, params,
+                           prompt_token_ids=_prompt(i))
+    _drain(engine)
+    plans = counts["sampler.plan"] - before["sampler.plan"]
+    reuses = counts["sampler.plan_reuse"] - before["sampler.plan_reuse"]
+    assert plans >= 6
+    # the first decode step builds the plan, the four after it reuse it
+    assert reuses == (0 if sampled else 4)
+
+
 # ---- the async loop ----
 
 def test_between_steps_is_counted_once_a_round_and_never_while_idle(

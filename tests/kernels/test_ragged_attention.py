@@ -404,6 +404,7 @@ def test_model_runner_builds_consistent_work_list():
     runner.pages_bucket = 8
     runner._input_sharding = None      # single-device placement plan
     runner._tp = 1
+    runner._decode_work = (None, None)
     runner.model_config = SimpleNamespace(
         get_sliding_window=lambda: None)
 
@@ -423,8 +424,15 @@ def test_model_runner_builds_consistent_work_list():
     assert meta.decode_work is not None and meta.decode_ppc > 0
     ws, wc = (np.asarray(meta.decode_work[0]),
               np.asarray(meta.decode_work[1]))
-    padded_batch = inputs["input_ids"].shape[0]   # bucketed to 4
+    padded_batch = inputs["padded_batch"]          # bucketed to 4
     ppc = meta.decode_ppc
+    # The batch rides as one array in the place of its block tables;
+    # the program slices it.
+    _, _, meta = ModelRunner._unpacked(None, None, meta)
+    assert meta.block_tables.shape == (4, 16)
+    assert np.asarray(meta.context_lens).tolist() == [3, 40, 150, 0]
+    assert np.asarray(meta.slot_mapping).tolist() == [
+        2, 102 * 16 + 7, 209 * 16 + 5, runner.num_slots]
     assert ppc == choose_pages_per_chunk(
         meta.block_tables.shape[1], 16, padded_batch)
     # Every padded row appears, chunks contiguous and chunk-ordered.
@@ -446,3 +454,14 @@ def test_model_runner_builds_consistent_work_list():
     # Work-item page walks stay inside the padded table width.
     max_chunk = wc[:nw_real].max()
     assert (max_chunk + 1) * ppc <= meta.block_tables.shape[1]
+    # The device copy of the list is kept while no row's chunk count
+    # changes (a token more on a row's last page), and rebuilt when one
+    # does.
+    mds[0].seq_data[0].append_token_id(7, 0.0)
+    again, _ = ModelRunner._prepare_decode(runner, mds)
+    assert again["metadata"].decode_work[0] is inputs[
+        "metadata"].decode_work[0]
+    mds[1].block_tables[1] = list(range(100, 100 + 4 * ppc + 1))
+    grown, _ = ModelRunner._prepare_decode(runner, mds)
+    assert grown["metadata"].decode_work[0] is not inputs[
+        "metadata"].decode_work[0]

@@ -24,8 +24,6 @@ def make_metadata(groups, seq_data, prompt_lens=None,
         seq_groups=groups,
         seq_data=seq_data,
         prompt_lens=prompt_lens or [],
-        selected_token_indices=jnp.arange(len(groups)),
-        categorized_sample_indices={},
         persistent_metadata=persistent or PersistentMetadata(),
         output_metadata=OutputMetadata())
 

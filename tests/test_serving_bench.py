@@ -2,9 +2,19 @@
 p50-TTFT artifact BASELINE.md tracks)."""
 import argparse
 import asyncio
+import os
 import sys
 
 import pytest
+
+
+def _forget_fault_env():
+    """The harness writes the fault spec into `os.environ` itself. It
+    is popped here, not with `monkeypatch.delenv`: that would record
+    the harness's value as the original and put it back at teardown,
+    into every later test of this worker."""
+    os.environ.pop("APHRODITE_FAULT", None)
+    os.environ.pop("APHRODITE_FAULT_SEED", None)
 
 
 def _args(tiny_model_dir, **kw):
@@ -62,7 +72,7 @@ def test_serving_harness_chaos_mode(tiny_model_dir, monkeypatch):
             chaos_fault="executor.execute_model:transient:1:2",
             chaos_abort_rate=0.3, chaos_seed=3)))
     finally:
-        monkeypatch.delenv("APHRODITE_FAULT", raising=False)
+        _forget_fault_env()
         faultinject.reset()
     c = result["detail"]["chaos"]
     assert c["engine_state"] == "RUNNING"
@@ -123,7 +133,7 @@ def test_serving_harness_chaos_kill_mode(tiny_model_dir, monkeypatch):
             kill_fault="executor.execute_model:fatal:1:1",
             chaos_seed=0)))
     finally:
-        monkeypatch.delenv("APHRODITE_FAULT", raising=False)
+        _forget_fault_env()
         faultinject.reset()
     ck = result["detail"]["chaos_kill"]
     assert ck["reincarnations"] == 1
