@@ -5,6 +5,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 from typing import Callable, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -22,16 +23,23 @@ def _load_json(path: str) -> dict:
         raise CellError(f"cannot read {path}: {e}") from e
 
 
-def load_function(path: str, attr: str) -> Callable:
-    """The function `attr` of the Python file at `path` (file names may
-    hold dots, so they are loaded by path and not by import name)."""
+def load_module(path: str):
+    """The Python file at `path` as a module (file names may hold
+    dots, so they are loaded by path and not by import name)."""
     if not os.path.isfile(path):
         raise CellError(f"no file {path}")
     name = "perf_dyn_" + os.path.basename(path)[:-3].replace(".", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
+    # a dataclass looks its module up by name while it is defined
+    sys.modules[name] = module
     spec.loader.exec_module(module)
-    fn = getattr(module, attr, None)
+    return module
+
+
+def load_function(path: str, attr: str) -> Callable:
+    """The function `attr` of the Python file at `path`."""
+    fn = getattr(load_module(path), attr, None)
     if not callable(fn):
         raise CellError(f"{path} defines no function {attr}()")
     return fn

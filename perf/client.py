@@ -29,6 +29,9 @@ class Reply:
         default_factory=list)
     tokens: int = 0
     ids: Optional[List[int]] = None
+    #: the prompt's ids, kept with a streamed reply (which has `ids`):
+    #: the two together are what the reference is run over
+    prompt: Optional[List[int]] = None
     error: Optional[str] = None
 
     @property
@@ -61,7 +64,8 @@ async def complete(session: aiohttp.ClientSession, url: str, model: str,
     sent = clock()
     reply = Reply(due=sent if due is None else due, sent=sent,
                   max_tokens=shape["max_tokens"],
-                  prompt_tokens=len(shape["prompt"]), block=block)
+                  prompt_tokens=len(shape["prompt"]), block=block,
+                  prompt=shape["prompt"] if shape["stream"] else None)
     body = dict(model=model, prompt=shape["prompt"],
                 max_tokens=shape["max_tokens"], ignore_eos=True,
                 stream=shape["stream"], **shape["sampling"])

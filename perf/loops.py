@@ -173,9 +173,12 @@ async def closed_loop(target: Target, seed: int, seconds: float,
     sent before it opens may wait for compiles and get
     `warm_timeout_s`. Every block of `clients` requests holds the same
     lengths, so what is open at any time is the same work in every
-    run."""
+    run. The first `journal_callers` callers stream every request with
+    the journal header, so their replies carry token ids: rows of the
+    full decode batch that the reference can be run over."""
     loop = target.traffic["loop"]
     clients = int(loop["clients"])
+    journal = int(loop.get("journal_callers", 0))
     groups = itertools.cycle(loop.get("ramp_groups", [1]))
     patient = float(target.traffic.get("warm_timeout_s", target.timeout))
 
@@ -200,8 +203,8 @@ async def closed_loop(target: Target, seed: int, seconds: float,
         while True:
             index, shape = next(stream)
             replies.append(await target.send(
-                shape, None, index,
-                timeout=patient if t0 is None else None))
+                dict(shape, stream=True) if j < journal else shape, None,
+                index, timeout=patient if t0 is None else None))
             if t1 is not None:      # the request open at t1 has ended
                 closed[j].set()
 

@@ -38,7 +38,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import aiohttp             # noqa: E402
 
-from perf import cells, loops, probes, server as srv, stats, trace  # noqa: E402
+from perf import (cells, loops, probes, reference, server as srv,  # noqa: E402
+                  stats, trace)
 from perf.client import clock, get_json   # noqa: E402
 
 HEALTH_COUNTERS = ("retries_total", "recovered_steps",
@@ -66,6 +67,10 @@ class Run:
     log_setup: str                   # server log before the window
     log_window: str                  # server log while it was open
     faults: List[str]
+    #: what the reference found (`perf/reference.py::check`)
+    reference: Optional[dict] = None
+    #: the canary's replies of before the window, each served alone
+    canary: list = dataclasses.field(default_factory=list)
     peaks: Optional[dict] = None     # perf/peaks.json for this device
     trace: Optional[dict] = None     # trace.reduce(), traced runs only
 
@@ -243,8 +248,10 @@ async def measure(cell: cells.Cell, server, session, seed: int,
         faults.append("the canary's ids differ before and after the "
                       "window: " + "; ".join(
                           str(r.error or r.ids[:6]) for r in before + after))
-    if any(r.ok and len(set(r.ids)) == 1 for r in before):
-        faults.append("a canary reply is one token repeated")
+    # That a reply is no constant is the reference's to say (the first
+    # canary reply is among the sequences it is run over): under
+    # weights whose layers count, greedy text settles into a few
+    # tokens, and a sound reply of 16 can be one token 16 times.
 
     status, health = await get_json(session, server.url + "/health")
     counters = {k: health.get(k) for k in HEALTH_COUNTERS}
@@ -258,7 +265,7 @@ async def measure(cell: cells.Cell, server, session, seed: int,
                                          else seconds),
                log_setup=server.read_log(0, probe.log_open),
                log_window=server.read_log(probe.log_open, probe.log_close),
-               faults=faults)
+               faults=faults, canary=before)
 
 
 def read_metrics(run: Run, entries: list, kind: str) -> dict:
@@ -287,12 +294,16 @@ async def serving(cell: cells.Cell, seed: int, rehearse: bool,
     model_dir = os.path.join(work, "model")
     srv.write_model_dir(model_dir, {k: v for k, v in cell.config.items()
                                     if k != "perf"})
+    # the whole configuration, for the child that makes the weights
+    config_path = os.path.join(work, "cell_config.json")
+    with open(config_path, "w") as f:
+        json.dump(cell.config, f)
     # The compile cache is the benchmark's own, at a fixed path inside
     # the checkout: two checkouts share nothing, and nothing is written
     # to a directory the machine owns.
     cache = os.path.join(cell.root, "perf", ".cache", "jax")
     server = srv.Server(
-        root=cell.root, model_dir=model_dir,
+        root=cell.root, model_dir=model_dir, config_path=config_path,
         engine_args=cfg["engine_args"], env=cfg["env"],
         device="cpu" if rehearse else "tpu", seed=seed % 2 ** 31,
         cache_dir=cache, log_path=os.path.join(work, "server.log"))
@@ -331,13 +342,27 @@ async def serve_and_measure(cell: cells.Cell, args):
             os.path.join(os.path.dirname(server.log_path), "trace")
             if args.trace else None, server.args[1],
             trace_mode=args.trace, python_tracer=args.python_tracer)
-        # Drain while the session is still open: its connections are idle.
-        code = server.drain(180.0)
+        # The reference's child starts now and stays off the chip: it
+        # imports and prepares while the server drains.
+        checking = start_reference(run, args)
+        try:
+            # Drain while the session is still open: its connections
+            # are idle.
+            code = server.drain(180.0)
+        except BaseException:
+            if checking is not None:
+                checking.abandon()
+            raise
+        # The server has exited, and its log holds its peak: the chip
+        # is free for the child (some 10 s to reach it; the trace is
+        # reduced meanwhile).
+        if checking is not None:
+            checking.release()
         log = server.read_log()
         if code != 0 or "Drain complete; exiting." not in log:
             run.faults.append(f"the server did not drain cleanly "
                               f"(exit code {code})")
-        return run, device, log
+        return run, device, log, checking
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -353,6 +378,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="run a tiny cell of perf/rehearse/ on the CPU")
     p.add_argument("--keep-log", default=None, metavar="PATH",
                    help="copy the server's log to PATH at the end")
+    p.add_argument("--control", action="store_true",
+                   help="run the configuration's controls too (the "
+                        "reference with what each entry of "
+                        "`perf.controls` lowers) and print their "
+                        "numbers; each has to exceed a limit. The "
+                        "builder's and the tests', never the driver's")
     p.add_argument("--keep-trace", default=None, metavar="DIR",
                    help="write a short slice of the trace to "
                         "DIR/cut.json")
@@ -369,7 +400,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         say(f"cell {cell.name}: config {cell.config_name} (source "
             f"{cell.config['perf']['source']}), traffic "
             f"{cell.traffic_name}, seed {args.seed}, {args.seconds:g} s")
-        run, device, log = asyncio.run(serve_and_measure(cell, args))
+        run, device, log, checking = asyncio.run(
+            serve_and_measure(cell, args))
     except (srv.RunFailure, cells.CellError, TimeoutError,
             asyncio.TimeoutError, aiohttp.ClientError) as e:
         print(f"perf/run.py: FAILED: {type(e).__name__}: {e}",
@@ -408,6 +440,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             run.trace = trace.reduce(planes)
         except ValueError as e:
             print(f"perf/run.py: FAILED: {e}", file=sys.stderr, flush=True)
+            if checking is not None:
+                checking.finish()       # leave no process behind
             return 1
         device["busy_s"] = run.trace["busy_s"]
         device["window_s"] = run.trace["window_s"]
@@ -418,11 +452,63 @@ def main(argv: Optional[List[str]] = None) -> int:
             # run's is overwritten by the cell's next run).
             shutil.rmtree(trace_dir, ignore_errors=True)
             shutil.rmtree(trace_dir + ".first", ignore_errors=True)
+    compared = finish_reference(run, args, checking)
     for fault in run.faults:
         say(f"FAULT: {fault}")
     say(f"total {clock() - T_START:.1f} s")
+    # each number compared beside its limit, last on standard error too
+    for line in compared + [f"FAULT: {f}" for f in run.faults]:
+        print(f"[perf] {line}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result_line(run, args.trace, device)), flush=True)
     return 0
+
+
+def start_reference(run: Run, args):
+    """Start the configuration's reference over the replies kept
+    (`perf/reference.py`): one of the canary's, served alone, and the
+    journal callers' of the window."""
+    cell, w = run.cell, run.window
+    cfg = cell.config["perf"]
+    if "reference" not in cfg:
+        return None
+    most = int(cfg.get("reference_replies", 2))
+    sequences, kept = reference.pick(run.canary, w.replies, w.t0,
+                                     w.t0 + w.seconds, most)
+    return reference.start(
+        cell, args.seed % 2 ** 31,       # the seed the server was given
+        sequences, kept, rows=1 + most, cpu=args.rehearse,
+        controls=sorted(cfg.get("controls", {})) if args.control else ())
+
+
+def finish_reference(run: Run, args, checking) -> List[str]:
+    """Wait for the reference; a number over its limit is a fault.
+    Returns one line for each number compared."""
+    if checking is None:
+        run.faults.append("the configuration names no reference")
+        return []
+    cfg = run.cell.config["perf"]
+    run.reference, lines, faults = checking.finish()
+    run.faults += faults
+    got = run.reference
+    if got.get("positions"):
+        say(f"reference {cfg['reference']}: {got['sequences']} sequences "
+            f"({got['window_replies']} of the window), {got['tokens']} "
+            f"tokens, {got['positions']} positions compared in "
+            f"{got['seconds']:.1f} s ({got['child_start_s']:.1f} s to "
+            f"start, {got['compute_s']:.1f} s computing); a layer adds "
+            f"{got['layer_share']:.3g} of the residual stream's norm")
+    for name, read in checking.controls.items():
+        numbers, under, failed = read or ({}, [], ["gave no number"])
+        lines += [f"control {name} " + ln for ln in under]
+        lines.append(f"control {name}: " + (
+            "not correct, as it has to be" if failed
+            else "PASSED: the limits do not hold it off"))
+        run.reference.setdefault("controls", {})[name] = {
+            k: numbers.get(k) for k in reference.NUMBERS}
+    for line in lines:
+        say(line)
+    return lines
 
 
 def result_line(run: Run, trace_mode: int, device: dict) -> dict:
@@ -435,6 +521,8 @@ def result_line(run: Run, trace_mode: int, device: dict) -> dict:
         metrics.update(read_metrics(run, cell.per_layer, "layers"))
     result = dict(correct=not run.faults, attempted=run.window.attempted,
                   failed=run.window.failed, metrics=metrics, device=device)
+    if run.reference is not None:
+        result["reference"] = run.reference
     if trace_mode:
         result["breakdown"] = dict(device_ops=run.trace["device_ops"],
                                    idle_gaps=run.trace["idle_gaps"])

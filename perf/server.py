@@ -58,7 +58,8 @@ def write_model_dir(path: str, hf_config: dict) -> None:
 class Server:
     """The OpenAI-compatible API server, as the one child process."""
 
-    def __init__(self, root: str, model_dir: str, engine_args: List[str],
+    def __init__(self, root: str, model_dir: str, config_path: str,
+                 engine_args: List[str],
                  env: Dict[str, str], device: str, seed: int,
                  cache_dir: str, log_path: str) -> None:
         with socket.socket() as s:
@@ -79,8 +80,8 @@ class Server:
                     "JAX_LOG_COMPILES": "1", "PYTHONPATH": root}
         self.log = open(log_path, "w")
         self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_child.py")]
-            + self.args, cwd=root, env={**os.environ, **self.env},
+            [sys.executable, os.path.join(HERE, "serve_child.py"),
+             config_path] + self.args, cwd=root, env={**os.environ, **self.env},
             stdout=self.log, stderr=subprocess.STDOUT,
             start_new_session=True)
 

@@ -103,8 +103,10 @@ def _attribute(gap: Interval, host: List[Tuple[str, float, float]]) -> str:
 def reduce(planes: dict) -> dict:
     """`busy_s` (union of device-operation intervals, averaged over
     the chips), `window_s`, `device_ops` and `idle_gaps` (each at most
-    ten `[name, seconds]`, largest first). The window is the time in
-    which the trace shows both tracers at work: from the later of the
+    ten `[name, seconds]`, largest first), and `ops`: every device
+    operation, whole, as `{short name: [seconds, calls]}`, both a chip
+    (an event cut by the window's edge counts as a call). The window
+    is the time in which both tracers were at work: from the later of the
     first device operation and the first host event to the earlier of
     the last of each. The host's tracer starts before the device's and
     stops after it, and the time between them is not idle time. Raises
@@ -119,7 +121,7 @@ def reduce(planes: dict) -> dict:
     if planes["host"]:
         t_first = max(t_first, min(s for _, s, _ in planes["host"]))
         t_last = min(t_last, max(e for _, _, e in planes["host"]))
-    busy, by_op, gaps = [], {}, {}
+    busy, by_op, calls, gaps = [], {}, {}, {}
     for events in devices.values():
         events = [(name, max(s, t_first), min(e, t_last))
                   for name, s, e in events if e > t_first and s < t_last]
@@ -128,6 +130,7 @@ def reduce(planes: dict) -> dict:
         for name, s, e in events:
             name = short_name(name)
             by_op[name] = by_op.get(name, 0.0) + (e - s)
+            calls[name] = calls.get(name, 0) + 1
         edges = [(t_first, t_first)] + merged + [(t_last, t_last)]
         idle = sorted(((b[0] - a[1], (a[1], b[0]))
                        for a, b in zip(edges, edges[1:])
@@ -143,7 +146,9 @@ def reduce(planes: dict) -> dict:
 
     return dict(busy_s=sum(busy) / chips / 1e9,
                 window_s=(t_last - t_first) / 1e9,
-                device_ops=top(by_op), idle_gaps=top(gaps))
+                device_ops=top(by_op), idle_gaps=top(gaps),
+                ops={name: [seconds / chips / 1e9, calls[name] / chips]
+                     for name, seconds in by_op.items()})
 
 
 def cut(planes: dict, span_ns: float = 150e6, name_chars: int = 400,

@@ -283,8 +283,11 @@ def test_closed_loop_end_to_end_against_the_stub(tmp_path):
     run = _measure(cell, tmp_path, seconds=1.0)
     assert run.faults == [] and run.window.attempted > 0
     assert run.window.failed_before == 0
-    # the callers joined 4, 2 and 1 at a time, never all 40 at once
-    assert 1 <= run.stub.most_streams_queued <= 4
+    # the callers joined 4, 2 and 1 at a time, never all 40 at once;
+    # a journal caller streams its later requests too, and one of
+    # those may wait for its first token beside a joining group
+    assert 1 <= run.stub.most_streams_queued <= \
+        4 + cell.traffic["loop"]["journal_callers"]
     t0, t1 = run.window.t0, run.window.t0 + 1.0
     # every request open at some time in the window, each waited for
     assert all(r.ended >= t0 and r.sent < t1 for r in run.window.replies)

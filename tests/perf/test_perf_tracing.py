@@ -105,6 +105,8 @@ def test_the_five_stages_of_the_hand_made_window_sum_to_its_round():
 
 
 def test_the_manifest_takes_the_key_and_appends_the_new_metrics():
+    """PR 25's eleven entries are found by name, and a later PR may
+    append metrics and put a new cell on the `workloads` of these."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert bench["trace_in_run"] is True
@@ -113,15 +115,93 @@ def test_the_manifest_takes_the_key_and_appends_the_new_metrics():
         "waiting_mean.batch", "running_mean.batch", "kv_used_pct.batch",
         "programs_warmed", "compiles_in_window.batch",
         "model_flops_pct.batch", "device_idle_pct.batch"]
-    assert set(names[7:]) == set(WANT)
+    assert set(names[7:]) >= set(WANT)
     layers = {m["layer"] for m in bench["per_layer"][:7]}
-    for m in bench["per_layer"][7:]:
-        assert m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
+    for m in bench["per_layer"]:
+        if m["name"] not in WANT:
+            continue
+        assert m["moves"] == "out_tok_s" and CELL in m["workloads"]
         assert m["source"] == ("device_trace" if m["name"].startswith(
             "idle_attributed") else "program_counter")
         # a layer the manifest already names keeps its name
         assert m["layer"] in layers or m["layer"].startswith(
             ("core engine", "HTTP front end"))
+
+
+# ---- PR 27's three readers: the sampling plan, the decode kernel ----
+
+#: `WINDOW` again, with 320 plans of which 144 reused, 0.112 s in all
+PLANS = [dict(WINDOW[0], **{"aphrodite:sampler_plan_seconds_total": 1.0,
+                            "aphrodite:sampler_plans_total": 2000.0,
+                            "aphrodite:sampler_plan_reuses_total": 900.0,
+                            "aphrodite:gpu_cache_usage_perc": 0.5}),
+         dict(WINDOW[1], **{"aphrodite:sampler_plan_seconds_total": 1.112,
+                            "aphrodite:sampler_plans_total": 2320.0,
+                            "aphrodite:sampler_plan_reuses_total": 1044.0,
+                            "aphrodite:gpu_cache_usage_perc": 0.5})]
+POOL_LINE = ("INFO [x] KV cache: 5000 device pages, 512 host pages "
+             "(8.00 GiB device)\n")
+#: 64 calls of the decode kernel in 0.032 s (0.5 ms each) and one of
+#: another shape; half of an 8 GiB pool live over 32 layers is 128 MiB
+#: a call and the 49 rows 0.8 MB more, 0.16486 ms at 819 GB/s: 32.97%
+KERNEL_TRACE = dict(TRACE, ops={
+    "_paged_decode_impl bf16[49,1,32,128] tpu_custom_call": [0.031, 62],
+    "_paged_decode_impl bf16[2,1,32,128] tpu_custom_call": [0.001, 2],
+    "gptq_matmul_a8 bf16[48,4096] tpu_custom_call": [0.5, 256]})
+NEW_WANT = {"sampler_plan_ms.batch": 0.35, "plan_reuse_pct.batch": 45.0,
+            "decode_attn_roofline_pct.batch": 32.972}
+
+
+def _run_27(samples, trace=None):
+    run = _run(samples, trace)
+    run.log_setup = POOL_LINE
+    run.peaks = cells.load_peaks("TPU v5 lite")
+    return run
+
+
+@pytest.mark.parametrize("metric", sorted(NEW_WANT))
+def test_each_reader_of_pr_27_on_a_hand_made_run(metric):
+    got = _read(metric, _run_27(PLANS, KERNEL_TRACE))
+    assert got == pytest.approx(NEW_WANT[metric], rel=1e-4)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[metric]
+    assert entry["moves"] == "out_tok_s" and CELL in entry["workloads"]
+    assert entry["layer"].startswith(
+        "kernels" if "roofline" in metric else "model runner")
+
+
+@pytest.mark.parametrize("metric", sorted(NEW_WANT))
+def test_a_reader_of_pr_27_that_finds_nothing_reads_nothing(metric):
+    """The parent's program exports no plan counter; an untraced run
+    has no trace, an old reduction no `ops`, a CPU trace no such
+    kernel. None, never 0 and never an exception."""
+    assert _read(metric, _run_27(WINDOW, TRACE)) is None
+    assert _read(metric, _run_27([])) is None
+    assert _read(metric, _run_27(PLANS, dict(
+        TRACE, ops={"fusion f32[8]": [1.0, 10]}))) is None or \
+        "roofline" not in metric
+    if "roofline" in metric:
+        # no pool line in the log, or no table of peaks: nothing to read
+        run = _run_27(PLANS, KERNEL_TRACE)
+        run.log_setup = ""
+        assert _read(metric, run) is None
+        run = _run_27(PLANS, KERNEL_TRACE)
+        run.peaks = None
+        assert _read(metric, run) is None
+
+
+def test_the_roofline_count_of_the_decode_kernel_from_its_shapes():
+    count = cells.load_function(os.path.join(
+        ROOT, "perf", "rooflines", "paged_decode.py"), "count")
+    config = dict(num_hidden_layers=32, num_attention_heads=32,
+                  num_key_value_heads=8, hidden_size=4096)
+    # 48 rows of 1,024 tokens: 128 KiB of K and V a token over 32 layers
+    live = 48 * 1024 * 131072
+    moved, computed = count(config, live, 48)
+    assert moved == live / 32 + 2 * 48 * 32 * 128 * 2
+    assert computed == 4 * 128 * 32 * 48 * 1024
+    # at these sizes the bytes bound it, by far: 0.25 ms against 4 us
+    assert moved / 819e9 > 50 * computed / 197e12
 
 
 # ---- `--trace 2` against the stub ----
@@ -186,10 +266,12 @@ def test_trace_2_prints_both_kinds_and_the_same_end_to_end_as_trace_0(
     both = perf_run.result_line(run, 2, dict(device))
     layers = perf_run.result_line(run, 1, dict(device))
     end_to_end = {m["name"] for m in cell.end_to_end}
-    # (no table of peaks goes with the stub: the one reader that needs
-    # it finds nothing to read and is left out of the line)
+    # (no table of peaks goes with the stub, and it counts no sampling
+    # plan: the readers that need them find nothing to read and are
+    # left out of the line)
     per_layer = {m["name"] for m in cell.per_layer} - {
-        "model_flops_pct.batch"}
+        "model_flops_pct.batch", "sampler_plan_ms.batch",
+        "plan_reuse_pct.batch"}
     assert set(plain) == {"correct", "attempted", "failed", "metrics",
                           "device"}
     assert set(both) == set(layers) == set(plain) | {"breakdown"}
@@ -266,7 +348,13 @@ def test_rehearsal_of_trace_2_prints_one_line_with_both_kinds(tmp_path):
     metrics = line["metrics"]
     assert {"out_tok_s", "setup_s", "running_mean.batch",
             "kv_used_pct.batch", "device_idle_pct.batch",
-            "programs_warmed"} <= set(metrics)
+            "programs_warmed", "sampler_plan_ms.batch",
+            "plan_reuse_pct.batch"} <= set(metrics)
+    assert 0 < metrics["sampler_plan_ms.batch"]["value"] < 50
+    # the served tokens against the float32 reference, after the exit
+    assert line["reference"]["positions"] > 20
+    assert line["reference"]["gap_worst"] <= 1e-4
+    assert "reference: gap_worst" in out.stderr.splitlines()[-1]
     assert metrics["out_tok_s"]["value"] > 0
     assert 0 < line["device"]["busy_s"] < line["device"]["window_s"] < 3
     gaps = dict(map(tuple, line["breakdown"]["idle_gaps"]))
