@@ -62,7 +62,8 @@ class SequenceData:
     """Token ids + cumulative logprob for one sequence."""
 
     __slots__ = ("prompt_token_ids", "output_token_ids",
-                 "cumulative_logprob", "num_computed_tokens")
+                 "cumulative_logprob", "num_computed_tokens",
+                 "in_flight")
 
     def __init__(self, prompt_token_ids: List[int]) -> None:
         self.prompt_token_ids = prompt_token_ids
@@ -71,6 +72,13 @@ class SequenceData:
         # Prompt tokens whose KV is already written (chunked-prefill
         # progress); reset to 0 on recompute-preemption.
         self.num_computed_tokens = 0
+        # Tokens a dispatched step has sampled for this sequence and
+        # the host has not pulled yet (0 or 1; the engine runs one
+        # round ahead): their ids are still on the device, their count
+        # is known. Positions, slots, pages and the seeded draw's
+        # salt of the next step count them; text, stops and a
+        # recompute do not. Reset with `num_computed_tokens`.
+        self.in_flight = 0
 
     def append_token_id(self, token_id: int, logprob: float) -> None:
         self.output_token_ids.append(token_id)

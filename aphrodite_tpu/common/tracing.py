@@ -25,10 +25,13 @@ from typing import Callable, Dict
 
 from jax.profiler import TraceAnnotation
 
-#: Every span, and the three events that are counted but not timed as
-#: a span (`Tracer.add`): a request's wait from arrival to the round
-#: that first schedules it, a preemption, and a sampling plan that
-#: built and sent nothing because the batch had not changed.
+#: Every span; the four events that are counted but not timed as a
+#: span (`Tracer.add`): a request's wait from arrival to the round that
+#: first schedules it, a preemption, a sampling plan that built and
+#: sent nothing because the batch had not changed, and a step program
+#: dispatched while the round before was still on the device; and the
+#: seconds in which a dispatched step had not been pulled yet
+#: (`Tracer.flight`).
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
     "engine.step",          # one AphroditeEngine.step()
@@ -43,6 +46,8 @@ NAMES = (
     "queue_wait",
     "preemptions",
     "sampler.plan_reuse",
+    "runner.ahead",
+    "runner.in_flight",
 )
 
 _clock = time.perf_counter
@@ -61,6 +66,10 @@ class Tracer:
         #: and once the round is scheduled `path`, `rows`,
         #: `prompt_tokens`
         self.facts: Dict[str, object] = {}
+        #: step programs dispatched and not pulled yet, and the clock
+        #: at the last change of that number
+        self.in_flight = 0
+        self._flight_mark = 0.0
 
     def annotate(self, on: bool) -> None:
         """Switch the TraceAnnotation half of the spans on or off. A
@@ -75,6 +84,23 @@ class Tracer:
         """Count one occurrence of `name` that lasted `secs`."""
         self.seconds[name] += secs
         self.counts[name] += 1
+
+    def flight(self, steps: int) -> None:
+        """`steps` step programs were dispatched (positive) or their
+        results pulled (negative). `seconds["runner.in_flight"]` grows
+        by the time since the last call if a step was in flight over
+        it: the union of the steps' intervals from dispatch entered to
+        result on the host, not their sum, and current at every call."""
+        now = _clock()
+        if self.in_flight > 0:
+            self.seconds["runner.in_flight"] += now - self._flight_mark
+        self._flight_mark = now
+        self.in_flight = max(0, self.in_flight + steps)
+
+    def grounded(self) -> None:
+        """Nothing is in flight any more: the steps that were are
+        abandoned (a failed round, a rebuild)."""
+        self.flight(-self.in_flight)
 
     def span(self, name: str, **facts) -> "Span":
         return Span(self, name, facts)

@@ -60,6 +60,15 @@ class ReincarnationOutcome:
     lost: List[str]
 
 
+@dataclasses.dataclass
+class RoundInFlight:
+    """A round dispatched and not pulled yet: what the scheduler
+    committed for it, and the handles of its steps, the decode step's
+    first (`TPUExecutor.dispatch_round`)."""
+    scheduler_outputs: SchedulerOutputs
+    handles: tuple
+
+
 def _enable_compilation_cache() -> None:
     """Point JAX's persistent compilation cache at a durable directory
     so a server restart replays every (phase, bucket) executable from
@@ -189,6 +198,9 @@ class AphroditeEngine:
         # the step pipelines builder rounds) — the crash barrier's
         # rollback scope.
         self._inflight_rounds: List[SchedulerOutputs] = []
+        # The round whose steps are on the device while the next one
+        # is scheduled and prepared (`step`); None at depth 0.
+        self._ahead: Optional[RoundInFlight] = None
         # Reincarnation epoch: bumped by reincarnate(). Each step
         # thread stamps the epoch it started under in thread-local
         # storage; a step that outlives a rebuild (a watchdog-
@@ -452,7 +464,10 @@ class AphroditeEngine:
                 len(self._arrival_finished))
 
     def has_unfinished_requests(self) -> bool:
+        # A round in flight is unfinished work even when every row of
+        # it was aborted since: a caller looping on this pulls it.
         return bool(self._arrival_finished) or \
+            self._ahead is not None or \
             self.scheduler.has_unfinished_seqs()
 
     # -- the step --
@@ -465,32 +480,54 @@ class AphroditeEngine:
         burst of K tokens per seq. A combined round enqueues the prefill
         program and the burst back-to-back and pays ONE host sync.
 
+        **Which round's outputs a call returns.** The engine runs one
+        round ahead where it can: call n schedules round n counting,
+        for every row, the token that round n-1 is still computing
+        (`SequenceData.in_flight`: its position, slot, page and the
+        length stops need its count, not its id), dispatches round n
+        with those tokens fed on the device, and only then pulls and
+        processes round n-1 and returns ITS outputs; round n runs on
+        the chip while the caller delivers them and call n+1 prepares.
+        A round that cannot be dispatched that way (`_runs_ahead`:
+        what its rows ask of the sampler, swaps, a speculative or
+        burst round, an empty one) first pulls the round in flight,
+        then runs synced, and the call returns the outputs of both.
+        So a call returns the outputs of zero, one or two rounds, and
+        `has_unfinished_requests()` stays true until the last one is
+        pulled. A row that stopped in round n-1 on what only its token
+        says (EOS, a stop id or string) has one step too many in
+        flight: its round-n token is dropped.
+
         Failure semantics (the crash barrier): if anything after
-        scheduling fails, every mutation of this round — scheduled
-        groups, freshly allocated/forked pages, swap/copy plans — is
-        rolled back via `Scheduler.crash_rollback` before the exception
-        propagates, so a retried step neither leaks KV pages nor
-        double-schedules. Requests the rollback could not restore are
+        scheduling fails, every mutation of this round and of the one
+        in flight — scheduled groups, freshly allocated/forked pages,
+        swap/copy plans — is rolled back via `Scheduler.crash_rollback`
+        before the exception propagates, so a retried step neither
+        leaks KV pages nor double-schedules, and the tokens in flight
+        are sampled again. Requests the rollback could not restore are
         recorded in `_step_faults` (drained by `drain_step_faults`)."""
         self._round += 1
         self.tracer.set_round(round=self._round)
         with self.tracer.span("engine.step"):
             self._step_tls.epoch = self._epoch
             faultinject.fire("engine.step")
-            self._inflight_rounds = []
-            with self.tracer.span("sched.schedule"):
-                self._expire_deadlines()
-                seq_group_metadata_list, scheduler_outputs = \
-                    self.scheduler.schedule()
-            self._inflight_rounds.append(scheduler_outputs)
-            # Continuations resolved on arrival (emitted output already at
-            # a stop): deliver their finished outputs ahead of the round.
-            # Drained only once scheduling succeeded, so a mid-schedule
-            # crash retries with them still stashed.
+            self._inflight_rounds = [] if self._ahead is None else \
+                [self._ahead.scheduler_outputs]
             resolved: List[SequenceGroup] = []
-            if self._arrival_finished:
-                resolved, self._arrival_finished = self._arrival_finished, []
             try:
+                with self.tracer.span("sched.schedule"):
+                    self._expire_deadlines()
+                    seq_group_metadata_list, scheduler_outputs = \
+                        self.scheduler.schedule()
+                self._inflight_rounds.append(scheduler_outputs)
+                # Continuations resolved on arrival (emitted output
+                # already at a stop): deliver their finished outputs
+                # ahead of the round. Drained only once scheduling
+                # succeeded, so a mid-schedule crash retries with them
+                # still stashed.
+                if self._arrival_finished:
+                    resolved, self._arrival_finished = \
+                        self._arrival_finished, []
                 outputs = self._execute_round(seq_group_metadata_list,
                                               scheduler_outputs)
                 if resolved:
@@ -510,6 +547,9 @@ class AphroditeEngine:
                     raise StaleEngineStepError(
                         "engine step outlived a reincarnation; its "
                         "rollback is discarded") from exc
+                # The steps in flight are abandoned with their rounds.
+                self._ahead = None
+                self.tracer.grounded()
                 for rid in self.scheduler.crash_rollback(
                         self._inflight_rounds):
                     err: Exception = RuntimeError(
@@ -582,6 +622,10 @@ class AphroditeEngine:
                     group.prefix.token_ids)
             self.scheduler.add_seq_group(group)
         self._inflight_rounds = []
+        # A round in flight died with the old executor; its rows were
+        # rolled back above and sample those tokens again.
+        self._ahead = None
+        self.tracer.grounded()
         for rid in lost:
             self._step_faults.append((rid, RequestLostOnRebuild(
                 f"request {rid} could not be restored across an "
@@ -608,6 +652,101 @@ class AphroditeEngine:
 
     def _execute_round(self, seq_group_metadata_list,
                        scheduler_outputs) -> List[RequestOutput]:
+        """Dispatch the round ahead of the pull of the one in flight
+        and return that one's outputs; or, where the round cannot run
+        that way, pull first and run it synced (`_execute_synced`:
+        the round algorithm at depth 0), returning both."""
+        n_chunks = len(scheduler_outputs.prompt_chunks)
+        prompt_mds = seq_group_metadata_list[:n_chunks]
+        decode_mds = seq_group_metadata_list[n_chunks:]
+        before = self._ahead
+        if self._runs_ahead(prompt_mds, decode_mds, scheduler_outputs):
+            self._mark_path("combined" if prompt_mds else "decode",
+                            scheduler_outputs)
+            handles = self.executor.dispatch_round(
+                prompt_mds, decode_mds,
+                before.handles if before is not None else ())
+            if handles is not None:
+                if before is not None:
+                    for _ in handles:
+                        self.tracer.add("runner.ahead")
+                outputs = self._pull_round_in_flight()
+                self._ahead = RoundInFlight(scheduler_outputs, handles)
+                # From here each row's next token is on the device.
+                for group in scheduler_outputs.sampling_groups:
+                    for seq in group.get_seqs(
+                            status=SequenceStatus.RUNNING):
+                        seq.data.in_flight = 1
+                return outputs
+
+        outputs = self._pull_round_in_flight()
+        if before is not None:
+            seq_group_metadata_list = self._without_finished(
+                seq_group_metadata_list, scheduler_outputs)
+        return outputs + self._execute_synced(seq_group_metadata_list,
+                                              scheduler_outputs)
+
+    def _runs_ahead(self, prompt_mds, decode_mds,
+                    scheduler_outputs: SchedulerOutputs) -> bool:
+        """Whether this round can be dispatched before the one in
+        flight is pulled, read from the round itself: it has decode
+        rows (a round of prompts alone is pulled at once, for its
+        first tokens, and may pipeline with its like), every row's
+        step is the one fused program and nothing it samples reads its
+        history on the host (`_spec_eligible`), no page moves (swap or
+        copy), and no other way of running several tokens a sync has
+        the round: a burst (`multi_step` > 1), a speculative verify
+        round (which drafts from the last token's id), the
+        disaggregated layout. Adapter rows and sliding windows keep
+        the synced path, which is the only one they were proven on."""
+        if not decode_mds or self.scheduler_config.multi_step > 1 or \
+                self._speculates() or self.executor.disagg or \
+                self.model_config.get_sliding_window() is not None:
+            return False
+        if scheduler_outputs.blocks_to_swap_in or \
+                scheduler_outputs.blocks_to_swap_out or \
+                scheduler_outputs.blocks_to_copy:
+            return False
+        rows = prompt_mds + decode_mds
+        return self._spec_eligible(rows) and \
+            all(md.lora_request is None for md in rows)
+
+    def _pull_round_in_flight(self) -> List[RequestOutput]:
+        """Pull the round in flight, if there is one, and process it:
+        its outputs."""
+        before, self._ahead = self._ahead, None
+        if before is None:
+            return []
+        decode_output, *prompt_output = self.executor.finalize_steps(
+            list(before.handles))
+        outputs = self._process_round(
+            prompt_output[0] if prompt_output else None,
+            [decode_output], before.scheduler_outputs, ahead=True)
+        # Processed: no longer the crash barrier's to roll back.
+        self._inflight_rounds.remove(before.scheduler_outputs)
+        return outputs
+
+    @staticmethod
+    def _without_finished(seq_group_metadata_list,
+                          scheduler_outputs: SchedulerOutputs):
+        """A round scheduled while its rows' last tokens were in
+        flight, now that they are pulled: the rows that turned out to
+        have stopped leave it, and it is the round a synced engine
+        would have scheduled."""
+        groups = scheduler_outputs.decode_groups
+        if not any(g.is_finished() for g in groups):
+            return seq_group_metadata_list
+        n_chunks = len(scheduler_outputs.prompt_chunks)
+        kept = [(g, md) for g, md in
+                zip(groups, seq_group_metadata_list[n_chunks:])
+                if not g.is_finished()]
+        scheduler_outputs.num_decode_tokens -= len(groups) - len(kept)
+        scheduler_outputs.decode_groups = [g for g, _ in kept]
+        return seq_group_metadata_list[:n_chunks] + \
+            [md for _, md in kept]
+
+    def _execute_synced(self, seq_group_metadata_list,
+                        scheduler_outputs) -> List[RequestOutput]:
         if scheduler_outputs.is_empty():
             self._mark_path("empty", scheduler_outputs)
             return self._process_round(None, [], scheduler_outputs)
@@ -698,7 +837,7 @@ class AphroditeEngine:
     @staticmethod
     def _prompt_fast_path_ok(prompt_mds) -> bool:
         """Cheap metadata-level precheck mirroring EVERY one of
-        dispatch_prompt's authoritative plan-based bail conditions
+        dispatch_step's authoritative plan-based bail conditions
         (logits processors, need_logprobs, max_best_of != 1,
         num_topk != 0), so rounds the dispatch would bail on skip the
         pipelined probe instead of paying the padded batch build
@@ -778,7 +917,7 @@ class AphroditeEngine:
         # sync we were paying anyway.
         self._flush_kv_handoff(all_prompt_mds)
         pending = [h for h in handles if hasattr(h, "packed")]
-        finalized = iter(self.executor.finalize_prompt_rounds(pending))
+        finalized = iter(self.executor.finalize_steps(pending))
         request_outputs = []
         for outputs_i, h in zip(rounds, handles):
             out_i = next(finalized) if hasattr(h, "packed") else h
@@ -875,6 +1014,12 @@ class AphroditeEngine:
                 return False
         return True
 
+    @staticmethod
+    def _speculates() -> bool:
+        """Whether decode-only rounds may run as verify rounds (the
+        one read of `APHRODITE_SPEC`)."""
+        return flags.get_bool("APHRODITE_SPEC")
+
     def _spec_round(self, decode_mds,
                     scheduler_outputs) -> Optional[List[RequestOutput]]:
         """One speculative decode round, or None for the classic path.
@@ -884,7 +1029,7 @@ class AphroditeEngine:
         the same watermark-respecting seam as the burst scan, verifies
         all rows in one widened dispatch, and applies the accepted
         runs. `APHRODITE_SPEC=0` pins the classic path for A/B."""
-        if not flags.get_bool("APHRODITE_SPEC"):
+        if not self._speculates():
             return None
         if self.model_config.get_sliding_window() is not None:
             return None
@@ -993,10 +1138,16 @@ class AphroditeEngine:
     def _process_round(
             self, prompt_output: Optional[SamplerOutput],
             decode_outputs_list: List[SamplerOutput],
-            scheduler_outputs: SchedulerOutputs) -> List[RequestOutput]:
+            scheduler_outputs: SchedulerOutputs,
+            ahead: bool = False) -> List[RequestOutput]:
         """Apply one round's sampled tokens: final prompt chunks first
         (mid-prompt chunks wrote KV but sample nothing), then each decode
-        step's outputs (a burst passes several)."""
+        step's outputs (a burst passes several). `ahead`: the round was
+        dispatched before the one before it was pulled, so a row of it
+        may have ended since (a stop only its token could say, an
+        abort) or been preempted or rolled back, which takes its token
+        out of flight (`SequenceData.in_flight` 0): such a row's token
+        is dropped, and it has no output here."""
         if getattr(self._step_tls, "epoch", self._epoch) != self._epoch:
             # This thread's step started before a reincarnation: its
             # groups were already restored (or errored) by the rebuild
@@ -1006,30 +1157,37 @@ class AphroditeEngine:
                 "are discarded")
         touched: List = []
         tokens_of = {}
-        failed: set = set()
+        skipped: set = set()
+        decode_groups = scheduler_outputs.decode_groups
+        if ahead:
+            for group in scheduler_outputs.sampling_groups:
+                (seq,) = group.get_seqs()
+                if group.is_finished() or not seq.data.in_flight:
+                    skipped.add(id(group))
+                seq.data.in_flight = 0
         if prompt_output:
             for chunk, outputs in zip(scheduler_outputs.prompt_chunks,
                                       prompt_output):
-                if not chunk.is_final:
+                if not chunk.is_final or id(chunk.group) in skipped:
                     continue
                 if self._process_group_isolated(chunk.group, outputs):
                     touched.append(chunk.group)
                     tokens_of[id(chunk.group)] = len(outputs.samples)
-        decode_groups = scheduler_outputs.decode_groups
         for group in decode_groups:
             tokens_of[id(group)] = 0
         for output in decode_outputs_list:
             for seq_group, outputs in zip(decode_groups, output):
-                if seq_group.is_finished():
+                if seq_group.is_finished() or id(seq_group) in skipped:
                     # Burst overran this group's stop, or a request-
-                    # scoped failure aborted it earlier in this burst.
+                    # scoped failure aborted it earlier in this burst,
+                    # or its token was dropped (above).
                     continue
                 if self._process_group_isolated(seq_group, outputs):
                     tokens_of[id(seq_group)] += len(outputs.samples)
                 else:
-                    failed.add(id(seq_group))
+                    skipped.add(id(seq_group))
         touched.extend(g for g in decode_groups
-                       if id(g) not in failed)
+                       if id(g) not in skipped)
         self._record_latencies(touched, tokens_of=tokens_of)
         self.scheduler.free_finished_seq_groups()
 

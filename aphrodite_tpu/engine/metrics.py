@@ -54,9 +54,10 @@ _STAGE_COUNTERS = [
      "Seconds building a step's host batch, sampling plan included, "
      "up to the dispatch.", lambda s, c: s["runner.prepare"]),
     ("aphrodite:device_wait_seconds_total",
-     "Seconds from a step's dispatch entered to its result on the "
-     "host.",
-     lambda s, c: s["runner.dispatch"] + s["runner.device_wait"]),
+     "Seconds in which a dispatched step had not been pulled yet: "
+     "from a step's dispatch entered to its result on the host, "
+     "overlapping steps counted once.",
+     lambda s, c: s["runner.in_flight"]),
     ("aphrodite:host_process_seconds_total",
      "Seconds unpacking sampled results and processing outputs "
      "(detokenise, stop checks, stats).",
@@ -84,6 +85,10 @@ _STAGE_COUNTERS = [
      "Sampling plans that built and sent nothing: the batch and its "
      "parameters were the step before's.",
      lambda s, c: c["sampler.plan_reuse"]),
+    ("aphrodite:steps_ahead_total",
+     "Step programs dispatched while the round before was still on "
+     "the device (of aphrodite:sampler_plans_total dispatched).",
+     lambda s, c: c["runner.ahead"]),
 ]
 
 

@@ -26,7 +26,7 @@ from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupMetadata)
 from aphrodite_tpu.executor.cache_engine import CacheEngine
-from aphrodite_tpu.executor.model_runner import ModelRunner
+from aphrodite_tpu.executor.model_runner import ModelRunner, StepHandle
 from aphrodite_tpu.modeling.loader import get_model
 
 logger = init_logger(__name__)
@@ -437,18 +437,41 @@ class TPUExecutor:
         self._pre_step(prompt_metadata, {}, {})
         kv = self.prefill_runner._apply_block_copies(
             self._prompt_pool(), blocks_to_copy)
-        handle, kv = self.prefill_runner.dispatch_prompt(
+        handle, kv = self.prefill_runner.dispatch_step(
             prompt_metadata, kv)
         self._set_prompt_pool(kv)
         return handle
 
-    def finalize_prompt_rounds(self, handles):
-        """One transfer for every pending round's packed results."""
-        with self.tracer.span("runner.device_wait"):
-            pulled = jax.device_get([h.packed for h in handles])
+    def dispatch_round(
+        self,
+        prompt_metadata: List[SequenceGroupMetadata],
+        decode_metadata: List[SequenceGroupMetadata],
+        fed_by: Tuple[StepHandle, ...] = (),
+    ) -> Optional[Tuple[StepHandle, ...]]:
+        """Enqueue a whole round WITHOUT syncing: its decode step, and
+        its prompt step if it has one (disjoint rows and pages; the
+        device runs them in this order). Returns their handles, the
+        decode step's first (what the next round's `fed_by` and
+        `finalize_steps` take), or None, nothing enqueued, when a step
+        needs the raw logits and the caller must run the round synced.
+        Decode rows whose token the round `fed_by` is still computing
+        take it on the device. For a colocated round without swaps or
+        block copies (the engine's `_runs_ahead`)."""
+        self._pre_step(prompt_metadata + decode_metadata, {}, {})
+        batches = [decode_metadata] + \
+            ([prompt_metadata] if prompt_metadata else [])
+        handles, kv = self.model_runner.dispatch_steps(
+            batches, self.cache_engine.kv_caches, fed_by)
+        self.cache_engine.kv_caches = kv
+        return tuple(handles) if handles else None
+
+    def finalize_steps(self, handles):
+        """One transfer for every pending step's packed results, and
+        each step's outputs from them."""
+        pulled = self.model_runner.pull(handles)
         with self.tracer.span("sampler.finalize"):
             return [
-                self.prefill_runner.finalize_step(h, np.asarray(p))
+                self.prefill_runner.finalize_step(h, p)
                 for h, p in zip(handles, pulled)
             ]
 
@@ -481,7 +504,7 @@ class TPUExecutor:
 
         handle = None
         if num_steps > 1:
-            handle, kv = self.model_runner.dispatch_prompt(
+            handle, kv = self.model_runner.dispatch_step(
                 prompt_metadata, kv)
         if handle is not None:
             bhandle, kv = self.model_runner.dispatch_burst(
@@ -509,12 +532,10 @@ class TPUExecutor:
         """The one host sync of a fused combined round: pull the prompt
         step's and the burst's packed results together, then unpack
         each (the prompt's by the runner that dispatched it)."""
-        with self.tracer.span("runner.device_wait"):
-            p_np, b_np = jax.device_get((handle.packed, bhandle.packed))
+        p_np, b_np = self.model_runner.pull([handle, bhandle])
         with self.tracer.span("sampler.finalize"):
-            return (prompt_runner.finalize_step(handle, np.asarray(p_np)),
-                    self.model_runner.finalize_burst(bhandle,
-                                                     np.asarray(b_np)))
+            return (prompt_runner.finalize_step(handle, p_np),
+                    self.model_runner.finalize_burst(bhandle, b_np))
 
     def _execute_combined_disagg(
         self,
@@ -540,7 +561,7 @@ class TPUExecutor:
 
         handle = None
         if num_steps > 1:
-            handle, pkv = self.prefill_runner.dispatch_prompt(
+            handle, pkv = self.prefill_runner.dispatch_step(
                 prompt_metadata, pkv)
         if handle is not None:
             bhandle, dkv = self.model_runner.dispatch_burst(

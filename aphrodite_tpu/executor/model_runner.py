@@ -91,7 +91,8 @@ class StepHandle:
     """An enqueued-but-unsynced device step: the packed result is still
     on device; `ModelRunner.finalize_step/finalize_burst` turns the
     pulled numpy array into SamplerOutputs. Lets a combined round
-    enqueue prefill + decode burst back-to-back and sync once."""
+    enqueue prefill + decode burst back-to-back and sync once, and the
+    engine dispatch a round before it pulls the one before."""
 
     __slots__ = ("packed", "sampling", "plan", "num_steps")
 
@@ -101,6 +102,18 @@ class StepHandle:
         self.sampling = sampling
         self.plan = plan
         self.num_steps = num_steps
+
+    def token_cells(self) -> Dict[int, int]:
+        """Where each sequence's sampled token lies in `packed[:, :2]`
+        read row by row: sequence id -> 2 * row + column (`fused_sample`
+        puts the greedy token in column 0 and the draw in column 1).
+        Single-sequence groups, one row each, as the fused path at
+        best_of 1 has them."""
+        return {
+            seq_ids[0]: 2 * row + (
+                0 if params.sampling_type == SamplingType.GREEDY else 1)
+            for row, (seq_ids, params) in enumerate(
+                self.sampling.seq_groups)}
 
 
 class ModelRunner:
@@ -146,6 +159,17 @@ class ModelRunner:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
             self._input_sharding = NamedSharding(mesh, P())
+        # Whether the step programs' results are committed to their
+        # device: a jit output is iff an operand is, so they follow
+        # the weights (a loader's `device_put` commits; weights made
+        # by a jitted program, as the dummy ones are, do not), and
+        # under a mesh everything is. `_dev(..., committed=True)`
+        # follows them, to `_batch_sharding`.
+        self._results_committed = mesh is not None or any(
+            getattr(leaf, "committed", False)
+            for leaf in jax.tree_util.tree_leaves(params))
+        self._batch_sharding = self._input_sharding or \
+            jax.sharding.SingleDeviceSharding(jax.devices()[0])
         # Whether the Pallas prefill page writer can ever run (TPU +
         # fp page dtype + single-device mesh — the writer is a
         # per-chip program; tp-sharded pages take the scatter path):
@@ -200,17 +224,32 @@ class ModelRunner:
             donate_argnums=(3,),      # kv_caches
         )
         self._copy_fn = jax.jit(self._copy_blocks, donate_argnums=(0,))
+        # Small, and apart from the step programs on purpose: a decode
+        # step whose tokens are still on the device takes them through
+        # this, so `_step_sample` keeps its signature and its compiled
+        # programs.
+        self._feed_fn = jax.jit(self._feed,
+                                out_shardings=self._input_sharding)
+        # The prompt-step source of a feed that has none, by shape.
+        self._no_source: Dict[tuple, jax.Array] = {}
 
     # ---- mesh placement helpers ----
 
-    def _dev(self, arr):
+    def _dev(self, arr, committed: bool = False):
         """Host array -> device, with the batch-input sharding made
         EXPLICIT under a mesh (replicated NamedSharding; the same one
         host->device transfer jnp.asarray pays, now with a declared
-        placement instead of a GSPMD guess)."""
-        if self._input_sharding is None:
+        placement instead of a GSPMD guess). `committed`: committed
+        to its device on a single chip too where the step programs'
+        results are (`_results_committed`). A program is lowered again
+        for an operand that changes between committed and not: the
+        packed decode batch, which reaches its step program either
+        from here or as a result of `_feed_fn`, is the same both
+        ways."""
+        if self._input_sharding is None and not (
+                committed and self._results_committed):
             return jnp.asarray(arr)
-        return jax.device_put(arr, self._input_sharding)
+        return jax.device_put(arr, self._batch_sharding)
 
     def _mesh_ctx(self):
         """Context every jitted dispatch runs under: the mesh (so the
@@ -326,6 +365,21 @@ class ModelRunner:
             body, self._unpacked(input_ids, positions, metadata) +
             (kv_caches,), jnp.arange(num_steps, dtype=jnp.int32))
         return packed, kv_caches
+
+    @staticmethod
+    def _feed(rows, decode_packed, prompt_packed):
+        """The token column of a packed decode batch, filled on the
+        device. A row whose token the step before has sampled and the
+        host has not pulled holds -(1 + cell) in that column: `cell`
+        counts through `packed[:, :2]` of that round's decode step row
+        by row, then through its prompt step's (`StepHandle.
+        token_cells`). Every other row keeps the token the host
+        wrote."""
+        cells = jnp.concatenate([decode_packed[:, :2].reshape(-1),
+                                 prompt_packed[:, :2].reshape(-1)])
+        token = rows[:, 0]
+        fed = jnp.take(cells, jnp.maximum(-token - 1, 0), mode="clip")
+        return rows.at[:, 0].set(jnp.where(token < 0, fed, token))
 
     def _copy_blocks(self, kv_caches, src, dst):
         return [
@@ -555,8 +609,19 @@ class ModelRunner:
             prefix.computed = True
 
     def _prepare_decode(
-        self, seq_group_metadata_list: List[SequenceGroupMetadata]
+        self, seq_group_metadata_list: List[SequenceGroupMetadata],
+        fed_by: Tuple[StepHandle, ...] = (),
     ) -> Tuple[dict, SamplingMetadata]:
+        """`fed_by` is the round in flight, its decode step first: a
+        row with a token of it still on the device (`SequenceData.
+        in_flight`) is built one position on, and its token comes
+        from that step's result through `_feed_fn`."""
+        cells: Dict[int, int] = {}
+        offset = 0
+        for handle in fed_by:
+            cells.update((seq_id, offset + cell) for seq_id, cell in
+                         handle.token_cells().items())
+            offset += 2 * handle.packed.shape[0]
         seq_ids_flat: List[int] = []
         seq_groups, seq_data_map, persistent = [], {}, {}
         tokens, positions, slot_list, ctx_list, tables_list = \
@@ -571,8 +636,11 @@ class ModelRunner:
                 seq_data_map[seq_id] = data
                 persistent[seq_id] = md.persistent_data.get(seq_id, {})
                 seq_ids_flat.append(seq_id)
-                tokens.append(data.get_last_token_id())
-                pos = data.get_len() - 1
+                if data.in_flight:
+                    tokens.append(-1 - cells[seq_id])
+                else:
+                    tokens.append(data.get_last_token_id())
+                pos = data.get_len() - 1 + data.in_flight
                 positions.append(pos)
                 table = md.block_tables[seq_id]
                 slot_pos = pos
@@ -604,6 +672,14 @@ class ModelRunner:
 
         inputs = self._send_decode_batch(tokens, positions, slot_list,
                                          ctx_list, tables_list)
+        if min(tokens) < 0:
+            meta = inputs["metadata"]
+            with self._mesh_ctx():
+                inputs["metadata"] = meta.replace(
+                    block_tables=self._feed_fn(
+                        meta.block_tables, fed_by[0].packed,
+                        fed_by[1].packed if len(fed_by) > 1 else
+                        self._no_prompt_source(fed_by[0].packed)))
         sampling = SamplingMetadata(
             seq_groups=seq_groups,
             seq_data=seq_data_map,
@@ -611,6 +687,17 @@ class ModelRunner:
             persistent_metadata=PersistentMetadata(persistent),
         )
         return inputs, sampling
+
+    def _no_prompt_source(self, like) -> jax.Array:
+        """What `_feed_fn` gets for a round in flight that had no
+        prompt step: zeros in the shape of a prompt step's result for
+        up to 8 prompts, so that such a round needs no program of its
+        own."""
+        key = (_PAGES_BUCKET, like.shape[1])
+        if key not in self._no_source:
+            self._no_source[key] = self._dev(
+                np.zeros(key, dtype=np.int32), committed=True)
+        return self._no_source[key]
 
     def _send_decode_batch(self, tokens, positions, slot_list, ctx_list,
                            tables_list, spec_verify: bool = False) -> dict:
@@ -667,7 +754,7 @@ class ModelRunner:
 
         metadata = InputMetadata(
             slot_mapping=None,
-            block_tables=self._dev(rows),
+            block_tables=self._dev(rows, committed=True),
             context_lens=None,
             kv_scale=self.kv_scale,
             tp=self._tp,
@@ -708,79 +795,51 @@ class ModelRunner:
             return self._copy_fn(kv_caches, self._dev(src_arr),
                                  self._dev(dst_arr))
 
-    def execute_model(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-        blocks_to_copy: Optional[Dict[int, List[int]]] = None,
-    ) -> Tuple[SamplerOutput, List[Tuple[jax.Array, jax.Array]]]:
-        with self.tracer.span("runner.prepare"):
-            kv_caches = self._apply_block_copies(kv_caches,
-                                                 blocks_to_copy)
-            if not seq_group_metadata_list:
-                return [], kv_caches
+    def _prepare_step(
+        self, seq_group_metadata_list: List[SequenceGroupMetadata],
+        fed_by: Tuple[StepHandle, ...] = (),
+    ):
+        """The host half of a step (inside `runner.prepare`): the
+        padded batch, the LoRA indices and the sampling plan, which is
+        None when a row has host logits processors."""
+        if seq_group_metadata_list[0].is_prompt:
+            inputs, sampling = self._prepare_prompt(
+                seq_group_metadata_list)
+            rows_per_group = [1] * len(seq_group_metadata_list)
+        else:
+            inputs, sampling = self._prepare_decode(
+                seq_group_metadata_list, fed_by)
+            rows_per_group = [
+                len(md.seq_data) for md in seq_group_metadata_list
+            ]
+        params = self._params_with_lora(
+            seq_group_metadata_list, inputs["padded_batch"],
+            rows_per_group)
+        has_processors = any(
+            p.logits_processors for _, p in sampling.seq_groups)
+        plan = None if has_processors else \
+            self._plan(sampling, inputs["sample_rows"])
+        return inputs, sampling, params, plan
 
-            is_prompt = seq_group_metadata_list[0].is_prompt
-            if is_prompt:
-                inputs, sampling = self._prepare_prompt(
-                    seq_group_metadata_list)
-                rows_per_group = [1] * len(seq_group_metadata_list)
-            else:
-                inputs, sampling = self._prepare_decode(
-                    seq_group_metadata_list)
-                rows_per_group = [
-                    len(md.seq_data) for md in seq_group_metadata_list
-                ]
+    @staticmethod
+    def _fused(plan) -> bool:
+        """Whether the step runs as ONE program, model and sampler.
+        The fused program's sampler statics stay PINNED at the serving
+        default (best_of=1, no top-k logprobs): a varying
+        best_of/logprobs request must not recompile the whole model
+        program; those route through the split path, where only the
+        small sampler program recompiles. Host logits processors (no
+        plan) need the logits mid-pipeline."""
+        return plan is not None and not plan.need_logprobs and \
+            plan.max_best_of == 1 and plan.num_topk == 0
 
-            params = self._params_with_lora(
-                seq_group_metadata_list, inputs["padded_batch"],
-                rows_per_group)
-
-            has_processors = any(
-                p.logits_processors for _, p in sampling.seq_groups)
-            plan = None if has_processors else \
-                self._plan(sampling, inputs["sample_rows"])
-
-        # The fused program's sampler statics stay PINNED at the
-        # serving default (best_of=1, no top-k logprobs): a varying
-        # best_of/logprobs request must not recompile the whole model
-        # program — those route through the split path, where only the
-        # small sampler program recompiles.
-        if has_processors or plan.need_logprobs or \
-                plan.max_best_of != 1 or plan.num_topk != 0:
-            # Raw-logits routes: host logits processors need the
-            # logits mid-pipeline; logprob requests need the full
-            # log-softmax rows. Two device programs.
-            with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-                logits, kv_caches = self._step_fn(
-                    params, inputs["input_ids"], inputs["positions"],
-                    kv_caches, inputs["metadata"], inputs["sel"],
-                    is_prompt=inputs["is_prompt"],
-                    use_prefix=inputs["use_prefix"])
-            self._mark_prefixes(inputs)
-            if has_processors:
-                # The host processors pull the logits: the wait, the
-                # sampler's own small program and its unpacking are
-                # one blocking call.
-                with self.tracer.span("runner.device_wait"):
-                    output = self.sampler(logits[:inputs["num_rows"]],
-                                          sampling)
-                return output, kv_caches
-            with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-                packed, logprobs_dev = _fused_sample_jit(
-                    logits, plan.tensors, plan.key_parts,
-                    max_best_of=plan.max_best_of,
-                    num_topk=plan.num_topk,
-                    need_logprobs=plan.need_logprobs)
-            with self.tracer.span("runner.device_wait"):
-                packed_np = np.asarray(packed)
-            with self.tracer.span("sampler.finalize"):
-                output = self.sampler.finalize(sampling, plan, packed_np,
-                                               logprobs_dev)
-            return output, kv_caches
-
-        # Fast path: model + fused sampler as ONE device program; the
-        # only blocking transfer per round is the packed result pull.
+    def _enqueue(self, inputs: dict, sampling: SamplingMetadata, params,
+                 plan, kv_caches
+                 ) -> Tuple[StepHandle,
+                            List[Tuple[jax.Array, jax.Array]]]:
+        """Dispatch the fused program of a prepared step; nothing
+        blocks."""
+        self.tracer.flight(1)
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._step_sample_fn(
                 params, inputs["input_ids"], inputs["positions"],
@@ -790,11 +849,75 @@ class ModelRunner:
                 use_prefix=inputs["use_prefix"],
                 max_best_of=plan.max_best_of, num_topk=plan.num_topk)
         self._mark_prefixes(inputs)
+        return StepHandle(packed, sampling, plan), kv_caches
+
+    def pull(self, handles: List[StepHandle]) -> List[np.ndarray]:
+        """The ONE blocking transfer for the results of `handles`."""
         with self.tracer.span("runner.device_wait"):
-            packed_np = np.asarray(packed)                 # ONE sync
+            pulled = jax.device_get([h.packed for h in handles])
+        self.tracer.flight(-len(handles))
+        return [np.asarray(p) for p in pulled]
+
+    def execute_model(
+        self,
+        seq_group_metadata_list: List[SequenceGroupMetadata],
+        kv_caches: List[Tuple[jax.Array, jax.Array]],
+        blocks_to_copy: Optional[Dict[int, List[int]]] = None,
+    ) -> Tuple[SamplerOutput, List[Tuple[jax.Array, jax.Array]]]:
+        """One synced step: prepared, dispatched and pulled here, so
+        the outputs returned are this step's own. (`dispatch_step`
+        returns before the result exists; the engine pulls it a round
+        later.)"""
+        with self.tracer.span("runner.prepare"):
+            kv_caches = self._apply_block_copies(kv_caches,
+                                                 blocks_to_copy)
+            if not seq_group_metadata_list:
+                return [], kv_caches
+            inputs, sampling, params, plan = self._prepare_step(
+                seq_group_metadata_list)
+
+        if self._fused(plan):
+            # Fast path: model + fused sampler as ONE device program;
+            # the only blocking transfer is the packed result pull.
+            handle, kv_caches = self._enqueue(inputs, sampling, params,
+                                              plan, kv_caches)
+            (packed_np,) = self.pull([handle])
+            with self.tracer.span("sampler.finalize"):
+                output = self.finalize_step(handle, packed_np)
+            return output, kv_caches
+
+        # Raw-logits routes: host logits processors need the logits
+        # mid-pipeline; logprob requests need the full log-softmax
+        # rows. Two device programs.
+        self.tracer.flight(1)
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
+            logits, kv_caches = self._step_fn(
+                params, inputs["input_ids"], inputs["positions"],
+                kv_caches, inputs["metadata"], inputs["sel"],
+                is_prompt=inputs["is_prompt"],
+                use_prefix=inputs["use_prefix"])
+        self._mark_prefixes(inputs)
+        if plan is None:
+            # The host processors pull the logits: the wait, the
+            # sampler's own small program and its unpacking are one
+            # blocking call.
+            with self.tracer.span("runner.device_wait"):
+                output = self.sampler(logits[:inputs["num_rows"]],
+                                      sampling)
+            self.tracer.flight(-1)
+            return output, kv_caches
+        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
+            packed, logprobs_dev = _fused_sample_jit(
+                logits, plan.tensors, plan.key_parts,
+                max_best_of=plan.max_best_of,
+                num_topk=plan.num_topk,
+                need_logprobs=plan.need_logprobs)
+        with self.tracer.span("runner.device_wait"):
+            packed_np = np.asarray(packed)
+        self.tracer.flight(-1)
         with self.tracer.span("sampler.finalize"):
             output = self.sampler.finalize(sampling, plan, packed_np,
-                                           None)
+                                           logprobs_dev)
         return output, kv_caches
 
     def _plan(self, sampling: SamplingMetadata, pad_to: int,
@@ -806,36 +929,42 @@ class ModelRunner:
             self.tracer.add("sampler.plan_reuse")
         return plan
 
-    def dispatch_prompt(
+    def dispatch_steps(
+        self,
+        batches: List[List[SequenceGroupMetadata]],
+        kv_caches: List[Tuple[jax.Array, jax.Array]],
+        fed_by: Tuple[StepHandle, ...] = (),
+    ) -> Tuple[Optional[List[StepHandle]],
+               List[Tuple[jax.Array, jax.Array]]]:
+        """Enqueue one step for each of `batches` (each all prompt
+        chunks or all decode rows), back to back and WITHOUT syncing
+        (fused-sampler path only): all of them or none. Returns (None,
+        kv_caches untouched) when a batch needs the raw-logits route
+        (host logits processors, logprobs, best_of>1): the caller
+        falls back to synced steps. `fed_by` (decode batches): the
+        handles of the round whose results are still on the device,
+        its decode step first; rows with a token in flight take it
+        from there (`_prepare_decode`)."""
+        with self.tracer.span("runner.prepare"):
+            prepared = [self._prepare_step(mds, fed_by)
+                        for mds in batches]
+        if not all(self._fused(plan) for *_, plan in prepared):
+            return None, kv_caches
+        handles = []
+        for step in prepared:
+            handle, kv_caches = self._enqueue(*step, kv_caches)
+            handles.append(handle)
+        return handles, kv_caches
+
+    def dispatch_step(
         self,
         seq_group_metadata_list: List[SequenceGroupMetadata],
         kv_caches: List[Tuple[jax.Array, jax.Array]],
     ) -> Tuple[Optional[StepHandle], List[Tuple[jax.Array, jax.Array]]]:
-        """Enqueue the prompt step WITHOUT syncing (fused-sampler path
-        only). Returns (None, kv_caches untouched) when the batch needs
-        the raw-logits route (host logits processors, logprobs,
-        best_of>1) — the caller falls back to synced steps."""
-        with self.tracer.span("runner.prepare"):
-            inputs, sampling = self._prepare_prompt(
-                seq_group_metadata_list)
-            if any(p.logits_processors for _, p in sampling.seq_groups):
-                return None, kv_caches
-            plan = self._plan(sampling, inputs["sample_rows"])
-            if plan.need_logprobs or plan.max_best_of != 1 or \
-                    plan.num_topk != 0:
-                return None, kv_caches
-            params = self._params_with_lora(
-                seq_group_metadata_list, inputs["padded_batch"],
-                [1] * len(seq_group_metadata_list))
-        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-            packed, kv_caches = self._step_sample_fn(
-                params, inputs["input_ids"], inputs["positions"],
-                kv_caches, inputs["metadata"], inputs["sel"],
-                plan.tensors, plan.key_parts, is_prompt=True,
-                use_prefix=inputs["use_prefix"],
-                max_best_of=plan.max_best_of, num_topk=plan.num_topk)
-        self._mark_prefixes(inputs)
-        return StepHandle(packed, sampling, plan), kv_caches
+        """`dispatch_steps` for one batch."""
+        handles, kv_caches = self.dispatch_steps(
+            [seq_group_metadata_list], kv_caches)
+        return (handles[0] if handles else None), kv_caches
 
     def finalize_step(self, handle: StepHandle,
                       packed_np: np.ndarray) -> SamplerOutput:
@@ -866,8 +995,7 @@ class ModelRunner:
         kv_caches = self._apply_block_copies(kv_caches, blocks_to_copy)
         handle, kv_caches = self.dispatch_burst(
             seq_group_metadata_list, kv_caches, num_steps, extra_cap)
-        with self.tracer.span("runner.device_wait"):
-            all_packed = np.asarray(handle.packed)         # ONE sync
+        (all_packed,) = self.pull([handle])                # ONE sync
         with self.tracer.span("sampler.finalize"):
             outputs = self.finalize_burst(handle, all_packed)
         return outputs, kv_caches
@@ -913,6 +1041,7 @@ class ModelRunner:
 
             ids, pos, meta = (inputs["input_ids"], inputs["positions"],
                               inputs["metadata"])
+        self.tracer.flight(1)
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, kv_caches = self._burst_scan_fn(
                 params, ids, pos, kv_caches, meta, plan.tensors,
@@ -1022,20 +1151,12 @@ class ModelRunner:
             # position.
             plan = self._plan(sampling, padded, salt_offsets=np.asarray(
                 row_offsets, dtype=np.int32))
-            assert plan.max_best_of == 1 and plan.num_topk == 0 and \
-                not plan.need_logprobs, "spec verify eligibility broken"
-        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-            packed, kv_caches = self._step_sample_fn(
-                params, inputs["input_ids"], inputs["positions"],
-                kv_caches, inputs["metadata"], inputs["sel"],
-                plan.tensors, plan.key_parts,
-                is_prompt=False, use_prefix=False,
-                max_best_of=plan.max_best_of, num_topk=plan.num_topk)
-        with self.tracer.span("runner.device_wait"):
-            packed_np = np.asarray(packed)                 # ONE sync
+            assert self._fused(plan), "spec verify eligibility broken"
+        handle, kv_caches = self._enqueue(inputs, sampling, params, plan,
+                                          kv_caches)
+        (packed_np,) = self.pull([handle])                 # ONE sync
         with self.tracer.span("sampler.finalize"):
-            per_row = self.sampler.finalize(sampling, plan, packed_np,
-                                            None)
+            per_row = self.finalize_step(handle, packed_np)
 
         results: List[SpecVerifyResult] = []
         row = 0

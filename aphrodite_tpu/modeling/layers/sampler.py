@@ -552,7 +552,10 @@ class Sampler:
         if last.seeded is not None:
             at, seq_ids, seeds, siblings = last.seeded
             parts[at, 0] = seeds
-            parts[at, 1] = [len(metadata.seq_data[s].output_token_ids)
+            # The output position sampled for: a token still on the
+            # device (`SequenceData.in_flight`) counts.
+            parts[at, 1] = [len(metadata.seq_data[s].output_token_ids) +
+                            metadata.seq_data[s].in_flight
                             for s in seq_ids]
             parts[at, 2] = siblings
         if salt_offsets is not None:

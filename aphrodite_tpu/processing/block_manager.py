@@ -270,7 +270,10 @@ class BlockSpaceManager:
         table = self.block_tables.get(seq.seq_id)
         if not table or self.block_sliding_window is not None:
             return 0
-        needed = (seq.get_len() - 1) // self.block_size + 1
+        # (the slot of a token still on the device is no look-ahead:
+        # the step that takes it is being scheduled or is in flight)
+        needed = (seq.get_len() - 1 + seq.data.in_flight) \
+            // self.block_size + 1
         freed = 0
         while len(table) > needed and table[-1].ref_count == 1 and \
                 table[-1].device == Device.TPU:

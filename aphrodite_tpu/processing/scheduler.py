@@ -95,6 +95,13 @@ class SchedulerOutputs:
         return [c.group for c in self.prompt_chunks] + self.decode_groups
 
     @property
+    def sampling_groups(self) -> List[SequenceGroup]:
+        """The groups this round samples a token for: its decode rows,
+        and the prompts whose final chunk it runs."""
+        return self.decode_groups + [c.group for c in self.prompt_chunks
+                                     if c.is_final]
+
+    @property
     def prompt_run(self) -> bool:
         # A pure-prefill round (the only kind the reference's prompt_run
         # flag could describe; combined rounds report both counts).
@@ -552,9 +559,17 @@ class Scheduler:
         running: Deque[SequenceGroup] = deque()
         preempted: List[SequenceGroup] = []
         deferred: List[SequenceGroup] = []
+        retiring: List[SequenceGroup] = []
         reclaimed = False
         while self.running:
             seq_group = self.running.popleft()
+            if self._last_token_in_flight(seq_group):
+                # The step in flight computes this row's last token
+                # (the engine runs one round ahead): nothing is left
+                # to schedule, and it holds its pages until that
+                # token is pulled.
+                retiring.append(seq_group)
+                continue
             while not self.block_manager.can_append_slot(seq_group):
                 if not reclaimed:
                     # First resort under page pressure: pull back
@@ -585,8 +600,10 @@ class Scheduler:
         self.running = running
         decode_groups = list(self.running)
         # Deferred rows stay RUNNING (they keep their pages and their
-        # priority) but are not decode rows this round.
+        # priority) but are not decode rows this round; nor are the
+        # rows that wait for their last token.
         self.running.extend(deferred)
+        self.running.extend(retiring)
 
         # 2. Bring swapped groups back while there is room (unless this
         # very step preempted or deferred — swapping both directions is
@@ -683,6 +700,20 @@ class Scheduler:
             blocks_to_copy=blocks_to_copy,
             ignored_seq_groups=ignored,
         )
+
+    def _last_token_in_flight(self, seq_group: SequenceGroup) -> bool:
+        """Whether the token a dispatched step is computing for this
+        row ends it by length (`AphroditeEngine._check_stop`'s two
+        length rules, over the length that counts it)."""
+        seqs = seq_group.get_seqs(status=SequenceStatus.RUNNING)
+        if len(seqs) != 1 or not seqs[0].data.in_flight:
+            return False
+        data = seqs[0].data
+        max_tokens = seq_group.sampling_params.max_tokens
+        return (data.get_len() + data.in_flight >
+                self.scheduler_config.max_model_len
+                or (max_tokens is not None and
+                    data.get_output_len() + data.in_flight >= max_tokens))
 
     def _group_metadata(self, seq_group: SequenceGroup, *, is_prompt: bool,
                         chunk: Optional[PromptChunk] = None
@@ -835,6 +866,7 @@ class Scheduler:
         seq.status = SequenceStatus.WAITING
         self.block_manager.free(seq)
         seq.data.num_computed_tokens = 0
+        seq.data.in_flight = 0
         self.waiting.appendleft(group)
 
     def reserve_decode_burst(self, seq_group_metadata_list,
@@ -940,6 +972,13 @@ class Scheduler:
     def _append_slot(self, seq_group: SequenceGroup,
                      blocks_to_copy: Dict[int, List[int]]) -> None:
         for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
+            if seq.data.in_flight:
+                # The slot of the token still on the device: one
+                # position past the known length. Only a single
+                # sequence whose tail page is its own is dispatched
+                # ahead, so no copy-on-write can arise.
+                self.block_manager.reserve_slots(seq, seq.data.in_flight)
+                continue
             cow = self.block_manager.append_slot(seq)
             if cow is not None:
                 src_block, dst_block = cow
@@ -974,8 +1013,11 @@ class Scheduler:
             seq.status = SequenceStatus.WAITING
             self.block_manager.free(seq)
             # The pages are gone; the re-admitted "prompt" (original +
-            # generated tokens) prefills from scratch.
+            # generated tokens) prefills from scratch; a token of it
+            # that is still on the device is dropped at the pull, and
+            # the recompute samples it again.
             seq.data.num_computed_tokens = 0
+            seq.data.in_flight = 0
         # FCFS: preempted groups go to the front of the waiting queue.
         self.waiting.appendleft(seq_group)
 

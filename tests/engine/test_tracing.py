@@ -159,7 +159,8 @@ def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
     rounds = recorder.rounds()
     paths = [r[-1][2]["path"] for r in rounds]
     assert paths[:3] == ["prompt", "decode", "combined"]
-    assert set(paths[3:]) == {"decode"}
+    # the last call finds nothing to schedule and pulls the last round
+    assert set(paths[3:-1]) == {"decode"} and paths[-1] == "empty"
 
     step = [("sched.schedule", "engine.step"),
             ("runner.prepare", "engine.step"),
@@ -167,19 +168,28 @@ def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
             ("runner.dispatch", "engine.step"),
             ("runner.device_wait", "engine.step"),
             ("sampler.finalize", "engine.step")]
-    # a decode round: every span of its path exactly once
-    for r in rounds[1:2] + rounds[3:]:
+    # a decode round is dispatched, then the round before it is pulled
+    # and processed: every span of the path exactly once, in the order
+    # of a synced round
+    for r in rounds[3:-1]:
         assert _tree(r) == [("engine.step", None)] + step + \
             [("engine.process", "engine.step")]
+    # the first decode round has no round before it to pull (the
+    # prompt round was pulled at once)
+    assert _tree(rounds[1]) == [("engine.step", None)] + step[:4]
     # a prompt round is dispatched without a sync, and the scheduler is
     # asked for a further prompt-only round to chain behind it
     assert _tree(rounds[0]) == [("engine.step", None)] + step[:4] + \
         [("sched.schedule", "engine.step")] + step[4:] + \
         [("engine.process", "engine.step")]
-    # a combined round off the fused path (multi_step 1) is two synced
-    # steps, prompt then decode, and one processing of both
-    assert _tree(rounds[2]) == [("engine.step", None)] + step + \
-        step[1:] + [("engine.process", "engine.step")]
+    # a combined round is prepared whole, decode step and prompt step,
+    # then dispatched, both steps ahead of the pull of the round before
+    assert _tree(rounds[2]) == [("engine.step", None)] + step[:3] + \
+        [step[2], step[3], step[3]] + step[4:] + \
+        [("engine.process", "engine.step")]
+    # the last call: the pull, and the processing of both rounds
+    assert _tree(rounds[-1]) == [("engine.step", None), step[0]] + \
+        step[4:] + [("engine.process", "engine.step")] * 2
     # the facts: `round` counts up; the scheduled round's spans say what
     # ran (the step and the schedule open before that is known)
     numbers = [r[0][2]["round"] for r in rounds]
@@ -298,7 +308,8 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
     monkeypatch.setenv("APHRODITE_SPEC", "0")
     engine = tiny_llm.engine
     engine.add_request("c", None, GREEDY, prompt_token_ids=_prompt(5))
-    engine.step()
+    engine.step()                       # the prompt round
+    engine.step()                       # a decode round, now in flight
     reads = []
 
     def clock():
@@ -309,12 +320,19 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
     monkeypatch.setattr(
         tracing, "TraceAnnotation",
         lambda *a, **k: pytest.fail("annotation with the profiler off"))
-    before = sum(engine.tracer.counts.values())
-    engine.step()                       # one decode round
-    spans = sum(engine.tracer.counts.values()) - before
+    def spans():
+        # (the round also counts two events that are no spans: it was
+        # dispatched ahead, and its plan was the round before's)
+        return sum(n for name, n in engine.tracer.counts.items()
+                   if name not in ("runner.ahead", "sampler.plan_reuse"))
+    before = spans()
+    engine.step()                       # one decode round, one ahead
+    spans = spans() - before
     monkeypatch.undo()
     _drain(engine)
-    assert spans == 8 and len(reads) == 2 * spans
+    # and one read each when the step is dispatched and when the one
+    # before is pulled (`Tracer.flight`)
+    assert spans == 8 and len(reads) == 2 * spans + 2
     # the budget: under 50 us of added host time a round
     tracer = tracing.Tracer()
 
@@ -414,9 +432,11 @@ def test_start_stop_start_in_one_process_and_the_spans_are_in_the_trace(
                   if plane.name.startswith("/host:")
                   for line in plane.lines for e in line.events]
         steps = events.count("aph.engine.step")
-        assert steps == 4               # p's rounds, not o's
+        # p's rounds, not o's: the prompt, three decode rounds, and
+        # the call that pulls the last of them
+        assert steps == 5
         for name in STAGES:
-            assert events.count("aph." + name) >= steps, name
+            assert events.count("aph." + name) >= steps - 1, name
         python_frames.append(sum(1 for n in events if n.startswith("$")))
     # Python frames only when asked for
     assert python_frames[0] == 0 and python_frames[1] > 0
@@ -479,12 +499,16 @@ def test_stage_counters_are_exported_from_the_tracers_totals():
         "aphrodite:queue_wait_seconds_total",
         "aphrodite:requests_first_scheduled_total",
         "aphrodite:preemptions_total",
-        "aphrodite:engine_step_seconds_total"}
+        "aphrodite:engine_step_seconds_total",
+        "aphrodite:steps_ahead_total"}
     # every one reads 0 from the start, not "absent"
     assert {n: _value(n, labels) for n in names} == dict.fromkeys(names,
                                                                  0.0)
     tracer.add("runner.dispatch", 0.25)
     tracer.add("runner.device_wait", 0.5)
+    tracer.add("runner.in_flight", 0.75)
+    tracer.add("runner.ahead")
+    tracer.add("runner.ahead")
     tracer.add("sampler.finalize", 0.125)
     tracer.add("engine.process", 0.125)
     tracer.add("queue_wait", 2.0)
@@ -496,12 +520,61 @@ def test_stage_counters_are_exported_from_the_tracers_totals():
     assert _value("aphrodite:host_process_seconds_total", labels) == \
         pytest.approx(0.25)
     assert _value("aphrodite:host_syncs_total", labels) == 1
+    assert _value("aphrodite:steps_ahead_total", labels) == 2
     assert _value("aphrodite:queue_wait_seconds_total", labels) == \
         pytest.approx(2.0)
     assert _value("aphrodite:requests_first_scheduled_total", labels) == 1
     assert _value("aphrodite:preemptions_total", labels) == 1
     log.log(_stats())                   # a Stats without them: no-op
     assert _value("aphrodite:preemptions_total", labels) == 1
+
+
+def test_device_wait_seconds_are_the_union_of_the_steps_in_flight(
+        monkeypatch):
+    """A step is in flight from its dispatch to its pull; with the
+    engine a round ahead two overlap, and the seconds in which a step
+    was in flight are counted once. The total is current at every
+    dispatch and pull, however long a step stays out."""
+    now = [100.0]
+    monkeypatch.setattr(tracing, "_clock", lambda: now[0])
+    tracer = tracing.Tracer()
+
+    def at(t, steps):
+        now[0] = 100.0 + t
+        tracer.flight(steps)
+        return tracer.seconds["runner.in_flight"], tracer.in_flight
+
+    assert at(0.0, 1) == (0.0, 1)       # round 1 dispatched
+    assert at(1.0, 2) == (1.0, 3)       # round 2, two steps, ahead
+    assert at(1.5, -1) == (1.5, 2)      # round 1 pulled
+    assert at(4.0, -2) == (4.0, 0)      # round 2 pulled: 4 s, not 5.5
+    assert at(6.0, 1) == (4.0, 1)       # nothing was in flight for 2 s
+    now[0] = 107.0
+    tracer.grounded()                   # a failed round: abandoned
+    assert (tracer.seconds["runner.in_flight"], tracer.in_flight) == \
+        (5.0, 0)
+    labels = dict(model_name="tracing-test-d")
+    log = StatLogger(labels=labels)
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    assert _value("aphrodite:device_wait_seconds_total", labels) == 5.0
+
+
+def test_a_synced_step_is_in_flight_from_its_dispatch_to_its_pull(
+        tiny_llm, monkeypatch):
+    """At depth 0 the in-flight seconds are what they were: the
+    dispatch span, the blocking pull and the glue between them."""
+    monkeypatch.setenv("APHRODITE_SPEC", "1")       # keeps it synced
+    engine = tiny_llm.engine
+    seconds, counts = engine.tracer.seconds, engine.tracer.counts
+    before = dict(seconds), counts["runner.ahead"]
+    engine.add_request("sync", None, GREEDY, prompt_token_ids=_prompt(8))
+    _drain(engine)
+    grew = {k: seconds[k] - before[0][k] for k in seconds}
+    spans = grew["runner.dispatch"] + grew["runner.device_wait"]
+    assert spans <= grew["runner.in_flight"] <= spans + 0.05
+    assert counts["runner.ahead"] == before[1]
+    assert engine.tracer.in_flight == 0
 
 
 def test_the_sampling_plan_counters_are_exported_and_a_zero_reads_zero():

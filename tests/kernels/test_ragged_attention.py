@@ -403,6 +403,7 @@ def test_model_runner_builds_consistent_work_list():
     runner.kv_scale = 1.0
     runner.pages_bucket = 8
     runner._input_sharding = None      # single-device placement plan
+    runner._results_committed = False  # weights made by a program
     runner._tp = 1
     runner._decode_work = (None, None)
     runner.model_config = SimpleNamespace(
