@@ -215,9 +215,55 @@ class SamplingParams:
             return SamplingType.GREEDY
         return SamplingType.RANDOM
 
+    # Which step program a row can take: THE list of the conditions,
+    # three facts of a request computed once. A round combines them
+    # (`AphroditeEngine._prompt_fast_path_ok`, `_burst_steps`,
+    # `_spec_eligible`); `ModelRunner._fused(plan)` is the authority
+    # for a prepared step, and `needs_raw_logits` its mirror: true
+    # of every row whose step `_fused` refuses (and of a decode row
+    # that asked for prompt log-probabilities, of which `_fused` can
+    # know that none are owed any more).
+
+    @cached_property
+    def needs_full_logits(self) -> bool:
+        """Logits or whole log-softmax rows leave the step program:
+        host logits processors, beam search, prompt
+        log-probabilities."""
+        return bool(self.logits_processors) or self.use_beam_search \
+            or self.prompt_logprobs is not None
+
+    @cached_property
+    def needs_raw_logits(self) -> bool:
+        """Off the fused step program (model and sampler as one, its
+        sampler statics pinned at `best_of` 1 and no top-k rows):
+        `needs_full_logits`, or a sampler wider than the pinned one.
+        `logprobs=0` stays on it: the sampled token's own
+        log-probability always rides in the packed result."""
+        return self.needs_full_logits or self.best_of > 1 or \
+            (self.logprobs or 0) > 0
+
+    @cached_property
+    def has_penalties(self) -> bool:
+        return abs(self.presence_penalty) >= _SAMPLING_EPS or \
+            abs(self.frequency_penalty) >= _SAMPLING_EPS or \
+            abs(self.repetition_penalty - 1.0) >= _SAMPLING_EPS
+
+    @cached_property
+    def reads_history(self) -> bool:
+        """A sampling stage reads on the host what the request has
+        generated so far (token lists for the penalties, mirostat's
+        mu): its next step cannot be built before its last token is
+        known."""
+        return self.has_penalties or self.mirostat_mode == 2
+
+    _CACHED = ("_knob_row", "needs_full_logits", "needs_raw_logits",
+               "has_penalties", "reads_history")
+
     def clone(self) -> "SamplingParams":
         new = copy.deepcopy(self)
         # The sampler's cached knob row (sampling_metadata.knob_row)
-        # belongs to the object it was computed from.
-        new.__dict__.pop("_knob_row", None)
+        # and the facts above belong to the object they were computed
+        # from.
+        for name in self._CACHED:
+            new.__dict__.pop(name, None)
         return new

@@ -39,7 +39,7 @@ from aphrodite_tpu.engine.supervisor import (FaultClass,
                                              RequestLostOnRebuild,
                                              StaleEngineStepError,
                                              classify_failure)
-from aphrodite_tpu.executor.executor import TPUExecutor
+from aphrodite_tpu.executor.executor import Round, TPUExecutor
 from aphrodite_tpu.processing.admission import (AdmissionController,
                                                 AdmissionSnapshot,
                                                 RequestTimeoutError)
@@ -64,7 +64,7 @@ class ReincarnationOutcome:
 class RoundInFlight:
     """A round dispatched and not pulled yet: what the scheduler
     committed for it, and the handles of its steps, the decode step's
-    first (`TPUExecutor.dispatch_round`)."""
+    first (`TPUExecutor.dispatch_steps`)."""
     scheduler_outputs: SchedulerOutputs
     handles: tuple
 
@@ -158,7 +158,7 @@ class AphroditeEngine:
                                    disagg=parallel_config.disagg,
                                    tracer=self.tracer)
         # Self-drafting speculative decoding: host-side prompt-lookup
-        # drafter feeding the widened verify dispatch (_spec_round).
+        # drafter feeding the widened verify dispatch (_spec_drafts).
         # Advisory per-seq acceptance state only — it survives
         # reincarnation harmlessly (seq_ids never repeat).
         self.drafter = NgramDrafter()
@@ -663,9 +663,9 @@ class AphroditeEngine:
         if self._runs_ahead(prompt_mds, decode_mds, scheduler_outputs):
             self._mark_path("combined" if prompt_mds else "decode",
                             scheduler_outputs)
-            handles = self.executor.dispatch_round(
-                prompt_mds, decode_mds,
-                before.handles if before is not None else ())
+            handles = self.executor.dispatch_steps(Round(
+                prompt=prompt_mds, decode=decode_mds, ahead=True,
+                fed_by=before.handles if before is not None else ()))
             if handles is not None:
                 if before is not None:
                     for _ in handles:
@@ -717,11 +717,11 @@ class AphroditeEngine:
         before, self._ahead = self._ahead, None
         if before is None:
             return []
-        decode_output, *prompt_output = self.executor.finalize_steps(
-            list(before.handles))
+        decode_outputs, *prompt_output = self.executor.finalize_steps(
+            before.handles)
         outputs = self._process_round(
-            prompt_output[0] if prompt_output else None,
-            [decode_output], before.scheduler_outputs, ahead=True)
+            prompt_output[0][0] if prompt_output else None,
+            decode_outputs, before.scheduler_outputs, ahead=True)
         # Processed: no longer the crash barrier's to roll back.
         self._inflight_rounds.remove(before.scheduler_outputs)
         return outputs
@@ -747,6 +747,13 @@ class AphroditeEngine:
 
     def _execute_synced(self, seq_group_metadata_list,
                         scheduler_outputs) -> List[RequestOutput]:
+        """The round at depth 0: described (`Round`), dispatched, and
+        pulled at once. What the description can hold is all the
+        choice there is: a decode-only round may verify drafts (one
+        dispatch can emit up to k+1 tokens per row — strictly better
+        amortization of the weight stream than the burst scan's one
+        token per device step) and where it does not, decode rows may
+        run a burst."""
         if scheduler_outputs.is_empty():
             self._mark_path("empty", scheduler_outputs)
             return self._process_round(None, [], scheduler_outputs)
@@ -754,65 +761,38 @@ class AphroditeEngine:
         n_chunks = len(scheduler_outputs.prompt_chunks)
         prompt_mds = seq_group_metadata_list[:n_chunks]
         decode_mds = seq_group_metadata_list[n_chunks:]
-
+        drafts, burst, extra_cap = None, 1, None
         if decode_mds and not prompt_mds:
-            # Speculative round first: when the drafter has proposals,
-            # one verify dispatch can emit up to k+1 tokens per row —
-            # strictly better amortization of the weight stream than
-            # the burst scan's one token per device step. Falls back
-            # to the classic burst/single-step path (None) whenever
-            # drafting or eligibility fails.
-            spec = self._spec_round(decode_mds, scheduler_outputs)
-            if spec is not None:
-                return spec
-
-        burst, extra_cap = (self._burst_steps(decode_mds,
-                                              scheduler_outputs)
-                            if decode_mds else (1, None))
-
-        if prompt_mds and decode_mds:
-            self._mark_path("combined", scheduler_outputs)
-            prompt_output, decode_outputs = \
-                self.executor.execute_combined(
-                    prompt_mds, decode_mds,
-                    scheduler_outputs.blocks_to_swap_in,
-                    scheduler_outputs.blocks_to_swap_out,
-                    scheduler_outputs.blocks_to_copy,
-                    num_steps=burst, extra_cap=extra_cap)
-            self._flush_kv_handoff(prompt_mds)
-            return self._process_round(prompt_output, decode_outputs,
-                                       scheduler_outputs)
-
-        if decode_mds and burst > 1:
-            self._mark_path("burst", scheduler_outputs)
-            outputs_list = self.executor.execute_decode_burst(
-                decode_mds,
-                scheduler_outputs.blocks_to_swap_in,
-                scheduler_outputs.blocks_to_swap_out,
-                scheduler_outputs.blocks_to_copy,
-                num_steps=burst, extra_cap=extra_cap)
-            return self._process_round(None, outputs_list,
-                                       scheduler_outputs)
-
-        self._mark_path("prompt" if prompt_mds else "decode",
-                        scheduler_outputs)
-        if prompt_mds and not scheduler_outputs.blocks_to_swap_in \
+            drafts = self._spec_drafts(decode_mds, scheduler_outputs)
+        if decode_mds and drafts is None:
+            burst, extra_cap = self._burst_steps(decode_mds,
+                                                 scheduler_outputs)
+        self._mark_path(
+            "spec" if drafts is not None else
+            "combined" if prompt_mds and decode_mds else
+            "burst" if burst > 1 else
+            "prompt" if prompt_mds else "decode", scheduler_outputs)
+        handles = self.executor.dispatch_steps(Round(
+            prompt_mds, decode_mds, scheduler_outputs.blocks_to_swap_in,
+            scheduler_outputs.blocks_to_swap_out,
+            scheduler_outputs.blocks_to_copy, num_steps=burst,
+            extra_cap=extra_cap, drafts=drafts))
+        if prompt_mds and not decode_mds \
+                and not scheduler_outputs.blocks_to_swap_in \
                 and not scheduler_outputs.blocks_to_swap_out \
                 and self._prompt_fast_path_ok(prompt_mds):
-            pipelined = self._pipelined_prompt_rounds(
-                prompt_mds, scheduler_outputs)
-            if pipelined is not None:
-                return pipelined
-
-        output = self.executor.execute_model(
-            seq_group_metadata_list,
-            scheduler_outputs.blocks_to_swap_in,
-            scheduler_outputs.blocks_to_swap_out,
-            scheduler_outputs.blocks_to_copy)
-        if prompt_mds:
-            self._flush_kv_handoff(prompt_mds)
-            return self._process_round(output, [], scheduler_outputs)
-        return self._process_round(None, [output], scheduler_outputs)
+            return self._pipelined_prompt_rounds(handles, prompt_mds,
+                                                 scheduler_outputs)
+        outputs = self.executor.finalize_steps(handles)
+        self._flush_kv_handoff(prompt_mds)
+        if drafts is not None:
+            return self._process_spec_round(outputs[0],
+                                            scheduler_outputs)
+        # (the decode step's outputs first, one for each iteration)
+        prompt_output = outputs.pop()[0] if prompt_mds else None
+        return self._process_round(prompt_output,
+                                   outputs[0] if decode_mds else [],
+                                   scheduler_outputs)
 
     def _flush_kv_handoff(self, prompt_mds) -> None:
         """Disagg only: push the pages of every group whose FINAL
@@ -836,91 +816,67 @@ class AphroditeEngine:
 
     @staticmethod
     def _prompt_fast_path_ok(prompt_mds) -> bool:
-        """Cheap metadata-level precheck mirroring EVERY one of
-        dispatch_step's authoritative plan-based bail conditions
-        (logits processors, need_logprobs, max_best_of != 1,
-        num_topk != 0), so rounds the dispatch would bail on skip the
-        pipelined probe instead of paying the padded batch build
-        twice."""
-        for md in prompt_mds:
-            p = md.sampling_params
-            if (p.logits_processors or p.use_beam_search
-                    or p.prompt_logprobs is not None or p.best_of > 1
-                    # plan.num_topk mirror: the fused program pulls
-                    # top-k logprob rows whenever any row requests
-                    # >= 1 logprobs; logprobs=0 keeps num_topk at 0
-                    # and stays on the fast path (the sampled token's
-                    # own logprob always rides in the packed result).
-                    or (p.logprobs or 0) > 0):
-                return False
-        return True
+        """Whether every row's prompt step is the one fused program:
+        the metadata-level mirror of `ModelRunner._fused(plan)`
+        (`SamplingParams.needs_raw_logits`), so rounds the dispatch
+        would run through the raw-logits route, synced, are not
+        pipelined. A prompt step reads no history yet: penalties and
+        mirostat keep it."""
+        return not any(md.sampling_params.needs_raw_logits
+                       for md in prompt_mds)
 
-    def _pipelined_prompt_rounds(self, prompt_mds, scheduler_outputs):
-        """Batch-building: enqueue up to 4 consecutive pure-prefill
-        rounds (they touch disjoint fresh groups and depend on no
-        sampled token) and pay ONE sync — each avoided round saves a
-        host<->device round trip plus the inter-round host gap. Returns
-        None when the sampling config needs the synced path."""
-        handle = self.executor.dispatch_prompt_round(
-            prompt_mds, scheduler_outputs.blocks_to_copy)
-        if handle is None:
-            return None
+    def _pipelined_prompt_rounds(self, handles, prompt_mds,
+                                 scheduler_outputs):
+        """Batch-building: behind a pure-prefill round just enqueued
+        (`handles`), enqueue up to 3 more (they touch disjoint fresh
+        groups and depend on no sampled token) and pay ONE sync — each
+        avoided round saves a host<->device round trip plus the
+        inter-round host gap."""
         # Off-loop admission commits follow (schedule_prompt_only
         # allocates pages and advances chunk progress): never against
         # a scheduler this step does not own.
         self._check_epoch()
         rounds = [scheduler_outputs]
-        handles = [handle]
+        steps = [handles]               # of each round
         all_prompt_mds = list(prompt_mds)
-        while len(handles) < 4:
+        while len(rounds) < 4:
             with self.tracer.span("sched.schedule"):
                 nxt = self.scheduler.schedule_prompt_only()
             if nxt is None:
                 break
             mds2, outputs2 = nxt
+            rounds.append(outputs2)
+            self._inflight_rounds.append(outputs2)
             if not mds2:
                 # Ignored-only round (over-limit prompts dropped, none
                 # admitted): no device work, but the FINISHED_IGNORED
                 # outputs must still flow to their streams.
-                rounds.append(outputs2)
-                self._inflight_rounds.append(outputs2)
-                handles.append([])
+                steps.append(())
                 break
             # schedule_prompt_only() has already committed this round's
             # admissions (pages allocated, chunk progress advanced), so
-            # an ineligible round must still EXECUTE — synced — not be
-            # dropped: its KV writes and sampled tokens are owed.
-            self._inflight_rounds.append(outputs2)
+            # a round off the fused program must still EXECUTE, not be
+            # dropped: its KV writes and sampled tokens are owed. It
+            # runs synced THROUGH THE EXECUTOR (prompt-only rounds
+            # carry no swaps, but outputs2's CoW copy plan and the LoRA
+            # adapter activation must still apply); earlier dispatches
+            # are already in flight and touch disjoint groups.
             all_prompt_mds.extend(mds2)
-            h2 = None
-            if self._prompt_fast_path_ok(mds2):
-                h2 = self.executor.dispatch_prompt_round(
-                    mds2, outputs2.blocks_to_copy)
-            rounds.append(outputs2)
-            if h2 is None:
-                # Raw-logits sampling config mid-stream: run this round
-                # synced THROUGH THE EXECUTOR (prompt-only rounds carry
-                # no swaps, but outputs2's CoW copy plan and the LoRA
-                # adapter activation must still apply — a direct
-                # model_runner call silently dropped blocks_to_copy);
-                # earlier dispatches are already in flight and touch
-                # disjoint groups.
-                out2 = self.executor.execute_model(
-                    mds2, {}, {}, outputs2.blocks_to_copy)
-                handles.append(out2)        # already finalized
+            steps.append(self.executor.dispatch_steps(Round(
+                prompt=mds2, blocks_to_copy=outputs2.blocks_to_copy)))
+            if not self._prompt_fast_path_ok(mds2):
                 break
-            handles.append(h2)
         # Disagg: hand off every final-chunk group of the batch-built
         # rounds BEFORE the finalize sync — the handoff gather chains
         # on the in-flight prompt programs' donated pool handles (JAX
         # data dependency), so the ICI transfer rides inside the one
         # sync we were paying anyway.
         self._flush_kv_handoff(all_prompt_mds)
-        pending = [h for h in handles if hasattr(h, "packed")]
-        finalized = iter(self.executor.finalize_steps(pending))
+        finalized = iter(self.executor.finalize_steps(
+            [h for handles in steps for h in handles]))
         request_outputs = []
-        for outputs_i, h in zip(rounds, handles):
-            out_i = next(finalized) if hasattr(h, "packed") else h
+        for outputs_i, handles in zip(rounds, steps):
+            out_i = next(finalized)[0] if handles else []
             request_outputs.extend(
                 self._process_round(out_i, [], outputs_i))
         return request_outputs
@@ -932,9 +888,12 @@ class AphroditeEngine:
         reservation and the device position clamp.
 
         Eligible: decode round, no sliding window, and every group is a
-        single-sequence greedy/random group without history-dependent
-        sampling stages (penalties, mirostat), custom processors, or
-        full-logprob needs — everything the device loop can't feed back.
+        single-sequence group that reads no history on the host
+        (everything the device loop can't feed back) and whose logits
+        stay in the program. The scan compiles its sampler statics
+        from the plan, so what only widens them (`best_of`, per-token
+        log-probabilities) keeps the burst: `needs_full_logits`, where
+        the fused step asks `needs_raw_logits`.
         """
         max_steps = self.scheduler_config.multi_step
         if max_steps <= 1:
@@ -945,12 +904,8 @@ class AphroditeEngine:
         extra_cap = {}          # seq_id -> max USEFUL extra slots
         for md in seq_group_metadata_list:
             p = md.sampling_params
-            if (len(md.seq_data) != 1 or p.use_beam_search
-                    or p.logits_processors or p.mirostat_mode == 2
-                    or p.prompt_logprobs is not None
-                    or abs(p.presence_penalty) >= 1e-5
-                    or abs(p.frequency_penalty) >= 1e-5
-                    or abs(p.repetition_penalty - 1.0) >= 1e-5):
+            if len(md.seq_data) != 1 or p.reads_history or \
+                    p.needs_full_logits:
                 return 1, None
             seq_id = next(iter(md.seq_data))
             data = md.seq_data[seq_id]
@@ -994,25 +949,16 @@ class AphroditeEngine:
 
     # -- speculative decoding (self-drafting verify rounds) --
 
-    def _spec_eligible(self, decode_mds) -> bool:
-        """Every group must fit the fused-sampler verify dispatch:
-        the burst-scan conditions (single-seq, no beam / custom
-        processors / mirostat-2 / prompt logprobs / history-dependent
-        penalties) PLUS no per-token logprob requests and best_of=1 —
-        the verify step reuses the pinned fast-path program
-        (max_best_of=1, num_topk=0), and a single ineligible row
-        routes the whole round to the classic path."""
-        for md in decode_mds:
-            p = md.sampling_params
-            if (len(md.seq_data) != 1 or p.use_beam_search
-                    or p.logits_processors or p.mirostat_mode == 2
-                    or p.prompt_logprobs is not None
-                    or (p.logprobs or 0) > 0 or p.best_of > 1
-                    or abs(p.presence_penalty) >= 1e-5
-                    or abs(p.frequency_penalty) >= 1e-5
-                    or abs(p.repetition_penalty - 1.0) >= 1e-5):
-                return False
-        return True
+    def _spec_eligible(self, mds) -> bool:
+        """Every row's step is the pinned fused program and can be
+        built before its last token is known: single-sequence groups
+        that neither need the raw logits nor read their history on
+        the host. A single ineligible row routes the whole round to
+        the classic path."""
+        return all(
+            len(md.seq_data) == 1 and
+            not md.sampling_params.needs_raw_logits and
+            not md.sampling_params.reads_history for md in mds)
 
     @staticmethod
     def _speculates() -> bool:
@@ -1020,15 +966,17 @@ class AphroditeEngine:
         one read of `APHRODITE_SPEC`)."""
         return flags.get_bool("APHRODITE_SPEC")
 
-    def _spec_round(self, decode_mds,
-                    scheduler_outputs) -> Optional[List[RequestOutput]]:
-        """One speculative decode round, or None for the classic path.
+    def _spec_drafts(self, decode_mds, scheduler_outputs
+                     ) -> Optional[Dict[int, List[int]]]:
+        """The drafts of a speculative verify round, or None for the
+        classic path.
 
         Drafts per sequence from its own joint (prompt + output) token
-        history, reserves KV pages for the drafted positions through
-        the same watermark-respecting seam as the burst scan, verifies
-        all rows in one widened dispatch, and applies the accepted
-        runs. `APHRODITE_SPEC=0` pins the classic path for A/B."""
+        history and reserves KV pages for the drafted positions
+        through the same watermark-respecting seam as the burst scan;
+        the round then verifies all rows in one widened dispatch and
+        applies the accepted runs (`_process_spec_round`).
+        `APHRODITE_SPEC=0` pins the classic path for A/B."""
         if not self._speculates():
             return None
         if self.model_config.get_sliding_window() is not None:
@@ -1071,16 +1019,7 @@ class AphroditeEngine:
                 groups=scheduler_outputs.decode_groups)
         if granted < want:
             drafts = {sid: d[:granted] for sid, d in drafts.items()}
-        if not any(drafts.values()):
-            return None
-
-        self._mark_path("spec", scheduler_outputs)
-        results = self.executor.execute_spec_verify(
-            decode_mds, drafts,
-            scheduler_outputs.blocks_to_swap_in,
-            scheduler_outputs.blocks_to_swap_out,
-            scheduler_outputs.blocks_to_copy)
-        return self._process_spec_round(results, scheduler_outputs)
+        return drafts if any(drafts.values()) else None
 
     @tracing.spanned("engine.process")
     def _process_spec_round(

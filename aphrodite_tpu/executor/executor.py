@@ -13,6 +13,7 @@ stats, and give the KV cache `gpu_memory_utilization` of what remains.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -23,8 +24,7 @@ from aphrodite_tpu.common.config import (CacheConfig, DeviceConfig,
                                          ModelConfig, ParallelConfig,
                                          SchedulerConfig)
 from aphrodite_tpu.common.logger import init_logger
-from aphrodite_tpu.common.sequence import (SamplerOutput,
-                                           SequenceGroupMetadata)
+from aphrodite_tpu.common.sequence import SequenceGroupMetadata
 from aphrodite_tpu.executor.cache_engine import CacheEngine
 from aphrodite_tpu.executor.model_runner import ModelRunner, StepHandle
 from aphrodite_tpu.modeling.loader import get_model
@@ -86,6 +86,32 @@ def build_mesh(parallel_config: ParallelConfig,
         devices[:parallel_config.world_size]).reshape(
             parallel_config.mesh_shape)
     return Mesh(mesh_devices, ParallelConfig.MESH_AXES)
+
+
+@dataclass(frozen=True)
+class Round:
+    """What one scheduling round asks of the device: the one argument
+    of `TPUExecutor.dispatch_steps`. A round has up to two steps, over
+    disjoint rows and pages: its prompt chunks' and its decode rows'.
+
+    `num_steps`, `extra_cap`: the device iterations the decode step
+    runs, 1 or a burst's, and each sequence's useful steps of them
+    (`AphroditeEngine._burst_steps`). `drafts`: makes the decode step
+    a speculative verify step, k+1 rows for a sequence with k drafted
+    tokens. `ahead`: the round is dispatched before the one in flight
+    is pulled; the handles of that one are `fed_by`, its decode step's
+    first, and the rows with a token still on the device take it from
+    there."""
+    prompt: List[SequenceGroupMetadata] = field(default_factory=list)
+    decode: List[SequenceGroupMetadata] = field(default_factory=list)
+    blocks_to_swap_in: Dict[int, int] = field(default_factory=dict)
+    blocks_to_swap_out: Dict[int, int] = field(default_factory=dict)
+    blocks_to_copy: Dict[int, List[int]] = field(default_factory=dict)
+    num_steps: int = 1
+    extra_cap: Optional[Dict[int, int]] = None
+    drafts: Optional[Dict[int, List[int]]] = None
+    ahead: bool = False
+    fed_by: Tuple[StepHandle, ...] = ()
 
 
 class TPUExecutor:
@@ -221,9 +247,10 @@ class TPUExecutor:
 
         return jax.tree_util.tree_map(_to_prefill, self.params)
 
-    # Prompt-phase pool indirection: under disagg, prefill programs
-    # read/write the prefill group's pool; colocated they're the one
-    # shared pool. Decode/burst/spec paths always use kv_caches.
+    # The disaggregated layout is which runner and which pool a
+    # round's prompt step uses: `prefill_runner` and the prefill
+    # group's pool. Colocated they're `model_runner` and the one
+    # shared pool. The decode step always uses kv_caches.
     def _prompt_pool(self):
         if self.disagg:
             return self.cache_engine.prefill_kv_caches
@@ -350,9 +377,8 @@ class TPUExecutor:
 
     def _pre_step(self, seq_group_metadata_list, blocks_to_swap_in,
                   blocks_to_swap_out) -> None:
-        """Swaps + LoRA activation shared by single-step and burst."""
-        # Every execution path (single-step, burst, combined, pipelined
-        # prompt dispatch) funnels through here, so one injection point
+        """Swaps + LoRA activation, once a round."""
+        # Every round funnels through here, so one injection point
         # covers the whole device-round surface.
         faultinject.fire("executor.execute_model")
         if blocks_to_swap_out:
@@ -364,223 +390,82 @@ class TPUExecutor:
                 [md.lora_request for md in seq_group_metadata_list])
             self.model_runner.lora_slot_of = self.lora_manager.slot_of
 
-    def execute_model(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        blocks_to_swap_in: Dict[int, int],
-        blocks_to_swap_out: Dict[int, int],
-        blocks_to_copy: Dict[int, List[int]],
-    ) -> SamplerOutput:
-        self._pre_step(seq_group_metadata_list, blocks_to_swap_in,
-                       blocks_to_swap_out)
-        if self.disagg and seq_group_metadata_list and \
-                all(md.is_prompt for md in seq_group_metadata_list):
-            # Pure prompt round -> prefill group. (Mixed rounds go
-            # through execute_combined; decode rounds fall through.)
-            output, new_caches = self.prefill_runner.execute_model(
-                seq_group_metadata_list, self._prompt_pool(),
-                blocks_to_copy)
-            self._set_prompt_pool(new_caches)
-            return output
-        output, new_caches = self.model_runner.execute_model(
-            seq_group_metadata_list, self.cache_engine.kv_caches,
-            blocks_to_copy)
-        self.cache_engine.kv_caches = new_caches
-        return output
+    def _copy_blocks(self, rnd: Round) -> None:
+        """The round's CoW copies, applied to each distinct pool a
+        step of it uses (same page ids, idempotent, so the split
+        layout's mirrors stay coherent whichever phase forked); a
+        round without rows copies in the decode pool."""
+        if not rnd.blocks_to_copy:
+            return
+        if rnd.prompt:
+            self._set_prompt_pool(self.prefill_runner._apply_block_copies(
+                self._prompt_pool(), rnd.blocks_to_copy))
+            if not rnd.decode or \
+                    self._prompt_pool() is self.cache_engine.kv_caches:
+                return
+        self.cache_engine.kv_caches = self.model_runner._apply_block_copies(
+            self.cache_engine.kv_caches, rnd.blocks_to_copy)
 
-    def execute_decode_burst(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        blocks_to_swap_in: Dict[int, int],
-        blocks_to_swap_out: Dict[int, int],
-        blocks_to_copy: Dict[int, List[int]],
-        num_steps: int,
-        extra_cap=None,
-    ) -> List[SamplerOutput]:
-        """Multi-step decode: one scheduling round drives `num_steps`
-        device iterations (see ModelRunner.execute_decode_burst)."""
-        self._pre_step(seq_group_metadata_list, blocks_to_swap_in,
-                       blocks_to_swap_out)
-        outputs, new_caches = self.model_runner.execute_decode_burst(
-            seq_group_metadata_list, self.cache_engine.kv_caches,
-            num_steps, blocks_to_copy, extra_cap)
-        self.cache_engine.kv_caches = new_caches
-        return outputs
+    def dispatch_steps(
+            self, rnd: Round) -> Optional[Tuple[StepHandle, ...]]:
+        """Enqueue a round: the one way into the device. Returns its
+        steps' handles, the decode step's first, for `finalize_steps`
+        (a synced round is this followed at once by that), and for
+        the next round's `fed_by`.
 
-    def execute_spec_verify(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        drafts,
-        blocks_to_swap_in: Dict[int, int],
-        blocks_to_swap_out: Dict[int, int],
-        blocks_to_copy: Dict[int, List[int]],
-    ):
-        """Speculative verify round: k+1 rows per drafted sequence in
-        one dispatch (see ModelRunner.execute_spec_verify)."""
-        self._pre_step(seq_group_metadata_list, blocks_to_swap_in,
-                       blocks_to_swap_out)
-        results, new_caches = self.model_runner.execute_spec_verify(
-            seq_group_metadata_list, self.cache_engine.kv_caches,
-            drafts, blocks_to_copy)
-        self.cache_engine.kv_caches = new_caches
-        return results
+        A synced round enqueues its prompt step, then its decode step
+        (a burst behind a prefill consumes its donated KV handles, so
+        the device serializes them; on the split layout the two run on
+        their own submeshes and pools with NO data dependency and
+        genuinely overlap), and ONE host sync collects both: an
+        arrival costs its prefill's device time, not a round of its
+        own. A step off the fused program (host processors, logprobs,
+        best_of>1) runs through the raw-logits route at once, and its
+        handle comes back finalised. A combined round whose decode
+        step is no burst pulls its prompt step before it enqueues the
+        decode step: two syncs (ROADMAP D3).
 
-    def dispatch_prompt_round(
-        self,
-        prompt_metadata: List[SequenceGroupMetadata],
-        blocks_to_copy: Dict[int, List[int]],
-    ):
-        """Enqueue one pure-prefill round WITHOUT syncing (fast sampler
-        path only; None = caller must run the synced path). Consecutive
-        batch-building rounds chain on the donated KV handles, so the
-        device runs them back-to-back while the host schedules ahead."""
-        self._pre_step(prompt_metadata, {}, {})
-        kv = self.prefill_runner._apply_block_copies(
-            self._prompt_pool(), blocks_to_copy)
-        handle, kv = self.prefill_runner.dispatch_step(
-            prompt_metadata, kv)
-        self._set_prompt_pool(kv)
-        return handle
-
-    def dispatch_round(
-        self,
-        prompt_metadata: List[SequenceGroupMetadata],
-        decode_metadata: List[SequenceGroupMetadata],
-        fed_by: Tuple[StepHandle, ...] = (),
-    ) -> Optional[Tuple[StepHandle, ...]]:
-        """Enqueue a whole round WITHOUT syncing: its decode step, and
-        its prompt step if it has one (disjoint rows and pages; the
-        device runs them in this order). Returns their handles, the
-        decode step's first (what the next round's `fed_by` and
-        `finalize_steps` take), or None, nothing enqueued, when a step
-        needs the raw logits and the caller must run the round synced.
-        Decode rows whose token the round `fed_by` is still computing
-        take it on the device. For a colocated round without swaps or
-        block copies (the engine's `_runs_ahead`)."""
-        self._pre_step(prompt_metadata + decode_metadata, {}, {})
-        batches = [decode_metadata] + \
-            ([prompt_metadata] if prompt_metadata else [])
-        handles, kv = self.model_runner.dispatch_steps(
-            batches, self.cache_engine.kv_caches, fed_by)
-        self.cache_engine.kv_caches = kv
-        return tuple(handles) if handles else None
-
-    def finalize_steps(self, handles):
-        """One transfer for every pending step's packed results, and
-        each step's outputs from them."""
-        pulled = self.model_runner.pull(handles)
-        with self.tracer.span("sampler.finalize"):
-            return [
-                self.prefill_runner.finalize_step(h, p)
-                for h, p in zip(handles, pulled)
-            ]
-
-    def execute_combined(
-        self,
-        prompt_metadata: List[SequenceGroupMetadata],
-        decode_metadata: List[SequenceGroupMetadata],
-        blocks_to_swap_in: Dict[int, int],
-        blocks_to_swap_out: Dict[int, int],
-        blocks_to_copy: Dict[int, List[int]],
-        num_steps: int,
-        extra_cap=None,
-    ) -> Tuple[SamplerOutput, List[SamplerOutput]]:
-        """One combined round: prompt chunks AND the decode batch. The
-        fast path enqueues the prefill program and the decode burst
-        back-to-back (the burst consumes the prefill's donated KV
-        handles, so the device serializes them) and pays ONE host sync
-        for both results — an arrival costs its prefill's device time,
-        not a dedicated scheduling round. Sampling configs off the fused
-        path (host processors, logprobs, best_of>1, burst-ineligible
-        decode) fall back to two synced steps within the round."""
-        self._pre_step(prompt_metadata + decode_metadata,
-                       blocks_to_swap_in, blocks_to_swap_out)
-        if self.disagg:
-            return self._execute_combined_disagg(
-                prompt_metadata, decode_metadata, blocks_to_copy,
-                num_steps, extra_cap)
-        kv = self.model_runner._apply_block_copies(
-            self.cache_engine.kv_caches, blocks_to_copy)
-
-        handle = None
-        if num_steps > 1:
-            handle, kv = self.model_runner.dispatch_step(
-                prompt_metadata, kv)
-        if handle is not None:
-            bhandle, kv = self.model_runner.dispatch_burst(
-                decode_metadata, kv, num_steps, extra_cap)
+        A round `ahead` (colocated, no swaps or copies: the engine's
+        `_runs_ahead`) enqueues its decode step, then its prompt
+        step, both or neither: None, nothing enqueued, when a step is
+        off the fused program and the caller must run the round
+        synced."""
+        self._pre_step(rnd.prompt + rnd.decode, rnd.blocks_to_swap_in,
+                       rnd.blocks_to_swap_out)
+        self._copy_blocks(rnd)
+        if rnd.ahead:
+            handles, kv = self.model_runner.dispatch_steps(
+                [rnd.decode] + ([rnd.prompt] if rnd.prompt else []),
+                self.cache_engine.kv_caches, rnd.fed_by)
             self.cache_engine.kv_caches = kv
-            return self._finalize_combined(self.model_runner, handle,
-                                           bhandle)
+            return tuple(handles) if handles else None
+        handles: List[StepHandle] = []
+        if rnd.prompt:
+            handles, kv = self.prefill_runner.dispatch_steps(
+                [rnd.prompt], self._prompt_pool(), or_raw=True)
+            self._set_prompt_pool(kv)
+            if rnd.decode and rnd.num_steps == 1:
+                self.finalize_steps(handles)
+        if rnd.decode:
+            # (Read after the prompt step wrote its pool back:
+            # colocated, that is this step's input.)
+            (handle,), kv = self.model_runner.dispatch_steps(
+                [rnd.decode], self.cache_engine.kv_caches, or_raw=True,
+                num_steps=rnd.num_steps, extra_cap=rnd.extra_cap,
+                drafts=rnd.drafts)
+            self.cache_engine.kv_caches = kv
+            handles.insert(0, handle)
+        return tuple(handles)
 
-        # Sequential fallback (two syncs): raw-logits prompt sampling
-        # and/or a burst-ineligible decode batch.
-        prompt_out, kv = self.model_runner.execute_model(
-            prompt_metadata, kv)
-        if num_steps > 1:
-            decode_outs, kv = self.model_runner.execute_decode_burst(
-                decode_metadata, kv, num_steps, extra_cap=extra_cap)
-        else:
-            out, kv = self.model_runner.execute_model(decode_metadata, kv)
-            decode_outs = [out]
-        self.cache_engine.kv_caches = kv
-        return prompt_out, decode_outs
-
-    def _finalize_combined(
-            self, prompt_runner: ModelRunner, handle, bhandle,
-    ) -> Tuple[SamplerOutput, List[SamplerOutput]]:
-        """The one host sync of a fused combined round: pull the prompt
-        step's and the burst's packed results together, then unpack
-        each (the prompt's by the runner that dispatched it)."""
-        p_np, b_np = self.model_runner.pull([handle, bhandle])
-        with self.tracer.span("sampler.finalize"):
-            return (prompt_runner.finalize_step(handle, p_np),
-                    self.model_runner.finalize_burst(bhandle, b_np))
-
-    def _execute_combined_disagg(
-        self,
-        prompt_metadata: List[SequenceGroupMetadata],
-        decode_metadata: List[SequenceGroupMetadata],
-        blocks_to_copy: Dict[int, List[int]],
-        num_steps: int,
-        extra_cap=None,
-    ) -> Tuple[SamplerOutput, List[SamplerOutput]]:
-        """Combined round on the split mesh: the prefill program runs on
-        the prefill submesh and the decode burst on the decode submesh
-        with NO data dependency between them (separate pools), so the
-        two groups genuinely overlap and one host sync collects both.
-        This is the interference fix the split buys — a long prefill
-        costs the decode arm nothing but the later page handoff.
-        Round-level CoW copies are applied to BOTH pools (same page
-        ids, idempotent) so the mirrors stay coherent regardless of
-        which phase forked."""
-        pkv = self.prefill_runner._apply_block_copies(
-            self.cache_engine.prefill_kv_caches, blocks_to_copy)
-        dkv = self.model_runner._apply_block_copies(
-            self.cache_engine.kv_caches, blocks_to_copy)
-
-        handle = None
-        if num_steps > 1:
-            handle, pkv = self.prefill_runner.dispatch_step(
-                prompt_metadata, pkv)
-        if handle is not None:
-            bhandle, dkv = self.model_runner.dispatch_burst(
-                decode_metadata, dkv, num_steps, extra_cap)
-            self.cache_engine.prefill_kv_caches = pkv
-            self.cache_engine.kv_caches = dkv
-            return self._finalize_combined(self.prefill_runner, handle,
-                                           bhandle)
-
-        # Sequential fallback — still pool-separated, two syncs.
-        prompt_out, pkv = self.prefill_runner.execute_model(
-            prompt_metadata, pkv)
-        self.cache_engine.prefill_kv_caches = pkv
-        if num_steps > 1:
-            decode_outs, dkv = self.model_runner.execute_decode_burst(
-                decode_metadata, dkv, num_steps, extra_cap=extra_cap)
-        else:
-            out, dkv = self.model_runner.execute_model(
-                decode_metadata, dkv)
-            decode_outs = [out]
-        self.cache_engine.kv_caches = dkv
-        return prompt_out, decode_outs
+    def finalize_steps(self, handles) -> List[list]:
+        """One transfer for every pending step's packed results, and
+        each step's outputs (`ModelRunner.finalize_step`), in the
+        order of `handles`."""
+        pending = [h for h in handles if h.outputs is None]
+        if pending:
+            pulled = self.model_runner.pull(pending)
+            with self.tracer.span("sampler.finalize"):
+                for handle, packed in zip(pending, pulled):
+                    handle.outputs = self.model_runner.finalize_step(
+                        handle, packed)
+        return [h.outputs for h in handles]

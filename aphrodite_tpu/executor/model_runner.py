@@ -88,20 +88,30 @@ class SpecVerifyResult(NamedTuple):
 
 
 class StepHandle:
-    """An enqueued-but-unsynced device step: the packed result is still
-    on device; `ModelRunner.finalize_step/finalize_burst` turns the
-    pulled numpy array into SamplerOutputs. Lets a combined round
+    """One step of a round, enqueued: its packed result is still on
+    the device, until `ModelRunner.pull` brings it over and
+    `finalize_step` turns it into `outputs`. Lets a combined round
     enqueue prefill + decode burst back-to-back and sync once, and the
-    engine dispatch a round before it pulls the one before."""
+    engine dispatch a round before it pulls the one before. A step
+    that ran through the raw-logits route, synced by nature, has its
+    `outputs` from the start and no `packed`.
 
-    __slots__ = ("packed", "sampling", "plan", "num_steps")
+    `num_steps`: the device iterations its program ran (a burst's
+    scan; `packed` is then stacked [num_steps, rows, w]). `verify`: of
+    a speculative verify step, each group's drafted tokens."""
 
-    def __init__(self, packed, sampling, plan,
-                 num_steps: Optional[int] = None) -> None:
+    __slots__ = ("packed", "sampling", "plan", "num_steps", "verify",
+                 "outputs")
+
+    def __init__(self, packed, sampling, plan, num_steps: int = 1,
+                 verify: Optional[List[List[int]]] = None,
+                 outputs: Optional[list] = None) -> None:
         self.packed = packed
         self.sampling = sampling
         self.plan = plan
         self.num_steps = num_steps
+        self.verify = verify
+        self.outputs = outputs
 
     def token_cells(self) -> Dict[int, int]:
         """Where each sequence's sampled token lies in `packed[:, :2]`
@@ -688,6 +698,80 @@ class ModelRunner:
         )
         return inputs, sampling
 
+    def _prepare_spec_verify(
+        self,
+        seq_group_metadata_list: List[SequenceGroupMetadata],
+        drafts: Dict[int, List[int]],
+    ) -> Tuple[dict, SamplingMetadata, np.ndarray]:
+        """Build the widened verify batch: each sequence with k_i draft
+        tokens contributes k_i+1 contiguous (seq, position) rows to the
+        ragged decode work list. Row j carries the token at position
+        L-1+j (the real last token for j=0, draft j-1 after) and
+        attends with ctx = L+j, so row j sees exactly the tokens the
+        classic path would have at that output position — the KV
+        scatter for ALL rows lands before attention, and per-row
+        context_lens masking keeps later rows invisible to earlier
+        ones. Eligibility (single-seq groups, fused-sampler statics
+        pinned at best_of=1 / no logprobs, no penalties) is enforced
+        by the engine. Returns (inputs, with each group's draft under
+        "verify"; sampling; each row's offset for the PRNG salt: the
+        acceptance rule consumes salts per OUTPUT POSITION, so row j
+        of a sequence gets salt1 = output_len + j, exactly the salt
+        the classic path uses when it reaches that position)."""
+        seq_groups, seq_data_map, persistent = [], {}, {}
+        tokens, positions, slot_list, ctx_list, tables_list = \
+            [], [], [], [], []
+        row_offsets: List[int] = []
+        group_drafts: List[List[int]] = []
+
+        for md in seq_group_metadata_list:
+            (seq_id,) = md.seq_data.keys()
+            data = md.seq_data[seq_id]
+            seq_data_map[seq_id] = data
+            persistent[seq_id] = md.persistent_data.get(seq_id, {})
+            table = md.block_tables[seq_id]
+            draft = drafts.get(seq_id) or []
+            group_drafts.append(list(draft))
+            base_pos = data.get_len() - 1
+            row_tokens = [data.get_last_token_id()] + list(draft)
+            for j, tok in enumerate(row_tokens):
+                # One single-seq group PER ROW: the sampler plan then
+                # derives each row's knobs and seed base independently
+                # and finalize emits one output per row.
+                seq_groups.append(([seq_id], md.sampling_params))
+                tokens.append(int(tok))
+                pos = base_pos + j
+                positions.append(pos)
+                # Direct index (no wrap): the scheduler's speculative
+                # page reservation must cover position L-1+k; an
+                # IndexError here means the reservation contract broke.
+                page = table[pos // self.page_size]
+                slot_list.append(page * self.page_size +
+                                 pos % self.page_size)
+                ctx_list.append(pos + 1)
+                tables_list.append(table)
+                row_offsets.append(j)
+
+        # Verify rows legitimately SHARE pages (consecutive positions
+        # of one sequence); the decode invariant that still holds is
+        # slot-exclusivity, which the XLA scatter needs.
+        if __debug__ and flags.get_bool("APHRODITE_DEBUG_KV"):
+            assert len(set(slot_list)) == len(slot_list), (
+                "spec verify rows share a KV slot: "
+                f"{sorted(slot_list)}")
+
+        inputs = self._send_decode_batch(tokens, positions, slot_list,
+                                         ctx_list, tables_list,
+                                         spec_verify=True)
+        sampling = SamplingMetadata(
+            seq_groups=seq_groups,
+            seq_data=seq_data_map,
+            prompt_lens=[],
+            persistent_metadata=PersistentMetadata(persistent),
+        )
+        inputs["verify"] = group_drafts
+        return inputs, sampling, np.asarray(row_offsets, dtype=np.int32)
+
     def _no_prompt_source(self, like) -> jax.Array:
         """What `_feed_fn` gets for a round in flight that had no
         prompt step: zeros in the shape of a prompt step's result for
@@ -797,15 +881,26 @@ class ModelRunner:
 
     def _prepare_step(
         self, seq_group_metadata_list: List[SequenceGroupMetadata],
-        fed_by: Tuple[StepHandle, ...] = (),
+        fed_by: Tuple[StepHandle, ...] = (), num_steps: int = 1,
+        extra_cap: Optional[Dict[int, int]] = None,
+        drafts: Optional[Dict[int, List[int]]] = None,
     ):
         """The host half of a step (inside `runner.prepare`): the
         padded batch, the LoRA indices and the sampling plan, which is
-        None when a row has host logits processors."""
+        None when a row has host logits processors. A decode batch
+        may be a verify round's (`drafts`: k+1 rows a sequence) or a
+        burst's (`num_steps` > 1: the scan's own operands ride in
+        `inputs["burst"]`); the other arguments are a decode batch's
+        too."""
+        salt_offsets = None
         if seq_group_metadata_list[0].is_prompt:
             inputs, sampling = self._prepare_prompt(
                 seq_group_metadata_list)
             rows_per_group = [1] * len(seq_group_metadata_list)
+        elif drafts is not None:
+            inputs, sampling, salt_offsets = self._prepare_spec_verify(
+                seq_group_metadata_list, drafts)
+            rows_per_group = [len(d) + 1 for d in inputs["verify"]]
         else:
             inputs, sampling = self._prepare_decode(
                 seq_group_metadata_list, fed_by)
@@ -818,8 +913,41 @@ class ModelRunner:
         has_processors = any(
             p.logits_processors for _, p in sampling.seq_groups)
         plan = None if has_processors else \
-            self._plan(sampling, inputs["sample_rows"])
+            self._plan(sampling, inputs["sample_rows"], salt_offsets)
+        assert drafts is None or self._fused(plan), \
+            "spec verify eligibility broken"
+        if num_steps > 1 and not inputs["is_prompt"]:
+            inputs["burst"] = self._burst_operands(
+                seq_group_metadata_list, inputs["padded_batch"],
+                num_steps, extra_cap)
         return inputs, sampling, params, plan
+
+    def _burst_operands(
+        self, seq_group_metadata_list: List[SequenceGroupMetadata],
+        padded: int, num_steps: int,
+        extra_cap: Optional[Dict[int, int]],
+    ) -> Tuple[jax.Array, jax.Array, int]:
+        """What the burst scan takes beside a decode step's operands:
+        which rows feed their greedy token back, and each row's last
+        reserved position: pos + the engine's per-seq useful-step cap
+        (tokens remaining / model-len room — ONE source of truth,
+        computed in AphroditeEngine._burst_steps and used for the page
+        reservation), clamped to the burst length. Overshot rows pin
+        there instead of walking the block table past their
+        reservation (advisor r3); pad rows pin at their pad slot."""
+        greedy = np.zeros((padded,), dtype=bool)
+        pos_cap = np.zeros((padded, 1), dtype=np.int32)
+        cap_of = extra_cap or {}
+        row = 0
+        for md in seq_group_metadata_list:
+            n = len(md.seq_data)
+            if md.sampling_params.sampling_type == SamplingType.GREEDY:
+                greedy[row:row + n] = True
+            for seq_id, data in md.seq_data.items():
+                r = min(cap_of.get(seq_id, num_steps), num_steps)
+                pos_cap[row, 0] = data.get_len() - 1 + r
+                row += 1
+        return self._dev(greedy), self._dev(pos_cap), num_steps
 
     @staticmethod
     def _fused(plan) -> bool:
@@ -829,7 +957,8 @@ class ModelRunner:
         best_of/logprobs request must not recompile the whole model
         program; those route through the split path, where only the
         small sampler program recompiles. Host logits processors (no
-        plan) need the logits mid-pipeline."""
+        plan) need the logits mid-pipeline. (What a request's
+        `SamplingParams.needs_raw_logits` says ahead of the plan.)"""
         return plan is not None and not plan.need_logprobs and \
             plan.max_best_of == 1 and plan.num_topk == 0
 
@@ -837,58 +966,41 @@ class ModelRunner:
                  plan, kv_caches
                  ) -> Tuple[StepHandle,
                             List[Tuple[jax.Array, jax.Array]]]:
-        """Dispatch the fused program of a prepared step; nothing
-        blocks."""
+        """Dispatch the one program of a prepared step, the fused step
+        or the burst's scan over it (which compiles its sampler
+        statics from the plan); nothing blocks."""
+        burst = inputs.get("burst")
         self.tracer.flight(1)
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-            packed, kv_caches = self._step_sample_fn(
-                params, inputs["input_ids"], inputs["positions"],
-                kv_caches, inputs["metadata"], inputs["sel"],
-                plan.tensors, plan.key_parts,
-                is_prompt=inputs["is_prompt"],
-                use_prefix=inputs["use_prefix"],
-                max_best_of=plan.max_best_of, num_topk=plan.num_topk)
+            if burst is None:
+                packed, kv_caches = self._step_sample_fn(
+                    params, inputs["input_ids"], inputs["positions"],
+                    kv_caches, inputs["metadata"], inputs["sel"],
+                    plan.tensors, plan.key_parts,
+                    is_prompt=inputs["is_prompt"],
+                    use_prefix=inputs["use_prefix"],
+                    max_best_of=plan.max_best_of, num_topk=plan.num_topk)
+            else:
+                greedy_mask, pos_cap, num_steps = burst
+                packed, kv_caches = self._burst_scan_fn(
+                    params, inputs["input_ids"], inputs["positions"],
+                    kv_caches, inputs["metadata"], plan.tensors,
+                    plan.key_parts, greedy_mask, pos_cap,
+                    num_steps=num_steps, max_best_of=plan.max_best_of,
+                    num_topk=plan.num_topk)
         self._mark_prefixes(inputs)
-        return StepHandle(packed, sampling, plan), kv_caches
+        return StepHandle(packed, sampling, plan,
+                          num_steps=burst[2] if burst else 1,
+                          verify=inputs.get("verify")), kv_caches
 
-    def pull(self, handles: List[StepHandle]) -> List[np.ndarray]:
-        """The ONE blocking transfer for the results of `handles`."""
-        with self.tracer.span("runner.device_wait"):
-            pulled = jax.device_get([h.packed for h in handles])
-        self.tracer.flight(-len(handles))
-        return [np.asarray(p) for p in pulled]
-
-    def execute_model(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-        blocks_to_copy: Optional[Dict[int, List[int]]] = None,
-    ) -> Tuple[SamplerOutput, List[Tuple[jax.Array, jax.Array]]]:
-        """One synced step: prepared, dispatched and pulled here, so
-        the outputs returned are this step's own. (`dispatch_step`
-        returns before the result exists; the engine pulls it a round
-        later.)"""
-        with self.tracer.span("runner.prepare"):
-            kv_caches = self._apply_block_copies(kv_caches,
-                                                 blocks_to_copy)
-            if not seq_group_metadata_list:
-                return [], kv_caches
-            inputs, sampling, params, plan = self._prepare_step(
-                seq_group_metadata_list)
-
-        if self._fused(plan):
-            # Fast path: model + fused sampler as ONE device program;
-            # the only blocking transfer is the packed result pull.
-            handle, kv_caches = self._enqueue(inputs, sampling, params,
-                                              plan, kv_caches)
-            (packed_np,) = self.pull([handle])
-            with self.tracer.span("sampler.finalize"):
-                output = self.finalize_step(handle, packed_np)
-            return output, kv_caches
-
-        # Raw-logits routes: host logits processors need the logits
-        # mid-pipeline; logprob requests need the full log-softmax
-        # rows. Two device programs.
+    def _run_raw(self, inputs: dict, sampling: SamplingMetadata, params,
+                 plan, kv_caches
+                 ) -> Tuple[StepHandle,
+                            List[Tuple[jax.Array, jax.Array]]]:
+        """The raw-logits route of a prepared step, synced by nature:
+        host logits processors need the logits mid-pipeline; logprob
+        requests need the full log-softmax rows. Two device programs,
+        and the handle has its outputs."""
         self.tracer.flight(1)
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             logits, kv_caches = self._step_fn(
@@ -905,7 +1017,8 @@ class ModelRunner:
                 output = self.sampler(logits[:inputs["num_rows"]],
                                       sampling)
             self.tracer.flight(-1)
-            return output, kv_caches
+            return StepHandle(None, sampling, plan,
+                              outputs=[output]), kv_caches
         with self.tracer.span("runner.dispatch"), self._mesh_ctx():
             packed, logprobs_dev = _fused_sample_jit(
                 logits, plan.tensors, plan.key_parts,
@@ -918,7 +1031,8 @@ class ModelRunner:
         with self.tracer.span("sampler.finalize"):
             output = self.sampler.finalize(sampling, plan, packed_np,
                                            logprobs_dev)
-        return output, kv_caches
+        return StepHandle(None, sampling, plan,
+                          outputs=[output]), kv_caches
 
     def _plan(self, sampling: SamplingMetadata, pad_to: int,
               salt_offsets: Optional[np.ndarray] = None):
@@ -934,240 +1048,75 @@ class ModelRunner:
         batches: List[List[SequenceGroupMetadata]],
         kv_caches: List[Tuple[jax.Array, jax.Array]],
         fed_by: Tuple[StepHandle, ...] = (),
+        or_raw: bool = False,
+        **decode_steps,
     ) -> Tuple[Optional[List[StepHandle]],
                List[Tuple[jax.Array, jax.Array]]]:
         """Enqueue one step for each of `batches` (each all prompt
-        chunks or all decode rows), back to back and WITHOUT syncing
-        (fused-sampler path only): all of them or none. Returns (None,
-        kv_caches untouched) when a batch needs the raw-logits route
-        (host logits processors, logprobs, best_of>1): the caller
-        falls back to synced steps. `fed_by` (decode batches): the
-        handles of the round whose results are still on the device,
-        its decode step first; rows with a token in flight take it
-        from there (`_prepare_decode`)."""
+        chunks or all decode rows), back to back and WITHOUT syncing:
+        all of them or none. Returns (None, kv_caches untouched) when
+        a batch is off the fused program (host logits processors,
+        logprobs, best_of>1) — or, `or_raw` and one batch, runs that
+        batch through the raw-logits route at once: its handle has
+        its outputs. `fed_by` (decode batches): the handles of the
+        round whose results are still on the device, its decode step
+        first; rows with a token in flight take it from there
+        (`_prepare_decode`). `decode_steps`: what a decode batch's
+        program runs if not one plain step (`_prepare_step`:
+        `num_steps` and `extra_cap` of a burst, a verify round's
+        `drafts`)."""
         with self.tracer.span("runner.prepare"):
-            prepared = [self._prepare_step(mds, fed_by)
+            prepared = [self._prepare_step(mds, fed_by, **decode_steps)
                         for mds in batches]
-        if not all(self._fused(plan) for *_, plan in prepared):
-            return None, kv_caches
+        if not all(self._fused(plan) or
+                   (plan is not None and "burst" in inputs)
+                   for inputs, _, _, plan in prepared):
+            if not or_raw:
+                return None, kv_caches
+            (step,) = prepared
+            handle, kv_caches = self._run_raw(*step, kv_caches)
+            return [handle], kv_caches
         handles = []
         for step in prepared:
             handle, kv_caches = self._enqueue(*step, kv_caches)
             handles.append(handle)
         return handles, kv_caches
 
-    def dispatch_step(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-    ) -> Tuple[Optional[StepHandle], List[Tuple[jax.Array, jax.Array]]]:
-        """`dispatch_steps` for one batch."""
-        handles, kv_caches = self.dispatch_steps(
-            [seq_group_metadata_list], kv_caches)
-        return (handles[0] if handles else None), kv_caches
+    def pull(self, handles: List[StepHandle]) -> List[np.ndarray]:
+        """The ONE blocking transfer for the results of `handles`."""
+        with self.tracer.span("runner.device_wait"):
+            pulled = jax.device_get([h.packed for h in handles])
+        self.tracer.flight(-len(handles))
+        return [np.asarray(p) for p in pulled]
 
     def finalize_step(self, handle: StepHandle,
-                      packed_np: np.ndarray) -> SamplerOutput:
-        return self.sampler.finalize(handle.sampling, handle.plan,
-                                     packed_np, None)
-
-    def finalize_burst(self, handle: StepHandle,
-                       all_packed: np.ndarray) -> List[SamplerOutput]:
-        return [
-            self.sampler.finalize(handle.sampling, handle.plan,
-                                  all_packed[t], None)
-            for t in range(handle.num_steps)
-        ]
-
-    def execute_decode_burst(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-        num_steps: int,
-        blocks_to_copy: Optional[Dict[int, List[int]]] = None,
-        extra_cap: Optional[Dict[int, int]] = None,
-    ) -> Tuple[List[SamplerOutput], List[Tuple[jax.Array, jax.Array]]]:
-        """Run `num_steps` decode iterations with device-side token
-        feedback as ONE compiled scan dispatch and ONE host sync (the
-        stacked packed results). Eligibility (single-seq greedy/random
-        groups, no history-dependent sampling stages) is enforced by the
-        engine."""
-        kv_caches = self._apply_block_copies(kv_caches, blocks_to_copy)
-        handle, kv_caches = self.dispatch_burst(
-            seq_group_metadata_list, kv_caches, num_steps, extra_cap)
-        (all_packed,) = self.pull([handle])                # ONE sync
-        with self.tracer.span("sampler.finalize"):
-            outputs = self.finalize_burst(handle, all_packed)
-        return outputs, kv_caches
-
-    def dispatch_burst(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-        num_steps: int,
-        extra_cap: Optional[Dict[int, int]] = None,
-    ) -> Tuple[StepHandle, List[Tuple[jax.Array, jax.Array]]]:
-        """Enqueue the K-step decode burst without syncing."""
-        with self.tracer.span("runner.prepare"):
-            inputs, sampling = self._prepare_decode(seq_group_metadata_list)
-            padded = inputs["padded_batch"]
-            rows_per_group = [
-                len(md.seq_data) for md in seq_group_metadata_list
+                      packed_np: np.ndarray) -> list:
+        """A pulled step's outputs: a SamplerOutput for each device
+        iteration it ran (one, or a burst's `num_steps`); of a verify
+        step, each group's accepted run (`SpecVerifyResult`)."""
+        if handle.num_steps > 1:
+            return [
+                self.sampler.finalize(handle.sampling, handle.plan,
+                                      packed_np[t], None)
+                for t in range(handle.num_steps)
             ]
-            params = self._params_with_lora(seq_group_metadata_list, padded,
-                                            rows_per_group)
-            plan = self._plan(sampling, padded)
-
-            greedy = np.zeros((padded,), dtype=bool)
-            # Per-row last reserved position: pos + the engine's per-seq
-            # useful-step cap (tokens remaining / model-len room — ONE
-            # source of truth, computed in AphroditeEngine._burst_steps and
-            # used for the page reservation), clamped to the burst length.
-            # Overshot rows pin here instead of walking the block table
-            # past their reservation (advisor r3); pad rows pin at their
-            # pad slot.
-            pos_cap = np.zeros((padded, 1), dtype=np.int32)
-            cap_of = extra_cap or {}
-            row = 0
-            for md in seq_group_metadata_list:
-                n = len(md.seq_data)
-                if md.sampling_params.sampling_type == SamplingType.GREEDY:
-                    greedy[row:row + n] = True
-                for seq_id, data in md.seq_data.items():
-                    r = min(cap_of.get(seq_id, num_steps), num_steps)
-                    pos_cap[row, 0] = data.get_len() - 1 + r
-                    row += 1
-            greedy_mask = self._dev(greedy)
-
-            ids, pos, meta = (inputs["input_ids"], inputs["positions"],
-                              inputs["metadata"])
-        self.tracer.flight(1)
-        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
-            packed, kv_caches = self._burst_scan_fn(
-                params, ids, pos, kv_caches, meta, plan.tensors,
-                plan.key_parts, greedy_mask, self._dev(pos_cap),
-                num_steps=num_steps, max_best_of=plan.max_best_of,
-                num_topk=plan.num_topk)
-        return StepHandle(packed, sampling, plan,
-                          num_steps=num_steps), kv_caches
-
-    def _prepare_spec_verify(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        drafts: Dict[int, List[int]],
-    ) -> Tuple[dict, SamplingMetadata, List[int], List[int]]:
-        """Build the widened verify batch: each sequence with k_i draft
-        tokens contributes k_i+1 contiguous (seq, position) rows to the
-        ragged decode work list. Row j carries the token at position
-        L-1+j (the real last token for j=0, draft j-1 after) and
-        attends with ctx = L+j, so row j sees exactly the tokens the
-        classic path would have at that output position — the KV
-        scatter for ALL rows lands before attention, and per-row
-        context_lens masking keeps later rows invisible to earlier
-        ones. Returns (inputs, sampling, row position offsets for the
-        PRNG salt, rows per group)."""
-        seq_groups, seq_data_map, persistent = [], {}, {}
-        tokens, positions, slot_list, ctx_list, tables_list = \
-            [], [], [], [], []
-        row_offsets: List[int] = []
-        rows_per_group: List[int] = []
-
-        for md in seq_group_metadata_list:
-            (seq_id,) = md.seq_data.keys()
-            data = md.seq_data[seq_id]
-            seq_data_map[seq_id] = data
-            persistent[seq_id] = md.persistent_data.get(seq_id, {})
-            table = md.block_tables[seq_id]
-            draft = drafts.get(seq_id) or []
-            rows_per_group.append(len(draft) + 1)
-            base_pos = data.get_len() - 1
-            row_tokens = [data.get_last_token_id()] + list(draft)
-            for j, tok in enumerate(row_tokens):
-                # One single-seq group PER ROW: the sampler plan then
-                # derives each row's knobs and seed base independently
-                # and finalize emits one output per row.
-                seq_groups.append(([seq_id], md.sampling_params))
-                tokens.append(int(tok))
-                pos = base_pos + j
-                positions.append(pos)
-                # Direct index (no wrap): the scheduler's speculative
-                # page reservation must cover position L-1+k; an
-                # IndexError here means the reservation contract broke.
-                page = table[pos // self.page_size]
-                slot_list.append(page * self.page_size +
-                                 pos % self.page_size)
-                ctx_list.append(pos + 1)
-                tables_list.append(table)
-                row_offsets.append(j)
-
-        # Verify rows legitimately SHARE pages (consecutive positions
-        # of one sequence); the decode invariant that still holds is
-        # slot-exclusivity, which the XLA scatter needs.
-        if __debug__ and flags.get_bool("APHRODITE_DEBUG_KV"):
-            assert len(set(slot_list)) == len(slot_list), (
-                "spec verify rows share a KV slot: "
-                f"{sorted(slot_list)}")
-
-        inputs = self._send_decode_batch(tokens, positions, slot_list,
-                                         ctx_list, tables_list,
-                                         spec_verify=True)
-        sampling = SamplingMetadata(
-            seq_groups=seq_groups,
-            seq_data=seq_data_map,
-            prompt_lens=[],
-            persistent_metadata=PersistentMetadata(persistent),
-        )
-        return inputs, sampling, row_offsets, rows_per_group
-
-    def execute_spec_verify(
-        self,
-        seq_group_metadata_list: List[SequenceGroupMetadata],
-        kv_caches: List[Tuple[jax.Array, jax.Array]],
-        drafts: Dict[int, List[int]],
-        blocks_to_copy: Optional[Dict[int, List[int]]] = None,
-    ) -> Tuple[List[SpecVerifyResult],
-               List[Tuple[jax.Array, jax.Array]]]:
-        """Score k+1 positions per sequence in ONE dispatch and run
-        delta rejection over each drafted suffix host-side.
-
-        Emitted distribution is the classic path's by construction:
-        row j samples from the TARGET with the PRNG salt of output
-        position output_len+j (never a per-step salt), so greedy and
-        seeded streams are bit-equal to `APHRODITE_SPEC=0`. Eligibility
-        (single-seq groups, fused-sampler statics pinned at best_of=1 /
-        no logprobs, no penalties) is enforced by the engine."""
-        with self.tracer.span("runner.prepare"):
-            kv_caches = self._apply_block_copies(kv_caches,
-                                                 blocks_to_copy)
-            inputs, sampling, row_offsets, rows_per_group = \
-                self._prepare_spec_verify(seq_group_metadata_list,
-                                          drafts)
-            padded = inputs["padded_batch"]
-            params = self._params_with_lora(seq_group_metadata_list,
-                                            padded, rows_per_group)
-            # The acceptance rule consumes salts per OUTPUT POSITION:
-            # row j of a sequence gets salt1 = output_len + j, exactly
-            # the salt the classic path uses when it reaches that
-            # position.
-            plan = self._plan(sampling, padded, salt_offsets=np.asarray(
-                row_offsets, dtype=np.int32))
-            assert self._fused(plan), "spec verify eligibility broken"
-        handle, kv_caches = self._enqueue(inputs, sampling, params, plan,
-                                          kv_caches)
-        (packed_np,) = self.pull([handle])                 # ONE sync
-        with self.tracer.span("sampler.finalize"):
-            per_row = self.finalize_step(handle, packed_np)
-
+        output = self.sampler.finalize(handle.sampling, handle.plan,
+                                       packed_np, None)
+        if handle.verify is None:
+            return [output]
+        # Delta rejection over each drafted suffix, host-side. The
+        # emitted distribution is the classic path's by construction:
+        # row j sampled from the TARGET with the PRNG salt of output
+        # position output_len+j (never a per-step salt), so greedy and
+        # seeded streams are bit-equal to `APHRODITE_SPEC=0`.
         results: List[SpecVerifyResult] = []
         row = 0
-        for md, n_rows in zip(seq_group_metadata_list, rows_per_group):
-            (seq_id,) = md.seq_data.keys()
-            draft = drafts.get(seq_id) or []
-            rows = per_row[row:row + n_rows]
+        for draft in handle.verify:
+            rows = output[row:row + len(draft) + 1]
             sampled = [g.samples[0].output_token for g in rows]
             m = delta_rejection_length(sampled, draft)
             results.append(SpecVerifyResult(
                 samples=[rows[j].samples[0] for j in range(m + 1)],
                 accepted=m, proposed=len(draft)))
-            row += n_rows
-        return results, kv_caches
+            row += len(draft) + 1
+        return results

@@ -7,7 +7,7 @@ jittable function over [batch, k, vocab] probability tensors — no
 module state, no device bookkeeping; acceptance, recovered-distribution
 sampling, and the after-first-rejection masking are all dense vector
 ops. The engine's self-drafting path (processing/drafter.py +
-ModelRunner.execute_spec_verify) uses the DELTA-PROPOSAL
+ModelRunner.finalize_step) uses the DELTA-PROPOSAL
 specialization below: an n-gram drafter is a point-mass proposal
 q = one-hot(draft), for which the general accept/recover machinery
 collapses to `target-sample == draft` (`delta_rejection_length`) —

@@ -153,9 +153,7 @@ def knob_row(p: SamplingParams, vocab_size: int) -> Tuple[np.ndarray, int]:
         p.presence_penalty, p.frequency_penalty, p.repetition_penalty,
     ], dtype=np.float32)
     on = dict(
-        do_penalties=abs(p.presence_penalty) >= _SAMPLING_EPS or
-        abs(p.frequency_penalty) >= _SAMPLING_EPS or
-        abs(p.repetition_penalty - 1.0) >= _SAMPLING_EPS,
+        do_penalties=p.has_penalties,
         do_temperatures=p.dynatemp_range > 0 or (
             p.temperature >= _SAMPLING_EPS and p.temperature != 1.0),
         do_top_p_top_k=p.top_p < 1.0 - _SAMPLING_EPS or

@@ -983,7 +983,7 @@ def main() -> None:
     # vs the classic 1-row decode (same model, same ragged work-list
     # grid; the verify arm rides spec_verify=True, i.e. the slot-wise
     # KV scatter instead of the fused in-kernel write, exactly as
-    # ModelRunner.execute_spec_verify dispatches it). The headline is
+    # ModelRunner.dispatch_steps dispatches it). The headline is
     # the BREAK-EVEN acceptance: cost_verify/cost_classic - 1 drafted
     # tokens must land per step before speculation pays on-device
     # (host-side draft + rejection are noise next to a dispatch). ---

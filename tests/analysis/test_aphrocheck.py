@@ -99,21 +99,23 @@ def test_allowlist_budget():
 
 
 def test_runtime_budget():
-    """The full sweep stays under 2 s on CPU (the --changed subset
-    is ~100 ms) — a checker too slow for pre-commit stops running.
-    Best-of-3: the budget bounds the CHECKER, not a contended CI
-    box — under full-suite load a single sweep can be descheduled
-    for hundreds of ms, and one clean run proves the work fits."""
+    """The full sweep stays under 2 s of the checker's OWN processor
+    time (the --changed subset is ~100 ms) — a checker too slow for
+    pre-commit stops running. `time.process_time()`, not a wall
+    clock: under six xdist workers a sweep of 1.1-1.2 CPU-seconds was
+    descheduled past 2 s of wall time on unchanged code (ROADMAP D11,
+    PRs 24, 29, 30), which said nothing about the checker. Best of
+    three: the first sweep of a process also pays its imports."""
     elapsed = min(_timed_sweep() for _ in range(3))
     assert elapsed < 2.0, \
-        f"aphrocheck full sweep took {elapsed:.2f}s best-of-3 " \
-        "(budget 2s)"
+        f"aphrocheck full sweep took {elapsed:.2f}s of processor " \
+        "time, best of 3 (budget 2s)"
 
 
 def _timed_sweep() -> float:
-    t0 = time.perf_counter()
+    t0 = time.process_time()
     run()
-    return time.perf_counter() - t0
+    return time.process_time() - t0
 
 
 def test_checker_never_imports_jax():

@@ -162,7 +162,8 @@ def test_ledger_domain_map_and_kv_handoff():
     assert domains[f"{runner}._prepare_prompt"] == "prefill"
     # a decode and a speculative-verify batch are sent by one function
     assert domains[f"{runner}._send_decode_batch"] == "decode"
-    assert domains[f"{runner}.dispatch_burst"] == "decode"
+    # what a burst's scan takes beside a decode step's operands
+    assert domains[f"{runner}._burst_operands"] == "decode"
     assert domains[f"{runner}._apply_block_copies"] == "maintenance"
     assert domains[f"{runner}._params_with_lora"] == "shared"
     handoff = baseline["kv_handoff"]

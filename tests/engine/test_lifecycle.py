@@ -375,7 +375,7 @@ def test_async_restore_no_duplicate_chunks(tiny_model_dir,
         sp = SamplingParams(temperature=1.0, seed=7, max_tokens=12,
                             ignore_eos=True)
         armed = {"fire": False, "fired": False}
-        real = engine.engine.executor.execute_model
+        real = engine.engine.executor.dispatch_steps
 
         def maybe_fail(*a, **kw):
             # One-shot fatal, armed by the watcher once tokens have
@@ -386,7 +386,7 @@ def test_async_restore_no_duplicate_chunks(tiny_model_dir,
                 raise InjectedFatalFault("mid-generation kill")
             return real(*a, **kw)
 
-        engine.engine.executor.execute_model = maybe_fail
+        engine.engine.executor.dispatch_steps = maybe_fail
         emissions = []
         async for out in engine.generate(None, sp, "r0",
                                          prompt_token_ids=_prompt(0)):
@@ -431,7 +431,7 @@ def test_stale_step_cannot_commit_after_reincarnation(tiny_model_dir,
     seq = group.get_seqs()[0]
     len_before = seq.get_output_len()
 
-    real = engine.executor.execute_model
+    real = engine.executor.dispatch_steps
 
     def bump_then_run(*a, **kw):
         # Simulate a reincarnation landing while this step is on the
@@ -439,7 +439,7 @@ def test_stale_step_cannot_commit_after_reincarnation(tiny_model_dir,
         engine._epoch += 1
         return real(*a, **kw)
 
-    monkeypatch.setattr(engine.executor, "execute_model",
+    monkeypatch.setattr(engine.executor, "dispatch_steps",
                         bump_then_run)
     with pytest.raises(StaleEngineStepError):
         engine.step()

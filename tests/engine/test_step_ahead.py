@@ -190,16 +190,16 @@ def test_a_stop_that_only_the_token_says_drops_the_token_in_flight(
                 (_prompt(5), greedy(k + 6), 0),
                 (_prompt(6), greedy(5), k)]
     steps = []
-    dispatch = engine.executor.dispatch_round
+    dispatch = engine.executor.dispatch_steps
 
-    def spy(prompt_mds, decode_mds, fed_by=()):
+    def spy(rnd):
         steps.append([md.request_id.rsplit("-", 1)[1]
-                      for md in decode_mds])
-        return dispatch(prompt_mds, decode_mds, fed_by)
+                      for md in rnd.decode])
+        return dispatch(rnd)
 
     with synced(engine):
         want, _ = run(engine, requests)
-    monkeypatch.setattr(engine.executor, "dispatch_round", spy)
+    monkeypatch.setattr(engine.executor, "dispatch_steps", spy)
     got, ahead = run(engine, requests)
     assert got == want and ahead > 0
     assert got[0][0] == tokens[:k + 1] and got[0][2] == "stop"
@@ -217,13 +217,13 @@ def test_a_row_is_never_scheduled_past_its_last_token_by_length(
     requests = [(_prompt(1), greedy(6), 0), (_prompt(2), greedy(11), 0),
                 (_prompt(3, 250), greedy(20), 2)]
     rows = []
-    dispatch = engine.executor.dispatch_round
+    dispatch = engine.executor.dispatch_steps
 
-    def spy(prompt_mds, decode_mds, fed_by=()):
-        rows.extend(md.request_id.rsplit("-", 1)[1] for md in decode_mds)
-        return dispatch(prompt_mds, decode_mds, fed_by)
+    def spy(rnd):
+        rows.extend(md.request_id.rsplit("-", 1)[1] for md in rnd.decode)
+        return dispatch(rnd)
 
-    monkeypatch.setattr(engine.executor, "dispatch_round", spy)
+    monkeypatch.setattr(engine.executor, "dispatch_steps", spy)
     got, ahead = run(engine, requests)
     assert ahead > 0
     assert [len(got[i][0]) for i in range(3)] == [6, 11, 7]
