@@ -25,13 +25,14 @@ from typing import Callable, Dict
 
 from jax.profiler import TraceAnnotation
 
-#: Every span; the four events that are counted but not timed as a
-#: span (`Tracer.add`): a request's wait from arrival to the round that
+#: Every span; the events that are counted but not timed as a span
+#: (`Tracer.add`): a request's wait from arrival to the round that
 #: first schedules it, a preemption, a sampling plan that built and
-#: sent nothing because the batch had not changed, and a step program
-#: dispatched while the round before was still on the device; and the
-#: seconds in which a dispatched step had not been pulled yet
-#: (`Tracer.flight`).
+#: sent nothing because the batch had not changed, a step program
+#: dispatched while the round before was still on the device, and the
+#: KV pages a decode step's attention copies and those of them that
+#: are live; and the seconds in which a dispatched step had not been
+#: pulled yet (`Tracer.flight`).
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
     "engine.step",          # one AphroditeEngine.step()
@@ -47,6 +48,8 @@ NAMES = (
     "preemptions",
     "sampler.plan_reuse",
     "runner.ahead",
+    "attn.pages_fetched",   # pages the decode kernel copies, a step
+    "attn.pages_live",      # pages below the rows' context lengths
     "runner.in_flight",
 )
 
@@ -80,10 +83,10 @@ class Tracer:
         """The facts of the round the step thread is in."""
         self.facts = facts
 
-    def add(self, name: str, secs: float = 0.0) -> None:
-        """Count one occurrence of `name` that lasted `secs`."""
+    def add(self, name: str, secs: float = 0.0, count: int = 1) -> None:
+        """Count `count` occurrences of `name` that lasted `secs`."""
         self.seconds[name] += secs
-        self.counts[name] += 1
+        self.counts[name] += count
 
     def flight(self, steps: int) -> None:
         """`steps` step programs were dispatched (positive) or their

@@ -89,6 +89,14 @@ _STAGE_COUNTERS = [
      "Step programs dispatched while the round before was still on "
      "the device (of aphrodite:sampler_plans_total dispatched).",
      lambda s, c: c["runner.ahead"]),
+    ("aphrodite:decode_attn_pages_fetched_total",
+     "KV pages the decode-attention kernel copied from the pool, "
+     "summed over decode steps (one layer's; host arithmetic).",
+     lambda s, c: c["attn.pages_fetched"]),
+    ("aphrodite:decode_attn_pages_live_total",
+     "KV pages below the rows' context lengths, summed over decode "
+     "steps: what aphrodite:decode_attn_pages_fetched_total cannot "
+     "go under.", lambda s, c: c["attn.pages_live"]),
 ]
 
 

@@ -41,8 +41,10 @@ from aphrodite_tpu.modeling.sampling_metadata import (OutputMetadata,
                                                       PersistentMetadata,
                                                       SamplingMetadata)
 from aphrodite_tpu.ops.kv_cache import copy_blocks as _copy_blocks_op
+from aphrodite_tpu.ops.kv_cache import padded_head_size
 from aphrodite_tpu.ops.pallas.paged_attention import (
-    build_decode_work_list, choose_pages_per_chunk)
+    build_decode_work_list, choose_pages_per_chunk, count_decode_pages,
+    lane_bytes_of, padded_work_length)
 
 logger = init_logger(__name__)
 
@@ -200,6 +202,12 @@ class ModelRunner:
         # count only if the table width doesn't pad back up).
         self.pages_bucket = _PAGES_BUCKET if page_size <= 16 else \
             max(2, _PAGES_BUCKET // 2)
+        # What one token of the decode kernel's widest head block
+        # holds in K: sizes its work item (choose_pages_per_chunk).
+        padded_head = padded_head_size(model_config.get_head_size())
+        self.attn_lane_bytes = max(
+            lane_bytes_of(h, padded_head, kv_cache_dtype)
+            for h in model_config.get_kv_heads_per_layer())
 
         # LoRA: bucket keys carrying slot-stacked adapter tensors, and a
         # slot resolver installed by the executor's WorkerLoRAManager.
@@ -816,25 +824,24 @@ class ModelRunner:
         # context the burst scan reaches (pos_cap pins rows inside
         # their reservation), so the list rides the whole burst.
         ppc = choose_pages_per_chunk(max_pages, self.page_size,
-                                     padded_batch)
+                                     self.attn_lane_bytes)
         page_counts = [len(t) for t in tables_list] + \
             [0] * (padded_batch - batch)
         chunks = tuple(max(1, -(-c // ppc)) for c in page_counts)
-        # Pad the list to padded_batch * 2^k (clamped to the dense cell
-        # count): each (batch, pages) bucket then exposes only a few
-        # possible work-list lengths, so a fluctuating serving mix
-        # reuses compiles; padding is dead items the kernel skips
-        # without issuing DMAs.
-        chunks_cap = -(-max_pages // ppc)
-        mix = 1
-        while padded_batch * mix < sum(chunks):
-            mix *= 2
-        work_key = (chunks, ppc, padded_batch * min(mix, chunks_cap))
+        work_key = (chunks, ppc, padded_work_length(
+            sum(chunks), padded_batch, max_pages, ppc))
         if self._decode_work[0] != work_key:
             wi_seq, wi_chunk = build_decode_work_list(
                 page_counts, ppc, pad_to=work_key[2])
             self._decode_work = (work_key, (self._dev(wi_seq),
                                             self._dev(wi_chunk)))
+        # The pages this step's attention copies and the pages that
+        # are live, by the kernel's rule (host arithmetic over the
+        # rows; `decode_attn_fetch_live_pct`).
+        fetched, live = count_decode_pages(
+            rows[:, 3], chunks, ppc, self.page_size)
+        self.tracer.add("attn.pages_fetched", count=fetched)
+        self.tracer.add("attn.pages_live", count=live)
 
         metadata = InputMetadata(
             slot_mapping=None,

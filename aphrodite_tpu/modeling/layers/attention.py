@@ -284,14 +284,16 @@ class PagedAttention:
             # work-list grid replaces the padded (batch, n_hb) grid
             # unless APHRODITE_ATTN_RAGGED=0 pins the classic kernel.
             from aphrodite_tpu.ops.pallas.paged_attention import (
-                choose_pages_per_chunk)
+                choose_pages_per_chunk, lane_bytes_of)
             work = metadata.decode_work
             if work is not None and metadata.decode_ppc:
                 ppc = metadata.decode_ppc
             else:
                 work = None
                 ppc = choose_pages_per_chunk(
-                    tables.shape[1], k_pages.shape[1], q3.shape[0])
+                    tables.shape[1], k_pages.shape[1],
+                    lane_bytes_of(self.num_kv_heads, self.padded_head,
+                                  k_pages.dtype))
             result = paged_decode_attention(
                 q3, k_pages, v_pages, tables,
                 metadata.context_lens, slopes, knew, vnew,

@@ -20,7 +20,15 @@ round-6 "ragged work-list" grid):
   scalar-prefetches it as two int32 arrays (wi_seq, wi_chunk). Grid is
   (n_hb, num_work_items); short sequences contribute few items, long
   ones many, and list padding is DEAD items (chunk -1) that skip DMA,
-  compute, and output entirely. ONE unified prefetch ring runs across
+  compute, and output entirely. An item is as large as the read ring
+  affords (choose_pages_per_chunk: 512 tokens of bf16 Mistral pages),
+  because a grid cell costs what it costs whatever it holds, and it
+  copies only its pages below the row's context length: a row's last
+  item stops at its last live page, so the table width need be no
+  multiple of the item and no dead page is read (the ring's V slots
+  start clean, so what a partly live item leaves uncopied is finite
+  under p = 0). What is a row's and not an item's (the packed query)
+  is built at the row's first item. ONE unified prefetch ring runs across
   all items: cell i issues cell i+pf_depth's K+V page copies
   back-to-back before waiting its own, so page-DMA latency overlaps
   several cells' compute regardless of how many chunks any sequence
@@ -129,10 +137,19 @@ def _mul_pow2(x, delta):
 # waits write n-_WB_SLOTS's DMA, so deeper rings hide more write latency.
 _WB_SLOTS = 8
 
-# Combined K+V read-ring VMEM budget: the prefetch depth is trimmed so
-# the ring never crowds out the rest of the ~16 MB VMEM when chunks are
-# large (small-batch long-context boosts chunk_tokens to 512).
+# Combined K+V read-ring VMEM budget: it sizes the work item
+# (choose_pages_per_chunk), and the prefetch depth is trimmed to it
+# where a caller asks for larger chunks, so the ring never crowds out
+# the rest of the ~16 MB VMEM.
 _RING_BUDGET_BYTES = 8 * 1024 * 1024
+
+# The largest work item the policy gives, in tokens (the f32 score
+# tile [rows, tokens] and the dequantised K and V of 8-bit pages are
+# temporaries beside the ring), and the slots it leaves the ring: the
+# item in use, two in flight and the one a landing load must not
+# alias.
+_MAX_ITEM_TOKENS = 512
+_MIN_RING_SLOTS = 4
 
 # Ragged work-list length buckets (each distinct padded length is one
 # compiled program — same power-of-two-and-a-half spacing rationale
@@ -168,6 +185,14 @@ def head_block(num_kv_heads: int) -> int:
     return 1
 
 
+def lane_bytes_of(num_kv_heads: int, head_dim: int, dtype) -> int:
+    """What one token of one head block holds in K (or in V): the lane
+    width of a ring slot and of a page copy, in bytes. `head_dim` is
+    the pages' (padded) head size. It sizes the ring (`_ring_slots`)
+    and the work item (`choose_pages_per_chunk`)."""
+    return head_block(num_kv_heads) * head_dim * jnp.dtype(dtype).itemsize
+
+
 def clamp_pages_per_chunk(pages_per_seq: int, requested: int) -> int:
     """Largest divisor of the table width that is <= the requested
     chunk size. The kernel iterates whole chunks over the table, so
@@ -183,20 +208,51 @@ def clamp_pages_per_chunk(pages_per_seq: int, requested: int) -> int:
 
 
 def choose_pages_per_chunk(pages_per_seq: int, page_size: int,
-                           batch: int) -> int:
-    """The shared chunking policy (layer + model runner must agree —
+                           lane_bytes: int) -> int:
+    """The shared work-item policy (layer + model runner must agree —
     the runner builds the ragged work list with it, the layer passes
-    the same value to the kernel). Largest divisor of the table width
-    <= 8, boosted for SMALL batches only: the table width is the batch
-    max, so in a mixed large batch one long sequence would inflate
-    every short sequence's chunk; small-batch long-context is where
-    fewer chunk iterations pay."""
-    ppc = next(d for d in (8, 4, 2, 1) if pages_per_seq % d == 0)
-    if batch < 32:
-        while ppc * 2 <= 32 and pages_per_seq % (ppc * 2) == 0 and \
-                ppc * page_size < 512:
-            ppc *= 2
-    return ppc
+    the same value to the kernel): the largest item, in pages, that
+    leaves the read ring `_MIN_RING_SLOTS` slots inside its budget, a
+    multiple of 128 tokens so the score tile keeps whole lanes, up to
+    `_MAX_ITEM_TOKENS`, at every batch size. `lane_bytes` is
+    `lane_bytes_of` the pages: bf16 Mistral pages and 8-bit ones give
+    512-token items,
+    head blocks twice as wide 256. A cell costs some 0.5 us whatever
+    it holds and a page's two descriptors 40 ns to issue, so an item
+    under 384 tokens of such pages takes longer than its copies
+    (PERF.md §5); what the ring loses in depth costs nothing down to
+    two items ahead. A table narrower than an item is one item; a
+    width that is no multiple of the item needs no divisor, because a
+    row's last item copies only its live pages (the classic grid
+    still clamps to a divisor)."""
+    tokens = _RING_BUDGET_BYTES // (_MIN_RING_SLOTS * 2 * lane_bytes)
+    tokens = min(max(tokens // 128 * 128, 128), _MAX_ITEM_TOKENS)
+    return max(1, min(tokens // page_size, pages_per_seq))
+
+
+def padded_work_length(num_items: int, batch: int, pages_per_seq: int,
+                       pages_per_chunk: int) -> int:
+    """The length the model runner pads a decode work list to: batch x
+    2^k items, clamped to the dense cell count. Each (batch, table
+    width) bucket then exposes only a few list lengths (the length is
+    part of a decode program's key), so a fluctuating serving mix
+    reuses compiles; padding is dead items the kernel skips."""
+    mix = 1
+    while batch * mix < num_items:
+        mix *= 2
+    return batch * min(mix, -(-pages_per_seq // pages_per_chunk))
+
+
+def count_decode_pages(context_lens, chunk_counts, pages_per_chunk: int,
+                       page_size: int):
+    """(fetched, live) pages of one decode call, by the kernel's own
+    rule: an item copies its pages below the row's context length and
+    no others, so a row fetches its live pages as far as its items
+    reach. Host arithmetic for the engine's counters
+    (`aphrodite:decode_attn_pages_*_total`); nothing on the device."""
+    live = -(-np.asarray(context_lens, dtype=np.int64) // page_size)
+    reach = np.asarray(chunk_counts, dtype=np.int64) * pages_per_chunk
+    return int(np.minimum(live, reach).sum()), int(live.sum())
 
 
 def _bucket_work(n: int) -> int:
@@ -579,9 +635,11 @@ def _decode_kernel_ragged(
     kv_scale: float,
     pf_depth: int,
     chunk_slots: int,
+    whole_lanes: bool,
     has_alibi: bool = False,
     fused_write: bool = False,
     amla: bool = True,
+    ablate: str = None,
 ):
     refs = list(refs)
     q_ref, k_hbm, v_hbm = refs[:3]
@@ -591,7 +649,7 @@ def _decode_kernel_ragged(
         knew_ref, vnew_ref = refs[:2]
         out_ref, kp_out, vp_out = refs[2:5]
         scratch = refs[5:]
-        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr,
+        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr,
          kwb, vwb, wbsem, wb_meta) = scratch
         # reads and writes go through the aliased OUTPUT refs so
         # in-place semantics hold
@@ -599,7 +657,7 @@ def _decode_kernel_ragged(
     else:
         knew_ref = vnew_ref = None
         out_ref = refs[0]
-        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr) = refs[1:]
+        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr) = refs[1:]
         kwb = vwb = wbsem = wb_meta = None
 
     j = pl.program_id(0)
@@ -617,74 +675,130 @@ def _decode_kernel_ragged(
     item_live = c >= 0
     ctx = context_lens_ref[s_idx]
 
-    def lanes_of(cell_j):
-        return pl.ds(cell_j * hb * d, hb * d)
+    def page_of(hbm, page_idx, j2):
+        # One head block (n_hb == 1) is the page's whole lane axis:
+        # indexing the page alone makes every copy one contiguous
+        # descriptor; several head blocks each take their lane slice.
+        if whole_lanes:
+            return hbm.at[page_idx]
+        return hbm.at[page_idx, :, pl.ds(j2 * hb * d, hb * d)]
 
-    def chunk_dmas(seq2, c2, j2, slot):
-        # K and V for each page issued back-to-back: one page's two
-        # copies land adjacently in the DMA queue, so the engine
-        # overlaps them instead of draining all K before any V.
-        lanes = lanes_of(j2)
-        copies = []
-        for p in range(pages_per_chunk):  # static unroll
-            page_idx = block_tables_ref[seq2, c2 * pages_per_chunk + p]
-            dst = pl.ds(p * page_size, page_size)
-            copies.append(
-                pltpu.make_async_copy(k_hbm.at[page_idx, :, lanes],
-                                      k_buf.at[slot, dst, :],
-                                      sems.at[slot, 0]))
-            copies.append(
-                pltpu.make_async_copy(v_hbm.at[page_idx, :, lanes],
-                                      v_buf.at[slot, dst, :],
-                                      sems.at[slot, 1]))
-        return copies
+    def live_pages(seq2, c2):
+        # Pages of item (seq2, c2) that lie below the row's context
+        # length: the only ones copied. A row's last item stops at its
+        # last live page (the page the fused write lands in holds
+        # position ctx-1 and is live by this rule); an item a burst
+        # reserved beyond the context copies nothing.
+        pages = (context_lens_ref[seq2] + page_size - 1) // page_size
+        return jnp.clip(pages - c2 * pages_per_chunk, 0, pages_per_chunk)
+
+    def over_live_pages(n_live, visit, whole=None):
+        # A whole item keeps the static unroll (or does `whole` once);
+        # a partly live one guards each page (its last page cannot be
+        # live).
+        @pl.when(n_live == pages_per_chunk)
+        def _():
+            if whole is not None:
+                return whole()
+            for p in range(pages_per_chunk):
+                visit(p)
+
+        @pl.when(n_live < pages_per_chunk)
+        def _():
+            for p in range(pages_per_chunk - 1):
+                @pl.when(p < n_live)
+                def _(p=p):
+                    visit(p)
 
     def start_cell(cell2, j2, w2):
         # Dead targets get no DMAs (and later skip the wait), so list
         # padding costs no bandwidth — only a skipped grid cell.
         @pl.when(wi_chunk_ref[w2] >= 0)
         def _():
+            seq2, c2 = wi_seq_ref[w2], wi_chunk_ref[w2]
             slot2 = jax.lax.rem(cell2, chunk_slots)
-            for dma in chunk_dmas(wi_seq_ref[w2], wi_chunk_ref[w2],
-                                  j2, slot2):
-                dma.start()
+
+            def start_page(p):
+                # K and V of a page issued back-to-back: its two
+                # copies land adjacently in the DMA queue, so the
+                # engine overlaps them instead of draining all K
+                # before any V.
+                page_idx = block_tables_ref[seq2,
+                                            c2 * pages_per_chunk + p]
+                dst = pl.ds(p * page_size, page_size)
+                pltpu.make_async_copy(
+                    page_of(k_hbm, page_idx, j2),
+                    k_buf.at[slot2, dst, :], sems.at[slot2, 0]).start()
+                pltpu.make_async_copy(
+                    page_of(v_hbm, page_idx, j2),
+                    v_buf.at[slot2, dst, :], sems.at[slot2, 1]).start()
+            over_live_pages(live_pages(seq2, c2), start_page)
+
+    def wait_cell(slot, n_live):
+        # A wait reads its semaphore and the size of its destination
+        # only, so it names no page: a whole item waits once for K and
+        # once for V, a partly live one page by page.
+        def wait_on(dst):
+            pltpu.make_async_copy(k_buf.at[slot, dst, :],
+                                  k_buf.at[slot, dst, :],
+                                  sems.at[slot, 0]).wait()
+            pltpu.make_async_copy(v_buf.at[slot, dst, :],
+                                  v_buf.at[slot, dst, :],
+                                  sems.at[slot, 1]).wait()
+
+        over_live_pages(
+            n_live, lambda p: wait_on(pl.ds(p * page_size, page_size)),
+            whole=lambda: wait_on(pl.ds(0, chunk_tokens)))
 
     # ---- unified cross-cell prefetch ring over ALL work items ----
     @pl.when(cell == 0)
     def _():
         if fused_write:
             wb_meta[0] = 0          # writeback counter
+        # What a slot holds where nothing was copied (a partly live
+        # item's dead pages) meets p = 0 in the PV dot, and 0 x NaN is
+        # NaN: the V ring starts clean, and from then on holds only
+        # zeros and copies of live pages. (Stale K only makes scores
+        # that the context mask replaces.)
+        def clean(slot2, _):
+            v_buf[slot2] = jnp.zeros(v_buf.shape[1:], v_buf.dtype)
+        jax.lax.fori_loop(0, chunk_slots, clean, None)
         # Cells 1..pf_depth have no predecessor pf_depth back; cell 0
-        # seeds their loads (static unroll).
-        for seed in range(min(pf_depth + 1, total_cells)):
-            start_cell(seed, seed // nw, seed % nw)
+        # seeds their loads.
+        if ablate != "copies":
+            def seed(cell2, _):
+                start_cell(cell2, cell2 // nw, jax.lax.rem(cell2, nw))
+            jax.lax.fori_loop(0, min(pf_depth + 1, total_cells), seed,
+                              None)
 
-    @pl.when((cell >= 1) & (cell + pf_depth < total_cells))
-    def _():
-        nc = cell + pf_depth
-        start_cell(nc, nc // nw, jax.lax.rem(nc, nw))
+    if ablate != "copies":
+        @pl.when((cell >= 1) & (cell + pf_depth < total_cells))
+        def _():
+            nc = cell + pf_depth
+            start_cell(nc, nc // nw, jax.lax.rem(nc, nw))
 
-    # Block-diagonal q packing (see _decode_kernel_tm); log2(e) folds
-    # into the static scale — base-2 scores for the AMLA rescale.
-    q = q_ref[0, 0].astype(jnp.float32) * \
-        (scale * kv_scale * _LOG2E)                  # [rows, d]
-    q_rep = jax.lax.concatenate([q] * hb, 1)                  # [rows, hb*d]
-    lane_head = jax.lax.broadcasted_iota(
-        jnp.int32, (rows, hb * d), 1) // d
-    row_head = jax.lax.broadcasted_iota(
-        jnp.int32, (rows, hb * d), 0) // group
-    q_packed = jnp.where(lane_head == row_head, q_rep,
-                         0.0).astype(jnp.bfloat16)
-
-    # Cross-chunk online-softmax state persists in VMEM scratch across
-    # grid cells; a sequence's items are grid-adjacent, so resetting at
-    # its chunk 0 and finalizing at its last item needs no inter-cell
-    # HBM combine pass.
+    # Cross-chunk state persists in VMEM scratch across grid cells; a
+    # sequence's items are grid-adjacent, so what is a row's and not an
+    # item's is made at its chunk 0 and read by its later items, and
+    # finalizing at its last item needs no inter-cell HBM combine
+    # pass: the online-softmax state, and the row's packed query.
     @pl.when(item_live & (c == 0))
     def _():
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
+        # Block-diagonal q packing (see _decode_kernel_tm); log2(e)
+        # folds into the static scale — base-2 scores for the AMLA
+        # rescale.
+        q = q_ref[0, 0].astype(jnp.float32) * \
+            (scale * kv_scale * _LOG2E)                  # [rows, d]
+        q_rep = jax.lax.concatenate([q] * hb, 1)         # [rows, hb*d]
+        lane_head = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, hb * d), 1) // d
+        row_head = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, hb * d), 0) // group
+        qp_scr[...] = jnp.where(lane_head == row_head, q_rep,
+                                0.0).astype(jnp.bfloat16)
 
     if fused_write:
         pos_new = jnp.maximum(ctx - 1, 0)
@@ -697,8 +811,8 @@ def _decode_kernel_ragged(
     @pl.when(item_live)
     def _():
         slot = jax.lax.rem(cell, chunk_slots)
-        for dma in chunk_dmas(s_idx, c, j, slot):
-            dma.wait()
+        if ablate != "copies":
+            wait_cell(slot, live_pages(s_idx, c))
 
         if fused_write:
             # Only ONE item per (sequence, head block) writes — the
@@ -718,10 +832,10 @@ def _decode_kernel_ragged(
                     pgs = wb_meta[1 + s_wb]
                     pj = wb_meta[1 + _WB_SLOTS + s_wb]
                     pltpu.make_async_copy(
-                        kwb.at[s_wb], k_hbm.at[pgs, :, lanes_of(pj)],
+                        kwb.at[s_wb], page_of(k_hbm, pgs, pj),
                         wbsem.at[s_wb, 0]).wait()
                     pltpu.make_async_copy(
-                        vwb.at[s_wb], v_hbm.at[pgs, :, lanes_of(pj)],
+                        vwb.at[s_wb], page_of(v_hbm, pgs, pj),
                         wbsem.at[s_wb, 1]).wait()
 
                 pg = pl.ds(p_star * page_size, page_size)
@@ -741,20 +855,23 @@ def _decode_kernel_ragged(
                 kwb[s_wb] = kpage
                 vwb[s_wb] = vpage
                 pltpu.make_async_copy(
-                    kwb.at[s_wb], k_hbm.at[g_star, :, lanes_of(j)],
+                    kwb.at[s_wb], page_of(k_hbm, g_star, j),
                     wbsem.at[s_wb, 0]).start()
                 pltpu.make_async_copy(
-                    vwb.at[s_wb], v_hbm.at[g_star, :, lanes_of(j)],
+                    vwb.at[s_wb], page_of(v_hbm, g_star, j),
                     wbsem.at[s_wb, 1]).start()
                 wb_meta[1 + s_wb] = g_star
                 wb_meta[1 + _WB_SLOTS + s_wb] = j
                 wb_meta[0] = n + 1
 
+        if ablate == "compute":
+            return
+
         k = k_buf[slot]                              # [chunk, hb*d]
         if k.dtype != jnp.bfloat16:                  # int8/fp8 KV dequant
             k = k.astype(jnp.bfloat16)
         s = jax.lax.dot_general(
-            q_packed, k, (((1,), (1,)), ((), ())),
+            qp_scr[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)      # [rows, chunk]
         pos = c * chunk_tokens + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -824,12 +941,10 @@ def _decode_kernel_ragged(
                     pgs = wb_meta[1 + kslot]
                     pj = wb_meta[1 + _WB_SLOTS + kslot]
                     pltpu.make_async_copy(
-                        kwb.at[kslot],
-                        k_hbm.at[pgs, :, lanes_of(pj)],
+                        kwb.at[kslot], page_of(k_hbm, pgs, pj),
                         wbsem.at[kslot, 0]).wait()
                     pltpu.make_async_copy(
-                        vwb.at[kslot],
-                        v_hbm.at[pgs, :, lanes_of(pj)],
+                        vwb.at[kslot], page_of(v_hbm, pgs, pj),
                         wbsem.at[kslot, 1]).wait()
 
 
@@ -847,11 +962,11 @@ def _ring_slots(pf_depth: int, chunk_tokens: int, lane_bytes: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "kv_scale", "pages_per_chunk", "pf_depth",
-                     "amla", "interpret"))
+                     "amla", "interpret", "ablate"))
 def _paged_decode_impl(
     q, k_pages, v_pages, block_tables, context_lens, wi_seq, wi_chunk,
     alibi_slopes, knew, vnew, *, scale, kv_scale, pages_per_chunk,
-    pf_depth, amla, interpret,
+    pf_depth, amla, interpret, ablate=None,
 ):
     batch, num_q_heads, head_dim = q.shape
     num_pages, page_size, hd = k_pages.shape
@@ -864,7 +979,7 @@ def _paged_decode_impl(
     chunk_tokens = pages_per_chunk * page_size
     fused_write = knew is not None
     ragged = wi_seq is not None
-    lane_bytes = hb * head_dim * k_pages.dtype.itemsize
+    lane_bytes = lane_bytes_of(num_kv_heads, head_dim, k_pages.dtype)
     single_chunk = pages_per_seq == pages_per_chunk
 
     # q rows are kv-head-major, so the rows for head block j are the
@@ -890,8 +1005,9 @@ def _paged_decode_impl(
             pages_per_chunk=pages_per_chunk, page_size=page_size,
             scale=scale, kv_scale=kv_scale,
             pf_depth=min(pf_depth, n_slots - 2), chunk_slots=n_slots,
+            whole_lanes=n_hb == 1,
             has_alibi=alibi_slopes is not None, fused_write=fused_write,
-            amla=amla)
+            amla=amla, ablate=ablate)
         grid = (n_hb, nw)
 
         def qmap(j, w, tbl, cl, ws, wc):
@@ -967,6 +1083,9 @@ def _paged_decode_impl(
         pltpu.VMEM((rows, 128), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
     ]
+    if ragged:
+        # a row's packed query, built at its first item
+        scratch.append(pltpu.VMEM((rows, hb * head_dim), jnp.bfloat16))
     out_shape = [jax.ShapeDtypeStruct((out_rows, n_hb, rows, head_dim),
                                       q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, rows, head_dim), qmap)]
@@ -1030,6 +1149,7 @@ def paged_decode_attention(
     pages_per_chunk: int = 8,
     work_items=None,          # (wi_seq [NW+1], wi_chunk [NW]) int32
     amla=None,                # pin the rescale variant (A/B hook)
+    ablate: str = None,       # benchmarks/attn_ab.py: time a part alone
     interpret: bool = False,
 ):
     """Token-major flash-decoding attention (see module docstring).
@@ -1041,14 +1161,19 @@ def paged_decode_attention(
 
     work_items selects the ragged work-list grid (unless pinned off by
     APHRODITE_ATTN_RAGGED=0): arrays from build_decode_work_list,
-    which MUST have been built with the same pages_per_chunk this call
-    resolves to (choose_pages_per_chunk / clamp_pages_per_chunk give a
-    consistent answer for a given table width). Without work_items the
-    classic padded (batch, n_hb) grid runs.
+    which MUST have been built with this call's pages_per_chunk
+    (choose_pages_per_chunk is the policy both sides share). The table
+    width need be no multiple of it: an item copies only its pages
+    below the context length, and those lie inside the table. Without
+    work_items the classic padded (batch, n_hb) grid runs, which walks
+    whole chunks over the table: there pages_per_chunk is clamped DOWN
+    to the largest divisor of the table width, so callers need not
+    pre-pad block tables to a chunk multiple.
 
-    pages_per_chunk is clamped DOWN to the largest divisor of the
-    table width, so callers need not pre-pad block tables to a chunk
-    multiple.
+    `ablate` is the measurement hook of benchmarks/attn_ab.py (ragged
+    grid only; the output is then meaningless): "compute" skips a live
+    item's arithmetic and leaves its copies and waits, "copies" skips
+    the page copies and computes on whatever the ring holds.
 
     `amla` pins the online-softmax rescale variant: True = AMLA
     exponent-bias adds, False = the classic per-chunk multiply (A/B);
@@ -1061,9 +1186,12 @@ def paged_decode_attention(
     if num_q_heads % num_kv_heads != 0:
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=}")
     pages_per_seq = block_tables.shape[1]
-    ppc = clamp_pages_per_chunk(pages_per_seq, pages_per_chunk)
     pf_depth = _pf_depth()      # call-time env read + validation
     use_ragged = work_items is not None and ragged_enabled()
+    ppc = pages_per_chunk if use_ragged else \
+        clamp_pages_per_chunk(pages_per_seq, pages_per_chunk)
+    if ppc < 1:
+        raise ValueError(f"pages_per_chunk must be >= 1, got {ppc}")
     if use_ragged:
         wi_seq, wi_chunk = work_items
         wi_seq = jnp.asarray(wi_seq, jnp.int32)
@@ -1079,4 +1207,5 @@ def paged_decode_attention(
         q, k_pages, v_pages, block_tables, context_lens, wi_seq,
         wi_chunk, alibi_slopes, knew, vnew, scale=scale,
         kv_scale=kv_scale, pages_per_chunk=ppc, pf_depth=pf_depth,
-        amla=use_amla, interpret=interpret)
+        amla=use_amla, interpret=interpret,
+        ablate=ablate if use_ragged else None)

@@ -74,7 +74,7 @@ def main() -> None:
 
     from aphrodite_tpu.ops.attention import paged_decode_attention_ref
     from aphrodite_tpu.ops.pallas.paged_attention import (
-        build_decode_work_list, choose_pages_per_chunk,
+        build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
         paged_decode_attention)
 
     B, page = args.batch, args.page_size
@@ -107,7 +107,8 @@ def main() -> None:
     cl = jnp.asarray(ctxs)
     scale = d ** -0.5
     kv_gb = float(ctxs.sum()) * 2 * Hkv * d * kp.dtype.itemsize / 1e9
-    ppc = choose_pages_per_chunk(pps, page, B)
+    ppc = choose_pages_per_chunk(
+        pps, page, lane_bytes_of(Hkv, d, kp.dtype))
     work = build_decode_work_list(pages_i, ppc)
 
     variants = {

@@ -34,6 +34,9 @@ HIDDEN, LAYERS, HEADS, KV_HEADS, INTER = 4096, 32, 32, 8, 14336
 VOCAB, HEAD_DIM = 32000, 128
 GROUP = 128
 PAGE = 16
+#: one token of the decode kernel's head block (8 KV heads), bf16:
+#: what sizes its work item (choose_pages_per_chunk)
+LANE_BYTES = KV_HEADS * HEAD_DIM * 2
 
 
 def stream_roofline_static(m: int, K: int, N: int, gs: int = GROUP):
@@ -421,7 +424,7 @@ def main() -> None:
         rcl = jnp.full((B,), ctx, dtype=jnp.int32)
         rq = jax.random.normal(key, (B, HEADS, HEAD_DIM),
                                dtype=jnp.bfloat16)
-        r_ppc = choose_pages_per_chunk(r_pps, PAGE, B)
+        r_ppc = choose_pages_per_chunk(r_pps, PAGE, LANE_BYTES)
         r_work = build_decode_work_list([-(-ctx // PAGE)] * B, r_ppc)
         hb = head_block(KV_HEADS)
         n_items = int(r_work[1].shape[0]) * (KV_HEADS // hb)
@@ -543,7 +546,8 @@ def main() -> None:
             build_decode_work_list, choose_pages_per_chunk)
         # Attribution row: the engine default (ragged work-list grid,
         # built exactly as ModelRunner._prepare_decode does).
-        attr_ppc = choose_pages_per_chunk(pages_per_seq, PAGE, B)
+        attr_ppc = choose_pages_per_chunk(pages_per_seq, PAGE,
+                                          LANE_BYTES)
         attr_work = build_decode_work_list(
             [-(-ctx // PAGE)] * B, attr_ppc)
 
@@ -584,7 +588,7 @@ def main() -> None:
             cl32 = jnp.full((ab_b,), ab_ctx, jnp.int32)
             q32 = jax.random.normal(key, (ab_b, HEADS, HEAD_DIM),
                                     dtype=jnp.bfloat16)
-            ab_ppc = choose_pages_per_chunk(pps, PAGE32, ab_b)
+            ab_ppc = choose_pages_per_chunk(pps, PAGE32, LANE_BYTES)
             ab_work = build_decode_work_list(
                 [-(-ab_ctx // PAGE32)] * ab_b, ab_ppc)
             ab_kv = 2 * ab_b * KV_HEADS * ab_ctx * HEAD_DIM * 2
@@ -1041,7 +1045,7 @@ def main() -> None:
             tbl[:, :pps_data] = (sidx[:, None] * pps_data +
                                  np.arange(pps_data)[None, :])
             counts = (-(-ctxl // PAGE)).tolist()
-            ppc = choose_pages_per_chunk(width, PAGE, nrows)
+            ppc = choose_pages_per_chunk(width, PAGE, LANE_BYTES)
             work = build_decode_work_list(counts, ppc)
             meta = InputMetadata(
                 slot_mapping=jnp.asarray(slots),

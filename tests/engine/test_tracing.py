@@ -321,10 +321,13 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
         tracing, "TraceAnnotation",
         lambda *a, **k: pytest.fail("annotation with the profiler off"))
     def spans():
-        # (the round also counts two events that are no spans: it was
-        # dispatched ahead, and its plan was the round before's)
+        # (the round also counts events that are no spans: it was
+        # dispatched ahead, its plan was the round before's, and the
+        # pages its attention copies and those that are live)
         return sum(n for name, n in engine.tracer.counts.items()
-                   if name not in ("runner.ahead", "sampler.plan_reuse"))
+                   if name not in ("runner.ahead", "sampler.plan_reuse",
+                                   "attn.pages_fetched",
+                                   "attn.pages_live"))
     before = spans()
     engine.step()                       # one decode round, one ahead
     spans = spans() - before
@@ -668,3 +671,49 @@ def test_between_steps_is_counted_once_a_round_and_never_while_idle(
     assert _value("aphrodite:engine_rounds_total", labels) >= rounds - 1
     assert _value("aphrodite:host_between_steps_seconds_total",
                   labels) > 0
+
+
+def test_decode_attention_pages_are_counted_a_step_and_exported(
+        tiny_llm, monkeypatch):
+    """Where the runner builds a decode step's work list it counts the
+    pages the step's attention copies and those below the rows' context
+    lengths (the kernel copies live pages only, so the two agree); the
+    totals ride through `Stats` onto what `/metrics` serves."""
+    from prometheus_client import generate_latest
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    runner = engine.executor.model_runner
+    counts = engine.tracer.counts
+    before = counts["attn.pages_fetched"], counts["attn.pages_live"]
+    steps = []
+    real = runner._send_decode_batch
+
+    def spy(tokens, positions, slots, ctx_list, tables, **kw):
+        steps.append(list(ctx_list))
+        return real(tokens, positions, slots, ctx_list, tables, **kw)
+    monkeypatch.setattr(runner, "_send_decode_batch", spy)
+    # 20 and 40 prompt tokens, pages of 16: a row's live pages grow
+    # from 2 to 3 and stay 3 over the steps
+    sp = SamplingParams(temperature=0.0, max_tokens=14, ignore_eos=True)
+    engine.add_request("pages-a", None, sp, prompt_token_ids=_prompt(1))
+    engine.add_request("pages-b", None, sp,
+                       prompt_token_ids=_prompt(2, n=40))
+    _drain(engine)
+    assert len(steps) >= 13
+    pages = sum(-(-ctx // 16) for step in steps for ctx in step)
+    assert {-(-ctx // 16) for step in steps for ctx in step} == {2, 3, 4}
+    assert counts["attn.pages_live"] - before[1] == pages
+    assert counts["attn.pages_fetched"] - before[0] == pages
+    labels = dict(model_name="tracing-test-pages")
+    names = ("aphrodite:decode_attn_pages_fetched_total",
+             "aphrodite:decode_attn_pages_live_total")
+    log = StatLogger(labels=labels)
+    assert [_value(n, labels) for n in names] == [0.0, 0.0]
+    log.log(_stats(stage_seconds=engine.tracer.seconds,
+                   stage_counts=counts))
+    assert [_value(n, labels) for n in names] == [
+        float(counts["attn.pages_fetched"]),
+        float(counts["attn.pages_live"])]
+    served = generate_latest().decode()
+    for name in names:
+        assert name.replace(":", "_") in served or name in served

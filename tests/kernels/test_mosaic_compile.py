@@ -81,17 +81,43 @@ def test_prefill_w4a8_compiles(on_v5e, K, N):
                          group_size=128, stream=False).compile()
 
 
-@pytest.mark.parametrize("fused", [True, False])
-def test_decode_attention_compiles(on_v5e, fused):
+#: (rows, query heads, KV heads, table width in pages, page dtype,
+#: fused KV write). The first two are a small batch; the next six the
+#: benchmark cell's decode programs (48 rows, pages of 16, the three
+#: table widths its contexts of 1,024-1,408 reach, decode steps and the
+#: read-only verify rounds): 512-token items, so a ring that outgrew
+#: the 16 MiB scoped VMEM at the served shape is met here and not on
+#: the chip; then 8-bit pages (512-token items too, the ring twice as
+#: deep) and two head blocks (n_hb > 1: the lane-sliced copies).
+DECODE_CASES = [
+    (8, 32, 8, 24, BF16, True), (8, 32, 8, 24, BF16, False),
+    (48, 32, 8, 72, BF16, True), (48, 32, 8, 72, BF16, False),
+    (48, 32, 8, 80, BF16, True), (48, 32, 8, 80, BF16, False),
+    (48, 32, 8, 88, BF16, True), (48, 32, 8, 88, BF16, False),
+    (48, 32, 8, 88, jnp.int8, True),
+    (48, 32, 16, 88, BF16, True),
+]
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,pps,dtype,fused", DECODE_CASES)
+def test_decode_attention_compiles(on_v5e, B, Hq, Hkv, pps, dtype,
+                                   fused):
     """The ragged decode kernel with the AMLA rescale, fused KV write
-    (decode steps) and read-only (speculative verify rounds)."""
+    (decode steps) and read-only (speculative verify rounds), its work
+    list sized by the shared policy and padded as the runner pads
+    it."""
     from aphrodite_tpu.ops.pallas.paged_attention import (
-        build_decode_work_list, choose_pages_per_chunk,
-        paged_decode_attention)
-    B, Hq, Hkv, d, page, pps = 8, 32, 8, 128, 16, 24
-    ppc = choose_pages_per_chunk(pps, page, B)
-    work = build_decode_work_list([pps] * B, ppc)
-    pages = on_v5e((2048, page, Hkv * d), BF16)
+        build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
+        padded_work_length, paged_decode_attention)
+    d, page = 128, 16
+    ppc = choose_pages_per_chunk(pps, page,
+                                 lane_bytes_of(Hkv, d, dtype))
+    # rows spread over the last bucket of the table, as contexts are
+    counts = [pps - i % 8 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    pages = on_v5e((5077, page, Hkv * d), dtype)
     new = on_v5e((B, Hkv, d), BF16)
 
     def attend(q, kp, vp, tables, ctx, kn, vn):

@@ -232,6 +232,128 @@ def test_ragged_env_pin_selects_classic(monkeypatch):
                                rtol=1e-2, atol=1e-2)
 
 
+# ---- 256-token items: only live pages are copied ----
+
+#: Contexts under 16-page (256-token) items on a table 40 pages wide,
+#: which is no multiple of the item. A row's last item has 1 page live
+#: (257), some (356: 7; 640: 8, reaching the table's last column) or
+#: all 16 (512); a pad row; a row whose first item is partly live.
+ITEM_CTX = np.array([257, 356, 512, 640, 0, 5], dtype=np.int32)
+ITEM_PAGE, ITEM_PPC, ITEM_WIDTH = 16, 16, 40
+
+
+def item_problem(reserve=0, seed=3):
+    """Rows of ITEM_CTX, each with pages for `reserve` tokens more
+    than its context (a burst's reservation). Every page that no row
+    holds is NaN, page 0 among them, which the table's pad entries
+    point at: a kernel that copied a dead page would carry NaN into
+    the PV dot (0 x NaN)."""
+    rng = np.random.default_rng(seed)
+    B, Hq, Hkv, d = len(ITEM_CTX), 8, 2, 128
+    pool = 1 + sum(-(-(int(c) + reserve) // ITEM_PAGE)
+                   for c in ITEM_CTX if c)
+    q = rng.normal(size=(B, Hq, d)).astype(np.float32)
+    kp = np.full((pool + 8, ITEM_PAGE, Hkv * d), np.nan, np.float32)
+    vp = kp.copy()
+    bt = np.zeros((B, ITEM_WIDTH), dtype=np.int32)
+    perm = rng.permutation(pool - 1) + 1
+    counts, taken = [], 0
+    for b, c in enumerate(ITEM_CTX):
+        n = min(-(-(int(c) + reserve) // ITEM_PAGE), ITEM_WIDTH) \
+            if c else 0
+        bt[b, :n] = perm[taken:taken + n]
+        taken += n
+        counts.append(n)
+        kp[bt[b, :n]] = rng.normal(size=(n, ITEM_PAGE, Hkv * d))
+        vp[bt[b, :n]] = rng.normal(size=(n, ITEM_PAGE, Hkv * d))
+    return q, kp, vp, bt, counts
+
+
+def _against_oracle(got, q, kp, vp, bt, ctx):
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    want = numpy_paged_attention(q, kp, vp, bt, np.maximum(ctx, 1), 0.1)
+    live = ctx > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-2,
+                               atol=1e-2)
+    np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
+
+
+def test_items_of_256_tokens_copy_live_pages_only():
+    """The oracle at 256-token items on a table that is no multiple of
+    the item, with NaN in every page no row holds: the output is
+    finite and the oracle's, so a row's last item copied its live
+    pages and nothing else, and what the ring held beside them was
+    clean."""
+    q, kp, vp, bt, counts = item_problem()
+    assert ITEM_WIDTH % ITEM_PPC and max(counts) == ITEM_WIDTH
+    work = build_decode_work_list(counts, ITEM_PPC)
+    got = paged_decode_attention(
+        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
+        jnp.array(ITEM_CTX), scale=0.1, pages_per_chunk=ITEM_PPC,
+        work_items=work, interpret=True)
+    _against_oracle(got, q, kp, vp, bt, ITEM_CTX)
+
+
+def test_fused_write_lands_in_a_partly_live_item():
+    """Position ctx-1 of the 257-token row is the one live page of its
+    last item, of the 5-token row a page of a first item that is
+    partly live: the page written back is the slot writer's, and the
+    NaN pages stay what they were."""
+    from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+    rng = np.random.default_rng(5)
+    q, kp, vp, bt, counts = item_problem()
+    B = len(ITEM_CTX)
+    knew = rng.normal(size=(B, 2, 128)).astype(np.float32)
+    vnew = rng.normal(size=(B, 2, 128)).astype(np.float32)
+    slots = np.full((B,), kp.shape[0] * ITEM_PAGE, dtype=np.int32)
+    for b, c in enumerate(ITEM_CTX):
+        if c:
+            slots[b] = bt[b, (c - 1) // ITEM_PAGE] * ITEM_PAGE + \
+                (c - 1) % ITEM_PAGE
+    ref_k, ref_v = write_to_kv_cache(
+        jnp.asarray(knew), jnp.asarray(vnew), jnp.asarray(kp),
+        jnp.asarray(vp), jnp.asarray(slots))
+    out, got_k, got_v = paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(bt), jnp.asarray(ITEM_CTX), None,
+        jnp.asarray(knew), jnp.asarray(vnew), scale=0.1,
+        pages_per_chunk=ITEM_PPC,
+        work_items=build_decode_work_list(counts, ITEM_PPC),
+        interpret=True)
+    _against_oracle(out, q, np.asarray(ref_k), np.asarray(ref_v), bt,
+                    ITEM_CTX)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(ref_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(ref_v))
+
+
+@pytest.mark.parametrize("steps_on", [0, 20])
+def test_a_bursts_list_serves_the_context_of_the_step(steps_on):
+    """A burst builds one list from the pages it reserved and reuses
+    it over its steps: which pages an item copies follows the context
+    length of the call, not the list. At the first step the reserved
+    pages beyond the context are NaN (an item that lies wholly beyond
+    it copies nothing); twenty steps on, the 512-token row has grown
+    into its third item and the 257-token row into a second live
+    page."""
+    q, kp, vp, bt, counts = item_problem(reserve=40)
+    ctx = np.where(ITEM_CTX > 0, ITEM_CTX + steps_on, 0).astype(np.int32)
+    ctx[3] = ITEM_CTX[3]        # already at the table's width
+    kp, vp = kp.copy(), vp.copy()
+    for b, c in enumerate(ctx):                 # not yet written
+        kp[bt[b, -(-int(c) // ITEM_PAGE):counts[b]]] = np.nan
+        vp[bt[b, -(-int(c) // ITEM_PAGE):counts[b]]] = np.nan
+    work = build_decode_work_list(counts, ITEM_PPC)
+    # a third item: the 640-token row, and the 512-token row's
+    # reservation (552 tokens), wholly beyond its context at step 0
+    assert work[1].tolist().count(2) == 2
+    got = paged_decode_attention(
+        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
+        jnp.array(ctx), scale=0.1, pages_per_chunk=ITEM_PPC,
+        work_items=work, interpret=True)
+    _against_oracle(got, q, kp, vp, bt, ctx)
+
+
 # ---- satellite: call-time APHRODITE_ATTN_PF ----
 
 def test_pf_depth_read_at_call_time(monkeypatch):
@@ -310,12 +432,55 @@ def test_build_work_list_bucketing_and_errors():
         build_decode_work_list([4, 4], 2, pad_to=3)
 
 
-def test_choose_pages_per_chunk_policy():
-    assert choose_pages_per_chunk(4, 32, 512) == 4
-    assert choose_pages_per_chunk(8, 16, 512) == 8
-    # small-batch boost stops at 512-token chunks
-    assert choose_pages_per_chunk(64, 32, 1) == 16
-    assert choose_pages_per_chunk(64, 16, 1) == 32
+#: one token of a head block of 8 bf16 heads of 128 (Mistral's pages)
+MISTRAL_LANE_BYTES = 8 * 128 * 2
+
+
+@pytest.mark.parametrize("width,page,lane_bytes,want", [
+    (4, 32, MISTRAL_LANE_BYTES, 4),     # a table narrower than an item
+    (8, 16, MISTRAL_LANE_BYTES, 8),     # is one item
+    (64, 32, MISTRAL_LANE_BYTES, 16),   # 512-token items, at any batch
+    (64, 16, MISTRAL_LANE_BYTES, 32),
+    (72, 16, MISTRAL_LANE_BYTES, 32),   # the benchmark cell's widths: no
+    (80, 16, MISTRAL_LANE_BYTES, 32),   # multiple of the item, and not
+    (88, 16, MISTRAL_LANE_BYTES, 32),   # shrunk to a divisor (11 pages)
+    (88, 16, 8 * 128 * 1, 32),          # 8-bit pages: the cap holds
+    (88, 16, 8 * 256 * 2, 16),          # lanes twice as wide: 256 tokens
+    (88, 16, 8 * 512 * 4, 8),           # never under 128 tokens
+])
+def test_choose_pages_per_chunk_policy(width, page, lane_bytes, want):
+    """The item is the largest multiple of 128 tokens, up to 512, that
+    leaves the read ring four slots inside its budget: a function of
+    the shapes a call sees, not of the batch."""
+    ppc = choose_pages_per_chunk(width, page, lane_bytes)
+    assert ppc == want
+    tokens = ppc * page
+    assert ppc == width or tokens % 128 == 0
+    slots = pa._ring_slots(6, tokens, lane_bytes)
+    assert slots >= pa._MIN_RING_SLOTS or tokens == 128
+    assert slots * 2 * tokens * lane_bytes <= pa._RING_BUDGET_BYTES or \
+        tokens == 128
+
+
+def test_padded_work_length_gives_a_bucket_few_lengths():
+    """The list length is part of a decode program's key: batch x 2^k,
+    clamped to the dense cell count. At the cell's 48 rows and 512-token
+    items every table width has one length."""
+    assert [pa.padded_work_length(n, 48, 88, 32)
+            for n in (48, 49, 96, 97, 144)] == [48, 96, 96, 144, 144]
+    assert pa.padded_work_length(48 * 3, 48, 72, 32) == 144
+    assert pa.padded_work_length(48 * 11, 48, 88, 8) == 528   # PR 31's
+    assert pa.padded_work_length(3, 1, 72, 32) == 3
+
+
+def test_count_decode_pages_follows_the_kernels_rule():
+    """A row copies its live pages as far as its items reach: all of
+    them, when the list was built from pages that cover the context."""
+    fetched, live = pa.count_decode_pages(
+        [257, 356, 512, 640, 0, 5], [2, 2, 2, 3, 1, 1], 16, 16)
+    assert (fetched, live) == (17 + 23 + 32 + 40 + 0 + 1,) * 2
+    # items that stop short of the context (never built by the runner)
+    assert pa.count_decode_pages([640], [2], 16, 16) == (32, 40)
 
 
 # ---- satellite: fused-write routing preconditions ----
@@ -381,7 +546,9 @@ def test_layer_passes_work_list_to_kernel(monkeypatch):
     # Without a runner-built list: shared policy, no work items.
     layer._decode(q, pages, pages, meta.replace(decode_work=None))
     assert calls["work_items"] is None
-    assert calls["pages_per_chunk"] == choose_pages_per_chunk(8, 8, 2)
+    # ... sized from the layer's own head block and the pages' type
+    assert calls["pages_per_chunk"] == choose_pages_per_chunk(
+        8, 8, 2 * 128 * 4) == 8
 
 
 # ---- model runner: work-list build inside the bucketed burst ----
@@ -389,12 +556,15 @@ def test_layer_passes_work_list_to_kernel(monkeypatch):
 def test_model_runner_builds_consistent_work_list():
     """_prepare_decode must emit a decode_work list consistent with
     its padded tables: chunk counts from each row's REAL reserved
-    pages, the shared pages_per_chunk policy, padded rows one masked
-    item, dead padding to the bucketed length."""
+    pages, the shared pages_per_chunk policy (sized from the lanes of
+    the runner's pages), padded rows one masked item, dead padding to
+    the runner's length; and it counts the pages the step's attention
+    copies and those that are live."""
     from types import SimpleNamespace
     from aphrodite_tpu.common.sampling_params import SamplingParams
     from aphrodite_tpu.common.sequence import (SequenceData,
                                                SequenceGroupMetadata)
+    from aphrodite_tpu.common.tracing import Tracer
     from aphrodite_tpu.executor.model_runner import ModelRunner
 
     runner = ModelRunner.__new__(ModelRunner)
@@ -402,6 +572,8 @@ def test_model_runner_builds_consistent_work_list():
     runner.num_slots = 16 * 1024
     runner.kv_scale = 1.0
     runner.pages_bucket = 8
+    runner.attn_lane_bytes = MISTRAL_LANE_BYTES
+    runner.tracer = Tracer()
     runner._input_sharding = None      # single-device placement plan
     runner._results_committed = False  # weights made by a program
     runner._tp = 1
@@ -411,8 +583,9 @@ def test_model_runner_builds_consistent_work_list():
 
     sp = SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True)
     mds = []
-    # Ragged mix: 3, 40, and 150 tokens -> 1, 3, and 10 reserved pages.
-    for i, n_tok in enumerate((3, 40, 150)):
+    # Ragged mix: 3, 40, and 600 tokens -> 1, 3, and 38 reserved pages
+    # (two 512-token items on a table 40 pages wide).
+    for i, n_tok in enumerate((3, 40, 600)):
         data = SequenceData(list(range(n_tok)))
         n_pages = -(-n_tok // 16)
         mds.append(SequenceGroupMetadata(
@@ -430,14 +603,18 @@ def test_model_runner_builds_consistent_work_list():
     # The batch rides as one array in the place of its block tables;
     # the program slices it.
     _, _, meta = ModelRunner._unpacked(None, None, meta)
-    assert meta.block_tables.shape == (4, 16)
-    assert np.asarray(meta.context_lens).tolist() == [3, 40, 150, 0]
+    assert meta.block_tables.shape == (4, 40)
+    assert np.asarray(meta.context_lens).tolist() == [3, 40, 600, 0]
     assert np.asarray(meta.slot_mapping).tolist() == [
-        2, 102 * 16 + 7, 209 * 16 + 5, runner.num_slots]
+        2, 102 * 16 + 7, 237 * 16 + 7, runner.num_slots]
     assert ppc == choose_pages_per_chunk(
-        meta.block_tables.shape[1], 16, padded_batch)
+        meta.block_tables.shape[1], 16, MISTRAL_LANE_BYTES) == 32
+    # The step's pages, counted where the list is built: each row's
+    # pages below its context length, all of them copied.
+    assert runner.tracer.counts["attn.pages_live"] == 1 + 3 + 38
+    assert runner.tracer.counts["attn.pages_fetched"] == 1 + 3 + 38
     # Every padded row appears, chunks contiguous and chunk-ordered.
-    expected_chunks = [max(1, -(-p // ppc)) for p in (1, 3, 10)] + \
+    expected_chunks = [max(1, -(-p // ppc)) for p in (1, 3, 38)] + \
         [1] * (padded_batch - 3)
     seqs, chunks = [], []
     for i, n in enumerate(expected_chunks):
@@ -451,10 +628,13 @@ def test_model_runner_builds_consistent_work_list():
     assert (ws[nw_real:-1] == padded_batch).all()
     assert ws[-1] == -1
     # The padded length follows the padded_batch * 2^k discipline.
-    assert wc.shape[0] % padded_batch == 0
-    # Work-item page walks stay inside the padded table width.
+    assert wc.shape[0] == pa.padded_work_length(
+        nw_real, padded_batch, 40, ppc) == 8
+    # A row's last item may reach past the table's width (40 is no
+    # multiple of 32); its live pages never do.
     max_chunk = wc[:nw_real].max()
-    assert (max_chunk + 1) * ppc <= meta.block_tables.shape[1]
+    assert (max_chunk + 1) * ppc > meta.block_tables.shape[1]
+    assert -(-600 // 16) <= meta.block_tables.shape[1]
     # The device copy of the list is kept while no row's chunk count
     # changes (a token more on a row's last page), and rebuilt when one
     # does.
@@ -462,7 +642,9 @@ def test_model_runner_builds_consistent_work_list():
     again, _ = ModelRunner._prepare_decode(runner, mds)
     assert again["metadata"].decode_work[0] is inputs[
         "metadata"].decode_work[0]
-    mds[1].block_tables[1] = list(range(100, 100 + 4 * ppc + 1))
+    # (a step counts its pages whether the list was rebuilt or not)
+    assert runner.tracer.counts["attn.pages_live"] == 2 * (1 + 3 + 38)
+    mds[1].block_tables[1] = list(range(100, 100 + ppc + 1))
     grown, _ = ModelRunner._prepare_decode(runner, mds)
     assert grown["metadata"].decode_work[0] is not inputs[
         "metadata"].decode_work[0]

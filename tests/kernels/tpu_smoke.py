@@ -127,6 +127,57 @@ def main() -> int:
             np.float32)
         check("ragged bf16, classic rescale multiply", ref, gotm)
 
+    with section("decode attention (512-token items, live pages)"):
+        # -- the served item: pages of 16 on a table 88 wide (no
+        #    multiple of the policy's 32-page item), a row's last item
+        #    with 1, some and all pages live, NaN in every page no row
+        #    holds (the pad entries' page among them): a dead page
+        #    copied, or a ring slot not clean under p = 0, reads NaN.
+        #    One head block (whole-page descriptors), two (lane
+        #    slices), and 8-bit pages. --
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list, choose_pages_per_chunk, lane_bytes_of)
+        ctx5 = np.array([1025, 1100, 1408, 513, 0, 40, 1024, 777],
+                        np.int32)
+        cnt5 = -(-ctx5 // 16)
+        tbl5 = np.zeros((len(ctx5), 88), np.int32)
+        used = 1
+        for i, n in enumerate(cnt5):
+            tbl5[i, :n] = np.arange(used, used + n)
+            used += n
+        mask5 = ctx5 > 0
+        for hkv5, dt5 in ((8, jnp.bfloat16), (16, jnp.bfloat16),
+                          (8, jnp.int8)):
+            raw = rs.randn(used + 4, 16, hkv5 * d) * (
+                20 if dt5 == jnp.int8 else 0.1)
+            kv5 = [jnp.asarray(np.round(raw) if dt5 == jnp.int8 else raw,
+                               dt5),
+                   jnp.asarray(np.round(raw[::-1]) if dt5 == jnp.int8
+                               else raw[::-1], dt5)]
+            q5 = jnp.asarray(rs.randn(len(ctx5), 32, d) * 0.1,
+                             jnp.bfloat16)
+            s5 = 0.05 if dt5 == jnp.int8 else 1.0
+            ref5 = np.asarray(paged_decode_attention_ref(
+                q5, kv5[0].astype(jnp.float32) * s5,
+                kv5[1].astype(jnp.float32) * s5, jnp.asarray(tbl5),
+                jnp.asarray(np.maximum(ctx5, 1)), scale), np.float32)
+            if dt5 != jnp.int8:     # every page no row holds: NaN
+                dead = np.ones(used + 4, bool)
+                dead[1:used] = False
+                kv5 = [x.at[jnp.asarray(np.flatnonzero(dead))].set(
+                    jnp.nan) for x in kv5]
+            ppc5 = choose_pages_per_chunk(
+                88, 16, lane_bytes_of(hkv5, d, dt5))
+            got5 = np.asarray(paged_decode_attention(
+                q5, kv5[0], kv5[1], jnp.asarray(tbl5),
+                jnp.asarray(ctx5), scale=scale, kv_scale=s5,
+                pages_per_chunk=ppc5,
+                work_items=build_decode_work_list(cnt5, ppc5)),
+                np.float32)
+            tag5 = f"ragged ppc={ppc5} Hkv={hkv5} {jnp.dtype(dt5).name}"
+            check(tag5, ref5[mask5], got5[mask5])
+            check(tag5 + ", the pad row reads 0", 0.0, got5[~mask5])
+
     with section("decode attention (padded heads)"):
         # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --
         for d_true in (64, 80):
