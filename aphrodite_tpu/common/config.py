@@ -13,8 +13,10 @@ LoRAConfig). TPU-first differences:
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 import os
-from typing import Optional, Tuple, Union
+from typing import List, Optional, Tuple, Union
 
 from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.transformers_utils.config import get_config
@@ -32,6 +34,60 @@ _STR_DTYPE_TO_JAX = {
     "float": "float32",
     "float32": "float32",
 }
+
+
+@dataclasses.dataclass(frozen=True)
+class PageGroups:
+    """Which KV page group each layer's attention belongs to.
+
+    A layer is `full` (it needs every key of its sequence) or `window`
+    (only the newest `window` keys). Layers of one kind are dealt, in
+    order, into groups of `layers_per_group` = gcd(full layers, window
+    layers), so that every group has the same number of layers and ONE
+    free list serves all of them: the pool is `layers_per_group` pairs
+    of page arrays, the layer at place `slot_of_layer[l]` of its group
+    uses pair `slot_of_layer[l]`, and a page id means the same bytes
+    (that page of every pair) whichever group takes it. A sequence
+    holds one block table a group; a window group's table lets go of
+    the pages its window has passed. A model whose layers are all of
+    one kind has one group of all its layers, and its pool is what it
+    always was: a pair a layer."""
+    kinds: Tuple[str, ...]              # a group: "full" | "window"
+    group_of_layer: Tuple[int, ...]
+    slot_of_layer: Tuple[int, ...]
+    window: Optional[int] = None        # tokens; None: no window group
+
+    @classmethod
+    def of(cls, layer_windows: List[bool],
+           window: Optional[int]) -> "PageGroups":
+        """`layer_windows[l]`: whether layer `l` has the window."""
+        if window is None:
+            layer_windows = [False] * len(layer_windows)
+        n_window = sum(map(bool, layer_windows))
+        per = math.gcd(n_window, len(layer_windows) - n_window)
+        kinds, group_of, slot_of = [], [], []
+        open_group = {}                 # kind -> (group, layers in it)
+        for has_window in layer_windows:
+            kind = "window" if has_window else "full"
+            group, filled = open_group.get(kind, (None, per))
+            if filled == per:
+                group, filled = len(kinds), 0
+                kinds.append(kind)
+            group_of.append(group)
+            slot_of.append(filled)
+            open_group[kind] = (group, filled + 1)
+        return cls(tuple(kinds), tuple(group_of), tuple(slot_of),
+                   window if n_window else None)
+
+    @property
+    def layers_per_group(self) -> int:
+        return len(self.group_of_layer) // len(self.kinds)
+
+    @property
+    def plain(self) -> bool:
+        """One group that lets go of nothing: block tables, swap,
+        prefix pins, bursts and speculative rounds as ever."""
+        return self.kinds == ("full",)
 
 
 class ModelConfig:
@@ -150,6 +206,20 @@ class ModelConfig:
     def get_sliding_window(self) -> Optional[int]:
         return getattr(self.hf_config, "sliding_window", None)
 
+    def get_page_groups(self) -> PageGroups:
+        """The layers' page groups: from `sliding_window_layout` and
+        `sliding_window_size` where the config has a layer-wise
+        pattern, else every layer in one group, a window group where
+        the model-wide `sliding_window` is set."""
+        cfg = self.hf_config
+        layout = getattr(cfg, "sliding_window_layout", None)
+        if layout is not None:
+            return PageGroups.of([bool(x) for x in layout],
+                                 getattr(cfg, "sliding_window_size", None))
+        window = self.get_sliding_window()
+        return PageGroups.of(
+            [window is not None] * cfg.num_hidden_layers, window)
+
     def get_vocab_size(self) -> int:
         return self.hf_config.vocab_size
 
@@ -189,6 +259,19 @@ class ModelConfig:
         return [self.get_total_num_kv_heads()] * \
             self.hf_config.num_hidden_layers
 
+    def get_kv_heads_per_slot(self) -> list:
+        """KV heads of each pair of page arrays: a layer's where the
+        layers are one page group, else a group's worth of them (the
+        groups' layers have to agree in heads, place by place)."""
+        heads, groups = self.get_kv_heads_per_layer(), self.get_page_groups()
+        per_slot = heads[:groups.layers_per_group]
+        for layer, slot in enumerate(groups.slot_of_layer):
+            if heads[layer] != per_slot[slot]:
+                raise ValueError(
+                    "page groups need layers of equal KV heads; layer "
+                    f"{layer} has {heads[layer]}, its slot {per_slot[slot]}")
+        return per_slot
+
     def get_num_attention_heads(
             self, parallel_config: "ParallelConfig") -> int:
         return (self.hf_config.num_attention_heads //
@@ -214,12 +297,17 @@ class CacheConfig:
         swap_space: float = 4,
         cache_dtype: str = "auto",
         sliding_window: Optional[int] = None,
+        page_groups: Optional[PageGroups] = None,
     ) -> None:
         self.block_size = block_size
         self.gpu_memory_utilization = gpu_memory_utilization
         self.swap_space_bytes = int(swap_space * _GB)
         self.cache_dtype = cache_dtype
-        self.sliding_window = sliding_window
+        # (`sliding_window` alone: one group of every layer, as a
+        # model-wide window is)
+        self.page_groups = page_groups or PageGroups.of(
+            [sliding_window is not None], sliding_window)
+        self.sliding_window = self.page_groups.window
         self._verify_args()
         self._verify_cache_dtype()
 
@@ -412,6 +500,13 @@ class SchedulerConfig:
         self.max_chunk_tokens = max_chunk_tokens \
             if max_chunk_tokens is not None else 2048
         self._verify_args()
+
+    @property
+    def window_chunk_cap(self) -> int:
+        """The longest prompt chunk of a model with a window page
+        group, whatever else the round holds (`Scheduler`; the
+        executor's headroom counts on it)."""
+        return self.max_chunk_tokens or self.max_num_batched_tokens
 
     def _verify_args(self) -> None:
         if self.max_num_batched_tokens < self.max_model_len:

@@ -332,7 +332,12 @@ class SequenceGroupMetadata:
         computed_ctx: int = 0,
         chunk_len: Optional[int] = None,
         is_final_chunk: bool = True,
+        group_tables: Optional[Dict[int, list]] = None,
     ) -> None:
+        """`group_tables`: for a model whose KV pages are not one plain
+        group, each sequence's `[(tokens let go of, page numbers)]`, an
+        entry a page group (`BlockSpaceManager.get_group_tables`);
+        `block_tables` is then the first group's."""
         self.request_id = request_id
         self.is_prompt = is_prompt
         self.seq_data = seq_data
@@ -344,6 +349,7 @@ class SequenceGroupMetadata:
         self.computed_ctx = computed_ctx
         self.chunk_len = chunk_len
         self.is_final_chunk = is_final_chunk
+        self.group_tables = group_tables
 
     @property
     def lora_int_id(self) -> int:

@@ -31,8 +31,10 @@ from jax.profiler import TraceAnnotation
 #: sent nothing because the batch had not changed, a step program
 #: dispatched while the round before was still on the device, and the
 #: KV pages a decode step's attention copies and those of them that
-#: are live; and the seconds in which a dispatched step had not been
-#: pulled yet (`Tracer.flight`).
+#: are live, by page group where the model has several; the pages
+#: window groups let go of; what a model's expert layers count in
+#: the step program (pairs routed, experts touched); and the seconds in
+#: which a dispatched step had not been pulled yet (`Tracer.flight`).
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
     "engine.step",          # one AphroditeEngine.step()
@@ -44,12 +46,24 @@ NAMES = (
     "sampler.finalize",     # unpacking the pulled results
     "engine.process",       # detokenise, stop checks, outputs, stats
     "cache.kv_handoff",     # disagg: prefill pool -> decode pool
+    "cache.window_release",  # window groups let go of passed pages
+                             # (inside sched.schedule)
     "queue_wait",
     "preemptions",
     "sampler.plan_reuse",
     "runner.ahead",
     "attn.pages_fetched",   # pages the decode kernel copies, a step
     "attn.pages_live",      # pages below the rows' context lengths
+    "attn.decode_steps",    # decode steps those two were summed over
+    "attn.pages_live.full",    # of them, the full groups' (a group's
+    "attn.pages_live.window",  # layers share a page), the window groups'
+    "attn.window_pages_unwindowed",  # what the window groups' rows
+                            # would hold live without a window
+    "cache.window_pages_freed",  # pages the window groups let go of
+    "moe.tokens_routed",    # token-expert pairs of the expert layers
+    "moe.experts_touched",  # experts with a pair, over layers and steps
+    "moe.decode_experts_touched",  # of them, the decode steps'
+    "moe.decode_expert_slots",     # experts x expert layers, a decode step
     "runner.in_flight",
 )
 

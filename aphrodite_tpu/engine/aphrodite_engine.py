@@ -44,6 +44,7 @@ from aphrodite_tpu.processing.admission import (AdmissionController,
                                                 AdmissionSnapshot,
                                                 RequestTimeoutError)
 from aphrodite_tpu.processing.drafter import NgramDrafter
+from aphrodite_tpu.processing.block_manager import PageGroupsUnsupported
 from aphrodite_tpu.processing.scheduler import (Scheduler,
                                                 SchedulerOutputs)
 from aphrodite_tpu.transformers_utils.tokenizer import (
@@ -338,6 +339,13 @@ class AphroditeEngine:
                 self._decode_sequence(seq, sampling_params)
 
         prefix = None
+        if prefix_pos is not None and \
+                not self.cache_config.page_groups.plain:
+            # refused at the door, as a fault of the request, rather
+            # than in the round that would allocate it
+            raise ValueError(str(PageGroupsUnsupported(
+                "the prefix cache", "send the request without a cached "
+                "prefix (prefix_pos)")))
         if prefix_pos is not None:
             prefix = self.scheduler.prefix_pool.intern(
                 prompt_token_ids[:prefix_pos])
@@ -697,11 +705,12 @@ class AphroditeEngine:
         copy), and no other way of running several tokens a sync has
         the round: a burst (`multi_step` > 1), a speculative verify
         round (which drafts from the last token's id), the
-        disaggregated layout. Adapter rows and sliding windows keep
-        the synced path, which is the only one they were proven on."""
+        disaggregated layout. Adapter rows keep the synced path, which
+        is the only one they were proven on. (A window layer's rows
+        run ahead like any other: which pages its group lets go of and
+        takes is the host's arithmetic on the sequence's length.)"""
         if not decode_mds or self.scheduler_config.multi_step > 1 or \
-                self._speculates() or self.executor.disagg or \
-                self.model_config.get_sliding_window() is not None:
+                self._speculates() or self.executor.disagg:
             return False
         if scheduler_outputs.blocks_to_swap_in or \
                 scheduler_outputs.blocks_to_swap_out or \
@@ -887,7 +896,7 @@ class AphroditeEngine:
         the caps map is the single source of truth shared by the page
         reservation and the device position clamp.
 
-        Eligible: decode round, no sliding window, and every group is a
+        Eligible: decode round, one plain page group, and every group is a
         single-sequence group that reads no history on the host
         (everything the device loop can't feed back) and whose logits
         stay in the program. The scan compiles its sampler statics
@@ -898,7 +907,8 @@ class AphroditeEngine:
         max_steps = self.scheduler_config.multi_step
         if max_steps <= 1:
             return 1, None
-        if self.model_config.get_sliding_window() is not None:
+        if not self.cache_config.page_groups.plain:
+            # the scan walks one block table a row
             return 1, None
         remaining = []
         extra_cap = {}          # seq_id -> max USEFUL extra slots
@@ -979,7 +989,7 @@ class AphroditeEngine:
         `APHRODITE_SPEC=0` pins the classic path for A/B."""
         if not self._speculates():
             return None
-        if self.model_config.get_sliding_window() is not None:
+        if not self.cache_config.page_groups.plain:
             return None
         if not self._spec_eligible(decode_mds):
             return None

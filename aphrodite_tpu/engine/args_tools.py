@@ -171,7 +171,8 @@ class EngineArgs:
             self.max_context_len_to_capture)
         cache_config = CacheConfig(
             self.block_size, self.gpu_memory_utilization, self.swap_space,
-            self.kv_cache_dtype, model_config.get_sliding_window())
+            self.kv_cache_dtype,
+            page_groups=model_config.get_page_groups())
         # --disagg-split wins; None defers to the APHRODITE_DISAGG
         # flag (registry-validated read), "" explicitly colocates.
         disagg_spec = self.disagg_split

@@ -97,6 +97,42 @@ _STAGE_COUNTERS = [
      "KV pages below the rows' context lengths, summed over decode "
      "steps: what aphrodite:decode_attn_pages_fetched_total cannot "
      "go under.", lambda s, c: c["attn.pages_live"]),
+    ("aphrodite:decode_attn_steps_total",
+     "Decode steps that aphrodite:decode_attn_pages_live_total and the "
+     "page-group counters below were summed over.",
+     lambda s, c: c["attn.decode_steps"]),
+    ("aphrodite:kv_pages_live_full_total",
+     "Of aphrodite:decode_attn_pages_live_total, the pages of the full "
+     "page groups (a page holds a group's layers).",
+     lambda s, c: c["attn.pages_live.full"]),
+    ("aphrodite:kv_pages_live_window_total",
+     "Of aphrodite:decode_attn_pages_live_total, the pages of the "
+     "window page groups.", lambda s, c: c["attn.pages_live.window"]),
+    ("aphrodite:window_pages_unwindowed_total",
+     "Pages the window groups' rows would hold live without a window, "
+     "summed over decode steps (aphrodite:kv_pages_live_window_total "
+     "is what they hold).",
+     lambda s, c: c["attn.window_pages_unwindowed"]),
+    ("aphrodite:window_pages_freed_total",
+     "KV pages that window page groups let go of, to the free list.",
+     lambda s, c: c["cache.window_pages_freed"]),
+    ("aphrodite:window_release_seconds_total",
+     "Seconds letting window groups' passed pages go (inside the "
+     "schedule seconds).", lambda s, c: s["cache.window_release"]),
+    ("aphrodite:moe_tokens_routed_total",
+     "Token-expert pairs the expert layers computed, counted in the "
+     "step programs.", lambda s, c: c["moe.tokens_routed"]),
+    ("aphrodite:moe_experts_touched_total",
+     "Experts with at least one pair, summed over expert layers and "
+     "steps, counted in the step programs.",
+     lambda s, c: c["moe.experts_touched"]),
+    ("aphrodite:moe_decode_experts_touched_total",
+     "Of aphrodite:moe_experts_touched_total, the decode steps'.",
+     lambda s, c: c["moe.decode_experts_touched"]),
+    ("aphrodite:moe_decode_expert_slots_total",
+     "Experts times expert layers, summed over decode steps: what "
+     "aphrodite:moe_decode_experts_touched_total cannot pass.",
+     lambda s, c: c["moe.decode_expert_slots"]),
 ]
 
 

@@ -7,6 +7,11 @@ on side stream `:118-134`, copy `:136-146`) and the CUDA cache kernels
 TPU-native: per layer the cache is (k_pages, v_pages) arrays of shape
 [num_pages, page_size, num_kv_heads * head_dim] — token-major, heads
 collapsed into lanes (see ops/kv_cache.py for the layout rationale).
+Where the model's layers lie in several page groups
+(`common/config.py::PageGroups`) there is a pair for each PLACE in a
+group, not for each layer: the layers at one place of the groups share
+it, each under its own group's page ids, so one page id is the same
+bytes whichever group holds it and one free list serves them all.
 Swap space is pinned host numpy; swap_in/out are `jax.device_put`/
 `device_get` of whole pages — JAX dispatches these asynchronously, which
 replaces the reference's dedicated CUDA stream + event machinery.
@@ -96,7 +101,8 @@ class CacheEngine:
 
         self.num_layers = model_config.hf_config.num_hidden_layers
         self.num_kv_heads = model_config.get_total_num_kv_heads()
-        self.kv_heads_per_layer = model_config.get_kv_heads_per_layer()
+        # (a pair of page arrays a layer, or a place in a page group)
+        self.kv_heads_per_layer = model_config.get_kv_heads_per_slot()
         # Pages store head_dim padded to the 128-lane tile (see
         # ops/kv_cache.padded_head_size) so every head size runs the
         # Pallas decode/write kernels.
@@ -297,7 +303,7 @@ class CacheEngine:
         Uses TOTAL kv heads: with TP sharding each chip holds
         heads/tp, but it also only gets budget/tp of the pool."""
         from aphrodite_tpu.ops.kv_cache import padded_head_size
-        total_heads = sum(model_config.get_kv_heads_per_layer())
+        total_heads = sum(model_config.get_kv_heads_per_slot())
         head_size = padded_head_size(model_config.get_head_size())
         if cache_config.cache_dtype in ("fp8", "int8"):
             elt = 1

@@ -13,6 +13,7 @@ stats, and give the KV cache `gpu_memory_utilization` of what remains.
 """
 from __future__ import annotations
 
+import gc
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -291,6 +292,14 @@ class TPUExecutor:
         none. A TPU that reports no limit is an error, not a guess."""
         if jax.devices()[0].platform == "cpu":
             return None
+        # The weights and nothing else: what the loader's programs
+        # still hold while they run, and what Python has not yet
+        # collected, is gone by the time the pool is in use. The page
+        # count is a shape of every step program, so a megabyte that
+        # comes and goes is a second set of programs where pages are
+        # small (PERF.md §6, PR 33: 44,346 or 44,362 pages of 96 KiB).
+        jax.block_until_ready(self.params)
+        gc.collect()
         free = []
         for dev in self._devices():
             stats = dev.memory_stats()
@@ -325,6 +334,8 @@ class TPUExecutor:
         if free is None:
             budget = _CPU_CACHE_BYTES
         else:
+            logger.info("Device memory before the KV pool: %d bytes free",
+                        free)
             # Weights are already resident; reserve headroom for compiled
             # programs + transient activations, then give the cache the
             # configured fraction of the rest. The dominant transient is
@@ -333,9 +344,16 @@ class TPUExecutor:
             # overlap (measured: an 8192-token Mistral-7B round peaks
             # ~1.1 GB; 512 MB headroom OOMed by exactly that delta).
             cfg = self.model_config.hf_config
-            inter = getattr(cfg, "intermediate_size",
-                            4 * cfg.hidden_size)
+            # (a model of experts alone states no dense width)
+            top_k = getattr(cfg, "num_experts_per_tok", 0) or getattr(
+                cfg, "moe_num_active_primary_experts", 0)
+            inter = getattr(cfg, "intermediate_size", None) or (
+                0 if top_k else 4 * cfg.hidden_size)
             tokens = self.scheduler_config.max_num_batched_tokens
+            if self.cache_config.page_groups.window is not None:
+                # the scheduler writes such a model's prompts in
+                # chunks
+                tokens = min(tokens, self.scheduler_config.window_chunk_cap)
             act_bytes = int(tokens * (2 * inter + 4 * cfg.hidden_size) *
                             2 * 1.5)
             # Quantized matmuls add XLA-side activation copies on top
@@ -349,9 +367,9 @@ class TPUExecutor:
             # MoE ragged dispatch materializes f32 gate/up/act tensors
             # at [tokens * top_k, moe_inter] (layers/fused_moe.py) —
             # for Mixtral shapes that dwarfs the dense estimate.
-            top_k = getattr(cfg, "num_experts_per_tok", 0)
             if top_k:
-                moe_inter = getattr(cfg, "moe_intermediate_size", inter)
+                moe_inter = getattr(cfg, "moe_intermediate_size", None) \
+                    or getattr(cfg, "moe_ffn_hidden_size", inter)
                 act_bytes = max(act_bytes, int(
                     tokens * top_k * moe_inter * 4 * 3 * 1.2))
             headroom = min(free // 2, max(512 << 20, act_bytes))

@@ -33,7 +33,7 @@ from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.sampling_params import SamplingType
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupMetadata)
-from aphrodite_tpu.modeling.input_metadata import InputMetadata
+from aphrodite_tpu.modeling.input_metadata import GroupView, InputMetadata
 from aphrodite_tpu.modeling.layers.rejection import delta_rejection_length
 from aphrodite_tpu.modeling.layers.sampler import (Sampler, fused_sample,
                                                    _fused_sample_jit)
@@ -67,6 +67,17 @@ def _bucket(value: int, buckets: List[int]) -> int:
     if idx == len(buckets):
         return buckets[-1] if value <= buckets[-1] else value
     return buckets[idx]
+
+
+#: Block tables wider than this many pages are padded to a multiple of
+#: `_WIDE_PAGES_BUCKET`: a table width is part of a step program's key,
+#: the decode kernel copies live pages only whatever the width, and a
+#: batch of 8k contexts that grows by 700 tokens would else walk
+#: through six programs a batch bucket. Their decode work lists, once
+#: within a factor two of a cell for every chunk of every row, are
+#: padded to that (`ModelRunner._work_length`).
+_WIDE_TABLE = 128
+_WIDE_PAGES_BUCKET = 64
 
 
 def _pow2_bucket(value: int, lo: int = 16) -> int:
@@ -103,11 +114,16 @@ class StepHandle:
     a speculative verify step, each group's drafted tokens."""
 
     __slots__ = ("packed", "sampling", "plan", "num_steps", "verify",
-                 "outputs")
+                 "outputs", "counts", "is_prompt")
 
     def __init__(self, packed, sampling, plan, num_steps: int = 1,
                  verify: Optional[List[List[int]]] = None,
-                 outputs: Optional[list] = None) -> None:
+                 outputs: Optional[list] = None, counts=None,
+                 is_prompt: bool = False) -> None:
+        # `counts`: what the model counted in the step's program
+        # (`ModelRunner.step_counters`), on the device beside `packed`
+        self.counts = counts
+        self.is_prompt = is_prompt
         self.packed = packed
         self.sampling = sampling
         self.plan = plan
@@ -208,15 +224,23 @@ class ModelRunner:
         self.attn_lane_bytes = max(
             lane_bytes_of(h, padded_head, kv_cache_dtype)
             for h in model_config.get_kv_heads_per_layer())
+        #: the model's page groups ("full" or "window" each), and the
+        #: window; one plain group for most models
+        self.page_groups = model_config.get_page_groups()
+        #: what the model counts inside its step programs
+        #: (`tracing.NAMES`), pulled with a step's result
+        self.step_counters: Tuple[str, ...] = tuple(
+            getattr(model, "step_counters", ()))
 
         # LoRA: bucket keys carrying slot-stacked adapter tensors, and a
         # slot resolver installed by the executor's WorkerLoRAManager.
         from aphrodite_tpu.lora.layers import LORA_A
         self.lora_buckets = [k for k, b in params.items() if LORA_A in b]
         self.lora_slot_of = None
-        # The last decode work list sent: (what it was built from, its
-        # device copy); see _send_decode_batch.
-        self._decode_work = (None, None)
+        # The last decode work list sent, for each kind of page group:
+        # (what it was built from, its device copy); see
+        # _decode_work_list.
+        self._decode_work: Dict[str, tuple] = {}
 
         # One jitted program per (is_prompt, use_prefix); shape buckets
         # land in XLA's compile cache keyed by array shapes.
@@ -278,18 +302,44 @@ class ModelRunner:
 
     # ---- jitted bodies ----
 
-    @staticmethod
-    def _unpacked(input_ids, positions, metadata):
+    def _table_width(self, pages: int) -> int:
+        """A block table's padded width (a step program's key)."""
+        bucket = self.pages_bucket if pages <= _WIDE_TABLE \
+            else _WIDE_PAGES_BUCKET
+        return max(bucket, -(-pages // bucket) * bucket)
+
+    def _unpacked(self, input_ids, positions, metadata):
         """A decode batch reaches the program as ONE int32 array
         (`_send_decode_batch`), in the place of its block tables: each
         row's token, position, slot, context length, then its table.
-        The program slices the columns."""
+        The program slices the columns. With page groups the row goes
+        on with, for each group, the tokens its table has let go of
+        and then the table (`group_layout` has the widths): a group's
+        context counts from its table's first page, and its slot is
+        read off its table here."""
         if metadata.slot_mapping is not None:
             return input_ids, positions, metadata
         rows = metadata.block_tables
+        if not metadata.group_layout:
+            return rows[:, 0:1], rows[:, 1:2], metadata.replace(
+                slot_mapping=rows[:, 2], context_lens=rows[:, 3],
+                block_tables=rows[:, 4:])
+        pos, ctx, at, views = rows[:, 1], rows[:, 3], 4, []
+        for view, width in zip(metadata.groups, metadata.group_layout):
+            let_go, table = rows[:, at], rows[:, at + 1:at + 1 + width]
+            at += 1 + width
+            own = pos - let_go
+            page = jnp.take_along_axis(
+                table, (own // self.page_size)[:, None], axis=1)[:, 0]
+            views.append(view.replace(
+                slot_mapping=jnp.minimum(
+                    page * self.page_size + own % self.page_size,
+                    self.num_slots),
+                block_tables=table, context_lens=ctx - let_go))
         return rows[:, 0:1], rows[:, 1:2], metadata.replace(
-            slot_mapping=rows[:, 2], context_lens=rows[:, 3],
-            block_tables=rows[:, 4:])
+            slot_mapping=views[0].slot_mapping,
+            block_tables=views[0].block_tables,
+            context_lens=views[0].context_lens, groups=tuple(views))
 
     def _logits(self, params, input_ids, positions, kv_caches, metadata,
                 sel_indices):
@@ -303,6 +353,14 @@ class ModelRunner:
         if sel_indices is not None:
             rows = jnp.take(rows, sel_indices, axis=0)
         return self.model.compute_logits(params, rows), new_caches
+
+    def _with_counts(self, packed):
+        """A step's result with what the model counted in the same
+        program (`step_counters`) beside it, for the one pull; the
+        result alone for a model that counts nothing."""
+        if not self.step_counters:
+            return packed
+        return packed, self.model.take_step_counts()
 
     def _step(self, params, input_ids, positions, kv_caches, metadata,
               sel_indices, *, is_prompt: bool, use_prefix: bool):
@@ -323,7 +381,7 @@ class ModelRunner:
         packed, _ = fused_sample(
             logits, tensors, key_parts, max_best_of=max_best_of,
             num_topk=num_topk, need_logprobs=False)
-        return packed, new_caches
+        return self._with_counts(packed), new_caches
 
     def _burst_step(self, params, input_ids, positions, kv_caches,
                     metadata, tensors, key_parts, greedy_mask, pos_cap,
@@ -504,24 +562,10 @@ class ModelRunner:
 
         ids = np.zeros((padded_batch, padded_len), dtype=np.int32)
         pos = np.zeros((padded_batch, padded_len), dtype=np.int32)
-        slots = np.full((padded_batch * padded_len,), self.num_slots,
-                        dtype=np.int32)
         ctx_lens = np.zeros((padded_batch,), dtype=np.int32)
         plens = np.zeros((padded_batch,), dtype=np.int32)
-        # Bucket the table width to the longest scheduled table (always
-        # — long prompts exceed one bucket regardless of prefix use).
-        pb = self.pages_bucket
-        max_pages = max(
-            pb,
-            -(-max((len(next(iter(md.block_tables.values()), []))
-                    for md in seq_group_metadata_list),
-                   default=1) // pb) * pb)
-        num_pages_oob = self.num_slots // self.page_size
-        tables = np.full((padded_batch, max_pages), num_pages_oob,
-                         dtype=np.int32)
 
         selected: List[int] = []
-        sel_offset = 0
         for i, md in enumerate(seq_group_metadata_list):
             seq_id = next(iter(md.seq_data))
             data = md.seq_data[seq_id]
@@ -533,15 +577,6 @@ class ModelRunner:
             pos[i, :n] = np.arange(ctx, ctx + n)
             ctx_lens[i] = ctx
             plens[i] = n
-            table = md.block_tables.get(seq_id, [])
-            tables[i, :len(table)] = table
-            # Vectorized slot computation (a per-token Python loop here
-            # costs ~100 ms per 16k-token prefill round).
-            abs_pos = np.arange(ctx, ctx + n)
-            table_arr = np.asarray(table, dtype=np.int64)
-            slots[i * padded_len:i * padded_len + n] = (
-                table_arr[abs_pos // self.page_size] * self.page_size +
-                abs_pos % self.page_size)
             # Sampler rows: all prompt positions if prompt_logprobs else
             # just the last (reference _prepare_sample, :372-451).
             if md.sampling_params.prompt_logprobs is not None:
@@ -549,53 +584,34 @@ class ModelRunner:
                                       i * padded_len + n))
             else:
                 selected.append(i * padded_len + n - 1)
-            sel_offset += n
 
-        # Page-writer cells: when every sequence's chunk starts on a
-        # page boundary and the padded length is page-aligned, prefill
-        # KV writes run as whole-page DMAs (one cell per (seq, page))
-        # instead of per-token read-modify-writes.
-        prefill_cells = None
-        ps = self.page_size
-        if self._prefill_writer_ok and padded_len % ps == 0 and \
-                all(int(c) % ps == 0 for c in ctx_lens[:batch]):
-            ppp = padded_len // ps               # pages per prompt
-            n_cells = padded_batch * ppp
-            pid = np.full((n_cells,), num_pages_oob, dtype=np.int32)
-            sblk = np.zeros((n_cells,), dtype=np.int32)
-            vld = np.zeros((n_cells,), dtype=np.int32)
-            for i, md in enumerate(seq_group_metadata_list):
-                seq_id = next(iter(md.seq_data))
-                table = md.block_tables.get(seq_id, [])
-                n = int(plens[i])
-                ctx_pages = int(ctx_lens[i]) // ps
-                for p in range(-(-n // ps)):
-                    cell = i * ppp + p
-                    pid[cell] = table[ctx_pages + p]
-                    sblk[cell] = (i * padded_len) // ps + p
-                    # The Pallas prefill writer fetches its source rows
-                    # by CELL INDEX (identity contract — its in-kernel
-                    # block map cannot consult sblk); this layout is
-                    # identity by construction, and the check keeps a
-                    # future re-layout from silently writing wrong KV.
-                    # A real raise, not an assert: it must survive -O.
-                    if sblk[cell] != cell:
-                        raise AssertionError(
-                            f"prefill cell layout not identity: "
-                            f"{sblk[cell]} != {cell}")
-                    vld[cell] = min(n - p * ps, ps)
-            prefill_cells = (self._dev(pid), self._dev(sblk),
-                             self._dev(vld))
+        # One view of the batch's pages for a plain model; one for each
+        # page group else, each with the group's own tables, and
+        # positions counted from a table's first page.
+        seq_ids = [next(iter(md.seq_data))
+                   for md in seq_group_metadata_list]
+        if self.page_groups.plain:
+            views = [self._prompt_view(
+                [(0, md.block_tables.get(seq_id, []))
+                 for md, seq_id in zip(seq_group_metadata_list, seq_ids)],
+                ctx_lens, plens, padded_len)]
+        else:
+            views = [self._prompt_view(
+                [md.group_tables[seq_id][g]
+                 for md, seq_id in zip(seq_group_metadata_list, seq_ids)],
+                ctx_lens, plens, padded_len)
+                for g in range(len(self.page_groups.kinds))]
 
         metadata = InputMetadata(
-            slot_mapping=self._dev(slots),
-            block_tables=self._dev(tables),
-            context_lens=self._dev(ctx_lens),
+            slot_mapping=views[0].slot_mapping,
+            block_tables=views[0].block_tables,
+            context_lens=views[0].context_lens,
             prompt_lens=self._dev(plens),
             kv_scale=self.kv_scale,
             sp=self.sp,
             tp=self._tp,
-            prefill_cells=prefill_cells,
+            prefill_cells=views[0].prefill_cells,
+            groups=None if self.page_groups.plain else tuple(views),
         )
         prompt_offsets = [int(c) for c in ctx_lens[:batch]]
         sampling = SamplingMetadata(
@@ -617,6 +633,74 @@ class ModelRunner:
                       is_prompt=True, use_prefix=use_prefix,
                       newly_computed=newly_computed)
         return inputs, sampling
+
+    def _prompt_view(self, rows: List[Tuple[int, List[int]]],
+                     ctx_lens: np.ndarray, plens: np.ndarray,
+                     padded_len: int) -> GroupView:
+        """What a prompt step writes to and reads from one page group:
+        `rows[i]` is sequence i's (tokens its table has let go of, a
+        multiple of the page; page numbers), `ctx_lens` and `plens`
+        the padded batch's cached and new tokens. Slots, table and
+        the page writer's cells, with the context counted from the
+        table's first page."""
+        batch, padded_batch = len(rows), len(ctx_lens)
+        ps = self.page_size
+        num_pages_oob = self.num_slots // ps
+        slots = np.full((padded_batch * padded_len,), self.num_slots,
+                        dtype=np.int32)
+        own_ctx = np.zeros((padded_batch,), dtype=np.int32)
+        # Bucket the table width to the longest scheduled table (always
+        # — long prompts exceed one bucket regardless of prefix use).
+        max_pages = self._table_width(
+            max((len(table) for _, table in rows), default=1))
+        tables = np.full((padded_batch, max_pages), num_pages_oob,
+                         dtype=np.int32)
+        for i, (let_go, table) in enumerate(rows):
+            ctx, n = int(ctx_lens[i]) - let_go, int(plens[i])
+            own_ctx[i] = ctx
+            tables[i, :len(table)] = table
+            # Vectorized slot computation (a per-token Python loop here
+            # costs ~100 ms per 16k-token prefill round).
+            own_pos = np.arange(ctx, ctx + n)
+            table_arr = np.asarray(table, dtype=np.int64)
+            slots[i * padded_len:i * padded_len + n] = (
+                table_arr[own_pos // ps] * ps + own_pos % ps)
+
+        # Page-writer cells: when every sequence's chunk starts on a
+        # page boundary and the padded length is page-aligned, prefill
+        # KV writes run as whole-page DMAs (one cell per (seq, page))
+        # instead of per-token read-modify-writes.
+        prefill_cells = None
+        if self._prefill_writer_ok and padded_len % ps == 0 and \
+                all(int(c) % ps == 0 for c in own_ctx[:batch]):
+            ppp = padded_len // ps               # pages per prompt
+            n_cells = padded_batch * ppp
+            pid = np.full((n_cells,), num_pages_oob, dtype=np.int32)
+            sblk = np.zeros((n_cells,), dtype=np.int32)
+            vld = np.zeros((n_cells,), dtype=np.int32)
+            for i, (_, table) in enumerate(rows):
+                n = int(plens[i])
+                ctx_pages = int(own_ctx[i]) // ps
+                for p in range(-(-n // ps)):
+                    cell = i * ppp + p
+                    pid[cell] = table[ctx_pages + p]
+                    sblk[cell] = (i * padded_len) // ps + p
+                    # The Pallas prefill writer fetches its source rows
+                    # by CELL INDEX (identity contract — its in-kernel
+                    # block map cannot consult sblk); this layout is
+                    # identity by construction, and the check keeps a
+                    # future re-layout from silently writing wrong KV.
+                    # A real raise, not an assert: it must survive -O.
+                    if sblk[cell] != cell:
+                        raise AssertionError(
+                            f"prefill cell layout not identity: "
+                            f"{sblk[cell]} != {cell}")
+                    vld[cell] = min(n - p * ps, ps)
+            prefill_cells = (self._dev(pid), self._dev(sblk),
+                             self._dev(vld))
+        return GroupView(
+            slot_mapping=self._dev(slots), block_tables=self._dev(tables),
+            context_lens=self._dev(own_ctx), prefill_cells=prefill_cells)
 
     @staticmethod
     def _mark_prefixes(inputs: dict) -> None:
@@ -644,7 +728,8 @@ class ModelRunner:
         seq_groups, seq_data_map, persistent = [], {}, {}
         tokens, positions, slot_list, ctx_list, tables_list = \
             [], [], [], [], []
-        sliding_window = self.model_config.get_sliding_window()
+        grouped = not self.page_groups.plain
+        group_rows: Optional[list] = [] if grouped else None
 
         for md in seq_group_metadata_list:
             group_ids = list(md.seq_data.keys())
@@ -660,21 +745,18 @@ class ModelRunner:
                     tokens.append(data.get_last_token_id())
                 pos = data.get_len() - 1 + data.in_flight
                 positions.append(pos)
+                ctx_list.append(pos + 1)
                 table = md.block_tables[seq_id]
-                slot_pos = pos
-                ctx = pos + 1
-                if sliding_window is not None:
-                    # Block table wraps modulo window (reference
-                    # block_manager sliding-window reuse).
-                    ctx = min(ctx, sliding_window)
-                    slot_pos = pos % (len(table) * self.page_size) \
-                        if len(table) * self.page_size <= sliding_window \
-                        else pos
-                page = table[(slot_pos // self.page_size) % len(table)]
-                slot_list.append(page * self.page_size +
-                                 slot_pos % self.page_size)
-                ctx_list.append(ctx)
                 tables_list.append(table)
+                if grouped:
+                    # each group's slot is read off its own table, in
+                    # the program (`_unpacked`)
+                    group_rows.append(md.group_tables[seq_id])
+                    slot_list.append(self.num_slots)
+                    continue
+                page = table[pos // self.page_size]
+                slot_list.append(page * self.page_size +
+                                 pos % self.page_size)
 
         # The pipelined decode page-writer (kv_write.py distinct_pages)
         # prefetches cell i+1's page before cell i's writeback lands, so
@@ -682,14 +764,16 @@ class ModelRunner:
         # append_slot makes decode pages sequence-exclusive; this guards
         # the precondition loudly when debugging (advisor r3). Read per
         # call — a bad env value must never kill the import.
-        if __debug__ and flags.get_bool("APHRODITE_DEBUG_KV"):
+        if __debug__ and not grouped and \
+                flags.get_bool("APHRODITE_DEBUG_KV"):
             written = [s // self.page_size for s in slot_list]
             assert len(set(written)) == len(written), (
                 "decode slots share a page — sequence-exclusive-pages "
                 f"precondition violated: {sorted(written)}")
 
         inputs = self._send_decode_batch(tokens, positions, slot_list,
-                                         ctx_list, tables_list)
+                                         ctx_list, tables_list,
+                                         group_rows=group_rows)
         if min(tokens) < 0:
             meta = inputs["metadata"]
             with self._mesh_ctx():
@@ -791,57 +875,131 @@ class ModelRunner:
                 np.zeros(key, dtype=np.int32), committed=True)
         return self._no_source[key]
 
+    def _decode_work_list(self, kind: str, page_counts: List[int],
+                   padded_batch: int, max_pages: int):
+        """The ragged decode work list of one page group, (its device
+        copy, pages a chunk, chunks a row): (sequence, chunk) pairs
+        flattened over each row's REAL reserved pages so the attention
+        grid has no padded cells for short contexts (the classic grid
+        pads every row to the batch-max context). Chunk counts come
+        from the reserved table lengths — a safe over-approximation of
+        any context the burst scan reaches (pos_cap pins rows inside
+        their reservation), so the list rides the whole burst. The
+        device copy is kept, for each kind of group, while no row's
+        chunk count changes (the groups of a kind hold the same
+        counts)."""
+        ppc = choose_pages_per_chunk(max_pages, self.page_size,
+                                     self.attn_lane_bytes)
+        chunks = tuple(max(1, -(-c // ppc)) for c in page_counts)
+        work_key = (chunks, ppc, self._work_length(
+            sum(chunks), padded_batch, max_pages, ppc))
+        if self._decode_work.get(kind, (None,))[0] != work_key:
+            wi_seq, wi_chunk = build_decode_work_list(
+                page_counts, ppc, pad_to=work_key[2])
+            self._decode_work[kind] = (work_key, (self._dev(wi_seq),
+                                                  self._dev(wi_chunk)))
+        return self._decode_work[kind][1], ppc, chunks
+
+    @staticmethod
+    def _work_length(items: int, padded_batch: int, max_pages: int,
+                     ppc: int) -> int:
+        """The length a decode work list is padded to (a step
+        program's key): `padded_work_length`'s batch x 2^k; for a wide
+        table (`_WIDE_TABLE`) the dense count, a cell for every chunk
+        of every row, once the list is within a factor two of it. Rows
+        of long contexts are alike, so their lists lie just under the
+        dense count, and on which side of the last power of two
+        depends on how many rows of the bucket are padding: at 8k
+        contexts that was three programs a batch bucket while callers
+        joined, for a few dead items the kernel skips."""
+        length = padded_work_length(items, padded_batch, max_pages, ppc)
+        dense = padded_batch * -(-max_pages // ppc)
+        if max_pages > _WIDE_TABLE and 2 * length >= dense:
+            return dense
+        return length
+
     def _send_decode_batch(self, tokens, positions, slot_list, ctx_list,
-                           tables_list, spec_verify: bool = False) -> dict:
+                           tables_list, spec_verify: bool = False,
+                           group_rows: Optional[list] = None) -> dict:
         """Pad a decode (or verify) batch to its buckets and send it:
         ONE [padded_batch, 4 + pages] int32 array (each row's token,
         position, slot, context length, then its block table; pad rows
         hold the out-of-range slot and page, so the cache scatter drops
         them; `_unpacked` slices it inside the program) and the ragged
-        work list, whose device copy is kept while no row's chunk count
-        changes. Every row is sampled, so there is no `sel`."""
+        work list. Every row is sampled, so there is no `sel`.
+
+        `group_rows` (a model with page groups): each row's `[(tokens
+        let go of, page numbers)]`, an entry a group. The row then
+        holds, after its first four columns, that number and the table
+        for each group in turn, and each group has its work list."""
         batch = len(tokens)
         padded_batch = _bucket(batch, _DECODE_BATCH_BUCKETS)
-        max_pages = max(len(t) for t in tables_list)
-        max_pages = -(-max_pages // self.pages_bucket) * \
-            self.pages_bucket
+        pad_rows = [0] * (padded_batch - batch)
+        if group_rows is None:
+            layout, views = (), None
+            widths = [self._table_width(max(len(t) for t in tables_list))]
+        else:
+            kinds = self.page_groups.kinds
+            # (a window group's table is never narrower than the
+            # window and a page: what it holds once a row has passed
+            # the window, but for the one step in sixteen at which the
+            # window starts on a page's edge, which is no program's key)
+            floor = -(-(self.page_groups.window or 0) // self.page_size) + 1
+            widths = [self._table_width(max(
+                [floor if kind == "window" else 1] +
+                [len(row[g][1]) for row in group_rows]))
+                for g, kind in enumerate(kinds)]
+            layout, views = tuple(widths), []
 
-        rows = np.zeros((padded_batch, 4 + max_pages), dtype=np.int32)
+        rows = np.zeros((padded_batch, 4 + sum(widths) + len(layout)),
+                        dtype=np.int32)
         rows[:, 2] = self.num_slots
         rows[:, 4:] = self.num_slots // self.page_size
         rows[:batch, 0] = tokens
         rows[:batch, 1] = positions
         rows[:batch, 2] = slot_list
         rows[:batch, 3] = ctx_list
-        for i, t in enumerate(tables_list):
-            rows[i, 4:4 + len(t)] = t
-
-        # Ragged decode work list: flatten (sequence, chunk) pairs over
-        # each row's REAL reserved pages so the attention grid has no
-        # padded cells for short contexts (the classic grid pads every
-        # row to the batch-max context). Chunk counts come from the
-        # reserved table lengths — a safe over-approximation of any
-        # context the burst scan reaches (pos_cap pins rows inside
-        # their reservation), so the list rides the whole burst.
-        ppc = choose_pages_per_chunk(max_pages, self.page_size,
-                                     self.attn_lane_bytes)
-        page_counts = [len(t) for t in tables_list] + \
-            [0] * (padded_batch - batch)
-        chunks = tuple(max(1, -(-c // ppc)) for c in page_counts)
-        work_key = (chunks, ppc, padded_work_length(
-            sum(chunks), padded_batch, max_pages, ppc))
-        if self._decode_work[0] != work_key:
-            wi_seq, wi_chunk = build_decode_work_list(
-                page_counts, ppc, pad_to=work_key[2])
-            self._decode_work = (work_key, (self._dev(wi_seq),
-                                            self._dev(wi_chunk)))
-        # The pages this step's attention copies and the pages that
-        # are live, by the kernel's rule (host arithmetic over the
-        # rows; `decode_attn_fetch_live_pct`).
-        fetched, live = count_decode_pages(
-            rows[:, 3], chunks, ppc, self.page_size)
+        if group_rows is None:
+            for i, t in enumerate(tables_list):
+                rows[i, 4:4 + len(t)] = t
+            work, ppc, chunks = self._decode_work_list(
+                "full", [len(t) for t in tables_list] + pad_rows,
+                padded_batch, widths[0])
+            # The pages this step's attention copies and the pages that
+            # are live, by the kernel's rule (host arithmetic over the
+            # rows; `decode_attn_fetch_live_pct`).
+            fetched, live = count_decode_pages(
+                rows[:, 3], chunks, ppc, self.page_size)
+        else:
+            fetched = live = 0
+            at = 4
+            for g, width in enumerate(widths):
+                rows[:, at] = 0
+                for i, row in enumerate(group_rows):
+                    let_go, table = row[g]
+                    rows[i, at] = let_go
+                    rows[i, at + 1:at + 1 + len(table)] = table
+                work, ppc, chunks = self._decode_work_list(
+                    kinds[g], [len(row[g][1]) for row in group_rows] +
+                    pad_rows, padded_batch, width)
+                views.append(GroupView(
+                    slot_mapping=None, block_tables=None,
+                    context_lens=None, decode_work=work, decode_ppc=ppc))
+                got = count_decode_pages(rows[:, 3] - rows[:, at], chunks,
+                                         ppc, self.page_size)
+                fetched, live = fetched + got[0], live + got[1]
+                self.tracer.add("attn.pages_live." + kinds[g],
+                                count=got[1])
+                if kinds[g] == "window":
+                    # (what the rows' whole contexts take in pages)
+                    self.tracer.add(
+                        "attn.window_pages_unwindowed",
+                        count=int(np.sum(-(-rows[:, 3] // self.page_size))))
+                at += 1 + width
+            work, ppc = views[0].decode_work, views[0].decode_ppc
         self.tracer.add("attn.pages_fetched", count=fetched)
         self.tracer.add("attn.pages_live", count=live)
+        self.tracer.add("attn.decode_steps", count=1)
 
         metadata = InputMetadata(
             slot_mapping=None,
@@ -849,9 +1007,11 @@ class ModelRunner:
             context_lens=None,
             kv_scale=self.kv_scale,
             tp=self._tp,
-            decode_work=self._decode_work[1],
+            decode_work=work,
             decode_ppc=ppc,
             spec_verify=spec_verify,
+            groups=None if views is None else tuple(views),
+            group_layout=layout,
         )
         return dict(input_ids=None, positions=None, metadata=metadata,
                     sel=None, padded_batch=padded_batch,
@@ -996,9 +1156,13 @@ class ModelRunner:
                     num_steps=num_steps, max_best_of=plan.max_best_of,
                     num_topk=plan.num_topk)
         self._mark_prefixes(inputs)
+        counts = None
+        if self.step_counters and burst is None:
+            packed, counts = packed
         return StepHandle(packed, sampling, plan,
                           num_steps=burst[2] if burst else 1,
-                          verify=inputs.get("verify")), kv_caches
+                          verify=inputs.get("verify"), counts=counts,
+                          is_prompt=inputs["is_prompt"]), kv_caches
 
     def _run_raw(self, inputs: dict, sampling: SamplingMetadata, params,
                  plan, kv_caches
@@ -1092,9 +1256,26 @@ class ModelRunner:
     def pull(self, handles: List[StepHandle]) -> List[np.ndarray]:
         """The ONE blocking transfer for the results of `handles`."""
         with self.tracer.span("runner.device_wait"):
-            pulled = jax.device_get([h.packed for h in handles])
+            pulled, counted = jax.device_get(
+                ([h.packed for h in handles],
+                 [h.counts for h in handles if h.counts is not None]))
         self.tracer.flight(-len(handles))
+        for handle, counts in zip(
+                (h for h in handles if h.counts is not None), counted):
+            self._add_step_counts(handle, counts)
         return [np.asarray(p) for p in pulled]
+
+    def _add_step_counts(self, handle: StepHandle, counts) -> None:
+        """What a step's program counted goes to the tracer; of a
+        decode step, the experts touched once more, beside the most it
+        could have touched."""
+        for name, value in zip(self.step_counters, counts):
+            self.tracer.add(name, count=int(value))
+            if name == "moe.experts_touched" and not handle.is_prompt:
+                self.tracer.add("moe.decode_experts_touched",
+                                count=int(value))
+                self.tracer.add("moe.decode_expert_slots",
+                                count=self.model.expert_slots)
 
     def finalize_step(self, handle: StepHandle,
                       packed_np: np.ndarray) -> list:

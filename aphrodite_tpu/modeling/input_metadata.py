@@ -19,6 +19,21 @@ from flax import struct
 
 
 @struct.dataclass
+class GroupView:
+    """What one page group of a step sees in the place of the batch's
+    own `slot_mapping`, `block_tables`, `context_lens`,
+    `prefill_cells` and `decode_work`: its table holds the pages the
+    group still has, from the first one on, and positions count from
+    that page's first token (a full group's from the sequence's)."""
+    slot_mapping: jax.Array
+    block_tables: jax.Array
+    context_lens: jax.Array
+    prefill_cells: Optional[tuple] = None
+    decode_work: Optional[tuple] = None
+    decode_ppc: int = struct.field(pytree_node=False, default=0)
+
+
+@struct.dataclass
 class InputMetadata:
     # [num_tokens] flat slot index per new token; padded entries hold an
     # out-of-range slot (>= num_pages*page_size) so the cache scatter drops
@@ -44,6 +59,17 @@ class InputMetadata:
     # burst-scan carry unchanged (chunk counts come from reserved
     # pages, a safe over-approximation of any in-burst context).
     decode_work: Optional[tuple] = None
+
+    # One `GroupView` a page group, for a model whose layers are not
+    # one plain group (`common/config.py::PageGroups`); None where
+    # they are, and the fields above are the step's. A layer takes its
+    # group's view with `for_group`.
+    groups: Optional[tuple] = None
+    # A decode batch of page groups as it is packed into
+    # `block_tables` (`ModelRunner._send_decode_batch`): each group's
+    # (columns of its table, whether a column with the tokens its
+    # table has let go of precedes them).
+    group_layout: tuple = struct.field(pytree_node=False, default=())
 
     is_prompt: bool = struct.field(pytree_node=False, default=False)
     # Speculative verify batch: rows are (sequence, position) work
@@ -80,3 +106,14 @@ class InputMetadata:
     # their prefill attention over the mesh's "sp" axis via ring
     # attention (ops/ring_attention.py).
     sp: object = struct.field(pytree_node=False, default=None)
+
+    def for_group(self, group: int) -> "InputMetadata":
+        """The step as page group `group` sees it."""
+        if self.groups is None:
+            return self
+        view = self.groups[group]
+        return self.replace(
+            slot_mapping=view.slot_mapping, block_tables=view.block_tables,
+            context_lens=view.context_lens,
+            prefill_cells=view.prefill_cells, decode_work=view.decode_work,
+            decode_ppc=view.decode_ppc, groups=None)

@@ -14,7 +14,12 @@ xformers prompt path `:104-161`, prefix path `:163-178`, decode dispatch
   (`ops/pallas/paged_attention.py`), with the jnp gather path as the
   interpret/CPU fallback.
 
-GQA/MQA, ALiBi, and sliding window are handled in all paths. Head sizes
+GQA/MQA, ALiBi, and sliding window are handled in all paths. A layer
+with a window sees, like any other, its page group's table
+(`InputMetadata.for_group`): the pages the group still holds, from the
+first on, positions counted from that page's first token; the window
+itself is a mask, in the prefill and in the decode kernel alike, so a
+window layer's decode step is the fused kernel's too. Head sizes
 are unrestricted (the reference's {64..256} list, `attention.py:17`, is a
 CUDA register-tiling constraint with no TPU analog).
 """
@@ -28,8 +33,10 @@ import numpy as np
 
 from aphrodite_tpu.common.utils import note_kernel_path
 from aphrodite_tpu.modeling.input_metadata import InputMetadata
-from aphrodite_tpu.ops.attention import (paged_decode_attention_ref,
-                                         prefill_attention)
+from aphrodite_tpu.ops.attention import (BLOCKED_FROM,
+                                         paged_decode_attention_ref,
+                                         prefill_attention,
+                                         prefill_attention_blocked)
 from aphrodite_tpu.ops.kv_cache import gather_pages, write_to_kv_cache
 
 
@@ -45,6 +52,7 @@ class PagedAttention:
         alibi_slopes: Optional[np.ndarray] = None,
         sliding_window: Optional[int] = None,
         use_pallas: bool = True,
+        page_group: int = 0,
     ) -> None:
         self.num_heads = num_heads
         self.head_size = head_size
@@ -55,6 +63,8 @@ class PagedAttention:
             jnp.asarray(alibi_slopes, dtype=jnp.float32)
         self.sliding_window = sliding_window
         self.use_pallas = use_pallas
+        # which of the step's page groups this layer's cache is in
+        self.page_group = page_group
         from aphrodite_tpu.ops.kv_cache import padded_head_size
         # Cache pages pad head_dim to the 128-lane tile; q/k/v pad with
         # zeros on the way in (inert in scores) and outputs slice the
@@ -73,6 +83,7 @@ class PagedAttention:
         """Returns (attn_out [batch, seq, num_heads*head_size], new
         k_pages, new v_pages). k_pages=None runs cache-less prefill (memory
         profiling, reference `model_runner.profile_run:571`)."""
+        metadata = metadata.for_group(self.page_group)
         batch, seq_len, _ = q.shape
         q = q.reshape(batch, seq_len, self.num_heads, self.head_size)
         k = k.reshape(batch, seq_len, self.num_kv_heads, self.head_size)
@@ -151,19 +162,17 @@ class PagedAttention:
                 k_pages, v_pages)
 
     def _fused_decode_ok(self, k_pages, metadata) -> bool:
-        """Routing precondition for the fused in-kernel KV write.
-        Sliding-window models write to a ROTATING ring slot
-        (pos % window, computed host-side in _prepare_decode); the
-        fused kernel derives the write position as ctx-1, which the
-        window clamp pins — so windowed models MUST keep the
-        slot-mapped writer path. Speculative verify batches carry
+        """Routing precondition for the fused in-kernel KV write. The
+        kernel derives the write position as ctx-1 of the table it is
+        given, which holds for a window layer too: its group's table
+        slides (it lets whole pages go and counts from the first it
+        keeps), it does not wrap. Speculative verify batches carry
         several rows per sequence into the same page; the fused
         write's one-row-per-page assumption does not hold, so they
         scatter first and attend read-only."""
         return (k_pages is not None and
                 not metadata.is_prompt and
                 not metadata.spec_verify and
-                self.sliding_window is None and
                 self._pallas_decode_ok(k_pages, metadata))
 
     def _pallas_decode_ok(self, k_pages, metadata) -> bool:
@@ -211,7 +220,10 @@ class PagedAttention:
             if self._ring_eligible(metadata, seq_len):
                 return self._ring_prefill(q, k, v, metadata)
 
-        return prefill_attention(
+        # (static: a function of the step program's shapes)
+        attend = prefill_attention_blocked \
+            if seq_len * kv_k.shape[1] >= BLOCKED_FROM else prefill_attention
+        return attend(
             q, kv_k, kv_v, context_lens, kv_valid, self.scale,
             sliding_window=self.sliding_window,
             alibi_slopes=self.alibi_slopes)
@@ -257,9 +269,8 @@ class PagedAttention:
             if knew is not None:
                 knew = jnp.pad(knew, hpad)
                 vnew = jnp.pad(vnew, hpad)
-        # Sliding window: context_lens are already clamped host-side to the
-        # window and block tables wrap (reference model_runner.py:278-293),
-        # so the kernels need no window logic in decode.
+        # Sliding window: the table is the window group's own and the
+        # kernels mask what lies before the newest `window` positions.
         # Quantized pages (int8/fp8) run in-kernel: the int8 scale folds
         # into the score scale and output epilogue (see ops/kv_quant.py).
         from aphrodite_tpu.ops.kv_quant import dequant_scale
@@ -300,7 +311,8 @@ class PagedAttention:
                 scale=self.scale,
                 kv_scale=dequant_scale(k_pages.dtype,
                                        metadata.kv_scale),
-                pages_per_chunk=ppc, work_items=work)
+                pages_per_chunk=ppc, work_items=work,
+                window=self.sliding_window)
             if knew is not None:
                 out, k_pages, v_pages = result
                 if self.padded_head != self.head_size:
@@ -316,7 +328,7 @@ class PagedAttention:
                 q3, k_pages, v_pages, metadata.block_tables,
                 metadata.context_lens, self.scale,
                 alibi_slopes=self.alibi_slopes,
-                kv_scale=metadata.kv_scale)
+                kv_scale=metadata.kv_scale, window=self.sliding_window)
         if self.padded_head != self.head_size:
             out = out[..., :self.head_size]
         return out[:, None]  # [batch, 1, H, d]

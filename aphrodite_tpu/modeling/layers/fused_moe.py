@@ -34,18 +34,34 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 class FusedMoE:
-    """Stacked-expert SwiGLU MoE with top-k softmax routing."""
+    """Stacked-expert gated MoE (`act(gate) * up`, SwiGLU by default,
+    ReGLU with `activation="relu"`) with top-k softmax routing.
+
+    `own_router=False`: the layer holds no router; the caller computes
+    the router logits from whatever tensor its architecture routes on
+    and passes them to `__call__` (SmallThinker routes on the layer's
+    input, before the norm and attention)."""
 
     def __init__(self, num_experts: int, top_k: int, hidden_size: int,
                  intermediate_size: int, *,
                  renormalize: bool = True,
+                 activation: str = "silu",
+                 own_router: bool = True,
                  dtype: jnp.dtype = jnp.bfloat16) -> None:
         self.num_experts = num_experts
         self.top_k = top_k
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
         self.renormalize = renormalize
+        if activation not in _ACTIVATIONS:
+            raise ValueError(f"FusedMoE gates with one of "
+                             f"{sorted(_ACTIVATIONS)}, not {activation!r}")
+        self.act = _ACTIVATIONS[activation]
+        self.own_router = own_router
         self.dtype = dtype
         # Set by the loader when the expert axis is actually partitioned
         # over a mesh; selects the GSPMD-friendly dense combine.
@@ -56,35 +72,59 @@ class FusedMoE:
     def init(self) -> Dict[str, jax.Array]:
         e, h, i = self.num_experts, self.hidden_size, \
             self.intermediate_size
-        return {
-            "gate": jnp.zeros((h, e), dtype=self.dtype),
+        params = {
             "w_gate": jnp.zeros((e, h, i), dtype=self.dtype),
             "w_up": jnp.zeros((e, h, i), dtype=self.dtype),
             "w_down": jnp.zeros((e, i, h), dtype=self.dtype),
         }
+        if self.own_router:
+            params["gate"] = jnp.zeros((h, e), dtype=self.dtype)
+        return params
 
     def specs(self) -> Dict[str, P]:
-        return {
-            "gate": P(None, None),
+        specs = {
             "w_gate": P("tp", None, None),
             "w_up": P("tp", None, None),
             "w_down": P("tp", None, None),
         }
+        if self.own_router:
+            specs["gate"] = P(None, None)
+        return specs
 
-    def __call__(self, params: Dict[str, jax.Array],
-                 hidden: jax.Array) -> jax.Array:
-        """hidden [..., hidden_size] -> same shape."""
-        sharded = self.sharded
-        orig_shape = hidden.shape
-        x = hidden.reshape(-1, self.hidden_size)          # [T, H]
-
-        router_logits = (x.astype(jnp.float32) @
-                         params["gate"].astype(jnp.float32))  # [T, E]
+    def route(self, router_logits: jax.Array
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """`(probs [T, E], top_vals [T, k], top_idx [T, k])` of float32
+        logits: the softmax over all experts, its `top_k` largest and,
+        renormalised, their weights (which is the softmax over the
+        `top_k` largest logits alone). The one routing function."""
         probs = jax.nn.softmax(router_logits, axis=-1)
         top_vals, top_idx = jax.lax.top_k(probs, self.top_k)  # [T, k]
         if self.renormalize:
             top_vals = top_vals / jnp.sum(top_vals, axis=-1,
                                           keepdims=True)
+        return probs, top_vals, top_idx
+
+    def __call__(self, params: Dict[str, jax.Array], hidden: jax.Array,
+                 router_logits: Optional[jax.Array] = None,
+                 counts: Optional[list] = None) -> jax.Array:
+        """hidden [..., hidden_size] -> same shape. `router_logits`
+        [..., E]: the caller's, in the place of `hidden @ gate`.
+        `counts`: a list that gains this call's `(token-expert pairs,
+        experts with a pair)`, int32 scalars counted in the program."""
+        sharded = self.sharded
+        orig_shape = hidden.shape
+        x = hidden.reshape(-1, self.hidden_size)          # [T, H]
+
+        if router_logits is None:
+            router_logits = (x.astype(jnp.float32) @
+                             params["gate"].astype(jnp.float32))  # [T, E]
+        probs, top_vals, top_idx = self.route(
+            router_logits.reshape(-1, self.num_experts).astype(
+                jnp.float32))
+        if counts is not None:
+            touched = jnp.zeros((self.num_experts,), jnp.int32).at[
+                top_idx.reshape(-1)].set(1)
+            counts.append((jnp.int32(top_idx.size), jnp.sum(touched)))
 
         if self.num_experts > 4 and not sharded:
             out = self._ragged_ffn(params, x, top_vals, top_idx)
@@ -101,7 +141,7 @@ class FusedMoE:
         # All-expert SwiGLU: [E, T, I] intermediates.
         gate = jnp.einsum("th,ehi->eti", x, params["w_gate"])
         up = jnp.einsum("th,ehi->eti", x, params["w_up"])
-        act = jax.nn.silu(gate) * up
+        act = self.act(gate) * up
         expert_out = jnp.einsum("eti,eih->eth", act, params["w_down"])
         return jnp.einsum("eth,te->th", expert_out,
                           combine.astype(expert_out.dtype))
@@ -126,7 +166,7 @@ class FusedMoE:
         gate = jax.lax.ragged_dot(x_sorted, params["w_gate"],
                                   group_sizes)
         up = jax.lax.ragged_dot(x_sorted, params["w_up"], group_sizes)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) *
+        act = (self.act(gate.astype(jnp.float32)) *
                up.astype(jnp.float32)).astype(x.dtype)
         down = jax.lax.ragged_dot(act, params["w_down"], group_sizes)
 

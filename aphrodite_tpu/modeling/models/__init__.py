@@ -26,6 +26,7 @@ _MODELS = {
     "GPTNeoXForCausalLM": ("gpt_neox", "GPTNeoXForCausalLM"),
     "PhiForCausalLM": ("phi", "PhiForCausalLM"),
     "Qwen2ForCausalLM": ("qwen2", "Qwen2ForCausalLM"),
+    "SmallThinkerForCausalLM": ("smallthinker", "SmallThinkerForCausalLM"),
 }
 
 

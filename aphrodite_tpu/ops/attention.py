@@ -100,6 +100,77 @@ def prefill_attention(
     return out.reshape(b, s, num_q_heads, d).astype(q.dtype)
 
 
+#: `prefill_attention` holds float32 scores `[batch, heads, queries,
+#: keys]`. From this many (queries x keys) a row on, the keys go by in
+#: blocks under an online softmax instead (`prefill_attention_blocked`):
+#: at 28 heads, 8,192 queries against 8,192 keys are 7.5 GB of scores,
+#: a 2,048-token chunk against them 1.9 GB.
+BLOCKED_FROM = 1 << 23
+KEY_BLOCK = 512
+
+
+def prefill_attention_blocked(
+    q: jax.Array, k: jax.Array, v: jax.Array, context_lens: jax.Array,
+    kv_valid_lens: jax.Array, scale: float,
+    sliding_window: Optional[int] = None,
+    alibi_slopes: Optional[jax.Array] = None,
+    key_block: int = KEY_BLOCK,
+) -> jax.Array:
+    """`prefill_attention`, the keys taken `key_block` at a time: the
+    running maximum, sum and weighted values of every query are
+    carried from block to block (the online softmax), so the transient
+    is `[batch, heads, queries, key_block]` whatever the context. The
+    mask is the same; a block no query may see adds nothing."""
+    b, s, num_q_heads, d = q.shape
+    kv_len, num_kv_heads = k.shape[1], k.shape[2]
+    group = num_q_heads // num_kv_heads
+    blocks = -(-kv_len // key_block)
+    pad = blocks * key_block - kv_len
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    qg = q.reshape(b, s, num_kv_heads, group, d).astype(jnp.float32)
+    abs_q = (jnp.arange(s, dtype=jnp.int32)[None, :] +
+             context_lens[:, None])[:, None, None, :, None]  # b,1,1,s,1
+
+    def body(carry, at):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice_in_dim(k, at, key_block, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(v, at, key_block, axis=1)
+        scores = jnp.einsum("bskgd,btkd->bkgst", qg,
+                            kb.astype(jnp.float32)) * scale
+        kv_pos = at + jnp.arange(key_block, dtype=jnp.int32)
+        if alibi_slopes is not None:
+            scores += (alibi_slopes.reshape(num_kv_heads, group, 1, 1) *
+                       kv_pos.astype(jnp.float32))[None]
+        mask = (kv_pos <= abs_q) & \
+            (kv_pos < kv_valid_lens[:, None, None, None, None])
+        if sliding_window is not None:
+            mask &= kv_pos > abs_q - sliding_window
+        scores = jnp.where(mask, scores, _NEG_INF)
+        m_new = jnp.maximum(m, scores.max(axis=-1))
+        # a query that has seen no key yet keeps m = -inf: its terms
+        # are exp(-inf - 0) = 0, not exp(-inf + inf)
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(scores - m_safe[..., None])
+        fade = jnp.exp(m - m_safe)
+        l = l * fade + p.sum(axis=-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bkgst,btkd->bkgsd", p, vb.astype(jnp.float32))
+        return (m_new, l, acc), None
+
+    shape = (b, num_kv_heads, group, s)
+    (_, l, acc), _ = jax.lax.scan(
+        body, (jnp.full(shape, _NEG_INF, jnp.float32),
+               jnp.zeros(shape, jnp.float32),
+               jnp.zeros(shape + (d,), jnp.float32)),
+        jnp.arange(blocks, dtype=jnp.int32) * key_block)
+    # a query with no key at all (padding) gives zeros, as above
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(
+        b, s, num_q_heads, d).astype(q.dtype)
+
+
 def paged_decode_attention_ref(
     q: jax.Array,              # [batch, num_q_heads, head_dim]
     k_pages: jax.Array,        # [num_pages, page_size, Hkv * head_dim]
@@ -109,12 +180,14 @@ def paged_decode_attention_ref(
     scale: float,
     alibi_slopes: Optional[jax.Array] = None,
     kv_scale: float = 1.0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Decode attention over the paged cache — jnp reference path.
 
     Gathers each sequence's pages then runs masked attention. Correct
     everywhere; materializes the gathered KV (extra HBM traffic) which the
-    Pallas kernel avoids.
+    Pallas kernel avoids. `window`: the newest `window` positions of
+    the table only, as the kernel's.
     """
     from aphrodite_tpu.ops.kv_cache import gather_pages
     from aphrodite_tpu.ops.kv_quant import dequant_scale
@@ -138,6 +211,8 @@ def paged_decode_attention_ref(
 
     positions = jnp.arange(ctx)[None, None, None, :]
     mask = positions < context_lens[:, None, None, None]
+    if window is not None:
+        mask &= positions >= context_lens[:, None, None, None] - window
     scores = jnp.where(mask, scores, _NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgt,bktd->bkgd", weights,

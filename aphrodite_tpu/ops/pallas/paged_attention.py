@@ -325,6 +325,7 @@ def _decode_kernel_tm(
     single_chunk: bool = False,
     fused_write: bool = False,
     amla: bool = True,
+    window: int = None,
 ):
     refs = list(refs)
     q_ref, k_hbm, v_hbm = refs[:3]
@@ -533,6 +534,10 @@ def _decode_kernel_tm(
             s = s + (slopes_ref[0, :, :1] * _LOG2E) * \
                 pos.astype(jnp.float32)
         live = pos < ctx
+        if window is not None:
+            # a causal window: the newest `window` keys, the row's own
+            # among them
+            live = live & (pos >= ctx - window)
         s = jnp.where(live, s, _NEG_INF)
 
         m_prev = m_scr[:, :1]                        # [rows, 1]
@@ -640,6 +645,7 @@ def _decode_kernel_ragged(
     fused_write: bool = False,
     amla: bool = True,
     ablate: str = None,
+    window: int = None,
 ):
     refs = list(refs)
     q_ref, k_hbm, v_hbm = refs[:3]
@@ -879,6 +885,10 @@ def _decode_kernel_ragged(
             s = s + (slopes_ref[0, :, :1] * _LOG2E) * \
                 pos.astype(jnp.float32)
         live = pos < ctx
+        if window is not None:
+            # a causal window: the newest `window` keys, the row's own
+            # among them
+            live = live & (pos >= ctx - window)
         s = jnp.where(live, s, _NEG_INF)
 
         m_prev = m_scr[:, :1]                        # [rows, 1]
@@ -962,11 +972,11 @@ def _ring_slots(pf_depth: int, chunk_tokens: int, lane_bytes: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "kv_scale", "pages_per_chunk", "pf_depth",
-                     "amla", "interpret", "ablate"))
+                     "amla", "interpret", "ablate", "window"))
 def _paged_decode_impl(
     q, k_pages, v_pages, block_tables, context_lens, wi_seq, wi_chunk,
     alibi_slopes, knew, vnew, *, scale, kv_scale, pages_per_chunk,
-    pf_depth, amla, interpret, ablate=None,
+    pf_depth, amla, interpret, ablate=None, window=None,
 ):
     batch, num_q_heads, head_dim = q.shape
     num_pages, page_size, hd = k_pages.shape
@@ -1007,7 +1017,7 @@ def _paged_decode_impl(
             pf_depth=min(pf_depth, n_slots - 2), chunk_slots=n_slots,
             whole_lanes=n_hb == 1,
             has_alibi=alibi_slopes is not None, fused_write=fused_write,
-            amla=amla, ablate=ablate)
+            amla=amla, ablate=ablate, window=window)
         grid = (n_hb, nw)
 
         def qmap(j, w, tbl, cl, ws, wc):
@@ -1031,7 +1041,7 @@ def _paged_decode_impl(
             chunk_slots=n_slots,
             has_alibi=alibi_slopes is not None,
             single_chunk=single_chunk, fused_write=fused_write,
-            amla=amla)
+            amla=amla, window=window)
         grid = (batch, n_hb)
 
         def qmap(b, j, *_):
@@ -1151,6 +1161,7 @@ def paged_decode_attention(
     amla=None,                # pin the rescale variant (A/B hook)
     ablate: str = None,       # benchmarks/attn_ab.py: time a part alone
     interpret: bool = False,
+    window: int = None,       # attend over the newest `window` keys only
 ):
     """Token-major flash-decoding attention (see module docstring).
 
@@ -1177,7 +1188,13 @@ def paged_decode_attention(
 
     `amla` pins the online-softmax rescale variant: True = AMLA
     exponent-bias adds, False = the classic per-chunk multiply (A/B);
-    None reads APHRODITE_ATTN_AMLA (default on)."""
+    None reads APHRODITE_ATTN_AMLA (default on).
+
+    `window`: a row attends over positions `ctx - window` to `ctx - 1`
+    of its table only (a causal window of `window` keys, its own
+    among them). The caller's table starts at the page that holds the
+    oldest of them, or before it: a row's first work item must have a
+    live key."""
     batch, num_q_heads, head_dim = q.shape
     num_pages, page_size, hd = k_pages.shape
     if hd % head_dim != 0:
@@ -1208,4 +1225,4 @@ def paged_decode_attention(
         wi_chunk, alibi_slopes, knew, vnew, scale=scale,
         kv_scale=kv_scale, pages_per_chunk=ppc, pf_depth=pf_depth,
         amla=use_amla, interpret=interpret,
-        ablate=ablate if use_ragged else None)
+        ablate=ablate if use_ragged else None, window=window)

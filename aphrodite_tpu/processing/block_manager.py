@@ -1,8 +1,16 @@
 """Paged KV-cache block accounting (host side) — the page OWNER.
 
 Semantics match the reference's `aphrodite/processing/block_manager.py:10,68`
-(ref-counted allocator, watermark admission, copy-on-write fork, sliding-
-window block reuse, host<->HBM swap planning). This module is pure Python
+(ref-counted allocator, watermark admission, copy-on-write fork,
+host<->HBM swap planning), but for the sliding window: a sequence holds
+one block table for each PAGE GROUP of its model
+(`common/config.py::PageGroups`), all from the one free list, and a
+window group's table SLIDES. It lets go of every page that lies wholly
+before the window of the oldest query still to come, in the round that
+passes it, and takes new pages at its end, so it holds the window, the
+chunk being written and a page at most. A model-wide window is the
+case of one group, a window group; a model without one has one full
+group and is served exactly as before. This module is pure Python
 and device-agnostic: it only plans block operations; the executor applies
 them to the HBM page arrays (`executor/cache.py`) as batched gathers/
 scatters and host transfers — there is no per-block memcpy on TPU, the
@@ -70,6 +78,16 @@ class BlockPool:
 BlockAllocator = BlockPool
 
 
+class PageGroupsUnsupported(RuntimeError):
+    """What a model with a window or with several page groups is
+    refused, rather than served half-right."""
+
+    def __init__(self, what: str, instead: str) -> None:
+        super().__init__(
+            f"{what} is not supported for a model whose KV pages are "
+            f"in window groups or in more than one group: {instead}")
+
+
 class AllocStatus(enum.Enum):
     """Admission verdict for a waiting sequence group."""
     OK = enum.auto()       # fits now
@@ -87,18 +105,36 @@ class BlockSpaceManager:
         num_cpu_blocks: int,
         watermark: float = 0.01,
         sliding_window: Optional[int] = None,
+        group_kinds: Optional[Tuple[str, ...]] = None,
+        max_chunk_tokens: Optional[int] = None,
     ) -> None:
+        """`group_kinds`: "full" or "window" for each page group (one
+        group by default: a window group where `sliding_window` is
+        set). `max_chunk_tokens`: the longest prompt chunk the
+        scheduler writes at once for a model with a window group
+        (None: a prompt may come whole)."""
         self.block_size = block_size
         self.num_total_gpu_blocks = num_gpu_blocks
         self.num_total_cpu_blocks = num_cpu_blocks
 
-        self.block_sliding_window: Optional[int] = None
-        if sliding_window is not None:
-            if sliding_window % block_size != 0:
-                raise ValueError(
-                    f"Sliding window ({sliding_window}) must be a multiple "
-                    f"of block size ({block_size}).")
-            self.block_sliding_window = sliding_window // block_size
+        self.group_kinds: Tuple[str, ...] = tuple(group_kinds) \
+            if group_kinds else (
+                ("full",) if sliding_window is None else ("window",))
+        #: one full group: block tables, swap, prefix pins and
+        #: look-ahead reservations as ever
+        self.plain = self.group_kinds == ("full",)
+        self.sliding_window = sliding_window
+        if "window" in self.group_kinds and not sliding_window:
+            raise ValueError("a window page group needs sliding_window")
+        #: the most pages a window group takes for a prompt: the
+        #: window, the longest chunk, and a page (neither end of the
+        #: two need lie on a page's edge)
+        self.window_cap_blocks: Optional[int] = None
+        if sliding_window is not None and max_chunk_tokens is not None:
+            self.window_cap_blocks = -(-sliding_window // block_size) + \
+                -(-max_chunk_tokens // block_size) + 1
+        #: pages that window groups have let go of (cumulative)
+        self.window_pages_freed = 0
 
         assert watermark >= 0.0
         self.watermark = watermark
@@ -113,6 +149,14 @@ class BlockSpaceManager:
         # steps (engine_step awaits the step future first); the two
         # writers are sequenced by the engine loop, never concurrent.
         self.block_tables: Dict[int, BlockTable] = {}
+        # A sequence's tables of the groups after the first, and, for
+        # every group, how many pages its table has let go of at its
+        # start (both only where the groups are not plain).
+        # thread-safe: written where `block_tables` is and nowhere
+        # else, so the same sequencing by the engine loop holds.
+        self.more_tables: Dict[int, List[BlockTable]] = {}
+        # thread-safe: as `more_tables`.
+        self.first_blocks: Dict[int, List[int]] = {}
 
     @property
     def gpu_allocator(self) -> BlockPool:
@@ -131,12 +175,21 @@ class BlockSpaceManager:
     def _prompt_blocks_needed(self, seq_group: SequenceGroup) -> int:
         seq = seq_group.get_seqs(status=SequenceStatus.WAITING)[0]
         needed = len(seq.logical_token_blocks)
+        if not self.plain:
+            return sum(self._prompt_blocks_of(kind, needed)
+                       for kind in self.group_kinds)
         prefix = seq_group.prefix
         if prefix is not None and prefix.allocated:
             needed -= prefix.get_num_blocks()
-        if self.block_sliding_window is not None:
-            needed = min(needed, self.block_sliding_window)
         return needed
+
+    def _prompt_blocks_of(self, kind: str, prompt_blocks: int) -> int:
+        """Pages a group of `kind` takes when a prompt is admitted: a
+        window group never needs more than its cap at once, and takes
+        the rest as its table slides (`prepare_chunk`)."""
+        if kind == "window" and self.window_cap_blocks is not None:
+            return min(prompt_blocks, self.window_cap_blocks)
+        return prompt_blocks
 
     def can_allocate(self, seq_group: SequenceGroup,
                      extra_reserved: int = 0) -> AllocStatus:
@@ -162,28 +215,26 @@ class BlockSpaceManager:
         # physical block table (forked on first divergent append).
         seq = seq_group.get_seqs(status=SequenceStatus.WAITING)[0]
         num_prompt_blocks = len(seq.logical_token_blocks)
+        prefix = seq_group.prefix
+        if not self.plain:
+            if prefix is not None:
+                raise PageGroupsUnsupported(
+                    "the prefix cache", "send the request without a "
+                    "cached prefix")
+            self._allocate_groups(seq_group, num_prompt_blocks)
+            return
 
         block_table: BlockTable = []
-        prefix = seq_group.prefix
         if prefix is not None and prefix.allocated:
             num_prompt_blocks -= prefix.get_num_blocks()
             for block in prefix.block_table:
                 block.ref_count += seq_group.num_seqs()
                 block_table.append(block)
 
-        for logical_idx in range(num_prompt_blocks):
-            if (self.block_sliding_window is not None
-                    and logical_idx >= self.block_sliding_window):
-                # Sliding-window reuse: the aliased block is already in
-                # this table and already carries this group's refs —
-                # frees walk set(table), one decrement per unique block.
-                # Re-assigning `= num_seqs` here (the reference's shape)
-                # CLOBBERED a prefix-pinned or cross-group-shared count
-                # when the window wrapped onto a prefix block.
-                block = block_table[logical_idx % self.block_sliding_window]
-            else:
-                block = self.hbm_pool.allocate()
-                block.ref_count = seq_group.num_seqs()
+        num_seqs = seq_group.num_seqs()
+        for _ in range(num_prompt_blocks):
+            block = self.hbm_pool.allocate()
+            block.ref_count = num_seqs
             block_table.append(block)
 
         if prefix is not None and not prefix.allocated:
@@ -197,45 +248,123 @@ class BlockSpaceManager:
         for waiting_seq in seq_group.get_seqs(status=SequenceStatus.WAITING):
             self.block_tables[waiting_seq.seq_id] = block_table.copy()
 
+    def _allocate_groups(self, seq_group: SequenceGroup,
+                         num_prompt_blocks: int) -> None:
+        """A table for each page group, from the one free list: a
+        full group's for the whole prompt, a window group's up to its
+        cap."""
+        num_seqs = seq_group.num_seqs()
+        waiting = seq_group.get_seqs(status=SequenceStatus.WAITING)
+        for seq in waiting:
+            self.more_tables[seq.seq_id] = []
+            self.first_blocks[seq.seq_id] = [0] * len(self.group_kinds)
+        for g, kind in enumerate(self.group_kinds):
+            block_table: BlockTable = []
+            for _ in range(self._prompt_blocks_of(kind,
+                                                  num_prompt_blocks)):
+                block = self.hbm_pool.allocate()
+                block.ref_count = num_seqs
+                block_table.append(block)
+            for seq in waiting:
+                if g == 0:
+                    self.block_tables[seq.seq_id] = block_table.copy()
+                else:
+                    self.more_tables[seq.seq_id].append(block_table.copy())
+
     # ------------------------------------------------------------------
-    # Decode-time slot append (with CoW)
+    # The tables follow the sequence: window release, new pages, CoW
     # ------------------------------------------------------------------
+
+    def _tables(self, seq_id: int) -> List[BlockTable]:
+        """The sequence's table of each page group."""
+        return [self.block_tables[seq_id]] + \
+            self.more_tables.get(seq_id, [])
+
+    def release_passed(self, seq: Sequence, first_query: int) -> int:
+        """Let the window groups go of every page that lies wholly
+        before the window of position `first_query`, the oldest query
+        still to come; no later one reaches back further. The pages
+        are on the free list at once, for any group of any sequence.
+        Returns how many were let go."""
+        firsts = self.first_blocks.get(seq.seq_id)
+        if firsts is None or self.sliding_window is None:
+            return 0
+        keep_from = max(0, first_query - self.sliding_window + 1) \
+            // self.block_size
+        freed = 0
+        for g, table in enumerate(self._tables(seq.seq_id)):
+            drop = min(keep_from - firsts[g], len(table))
+            if self.group_kinds[g] != "window" or drop <= 0:
+                continue
+            for block in table[:drop]:
+                self.hbm_pool.free(block)
+            del table[:drop]
+            firsts[g] += drop
+            freed += drop
+        self.window_pages_freed += freed
+        return freed
+
+    def _cover(self, seq_id: int, last_pos: int) -> None:
+        """New pages at every table's end, up to position `last_pos`.
+        (Here and in `append_slots` a table is read off its owned
+        container by name and not through `_tables`: the ownership
+        ledger, `OWNERSHIP.json`, follows a page from `allocate()` to
+        the container it lands in.)"""
+        firsts = self.first_blocks.get(seq_id)
+        table = self.block_tables[seq_id]
+        for g in range(len(self.group_kinds)):
+            if g:
+                table = self.more_tables[seq_id][g - 1]
+            needed = last_pos // self.block_size + 1 - \
+                (firsts[g] if firsts else 0)
+            while len(table) < needed:
+                table.append(self.hbm_pool.allocate())
+
+    def prepare_chunk(self, seq: Sequence, ctx: int, length: int) -> None:
+        """Before the prompt chunk `[ctx, ctx + length)` is written:
+        the window groups let go of what its first query no longer
+        sees, and take the pages up to its last token (a prompt's
+        pages beyond a window group's cap are not taken at
+        admission). What they let go covers what they take, once a
+        table has reached its cap. A plain model's tables have held
+        the whole prompt since admission."""
+        if self.plain:
+            return
+        self.release_passed(seq, ctx)
+        self._cover(seq.seq_id, ctx + length - 1)
 
     def can_append_slot(self, seq_group: SequenceGroup) -> bool:
-        # One new block per running sequence is the worst case.
+        # One new block per running sequence and page group is the
+        # worst case.
         num_seqs = seq_group.num_seqs(status=SequenceStatus.RUNNING)
-        return num_seqs <= self.hbm_pool.get_num_free_blocks()
+        return num_seqs * len(self.group_kinds) <= \
+            self.hbm_pool.get_num_free_blocks()
 
-    def append_slot(self, seq: Sequence) -> Optional[Tuple[int, int]]:
-        """Reserve a slot for one new token.
+    def append_slots(self, seq: Sequence) -> List[Tuple[int, int]]:
+        """Reserve a slot for one new token in every page group.
 
-        Returns a (src, dst) physical block pair when a copy-on-write is
-        required (the executor batches all pairs into one device copy).
-        """
-        logical_blocks = seq.logical_token_blocks
+        Returns the (src, dst) physical block pairs of the
+        copy-on-writes required (the executor batches all pairs into
+        one device copy)."""
+        pos = seq.get_len() - 1
+        self.release_passed(seq, pos)
+        self._cover(seq.seq_id, pos)
+        copies = []
         block_table = self.block_tables[seq.seq_id]
-
-        if len(block_table) < len(logical_blocks):
-            if (self.block_sliding_window
-                    and len(block_table) >= self.block_sliding_window):
-                # Sliding window: cycle back onto the oldest in-window
-                # block — which may be shared post-fork, so fall through to
-                # the CoW check below.
-                block_table.append(block_table[len(block_table) %
-                                               self.block_sliding_window])
-            else:
-                block_table.append(self.hbm_pool.allocate())
-                return None
-
-        last_block = block_table[-1]
-        assert last_block.device == Device.TPU
-        if last_block.ref_count == 1:
-            return None
-        # Shared tail block (post-fork): copy-on-write.
-        new_block = self.hbm_pool.allocate()
-        block_table[-1] = new_block
-        self.hbm_pool.free(last_block)
-        return last_block.block_number, new_block.block_number
+        for g in range(len(self.group_kinds)):
+            if g:
+                block_table = self.more_tables[seq.seq_id][g - 1]
+            last_block = block_table[-1]
+            assert last_block.device == Device.TPU
+            if last_block.ref_count == 1:
+                continue
+            # Shared tail block (post-fork): copy-on-write.
+            new_block = self.hbm_pool.allocate()
+            block_table[-1] = new_block
+            self.hbm_pool.free(last_block)
+            copies.append((last_block.block_number,
+                           new_block.block_number))
+        return copies
 
     def burst_blocks_needed(self, seq: Sequence, num_ahead: int) -> int:
         """Blocks to allocate so the table covers positions up to
@@ -245,20 +374,24 @@ class BlockSpaceManager:
         return max(0, needed - len(table))
 
     def has_unshared_tail(self, seq: Sequence) -> bool:
-        table = self.block_tables.get(seq.seq_id)
-        return bool(table) and table[-1].ref_count == 1
+        if seq.seq_id not in self.block_tables:
+            return False
+        return all(table and table[-1].ref_count == 1
+                   for table in self._tables(seq.seq_id))
 
     def reserve_slots(self, seq: Sequence, num_ahead: int) -> None:
         """Append enough fresh blocks for `num_ahead` future tokens.
 
         Only valid for unshared-tail sequences (no CoW can arise); the
         device computes each burst step's slot from the block table, so
-        the pages must exist before the burst launches.
+        the pages must exist before the burst launches. With page
+        groups this is the slot of a token still on the device, which
+        is the next query: the window groups let go behind it.
         """
-        table = self.block_tables[seq.seq_id]
-        needed = (seq.get_len() - 1 + num_ahead) // self.block_size + 1
-        while len(table) < needed:
-            table.append(self.hbm_pool.allocate())
+        last_pos = seq.get_len() - 1 + num_ahead
+        if not self.plain:
+            self.release_passed(seq, last_pos)
+        self._cover(seq.seq_id, last_pos)
 
     def trim_reserved(self, seq: Sequence) -> int:
         """Release look-ahead pages reserved past the sequence's
@@ -268,7 +401,7 @@ class BlockSpaceManager:
         swapped tail means the pages are owned by more than this
         reservation. Returns the number of pages freed."""
         table = self.block_tables.get(seq.seq_id)
-        if not table or self.block_sliding_window is not None:
+        if not table or not self.plain:
             return 0
         # (the slot of a token still on the device is no look-ahead:
         # the step that takes it is being scheduled or is in flight)
@@ -286,6 +419,15 @@ class BlockSpaceManager:
         self.block_tables[child_seq.seq_id] = src_block_table.copy()
         for block in src_block_table:
             block.ref_count += 1
+        if self.plain:
+            return
+        more = [t.copy() for t in self.more_tables[parent_seq.seq_id]]
+        self.more_tables[child_seq.seq_id] = more
+        self.first_blocks[child_seq.seq_id] = list(
+            self.first_blocks[parent_seq.seq_id])
+        for src_block_table in more:
+            for block in src_block_table:
+                block.ref_count += 1
 
     # ------------------------------------------------------------------
     # Swap planning (preemption-by-swap)
@@ -301,6 +443,7 @@ class BlockSpaceManager:
         return list(blocks)
 
     def can_swap_in(self, seq_group: SequenceGroup) -> bool:
+        self._plain_only("preemption by swap")
         blocks = self._group_physical_blocks(seq_group)
         num_swapped_seqs = seq_group.num_seqs(status=SequenceStatus.SWAPPED)
         free = self.hbm_pool.get_num_free_blocks()
@@ -335,6 +478,7 @@ class BlockSpaceManager:
         }
 
     def can_swap_out(self, seq_group: SequenceGroup) -> bool:
+        self._plain_only("preemption by swap")
         blocks = self._group_physical_blocks(seq_group)
         return len(blocks) <= self.host_pool.get_num_free_blocks()
 
@@ -378,11 +522,25 @@ class BlockSpaceManager:
             else:
                 self.host_pool.free(block)
 
+    def _free_group_tables(self, tables: List[BlockTable]) -> None:
+        """A sequence's tables of the page groups after the first."""
+        for group_table in tables:
+            self._free_block_table(group_table)
+
+    def _plain_only(self, what: str) -> None:
+        if not self.plain:
+            raise PageGroupsUnsupported(
+                what, "a sequence group of several sequences is "
+                "preempted by swap: use best_of 1, or give the pool "
+                "room (--gpu-memory-utilization, --max-num-seqs)")
+
     def free(self, seq: Sequence) -> None:
         if seq.seq_id not in self.block_tables:
             # Never scheduled, or already freed.
             return
         self._free_block_table(self.block_tables.pop(seq.seq_id))
+        self._free_group_tables(self.more_tables.pop(seq.seq_id, []))
+        self.first_blocks.pop(seq.seq_id, None)
 
     def free_prefix(self, prefix: Prefix) -> int:
         """Release a prefix's pin: the one refcount `allocate` added
@@ -403,10 +561,26 @@ class BlockSpaceManager:
     def reset(self) -> None:
         for block_table in self.block_tables.values():
             self._free_block_table(block_table)
+        for tables in self.more_tables.values():
+            self._free_group_tables(tables)
         self.block_tables.clear()
+        self.more_tables.clear()
+        self.first_blocks.clear()
 
     def get_block_table(self, seq: Sequence) -> List[int]:
         return [b.block_number for b in self.block_tables[seq.seq_id]]
+
+    def get_group_tables(self, seq: Sequence
+                         ) -> Optional[List[Tuple[int, List[int]]]]:
+        """For a model whose page groups are not plain: each group's
+        (tokens its table has let go of at its start, page numbers);
+        None for a plain one, whose table is `get_block_table`'s."""
+        if self.plain:
+            return None
+        firsts = self.first_blocks[seq.seq_id]
+        return [(firsts[g] * self.block_size,
+                 [b.block_number for b in table])
+                for g, table in enumerate(self._tables(seq.seq_id))]
 
     def block_numbers(self, seq_id: int) -> List[int]:
         """Page numbers for one sequence id — the int-only projection

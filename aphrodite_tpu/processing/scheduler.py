@@ -147,11 +147,21 @@ class Scheduler:
                                 scheduler_config.max_num_batched_tokens)
 
         self.policy = PolicyFactory.get_policy(policy_name="fcfs")
+        # A model with a window group has its prompts written in
+        # chunks of this many tokens at most, whatever else the round
+        # holds: the window groups' tables then stay under the window,
+        # a chunk and a page, and so does the prefill's transient.
+        groups = cache_config.page_groups
+        self.window_chunk_cap: Optional[int] = \
+            scheduler_config.window_chunk_cap \
+            if groups.window is not None else None
         self.block_manager = BlockSpaceManager(
             block_size=cache_config.block_size,
             num_gpu_blocks=cache_config.num_gpu_blocks,
             num_cpu_blocks=cache_config.num_cpu_blocks,
-            sliding_window=cache_config.sliding_window)
+            sliding_window=groups.window,
+            group_kinds=groups.kinds,
+            max_chunk_tokens=self.window_chunk_cap)
         self.prefix_pool = PrefixPool(cache_config.block_size)
 
         # thread-safe: two-world by sequencing, not locking — the
@@ -289,6 +299,8 @@ class Scheduler:
         padded-batch cost (rows x longest row) stays within `budget`.
         Partial chunks stay page-aligned (the whole-page prefill writer
         requires every row's cached context to be a page multiple)."""
+        if self.window_chunk_cap is not None:
+            budget = min(budget, self.window_chunk_cap)
         rows = len(seq_lens) + 1
         longest = max(seq_lens) if seq_lens else 0
         limit = budget // rows
@@ -321,6 +333,7 @@ class Scheduler:
                 self.prefilling.clear()
                 break
             final = n == remaining
+            self._prepare_chunk(seq, ctx, n)
             chunks.append(PromptChunk(group, ctx, n, final))
             seq_lens.append(n)
             seq.data.num_computed_tokens = ctx + n
@@ -329,6 +342,18 @@ class Scheduler:
             else:
                 still.append(group)
         self.prefilling = still
+
+    def _prepare_chunk(self, seq: Sequence, ctx: int, n: int) -> None:
+        """A model with page groups: its window groups let go of the
+        pages the chunk has passed and take the chunk's own."""
+        manager = self.block_manager
+        if manager.plain:
+            return
+        freed = manager.window_pages_freed
+        with self.tracer.span("cache.window_release"):
+            manager.prepare_chunk(seq, ctx, n)
+        self.tracer.add("cache.window_pages_freed",
+                        count=manager.window_pages_freed - freed)
 
     def _admit_prompts(self, seq_lens: List[int], budget: int,
                        chunks: List[PromptChunk],
@@ -347,10 +372,6 @@ class Scheduler:
                           list(self.prefilling))
                       if self.lora_enabled else None)
         deferred: Deque[SequenceGroup] = deque()
-        # Chunked prefill needs the gather-over-pages attention path,
-        # which does not model sliding-window rings; such models admit
-        # whole prompts only.
-        can_split = self.cache_config.sliding_window is None
         page_reserve = self._admission_page_reserve()
 
         while self.waiting:
@@ -415,9 +436,8 @@ class Scheduler:
             if n <= 0:
                 break
             final = n == remaining
-            if not final and (not can_split
-                              or group.sampling_params.prompt_logprobs
-                              is not None):
+            if not final and \
+                    group.sampling_params.prompt_logprobs is not None:
                 # Needs the whole prompt in one round; wait for one.
                 break
 
@@ -447,6 +467,7 @@ class Scheduler:
                                 group.arrival_time)
             num_curr_seqs += num_new_seqs
             seq = group.get_seqs(status=SequenceStatus.RUNNING)[0]
+            self._prepare_chunk(seq, ctx, n)
             chunks.append(PromptChunk(group, ctx, n, final))
             seq.data.num_computed_tokens = ctx + n
             if final:
@@ -561,6 +582,7 @@ class Scheduler:
         deferred: List[SequenceGroup] = []
         retiring: List[SequenceGroup] = []
         reclaimed = False
+        self._release_window_pages()
         while self.running:
             seq_group = self.running.popleft()
             if self._last_token_in_flight(seq_group):
@@ -701,6 +723,23 @@ class Scheduler:
             ignored_seq_groups=ignored,
         )
 
+    def _release_window_pages(self) -> None:
+        """Before the decode rows take their slots: every running
+        sequence's window groups let go of the pages that its next
+        query, the token this round feeds, no longer sees. One sweep a
+        round, so that what it frees is there for every row and every
+        prompt of the round."""
+        manager = self.block_manager
+        if manager.sliding_window is None:
+            return
+        freed = 0
+        with self.tracer.span("cache.window_release"):
+            for group in self.running:
+                for seq in group.get_seqs(status=SequenceStatus.RUNNING):
+                    freed += manager.release_passed(
+                        seq, seq.get_len() - 1 + seq.data.in_flight)
+        self.tracer.add("cache.window_pages_freed", count=freed)
+
     def _last_token_in_flight(self, seq_group: SequenceGroup) -> bool:
         """Whether the token a dispatched step is computing for this
         row ends it by length (`AphroditeEngine._check_stop`'s two
@@ -721,11 +760,15 @@ class Scheduler:
         seq_data: Dict[int, SequenceData] = {}
         block_tables: Dict[int, List[int]] = {}
         persistent_data: Dict[int, dict] = {}
+        group_tables = None if self.block_manager.plain else {}
         for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
             seq_data[seq.seq_id] = seq.data
             block_tables[seq.seq_id] = (
                 self.block_manager.get_block_table(seq))
             persistent_data[seq.seq_id] = seq.persistent_data
+            if group_tables is not None:
+                group_tables[seq.seq_id] = \
+                    self.block_manager.get_group_tables(seq)
         return SequenceGroupMetadata(
             request_id=seq_group.request_id,
             is_prompt=is_prompt,
@@ -738,6 +781,7 @@ class Scheduler:
             computed_ctx=chunk.ctx if chunk else 0,
             chunk_len=chunk.length if chunk else None,
             is_final_chunk=chunk.is_final if chunk else True,
+            group_tables=group_tables,
         )
 
     def schedule(
@@ -979,9 +1023,8 @@ class Scheduler:
                 # ahead, so no copy-on-write can arise.
                 self.block_manager.reserve_slots(seq, seq.data.in_flight)
                 continue
-            cow = self.block_manager.append_slot(seq)
-            if cow is not None:
-                src_block, dst_block = cow
+            for src_block, dst_block in \
+                    self.block_manager.append_slots(seq):
                 blocks_to_copy.setdefault(src_block, []).append(dst_block)
 
     def _preempt(

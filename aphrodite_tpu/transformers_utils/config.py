@@ -31,10 +31,10 @@ def get_config(model: str,
     if cfg_json and _os.path.isfile(cfg_json):
         with open(cfg_json) as f:
             declared = _json.load(f).get("model_type", "").lower()
-        if declared in ("yi", "qwen"):
-            from aphrodite_tpu.transformers_utils.configs import (
-                QWenConfig, YiConfig)
-            cls = YiConfig if declared == "yi" else QWenConfig
+        if declared in ("yi", "qwen", "smallthinker"):
+            from aphrodite_tpu.transformers_utils import configs
+            cls = {"yi": configs.YiConfig, "qwen": configs.QWenConfig,
+                   "smallthinker": configs.SmallThinkerConfig}[declared]
             return cls.from_pretrained(model, revision=revision)
     try:
         config = AutoConfig.from_pretrained(

@@ -114,8 +114,11 @@ def test_ledger_covers_canonical_sites():
         baseline = json.load(f)
     sites = baseline["alloc_sites"]
     bm = "aphrodite_tpu/processing/block_manager.py::BlockSpaceManager"
-    for fn in ("allocate", "append_slot", "reserve_slots", "swap_in",
-               "swap_out"):
+    # (since PR 33 a slot's pages are taken in `_cover`, for every
+    # page group's table, and a prompt's in `_allocate_groups` where
+    # the groups are not one plain one)
+    for fn in ("allocate", "_allocate_groups", "_cover", "append_slots",
+               "swap_in", "swap_out"):
         key = f"{bm}.{fn}"
         assert key in sites, f"{key} missing from OWNERSHIP.json"
         assert sites[key]["free_seams"], f"{key} has no free seam"

@@ -14,6 +14,7 @@ import types
 import pytest
 
 from aphrodite_tpu.common import tracing
+from aphrodite_tpu.common.config import PageGroups
 from aphrodite_tpu.common.sampling_params import SamplingParams
 from aphrodite_tpu.common.sequence import (SequenceData,
                                            SequenceGroupMetadata)
@@ -92,6 +93,8 @@ def _engine_of(multi_step: int):
                                                max_model_len=256),
         model_config=types.SimpleNamespace(
             get_sliding_window=lambda: None),
+        cache_config=types.SimpleNamespace(
+            page_groups=PageGroups.of([False], None)),
         executor=types.SimpleNamespace(disagg=False),
         scheduler=types.SimpleNamespace(
             reserve_decode_burst=lambda mds, want, cap, groups: want),

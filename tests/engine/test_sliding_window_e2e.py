@@ -1,9 +1,10 @@
-"""Engine e2e sliding-window coverage where the block table actually
-WRAPS: generation runs far past the window so decode slot arithmetic
-takes the modular branch (`executor/model_runner.py` three-way cases)
-and the block manager reuses window pages — the round-2 verdict's named
-weak spot. Ground truth is HF transformers' eager Mistral (which masks
-by the same sliding window) generating greedily from identical
+"""Engine e2e sliding-window coverage where the window actually
+BINDS: generation runs far past the window, so the one page group of a
+Mistral-style model, a window group, lets go of the pages the window
+has passed while decode takes new ones (`processing/block_manager.py`,
+the same code that serves SmallThinker's window layers beside its full
+ones). Ground truth is HF transformers' eager Mistral (which masks by
+the same sliding window) generating greedily from identical
 weights."""
 import numpy as np
 import pytest
@@ -11,7 +12,7 @@ import pytest
 import torch
 
 WINDOW = 24
-BLOCK = 8          # window == 3 pages exactly -> table wraps in place
+BLOCK = 8          # window == 3 pages exactly: the table holds 3 or 4
 
 
 @pytest.fixture(scope="module")
@@ -57,7 +58,13 @@ def test_sliding_window_wrap_matches_hf(mistral_dir):
                                        max_tokens=steps,
                                        ignore_eos=True))
     got = list(out[0].outputs[0].token_ids)
-    # Block table wrapped: the sequence holds only window//BLOCK pages.
+    # The table slid: the sequence never held more than the window and
+    # a page, and every page is back on the free list.
+    manager = llm.engine.scheduler.block_manager
+    assert manager.window_pages_freed == (len(prompt) + steps - 2
+                                          - WINDOW + 1) // BLOCK
+    assert manager.get_num_free_gpu_blocks() == \
+        manager.num_total_gpu_blocks
     assert got == hf_tokens
 
 

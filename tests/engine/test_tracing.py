@@ -323,11 +323,13 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
     def spans():
         # (the round also counts events that are no spans: it was
         # dispatched ahead, its plan was the round before's, and the
-        # pages its attention copies and those that are live)
+        # pages its attention copies and those that are live, over
+        # one more decode step)
         return sum(n for name, n in engine.tracer.counts.items()
                    if name not in ("runner.ahead", "sampler.plan_reuse",
                                    "attn.pages_fetched",
-                                   "attn.pages_live"))
+                                   "attn.pages_live",
+                                   "attn.decode_steps"))
     before = spans()
     engine.step()                       # one decode round, one ahead
     spans = spans() - before

@@ -472,3 +472,124 @@ def test_paged_attention_layer_pads_small_heads():
         d ** -0.5)
     np.testing.assert_allclose(np.asarray(out_d).reshape(B, H, d),
                                np.asarray(ref), rtol=1e-4, atol=1e-5)
+
+
+# ---- keys in blocks under an online softmax ----
+
+@pytest.mark.parametrize("window,slopes", [(None, False), (9, False),
+                                           (None, True)],
+                         ids=["full", "window", "alibi"])
+@pytest.mark.parametrize("kv_len", [32, 37])
+def test_blocked_prefill_is_plain_prefill(window, slopes, kv_len):
+    """`prefill_attention_blocked` (the keys a block at a time, what a
+    step program takes from `BLOCKED_FROM` queries x keys a row on) is
+    `prefill_attention`: against the numpy oracle for a chunk behind a
+    cached prefix, a key count that is no multiple of the block, a
+    row whose valid keys end early, a window and ALiBi."""
+    from aphrodite_tpu.ops.attention import (BLOCKED_FROM,
+                                             prefill_attention_blocked)
+    assert BLOCKED_FROM > 4096 * 1024      # Mistral's 1,024-token
+    # prompts, four a step, keep the plain function
+    rng = np.random.default_rng(5)
+    b, s_new, Hq, Hkv, d = 2, 8, 4, 2, 16
+    prefix = kv_len - s_new
+    q = rng.normal(size=(b, s_new, Hq, d)).astype(np.float32)
+    k = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
+    v = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
+    ctx = np.array([prefix, prefix - 5], dtype=np.int32)
+    kv_valid = ctx + np.array([s_new, s_new - 2], dtype=np.int32)
+    alibi = np.array([0.5, 0.25, 0.125, 0.0625], np.float32) \
+        if slopes else None
+    scale = 1 / np.sqrt(d)
+    expected = numpy_prefill(q, k, v, ctx, kv_valid, scale, window=window,
+                             slopes=alibi)
+    args = (jnp.array(q), jnp.array(k), jnp.array(v), jnp.array(ctx),
+            jnp.array(kv_valid), scale)
+    kw = dict(sliding_window=window,
+              alibi_slopes=None if alibi is None else jnp.array(alibi))
+    got = np.array(prefill_attention_blocked(*args, key_block=8, **kw))
+    plain = np.array(prefill_attention(*args, **kw))
+    for bi, n in enumerate(kv_valid - ctx):
+        np.testing.assert_allclose(got[bi, :n], expected[bi, :n],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got[bi, :n], plain[bi, :n],
+                                   rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all()
+
+
+# ---- a causal window over a table that slides ----
+
+def _windowed_oracle(q, kp, vp, bt, ctx, window, scale=0.1):
+    """The oracle over the newest `window` positions of each row."""
+    out = np.zeros_like(q, dtype=np.float32)
+    for b in range(q.shape[0]):
+        first = max(0, int(ctx[b]) - window)
+        page = kp.shape[1]
+        # drop whole pages before `first`, shift the rest
+        skip = first // page
+        table = bt[b:b + 1, skip:]
+        full = numpy_paged_attention(
+            q[b:b + 1], kp, vp, table,
+            np.array([ctx[b] - skip * page]), scale)
+        if first % page == 0:
+            out[b] = full[0]
+            continue
+        # mask the passed keys of the first kept page by hand
+        keep = int(ctx[b]) - first
+        ks, vs = [], []
+        for pos in range(first, int(ctx[b])):
+            ks.append(kp[bt[b][pos // page], pos % page])
+            vs.append(vp[bt[b][pos // page], pos % page])
+        d = q.shape[2]
+        ks = np.stack(ks).reshape(keep, -1, d)
+        vs = np.stack(vs).reshape(keep, -1, d)
+        group = q.shape[1] // ks.shape[1]
+        for h in range(q.shape[1]):
+            sc = ks[:, h // group] @ q[b, h] * scale
+            p = np.exp(sc - sc.max())
+            out[b, h] = (p / p.sum()) @ vs[:, h // group]
+    return out
+
+
+@pytest.mark.parametrize("window", [8, 13, 64])
+def test_decode_under_a_window_reference_and_kernel(window):
+    """A window layer's decode: the row attends over the newest
+    `window` positions of its table (which starts at the page that
+    holds the oldest of them, or before it), in the jnp reference and
+    in the ragged Pallas kernel alike; the window is a mask, not a
+    second kernel."""
+    from aphrodite_tpu.ops.pallas.paged_attention import \
+        build_decode_work_list
+    q, kp, vp, bt, ctx = make_problem(
+        batch=4, num_q_heads=8, num_kv_heads=2, dim=128, page_size=8,
+        pages_per_seq=8, pages=64, seed=7)
+    ctx = np.array([1, 23, 40, 64], dtype=np.int32)
+    rng = np.random.default_rng(8)
+    for b in range(4):
+        bt[b] = rng.choice(64, 8, replace=False)
+    want = _windowed_oracle(q, kp, vp, bt, ctx, window)
+    got = np.asarray(paged_decode_attention_ref(
+        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
+        jnp.array(ctx), 0.1, window=window))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # the kernel is given the table from the first page it needs
+    # (`InputMetadata.for_group`): contexts count from that page
+    page = 8
+    skip = np.maximum(0, ctx - window) // page
+    slid = np.stack([np.concatenate([bt[b, skip[b]:],
+                                     np.zeros(skip[b], np.int32)])
+                     for b in range(4)]).astype(np.int32)
+    own = (ctx - skip * page).astype(np.int32)
+    work = build_decode_work_list([-(-int(c) // page) for c in own], 2)
+    out = np.asarray(paged_decode_attention(
+        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(slid),
+        jnp.array(own), scale=0.1, pages_per_chunk=2, work_items=work,
+        window=window, interpret=True))
+    np.testing.assert_allclose(out, want, rtol=1e-2, atol=1e-2)
+    if window < 64:
+        # without the mask the first kept page's passed keys count
+        wide = np.asarray(paged_decode_attention(
+            jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(slid),
+            jnp.array(own), scale=0.1, pages_per_chunk=2,
+            work_items=work, interpret=True))
+        assert np.abs(wide - want).max() > 0.05

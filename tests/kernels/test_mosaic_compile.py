@@ -131,6 +131,69 @@ def test_decode_attention_compiles(on_v5e, B, Hq, Hkv, pps, dtype,
         on_v5e((B,), I32), new, new).compile()
 
 
+#: SmallThinker's decode programs (`smallthinker-21ba3b-bf16.batch-8k`):
+#: 28 query heads over 4 KV heads of 128 (seven queries a KV head, one
+#: head block of 512 lanes), pages of 16, 24 rows. (table width, window,
+#: fused write): the full group's table at 8,192-8,960 tokens (576 wide,
+#: 513-560 pages held), the canary's one row at 512, and a window
+#: group's at 320 (257-258 pages held under a window of 4,096), with
+#: the window's mask in the kernel; the widths a window group's table
+#: has while a prompt is written (385 pages at most) are prefill's.
+GROUP_CASES = [
+    (24, 576, None, True), (1, 512, None, True), (24, 320, 4096, True),
+    (24, 320, 4096, False), (24, 448, 4096, True),
+]
+
+
+@pytest.mark.parametrize("B,pps,window,fused", GROUP_CASES)
+def test_decode_attention_compiles_at_smallthinker_shapes(
+        on_v5e, B, pps, window, fused):
+    """The same kernel as above, called once a layer with its page
+    group's table: 4 KV heads, tables five times as wide, and for a
+    window layer the mask over the newest 4,096 keys."""
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
+        padded_work_length, paged_decode_attention)
+    Hq, Hkv, d, page = 28, 4, 128, 16
+    ppc = choose_pages_per_chunk(pps, page, lane_bytes_of(Hkv, d, BF16))
+    assert ppc == 32
+    held = 258 if window else pps - 16
+    counts = [held - i % 2 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    # a pool of 4.5 GB in three pairs of page arrays
+    pages = on_v5e((46000, page, Hkv * d), BF16)
+    new = on_v5e((B, Hkv, d), BF16)
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn if fused else None,
+            vn if fused else None, scale=d ** -0.5,
+            pages_per_chunk=ppc, work_items=work, amla=True,
+            window=window)
+
+    jax.jit(attend, donate_argnums=(1, 2) if fused else ()).lower(
+        on_v5e((B, Hq, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
+def test_kv_writer_compiles_at_smallthinker_shapes(on_v5e):
+    """The prefill page writer for a chunk of 2,048 tokens into pages
+    of 4 KV heads x 128 lanes (128 cells), once a layer with its
+    group's cells."""
+    from aphrodite_tpu.ops.pallas.kv_write import (can_use_pallas_writer,
+                                                   write_kv_pages_prefill)
+    page, hd = 16, 4 * 128
+    assert can_use_pallas_writer(BF16, page, hd)
+    pages = on_v5e((46000, page, hd), BF16)
+    cells = 2048 // page
+    chunk = on_v5e((cells * page, hd), BF16)
+    ids = on_v5e((cells,), I32)
+    jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
+        chunk, chunk, pages, pages, ids, ids, ids).compile()
+
+
 def test_kv_writers_compile(on_v5e):
     from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,
                                                    write_kv_pages_prefill)

@@ -495,11 +495,11 @@ def _routing_layer(sliding_window):
     return layer
 
 
-def test_sliding_window_routes_to_slot_mapped_writer():
-    """Sliding-window models write to a rotating ring slot; the fused
-    kernel derives the write position as ctx-1 — routing them to the
-    fused path would silently write the wrong page. They MUST take the
-    slot-mapped writer."""
+def test_sliding_window_routes_to_the_fused_write_too():
+    """A window layer's table slides (its page group lets whole pages
+    go and counts from the first it keeps); it does not wrap. So the
+    write position is ctx-1 of the table the layer is given, as for
+    any other layer, and the fused in-kernel write serves it."""
     from aphrodite_tpu.modeling.input_metadata import InputMetadata
     meta = InputMetadata(
         slot_mapping=jnp.zeros((2,), jnp.int32),
@@ -508,7 +508,7 @@ def test_sliding_window_routes_to_slot_mapped_writer():
         is_prompt=False)
     pages = jnp.zeros((4, 8, 2 * 128), jnp.bfloat16)
     assert _routing_layer(None)._fused_decode_ok(pages, meta)
-    assert not _routing_layer(1024)._fused_decode_ok(pages, meta)
+    assert _routing_layer(1024)._fused_decode_ok(pages, meta)
     # Prompt steps and cache-less profiling runs never fuse either.
     assert not _routing_layer(None)._fused_decode_ok(
         pages, meta.replace(is_prompt=True))
@@ -564,6 +564,7 @@ def test_model_runner_builds_consistent_work_list():
     from aphrodite_tpu.common.sampling_params import SamplingParams
     from aphrodite_tpu.common.sequence import (SequenceData,
                                                SequenceGroupMetadata)
+    from aphrodite_tpu.common.config import PageGroups
     from aphrodite_tpu.common.tracing import Tracer
     from aphrodite_tpu.executor.model_runner import ModelRunner
 
@@ -577,9 +578,9 @@ def test_model_runner_builds_consistent_work_list():
     runner._input_sharding = None      # single-device placement plan
     runner._results_committed = False  # weights made by a program
     runner._tp = 1
-    runner._decode_work = (None, None)
-    runner.model_config = SimpleNamespace(
-        get_sliding_window=lambda: None)
+    runner._decode_work = {}
+    runner.page_groups = PageGroups.of([False], None)
+    runner.step_counters = ()
 
     sp = SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True)
     mds = []
@@ -602,7 +603,7 @@ def test_model_runner_builds_consistent_work_list():
     ppc = meta.decode_ppc
     # The batch rides as one array in the place of its block tables;
     # the program slices it.
-    _, _, meta = ModelRunner._unpacked(None, None, meta)
+    _, _, meta = ModelRunner._unpacked(runner, None, None, meta)
     assert meta.block_tables.shape == (4, 40)
     assert np.asarray(meta.context_lens).tolist() == [3, 40, 600, 0]
     assert np.asarray(meta.slot_mapping).tolist() == [

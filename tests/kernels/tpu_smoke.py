@@ -178,6 +178,68 @@ def main() -> int:
             check(tag5, ref5[mask5], got5[mask5])
             check(tag5 + ", the pad row reads 0", 0.0, got5[~mask5])
 
+    with section("decode attention (SmallThinker: 4 KV heads, page groups)"):
+        # -- the decode kernel as `smallthinker-21ba3b-bf16.batch-8k`
+        #    calls it, once a layer with its page group's table: 28
+        #    query heads over 4 KV heads of 128, pages of 16, 24 rows,
+        #    the fused write. A full group's table 576 wide at contexts
+        #    of 8,193-8,960 (513-560 pages); a window group's 320 wide
+        #    holding 257-258 pages, the window of 4,096 as a mask (the
+        #    first page kept is partly passed). NaN in every page no
+        #    row holds. --
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list, choose_pages_per_chunk, lane_bytes_of)
+        hq6, hkv6, rows6 = 28, 4, 24
+        ppc6 = choose_pages_per_chunk(576, 16,
+                                      lane_bytes_of(hkv6, d, jnp.bfloat16))
+        for tag6, width6, window6 in (("full", 576, None),
+                                      ("window", 320, 4096)):
+            whole = rs.randint(8193, 8961, (rows6,)).astype(np.int32)
+            whole[:2] = (8193, 8960)
+            # a window group's table starts at the page that holds the
+            # oldest key of the window; its context counts from there
+            let_go = np.maximum(0, whole - 1 - window6 + 1) // 16 * 16 \
+                if window6 else np.zeros_like(whole)
+            ctx6 = whole - let_go
+            cnt6 = -(-ctx6 // 16)
+            tbl6 = np.zeros((rows6, width6), np.int32)
+            used6 = 1
+            for i, n in enumerate(cnt6):
+                tbl6[i, :n] = np.arange(used6, used6 + n)
+                used6 += n
+            raw6 = rs.randn(used6 + 4, 16, hkv6 * d) * 0.1
+            kv6 = [jnp.asarray(raw6, jnp.bfloat16),
+                   jnp.asarray(raw6[::-1], jnp.bfloat16)]
+            q6 = jnp.asarray(rs.randn(rows6, hq6, d) * 0.1, jnp.bfloat16)
+            new6 = [jnp.asarray(rs.randn(rows6, hkv6, d) * 0.1,
+                                jnp.bfloat16) for _ in range(2)]
+            # the reference after the slot-mapped write of the new token
+            slots6 = jnp.asarray(
+                tbl6[np.arange(rows6), (ctx6 - 1) // 16] * 16 +
+                (ctx6 - 1) % 16)
+            from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+            wk6, wv6 = write_to_kv_cache(new6[0], new6[1], kv6[0], kv6[1],
+                                         slots6)
+            ref6 = np.asarray(paged_decode_attention_ref(
+                q6, wk6, wv6, jnp.asarray(tbl6), jnp.asarray(ctx6), scale,
+                window=window6), np.float32)
+            dead6 = np.ones(used6 + 4, bool)
+            dead6[1:used6] = False
+            kv6 = [x.at[jnp.asarray(np.flatnonzero(dead6))].set(jnp.nan)
+                   for x in kv6]
+            got6, gk6, gv6 = paged_decode_attention(
+                q6, kv6[0], kv6[1], jnp.asarray(tbl6), jnp.asarray(ctx6),
+                None, new6[0], new6[1], scale=scale,
+                pages_per_chunk=ppc6,
+                work_items=build_decode_work_list(cnt6, ppc6),
+                window=window6)
+            check(f"{tag6} group, table {width6}, ppc={ppc6}", ref6,
+                  np.asarray(got6, np.float32))
+            live6 = ~dead6
+            check(f"{tag6} group, the fused write's pages",
+                  np.asarray(wk6, np.float32)[live6],
+                  np.asarray(gk6, np.float32)[live6], tol=1e-6)
+
     with section("decode attention (padded heads)"):
         # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --
         for d_true in (64, 80):
@@ -291,6 +353,24 @@ def main() -> int:
         print(f"prefill page writer: max err {errw:.2e}")
         if not (errw < 1e-6):
             failures.append(("prefill_writer", errw))
+
+    with section("prefill page writer (SmallThinker chunk)"):
+        # -- a chunk of 2,048 tokens into pages of 4 KV heads x 128
+        #    lanes: 128 whole-page cells --
+        shd, scells = 4 * 128, 2048 // 16
+        spool = jnp.zeros((scells + 8, 16, shd), jnp.bfloat16)
+        srows = jnp.asarray(rs.randn(2048, shd) * 0.1, jnp.bfloat16)
+        spid = rs.permutation(scells + 8)[:scells].astype(np.int32)
+        sk, _ = write_kv_pages_prefill(
+            srows, srows, spool, spool, jnp.asarray(spid),
+            jnp.asarray(np.arange(scells, dtype=np.int32)),
+            jnp.full((scells,), 16, jnp.int32))
+        errs = np.abs(np.asarray(sk, np.float32)[spid].reshape(2048, shd)
+                      - np.asarray(srows, np.float32)).max()
+        print(f"prefill page writer, 128 cells of 512 lanes: "
+              f"max err {errs:.2e}")
+        if not (errs < 1e-6):
+            failures.append(("prefill_writer_smallthinker", errs))
 
     with section("decode page writer"):
         # decode pipelined writer on-chip

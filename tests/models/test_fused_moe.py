@@ -86,3 +86,88 @@ def test_loader_marks_sharded(tmp_path):
     m = Model()
     _mark_moe_sharded(m)
     assert all(b.moe.sharded for b in m.layers)
+
+
+# ---- the gate's activation and a router of the caller's ----
+
+def _plain_moe(x, logits, params, top_k, act):
+    """`sum_k w_k W_down,k (act(W_gate,k m) * W_up,k m)` over the
+    `top_k` largest logits, `w` the softmax over those logits alone:
+    a loop over tokens and experts, in float64."""
+    x, logits = np.asarray(x, np.float64), np.asarray(logits, np.float64)
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        top = np.argsort(-logits[t])[:top_k]
+        w = np.exp(logits[t, top] - logits[t, top].max())
+        w /= w.sum()
+        for e, we in zip(top, w):
+            gate = x[t] @ np.asarray(params["w_gate"][e], np.float64)
+            up = x[t] @ np.asarray(params["w_up"][e], np.float64)
+            out[t] += we * ((act(gate) * up) @
+                            np.asarray(params["w_down"][e], np.float64))
+    return out
+
+
+_ACTS = {"relu": lambda g: np.maximum(g, 0.0),
+         "silu": lambda g: g / (1.0 + np.exp(-g))}
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+@pytest.mark.parametrize("num_experts,top_k,tokens", [
+    (64, 6, 19),        # SmallThinker's routing: ragged path
+    (4, 2, 5),          # the dense combine
+])
+def test_gate_activation_and_the_callers_router(activation, num_experts,
+                                                top_k, tokens):
+    """ReGLU or SwiGLU experts under router logits the caller computed
+    from another tensor than the experts' input; the softmax over all
+    experts renormalised over the top k is the softmax over the top
+    k logits alone. A layer without its own router holds no `gate`."""
+    moe, params = make_moe(num_experts, top_k)
+    routed = FusedMoE(num_experts, top_k, 32, 48, activation=activation,
+                      own_router=False, dtype=jnp.float32)
+    assert sorted(routed.init()) == ["w_down", "w_gate", "w_up"]
+    assert sorted(routed.specs()) == ["w_down", "w_gate", "w_up"]
+    assert sorted(moe.init()) == ["gate", "w_down", "w_gate", "w_up"]
+    experts = {k: v for k, v in params.items() if k != "gate"}
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(tokens, 32) * 0.5, jnp.float32)
+    elsewhere = jnp.asarray(rs.randn(tokens, num_experts) * 2.0,
+                            jnp.float32)
+    counts = []
+    out = np.asarray(routed(experts, x, router_logits=elsewhere,
+                            counts=counts))
+    want = _plain_moe(x, elsewhere, experts, top_k, _ACTS[activation])
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+    # counted in the program: pairs routed and experts with a pair
+    (pairs, touched), = counts
+    top = np.argsort(-np.asarray(elsewhere), axis=-1)[:, :top_k]
+    assert int(pairs) == tokens * top_k
+    assert int(touched) == len(np.unique(top))
+    # the other activation, or the layer's own input as the router's,
+    # is another function
+    other = "silu" if activation == "relu" else "relu"
+    wrong = FusedMoE(num_experts, top_k, 32, 48, activation=other,
+                     own_router=False, dtype=jnp.float32)
+    assert np.abs(np.asarray(wrong(experts, x, router_logits=elsewhere))
+                  - want).max() > 1e-3
+    assert np.abs(np.asarray(moe(params, x)) - want).max() > 1e-3
+
+
+def test_mixtral_routing_is_what_it_was():
+    """The defaults are Mixtral's: SiLU, the layer's own router over
+    its own input. The same numbers with and without the new
+    arguments spelled out, and an unknown activation is refused."""
+    moe, params = make_moe(8, 2)
+    rs = np.random.RandomState(4)
+    x = jnp.asarray(rs.randn(9, 32) * 0.5, jnp.float32)
+    spelled = FusedMoE(8, 2, 32, 48, activation="silu", own_router=True,
+                       dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(moe(params, x)),
+                                  np.asarray(spelled(params, x)))
+    logits = np.asarray(x) @ np.asarray(params["gate"])
+    want = _plain_moe(x, logits, params, 2, _ACTS["silu"])
+    np.testing.assert_allclose(np.asarray(moe(params, x)), want,
+                               rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="gelu"):
+        FusedMoE(8, 2, 32, 48, activation="gelu")

@@ -66,13 +66,13 @@ def test_allocate_and_append_slot():
 
     # Append within last block: no new allocation.
     seq.append_token_id(100, {100: 0.0})  # len 7, fits block 2
-    assert mgr.append_slot(seq) is None
+    assert mgr.append_slots(seq) == []
     assert mgr.get_num_free_gpu_blocks() == 8
     # Cross the block boundary: new block allocated.
     seq.append_token_id(101, {101: 0.0})  # len 8 -> still 2 blocks
-    assert mgr.append_slot(seq) is None
+    assert mgr.append_slots(seq) == []
     seq.append_token_id(102, {102: 0.0})  # len 9 -> 3 blocks
-    assert mgr.append_slot(seq) is None
+    assert mgr.append_slots(seq) == []
     assert mgr.get_num_free_gpu_blocks() == 7
 
 
@@ -87,33 +87,45 @@ def test_copy_on_write_fork():
     mgr.fork(parent, child)
     # Both tables share blocks; last block is shared => CoW on append.
     parent.append_token_id(7, {7: 0.0})
-    cow = mgr.append_slot(parent)
-    assert cow is not None
-    src, dst = cow
+    ((src, dst),) = mgr.append_slots(parent)
     assert src != dst
     # Child keeps the old block; appending to child now hits ref_count 1.
     child.append_token_id(8, {8: 0.0})
-    assert mgr.append_slot(child) is None
+    assert mgr.append_slots(child) == []
 
 
-def test_sliding_window_reuse():
+def test_sliding_window_table_slides():
+    """A model-wide window is one page group, a window group: its
+    table slides. A prompt that comes whole takes its pages whole (the
+    chunk being written is the prompt); from then on the table lets go
+    of every page that lies wholly before the window of the next
+    query, so it never holds more than the window and a page."""
     mgr = BlockSpaceManager(BLOCK_SIZE,
                             10,
                             10,
                             watermark=0,
                             sliding_window=8)  # 2 blocks
+    assert mgr.group_kinds == ("window",) and not mgr.plain
     group = make_group(prompt_len=16)  # 4 logical blocks
     assert mgr.can_allocate(group) == AllocStatus.OK
     mgr.allocate(group)
     seq = group.get_seqs()[0]
     seq.status = SequenceStatus.RUNNING
-    # Only window-worth of physical blocks were consumed.
-    assert mgr.get_num_free_gpu_blocks() == 8
-    # Appending past the window reuses blocks, never allocating.
+    assert mgr.get_num_free_gpu_blocks() == 6
+    # Appending past the window: what the table lets go of covers what
+    # it takes, and it holds the window and a page at most.
     for tok in range(16, 32):
         seq.append_token_id(tok, {tok: 0.0})
-        mgr.append_slot(seq)
-    assert mgr.get_num_free_gpu_blocks() == 8
+        assert mgr.append_slots(seq) == []
+        let_go, table = mgr.get_group_tables(seq)[0]
+        pos = seq.get_len() - 1
+        assert let_go == max(0, pos - 8 + 1) // BLOCK_SIZE * BLOCK_SIZE
+        assert len(table) == pos // BLOCK_SIZE + 1 - let_go // BLOCK_SIZE
+        assert len(table) <= 8 // BLOCK_SIZE + 1
+    assert mgr.get_num_free_gpu_blocks() == 10 - 2
+    assert mgr.window_pages_freed == 6
+    mgr.free(seq)
+    assert mgr.get_num_free_gpu_blocks() == 10
 
 
 def test_swap_roundtrip():
@@ -137,42 +149,39 @@ def test_swap_roundtrip():
     assert mgr.get_num_free_gpu_blocks() == 10
 
 
-def test_sliding_window_reuse_does_not_clobber_prefix_pin():
-    """Regression (the LEAK002 clobber shape): when window reuse and
-    prefix sharing coincide, the reused in-window slot aliases a
-    PREFIX block — the old unconditional `ref_count = num_seqs`
-    overwrote the pin + sharers and a later free double-freed. The
-    reuse path must leave the count alone (each unique block already
-    carries one ref per owner)."""
+def test_a_window_model_refuses_the_prefix_cache_and_swap():
+    """A cached prefix pins pages that a window group would let go of,
+    and a swapped table has no host copy of what slid away: a model
+    with a window group refuses both with a stated error rather than
+    be half-right (the wrapped table of the old window code aliased a
+    prefix block and clobbered its pin)."""
+    from aphrodite_tpu.processing.block_manager import \
+        PageGroupsUnsupported
     mgr = BlockSpaceManager(BLOCK_SIZE, 10, 10, watermark=0,
                             sliding_window=8)   # 2-block window
     prefix = Prefix(list(range(BLOCK_SIZE)), BLOCK_SIZE)  # 1 block
     g1 = make_group(20, request_id="g1", prefix=prefix)   # 5 blocks
-    mgr.allocate(g1)
-    assert prefix.allocated
-    prefix.computed = True
-    pinned = prefix.block_table[0]
-    # pin (1) + g1's share (1)
-    assert pinned.ref_count == 2
-
-    g2 = make_group(20, request_id="g2", prefix=prefix)
-    mgr.allocate(g2)
-    # pin + g1 + g2 — the window wrapping onto the prefix block must
-    # not have reset this to 1 (the old bug)
-    assert pinned.ref_count == 3
-
-    for g in (g1, g2):
-        for seq in g.get_seqs():
-            mgr.free(seq)
-    # only the pin holds one page now
-    assert pinned.ref_count == 1
-    assert mgr.get_num_free_gpu_blocks() == 9
-    # releasing the pin through the owner's free seam drains it fully
-    assert mgr.free_prefix(prefix) == 1
-    assert not prefix.allocated and not prefix.computed
+    with pytest.raises(PageGroupsUnsupported, match="prefix cache"):
+        mgr.allocate(g1)
+    # nothing was taken, nothing pinned
+    assert not prefix.allocated
     assert mgr.get_num_free_gpu_blocks() == 10
-    # idempotent: a reset prefix releases nothing more
-    assert mgr.free_prefix(prefix) == 0
+    g2 = make_group(20, request_id="g2")
+    mgr.allocate(g2)
+    for seq in g2.get_seqs():
+        seq.status = SequenceStatus.RUNNING
+    with pytest.raises(PageGroupsUnsupported, match="swap"):
+        mgr.can_swap_out(g2)
+    with pytest.raises(PageGroupsUnsupported, match="swap"):
+        mgr.can_swap_in(g2)
+    for seq in g2.get_seqs():
+        mgr.free(seq)
+    assert mgr.get_num_free_gpu_blocks() == 10
+    # a model without a window serves both as ever
+    plain = BlockSpaceManager(BLOCK_SIZE, 10, 10, watermark=0)
+    g3 = make_group(20, request_id="g3", prefix=prefix)
+    plain.allocate(g3)
+    assert prefix.allocated and plain.can_swap_out(g3)
 
 
 def test_prefix_pool_accounting_and_clear():
