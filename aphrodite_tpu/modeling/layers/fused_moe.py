@@ -14,15 +14,22 @@ a dense masked combine:
     out = sum_e weight_e(token) * FFN_e(token)
 
 computed as batched einsum over all experts — but ONLY when experts are
-few (<= 4) or sharded over a mesh. Above that, tokens sort by assigned
-expert and run GROUPED matmuls via `jax.lax.ragged_dot` (the TPU-native
-equivalent of the reference's moe_align_block_size + fused expert GEMM:
-sorting IS the alignment, the ragged group sizes ARE the block
-boundaries), costing top_k/E of the dense path's FLOPs — 4x fewer for
-Mixtral's top-2-of-8 — with no capacity dropping. The dense combine
-remains the mesh path: expert-axis sharding composes with it through
-plain GSPMD annotations, whereas a sharded ragged dispatch needs an
-all-to-all token exchange (future work).
+few (<= 4) or sharded over a mesh. Above that, the (token, slot) pairs
+sort by assigned expert and run GROUPED matmuls via `jax.lax.ragged_dot`
+(the TPU-native equivalent of the reference's moe_align_block_size +
+fused expert GEMM: sorting IS the alignment, the ragged group sizes ARE
+the block boundaries), costing top_k/E of the dense path's FLOPs — 4x
+fewer for Mixtral's top-2-of-8 — with no capacity dropping. Pairs reach
+the matmuls and return to their tokens by a permutation and its inverse,
+two gathers: every token has exactly top_k pairs, so the sorted rows go
+back into a [T, top_k, H] block that is summed over top_k under the
+routing weights. Group sizes are a comparison against the expert ids,
+summed. Nothing in the grouped path scatters: XLA runs a row scatter on
+the TPU one update after another (77 ns a pair at 64 experts, top 6 and
+2,048 tokens, PERF.md §6 PR 34). The dense combine remains the mesh
+path: expert-axis sharding composes with it through plain GSPMD
+annotations, whereas a sharded ragged dispatch needs an all-to-all
+token exchange (future work).
 """
 from __future__ import annotations
 
@@ -121,13 +128,20 @@ class FusedMoE:
         probs, top_vals, top_idx = self.route(
             router_logits.reshape(-1, self.num_experts).astype(
                 jnp.float32))
+        ragged = self.num_experts > 4 and not sharded
+        if ragged or counts is not None:
+            # Pairs an expert: each pair's expert compared with every
+            # expert id, summed over the pairs.
+            group_sizes = jnp.sum(
+                top_idx.reshape(-1, 1) == jnp.arange(self.num_experts),
+                axis=0, dtype=jnp.int32)                  # [E]
         if counts is not None:
-            touched = jnp.zeros((self.num_experts,), jnp.int32).at[
-                top_idx.reshape(-1)].set(1)
-            counts.append((jnp.int32(top_idx.size), jnp.sum(touched)))
+            counts.append((jnp.int32(top_idx.size),
+                           jnp.sum(group_sizes > 0, dtype=jnp.int32)))
 
-        if self.num_experts > 4 and not sharded:
-            out = self._ragged_ffn(params, x, top_vals, top_idx)
+        if ragged:
+            out = self._ragged_ffn(params, x, top_vals, top_idx,
+                                   group_sizes)
         else:
             out = self._dense_ffn(params, x, probs, top_vals, top_idx)
         return out.reshape(orig_shape).astype(hidden.dtype)
@@ -146,22 +160,26 @@ class FusedMoE:
         return jnp.einsum("eth,te->th", expert_out,
                           combine.astype(expert_out.dtype))
 
-    def _ragged_ffn(self, params, x, top_vals, top_idx):
+    def _ragged_ffn(self, params, x, top_vals, top_idx, group_sizes):
         """Grouped-GEMM dispatch: (token, slot) pairs sort by expert,
-        each expert's contiguous token group multiplies its own weights
-        (`jax.lax.ragged_dot`), and outputs scatter-add back — the
-        moe_align + fused-GEMM design, with the sort as the alignment."""
+        each expert's contiguous group of rows multiplies its own
+        weights (`jax.lax.ragged_dot`), and the rows return to their
+        pairs' places by the inverse permutation, where a token's
+        `top_k` rows are summed under its routing weights in float32 —
+        the moe_align + fused-GEMM design, with the sort as the
+        alignment and no scatter on either side."""
         T = x.shape[0]
         k = self.top_k
-        pair_expert = top_idx.reshape(-1)                 # [T*k]
-        pair_token = jnp.repeat(jnp.arange(T), k)
-        pair_w = top_vals.reshape(-1)
-        order = jnp.argsort(pair_expert)
-        tok_sorted = pair_token[order]
-        x_sorted = jnp.take(x, tok_sorted, axis=0)        # [T*k, H]
-        group_sizes = jnp.bincount(pair_expert,
-                                   length=self.num_experts
-                                   ).astype(jnp.int32)
+        # Pairs in slot-major order: pair p is token p % T in slot
+        # p // T, so the rows that come back split into [k, T, H] on the
+        # leading axis (a [T, k, H] block would pad k to the TPU's
+        # 8-row tile and copy itself into that layout). `order[i]` is
+        # the pair in sorted row i; `dest[p]` is the sorted row of
+        # pair p.
+        order = jnp.argsort(top_idx.T.reshape(-1))        # [k*T]
+        dest = jnp.argsort(order)
+        x_sorted = x.at[order % T].get(
+            mode="promise_in_bounds")                     # [k*T, H]
 
         gate = jax.lax.ragged_dot(x_sorted, params["w_gate"],
                                   group_sizes)
@@ -170,10 +188,16 @@ class FusedMoE:
                up.astype(jnp.float32)).astype(x.dtype)
         down = jax.lax.ragged_dot(act, params["w_down"], group_sizes)
 
-        weighted = down.astype(jnp.float32) * \
-            pair_w[order].astype(jnp.float32)[:, None]
+        # A slot's T rows come back by one gather; the k slots are
+        # weighted and added in float32 with no [k*T, H] float32 array
+        # in between.
+        weights = top_vals.astype(jnp.float32)
         out = jnp.zeros((T, self.hidden_size), jnp.float32)
-        return out.at[tok_sorted].add(weighted)
+        for slot in range(k):
+            rows = down.at[dest[slot * T:(slot + 1) * T]].get(
+                unique_indices=True, mode="promise_in_bounds")
+            out += rows.astype(jnp.float32) * weights[:, slot:slot + 1]
+        return out
 
     # -- host-side weight placement --
 

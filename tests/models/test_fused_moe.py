@@ -6,6 +6,7 @@ combine exactly, and the dense path stays for sharded/small configs."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from aphrodite_tpu.modeling.layers.fused_moe import FusedMoE
@@ -28,21 +29,42 @@ def make_moe(num_experts, top_k, hidden=32, inter=48, seed=0):
     return moe, params
 
 
-@pytest.mark.parametrize("num_experts,top_k,tokens", [
-    (8, 2, 17),        # Mixtral shape: ragged path engages
-    (8, 2, 1),         # single token
-    (16, 4, 33),       # Deepseek-ish
+def _routed(logits, routing):
+    """Router logits under a routing: as drawn (`random`), with every
+    fourth expert out of reach (`idle`: experts without a pair), or
+    every token with the first token's (`same`: a few experts hold all
+    the pairs)."""
+    logits = np.array(logits, np.float32)
+    if routing == "idle":
+        logits[:, ::4] -= 30.0
+    elif routing == "same":
+        logits[:] = logits[0]
+    return jnp.asarray(logits)
+
+
+@pytest.mark.parametrize("num_experts,top_k,tokens,routing", [
+    (8, 2, 17, "own"),     # Mixtral shape: ragged path engages
+    (8, 2, 1, "own"),      # single token
+    (16, 4, 33, "own"),    # Deepseek-ish
+    (64, 6, 512, "own"),   # a prompt chunk's routing, SmallThinker's
+    (64, 6, 40, "idle"),   # some experts get no pair
+    (16, 4, 33, "same"),   # every token picks the same experts
+    (8, 1, 9, "own"),      # one expert a token
+    (8, 1, 9, "same"),     # one group holds every pair
 ])
-def test_ragged_matches_dense(num_experts, top_k, tokens):
+def test_ragged_matches_dense(num_experts, top_k, tokens, routing):
     moe, params = make_moe(num_experts, top_k)
     rs = np.random.RandomState(1)
     x = jnp.asarray(rs.randn(tokens, 32) * 0.5, jnp.float32)
+    logits = None if routing == "own" else _routed(
+        np.asarray(x) @ np.asarray(params["gate"]), routing)
 
     assert not moe.sharded
-    ragged = np.asarray(moe(params, x))        # default: ragged (E > 4)
+    ragged = np.asarray(moe(params, x, router_logits=logits))  # E > 4
     moe.sharded = True
-    dense = np.asarray(moe(params, x))         # forced dense combine
+    dense = np.asarray(moe(params, x, router_logits=logits))   # forced
     np.testing.assert_allclose(ragged, dense, rtol=2e-5, atol=2e-5)
+    assert np.abs(dense).max() > 1e-2
 
 
 def test_small_expert_count_uses_dense():
@@ -113,12 +135,16 @@ _ACTS = {"relu": lambda g: np.maximum(g, 0.0),
 
 
 @pytest.mark.parametrize("activation", ["relu", "silu"])
-@pytest.mark.parametrize("num_experts,top_k,tokens", [
-    (64, 6, 19),        # SmallThinker's routing: ragged path
-    (4, 2, 5),          # the dense combine
+@pytest.mark.parametrize("num_experts,top_k,tokens,routing", [
+    (64, 6, 19, "random"),      # SmallThinker's routing: ragged path
+    (4, 2, 5, "random"),        # the dense combine
+    (64, 6, 512, "random"),     # a prompt chunk: 3,072 pairs
+    (64, 6, 40, "idle"),        # experts without a pair
+    (16, 4, 33, "same"),        # every token picks the same experts
+    (8, 1, 9, "random"),        # one expert a token
 ])
 def test_gate_activation_and_the_callers_router(activation, num_experts,
-                                                top_k, tokens):
+                                                top_k, tokens, routing):
     """ReGLU or SwiGLU experts under router logits the caller computed
     from another tensor than the experts' input; the softmax over all
     experts renormalised over the top k is the softmax over the top
@@ -132,8 +158,7 @@ def test_gate_activation_and_the_callers_router(activation, num_experts,
     experts = {k: v for k, v in params.items() if k != "gate"}
     rs = np.random.RandomState(3)
     x = jnp.asarray(rs.randn(tokens, 32) * 0.5, jnp.float32)
-    elsewhere = jnp.asarray(rs.randn(tokens, num_experts) * 2.0,
-                            jnp.float32)
+    elsewhere = _routed(rs.randn(tokens, num_experts) * 2.0, routing)
     counts = []
     out = np.asarray(routed(experts, x, router_logits=elsewhere,
                             counts=counts))
@@ -152,6 +177,78 @@ def test_gate_activation_and_the_callers_router(activation, num_experts,
     assert np.abs(np.asarray(wrong(experts, x, router_logits=elsewhere))
                   - want).max() > 1e-3
     assert np.abs(np.asarray(moe(params, x)) - want).max() > 1e-3
+
+
+def _lowered(moe, tokens, counts):
+    """StableHLO of one call of the layer, `counts` asked for or not."""
+    def layer(params, x, logits):
+        got = [] if counts else None
+        out = moe(params, x, counts=got,
+                  router_logits=None if moe.own_router else logits)
+        return out, got
+    shapes = (jax.eval_shape(moe.init),
+              jax.ShapeDtypeStruct((tokens, moe.hidden_size), moe.dtype),
+              jax.ShapeDtypeStruct((tokens, moe.num_experts), jnp.float32))
+    # for the TPU, where `ragged_dot` stays one operation
+    return jax.jit(layer).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("num_experts,top_k,tokens,own_router", [
+    (64, 6, 2048, False),       # a chunk of the benchmark's cell
+    (64, 6, 24, False),         # its decode step
+    (8, 2, 17, True),           # Mixtral
+])
+def test_the_grouped_path_lowers_without_a_scatter(num_experts, top_k,
+                                                   tokens, own_router):
+    """What holds the mechanism on the CPU: pairs reach the grouped
+    matmuls and come back by two gathers, group sizes and the touched
+    count by a comparison. XLA runs a row scatter on the TPU one update
+    after another (PERF.md §6, PR 34), so none may come back: not the
+    combine, not a `bincount`, not an index update under either."""
+    moe = FusedMoE(num_experts, top_k, 64, 32, activation="relu",
+                   own_router=own_router)
+    for counts in (True, False):
+        text = _lowered(moe, tokens, counts)
+        assert "scatter" not in text     # `stablehlo.scatter` or any
+        assert text.count('"chlo.ragged_dot"(') == 3
+        # the pairs' rows out, and a slot's rows back
+        assert text.count('"stablehlo.gather"(') == 1 + top_k
+    # the dense combine, the mesh path, is another matter and keeps its
+    # scatter of the routing weights
+    moe.sharded = True
+    assert "stablehlo.scatter" in _lowered(moe, tokens, True)
+
+
+@pytest.mark.parametrize("num_experts,top_k,tokens,routing", [
+    (64, 6, 24, "random"),      # a decode step: not every expert
+    (64, 6, 512, "random"),     # a chunk: every expert
+    (64, 6, 40, "idle"),
+    (16, 4, 33, "same"),        # four experts hold every pair
+    (4, 2, 5, "random"),        # the dense combine counts too
+])
+def test_counts_are_what_numpy_counts(num_experts, top_k, tokens, routing):
+    """`counts` gains the call's token-expert pairs and the experts
+    with a pair, as int32 scalars, on either path."""
+    moe, params = make_moe(num_experts, top_k)
+    rs = np.random.RandomState(5)
+    x = jnp.asarray(rs.randn(3, tokens // 3 + 1, 32)[:, :tokens] * 0.5,
+                    jnp.float32)                # a leading batch axis
+    logits = _routed(rs.randn(x.shape[0] * x.shape[1], num_experts)
+                     * 2.0, routing)
+    counts = []
+    out = moe(params, x, router_logits=logits.reshape(
+        x.shape[:2] + (num_experts,)), counts=counts)
+    assert out.shape == x.shape
+    (pairs, touched), = counts
+    top = np.argsort(-np.asarray(logits), axis=-1)[:, :top_k]
+    assert pairs.dtype == touched.dtype == jnp.int32
+    assert int(pairs) == top.size == x.shape[0] * x.shape[1] * top_k
+    assert int(touched) == len(np.unique(top))
+    if routing == "same":
+        assert int(touched) == top_k
+    if routing == "idle":
+        assert int(touched) <= num_experts - num_experts // 4
 
 
 def test_mixtral_routing_is_what_it_was():
