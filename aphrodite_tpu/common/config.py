@@ -14,6 +14,7 @@ LoRAConfig). TPU-first differences:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import List, Optional, Tuple, Union
@@ -36,13 +37,27 @@ _STR_DTYPE_TO_JAX = {
 }
 
 
+_DTYPE_BYTES = {"float16": 2, "bfloat16": 2, "float32": 4}
+
+
 @dataclasses.dataclass(frozen=True)
 class PageGroups:
     """Which KV page group each layer's attention belongs to.
 
-    A layer is `full` (it needs every key of its sequence) or `window`
-    (only the newest `window` keys). Layers of one kind are dealt, in
-    order, into groups of `layers_per_group` = gcd(full layers, window
+    A layer is one of four kinds:
+
+    - `full`: it writes K and V and needs every key of its sequence;
+    - `window`: it writes K and V and needs only the newest `window`
+      keys;
+    - it **holds nothing** (a state-space layer, a gated unit, an MLP:
+      whatever it keeps per sequence is no KV page): `group_of_layer`
+      and `slot_of_layer` are -1;
+    - it **reads layer k's pages** (a cross-attention layer over
+      another layer's K and V): it writes no page, and its group and
+      place are layer k's.
+
+    The layers that hold pages are dealt, kind by kind and in order,
+    into groups of `layers_per_group` = gcd(full layers, window
     layers), so that every group has the same number of layers and ONE
     free list serves all of them: the pool is `layers_per_group` pairs
     of page arrays, the layer at place `slot_of_layer[l]` of its group
@@ -53,22 +68,46 @@ class PageGroups:
     one kind has one group of all its layers, and its pool is what it
     always was: a pair a layer."""
     kinds: Tuple[str, ...]              # a group: "full" | "window"
-    group_of_layer: Tuple[int, ...]
+    group_of_layer: Tuple[int, ...]     # -1: the layer holds nothing
     slot_of_layer: Tuple[int, ...]
     window: Optional[int] = None        # tokens; None: no window group
+    #: a layer that holds nothing keeps recurrent state instead
+    #: (`StateSpec`): what follows the pages alone (swap, prefix pins,
+    #: bursts, speculative rounds) does not carry it
+    stateful: bool = False
 
     @classmethod
-    def of(cls, layer_windows: List[bool],
-           window: Optional[int]) -> "PageGroups":
-        """`layer_windows[l]`: whether layer `l` has the window."""
-        if window is None:
-            layer_windows = [False] * len(layer_windows)
-        n_window = sum(map(bool, layer_windows))
-        per = math.gcd(n_window, len(layer_windows) - n_window)
+    def of(cls, layer_kinds: List[Union[bool, str, int, None]],
+           window: Optional[int], stateful: bool = False
+           ) -> "PageGroups":
+        """`layer_kinds[l]`: "window" (or True), "full" (or False),
+        None for a layer that holds nothing, or the index of the
+        earlier layer whose pages layer `l` reads."""
+        def kind_of(entry):
+            if entry is None or (isinstance(entry, int) and
+                                 not isinstance(entry, bool)):
+                return entry
+            has_window = entry is True or entry == "window"
+            return "window" if has_window and window is not None \
+                else "full"
+        layer_kinds = [kind_of(entry) for entry in layer_kinds]
+        n_window = layer_kinds.count("window")
+        per = math.gcd(n_window, layer_kinds.count("full"))
         kinds, group_of, slot_of = [], [], []
         open_group = {}                 # kind -> (group, layers in it)
-        for has_window in layer_windows:
-            kind = "window" if has_window else "full"
+        for kind in layer_kinds:
+            if kind is None:
+                group_of.append(-1)
+                slot_of.append(-1)
+                continue
+            if not isinstance(kind, str):   # reads layer `kind`'s pages
+                if not 0 <= kind < len(group_of) or group_of[kind] < 0:
+                    raise ValueError(
+                        f"a layer reads the pages of layer {kind}, "
+                        "which comes no earlier or holds none")
+                group_of.append(group_of[kind])
+                slot_of.append(slot_of[kind])
+                continue
             group, filled = open_group.get(kind, (None, per))
             if filled == per:
                 group, filled = len(kinds), 0
@@ -77,17 +116,44 @@ class PageGroups:
             slot_of.append(filled)
             open_group[kind] = (group, filled + 1)
         return cls(tuple(kinds), tuple(group_of), tuple(slot_of),
-                   window if n_window else None)
+                   window if n_window else None, stateful)
 
     @property
     def layers_per_group(self) -> int:
-        return len(self.group_of_layer) // len(self.kinds)
+        """Pairs of page arrays: the places of a group."""
+        return 1 + max(self.slot_of_layer)
+
+    @functools.cached_property
+    def readers(self) -> Tuple[int, ...]:
+        """For each group, the layers whose attention reads its pages:
+        its own and those that read theirs."""
+        return tuple(self.group_of_layer.count(g)
+                     for g in range(len(self.kinds)))
 
     @property
     def plain(self) -> bool:
-        """One group that lets go of nothing: block tables, swap,
-        prefix pins, bursts and speculative rounds as ever."""
-        return self.kinds == ("full",)
+        """One group that lets go of nothing, and no state beside it:
+        block tables, swap, prefix pins, bursts and speculative rounds
+        as ever."""
+        return self.kinds == ("full",) and not self.stateful
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpec:
+    """The constant-size recurrent state a model keeps for a sequence
+    beside its KV pages: `layers` layers, each with one array
+    `[slots + 1, *shape]` of `dtype` for every entry of `arrays`. A
+    sequence owns one STATE SLOT, the same row of all of them
+    (`processing/block_manager.py`); the last row is the pad rows'
+    scratch."""
+    layers: int
+    arrays: Tuple[Tuple[Tuple[int, ...], str], ...]   # (shape, dtype)
+
+    @property
+    def slot_bytes(self) -> int:
+        return self.layers * sum(
+            math.prod(shape) * _DTYPE_BYTES[dtype]
+            for shape, dtype in self.arrays)
 
 
 class ModelConfig:
@@ -212,6 +278,12 @@ class ModelConfig:
         pattern, else every layer in one group, a window group where
         the model-wide `sliding_window` is set."""
         cfg = self.hf_config
+        kinds = getattr(cfg, "page_layer_kinds", None)
+        if kinds is not None:
+            # the config states each layer's kind itself
+            return PageGroups.of(
+                kinds, self.get_sliding_window(),
+                stateful=self.get_state_spec() is not None)
         layout = getattr(cfg, "sliding_window_layout", None)
         if layout is not None:
             return PageGroups.of([bool(x) for x in layout],
@@ -226,7 +298,22 @@ class ModelConfig:
     def get_hidden_size(self) -> int:
         return self.hf_config.hidden_size
 
+    def get_state_spec(self) -> Optional[StateSpec]:
+        """What the model keeps for a sequence that is no KV page, as
+        its config states it (`state_spec(dtype)`): None for a stack
+        of attention layers."""
+        stated = getattr(self.hf_config, "state_spec", None)
+        if stated is None:
+            return None
+        layers, arrays = stated(self.dtype)
+        return StateSpec(layers=layers, arrays=arrays)
+
     def get_head_size(self) -> int:
+        """(of what the KV pages hold, where that is not the model's
+        own head: `paged_head_dim`, `paged_kv_heads`)"""
+        paged = getattr(self.hf_config, "paged_head_dim", None)
+        if paged:
+            return paged
         if hasattr(self.hf_config, "head_dim") and self.hf_config.head_dim:
             return self.hf_config.head_dim
         return (self.hf_config.hidden_size //
@@ -237,7 +324,8 @@ class ModelConfig:
         # Falcon-style multi_query flag.
         if getattr(self.hf_config, "multi_query", False):
             return 1
-        for attr in ("n_head_kv", "num_kv_heads", "num_key_value_heads",
+        for attr in ("paged_kv_heads", "n_head_kv", "num_kv_heads",
+                     "num_key_value_heads",
                      "multi_query_group_num"):
             value = getattr(self.hf_config, attr, None)
             if value is not None:
@@ -264,9 +352,10 @@ class ModelConfig:
         layers are one page group, else a group's worth of them (the
         groups' layers have to agree in heads, place by place)."""
         heads, groups = self.get_kv_heads_per_layer(), self.get_page_groups()
-        per_slot = heads[:groups.layers_per_group]
+        per_slot = [heads[groups.slot_of_layer.index(slot)]
+                    for slot in range(groups.layers_per_group)]
         for layer, slot in enumerate(groups.slot_of_layer):
-            if heads[layer] != per_slot[slot]:
+            if slot >= 0 and heads[layer] != per_slot[slot]:
                 raise ValueError(
                     "page groups need layers of equal KV heads; layer "
                     f"{layer} has {heads[layer]}, its slot {per_slot[slot]}")
@@ -298,6 +387,7 @@ class CacheConfig:
         cache_dtype: str = "auto",
         sliding_window: Optional[int] = None,
         page_groups: Optional[PageGroups] = None,
+        state_spec: Optional[StateSpec] = None,
     ) -> None:
         self.block_size = block_size
         self.gpu_memory_utilization = gpu_memory_utilization
@@ -311,9 +401,17 @@ class CacheConfig:
         self._verify_args()
         self._verify_cache_dtype()
 
+        #: what the model keeps for a sequence beside its pages
+        self.state_spec = state_spec
+        if (state_spec is not None) != self.page_groups.stateful:
+            raise ValueError("a model's recurrent state comes with "
+                             "page groups that say so, and only then")
+
         # Set after profiling:
         self.num_gpu_blocks: Optional[int] = None
         self.num_cpu_blocks: Optional[int] = None
+        #: state slots (None: the model keeps no state)
+        self.num_state_slots: Optional[int] = None
 
     def _verify_args(self) -> None:
         if self.gpu_memory_utilization > 1.0:

@@ -333,11 +333,14 @@ class SequenceGroupMetadata:
         chunk_len: Optional[int] = None,
         is_final_chunk: bool = True,
         group_tables: Optional[Dict[int, list]] = None,
+        state_slots: Optional[Dict[int, int]] = None,
     ) -> None:
         """`group_tables`: for a model whose KV pages are not one plain
         group, each sequence's `[(tokens let go of, page numbers)]`, an
         entry a page group (`BlockSpaceManager.get_group_tables`);
-        `block_tables` is then the first group's."""
+        `block_tables` is then the first group's. `state_slots`: each
+        sequence's state slot, for a model with recurrent state
+        (`BlockSpaceManager.get_state_slot`)."""
         self.request_id = request_id
         self.is_prompt = is_prompt
         self.seq_data = seq_data
@@ -350,6 +353,7 @@ class SequenceGroupMetadata:
         self.chunk_len = chunk_len
         self.is_final_chunk = is_final_chunk
         self.group_tables = group_tables
+        self.state_slots = state_slots
 
     @property
     def lora_int_id(self) -> int:

@@ -33,7 +33,10 @@ from jax.profiler import TraceAnnotation
 #: KV pages a decode step's attention copies and those of them that
 #: are live, by page group where the model has several; the pages
 #: window groups let go of; what a model's expert layers count in
-#: the step program (pairs routed, experts touched); and the seconds in
+#: the step program (pairs routed, experts touched); what the model
+#: runner counts where it builds a step of a model with state slots
+#: (rows started from zeros, decode rows, prompt tokens scanned, page
+#: reads with every reading layer counted); and the seconds in
 #: which a dispatched step had not been pulled yet (`Tracer.flight`).
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
@@ -48,6 +51,8 @@ NAMES = (
     "cache.kv_handoff",     # disagg: prefill pool -> decode pool
     "cache.window_release",  # window groups let go of passed pages
                              # (inside sched.schedule)
+    "cache.state_assign",   # state slots given to admitted prompts
+                            # (inside sched.schedule)
     "queue_wait",
     "preemptions",
     "sampler.plan_reuse",
@@ -64,6 +69,12 @@ NAMES = (
     "moe.experts_touched",  # experts with a pair, over layers and steps
     "moe.decode_experts_touched",  # of them, the decode steps'
     "moe.decode_expert_slots",     # experts x expert layers, a decode step
+    "ssm.state_resets",     # prompt rows that start at position 0: the
+                            # step's program starts them from zeros
+    "ssm.decode_rows",      # decode rows of a model with state slots
+    "ssm.prefill_tokens",   # prompt tokens its chunk scans went over
+    "attn.page_reads_shared",  # a decode step's live pages, each group's
+                            # times the layers that read them
     "runner.in_flight",
 )
 

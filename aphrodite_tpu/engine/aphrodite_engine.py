@@ -673,7 +673,8 @@ class AphroditeEngine:
                             scheduler_outputs)
             handles = self.executor.dispatch_steps(Round(
                 prompt=prompt_mds, decode=decode_mds, ahead=True,
-                fed_by=before.handles if before is not None else ()))
+                fed_by=before.handles if before is not None else (),
+                state_copies=scheduler_outputs.state_copies))
             if handles is not None:
                 if before is not None:
                     for _ in handles:
@@ -785,7 +786,8 @@ class AphroditeEngine:
             prompt_mds, decode_mds, scheduler_outputs.blocks_to_swap_in,
             scheduler_outputs.blocks_to_swap_out,
             scheduler_outputs.blocks_to_copy, num_steps=burst,
-            extra_cap=extra_cap, drafts=drafts))
+            extra_cap=extra_cap, drafts=drafts,
+            state_copies=scheduler_outputs.state_copies))
         if prompt_mds and not decode_mds \
                 and not scheduler_outputs.blocks_to_swap_in \
                 and not scheduler_outputs.blocks_to_swap_out \
@@ -872,7 +874,8 @@ class AphroditeEngine:
             # are already in flight and touch disjoint groups.
             all_prompt_mds.extend(mds2)
             steps.append(self.executor.dispatch_steps(Round(
-                prompt=mds2, blocks_to_copy=outputs2.blocks_to_copy)))
+                prompt=mds2, blocks_to_copy=outputs2.blocks_to_copy,
+                state_copies=outputs2.state_copies)))
             if not self._prompt_fast_path_ok(mds2):
                 break
         # Disagg: hand off every final-chunk group of the batch-built
@@ -1448,6 +1451,7 @@ class AphroditeEngine:
                 self.scheduler.block_manager.get_num_free_cpu_blocks()
             cpu_cache_usage = 1.0 - num_free_cpu / num_total_cpu
 
+        slots = self.cache_config.num_state_slots or 0
         num_prompt_tokens = 0
         num_generation_tokens = 0
         if scheduler_outputs is not None:
@@ -1484,6 +1488,9 @@ class AphroditeEngine:
             time_e2e_requests=e2es,
             num_waiting_tokens=self.scheduler.waiting_prefill_tokens(),
             prefix_pinned_pages=self.scheduler.prefix_pinned_pages(),
+            ssm_slots_total=slots,
+            ssm_slots_live=slots -
+            self.scheduler.block_manager.get_num_free_state_slots(),
             sheds_total=self.admission.sheds_total,
             expired_total=self.admission.expired_total,
             ewma_prefill_tok_s=self.admission.ewma_prefill_tok_s,

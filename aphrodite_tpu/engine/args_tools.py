@@ -172,7 +172,8 @@ class EngineArgs:
         cache_config = CacheConfig(
             self.block_size, self.gpu_memory_utilization, self.swap_space,
             self.kv_cache_dtype,
-            page_groups=model_config.get_page_groups())
+            page_groups=model_config.get_page_groups(),
+            state_spec=model_config.get_state_spec())
         # --disagg-split wins; None defers to the APHRODITE_DISAGG
         # flag (registry-validated read), "" explicitly colocates.
         disagg_spec = self.disagg_split

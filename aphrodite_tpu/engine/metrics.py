@@ -119,6 +119,26 @@ _STAGE_COUNTERS = [
     ("aphrodite:window_release_seconds_total",
      "Seconds letting window groups' passed pages go (inside the "
      "schedule seconds).", lambda s, c: s["cache.window_release"]),
+    ("aphrodite:kv_page_reads_shared_total",
+     "Live KV pages of each page group times the layers whose "
+     "attention reads them (a group's own and those that read "
+     "theirs), summed over decode steps.",
+     lambda s, c: c["attn.page_reads_shared"]),
+    ("aphrodite:state_assign_seconds_total",
+     "Seconds giving admitted prompts their state slots (inside the "
+     "schedule seconds).", lambda s, c: s["cache.state_assign"]),
+    ("aphrodite:ssm_state_resets_total",
+     "Prompt rows that started at position 0, where the step's "
+     "program starts the row's state slot from zeros.",
+     lambda s, c: c["ssm.state_resets"]),
+    ("aphrodite:ssm_decode_rows_total",
+     "Decode rows of a model with state slots: one-token state "
+     "updates a state layer, summed over decode steps.",
+     lambda s, c: c["ssm.decode_rows"]),
+    ("aphrodite:ssm_prefill_tokens_total",
+     "Prompt tokens the chunk scans of a model with state slots went "
+     "over (a state layer's), summed over prompt steps.",
+     lambda s, c: c["ssm.prefill_tokens"]),
     ("aphrodite:moe_tokens_routed_total",
      "Token-expert pairs the expert layers computed, counted in the "
      "step programs.", lambda s, c: c["moe.tokens_routed"]),
@@ -193,6 +213,13 @@ class Metrics:
             Gauge, "aphrodite:prefix_pinned_pages",
             "KV pages pinned by the prefix cache (held on purpose; "
             "subtracted by the zero-leak accounting).", labelnames)
+        self.gauge_ssm_slots_total = _get_or_create(
+            Gauge, "aphrodite:ssm_slots_total",
+            "State slots of a model that keeps recurrent state beside "
+            "its KV pages (0: it keeps none).", labelnames)
+        self.gauge_ssm_slots_live = _get_or_create(
+            Gauge, "aphrodite:ssm_slots_live",
+            "State slots that sequences hold.", labelnames)
         self.counter_requests_shed = _get_or_create(
             Counter, "aphrodite:num_requests_shed",
             "Requests rejected at admission by overload control.",
@@ -253,6 +280,8 @@ class Stats:
     # tracks deltas for the Prometheus counters).
     num_waiting_tokens: int = 0
     prefix_pinned_pages: int = 0
+    ssm_slots_total: int = 0
+    ssm_slots_live: int = 0
     sheds_total: int = 0
     expired_total: int = 0
     ewma_prefill_tok_s: float = 0.0
@@ -319,6 +348,8 @@ class StatLogger:
         labeled(m.gauge_waiting_prefill_tokens).set(
             stats.num_waiting_tokens)
         labeled(m.gauge_prefix_pinned).set(stats.prefix_pinned_pages)
+        labeled(m.gauge_ssm_slots_total).set(stats.ssm_slots_total)
+        labeled(m.gauge_ssm_slots_live).set(stats.ssm_slots_live)
         labeled(m.gauge_ewma_prefill).set(stats.ewma_prefill_tok_s)
         labeled(m.gauge_ewma_decode).set(stats.ewma_decode_tok_s)
         export(m.counter_requests_shed, stats.sheds_total)

@@ -12,6 +12,13 @@ Where the model's layers lie in several page groups
 group, not for each layer: the layers at one place of the groups share
 it, each under its own group's page ids, so one page id is the same
 bytes whichever group holds it and one free list serves them all.
+A model that keeps recurrent state beside its pages
+(`common/config.py::StateSpec`) has, after those pairs in `kv_caches`,
+a tuple of state arrays for each state layer, `[slots + 1, ...]` each:
+a sequence's STATE SLOT is the same row of all of them, the last row
+the pad rows' scratch. They ride through the step programs with the
+pages, donated and updated in place; a slot is never zeroed from the
+host (the program of a sequence's first chunk starts from zeros).
 Swap space is pinned host numpy; swap_in/out are `jax.device_put`/
 `device_get` of whole pages — JAX dispatches these asynchronously, which
 replaces the reference's dedicated CUDA stream + event machinery.
@@ -125,7 +132,10 @@ class CacheEngine:
             self.kv_scale = flags.get_float(
                 "APHRODITE_KV_SCALE", default=DEFAULT_KV_SCALE)
 
-        self.kv_caches: List[KVCache] = self._allocate_device()
+        #: pairs of page arrays at the head of `kv_caches`
+        self.num_page_pairs = len(self.kv_heads_per_layer)
+        self.kv_caches: List[KVCache] = self._allocate_device() + \
+            self._allocate_state()
         # Prefill-group pool: same page count as the decode pool so the
         # two mirror one logical page space — a handed-off page keeps
         # its id, only its physical residency changes. None when
@@ -172,6 +182,23 @@ class CacheEngine:
 
         return [(alloc(heads), alloc(heads))
                 for heads in self.kv_heads_per_layer]
+
+    def _allocate_state(self) -> List[tuple]:
+        """The state arrays of a model with recurrent state: a tuple a
+        state layer, zeros (what a slot holds before its first owner
+        matters to no program; the scratch slot's to none at all)."""
+        spec = self.cache_config.state_spec
+        if spec is None:
+            return []
+        if self.mesh is not None and self.mesh.size > 1:
+            raise NotImplementedError(
+                "a model with recurrent state is served on one chip: "
+                "its state arrays and scan kernels are single-device")
+        slots = self.cache_config.num_state_slots
+        return [tuple(jnp.zeros((slots + 1,) + shape,
+                                dtype=jnp.dtype(dtype))
+                      for shape, dtype in spec.arrays)
+                for _ in range(spec.layers)]
 
     def _allocate_prefill_pool(self) -> List[KVCache]:
         """Prefill-group mirror of the device pool.
@@ -268,7 +295,8 @@ class CacheEngine:
         self._ensure_host_pool()
         src = np.fromiter(mapping.keys(), dtype=np.int64)
         dst = np.fromiter(mapping.values(), dtype=np.int64)
-        for layer, (k_pages, v_pages) in enumerate(self.kv_caches):
+        for layer, (k_pages, v_pages) in enumerate(
+                self.kv_caches[:self.num_page_pairs]):
             # One bulk gather per side, then a single host transfer in
             # the page dtype (no f32 inflation).
             k_host = np.asarray(jnp.take(k_pages, src, axis=0))
@@ -284,7 +312,8 @@ class CacheEngine:
         src = np.fromiter(mapping.keys(), dtype=np.int64)
         dst = np.fromiter(mapping.values(), dtype=np.int64)
         new_caches: List[KVCache] = []
-        for layer, (k_pages, v_pages) in enumerate(self.kv_caches):
+        for layer, (k_pages, v_pages) in enumerate(
+                self.kv_caches[:self.num_page_pairs]):
             k_in = jnp.asarray(self._host_pool[layer][0][src],
                                dtype=self.dtype)
             v_in = jnp.asarray(self._host_pool[layer][1][src],
@@ -292,7 +321,7 @@ class CacheEngine:
             k_pages = k_pages.at[dst].set(k_in)
             v_pages = v_pages.at[dst].set(v_in)
             new_caches.append((k_pages, v_pages))
-        self.kv_caches = new_caches
+        self.kv_caches = new_caches + self.kv_caches[self.num_page_pairs:]
 
     @staticmethod
     def get_cache_block_size(cache_config: CacheConfig,

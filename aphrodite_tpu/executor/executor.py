@@ -27,7 +27,8 @@ from aphrodite_tpu.common.config import (CacheConfig, DeviceConfig,
 from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.sequence import SequenceGroupMetadata
 from aphrodite_tpu.executor.cache_engine import CacheEngine
-from aphrodite_tpu.executor.model_runner import ModelRunner, StepHandle
+from aphrodite_tpu.executor.model_runner import (_DECODE_BATCH_BUCKETS,
+                                                  ModelRunner, StepHandle)
 from aphrodite_tpu.modeling.loader import get_model
 
 logger = init_logger(__name__)
@@ -113,6 +114,8 @@ class Round:
     drafts: Optional[Dict[int, List[int]]] = None
     ahead: bool = False
     fed_by: Tuple[StepHandle, ...] = ()
+    #: (from, to) state slots of forks, copied before the steps
+    state_copies: List[Tuple[int, int]] = field(default_factory=list)
 
 
 class TPUExecutor:
@@ -145,6 +148,10 @@ class TPUExecutor:
         # classic single full mesh and `prefill_runner is model_runner`.
         self.prefill_mesh = None
         if parallel_config.disagg:
+            if cache_config.state_spec is not None:
+                raise NotImplementedError(
+                    "disagg_split + a model with recurrent state is not "
+                    "supported: kv_handoff carries pages, not state")
             if lora_config is not None:
                 raise NotImplementedError(
                     "disagg_split + LoRA is not supported: adapter "
@@ -192,7 +199,8 @@ class TPUExecutor:
             kv_scale=self.cache_engine.kv_scale,
             sp=sp,
             kv_cache_dtype=self.cache_engine.dtype,
-            tracer=self.tracer)
+            tracer=self.tracer,
+            num_state_slots=cache_config.num_state_slots)
         self.prefill_runner = self.model_runner
         if self.prefill_mesh is not None:
             self.prefill_runner = ModelRunner(
@@ -329,6 +337,10 @@ class TPUExecutor:
             if self.cache_config.num_cpu_blocks is None:
                 self.cache_config.num_cpu_blocks = int(
                     self.cache_config.swap_space_bytes // block_bytes)
+            if self.cache_config.state_spec is not None and \
+                    self.cache_config.num_state_slots is None:
+                self.cache_config.num_state_slots = \
+                    self.scheduler_config.max_num_seqs
             return
         free = self._device_free_memory()
         if free is None:
@@ -381,6 +393,7 @@ class TPUExecutor:
             layers = max(1, self.model_config.get_num_layers(
                 self.parallel_config))
             budget = int(budget * layers / (layers + 1))
+        budget -= self._size_state_slots(budget, block_bytes)
         num_pages = max(budget // block_bytes, 16)
         self.cache_config.num_gpu_blocks = int(num_pages)
         if self.cache_config.num_cpu_blocks is None:
@@ -390,6 +403,41 @@ class TPUExecutor:
                     "(%.2f GiB device)", self.cache_config.num_gpu_blocks,
                     self.cache_config.num_cpu_blocks,
                     num_pages * block_bytes / _GB)
+
+    def _size_state_slots(self, budget: int, block_bytes: int) -> int:
+        """Pages and state slots share the one budget. The rule: as
+        many slots as the largest decode bucket (no more than
+        `--max-num-seqs`) whose rows, each at `max_model_len` tokens
+        with its slot, fit the budget; the rest of the budget is
+        pages. So every slot can be fed pages to the longest context
+        the engine admits, and no page waits for a slot that is not
+        there. Returns the bytes the slots take (the scratch slot
+        among them); 0 for a model without state."""
+        spec = self.cache_config.state_spec
+        if spec is None:
+            return 0
+        groups, page = self.cache_config.page_groups, \
+            self.cache_config.block_size
+        longest = -(-self.model_config.max_model_len // page)
+        # a window group holds the window and a page once a row has
+        # passed it
+        held = -(-(groups.window or 0) // page) + 1
+        row_pages = sum(min(longest, held) if kind == "window" else longest
+                        for kind in groups.kinds)
+        row_bytes = row_pages * block_bytes + spec.slot_bytes
+        fitting = [b for b in _DECODE_BATCH_BUCKETS
+                   if b <= self.scheduler_config.max_num_seqs and
+                   (b + 1) * row_bytes <= budget]
+        slots = max(fitting, default=1)
+        self.cache_config.num_state_slots = slots
+        taken = (slots + 1) * spec.slot_bytes
+        logger.info(
+            "State slots: %d of %d bytes each (%.2f GiB with the scratch "
+            "slot) from the KV budget of %.2f GiB; a row at %d tokens "
+            "holds %d pages of %d bytes",
+            slots, spec.slot_bytes, taken / _GB, budget / _GB,
+            self.model_config.max_model_len, row_pages, block_bytes)
+        return taken
 
     # -- step execution --
 
@@ -413,6 +461,9 @@ class TPUExecutor:
         step of it uses (same page ids, idempotent, so the split
         layout's mirrors stay coherent whichever phase forked); a
         round without rows copies in the decode pool."""
+        if rnd.state_copies:
+            self.cache_engine.kv_caches = self.model_runner.copy_state(
+                self.cache_engine.kv_caches, rnd.state_copies)
         if not rnd.blocks_to_copy:
             return
         if rnd.prompt:

@@ -147,6 +147,12 @@ class StepHandle:
 class ModelRunner:
     """Drives one model replica (single chip or one SPMD mesh)."""
 
+    #: the state slots of a model with recurrent state beside its
+    #: pages (None: it has none): the state arrays follow the
+    #: `page_pairs` pairs of page arrays in `kv_caches`, and a pad
+    #: row's slot is the scratch one, `num_state_slots`
+    num_state_slots: Optional[int] = None
+
     def __init__(
         self,
         model,
@@ -160,6 +166,7 @@ class ModelRunner:
         sp: Optional[tuple] = None,         # (Mesh, threshold) or None
         kv_cache_dtype=jnp.bfloat16,
         tracer: Optional[tracing.Tracer] = None,
+        num_state_slots: Optional[int] = None,
     ) -> None:
         # The engine's span accumulators (its own, when built alone).
         self.tracer = tracer or tracing.Tracer()
@@ -227,6 +234,8 @@ class ModelRunner:
         #: the model's page groups ("full" or "window" each), and the
         #: window; one plain group for most models
         self.page_groups = model_config.get_page_groups()
+        self.num_state_slots = num_state_slots
+        self.page_pairs = self.page_groups.layers_per_group
         #: what the model counts inside its step programs
         #: (`tracing.NAMES`), pulled with a step's result
         self.step_counters: Tuple[str, ...] = tuple(
@@ -266,6 +275,8 @@ class ModelRunner:
             donate_argnums=(3,),      # kv_caches
         )
         self._copy_fn = jax.jit(self._copy_blocks, donate_argnums=(0,))
+        self._copy_state_fn = jax.jit(self._copy_state,
+                                      donate_argnums=(0,))
         # Small, and apart from the step programs on purpose: a decode
         # step whose tokens are still on the device takes them through
         # this, so `_step_sample` keeps its signature and its compiled
@@ -339,7 +350,10 @@ class ModelRunner:
         return rows[:, 0:1], rows[:, 1:2], metadata.replace(
             slot_mapping=views[0].slot_mapping,
             block_tables=views[0].block_tables,
-            context_lens=views[0].context_lens, groups=tuple(views))
+            context_lens=views[0].context_lens, groups=tuple(views),
+            # (the last column, where the model has state slots)
+            state_slots=None if self.num_state_slots is None
+            else rows[:, at])
 
     def _logits(self, params, input_ids, positions, kv_caches, metadata,
                 sel_indices):
@@ -458,9 +472,27 @@ class ModelRunner:
         return rows.at[:, 0].set(jnp.where(token < 0, fed, token))
 
     def _copy_blocks(self, kv_caches, src, dst):
-        return [
-            _copy_blocks_op(k, v, src, dst) for (k, v) in kv_caches
-        ]
+        pages = self.page_pairs
+        return [_copy_blocks_op(k, v, src, dst)
+                for (k, v) in kv_caches[:pages]] + list(kv_caches[pages:])
+
+    def _copy_state(self, kv_caches, src, dst):
+        pages = self.page_pairs
+        return list(kv_caches[:pages]) + [
+            tuple(a.at[dst].set(a[src]) for a in arrays)
+            for arrays in kv_caches[pages:]]
+
+    def copy_state(self, kv_caches, copies: List[Tuple[int, int]]):
+        """A fork's state: each child's slot takes its parent's rows
+        of every state array, before the round's steps. Padded to a
+        bucket with the scratch slot onto itself."""
+        padded = _pow2_bucket(len(copies), lo=8)
+        src = np.full((padded,), self.num_state_slots, dtype=np.int32)
+        dst = src.copy()
+        src[:len(copies)], dst[:len(copies)] = zip(*copies)
+        with self._mesh_ctx():
+            return self._copy_state_fn(kv_caches, self._dev(src),
+                                       self._dev(dst))
 
     # ---- LoRA slot plumbing ----
 
@@ -602,10 +634,22 @@ class ModelRunner:
                 ctx_lens, plens, padded_len)
                 for g in range(len(self.page_groups.kinds))]
 
+        state_slots = None
+        if self.num_state_slots is not None:
+            slots = np.full((padded_batch,), self.num_state_slots,
+                            dtype=np.int32)
+            slots[:batch] = [md.state_slots[seq_id] for md, seq_id in
+                             zip(seq_group_metadata_list, seq_ids)]
+            state_slots = self._dev(slots)
+            # (a row at position 0 starts from zeros in the program)
+            self.tracer.add("ssm.state_resets",
+                            count=sum(c == 0 for c in ctxs))
+            self.tracer.add("ssm.prefill_tokens", count=sum(prompt_lens))
         metadata = InputMetadata(
             slot_mapping=views[0].slot_mapping,
             block_tables=views[0].block_tables,
             context_lens=views[0].context_lens,
+            state_slots=state_slots,
             prompt_lens=self._dev(plens),
             kv_scale=self.kv_scale,
             sp=self.sp,
@@ -730,6 +774,8 @@ class ModelRunner:
             [], [], [], [], []
         grouped = not self.page_groups.plain
         group_rows: Optional[list] = [] if grouped else None
+        state_slots: Optional[List[int]] = \
+            None if self.num_state_slots is None else []
 
         for md in seq_group_metadata_list:
             group_ids = list(md.seq_data.keys())
@@ -748,6 +794,8 @@ class ModelRunner:
                 ctx_list.append(pos + 1)
                 table = md.block_tables[seq_id]
                 tables_list.append(table)
+                if state_slots is not None:
+                    state_slots.append(md.state_slots[seq_id])
                 if grouped:
                     # each group's slot is read off its own table, in
                     # the program (`_unpacked`)
@@ -773,7 +821,8 @@ class ModelRunner:
 
         inputs = self._send_decode_batch(tokens, positions, slot_list,
                                          ctx_list, tables_list,
-                                         group_rows=group_rows)
+                                         group_rows=group_rows,
+                                         state_slots=state_slots)
         if min(tokens) < 0:
             meta = inputs["metadata"]
             with self._mesh_ctx():
@@ -920,7 +969,9 @@ class ModelRunner:
 
     def _send_decode_batch(self, tokens, positions, slot_list, ctx_list,
                            tables_list, spec_verify: bool = False,
-                           group_rows: Optional[list] = None) -> dict:
+                           group_rows: Optional[list] = None,
+                           state_slots: Optional[List[int]] = None
+                           ) -> dict:
         """Pad a decode (or verify) batch to its buckets and send it:
         ONE [padded_batch, 4 + pages] int32 array (each row's token,
         position, slot, context length, then its block table; pad rows
@@ -931,7 +982,9 @@ class ModelRunner:
         `group_rows` (a model with page groups): each row's `[(tokens
         let go of, page numbers)]`, an entry a group. The row then
         holds, after its first four columns, that number and the table
-        for each group in turn, and each group has its work list."""
+        for each group in turn, and each group has its work list.
+        `state_slots` (a model with recurrent state): each row's state
+        slot, the row's last column; a pad row's is the scratch one."""
         batch = len(tokens)
         padded_batch = _bucket(batch, _DECODE_BATCH_BUCKETS)
         pad_rows = [0] * (padded_batch - batch)
@@ -951,10 +1004,14 @@ class ModelRunner:
                 for g, kind in enumerate(kinds)]
             layout, views = tuple(widths), []
 
-        rows = np.zeros((padded_batch, 4 + sum(widths) + len(layout)),
-                        dtype=np.int32)
+        rows = np.zeros((padded_batch, 4 + sum(widths) + len(layout) +
+                         (state_slots is not None)), dtype=np.int32)
         rows[:, 2] = self.num_slots
         rows[:, 4:] = self.num_slots // self.page_size
+        if state_slots is not None:
+            rows[:, -1] = self.num_state_slots
+            rows[:batch, -1] = state_slots
+            self.tracer.add("ssm.decode_rows", count=batch)
         rows[:batch, 0] = tokens
         rows[:batch, 1] = positions
         rows[:batch, 2] = slot_list
@@ -971,7 +1028,7 @@ class ModelRunner:
             fetched, live = count_decode_pages(
                 rows[:, 3], chunks, ppc, self.page_size)
         else:
-            fetched = live = 0
+            fetched = live = shared = 0
             at = 4
             for g, width in enumerate(widths):
                 rows[:, at] = 0
@@ -990,12 +1047,14 @@ class ModelRunner:
                 fetched, live = fetched + got[0], live + got[1]
                 self.tracer.add("attn.pages_live." + kinds[g],
                                 count=got[1])
+                shared += got[1] * self.page_groups.readers[g]
                 if kinds[g] == "window":
                     # (what the rows' whole contexts take in pages)
                     self.tracer.add(
                         "attn.window_pages_unwindowed",
                         count=int(np.sum(-(-rows[:, 3] // self.page_size))))
                 at += 1 + width
+            self.tracer.add("attn.page_reads_shared", count=shared)
             work, ppc = views[0].decode_work, views[0].decode_ppc
         self.tracer.add("attn.pages_fetched", count=fetched)
         self.tracer.add("attn.pages_live", count=live)

@@ -70,6 +70,11 @@ class InputMetadata:
     # (columns of its table, whether a column with the tokens its
     # table has let go of precedes them).
     group_layout: tuple = struct.field(pytree_node=False, default=())
+    # [batch] each row's state slot, for a model that keeps recurrent
+    # state beside its pages (`common/config.py::StateSpec`); pad rows
+    # hold the scratch slot, the arrays' last. None for every other
+    # model. A packed decode batch carries it as its last column.
+    state_slots: Optional[jax.Array] = None
 
     is_prompt: bool = struct.field(pytree_node=False, default=False)
     # Speculative verify batch: rows are (sequence, position) work

@@ -19,7 +19,11 @@ with a window sees, like any other, its page group's table
 (`InputMetadata.for_group`): the pages the group still holds, from the
 first on, positions counted from that page's first token; the window
 itself is a mask, in the prefill and in the decode kernel alike, so a
-window layer's decode step is the fused kernel's too. Head sizes
+window layer's decode step is the fused kernel's too. A layer that
+`writes_kv` nothing attends over the pages another layer of its group
+has written (a cross-attention layer over an earlier layer's K and V):
+its step writes no page, and its decode step is the read-only kernel.
+Head sizes
 are unrestricted (the reference's {64..256} list, `attention.py:17`, is a
 CUDA register-tiling constraint with no TPU analog).
 """
@@ -53,6 +57,8 @@ class PagedAttention:
         sliding_window: Optional[int] = None,
         use_pallas: bool = True,
         page_group: int = 0,
+        writes_kv: bool = True,
+        blocked_from: int = BLOCKED_FROM,
     ) -> None:
         self.num_heads = num_heads
         self.head_size = head_size
@@ -65,6 +71,11 @@ class PagedAttention:
         self.use_pallas = use_pallas
         # which of the step's page groups this layer's cache is in
         self.page_group = page_group
+        # False: the pages are another layer's to write; this one reads
+        self.writes_kv = writes_kv
+        # queries x keys a row from which the prefill takes the keys
+        # in blocks (a model of many heads sets it lower)
+        self.blocked_from = blocked_from
         from aphrodite_tpu.ops.kv_cache import padded_head_size
         # Cache pages pad head_dim to the 128-lane tile; q/k/v pad with
         # zeros on the way in (inert in scores) and outputs slice the
@@ -74,8 +85,8 @@ class PagedAttention:
     def __call__(
         self,
         q: jax.Array,              # [batch, seq, num_heads * head_size]
-        k: jax.Array,              # [batch, seq, num_kv_heads * head_size]
-        v: jax.Array,
+        k: Optional[jax.Array],    # [batch, seq, num_kv_heads * head_size]
+        v: Optional[jax.Array],    # (None: a decode step that writes none)
         k_pages: Optional[jax.Array],
         v_pages: Optional[jax.Array],
         metadata: InputMetadata,
@@ -86,11 +97,15 @@ class PagedAttention:
         metadata = metadata.for_group(self.page_group)
         batch, seq_len, _ = q.shape
         q = q.reshape(batch, seq_len, self.num_heads, self.head_size)
-        k = k.reshape(batch, seq_len, self.num_kv_heads, self.head_size)
-        v = v.reshape(batch, seq_len, self.num_kv_heads, self.head_size)
+        if k is not None:
+            k = k.reshape(batch, seq_len, self.num_kv_heads,
+                          self.head_size)
+            v = v.reshape(batch, seq_len, self.num_kv_heads,
+                          self.head_size)
 
-        fused_decode = self._fused_decode_ok(k_pages, metadata)
-        if k_pages is not None and not fused_decode:
+        fused_decode = self.writes_kv and \
+            self._fused_decode_ok(k_pages, metadata)
+        if self.writes_kv and k_pages is not None and not fused_decode:
             flat_k = k.reshape(-1, self.num_kv_heads, self.head_size)
             flat_v = v.reshape(-1, self.num_kv_heads, self.head_size)
             if self.padded_head != self.head_size:
@@ -222,7 +237,8 @@ class PagedAttention:
 
         # (static: a function of the step program's shapes)
         attend = prefill_attention_blocked \
-            if seq_len * kv_k.shape[1] >= BLOCKED_FROM else prefill_attention
+            if seq_len * kv_k.shape[1] >= self.blocked_from \
+            else prefill_attention
         return attend(
             q, kv_k, kv_v, context_lens, kv_valid, self.scale,
             sliding_window=self.sliding_window,

@@ -10,7 +10,16 @@ before the window of the oldest query still to come, in the round that
 passes it, and takes new pages at its end, so it holds the window, the
 chunk being written and a page at most. A model-wide window is the
 case of one group, a window group; a model without one has one full
-group and is served exactly as before. This module is pure Python
+group and is served exactly as before. A model that keeps recurrent
+state beside its pages (`common/config.py::StateSpec`) has a second
+kind of per-sequence memory here, the STATE SLOT: one id a sequence,
+the same row of every state array, from a free list of its own; given
+with the prompt's pages, freed with them, and on a fork the child
+takes a slot and the device copies the parent's row into it
+(`take_state_copies`). Nothing zeroes a slot on the host: the program
+of a sequence's first chunk (position 0) starts from zeros whatever
+the slot holds, so a slot's next owner, a row preempted by recompute
+and a round rolled back all start clean. This module is pure Python
 and device-agnostic: it only plans block operations; the executor applies
 them to the HBM page arrays (`executor/cache.py`) as batched gathers/
 scatters and host transfers — there is no per-block memcpy on TPU, the
@@ -79,13 +88,15 @@ BlockAllocator = BlockPool
 
 
 class PageGroupsUnsupported(RuntimeError):
-    """What a model with a window or with several page groups is
-    refused, rather than served half-right."""
+    """What a model with a window, with several page groups or with
+    recurrent state beside its pages is refused, rather than served
+    half-right."""
 
     def __init__(self, what: str, instead: str) -> None:
         super().__init__(
             f"{what} is not supported for a model whose KV pages are "
-            f"in window groups or in more than one group: {instead}")
+            "in window groups or in more than one group, or that keeps "
+            f"recurrent state beside them: {instead}")
 
 
 class AllocStatus(enum.Enum):
@@ -107,12 +118,14 @@ class BlockSpaceManager:
         sliding_window: Optional[int] = None,
         group_kinds: Optional[Tuple[str, ...]] = None,
         max_chunk_tokens: Optional[int] = None,
+        num_state_slots: Optional[int] = None,
     ) -> None:
         """`group_kinds`: "full" or "window" for each page group (one
         group by default: a window group where `sliding_window` is
         set). `max_chunk_tokens`: the longest prompt chunk the
         scheduler writes at once for a model with a window group
-        (None: a prompt may come whole)."""
+        (None: a prompt may come whole). `num_state_slots`: the state
+        slots of a model with recurrent state (None: it has none)."""
         self.block_size = block_size
         self.num_total_gpu_blocks = num_gpu_blocks
         self.num_total_cpu_blocks = num_cpu_blocks
@@ -122,7 +135,8 @@ class BlockSpaceManager:
                 ("full",) if sliding_window is None else ("window",))
         #: one full group: block tables, swap, prefix pins and
         #: look-ahead reservations as ever
-        self.plain = self.group_kinds == ("full",)
+        self.plain = self.group_kinds == ("full",) and \
+            num_state_slots is None
         self.sliding_window = sliding_window
         if "window" in self.group_kinds and not sliding_window:
             raise ValueError("a window page group needs sliding_window")
@@ -157,6 +171,18 @@ class BlockSpaceManager:
         self.more_tables: Dict[int, List[BlockTable]] = {}
         # thread-safe: as `more_tables`.
         self.first_blocks: Dict[int, List[int]] = {}
+        # State slots: the free ids, each sequence's, and the
+        # (parent's, child's) of forks whose device copy is still to
+        # be scheduled.
+        self.num_state_slots = num_state_slots
+        # thread-safe: written where `block_tables` is and nowhere
+        # else, so the same sequencing by the engine loop holds.
+        self._free_state_slots: List[int] = list(
+            range(num_state_slots or 0))[::-1]
+        # thread-safe: as `_free_state_slots`.
+        self.state_slots: Dict[int, int] = {}
+        # thread-safe: as `_free_state_slots`.
+        self._state_copies: List[Tuple[int, int]] = []
 
     @property
     def gpu_allocator(self) -> BlockPool:
@@ -204,6 +230,15 @@ class BlockSpaceManager:
         # immediately force evictions.
         if self.num_total_gpu_blocks - needed < self.watermark_blocks:
             return AllocStatus.NEVER
+        if self.num_state_slots is not None:
+            # a slot for every sequence the group may come to hold
+            # (its forks take theirs later: `Scheduler` holds the
+            # running sequences under the number of slots)
+            seqs = seq_group.get_max_num_running_seqs()
+            if seqs > self.num_state_slots:
+                return AllocStatus.NEVER
+            if seqs > len(self._free_state_slots):
+                return AllocStatus.LATER
         if free - needed >= self.watermark_blocks + extra_reserved:
             return AllocStatus.OK
         return AllocStatus.LATER
@@ -270,6 +305,42 @@ class BlockSpaceManager:
                     self.block_tables[seq.seq_id] = block_table.copy()
                 else:
                     self.more_tables[seq.seq_id].append(block_table.copy())
+
+    # ------------------------------------------------------------------
+    # State slots
+    # ------------------------------------------------------------------
+
+    def assign_state(self, seq_group: SequenceGroup) -> None:
+        """A state slot for each sequence of a prompt being admitted,
+        beside the pages `allocate` gave it."""
+        for seq in seq_group.get_seqs(status=SequenceStatus.WAITING):
+            self._assign_state_slot(seq.seq_id)
+
+    def _assign_state_slot(self, seq_id: int) -> int:
+        if not self._free_state_slots:
+            raise ValueError("Out of state slots! The scheduler admits "
+                             "no more sequences than there are slots.")
+        slot = self.state_slots[seq_id] = self._free_state_slots.pop()
+        return slot
+
+    def _free_state_slot(self, seq_id: int) -> None:
+        slot = self.state_slots.pop(seq_id, None)
+        if slot is not None:
+            self._free_state_slots.append(slot)
+
+    def get_state_slot(self, seq: Sequence) -> Optional[int]:
+        """The sequence's state slot; None for a model without
+        state."""
+        return self.state_slots.get(seq.seq_id)
+
+    def get_num_free_state_slots(self) -> int:
+        return len(self._free_state_slots)
+
+    def take_state_copies(self) -> List[Tuple[int, int]]:
+        """The (from, to) slot copies that forks since the last call
+        need on the device before their children's first step."""
+        copies, self._state_copies = self._state_copies, []
+        return copies
 
     # ------------------------------------------------------------------
     # The tables follow the sequence: window release, new pages, CoW
@@ -419,6 +490,10 @@ class BlockSpaceManager:
         self.block_tables[child_seq.seq_id] = src_block_table.copy()
         for block in src_block_table:
             block.ref_count += 1
+        if self.num_state_slots is not None:
+            self._state_copies.append(
+                (self.state_slots[parent_seq.seq_id],
+                 self._assign_state_slot(child_seq.seq_id)))
         if self.plain:
             return
         more = [t.copy() for t in self.more_tables[parent_seq.seq_id]]
@@ -541,6 +616,7 @@ class BlockSpaceManager:
         self._free_block_table(self.block_tables.pop(seq.seq_id))
         self._free_group_tables(self.more_tables.pop(seq.seq_id, []))
         self.first_blocks.pop(seq.seq_id, None)
+        self._free_state_slot(seq.seq_id)
 
     def free_prefix(self, prefix: Prefix) -> int:
         """Release a prefix's pin: the one refcount `allocate` added
@@ -566,6 +642,10 @@ class BlockSpaceManager:
         self.block_tables.clear()
         self.more_tables.clear()
         self.first_blocks.clear()
+        self._free_state_slots = list(
+            range(self.num_state_slots or 0))[::-1]
+        self.state_slots.clear()
+        self._state_copies.clear()
 
     def get_block_table(self, seq: Sequence) -> List[int]:
         return [b.block_number for b in self.block_tables[seq.seq_id]]

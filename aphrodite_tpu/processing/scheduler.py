@@ -77,6 +77,7 @@ class SchedulerOutputs:
         blocks_to_swap_out: Dict[int, int],
         blocks_to_copy: Dict[int, List[int]],
         ignored_seq_groups: List[SequenceGroup],
+        state_copies: Optional[List[Tuple[int, int]]] = None,
     ) -> None:
         self.prompt_chunks = prompt_chunks
         self.decode_groups = decode_groups
@@ -85,6 +86,8 @@ class SchedulerOutputs:
         self.blocks_to_swap_in = blocks_to_swap_in
         self.blocks_to_swap_out = blocks_to_swap_out
         self.blocks_to_copy = blocks_to_copy
+        #: (from, to) state slots of forks, copied on the device first
+        self.state_copies = state_copies or []
         # Structural invariant: a step never swaps both directions.
         assert not (blocks_to_swap_in and blocks_to_swap_out)
         self.ignored_seq_groups = ignored_seq_groups
@@ -115,7 +118,8 @@ class SchedulerOutputs:
         # Ignored groups still produce outputs but schedule no device work.
         return (not self.prompt_chunks and not self.decode_groups
                 and not self.blocks_to_swap_in
-                and not self.blocks_to_swap_out and not self.blocks_to_copy)
+                and not self.blocks_to_swap_out and not self.blocks_to_copy
+                and not self.state_copies)
 
 
 class Scheduler:
@@ -161,7 +165,13 @@ class Scheduler:
             num_cpu_blocks=cache_config.num_cpu_blocks,
             sliding_window=groups.window,
             group_kinds=groups.kinds,
-            max_chunk_tokens=self.window_chunk_cap)
+            max_chunk_tokens=self.window_chunk_cap,
+            num_state_slots=cache_config.num_state_slots)
+        #: the most sequences admitted: a model with recurrent state
+        #: has a slot for each, forks included
+        self.max_num_seqs = min(
+            scheduler_config.max_num_seqs,
+            cache_config.num_state_slots or scheduler_config.max_num_seqs)
         self.prefix_pool = PrefixPool(cache_config.block_size)
 
         # thread-safe: two-world by sequencing, not locking — the
@@ -442,8 +452,7 @@ class Scheduler:
                 break
 
             num_new_seqs = group.get_max_num_running_seqs()
-            if (num_curr_seqs + num_new_seqs >
-                    self.scheduler_config.max_num_seqs):
+            if num_curr_seqs + num_new_seqs > self.max_num_seqs:
                 break
 
             new_seq_lens = seq_lens + [n]
@@ -651,8 +660,7 @@ class Scheduler:
                 if not self.block_manager.can_swap_in(seq_group):
                     break
                 num_new_seqs = seq_group.get_max_num_running_seqs()
-                if (num_curr_seqs + num_new_seqs >
-                        self.scheduler_config.max_num_seqs):
+                if num_curr_seqs + num_new_seqs > self.max_num_seqs:
                     break
                 if lora_int_id > 0:
                     curr_loras.add(lora_int_id)
@@ -761,6 +769,8 @@ class Scheduler:
         block_tables: Dict[int, List[int]] = {}
         persistent_data: Dict[int, dict] = {}
         group_tables = None if self.block_manager.plain else {}
+        state_slots = None \
+            if self.block_manager.num_state_slots is None else {}
         for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
             seq_data[seq.seq_id] = seq.data
             block_tables[seq.seq_id] = (
@@ -769,6 +779,9 @@ class Scheduler:
             if group_tables is not None:
                 group_tables[seq.seq_id] = \
                     self.block_manager.get_group_tables(seq)
+            if state_slots is not None:
+                state_slots[seq.seq_id] = \
+                    self.block_manager.get_state_slot(seq)
         return SequenceGroupMetadata(
             request_id=seq_group.request_id,
             is_prompt=is_prompt,
@@ -782,6 +795,7 @@ class Scheduler:
             chunk_len=chunk.length if chunk else None,
             is_final_chunk=chunk.is_final if chunk else True,
             group_tables=group_tables,
+            state_slots=state_slots,
         )
 
     def schedule(
@@ -791,6 +805,8 @@ class Scheduler:
         self._round_ignored = []
         try:
             scheduler_outputs = self._schedule()
+            scheduler_outputs.state_copies = \
+                self.block_manager.take_state_copies()
             seq_group_metadata_list = [
                 self._group_metadata(c.group, is_prompt=True, chunk=c)
                 for c in scheduler_outputs.prompt_chunks
@@ -1010,6 +1026,9 @@ class Scheduler:
 
     def _allocate(self, seq_group: SequenceGroup) -> None:
         self.block_manager.allocate(seq_group)
+        if self.block_manager.num_state_slots is not None:
+            with self.tracer.span("cache.state_assign"):
+                self.block_manager.assign_state(seq_group)
         for seq in seq_group.get_seqs(status=SequenceStatus.WAITING):
             seq.status = SequenceStatus.RUNNING
 

@@ -31,10 +31,11 @@ def get_config(model: str,
     if cfg_json and _os.path.isfile(cfg_json):
         with open(cfg_json) as f:
             declared = _json.load(f).get("model_type", "").lower()
-        if declared in ("yi", "qwen", "smallthinker"):
-            from aphrodite_tpu.transformers_utils import configs
-            cls = {"yi": configs.YiConfig, "qwen": configs.QWenConfig,
-                   "smallthinker": configs.SmallThinkerConfig}[declared]
+        from aphrodite_tpu.transformers_utils import configs
+        cls = {"yi": configs.YiConfig, "qwen": configs.QWenConfig,
+               "smallthinker": configs.SmallThinkerConfig,
+               "phi4flash": configs.Phi4FlashConfig}.get(declared)
+        if cls is not None:
             return cls.from_pretrained(model, revision=revision)
     try:
         config = AutoConfig.from_pretrained(

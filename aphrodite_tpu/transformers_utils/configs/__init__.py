@@ -2,9 +2,12 @@
 model_type transformers doesn't ship (reference
 `aphrodite/transformers_utils/configs/`): loading them through these
 classes avoids trust_remote_code."""
+from aphrodite_tpu.transformers_utils.configs.phi4flash import (
+    Phi4FlashConfig)
 from aphrodite_tpu.transformers_utils.configs.qwen import QWenConfig
 from aphrodite_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
 from aphrodite_tpu.transformers_utils.configs.yi import YiConfig
 
-__all__ = ["QWenConfig", "SmallThinkerConfig", "YiConfig"]
+__all__ = ["Phi4FlashConfig", "QWenConfig", "SmallThinkerConfig",
+           "YiConfig"]

@@ -210,3 +210,100 @@ def test_kv_writers_compile(on_v5e):
     ids = on_v5e((cells,), I32)
     jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
         chunk, chunk, pages, pages, ids, ids, ids).compile()
+
+
+#: Phi-4-mini-flash's decode programs (`phi-4-mini-flash-bf16.reason-2k`):
+#: 40 query heads over 10 KV heads of 128, a differential pair of the
+#: model's 64-wide heads held as one head: head blocks of 5 (640 lanes),
+#: TWO of them, a head count no other cell has; the model's own scale
+#: 1/8. (rows, table width, window, fused write): the full layer's table
+#: at 2,049-3,072 tokens (192 wide) with the fused write; a cross
+#: layer's read-only call over the same pages; a window group's table
+#: (40 wide, 32-33 pages held under the window of 512); the canary's
+#: one row under and over 128 pages.
+FLASH_CASES = [
+    (64, 192, None, True), (64, 192, None, False), (64, 40, 512, True),
+    (1, 128, None, True), (1, 192, None, False), (1, 40, 512, True),
+]
+
+
+@pytest.mark.parametrize("B,pps,window,fused", FLASH_CASES)
+def test_decode_attention_compiles_at_phi4flash_shapes(
+        on_v5e, B, pps, window, fused):
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk, head_block,
+        lane_bytes_of, padded_work_length, paged_decode_attention)
+    Hq, Hkv, d, page = 40, 10, 128, 16
+    assert head_block(Hkv) == 5
+    ppc = choose_pages_per_chunk(pps, page, lane_bytes_of(Hkv, d, BF16))
+    assert ppc == 32
+    held = 33 if window else pps - 8
+    counts = [held - i % 2 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    # a pool of 6 GB in one pair of page arrays
+    pages = on_v5e((75000, page, Hkv * d), BF16)
+    new = on_v5e((B, Hkv, d), BF16)
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn if fused else None,
+            vn if fused else None, scale=0.125, pages_per_chunk=ppc,
+            work_items=work, amla=True, window=window)
+
+    jax.jit(attend, donate_argnums=(1, 2) if fused else ()).lower(
+        on_v5e((B, Hq, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
+def test_kv_writer_compiles_at_phi4flash_shapes(on_v5e):
+    """The prefill page writer for a chunk of 2,048 tokens into pages
+    of 10 KV heads x 128 lanes, once a page-holding layer."""
+    from aphrodite_tpu.ops.pallas.kv_write import (can_use_pallas_writer,
+                                                   write_kv_pages_prefill)
+    page, hd = 16, 10 * 128
+    assert can_use_pallas_writer(BF16, page, hd)
+    pages = on_v5e((75000, page, hd), BF16)
+    cells = 2048 // page
+    chunk = on_v5e((cells * page, hd), BF16)
+    ids = on_v5e((cells,), I32)
+    jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
+        chunk, chunk, pages, pages, ids, ids, ids).compile()
+
+
+#: the selective-scan kernels at Phi-4-mini-flash's widths: 5,120
+#: channels, 16 states, 128 state slots and the scratch one.
+_SSM = dict(n=16, ch=5120, slots=129)
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 2048), (1, 1024), (2, 512),
+                                         (1, 128)])
+def test_ssm_chunk_scan_compiles(on_v5e, rows, tokens):
+    """A prompt chunk's scan: channels in blocks of 512, time in blocks
+    of 256 with the state in VMEM, the slot's state aliased in place.
+    (A chunk under 128 tokens is padded to 128 by the dispatcher.)"""
+    from aphrodite_tpu.ops.pallas.ssm_scan import _ssm_scan_impl
+    n, ch, slots = _SSM["n"], _SSM["ch"], _SSM["slots"]
+    f32 = jnp.float32
+    seq, coeff = on_v5e((rows, tokens, ch), f32), \
+        on_v5e((rows, n, tokens), f32)
+    _ssm_scan_impl.lower(
+        seq, seq, coeff, coeff, on_v5e((n, ch), f32), on_v5e((1, ch), f32),
+        on_v5e((slots, n, ch), f32), on_v5e((rows,), I32),
+        on_v5e((rows,), I32)).compile()
+
+
+@pytest.mark.parametrize("rows", [64, 8, 1])
+def test_ssm_decode_update_compiles(on_v5e, rows):
+    """A decode step's update: a row's state and convolution tail by
+    its slot id, read, moved on and written in place."""
+    from aphrodite_tpu.ops.pallas.ssm_scan import _ssm_update_impl
+    n, ch, slots = _SSM["n"], _SSM["ch"], _SSM["slots"]
+    f32 = jnp.float32
+    row = on_v5e((rows, 1, ch), f32)
+    _ssm_update_impl.lower(
+        on_v5e((rows, 1, ch), BF16), row, row, on_v5e((n, rows), f32),
+        on_v5e((n, rows), f32), on_v5e((n, ch), f32), on_v5e((1, ch), f32),
+        on_v5e((slots, n, ch), f32), on_v5e((slots, 3, ch), BF16),
+        on_v5e((rows,), I32)).compile()

@@ -240,6 +240,147 @@ def main() -> int:
                   np.asarray(wk6, np.float32)[live6],
                   np.asarray(gk6, np.float32)[live6], tol=1e-6)
 
+    with section("decode attention (Phi-4-mini-flash: 10 KV heads, "
+                 "two head blocks)"):
+        # -- the decode kernel as `phi-4-mini-flash-bf16.reason-2k`
+        #    calls it: 40 query heads over 10 KV heads of 128 (a
+        #    differential pair of the model's 64-wide heads held as one
+        #    head; head blocks of 5, two of them), the model's own
+        #    scale 1/8, half of every query zeros, pages of 16, 64 rows.
+        #    The full layer's table 192 wide at contexts of 2,049-3,072
+        #    with the fused write; a window group's 40 wide holding
+        #    32-33 pages under the window of 512; a cross layer's call,
+        #    read-only over the full layer's pages. NaN in every page
+        #    no row holds. --
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list, choose_pages_per_chunk, lane_bytes_of)
+        from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+        hq7, hkv7, rows7, scale7 = 40, 10, 64, 0.125
+        for tag7, width7, window7, fused7 in (
+                ("full", 192, None, True), ("window", 40, 512, True),
+                ("cross", 192, None, False)):
+            ppc7 = choose_pages_per_chunk(
+                width7, 16, lane_bytes_of(hkv7, d, jnp.bfloat16))
+            whole = rs.randint(2049, 3073, (rows7,)).astype(np.int32)
+            whole[:2] = (2049, 3072)
+            let_go = np.maximum(0, whole - window7) // 16 * 16 \
+                if window7 else np.zeros_like(whole)
+            ctx7 = whole - let_go
+            cnt7 = -(-ctx7 // 16)
+            tbl7 = np.zeros((rows7, width7), np.int32)
+            used7 = 1
+            for i, n in enumerate(cnt7):
+                tbl7[i, :n] = np.arange(used7, used7 + n)
+                used7 += n
+            raw7 = rs.randn(used7 + 4, 16, hkv7 * d) * 0.3
+            kv7 = [jnp.asarray(raw7, jnp.bfloat16),
+                   jnp.asarray(raw7[::-1], jnp.bfloat16)]
+            q7 = rs.randn(rows7, hq7, d) * 0.3
+            q7[:, 0::2, 64:] = 0.0          # [q1 ; 0]
+            q7[:, 1::2, :64] = 0.0          # [0 ; q2]
+            q7 = jnp.asarray(q7, jnp.bfloat16)
+            new7 = [jnp.asarray(rs.randn(rows7, hkv7, d) * 0.3,
+                                jnp.bfloat16) for _ in range(2)]
+            wk7, wv7 = kv7
+            if fused7:
+                slots7 = jnp.asarray(
+                    tbl7[np.arange(rows7), (ctx7 - 1) // 16] * 16 +
+                    (ctx7 - 1) % 16)
+                wk7, wv7 = write_to_kv_cache(new7[0], new7[1], kv7[0],
+                                             kv7[1], slots7)
+            ref7 = np.asarray(paged_decode_attention_ref(
+                q7, wk7, wv7, jnp.asarray(tbl7), jnp.asarray(ctx7), scale7,
+                window=window7), np.float32)
+            dead7 = np.ones(used7 + 4, bool)
+            dead7[1:used7] = False
+            kv7 = [x.at[jnp.asarray(np.flatnonzero(dead7))].set(jnp.nan)
+                   for x in kv7]
+            got7 = paged_decode_attention(
+                q7, kv7[0], kv7[1], jnp.asarray(tbl7), jnp.asarray(ctx7),
+                None, new7[0] if fused7 else None,
+                new7[1] if fused7 else None, scale=scale7,
+                pages_per_chunk=ppc7,
+                work_items=build_decode_work_list(cnt7, ppc7),
+                window=window7)
+            if fused7:
+                got7, gk7, _ = got7
+                check(f"{tag7} layer, the fused write's pages",
+                      np.asarray(wk7, np.float32)[~dead7],
+                      np.asarray(gk7, np.float32)[~dead7], tol=1e-6)
+            check(f"{tag7} layer, table {width7}, ppc={ppc7}", ref7,
+                  np.asarray(got7, np.float32))
+
+    with section("selective scan (Phi-4-mini-flash: chunk scan, decode "
+                 "update)"):
+        # -- `ops/pallas/ssm_scan.py` at the served shape: 5,120
+        #    channels, 16 states, float32. A prompt chunk of 2,048
+        #    tokens from zeros, and the same tokens as two chunks of
+        #    1,024 through the slot, against the jnp scan; a decode
+        #    step of 64 rows (60 live on scattered slots, 4 pad rows on
+        #    the scratch slot) against the jnp update, NaN in every
+        #    slot no row holds. --
+        from aphrodite_tpu.ops.pallas import ssm_scan as ssm
+        n8, ch8, slots8, t8 = 16, 5120, 128, 2048
+        f8 = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.float32)
+        u8, b8, c8 = f8(1, t8, ch8), f8(1, t8, n8), f8(1, t8, n8)
+        dl8 = jax.nn.softplus(f8(1, t8, ch8) - 4.0)
+        a8 = -jnp.exp(jnp.asarray(rs.uniform(-1.5, 1.5, (n8, ch8)),
+                                  jnp.float32))
+        d8 = jnp.asarray(rs.uniform(0, 0.5, (ch8,)), jnp.float32)
+        st8 = jnp.full((slots8 + 1, n8, ch8), jnp.nan, jnp.float32)
+        one, yes = jnp.asarray([7], jnp.int32), jnp.asarray([1], jnp.int32)
+        y_ref, s_ref = ssm.ssm_scan_ref(u8, dl8, b8, c8, a8, d8, st8, one,
+                                        yes)
+        y_got, s_got = ssm.selective_scan(u8, dl8, b8, c8, a8, d8, st8,
+                                          one, yes)
+        check("chunk scan, 2,048 tokens", np.asarray(y_ref),
+              np.asarray(y_got), tol=1e-4)
+        check("chunk scan, the slot's state", np.asarray(s_ref)[7],
+              np.asarray(s_got)[7], tol=1e-4)
+        half = t8 // 2
+        y1, s1 = ssm.selective_scan(
+            u8[:, :half], dl8[:, :half], b8[:, :half], c8[:, :half], a8, d8,
+            st8, one, yes)
+        y2, s2 = ssm.selective_scan(
+            u8[:, half:], dl8[:, half:], b8[:, half:], c8[:, half:], a8, d8,
+            s1, one, 0 * yes)
+        check("two chunks through the slot",
+              np.asarray(y_ref), np.concatenate([y1, y2], axis=1),
+              tol=1e-4)
+        if not np.isnan(np.asarray(s2)[[0, 6, 8, slots8]]).all():
+            failures.append(("ssm scan touched a slot no row holds", 0))
+        rows8, live8 = 64, 60
+        owners = rs.permutation(slots8)[:live8]
+        slot8 = np.full((rows8,), slots8, np.int32)
+        slot8[:live8] = owners
+        held = np.zeros(slots8 + 1, bool)
+        held[owners] = True
+        held[slots8] = True
+        st9 = jnp.where(held[:, None, None], f8(slots8 + 1, n8, ch8),
+                        jnp.nan)
+        tl9 = jnp.where(held[:, None, None],
+                        f8(slots8 + 1, 3, ch8), jnp.nan).astype(jnp.bfloat16)
+        x9 = f8(rows8, ch8).astype(jnp.bfloat16)
+        u9, b9, c9 = f8(rows8, ch8), f8(rows8, n8), f8(rows8, n8)
+        dl9 = jax.nn.softplus(f8(rows8, ch8) - 4.0)
+        want = ssm.ssm_update_ref(x9, u9, dl9, b9, c9, a8, d8, st9, tl9,
+                                  jnp.asarray(slot8))
+        got = ssm.selective_update(x9, u9, dl9, b9, c9, a8, d8, st9, tl9,
+                                   jnp.asarray(slot8))
+        check("decode update, the rows' outputs",
+              np.asarray(want[0])[:live8], np.asarray(got[0])[:live8],
+              tol=1e-4)
+        check("decode update, the live slots' state",
+              np.asarray(want[1])[owners], np.asarray(got[1])[owners],
+              tol=1e-4)
+        check("decode update, the live slots' tail",
+              np.asarray(want[2], np.float32)[owners],
+              np.asarray(got[2], np.float32)[owners], tol=1e-6)
+        free = ~held
+        if not (np.isnan(np.asarray(got[1])[free]).all() and
+                np.isnan(np.asarray(got[2], np.float32)[free]).all()):
+            failures.append(("ssm update touched a slot no row holds", 0))
+
     with section("decode attention (padded heads)"):
         # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --
         for d_true in (64, 80):

@@ -52,6 +52,7 @@ import collections
 import dataclasses
 import json
 import os
+import weakref
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -400,10 +401,32 @@ def keyword_arg(call: ast.Call, name: str) -> Optional[ast.AST]:
     return None
 
 
+#: walk_nodes() memo: the passes walk the same few thousand function
+#: bodies again and again (20,000 walks a sweep for their calls alone
+#: were a quarter of its runtime budget); a tree is never mutated
+#: after parsing, and the weak keys die with their Module
+_NODES_UNDER: "weakref.WeakKeyDictionary[ast.AST, Tuple[ast.AST, ...]]" \
+    = weakref.WeakKeyDictionary()
+_CALLS_UNDER: "weakref.WeakKeyDictionary[ast.AST, Tuple[ast.Call, ...]]" \
+    = weakref.WeakKeyDictionary()
+
+
+def walk_nodes(root: ast.AST) -> Tuple[ast.AST, ...]:
+    """`ast.walk(root)`, in its order, walked once a root."""
+    nodes = _NODES_UNDER.get(root)
+    if nodes is None:
+        nodes = tuple(ast.walk(root))
+        _NODES_UNDER[root] = nodes
+    return nodes
+
+
 def iter_calls(root: ast.AST) -> Iterable[ast.Call]:
-    for node in ast.walk(root):
-        if isinstance(node, ast.Call):
-            yield node
+    calls = _CALLS_UNDER.get(root)
+    if calls is None:
+        calls = tuple(node for node in walk_nodes(root)
+                      if isinstance(node, ast.Call))
+        _CALLS_UNDER[root] = calls
+    return calls
 
 
 def assignments_of(scope: ast.AST, name: str,

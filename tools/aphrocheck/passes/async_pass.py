@@ -53,7 +53,8 @@ from typing import Dict, List, Optional, Set
 
 from tools.aphrocheck.core import (EVENT_LOOP, Finding, Module,
                                    call_tail, dotted_name, has_pragma,
-                                   paths_conflict, tail_name)
+                                   paths_conflict, tail_name,
+                                   walk_nodes)
 
 #: Scope: the layers between a client connection and the step thread,
 #: plus the fleet router — pure event-loop code where one blocked
@@ -112,12 +113,12 @@ def _awaited_wait_names(fn: ast.AST) -> Set[str]:
     """Names passed into `asyncio.wait(...)` / `asyncio.wait_for(...)`
     within `fn` — futures known resolved before `.result()` reads."""
     out: Set[str] = set()
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.Call) and \
                 dotted_name(node.func) in ("asyncio.wait",
                                            "asyncio.wait_for"):
             for arg in node.args:
-                for inner in ast.walk(arg):
+                for inner in walk_nodes(arg):
                     if isinstance(inner, ast.Name):
                         out.add(inner.id)
     return out
@@ -186,7 +187,7 @@ def _toctou_findings(module: Module, fn: ast.AsyncFunctionDef
     coroutine (branch-compatible occurrences only)."""
     # only nodes whose nearest enclosing function IS this coroutine
     # (a nested def's awaits/attribute traffic is its own analysis)
-    direct = [n for n in ast.walk(fn)
+    direct = [n for n in walk_nodes(fn)
               if module.enclosing_function(n) is fn]
     awaits = [n for n in direct if isinstance(n, ast.Await)]
     if not awaits:
@@ -296,7 +297,7 @@ def run(ctx) -> List[Finding]:
                 locky = any(_looks_like_lock(item.context_expr)
                             for item in node.items)
                 if locky and any(isinstance(n, ast.Await)
-                                 for n in ast.walk(node)) and \
+                                 for n in walk_nodes(node)) and \
                         not has_pragma(module, node.lineno, _PRAGMA):
                     findings.append(module.finding(
                         "ASYNC004", node,

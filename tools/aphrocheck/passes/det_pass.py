@@ -63,7 +63,7 @@ from typing import Dict, Iterator, List, Optional, Set, Tuple
 
 from tools.aphrocheck.core import (Finding, Module, assignments_of,
                                    call_tail, dotted_name, has_pragma,
-                                   keyword_arg)
+                                   keyword_arg, walk_nodes)
 
 BASELINE_FILE = "REPLAYPLAN.json"
 
@@ -223,7 +223,7 @@ def _commits_state(loop: ast.For) -> bool:
     named helper, a `self.`-rooted container verb, or a store through
     a `self.`-rooted attribute/subscript."""
     for stmt in loop.body + loop.orelse:
-        for node in ast.walk(stmt):
+        for node in walk_nodes(stmt):
             if isinstance(node, ast.Call):
                 t = call_tail(node) or ""
                 if t in _COMMIT_TAILS or \
@@ -317,7 +317,7 @@ def _tuple_unpacked_from_derive(scope: ast.AST, name: str) -> bool:
     """`key_u, key_r = jax.random.split(key)` — assignments_of only
     indexes Name targets, so the threaded check scans Tuple targets
     here."""
-    for node in ast.walk(scope):
+    for node in walk_nodes(scope):
         if not isinstance(node, ast.Assign):
             continue
         for tgt in node.targets:
@@ -413,11 +413,11 @@ def _nondet_in(root: ast.AST) -> Optional[Tuple[ast.AST, str]]:
     lookup (the decision value is the score, not the address), so
     anything inside a Subscript slice is exempt."""
     lookup_keys: Set[int] = set()
-    for node in ast.walk(root):
+    for node in walk_nodes(root):
         if isinstance(node, ast.Subscript):
-            for sub in ast.walk(node.slice):
+            for sub in walk_nodes(node.slice):
                 lookup_keys.add(id(sub))
-    for node in ast.walk(root):
+    for node in walk_nodes(root):
         if id(node) in lookup_keys:
             continue
         what = _nondet_value(node)
@@ -490,7 +490,7 @@ def _seam_functions(module: Module
 def _ephemera_reads(module: Module, fn: ast.AST
                     ) -> Iterator[Tuple[ast.AST, str]]:
     seen: Set[int] = set()
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         what = None
         if isinstance(node, ast.Attribute) and \
                 isinstance(node.ctx, ast.Load) and \

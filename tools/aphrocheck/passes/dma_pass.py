@@ -34,7 +34,8 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from tools.aphrocheck.core import (Finding, IntervalEvaluator, Module,
                                    dotted_name, iter_calls,
-                                   paths_conflict, tail_name)
+                                   paths_conflict, tail_name,
+                                   walk_nodes)
 from tools.aphrocheck.sites import (find_sites, list_elements,
                                     resolve, resolve_kernel_functions)
 
@@ -85,7 +86,7 @@ class _Kernel:
         self.bases: Set[str] = set(
             filter(None, (_constructor_base(c) for c in self.ctors)))
         self.local_fns: Dict[str, ast.AST] = {
-            n.name: n for n in ast.walk(fn)
+            n.name: n for n in walk_nodes(fn)
             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))}
 
     def _bases_of_expr(self, node: ast.AST, depth: int = 0
@@ -114,7 +115,7 @@ class _Kernel:
         if isinstance(node, ast.Name):
             out = set()
             found = False
-            for n in ast.walk(self.fn):
+            for n in walk_nodes(self.fn):
                 if isinstance(n, ast.Assign):
                     for tgt in n.targets:
                         if isinstance(tgt, ast.Name) and \
@@ -156,7 +157,7 @@ class _Kernel:
         if isinstance(node, ast.Name):
             out = []
             # assignments to the name
-            for n in ast.walk(self.fn):
+            for n in walk_nodes(self.fn):
                 if isinstance(n, ast.Assign):
                     for tgt in n.targets:
                         if isinstance(tgt, ast.Name) and \

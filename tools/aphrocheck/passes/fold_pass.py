@@ -39,7 +39,7 @@ import ast
 from typing import Dict, List, Optional, Set, Tuple
 
 from tools.aphrocheck.core import (Finding, Module, has_pragma,
-                                   iter_calls, tail_name)
+                                   iter_calls, tail_name, walk_nodes)
 from tools.aphrocheck.passes.roofline_pass import PRAGMA
 from tools.aphrocheck.sites import find_sites, resolve_kernel_functions
 
@@ -61,7 +61,7 @@ _MIN_CHAIN = 2
 
 def _assigns_in_order(module: Module, scope: ast.AST
                       ) -> List[ast.Assign]:
-    return sorted((n for n in ast.walk(scope)
+    return sorted((n for n in walk_nodes(scope)
                    if isinstance(n, ast.Assign)),
                   key=lambda n: n.lineno)
 
@@ -206,7 +206,7 @@ def _helper_chain_return(ctx, module: Module, call: ast.Call
     if name is None or ctx.call_graph is None:
         return None
     for mod, fn in ctx.call_graph.functions_named(name):
-        for node in ast.walk(fn):
+        for node in walk_nodes(fn):
             if not isinstance(node, ast.Return) or node.value is None:
                 continue
             values = node.value.elts if isinstance(
@@ -223,7 +223,7 @@ def _breaks_adjacency(node: ast.AST, launch_ids: Set[int]) -> bool:
     the chain — a matmul or a non-elementwise call (reshape,
     hadamard helpers, gathers) — after which folding into the kernel
     epilogue is no longer the rewrite."""
-    for sub in ast.walk(node):
+    for sub in walk_nodes(node):
         if isinstance(sub, ast.BinOp) and \
                 isinstance(sub.op, ast.MatMult):
             return True
@@ -267,11 +267,11 @@ def _fold001(ctx, findings: List[Finding],
             targets = _assign_targets(node)
             if not targets:
                 continue
-            reads = {n.id for n in ast.walk(node.value)
+            reads = {n.id for n in walk_nodes(node.value)
                      if isinstance(n, ast.Name)}
             contains_launch = any(
                 isinstance(c, ast.Call) and id(c) in launch_ids
-                for c in ast.walk(node.value))
+                for c in walk_nodes(node.value))
             if contains_launch:
                 derived.update(targets)
                 continue
@@ -349,7 +349,7 @@ def _fold002_kernel(module: Module, fn: ast.AST,
                     findings: List[Finding],
                     honor_pragmas: bool) -> None:
     matches: List[ast.Assign] = []
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if not isinstance(node, ast.Assign):
             continue
         value = node.value

@@ -52,7 +52,8 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from tools.aphrocheck.core import (Finding, Module, call_tail,
                                    dotted_name, has_pragma,
-                                   paths_conflict, tail_name)
+                                   paths_conflict, tail_name,
+                                   walk_nodes)
 
 #: The page-owner modules: the only places block internals may be
 #: touched (OWN001/002 enforce the outside; LEAK rules audit the
@@ -172,7 +173,7 @@ def _storing_methods(ctx) -> Dict[str, str]:
             args = fn.args
             params = {a.arg for a in args.posonlyargs + args.args +
                       args.kwonlyargs} - {"self", "cls"}
-            for node in ast.walk(fn):
+            for node in walk_nodes(fn):
                 if not isinstance(node, ast.Assign):
                     continue
                 src = node.value
@@ -214,7 +215,7 @@ def _free_helpers(ctx) -> Set[str]:
                 continue
             derived = set(params)
             calls: List[Tuple[str, str]] = []
-            for node in ast.walk(fn):
+            for node in walk_nodes(fn):
                 if isinstance(node, ast.For) and \
                         isinstance(node.target, ast.Name):
                     src = node.iter
@@ -256,7 +257,7 @@ def _loop_container(module: Module, fn: ast.AST,
                     name_node: ast.Name) -> Optional[str]:
     """Container key of the loop a Name is the target of, resolving a
     Name iterable through its local assignment one level."""
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.For) and \
                 isinstance(node.target, ast.Name) and \
                 node.target.id == name_node.id:
@@ -273,7 +274,7 @@ def _loop_container(module: Module, fn: ast.AST,
 
 def _local_sources(fn: ast.AST, name: str) -> List[ast.AST]:
     out = []
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.Assign):
             for tgt in node.targets:
                 if isinstance(tgt, ast.Name) and tgt.id == name:
@@ -293,7 +294,7 @@ def _local_container_keys(module: Module, fn: ast.AST, local: str,
         key = _container_key(value)
         if key in OWNED_TABLES or key == "block_table":
             keys.add(key)
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.Assign):
             src = node.value
             if isinstance(src, ast.Call) and call_tail(src) == "copy" \
@@ -358,7 +359,7 @@ def _block_destinations(module: Module, fn: ast.AST, name: str,
             return dests
     dests = _stores_of_name(module, fn, fn, name, storing)
     loop_key = None
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.For) and \
                 isinstance(node.target, ast.Name) and \
                 node.target.id == name:
@@ -385,7 +386,7 @@ def _stores_of_name(module: Module, fn: ast.AST, root: ast.AST,
     """Append/subscript-store/storing-call destinations of `name`
     within `root` (container locals resolved across the whole fn)."""
     dests: Set[str] = set()
-    for node in ast.walk(root):
+    for node in walk_nodes(root):
         if isinstance(node, ast.Call):
             t = call_tail(node)
             takes = any(isinstance(a, ast.Name) and a.id == name
@@ -429,7 +430,7 @@ def _free_seams(ctx, helpers: Set[str]) -> List[FreeSeam]:
         for fn in _fns(module):
             where = f"{module.rel.replace(chr(92), '/')}::" \
                     f"{_qualname(module, fn)}"
-            for call in ast.walk(fn):
+            for call in walk_nodes(fn):
                 if not isinstance(call, ast.Call):
                     continue
                 if call_tail(call) not in helpers or not call.args:
@@ -527,7 +528,7 @@ def _name_sinks(module: Module, fn: ast.AST, name: str,
     """Uses of `name` that settle ownership: stored into a container,
     freed, returned, or handed to a same-package function."""
     sinks: List[ast.AST] = []
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.Call):
             t = call_tail(node)
             takes = any(isinstance(a, ast.Name) and a.id == name
@@ -542,7 +543,7 @@ def _name_sinks(module: Module, fn: ast.AST, name: str,
                     if isinstance(tgt, (ast.Subscript, ast.Attribute)):
                         sinks.append(node)
         elif isinstance(node, ast.Return) and node.value is not None:
-            for sub in ast.walk(node.value):
+            for sub in walk_nodes(node.value):
                 if isinstance(sub, ast.Name) and sub.id == name:
                     sinks.append(node)
                     break
@@ -553,7 +554,7 @@ def _leak001(ctx, module: Module, model: OwnershipModel,
              resolvable: Set[str]) -> List[Finding]:
     findings: List[Finding] = []
     for fn in _fns(module):
-        for call in ast.walk(fn):
+        for call in walk_nodes(fn):
             if not (isinstance(call, ast.Call) and _is_alloc_call(call)):
                 continue
             if has_pragma(module, call.lineno, _PRAGMA):
@@ -615,7 +616,7 @@ def _leak001(ctx, module: Module, model: OwnershipModel,
             start = body.index(alloc_stmt)
             for stmt in body[start + 1:first]:
                 hazard = None
-                for sub in ast.walk(stmt):
+                for sub in walk_nodes(stmt):
                     if isinstance(sub, ast.Call):
                         recv = _recv_tail(sub)
                         if recv == name:
@@ -651,7 +652,7 @@ def _leak002(ctx, module: Module, model: OwnershipModel) -> List[Finding]:
     for fn in _fns(module):
         if fn.name in ("__init__", "__post_init__"):
             continue
-        for node in ast.walk(fn):
+        for node in walk_nodes(fn):
             if isinstance(node, ast.AugAssign) and \
                     isinstance(node.op, ast.Add):
                 recv = _refcount_target(node.target)
@@ -748,7 +749,7 @@ def _leak003(ctx, module: Module, model: OwnershipModel) -> List[Finding]:
     findings: List[Finding] = []
     for fn in _fns(module):
         frees: List[Tuple[str, ast.Call, tuple, list, int]] = []
-        for call in ast.walk(fn):
+        for call in walk_nodes(fn):
             if isinstance(call, ast.Call) and \
                     call_tail(call) in model.helpers and call.args and \
                     isinstance(call.args[0], ast.Name):
@@ -757,7 +758,7 @@ def _leak003(ctx, module: Module, model: OwnershipModel) -> List[Finding]:
                               module.branch_path(call), body, idx))
         if not frees:
             continue
-        for node in ast.walk(fn):
+        for node in walk_nodes(fn):
             use_kind = None
             name = None
             if isinstance(node, ast.Call):
@@ -826,7 +827,7 @@ def _reads_table_before(fn: ast.AST, attr: str,
     the iterate-free (reset) or capture-and-return (PrefixPool.clear)
     idioms. Strictly earlier: the clear call's own receiver load must
     not satisfy this."""
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, ast.Attribute) and node.attr == attr and \
                 isinstance(node.ctx, ast.Load) and \
                 getattr(node, "lineno", 0) < before_line:
@@ -839,7 +840,7 @@ def _leak004(ctx, module: Module, model: OwnershipModel) -> List[Finding]:
     for fn in _fns(module):
         if fn.name in ("__init__", "__post_init__"):
             continue
-        for node in ast.walk(fn):
+        for node in walk_nodes(fn):
             if isinstance(node, ast.Delete):
                 for tgt in node.targets:
                     key = _container_key(tgt)

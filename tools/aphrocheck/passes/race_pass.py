@@ -54,7 +54,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from tools.aphrocheck.core import (EVENT_LOOP, STEP_THREAD, Finding,
                                    Module, call_tail, has_pragma,
-                                   tail_name)
+                                   tail_name, walk_nodes)
 
 _HOT_PREFIXES = ("aphrodite_tpu/engine/", "aphrodite_tpu/endpoints/",
                  "aphrodite_tpu/processing/", "aphrodite_tpu/fleet/")
@@ -128,7 +128,7 @@ def _attr_writes(module: Module, fn: ast.AST
                  ) -> List[Tuple[str, ast.AST]]:
     """(attr, node) for every `self.X` write in one method body."""
     out: List[Tuple[str, ast.AST]] = []
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, (ast.Assign, ast.AnnAssign, ast.AugAssign)):
             targets = node.targets if isinstance(node, ast.Assign) \
                 else [node.target]
@@ -219,10 +219,10 @@ def _defs_of(module: Module) -> Dict[str, List[ast.AST]]:
 
 
 def _has_epoch_compare(fn: ast.AST) -> bool:
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if not isinstance(node, ast.Compare):
             continue
-        for sub in ast.walk(node):
+        for sub in walk_nodes(node):
             if isinstance(sub, ast.Attribute) and \
                     "epoch" in sub.attr:
                 return True
@@ -237,7 +237,7 @@ def _has_epoch_compare(fn: ast.AST) -> bool:
 
 def _rotates_epoch(fn: ast.AST) -> bool:
     """The epoch-rotation point (reincarnate) writes `_epoch` itself."""
-    for node in ast.walk(fn):
+    for node in walk_nodes(fn):
         if isinstance(node, (ast.Assign, ast.AugAssign)):
             targets = node.targets if isinstance(node, ast.Assign) \
                 else [node.target]
@@ -260,11 +260,11 @@ def _race002(ctx, module: Module, guarded_names: Set[str]
                 continue
             if _has_epoch_compare(fn) or _rotates_epoch(fn):
                 continue
-            called = {call_tail(c) for c in ast.walk(fn)
+            called = {call_tail(c) for c in walk_nodes(fn)
                       if isinstance(c, ast.Call)}
             if called & guarded_names:
                 continue
-            for call in ast.walk(fn):
+            for call in walk_nodes(fn):
                 if not isinstance(call, ast.Call):
                     continue
                 f = call.func
@@ -319,7 +319,7 @@ def _race003(ctx, module: Module) -> List[Finding]:
         domains = cg.domains_of(node)
         if not domains:
             continue
-        for inner in ast.walk(node):
+        for inner in walk_nodes(node):
             name = None
             is_write = False
             if isinstance(inner, ast.Name) and inner.id in mutables:
