@@ -40,7 +40,11 @@ round-6 "ragged work-list" grid):
   combine pass. The classic padded (batch, n_hb) grid remains below
   and is selected by APHRODITE_ATTN_RAGGED=0 or by calling without
   work_items.
-- Head blocks: hb = min(8, largest divisor of Hkv). The cell's hb
+- Head blocks: hb = Hkv, the page's whole lane axis (one contiguous
+  descriptor a page, a page visited once), while the read ring
+  affords four slots of a 384-token item (`head_block`: up to ten bf16
+  heads of 128, up to 21 of 8-bit pages); past that the largest
+  divisor of Hkv that is <= 8, each block a lane slice. The cell's hb
   kv-heads ride as a LANE block: scores come from one MXU dot
   [group*hb, hb*d] x [hb*d, chunk] where q is packed block-diagonally
   (row r holds q in its own head's d lanes, zeros elsewhere) —
@@ -151,6 +155,12 @@ _RING_BUDGET_BYTES = 8 * 1024 * 1024
 _MAX_ITEM_TOKENS = 512
 _MIN_RING_SLOTS = 4
 
+# The shortest item whose copies still take longer than the cell's
+# instruction stream (its fixed cost, its descriptors' issue time and
+# its arithmetic; PERF.md §5): a head block is the page's whole lane
+# axis as long as the ring affords such an item (head_block).
+_MIN_WHOLE_ITEM_TOKENS = 384
+
 # Ragged work-list length buckets (each distinct padded length is one
 # compiled program — same power-of-two-and-a-half spacing rationale
 # as the decode batch buckets in executor/model_runner.py).
@@ -175,10 +185,28 @@ def ragged_enabled() -> bool:
     return flags.get_bool("APHRODITE_ATTN_RAGGED")
 
 
-def head_block(num_kv_heads: int) -> int:
-    """Largest divisor of H that is <= 8: the per-grid-cell head count.
-    8 bounds the q-packing redundancy (scores cost hb x the minimal
-    FLOPs, on an otherwise idle MXU) and the VMEM chunk footprint."""
+def _item_tokens(lane_bytes: int) -> int:
+    """Tokens of the largest work item that leaves the read ring
+    `_MIN_RING_SLOTS` slots of `lane_bytes` a token (K and V) inside
+    its budget: a multiple of 128, so the score tile keeps whole
+    lanes, up to `_MAX_ITEM_TOKENS`."""
+    tokens = _RING_BUDGET_BYTES // (_MIN_RING_SLOTS * 2 * lane_bytes)
+    return min(max(tokens // 128 * 128, 128), _MAX_ITEM_TOKENS)
+
+
+def head_block(num_kv_heads: int, head_dim: int, dtype) -> int:
+    """The KV heads a grid cell holds. All of them, so that the block
+    is the page's whole lane axis (every copy one contiguous page, a
+    page visited once, a row's packed query built once), whenever the
+    ring still affords `_MIN_RING_SLOTS` slots of an item of
+    `_MIN_WHOLE_ITEM_TOKENS`: up to ten bf16 heads of 128 lanes, up to
+    21 of 8-bit pages. Past that the largest divisor of H that is
+    <= 8, each block a lane slice of the page (16 and 32 bf16 heads:
+    blocks of 8). `head_dim` is the pages' (padded) head size. Scores
+    cost hb x the minimal FLOPs, on an otherwise idle MXU."""
+    lanes = num_kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    if _item_tokens(lanes) >= _MIN_WHOLE_ITEM_TOKENS:
+        return num_kv_heads
     for hb in (8, 7, 6, 5, 4, 3, 2):
         if num_kv_heads % hb == 0:
             return hb
@@ -190,7 +218,8 @@ def lane_bytes_of(num_kv_heads: int, head_dim: int, dtype) -> int:
     width of a ring slot and of a page copy, in bytes. `head_dim` is
     the pages' (padded) head size. It sizes the ring (`_ring_slots`)
     and the work item (`choose_pages_per_chunk`)."""
-    return head_block(num_kv_heads) * head_dim * jnp.dtype(dtype).itemsize
+    hb = head_block(num_kv_heads, head_dim, dtype)
+    return hb * head_dim * jnp.dtype(dtype).itemsize
 
 
 def clamp_pages_per_chunk(pages_per_seq: int, requested: int) -> int:
@@ -215,19 +244,19 @@ def choose_pages_per_chunk(pages_per_seq: int, page_size: int,
     leaves the read ring `_MIN_RING_SLOTS` slots inside its budget, a
     multiple of 128 tokens so the score tile keeps whole lanes, up to
     `_MAX_ITEM_TOKENS`, at every batch size. `lane_bytes` is
-    `lane_bytes_of` the pages: bf16 Mistral pages and 8-bit ones give
-    512-token items,
-    head blocks twice as wide 256. A cell costs some 0.5 us whatever
-    it holds and a page's two descriptors 40 ns to issue, so an item
-    under 384 tokens of such pages takes longer than its copies
-    (PERF.md §5); what the ring loses in depth costs nothing down to
-    two items ahead. A table narrower than an item is one item; a
-    width that is no multiple of the item needs no divisor, because a
-    row's last item copies only its live pages (the classic grid
-    still clamps to a divisor)."""
-    tokens = _RING_BUDGET_BYTES // (_MIN_RING_SLOTS * 2 * lane_bytes)
-    tokens = min(max(tokens // 128 * 128, 128), _MAX_ITEM_TOKENS)
-    return max(1, min(tokens // page_size, pages_per_seq))
+    `lane_bytes_of` the pages: bf16 Mistral pages (eight heads of 128)
+    and narrower ones give 512-token items, a whole block of nine or
+    ten such heads 384, blocks of 4 KB a token 256 (`_item_tokens`).
+    A cell costs some 0.5 us whatever it holds and a page's two
+    descriptors 40 ns to issue, so an item under 384 tokens of such
+    pages takes longer than its copies (PERF.md §5), which is where
+    `head_block` stops widening the block; what the ring loses in
+    depth costs nothing down to two items ahead. A table narrower
+    than an item is one item; a width that is no multiple of the item
+    needs no divisor, because a row's last item copies only its live
+    pages (the classic grid still clamps to a divisor)."""
+    return max(1, min(_item_tokens(lane_bytes) // page_size,
+                      pages_per_seq))
 
 
 def padded_work_length(num_items: int, batch: int, pages_per_seq: int,
@@ -972,24 +1001,25 @@ def _ring_slots(pf_depth: int, chunk_tokens: int, lane_bytes: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "kv_scale", "pages_per_chunk", "pf_depth",
-                     "amla", "interpret", "ablate", "window"))
+                     "amla", "interpret", "ablate", "window", "hb"))
 def _paged_decode_impl(
     q, k_pages, v_pages, block_tables, context_lens, wi_seq, wi_chunk,
     alibi_slopes, knew, vnew, *, scale, kv_scale, pages_per_chunk,
-    pf_depth, amla, interpret, ablate=None, window=None,
+    pf_depth, amla, interpret, ablate=None, window=None, hb=None,
 ):
     batch, num_q_heads, head_dim = q.shape
     num_pages, page_size, hd = k_pages.shape
     num_kv_heads = hd // head_dim
     pages_per_seq = block_tables.shape[1]
     group = num_q_heads // num_kv_heads
-    hb = head_block(num_kv_heads)
+    if hb is None:
+        hb = head_block(num_kv_heads, head_dim, k_pages.dtype)
     n_hb = num_kv_heads // hb
     rows = group * hb
     chunk_tokens = pages_per_chunk * page_size
     fused_write = knew is not None
     ragged = wi_seq is not None
-    lane_bytes = lane_bytes_of(num_kv_heads, head_dim, k_pages.dtype)
+    lane_bytes = hb * head_dim * k_pages.dtype.itemsize
     single_chunk = pages_per_seq == pages_per_chunk
 
     # q rows are kv-head-major, so the rows for head block j are the
@@ -1160,6 +1190,7 @@ def paged_decode_attention(
     work_items=None,          # (wi_seq [NW+1], wi_chunk [NW]) int32
     amla=None,                # pin the rescale variant (A/B hook)
     ablate: str = None,       # benchmarks/attn_ab.py: time a part alone
+    hb: int = None,           # benchmarks/attn_ab.py: pin the head block
     interpret: bool = False,
     window: int = None,       # attend over the newest `window` keys only
 ):
@@ -1186,6 +1217,10 @@ def paged_decode_attention(
     item's arithmetic and leaves its copies and waits, "copies" skips
     the page copies and computes on whatever the ring holds.
 
+    `hb` is that harness's too: it pins the KV heads a grid cell holds
+    (a divisor of the head count; `pages_per_chunk` and the work list
+    are then the caller's to size for it) where `head_block` decides.
+
     `amla` pins the online-softmax rescale variant: True = AMLA
     exponent-bias adds, False = the classic per-chunk multiply (A/B);
     None reads APHRODITE_ATTN_AMLA (default on).
@@ -1202,6 +1237,8 @@ def paged_decode_attention(
     num_kv_heads = hd // head_dim
     if num_q_heads % num_kv_heads != 0:
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=}")
+    if hb is not None and (hb < 1 or num_kv_heads % hb != 0):
+        raise ValueError(f"{hb=} does not divide {num_kv_heads=}")
     pages_per_seq = block_tables.shape[1]
     pf_depth = _pf_depth()      # call-time env read + validation
     use_ragged = work_items is not None and ragged_enabled()
@@ -1225,4 +1262,4 @@ def paged_decode_attention(
         wi_chunk, alibi_slopes, knew, vnew, scale=scale,
         kv_scale=kv_scale, pages_per_chunk=ppc, pf_depth=pf_depth,
         amla=use_amla, interpret=interpret,
-        ablate=ablate if use_ragged else None, window=window)
+        ablate=ablate if use_ragged else None, window=window, hb=hb)

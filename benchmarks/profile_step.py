@@ -426,7 +426,7 @@ def main() -> None:
                                dtype=jnp.bfloat16)
         r_ppc = choose_pages_per_chunk(r_pps, PAGE, LANE_BYTES)
         r_work = build_decode_work_list([-(-ctx // PAGE)] * B, r_ppc)
-        hb = head_block(KV_HEADS)
+        hb = head_block(KV_HEADS, HEAD_DIM, jnp.bfloat16)
         n_items = int(r_work[1].shape[0]) * (KV_HEADS // hb)
         ast_static = ragged_roofline_static(
             r_ppc, PAGE, hb, HEAD_DIM, 2, n_items)

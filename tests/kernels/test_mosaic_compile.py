@@ -88,7 +88,10 @@ def test_prefill_w4a8_compiles(on_v5e, K, N):
 #: read-only verify rounds): 512-token items, so a ring that outgrew
 #: the 16 MiB scoped VMEM at the served shape is met here and not on
 #: the chip; then 8-bit pages (512-token items too, the ring twice as
-#: deep) and two head blocks (n_hb > 1: the lane-sliced copies).
+#: deep) and two head blocks (n_hb > 1: the lane-sliced copies); last
+#: the far end of `head_block`'s rule, 8-bit pages of 16 and of 21 KV
+#: heads in ONE block (2,048 and 2,688 lanes; the dequantised K and V
+#: of an item are temporaries beside the ring).
 DECODE_CASES = [
     (8, 32, 8, 24, BF16, True), (8, 32, 8, 24, BF16, False),
     (48, 32, 8, 72, BF16, True), (48, 32, 8, 72, BF16, False),
@@ -96,6 +99,8 @@ DECODE_CASES = [
     (48, 32, 8, 88, BF16, True), (48, 32, 8, 88, BF16, False),
     (48, 32, 8, 88, jnp.int8, True),
     (48, 32, 16, 88, BF16, True),
+    (48, 64, 16, 88, jnp.int8, True), (48, 84, 21, 88, jnp.int8, True),
+    (48, 84, 21, 88, jnp.float8_e5m2, True),
 ]
 
 
@@ -214,29 +219,39 @@ def test_kv_writers_compile(on_v5e):
 
 #: Phi-4-mini-flash's decode programs (`phi-4-mini-flash-bf16.reason-2k`):
 #: 40 query heads over 10 KV heads of 128, a differential pair of the
-#: model's 64-wide heads held as one head: head blocks of 5 (640 lanes),
-#: TWO of them, a head count no other cell has; the model's own scale
-#: 1/8. (rows, table width, window, fused write): the full layer's table
-#: at 2,049-3,072 tokens (192 wide) with the fused write; a cross
-#: layer's read-only call over the same pages; a window group's table
-#: (40 wide, 32-33 pages held under the window of 512); the canary's
-#: one row under and over 128 pages.
+#: model's 64-wide heads held as one head: ONE head block of ten (1,280
+#: lanes, a page one contiguous 40 KB descriptor; PR 37: two blocks of
+#: 5 before), a head count no other cell has; the model's own scale
+#: 1/8; 384-token items, four ring slots. (query heads, KV heads, rows,
+#: table width, window, fused write): at the cell's 48 rows and at 64,
+#: the full layer's table at 2,049-3,072 tokens (192 wide) with the
+#: fused write, a cross layer's read-only call over the same pages, a
+#: window group's table (40 wide, 32-33 pages held under the window of
+#: 512); the canary's one row under and over 128 pages. Then the head
+#: counts just past the rule's threshold, which still divide into lane
+#: slices: 12 heads in two blocks of 6, 11 in eleven of 1.
 FLASH_CASES = [
-    (64, 192, None, True), (64, 192, None, False), (64, 40, 512, True),
-    (1, 128, None, True), (1, 192, None, False), (1, 40, 512, True),
+    (40, 10, rows, pps, window, fused)
+    for rows in (48, 64)
+    for pps, window, fused in ((192, None, True), (192, None, False),
+                               (40, 512, True))
+] + [
+    (40, 10, 1, 128, None, True), (40, 10, 1, 192, None, False),
+    (40, 10, 1, 40, 512, True),
+    (48, 12, 48, 192, None, True), (44, 11, 48, 192, None, True),
 ]
 
 
-@pytest.mark.parametrize("B,pps,window,fused", FLASH_CASES)
+@pytest.mark.parametrize("Hq,Hkv,B,pps,window,fused", FLASH_CASES)
 def test_decode_attention_compiles_at_phi4flash_shapes(
-        on_v5e, B, pps, window, fused):
+        on_v5e, Hq, Hkv, B, pps, window, fused):
     from aphrodite_tpu.ops.pallas.paged_attention import (
         build_decode_work_list, choose_pages_per_chunk, head_block,
         lane_bytes_of, padded_work_length, paged_decode_attention)
-    Hq, Hkv, d, page = 40, 10, 128, 16
-    assert head_block(Hkv) == 5
+    d, page = 128, 16
+    assert head_block(Hkv, d, BF16) == {10: 10, 12: 6, 11: 1}[Hkv]
     ppc = choose_pages_per_chunk(pps, page, lane_bytes_of(Hkv, d, BF16))
-    assert ppc == 32
+    assert ppc == (24 if Hkv == 10 else 32)
     held = 33 if window else pps - 8
     counts = [held - i % 2 for i in range(B)]
     items = sum(-(-n // ppc) for n in counts)

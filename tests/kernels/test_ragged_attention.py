@@ -327,6 +327,141 @@ def test_fused_write_lands_in_a_partly_live_item():
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(ref_v))
 
 
+#: Ten KV heads of 128 in bf16 pages (Phi-4-mini-flash's differential
+#: pairs, 40 query heads, scale 1/8): ONE head block, the page's whole
+#: lane axis, at the policy's 24-page (384-token) items. Contexts whose
+#: last item has 1 page live (385), some (900: 9), all (768) or that
+#: fill a first item partly (5, 100); a pad row. Under a window of 512
+#: a row's table starts at the window's first page (32-33 pages held:
+#: a whole item and one of 8-9 live pages, as a Phi window layer's).
+TEN_CTX = np.array([385, 900, 768, 1130, 0, 5, 100], dtype=np.int32)
+TEN_PAGE, TEN_HQ, TEN_HKV, TEN_SCALE = 16, 40, 10, 0.125
+
+
+def ten_head_problem(window, width, seed=7):
+    """(q, K pages, V pages, table, contexts, page counts, dead pages):
+    bf16 pages, every page no row holds NaN (page 0, which the table's
+    pad entries point at, among them), pages in shuffled order."""
+    rng = np.random.default_rng(seed)
+    ctx = TEN_CTX.copy()
+    if window:      # what the block manager's window group lets go of
+        ctx -= np.maximum(0, ctx - window) // TEN_PAGE * TEN_PAGE
+    counts = -(-ctx // TEN_PAGE)
+    pool = 1 + int(counts.sum())
+    B, lanes = len(ctx), TEN_HKV * 128
+    perm = rng.permutation(pool - 1) + 1
+    bt = np.zeros((B, width), dtype=np.int32)
+    taken = 0
+    for b, n in enumerate(counts):
+        bt[b, :n] = perm[taken:taken + n]
+        taken += n
+    dead = np.ones(pool + 4, bool)
+    dead[perm] = False
+    pages = []
+    for _ in range(2):
+        raw = rng.normal(size=(pool + 4, TEN_PAGE, lanes)) * 0.3
+        raw[dead] = np.nan
+        pages.append(jnp.asarray(raw, jnp.bfloat16))
+    q = rng.normal(size=(B, TEN_HQ, 128)) * 0.3
+    q[:, 0::2, 64:] = 0.0           # [q1 ; 0]
+    q[:, 1::2, :64] = 0.0           # [0 ; q2]
+    return (jnp.asarray(q, jnp.bfloat16), pages[0], pages[1], bt, ctx,
+            counts, dead)
+
+
+def _ten_head_write(kp, vp, bt, ctx):
+    """A new token's K and V a row, and the pages as the slot writer
+    leaves them."""
+    from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+    rng = np.random.default_rng(9)
+    B = len(ctx)
+    new = [jnp.asarray(rng.normal(size=(B, TEN_HKV, 128)) * 0.3,
+                       jnp.bfloat16) for _ in range(2)]
+    slots = np.where(
+        ctx > 0, bt[np.arange(B), np.maximum(ctx - 1, 0) // TEN_PAGE]
+        * TEN_PAGE + (ctx - 1) % TEN_PAGE, kp.shape[0] * TEN_PAGE)
+    return new, write_to_kv_cache(new[0], new[1], kp, vp,
+                                  jnp.asarray(slots, jnp.int32))
+
+
+@pytest.mark.parametrize("window,width,fused,ragged", [
+    (None, 80, True, True), (None, 80, False, True),
+    (512, 40, True, True), (512, 40, False, True),
+    (None, 80, True, False),
+], ids=["full-fused-write", "full-read-only", "window-fused-write",
+        "window-read-only", "classic-grid"])
+def test_ten_heads_are_one_head_block(window, width, fused, ragged):
+    """The three calls of a Phi decode step (the full layer's with the
+    fused write, a cross layer's read-only over the same pages, a
+    window layer's) and a read-only window call, at ten KV heads in one
+    block, against the jnp reference: the output its, the pages
+    written the slot writer's exactly, no dead page read (NaN in every
+    page no row holds), items that end partly live. The classic padded
+    grid (APHRODITE_ATTN_RAGGED=0, or a call without a work list)
+    follows the same rule; it walks whole chunks over the table, pad
+    entries too, so there the pages no row holds are zeros."""
+    from aphrodite_tpu.ops.attention import paged_decode_attention_ref
+    assert pa.head_block(TEN_HKV, 128, jnp.bfloat16) == TEN_HKV
+    ppc = choose_pages_per_chunk(
+        80, TEN_PAGE, pa.lane_bytes_of(TEN_HKV, 128, jnp.bfloat16))
+    assert ppc == 24
+    q, kp, vp, bt, ctx, counts, dead = ten_head_problem(window, width)
+    assert any(0 < n % ppc < ppc for n in counts)   # partly live items
+    dead = jnp.asarray(dead)[:, None, None]
+    if not ragged:
+        kp, vp = jnp.where(dead, 0, kp), jnp.where(dead, 0, vp)
+    new, (want_k, want_v) = (None, None), (kp, vp)
+    if fused:
+        new, (want_k, want_v) = _ten_head_write(kp, vp, bt, ctx)
+    # the reference gathers every table entry: give it the pages with
+    # zeros where no row's context reaches
+    want = np.asarray(paged_decode_attention_ref(
+        q, jnp.where(dead, 0, want_k), jnp.where(dead, 0, want_v),
+        jnp.asarray(bt), jnp.asarray(np.maximum(ctx, 1)), TEN_SCALE,
+        window=window), np.float32)
+    got = paged_decode_attention(
+        q, kp, vp, jnp.asarray(bt), jnp.asarray(ctx), None, *new,
+        scale=TEN_SCALE, pages_per_chunk=ppc,
+        work_items=build_decode_work_list(counts, ppc) if ragged
+        else None, window=window, interpret=True)
+    if fused:
+        got, got_k, got_v = got
+        np.testing.assert_array_equal(np.asarray(got_k, np.float32),
+                                      np.asarray(want_k, np.float32))
+        np.testing.assert_array_equal(np.asarray(got_v, np.float32),
+                                      np.asarray(want_v, np.float32))
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    live = ctx > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-2,
+                               atol=2e-2)
+    np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
+
+
+def test_a_pinned_head_block_reads_what_the_whole_one_reads():
+    """`hb=` (benchmarks/attn_ab.py's arm) divides the ten heads into
+    two lane-sliced blocks of five, as the policy did before a block
+    was the whole lane axis: the same output and the same pages."""
+    q, kp, vp, bt, ctx, counts, _ = ten_head_problem(None, 80)
+    new, _ = _ten_head_write(kp, vp, bt, ctx)
+
+    def call(hb):
+        return paged_decode_attention(
+            q, kp, vp, jnp.asarray(bt), jnp.asarray(ctx), None, *new,
+            scale=TEN_SCALE, pages_per_chunk=16, hb=hb,
+            work_items=build_decode_work_list(counts, 16),
+            interpret=True)
+    whole, halves = call(None), call(5)
+    np.testing.assert_allclose(np.asarray(halves[0], np.float32),
+                               np.asarray(whole[0], np.float32),
+                               atol=1e-2)
+    for a, b in zip(halves[1:], whole[1:]):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    with pytest.raises(ValueError, match="does not divide"):
+        call(4)
+
+
 @pytest.mark.parametrize("steps_on", [0, 20])
 def test_a_bursts_list_serves_the_context_of_the_step(steps_on):
     """A burst builds one list from the pages it reserved and reuses
@@ -460,6 +595,54 @@ def test_choose_pages_per_chunk_policy(width, page, lane_bytes, want):
     assert slots >= pa._MIN_RING_SLOTS or tokens == 128
     assert slots * 2 * tokens * lane_bytes <= pa._RING_BUDGET_BYTES or \
         tokens == 128
+
+
+def _divisor_block(heads):
+    return next(hb for hb in (8, 7, 6, 5, 4, 3, 2, 1) if heads % hb == 0)
+
+
+@pytest.mark.parametrize("dtype,head_dim,whole_up_to", [
+    (jnp.bfloat16, 128, 10),        # 2,560 B of lanes a token
+    (jnp.int8, 128, 21),            # 2,688 B
+    (jnp.float8_e5m2, 128, 21),
+    (jnp.float32, 128, 5),
+    (jnp.bfloat16, 256, 5),         # padded 192- or 256-wide heads
+])
+def test_head_block_is_the_whole_lane_axis_while_the_ring_affords_it(
+        dtype, head_dim, whole_up_to):
+    """All heads in one block while the read ring keeps four slots of
+    a 384-token item, by shapes and the page type alone; past that the
+    largest divisor <= 8, as before. The item and the ring follow the
+    block (`lane_bytes_of`), so runner and kernel agree."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for heads in range(1, 41):
+        hb = pa.head_block(heads, head_dim, dtype)
+        want = heads if heads <= whole_up_to else _divisor_block(heads)
+        assert hb == want, (heads, hb)
+        lane_bytes = pa.lane_bytes_of(heads, head_dim, dtype)
+        assert lane_bytes == hb * head_dim * itemsize
+        tokens = choose_pages_per_chunk(4096, 16, lane_bytes) * 16
+        if hb == heads and heads > 8:
+            assert tokens >= pa._MIN_WHOLE_ITEM_TOKENS
+            assert pa._ring_slots(6, tokens, lane_bytes) >= \
+                pa._MIN_RING_SLOTS
+
+
+@pytest.mark.parametrize("heads,dtype,hb,item_tokens", [
+    (8, jnp.bfloat16, 8, 512),      # mistral-7b-w4a8: as it was
+    (4, jnp.bfloat16, 4, 512),      # smallthinker-21ba3b-bf16: as it was
+    (10, jnp.bfloat16, 10, 384),    # phi-4-mini-flash-bf16: was 5, 512
+    (9, jnp.bfloat16, 9, 384),      # was 3
+    (11, jnp.bfloat16, 1, 512),     # past the threshold: divides
+    (12, jnp.bfloat16, 6, 512),
+    (16, jnp.bfloat16, 8, 512), (32, jnp.bfloat16, 8, 512),
+    (16, jnp.int8, 16, 512),        # was 8
+    (21, jnp.int8, 21, 384), (22, jnp.int8, 2, 512),
+])
+def test_head_block_of_the_served_models(heads, dtype, hb, item_tokens):
+    assert pa.head_block(heads, 128, dtype) == hb
+    assert choose_pages_per_chunk(
+        4096, 16, pa.lane_bytes_of(heads, 128, dtype)) * 16 == item_tokens
 
 
 def test_padded_work_length_gives_a_bucket_few_lengths():

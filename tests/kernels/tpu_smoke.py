@@ -241,12 +241,13 @@ def main() -> int:
                   np.asarray(gk6, np.float32)[live6], tol=1e-6)
 
     with section("decode attention (Phi-4-mini-flash: 10 KV heads, "
-                 "two head blocks)"):
+                 "one head block)"):
         # -- the decode kernel as `phi-4-mini-flash-bf16.reason-2k`
         #    calls it: 40 query heads over 10 KV heads of 128 (a
         #    differential pair of the model's 64-wide heads held as one
-        #    head; head blocks of 5, two of them), the model's own
-        #    scale 1/8, half of every query zeros, pages of 16, 64 rows.
+        #    head; all ten in one head block, a page one contiguous
+        #    copy, 384-token items), the model's own scale 1/8, half of
+        #    every query zeros, pages of 16, 64 rows.
         #    The full layer's table 192 wide at contexts of 2,049-3,072
         #    with the fused write; a window group's 40 wide holding
         #    32-33 pages under the window of 512; a cross layer's call,
