@@ -29,17 +29,21 @@ from jax.profiler import TraceAnnotation
 #: (`Tracer.add`): a request's wait from arrival to the round that
 #: first schedules it, a preemption, a sampling plan that built and
 #: sent nothing because the batch had not changed, a step program
-#: dispatched while the round before was still on the device, and the
+#: dispatched while the round before had not been pulled, and the
 #: KV pages a decode step's attention copies and those of them that
 #: are live, by page group where the model has several; the pages
 #: window groups let go of; what a model's expert layers count in
 #: the step program (pairs routed, experts touched); what the model
 #: runner counts where it builds a step of a model with state slots
 #: (rows started from zeros, decode rows, prompt tokens scanned, page
-#: reads with every reading layer counted); and the seconds in
-#: which a dispatched step had not been pulled yet (`Tracer.flight`).
+#: reads with every reading layer counted); the seconds in
+#: which a dispatched step had not been pulled yet (`Tracer.flight`);
+#: and the host's lead over the device, counted where the round turns
+#: (`Tracer.add_split`: a name's `.prompt` and `.decode` twins).
 NAMES = (
     "async.between_steps",  # engine.step returning -> the next entering
+    "async.step_call",      # the loop's call of engine.step: the hop to
+                            # the step thread, the step, the hop back
     "engine.step",          # one AphroditeEngine.step()
     "sched.schedule",       # deadline expiry + Scheduler.schedule()
     "runner.prepare",       # host batch build, up to the dispatch call
@@ -56,7 +60,18 @@ NAMES = (
     "queue_wait",
     "preemptions",
     "sampler.plan_reuse",
-    "runner.ahead",
+    "runner.ahead",         # a step program dispatched while the round
+                            # before had not been pulled (`runner.starved`
+                            # says whether the device had drained)
+    "round.ahead",          # rounds dispatched with a round in flight
+    "round.ahead.prompt",   # of them, those that carry a prompt step
+    "runner.starved",       # of them, those whose round in flight had
+                            # finished at the dispatch: nothing queued
+    "runner.starved.prompt",
+    "pull.blocked",         # the blocking pull after a round went out
+                            # ahead: the device's work still to do when
+                            # the host had none left (seconds, pulls)
+    "pull.blocked.decode",  # a decode-only round under a decode-only step
     "attn.pages_fetched",   # pages the decode kernel copies, a step
     "attn.pages_live",      # pages below the rows' context lengths
     "attn.decode_steps",    # decode steps those two were summed over
@@ -92,7 +107,8 @@ class Tracer:
         self.annotating = False
         #: what every annotation of the current round carries: `round`,
         #: and once the round is scheduled `path`, `rows`,
-        #: `prompt_tokens`
+        #: `prompt_tokens`; of a round dispatched ahead with a round in
+        #: flight, also `pulls`: that one is `combined` or `decode`
         self.facts: Dict[str, object] = {}
         #: step programs dispatched and not pulled yet, and the clock
         #: at the last change of that number
@@ -112,6 +128,24 @@ class Tracer:
         """Count `count` occurrences of `name` that lasted `secs`."""
         self.seconds[name] += secs
         self.counts[name] += count
+
+    def add_split(self, name: str, secs: float = 0.0,
+                  count: int = 1) -> None:
+        """`add` to `name` and to the twin of it that the round's
+        facts call for, where `NAMES` has that twin: `<name>.prompt`
+        when the round carries a prompt step (`path` is `combined`),
+        `<name>.decode` when the round and the round in flight are
+        both decode-only: an ordinary round under an ordinary step."""
+        self.add(name, secs, count)
+        path = self.facts.get("path")
+        if path == "combined":
+            twin = name + ".prompt"
+        elif path == "decode" and self.facts.get("pulls") == "decode":
+            twin = name + ".decode"
+        else:
+            return
+        if twin in self.counts:
+            self.add(twin, secs, count)
 
     def flight(self, steps: int) -> None:
         """`steps` step programs were dispatched (positive) or their
@@ -137,9 +171,12 @@ class Tracer:
 class Span:
     """`with tracer.span("runner.prepare"): ...`; entered and left on
     one thread. Also usable by hand (`__enter__` returns the span)
-    where the stage does not fit a block."""
+    where the stage does not fit a block. Once left it keeps its
+    duration (`seconds`), for a caller that files it under a second
+    name without reading the clock again."""
 
-    __slots__ = ("tracer", "name", "facts", "t0", "annotation")
+    __slots__ = ("tracer", "name", "facts", "t0", "annotation",
+                 "seconds")
 
     def __init__(self, tracer: Tracer, name: str, facts: dict) -> None:
         self.tracer = tracer
@@ -156,7 +193,8 @@ class Span:
         return self
 
     def __exit__(self, *exc) -> None:
-        self.tracer.add(self.name, _clock() - self.t0)
+        self.seconds = _clock() - self.t0
+        self.tracer.add(self.name, self.seconds)
         if self.annotation is not None:
             self.annotation.__exit__(*exc)
 

@@ -649,14 +649,22 @@ class AphroditeEngine:
         faults, self._step_faults = self._step_faults, []
         return faults
 
-    def _mark_path(self, path: str,
-                   scheduler_outputs: SchedulerOutputs) -> None:
-        """Which way this round runs, for the spans still to come."""
-        self.tracer.set_round(
+    def _mark_path(self, path: str, scheduler_outputs: SchedulerOutputs,
+                   in_flight: Optional[RoundInFlight] = None) -> None:
+        """Which way this round runs, for the spans still to come;
+        of a round that goes out ahead of the pull of `in_flight`,
+        also what that one carries (`pulls`). `Tracer.add_split` reads
+        the two to tell an ordinary round from one with a prompt
+        step."""
+        facts = dict(
             round=self._round, path=path,
             rows=(len(scheduler_outputs.prompt_chunks) +
                   len(scheduler_outputs.decode_groups)),
             prompt_tokens=scheduler_outputs.num_prefill_tokens)
+        if in_flight is not None:
+            facts["pulls"] = "combined" if any(
+                h.is_prompt for h in in_flight.handles) else "decode"
+        self.tracer.set_round(**facts)
 
     def _execute_round(self, seq_group_metadata_list,
                        scheduler_outputs) -> List[RequestOutput]:
@@ -670,15 +678,15 @@ class AphroditeEngine:
         before = self._ahead
         if self._runs_ahead(prompt_mds, decode_mds, scheduler_outputs):
             self._mark_path("combined" if prompt_mds else "decode",
-                            scheduler_outputs)
+                            scheduler_outputs, in_flight=before)
             handles = self.executor.dispatch_steps(Round(
                 prompt=prompt_mds, decode=decode_mds, ahead=True,
                 fed_by=before.handles if before is not None else (),
                 state_copies=scheduler_outputs.state_copies))
             if handles is not None:
                 if before is not None:
-                    for _ in handles:
-                        self.tracer.add("runner.ahead")
+                    self.tracer.add("runner.ahead", count=len(handles))
+                    self.tracer.add_split("round.ahead")
                 outputs = self._pull_round_in_flight()
                 self._ahead = RoundInFlight(scheduler_outputs, handles)
                 # From here each row's next token is on the device.
@@ -687,6 +695,8 @@ class AphroditeEngine:
                             status=SequenceStatus.RUNNING):
                         seq.data.in_flight = 1
                 return outputs
+            # Nothing went out: the pull below is a synced round's.
+            self.tracer.set_round(round=self._round)
 
         outputs = self._pull_round_in_flight()
         if before is not None:

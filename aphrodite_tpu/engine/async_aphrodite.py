@@ -383,14 +383,18 @@ class AsyncAphrodite:
         point is detection instead of a forever-'healthy' hang."""
         loop = asyncio.get_running_loop()
         self._between_steps(False)
-        fut = loop.run_in_executor(None, self.engine.step)
         try:
-            timeout = flags.get_float("APHRODITE_STEP_TIMEOUT_S")
-            if not timeout or timeout <= 0:
-                return await fut
-            done, _ = await asyncio.wait({fut}, timeout=timeout)
-            if done:
-                return fut.result()
+            # The loop's side of the step: the hop to the step thread,
+            # `engine.step`, the hop back. Its seconds less the step's
+            # are the two hops; a step the watchdog abandons closes it.
+            with self.engine.tracer.span("async.step_call"):
+                fut = loop.run_in_executor(None, self.engine.step)
+                timeout = flags.get_float("APHRODITE_STEP_TIMEOUT_S")
+                if not timeout or timeout <= 0:
+                    return await fut
+                done, _ = await asyncio.wait({fut}, timeout=timeout)
+                if done:
+                    return fut.result()
         finally:
             self._between_steps(True)
         fut.add_done_callback(_consume_abandoned_step)
@@ -402,7 +406,8 @@ class AsyncAphrodite:
     def _between_steps(self, begin: bool) -> None:
         """The `async.between_steps` span: the loop's own work from one
         `engine.step` returning to the next entering (stream delivery,
-        request intake and `add_request`, the hop to the step thread).
+        request intake and `add_request`; the hops to and from the step
+        thread are `async.step_call`'s).
         `begin` false closes the open span, true also opens the next;
         the loop closes it before it idles, so no wait for a request
         is counted."""

@@ -86,9 +86,50 @@ _STAGE_COUNTERS = [
      "parameters were the step before's.",
      lambda s, c: c["sampler.plan_reuse"]),
     ("aphrodite:steps_ahead_total",
-     "Step programs dispatched while the round before was still on "
-     "the device (of aphrodite:sampler_plans_total dispatched).",
+     "Step programs dispatched while the round before had not been "
+     "pulled (of aphrodite:sampler_plans_total dispatched); whether "
+     "the device had drained by then is "
+     "aphrodite:dispatches_starved_total.",
      lambda s, c: c["runner.ahead"]),
+    ("aphrodite:rounds_ahead_total",
+     "Rounds dispatched while the round before had not been pulled.",
+     lambda s, c: c["round.ahead"]),
+    ("aphrodite:rounds_ahead_prompt_total",
+     "Of aphrodite:rounds_ahead_total, the rounds that carry a prompt "
+     "step.", lambda s, c: c["round.ahead.prompt"]),
+    ("aphrodite:dispatches_starved_total",
+     "Of aphrodite:rounds_ahead_total, the rounds whose round in "
+     "flight had finished when they were dispatched: the device had "
+     "nothing queued and waited for the host.",
+     lambda s, c: c["runner.starved"]),
+    ("aphrodite:dispatches_starved_prompt_total",
+     "Of aphrodite:dispatches_starved_total, the rounds that carry a "
+     "prompt step (of aphrodite:rounds_ahead_prompt_total).",
+     lambda s, c: c["runner.starved.prompt"]),
+    ("aphrodite:pull_blocked_seconds_total",
+     "Seconds the step thread blocked pulling the round in flight "
+     "after it had dispatched the next: the host's lead over the "
+     "device (the result's transfer included).",
+     lambda s, c: s["pull.blocked"]),
+    ("aphrodite:pulls_ahead_total",
+     "Pulls that aphrodite:pull_blocked_seconds_total was summed "
+     "over.", lambda s, c: c["pull.blocked"]),
+    ("aphrodite:pull_blocked_decode_seconds_total",
+     "Of aphrodite:pull_blocked_seconds_total, the pulls of a "
+     "decode-only round behind a decode-only dispatch: the slack of "
+     "an ordinary round.", lambda s, c: s["pull.blocked.decode"]),
+    ("aphrodite:pulls_ahead_decode_total",
+     "Pulls that aphrodite:pull_blocked_decode_seconds_total was "
+     "summed over.", lambda s, c: c["pull.blocked.decode"]),
+    ("aphrodite:host_dispatch_seconds_total",
+     "Seconds in the jitted calls that enqueue step programs, up to "
+     "their return (a compile shows here).",
+     lambda s, c: s["runner.dispatch"]),
+    ("aphrodite:step_call_seconds_total",
+     "Seconds of the async loop's calls of AphroditeEngine.step: the "
+     "hop to the step thread, the step and the hop back (less "
+     "aphrodite:engine_step_seconds_total: the two hops).",
+     lambda s, c: s["async.step_call"]),
     ("aphrodite:decode_attn_pages_fetched_total",
      "KV pages the decode-attention kernel copied from the pool, "
      "summed over decode steps (one layer's; host arithmetic).",
@@ -124,9 +165,6 @@ _STAGE_COUNTERS = [
      "attention reads them (a group's own and those that read "
      "theirs), summed over decode steps.",
      lambda s, c: c["attn.page_reads_shared"]),
-    ("aphrodite:state_assign_seconds_total",
-     "Seconds giving admitted prompts their state slots (inside the "
-     "schedule seconds).", lambda s, c: s["cache.state_assign"]),
     ("aphrodite:ssm_state_resets_total",
      "Prompt rows that started at position 0, where the step's "
      "program starts the row's state slot from zeros.",

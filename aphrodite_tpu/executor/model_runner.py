@@ -131,6 +131,11 @@ class StepHandle:
         self.verify = verify
         self.outputs = outputs
 
+    def is_ready(self) -> bool:
+        """Whether the step's program has finished on the device;
+        never blocks."""
+        return self.packed.is_ready()
+
     def token_cells(self) -> Dict[int, int]:
         """Where each sequence's sampled token lies in `packed[:, :2]`
         read row by row: sequence id -> 2 * row + column (`fused_sample`
@@ -1189,15 +1194,17 @@ class ModelRunner:
             plan.max_best_of == 1 and plan.num_topk == 0
 
     def _enqueue(self, inputs: dict, sampling: SamplingMetadata, params,
-                 plan, kv_caches
+                 plan, kv_caches, **facts
                  ) -> Tuple[StepHandle,
                             List[Tuple[jax.Array, jax.Array]]]:
         """Dispatch the one program of a prepared step, the fused step
         or the burst's scan over it (which compiles its sampler
-        statics from the plan); nothing blocks."""
+        statics from the plan); nothing blocks. `facts` go on the
+        dispatch's annotation."""
         burst = inputs.get("burst")
         self.tracer.flight(1)
-        with self.tracer.span("runner.dispatch"), self._mesh_ctx():
+        with self.tracer.span("runner.dispatch", **facts), \
+                self._mesh_ctx():
             if burst is None:
                 packed, kv_caches = self._step_sample_fn(
                     params, inputs["input_ids"], inputs["positions"],
@@ -1306,18 +1313,33 @@ class ModelRunner:
             (step,) = prepared
             handle, kv_caches = self._run_raw(*step, kv_caches)
             return [handle], kv_caches
+        # The round in flight has finished and nothing is queued behind
+        # it: the device waited for this dispatch. Said on the round's
+        # first program; the second follows a program just enqueued.
+        facts = {}
+        if fed_by:
+            starved = all(handle.is_ready() for handle in fed_by)
+            if starved:
+                self.tracer.add_split("runner.starved")
+            facts["starved"] = int(starved)
         handles = []
         for step in prepared:
-            handle, kv_caches = self._enqueue(*step, kv_caches)
+            handle, kv_caches = self._enqueue(
+                *step, kv_caches, **({} if handles else facts))
             handles.append(handle)
         return handles, kv_caches
 
     def pull(self, handles: List[StepHandle]) -> List[np.ndarray]:
-        """The ONE blocking transfer for the results of `handles`."""
-        with self.tracer.span("runner.device_wait"):
+        """The ONE blocking transfer for the results of `handles`.
+        After a round went out ahead (the engine's `pulls` fact) the
+        wait is also the host's lead, `pull.blocked`: what the device
+        had left to do when the host had nothing."""
+        with self.tracer.span("runner.device_wait") as wait:
             pulled, counted = jax.device_get(
                 ([h.packed for h in handles],
                  [h.counts for h in handles if h.counts is not None]))
+        if "pulls" in self.tracer.facts:
+            self.tracer.add_split("pull.blocked", wait.seconds)
         self.tracer.flight(-len(handles))
         for handle, counts in zip(
                 (h for h in handles if h.counts is not None), counted):

@@ -428,12 +428,12 @@ def test_a_decode_batch_is_committed_as_the_results_are_fed_or_not(
     seen = []
     enqueue = runner._enqueue
 
-    def spy(inputs, sampling, params, plan, kv_caches):
+    def spy(inputs, sampling, params, plan, kv_caches, **facts):
         if not inputs["is_prompt"]:
             seen.append((inputs["metadata"].block_tables.committed,
                          any(d.in_flight
                              for d in sampling.seq_data.values())))
-        return enqueue(inputs, sampling, params, plan, kv_caches)
+        return enqueue(inputs, sampling, params, plan, kv_caches, **facts)
 
     runner._enqueue = spy
     # a prompt round alone, so that the first decode step is not fed

@@ -195,8 +195,25 @@ def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
     numbers = [r[0][2]["round"] for r in rounds]
     assert numbers == list(range(numbers[0], numbers[0] + len(rounds)))
     assert rounds[2][0][2] == dict(round=numbers[2])
+    # a round that goes out ahead of a round in flight says what that
+    # one carries (`pulls`); the first decode round had none in flight
     assert rounds[2][-1][2] == dict(round=numbers[2], path="combined",
-                                    rows=2, prompt_tokens=20)
+                                    rows=2, prompt_tokens=20,
+                                    pulls="decode")
+    assert "pulls" not in rounds[1][-1][2]
+    assert rounds[3][-1][2]["pulls"] == "combined"
+    assert {r[-1][2].get("pulls") for r in rounds[4:-1]} == {"decode"}
+    # and its first program's dispatch whether the device had drained
+
+    def says_starved(r):
+        return ["starved" in facts for name, _, facts in r
+                if name == "aph.runner.dispatch"]
+    assert says_starved(rounds[1]) == [False]       # nothing in flight
+    assert says_starved(rounds[2]) == [True, False]
+    assert says_starved(rounds[3]) == [True]
+    assert not any(says_starved(rounds[0]))         # a synced round
+    assert all(facts["starved"] in (0, 1) for r in rounds
+               for name, _, facts in r if "starved" in facts)
 
 
 @pytest.fixture(scope="module")
@@ -322,11 +339,15 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
         lambda *a, **k: pytest.fail("annotation with the profiler off"))
     def spans():
         # (the round also counts events that are no spans: it was
-        # dispatched ahead, its plan was the round before's, and the
-        # pages its attention copies and those that are live, over
-        # one more decode step)
+        # dispatched ahead, to a device that had drained or had not,
+        # and the pull behind it blocked; its plan was the round
+        # before's; and the pages its attention copies and those that
+        # are live, over one more decode step)
         return sum(n for name, n in engine.tracer.counts.items()
-                   if name not in ("runner.ahead", "sampler.plan_reuse",
+                   if name not in ("runner.ahead", "round.ahead",
+                                   "runner.starved", "pull.blocked",
+                                   "pull.blocked.decode",
+                                   "sampler.plan_reuse",
                                    "attn.pages_fetched",
                                    "attn.pages_live",
                                    "attn.decode_steps"))
@@ -335,9 +356,14 @@ def test_profiler_off_a_round_costs_two_clock_reads_a_span(
     spans = spans() - before
     monkeypatch.undo()
     _drain(engine)
+    # eight spans on the step thread (the async loop adds two of its
+    # own a round, `async.between_steps` and `async.step_call`: ten),
     # and one read each when the step is dispatched and when the one
-    # before is pulled (`Tracer.flight`)
+    # before is pulled (`Tracer.flight`); the lead and the starved
+    # dispatch read no clock: the pull's span is timed once, and
+    # `is_ready()` is no clock read
     assert spans == 8 and len(reads) == 2 * spans + 2
+    assert engine.tracer.counts["async.step_call"] == 0
     # the budget: under 50 us of added host time a round
     tracer = tracing.Tracer()
 
@@ -404,6 +430,123 @@ def test_queue_wait_and_preemptions_count_on_a_forced_case():
     assert [c.group for c in out.prompt_chunks] == [preempted]
     assert grew("queue_wait")[1] == 2
     assert preempted.first_scheduled_time == stamp
+
+
+# ---- the host's lead over the device ----
+
+@pytest.mark.parametrize("facts, twin", [
+    (dict(path="combined", pulls="decode"), ".prompt"),
+    (dict(path="combined", pulls="combined"), ".prompt"),
+    (dict(path="decode", pulls="decode"), ".decode"),
+    (dict(path="decode", pulls="combined"), None),
+    (dict(path="decode"), None),        # nothing was in flight
+    (dict(path="prompt"), None),        # a synced round
+    (dict(), None),
+], ids=lambda v: "-".join(v.values()) or "bare" if isinstance(v, dict)
+    else str(v))
+def test_a_twin_moves_only_on_the_round_its_name_says(facts, twin):
+    """`.prompt` on a round that carries a prompt step, `.decode` when
+    the round dispatched and the round pulled are both decode-only;
+    and only the twins that `NAMES` lists exist."""
+    for name in ("pull.blocked", "runner.starved", "round.ahead"):
+        tracer = tracing.Tracer()
+        tracer.set_round(round=1, **facts)
+        tracer.add_split(name, 0.5)
+        moved = {k: v for k, v in tracer.counts.items() if v}
+        want = {name: 1}
+        if twin is not None and name + twin in tracing.NAMES:
+            want[name + twin] = 1
+        assert moved == want
+        assert {k: v for k, v in tracer.seconds.items() if v} == \
+            dict.fromkeys(want, 0.5)
+    assert {n for n in tracing.NAMES if n.endswith((".prompt", ".decode"))
+            } == {"pull.blocked.decode", "runner.starved.prompt",
+                  "round.ahead.prompt"}
+
+
+def _grew(tracer, names, before=None):
+    now = {n: (tracer.counts[n], tracer.seconds[n]) for n in names}
+    if before is None:
+        return now
+    return {n: (now[n][0] - before[n][0], now[n][1] - before[n][1])
+            for n in names}
+
+
+def test_only_pulls_behind_a_round_ahead_are_the_lead_and_by_kind(
+        tiny_llm, monkeypatch):
+    """Six calls: a's prompt (synced), a decode round with nothing in
+    flight, a decode round ahead, b's prompt beside a's decode ahead,
+    b's two decode rounds ahead (the first pulls the combined round),
+    and the call that finds nothing to schedule and pulls synced."""
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    engine = tiny_llm.engine
+    names = ("pull.blocked", "pull.blocked.decode", "round.ahead",
+             "round.ahead.prompt", "runner.starved",
+             "runner.starved.prompt", "runner.device_wait",
+             "runner.ahead")
+    before = _grew(engine.tracer, names)
+    sp = SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True)
+    engine.add_request("lead-a", None, sp, prompt_token_ids=_prompt(1))
+    for _ in range(3):
+        engine.step()
+    engine.add_request("lead-b", None, sp, prompt_token_ids=_prompt(2))
+    _drain(engine)
+    grew = _grew(engine.tracer, names, before)
+    # rounds 3 to 7 went out with a round in flight; one carried b's
+    # prompt step, and had two programs
+    assert grew["round.ahead"][0] == 5
+    assert grew["round.ahead.prompt"][0] == 1
+    assert grew["runner.ahead"][0] == 6
+    # each was followed by the pull of the round before it; the prompt
+    # round's pull and the last call's are a synced round's
+    assert grew["pull.blocked"][0] == 5
+    assert grew["runner.device_wait"][0] == 7
+    assert 0 < grew["pull.blocked"][1] < grew["runner.device_wait"][1]
+    # decode under decode: round 3 and rounds 6 and 7; round 4 carries
+    # a prompt step and round 5 pulls one
+    assert grew["pull.blocked.decode"][0] == 3
+    assert 0 < grew["pull.blocked.decode"][1] < grew["pull.blocked"][1]
+    assert grew["runner.starved.prompt"][0] <= \
+        grew["round.ahead.prompt"][0]
+    assert grew["runner.starved"][0] <= grew["round.ahead"][0]
+
+
+@pytest.mark.parametrize("ready", [True, False],
+                         ids=["drained", "busy"])
+def test_a_dispatch_to_a_drained_device_is_starved(
+        tiny_llm, recorder, _annotating, ready):
+    """Whether the round in flight had finished when the next was
+    dispatched is read off its handles, without blocking: here
+    stand-ins whose `is_ready()` the test sets."""
+    from aphrodite_tpu.executor.model_runner import StepHandle
+
+    class SetHandle(StepHandle):
+        __slots__ = ()
+
+        def is_ready(self):
+            return ready
+
+    engine = _annotating(tiny_llm.engine)
+    sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
+    engine.add_request(f"starved-{ready}", None, sp,
+                       prompt_token_ids=_prompt(3))
+    engine.step()                       # the prompt round
+    engine.step()                       # a decode round, now in flight
+    engine._ahead.handles = tuple(
+        SetHandle(h.packed, h.sampling, h.plan, counts=h.counts,
+                  is_prompt=h.is_prompt) for h in engine._ahead.handles)
+    names = ("runner.starved", "runner.starved.prompt", "round.ahead")
+    before = _grew(engine.tracer, names)
+    engine.step()                       # dispatched behind the stand-in
+    grew = _grew(engine.tracer, names, before)
+    assert grew["round.ahead"][0] == 1
+    assert grew["runner.starved"] == (int(ready), 0.0)
+    assert grew["runner.starved.prompt"][0] == 0    # a decode round
+    dispatches = [facts for name, _, facts in recorder.rounds()[2]
+                  if name == "aph.runner.dispatch"]
+    assert [f["starved"] for f in dispatches] == [int(ready)]
+    assert dispatches[0]["pulls"] == "decode"
+    _drain(engine)
 
 
 # ---- the control ----
@@ -532,6 +675,72 @@ def test_stage_counters_are_exported_from_the_tracers_totals():
     assert _value("aphrodite:preemptions_total", labels) == 1
     log.log(_stats())                   # a Stats without them: no-op
     assert _value("aphrodite:preemptions_total", labels) == 1
+
+
+#: this PR's counters, each with the accumulator it exports
+LEAD_COUNTERS = {
+    "aphrodite:pull_blocked_seconds_total": ("s", "pull.blocked"),
+    "aphrodite:pulls_ahead_total": ("c", "pull.blocked"),
+    "aphrodite:pull_blocked_decode_seconds_total":
+        ("s", "pull.blocked.decode"),
+    "aphrodite:pulls_ahead_decode_total": ("c", "pull.blocked.decode"),
+    "aphrodite:dispatches_starved_total": ("c", "runner.starved"),
+    "aphrodite:dispatches_starved_prompt_total":
+        ("c", "runner.starved.prompt"),
+    "aphrodite:rounds_ahead_total": ("c", "round.ahead"),
+    "aphrodite:rounds_ahead_prompt_total": ("c", "round.ahead.prompt"),
+    "aphrodite:host_dispatch_seconds_total": ("s", "runner.dispatch"),
+    "aphrodite:step_call_seconds_total": ("s", "async.step_call"),
+}
+
+
+@pytest.mark.parametrize("counter", sorted(LEAD_COUNTERS))
+def test_a_lead_counter_reads_zero_before_its_first_event(counter):
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    kind, name = LEAD_COUNTERS[counter]
+    labels = dict(model_name="tracing-test-" + counter.split(":")[1])
+    log = StatLogger(labels=labels)
+    assert _value(counter, labels) == 0.0
+    tracer = tracing.Tracer()
+    tracer.add(name, 0.75, count=3)
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    assert _value(counter, labels) == (0.75 if kind == "s" else 3.0)
+    # one name each: no two counters export the same accumulator
+    reads = [doc for metric, doc, total in _STAGE_COUNTERS
+             if total(tracer.seconds, tracer.counts)]
+    # (the lead's seconds and its pulls are one accumulator's two sides)
+    assert len(reads) == (2 if name.startswith("pull.blocked") else 1)
+
+
+def test_steps_ahead_says_not_pulled_wherever_it_is_described():
+    """`runner.ahead` counts a round that had not been pulled, not one
+    that was still on the device; the help text, the comment in
+    `NAMES`, the README and PERF.md say so and point at the counter
+    that does."""
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    (doc,) = [doc for metric, doc, _ in _STAGE_COUNTERS
+              if metric == "aphrodite:steps_ahead_total"]
+    assert "had not been pulled" in doc
+    assert "aphrodite:dispatches_starved_total" in doc
+    texts = {"tracing.py": os.path.join(ROOT, "aphrodite_tpu", "common",
+                                        "tracing.py"),
+             "README.md": os.path.join(ROOT, "README.md"),
+             "PERF.md": os.path.join(ROOT, "PERF.md")}
+    for name, path in texts.items():
+        with open(path) as f:
+            text = " ".join(f.read().split())
+        assert "was still on the device" not in text, name
+        assert "had not been pulled" in text, name
+    # every new span and counter under one name in each of the four
+    with open(texts["README.md"]) as f:
+        readme = f.read()
+    with open(texts["PERF.md"]) as f:
+        perf = f.read()
+    for counter, (_, name) in LEAD_COUNTERS.items():
+        assert name in tracing.NAMES
+        assert counter in readme, counter
+        assert counter.split(":")[1] in perf, counter
 
 
 def test_device_wait_seconds_are_the_union_of_the_steps_in_flight(
@@ -673,6 +882,107 @@ def test_between_steps_is_counted_once_a_round_and_never_while_idle(
     assert _value("aphrodite:engine_rounds_total", labels) >= rounds - 1
     assert _value("aphrodite:host_between_steps_seconds_total",
                   labels) > 0
+
+
+def test_step_call_spans_the_hops_on_the_loop_thread(
+        tiny_model_dir, monkeypatch):
+    """`async.step_call` is entered and left on the event-loop thread
+    once a round, around the hop to the step thread, `engine.step` and
+    the hop back; never open while the loop idles; a span that is open
+    when the profiler starts or stops ends as it began; and a step the
+    watchdog abandons closes it."""
+    import threading
+    from aphrodite_tpu.engine.args_tools import AsyncEngineArgs
+    from aphrodite_tpu.engine.async_aphrodite import AsyncAphrodite
+    from aphrodite_tpu.engine.supervisor import StepTimeoutError
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
+    events, open_calls = [], []
+
+    class Annotation:
+        def __init__(self, name, **facts):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name, threading.get_ident()))
+            if self.name == "aph.async.step_call":
+                open_calls.append(self)
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name, threading.get_ident()))
+            if self.name == "aph.async.step_call":
+                open_calls.remove(self)
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Annotation)
+    names = ("async.step_call", "engine.step", "async.between_steps")
+
+    async def go():
+        engine = AsyncAphrodite.from_engine_args(AsyncEngineArgs(
+            model=tiny_model_dir, load_format="dummy", dtype="float32",
+            block_size=16, max_model_len=256, max_num_seqs=8,
+            swap_space=0.01, disable_log_requests=True))
+        tracer = engine.engine.tracer
+        loop_thread = threading.get_ident()
+
+        async def one(tag):
+            n = 0
+            async for out in engine.generate(None, sp, tag,
+                                             prompt_token_ids=_prompt(1)):
+                n += 1
+                # the profiler starts and stops while a call is open
+                # or between two: a span ends the way it began
+                tracer.annotate(n % 4 < 2)
+            return out
+
+        await one("warm")
+        await asyncio.sleep(0.2)        # the call that pulls the last round
+        tracer.annotate(False)
+        events.clear()
+        before = _grew(tracer, names)
+        await asyncio.sleep(0.4)        # idle: no call is open
+        assert open_calls == [] and events == []
+        idle = _grew(tracer, names, before)
+        out = await one("hops")
+        assert len(out.outputs[0].token_ids) == 8
+        await asyncio.sleep(0.2)        # the loop idles again
+        grew = _grew(tracer, names, before)
+        # a step the watchdog abandons: the call's span closes with it
+        monkeypatch.setenv("APHRODITE_STEP_TIMEOUT_S", "0.05")
+        monkeypatch.setattr(engine.engine, "step",
+                            lambda: time.sleep(0.3))
+        with pytest.raises(StepTimeoutError):
+            await engine._step_with_watchdog()
+        abandoned = _grew(tracer, names, before)
+        await asyncio.sleep(0.35)       # the wedged thread ends
+        return loop_thread, idle, grew, abandoned
+
+    loop_thread, idle, grew, abandoned = asyncio.run(go())
+    assert idle["async.step_call"] == (0, 0.0)
+    rounds = grew["engine.step"][0]
+    assert rounds >= 8 and grew["async.step_call"][0] == rounds
+    # the call holds the step and the two hops, and none of the idling
+    assert grew["engine.step"][1] <= grew["async.step_call"][1] < \
+        grew["engine.step"][1] + 0.3
+    # entered and left on the loop's thread, balanced, whatever the
+    # profiler did meanwhile; the step's own spans are another thread's
+    calls = [e for e in events if e[1] == "aph.async.step_call"]
+    assert calls and open_calls == []
+    assert {thread for _, _, thread in calls} == {loop_thread}
+    assert [kind for kind, _, _ in calls] == \
+        ["enter", "exit"] * (len(calls) // 2)
+    assert 0 < len(calls) // 2 < rounds     # some began with it off
+    assert loop_thread not in {thread for _, name, thread in events
+                               if name == "aph.engine.step"}
+    # nothing else of the program's opens on the loop's thread inside it
+    inside = False
+    for kind, name, thread in events:
+        if name == "aph.async.step_call":
+            inside = kind == "enter"
+        elif thread == loop_thread:
+            assert not inside, name
+    assert abandoned["async.step_call"][0] == rounds + 1
+    assert 0.05 <= abandoned["async.step_call"][1] - \
+        grew["async.step_call"][1] < 0.3
 
 
 def test_decode_attention_pages_are_counted_a_step_and_exported(
