@@ -90,6 +90,10 @@ NAMES = (
     "ssm.prefill_tokens",   # prompt tokens its chunk scans went over
     "attn.page_reads_shared",  # a decode step's live pages, each group's
                             # times the layers that read them
+    "attn.prefill_tiles_visited",  # (query block, key block) tiles the
+                            # blocked prompt attention visits, over a
+                            # step's attention layers
+    "attn.prefill_tiles_padded",   # the tiles of its padded rectangles
     "runner.in_flight",
 )
 

@@ -142,6 +142,18 @@ _STAGE_COUNTERS = [
      "Decode steps that aphrodite:decode_attn_pages_live_total and the "
      "page-group counters below were summed over.",
      lambda s, c: c["attn.decode_steps"]),
+    ("aphrodite:prefill_attn_tiles_visited_total",
+     "Tiles of key_block queries x key_block keys that the blocked "
+     "prompt attention visited, summed over the attention layers of "
+     "the prompt steps that took it (host arithmetic, by "
+     "ops/attention.py::count_prefill_tiles).",
+     lambda s, c: c["attn.prefill_tiles_visited"]),
+    ("aphrodite:prefill_attn_tiles_padded_total",
+     "Tiles of the same steps' padded rectangles, queries x keys as "
+     "the step program pads them: what "
+     "aphrodite:prefill_attn_tiles_visited_total would read if every "
+     "key block were scored for every query.",
+     lambda s, c: c["attn.prefill_tiles_padded"]),
     ("aphrodite:kv_pages_live_full_total",
      "Of aphrodite:decode_attn_pages_live_total, the pages of the full "
      "page groups (a page holds a group's layers).",

@@ -34,12 +34,14 @@ from aphrodite_tpu.common.sampling_params import SamplingType
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupMetadata)
 from aphrodite_tpu.modeling.input_metadata import GroupView, InputMetadata
+from aphrodite_tpu.modeling.layers.attention import takes_blocked_prefill
 from aphrodite_tpu.modeling.layers.rejection import delta_rejection_length
 from aphrodite_tpu.modeling.layers.sampler import (Sampler, fused_sample,
                                                    _fused_sample_jit)
 from aphrodite_tpu.modeling.sampling_metadata import (OutputMetadata,
                                                       PersistentMetadata,
                                                       SamplingMetadata)
+from aphrodite_tpu.ops.attention import BLOCKED_FROM, count_prefill_tiles
 from aphrodite_tpu.ops.kv_cache import copy_blocks as _copy_blocks_op
 from aphrodite_tpu.ops.kv_cache import padded_head_size
 from aphrodite_tpu.ops.pallas.paged_attention import (
@@ -245,6 +247,10 @@ class ModelRunner:
         #: (`tracing.NAMES`), pulled with a step's result
         self.step_counters: Tuple[str, ...] = tuple(
             getattr(model, "step_counters", ()))
+        #: queries x keys a row from which the model's prompt attention
+        #: goes in tiles (`PagedAttention.blocked_from`)
+        self.prefill_blocked_from: int = getattr(
+            model, "prefill_blocked_from", BLOCKED_FROM)
 
         # LoRA: bucket keys carrying slot-stacked adapter tensors, and a
         # slot resolver installed by the executor's WorkerLoRAManager.
@@ -628,16 +634,18 @@ class ModelRunner:
         seq_ids = [next(iter(md.seq_data))
                    for md in seq_group_metadata_list]
         if self.page_groups.plain:
-            views = [self._prompt_view(
-                [(0, md.block_tables.get(seq_id, []))
-                 for md, seq_id in zip(seq_group_metadata_list, seq_ids)],
-                ctx_lens, plens, padded_len)]
+            group_rows = [[
+                (0, md.block_tables.get(seq_id, []))
+                for md, seq_id in zip(seq_group_metadata_list, seq_ids)]]
         else:
-            views = [self._prompt_view(
-                [md.group_tables[seq_id][g]
-                 for md, seq_id in zip(seq_group_metadata_list, seq_ids)],
-                ctx_lens, plens, padded_len)
+            group_rows = [[
+                md.group_tables[seq_id][g]
+                for md, seq_id in zip(seq_group_metadata_list, seq_ids)]
                 for g in range(len(self.page_groups.kinds))]
+        views = [self._prompt_view(rows, ctx_lens, plens, padded_len)
+                 for rows in group_rows]
+        self._count_prefill_tiles(group_rows, views, ctx_lens, plens,
+                                  padded_len, use_prefix)
 
         state_slots = None
         if self.num_state_slots is not None:
@@ -682,6 +690,40 @@ class ModelRunner:
                       is_prompt=True, use_prefix=use_prefix,
                       newly_computed=newly_computed)
         return inputs, sampling
+
+    def _count_prefill_tiles(self, group_rows, views, ctx_lens, plens,
+                             padded_len: int, use_prefix: bool) -> None:
+        """The (query block, key block) tiles this prompt step's
+        attention visits and the tiles of its padded rectangle, summed
+        over the attention layers: each page group's view as
+        `PagedAttention._prefill` hands it to
+        `prefill_attention_blocked`, by that function's own rule, times
+        the layers that read the group. Host arithmetic over the rows,
+        nothing on the device. A step under the threshold counts
+        nothing, nor does a whole prompt under a sequence-parallel
+        mesh (the ring's)."""
+        if self.sp is not None and not use_prefix:
+            return
+        visited = padded = 0
+        for g, (rows, view) in enumerate(zip(group_rows, views)):
+            kv_len = view.block_tables.shape[1] * self.page_size \
+                if use_prefix else padded_len
+            if not takes_blocked_prefill(padded_len, kv_len,
+                                         self.prefill_blocked_from):
+                continue
+            own_ctx = np.zeros_like(ctx_lens)
+            if use_prefix:
+                own_ctx[:len(rows)] = ctx_lens[:len(rows)] - \
+                    [let_go for let_go, _ in rows]
+            got = count_prefill_tiles(
+                own_ctx, own_ctx + plens, padded_len, kv_len,
+                self.page_groups.window
+                if self.page_groups.kinds[g] == "window" else None)
+            visited += got[0] * self.page_groups.readers[g]
+            padded += got[1] * self.page_groups.readers[g]
+        if padded:
+            self.tracer.add("attn.prefill_tiles_visited", count=visited)
+            self.tracer.add("attn.prefill_tiles_padded", count=padded)
 
     def _prompt_view(self, rows: List[Tuple[int, List[int]]],
                      ctx_lens: np.ndarray, plens: np.ndarray,

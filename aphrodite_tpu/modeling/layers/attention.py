@@ -44,6 +44,14 @@ from aphrodite_tpu.ops.attention import (BLOCKED_FROM,
 from aphrodite_tpu.ops.kv_cache import gather_pages, write_to_kv_cache
 
 
+def takes_blocked_prefill(seq_len: int, kv_len: int,
+                          blocked_from: int) -> bool:
+    """Whether a prompt step of `seq_len` queries against `kv_len` keys
+    a row takes `prefill_attention_blocked` (the layer below chooses by
+    it, and the runner counts the step's tiles by it)."""
+    return seq_len * kv_len >= blocked_from
+
+
 class PagedAttention:
     """Stateless attention dispatcher (all state is in the KV pages)."""
 
@@ -237,7 +245,8 @@ class PagedAttention:
 
         # (static: a function of the step program's shapes)
         attend = prefill_attention_blocked \
-            if seq_len * kv_k.shape[1] >= self.blocked_from \
+            if takes_blocked_prefill(seq_len, kv_k.shape[1],
+                                     self.blocked_from) \
             else prefill_attention
         return attend(
             q, kv_k, kv_v, context_lens, kv_valid, self.scale,

@@ -374,6 +374,9 @@ class Phi4FlashForCausalLM:
         kinds = config.layer_kinds
         self.groups = PageGroups.of(config.page_layer_kinds,
                                     config.sliding_window, stateful=True)
+        #: the attention layers' `blocked_from`, for the runner's
+        #: count of the tiles a prompt step visits
+        self.prefill_blocked_from = PREFILL_BLOCKED_FROM
         self.embed_tokens = VocabParallelEmbedding(
             config.vocab_size, config.hidden_size, dtype=dtype)
         self.layers = [

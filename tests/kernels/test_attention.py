@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from aphrodite_tpu.ops.attention import (paged_decode_attention_ref,
+from aphrodite_tpu.ops.attention import (make_causal_mask,
+                                         paged_decode_attention_ref,
                                          prefill_attention)
 from aphrodite_tpu.ops.pallas.paged_attention import paged_decode_attention
 
@@ -179,6 +180,8 @@ def numpy_prefill(q, k, v, context_lens, kv_valid, scale, window=None,
                         sc += slopes[h] * t
                     scores.append(sc)
                     idxs.append(t)
+                if not scores:      # a query that sees no key: zeros
+                    continue
                 scores = np.array(scores)
                 probs = np.exp(scores - scores.max())
                 probs /= probs.sum()
@@ -474,32 +477,68 @@ def test_paged_attention_layer_pads_small_heads():
                                np.asarray(ref), rtol=1e-4, atol=1e-5)
 
 
-# ---- keys in blocks under an online softmax ----
+# ---- queries and keys in blocks under an online softmax ----
 
-@pytest.mark.parametrize("window,slopes", [(None, False), (9, False),
-                                           (None, True)],
-                         ids=["full", "window", "alibi"])
-@pytest.mark.parametrize("kv_len", [32, 37])
-def test_blocked_prefill_is_plain_prefill(window, slopes, kv_len):
-    """`prefill_attention_blocked` (the keys a block at a time, what a
-    step program takes from `BLOCKED_FROM` queries x keys a row on) is
-    `prefill_attention`: against the numpy oracle for a chunk behind a
-    cached prefix, a key count that is no multiple of the block, a
-    row whose valid keys end early, a window and ALiBi."""
+def _case(s_new, kv_len, ctx, new=None, window=None, slopes=False):
+    """One call of the blocked prefill at `key_block` 8: `s_new` padded
+    queries a row behind `ctx` cached tokens, of which `new` (default:
+    all) are the row's own, against `kv_len` keys."""
+    return dict(s_new=s_new, kv_len=kv_len, ctx=ctx,
+                new=new or [s_new] * len(ctx), window=window,
+                slopes=slopes)
+
+
+BLOCKED_CASES = {
+    # one query block behind a cached prefix: a key count that is no
+    # multiple of the block, a row whose valid keys end early
+    **{f"{kv_len}-{name}": _case(8, kv_len, [kv_len - 8, kv_len - 13],
+                                 [8, 6], window, slopes)
+       for kv_len in (32, 37)
+       for name, window, slopes in (("full", None, False),
+                                    ("window", 9, False),
+                                    ("alibi", None, True))},
+    # four query blocks: a chunk at once, twice and three times its
+    # length into a table padded far past the valid keys
+    "chunk-2-of-a-padded-table": _case(32, 160, [32]),
+    "chunk-3-of-a-padded-table": _case(32, 160, [64]),
+    "chunk-4-of-a-padded-table": _case(32, 160, [96]),
+    "first-chunk-own-keys": _case(32, 32, [0, 0], [32, 27]),
+    "rows-at-different-contexts": _case(32, 128, [64, 16]),
+    "a-pad-row-beside-a-row": _case(32, 96, [48, 0], [32, 0], window=11),
+    "window-under-a-block": _case(32, 96, [32, 40], window=5),
+    "window-of-a-block": _case(32, 96, [32, 40], window=8),
+    "window-over-a-block": _case(32, 96, [32, 40], window=19),
+    "window-alibi-blocks": _case(32, 96, [40, 8], window=19, slopes=True),
+    "queries-past-the-prompt": _case(32, 64, [24, 0], [32, 13]),
+    "queries-past-the-prompt-window": _case(32, 64, [24, 0], [9, 13],
+                                            window=12),
+    "chunk-under-a-block": _case(5, 32, [20, 3]),
+    "chunk-of-no-whole-blocks": _case(20, 64, [24, 40], window=10),
+}
+
+
+@pytest.mark.parametrize("case", BLOCKED_CASES.values(),
+                         ids=list(BLOCKED_CASES))
+def test_blocked_prefill_is_plain_prefill(case):
+    """`prefill_attention_blocked` (tiles of `key_block` queries x
+    `key_block` keys, a query block visiting the key blocks it can see:
+    what a step program takes from `BLOCKED_FROM` queries x keys a row
+    on) is `prefill_attention`, at every query of the padded chunk, and
+    the numpy oracle at every query of the prompt."""
     from aphrodite_tpu.ops.attention import (BLOCKED_FROM,
                                              prefill_attention_blocked)
     assert BLOCKED_FROM > 4096 * 1024      # Mistral's 1,024-token
     # prompts, four a step, keep the plain function
     rng = np.random.default_rng(5)
-    b, s_new, Hq, Hkv, d = 2, 8, 4, 2, 16
-    prefix = kv_len - s_new
+    s_new, kv_len, window = case["s_new"], case["kv_len"], case["window"]
+    b, Hq, Hkv, d = len(case["ctx"]), 4, 2, 16
     q = rng.normal(size=(b, s_new, Hq, d)).astype(np.float32)
     k = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
     v = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
-    ctx = np.array([prefix, prefix - 5], dtype=np.int32)
-    kv_valid = ctx + np.array([s_new, s_new - 2], dtype=np.int32)
+    ctx = np.array(case["ctx"], dtype=np.int32)
+    kv_valid = ctx + np.array(case["new"], dtype=np.int32)
     alibi = np.array([0.5, 0.25, 0.125, 0.0625], np.float32) \
-        if slopes else None
+        if case["slopes"] else None
     scale = 1 / np.sqrt(d)
     expected = numpy_prefill(q, k, v, ctx, kv_valid, scale, window=window,
                              slopes=alibi)
@@ -509,12 +548,149 @@ def test_blocked_prefill_is_plain_prefill(window, slopes, kv_len):
               alibi_slopes=None if alibi is None else jnp.array(alibi))
     got = np.array(prefill_attention_blocked(*args, key_block=8, **kw))
     plain = np.array(prefill_attention(*args, **kw))
+    assert got.shape == plain.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
     for bi, n in enumerate(kv_valid - ctx):
         np.testing.assert_allclose(got[bi, :n], expected[bi, :n],
                                    rtol=2e-5, atol=2e-5)
-        np.testing.assert_allclose(got[bi, :n], plain[bi, :n],
-                                   rtol=2e-5, atol=2e-5)
+
+
+def test_blocked_prefill_never_reads_a_tile_no_query_can_see():
+    """NaN in every key and value of the key blocks that no query block
+    of the chunk can see (before the first query's window, after the
+    last valid key): the output is finite and what zeros there give.
+    Scored and masked, as every block was before the walk, a NaN value
+    would have reached the sum through `0 * NaN`."""
+    from aphrodite_tpu.ops.attention import (prefill_attention_blocked,
+                                             prefill_tile_ranges)
+    rng = np.random.default_rng(11)
+    s_new, kv_len, window, block = 32, 160, 10, 8
+    ctx = np.array([64, 72], dtype=np.int32)
+    kv_valid = ctx + np.array([32, 20], dtype=np.int32)
+    first, stop = prefill_tile_ranges(ctx, kv_valid, s_new, kv_len, block,
+                                      window, xp=np)
+    seen = np.zeros(kv_len // block, bool)
+    for lo, hi in zip(first, stop):
+        seen[lo:hi] = True
+    assert seen.sum() == 6 and seen[6:12].all()
+    q = rng.normal(size=(2, s_new, 4, 16)).astype(np.float32)
+    k = rng.normal(size=(2, kv_len, 2, 16)).astype(np.float32)
+    v = rng.normal(size=(2, kv_len, 2, 16)).astype(np.float32)
+    unseen = np.repeat(~seen, block)
+    clean = [np.where(unseen[None, :, None, None], 0.0, x) for x in (k, v)]
+    dirty = [np.where(unseen[None, :, None, None], np.nan, x)
+             for x in (k, v)]
+
+    def attend(kk, vv):
+        return np.array(prefill_attention_blocked(
+            jnp.array(q), jnp.array(kk), jnp.array(vv), jnp.array(ctx),
+            jnp.array(kv_valid), 0.25, sliding_window=window,
+            key_block=block))
+    got = attend(*dirty)
     assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, attend(*clean))
+    assert not np.isfinite(np.array(prefill_attention(
+        jnp.array(q), *map(jnp.array, dirty), jnp.array(ctx),
+        jnp.array(kv_valid), 0.25, sliding_window=window))).all()
+
+
+#: (queries, keys, key_block, context, window) -> (visited, padded):
+#: ISSUE 39's counts of the two benchmark cells that take the function
+TILE_COUNTS = {
+    "phi-full-or-cross": ((2048, 2048, 512, 0, None), (10, 16)),
+    "phi-window-512": ((2048, 2048, 512, 0, 512), (7, 16)),
+    "smallthinker-full-chunk-2": ((2048, 8192, 512, 2048, None), (26, 64)),
+    "smallthinker-full-chunk-3": ((2048, 8192, 512, 4096, None), (42, 64)),
+    "smallthinker-full-chunk-4": ((2048, 8192, 512, 6144, None), (58, 64)),
+    "smallthinker-window-chunk-2": ((2048, 7168, 512, 2048, 4096),
+                                    (26, 56)),
+    "smallthinker-window-chunk-3": ((2048, 7168, 512, 4096, 4096),
+                                    (36, 56)),
+    "smallthinker-window-chunk-4": ((2048, 6144, 512, 4096, 4096),
+                                    (36, 48)),
+}
+
+
+@pytest.mark.parametrize("shape,want", TILE_COUNTS.values(),
+                         ids=list(TILE_COUNTS))
+def test_the_tile_count_rule_is_what_the_program_visits(shape, want):
+    """`count_prefill_tiles` (the host's count, behind
+    `aphrodite:prefill_attn_tiles_visited_total`) at a cell's real
+    sizes, and at a 64th of them against the count that the program
+    carries out of its loops; a brute-force reading of the mask (a
+    tile is live when some query of it sees some key of it) says the
+    same, since one row's visible keys are one run."""
+    from aphrodite_tpu.ops.attention import (count_prefill_tiles,
+                                             prefill_attention_tiles)
+    s_new, kv_len, block, ctx, window = shape
+    assert count_prefill_tiles([ctx], [ctx + s_new], s_new, kv_len,
+                               window, block) == want
+    s_new, kv_len, block, ctx = (x // 64 for x in shape[:4])
+    window = window and window // 64
+    valid = [ctx + s_new]
+    assert count_prefill_tiles([ctx], valid, s_new, kv_len, window,
+                               block) == want
+    rng = np.random.default_rng(2)
+    q = rng.normal(size=(1, s_new, 2, 8)).astype(np.float32)
+    k = rng.normal(size=(1, kv_len, 1, 8)).astype(np.float32)
+    _, visited = prefill_attention_tiles(
+        jnp.array(q), jnp.array(k), jnp.array(k),
+        jnp.array([ctx], jnp.int32), jnp.array(valid, jnp.int32), 1.0,
+        sliding_window=window, key_block=block)
+    assert int(visited) == want[0]
+    mask = np.array(make_causal_mask(s_new, jnp.array([ctx]), kv_len,
+                                     window))[0]
+    mask &= np.arange(kv_len) < valid[0]
+    live = mask.reshape(s_new // block, block, kv_len // block,
+                        block).any(axis=(1, 3))
+    assert (int(live.sum()), live.size) == want
+
+
+def test_a_prompt_steps_tile_count_over_its_layers():
+    """Phi-4-mini-flash's prompt step, 8 window layers and 8 that see
+    every key: 136 of 256 tiles; a row that holds nothing (a pad row)
+    widens no range, and rows at different contexts take the union."""
+    from aphrodite_tpu.ops.attention import count_prefill_tiles
+    full = count_prefill_tiles([0], [2048], 2048, 2048)
+    window = count_prefill_tiles([0], [2048], 2048, 2048, 512)
+    assert (8 * full[0] + 8 * window[0], 8 * full[1] + 8 * window[1]) \
+        == (136, 256)
+    assert count_prefill_tiles([0, 0], [2048, 0], 2048, 2048, 512) == \
+        window
+    # a row behind 4,096 cached tokens beside one behind none: from
+    # the second row's first key to the first row's last (a range, so
+    # what lies between the rows' runs is visited too)
+    assert count_prefill_tiles([4096, 0], [6144, 2048], 2048, 8192,
+                               4096) == (9 + 10 + 11 + 12, 64)
+    assert count_prefill_tiles([0], [0], 2048, 2048) == (0, 16)
+    # SmallThinker's three blocked chunks, 3 full and 9 window layers:
+    # what the cell's counters read over a window, 62.5%
+    from benchmarks.prefill_ab import CELLS
+    tiles = np.array([count_prefill_tiles(
+        [ctx], [ctx + queries], queries, keys, window or None)
+        for _, queries, keys, ctx, window, *_ in CELLS[2:]])
+    assert tuple(3 * tiles[:3].sum(0) + 9 * tiles[3:].sum(0)) == \
+        (1260, 2016)
+
+
+def test_prefill_ab_check_arm_rehearses_on_the_cpu(monkeypatch, capsys):
+    """`benchmarks/prefill_ab.py --check` at a toy geometry: the call
+    is compared with `prefill_attention`, the tiles it visits are said
+    by the function's own rule, and nothing is timed off the chip."""
+    from benchmarks import prefill_ab
+    assert [c[1:5] for c in prefill_ab.CELLS[:2]] == [
+        (2048, 2048, 0, 0), (2048, 2048, 0, 512)]
+    monkeypatch.setattr("sys.argv", [
+        "prefill_ab.py", "--queries", "32", "--keys", "64", "--ctx", "16",
+        "--window", "12", "--block", "8", "--heads", "4", "--kv-heads",
+        "2", "--head-dim", "16", "--check"])
+    prefill_ab.main()
+    said = capsys.readouterr().out
+    assert "window=12: 12 of 32 tiles" in said
+    (check,) = [line for line in said.splitlines() if "check:" in line]
+    assert "finite: True" in check
+    assert float(check.split("=")[1].split()[0]) < 1e-2
+    assert "whole call" not in said
 
 
 # ---- a causal window over a table that slides ----

@@ -185,6 +185,81 @@ def test_engine_logits_against_the_reference(chunk, tmp_path, monkeypatch):
     assert manager.get_num_free_state_slots() == slots
 
 
+def test_a_blocked_prompt_steps_tiles_are_counted(tmp_path, monkeypatch):
+    """Where the runner builds a prompt step that takes the blocked
+    attention (`takes_blocked_prefill`) it counts the tiles the walk
+    visits and those of the padded rectangle, each page group's view
+    times the layers that read it; a step under the threshold counts
+    neither. Here the threshold is 16 queries x 64 keys and a tile 8 x
+    8, so the chunks behind a cached prefix count and a first chunk
+    does not; the count is held to the mask itself (a tile is live when
+    some query of it sees some key of it: one row's keys are one run,
+    so the rule's range is exact)."""
+    import functools
+
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    from aphrodite_tpu.executor import model_runner
+    from aphrodite_tpu.modeling.layers import attention as layer
+    from aphrodite_tpu.modeling.models import phi4flash
+    from aphrodite_tpu.ops.attention import make_causal_mask
+    block = 8
+    monkeypatch.setattr(phi4flash, "PREFILL_BLOCKED_FROM", CHUNK * 64)
+    monkeypatch.setattr(layer, "prefill_attention_blocked",
+                        functools.partial(layer.prefill_attention_blocked,
+                                          key_block=block))
+    monkeypatch.setattr(model_runner, "count_prefill_tiles",
+                        functools.partial(model_runner.count_prefill_tiles,
+                                          key_block=block))
+    s = Served(tmp_path, monkeypatch)
+    runner = s.engine.executor.model_runner
+    assert runner.prefill_blocked_from == CHUNK * 64
+    counts, groups = s.engine.tracer.counts, runner.page_groups
+    names = ("attn.prefill_tiles_visited", "attn.prefill_tiles_padded")
+    want, steps, count = [0, 0], [], runner._count_prefill_tiles
+
+    def spy(group_rows, views, ctx_lens, plens, padded_len, use_prefix):
+        steps.append((padded_len, use_prefix))
+        for g, (rows, view) in enumerate(zip(group_rows, views)):
+            kv_len = view.block_tables.shape[1] * PAGE \
+                if use_prefix else padded_len
+            if padded_len * kv_len < CHUNK * 64:
+                continue
+            (let_go, _), = rows
+            ctx = int(ctx_lens[0]) - let_go if use_prefix else 0
+            mask = np.array(make_causal_mask(
+                padded_len, jnp.array([ctx]), kv_len,
+                WINDOW if groups.kinds[g] == "window" else None))[0]
+            mask &= np.arange(kv_len) < ctx + int(plens[0])
+            live = mask.reshape(padded_len // block, block,
+                                kv_len // block, block).any(axis=(1, 3))
+            want[0] += int(live.sum()) * groups.readers[g]
+            want[1] += live.size * groups.readers[g]
+        return count(group_rows, views, ctx_lens, plens, padded_len,
+                     use_prefix)
+    monkeypatch.setattr(runner, "_count_prefill_tiles", spy)
+    # ten tokens: one step of 16 queries on their own 16 keys
+    s.run([_prompt(4, 10)], 2)
+    assert steps == [(16, False)] and want == [0, 0]
+    assert [counts[n] for n in names] == [0, 0]
+    # fifty: a first chunk, then three behind a prefix on a table of
+    # eight pages, each of its groups' views past the threshold
+    s.run([_prompt(5)], 2)
+    assert steps[1:] == [(16, False), (16, True), (16, True), (16, True)]
+    assert [counts[n] for n in names] == want
+    # two query blocks x eight key blocks, four layers (a window
+    # layer's group each, the full group's two), three steps
+    assert want == [108, 2 * 8 * 4 * 3]
+    exported = {metric: total(s.engine.tracer.seconds, counts)
+                for metric, _, total in _STAGE_COUNTERS}
+    assert exported["aphrodite:prefill_attn_tiles_visited_total"] == \
+        want[0]
+    assert exported["aphrodite:prefill_attn_tiles_padded_total"] == want[1]
+    for name in ("aphrodite:prefill_attn_tiles_visited_total",
+                 "aphrodite:prefill_attn_tiles_padded_total"):
+        with open(os.path.join(ROOT, "README.md")) as f:
+            assert name in f.read()
+
+
 def test_rows_that_swap_slots_and_a_slot_left_dirty(served):
     """Two prompts together, then again in the other order: each takes
     the slot the other had (and finds it as the other left it), and
