@@ -215,6 +215,7 @@ class SequenceGroup:
         prefix: Optional[Prefix] = None,
         lora_request=None,
         deadline: Optional[float] = None,
+        final_only: bool = False,
     ) -> None:
         self.request_id = request_id
         self.seqs_dict = {seq.seq_id: seq for seq in seqs}
@@ -226,6 +227,10 @@ class SequenceGroup:
         # the scheduler expires the group if it is still waiting,
         # never computed, past this instant. None = no deadline.
         self.deadline = deadline
+        # Nobody streams the request: the engine hands out its
+        # finished output and none before
+        # (`AphroditeEngine._outputs_of`).
+        self.final_only = final_only
         # Mid-stream continuation (engine resume seam): how many
         # output tokens were already emitted to the client by a prior
         # incarnation of this request, and the text they detokenized
@@ -270,6 +275,10 @@ class SequenceGroup:
     def get_seqs(self, status: Optional[SequenceStatus] = None
                  ) -> List[Sequence]:
         seqs = self.seqs_dict.values()
+        if len(seqs) == 1:
+            # the common group, asked ten times a row a round
+            (seq,) = seqs
+            return [seq] if status is None or seq.status == status else []
         if status is None:
             return list(seqs)
         return [s for s in seqs if s.status == status]
@@ -305,7 +314,11 @@ class SequenceGroup:
             raise ValueError(f"Sequence {seq_id} not found.")
 
     def is_finished(self) -> bool:
-        return all(s.is_finished() for s in self.seqs_dict.values())
+        # asked several times a row a round: a plain loop, no generator
+        for seq in self.seqs_dict.values():
+            if seq.status not in _FINISHED:
+                return False
+        return True
 
     def __repr__(self) -> str:
         return (f"SequenceGroup(request_id={self.request_id}, "

@@ -88,6 +88,8 @@ NAMES = (
                             # step's program starts them from zeros
     "ssm.decode_rows",      # decode rows of a model with state slots
     "ssm.prefill_tokens",   # prompt tokens its chunk scans went over
+    "ssm.slot_waits",       # admission verdicts of "later" for want of a
+                            # state slot while the pages were there
     "attn.page_reads_shared",  # a decode step's live pages, each group's
                             # times the layers that read them
     "attn.prefill_tiles_visited",  # (query block, key block) tiles the

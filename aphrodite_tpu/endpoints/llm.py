@@ -104,7 +104,9 @@ class LLM:
         request_id = str(next(self.request_counter))
         self.engine.add_request(request_id, prompt, sampling_params,
                                 prompt_token_ids, prefix_pos=prefix_pos,
-                                lora_request=lora_request)
+                                lora_request=lora_request,
+                                # `_run_engine` keeps finished outputs alone
+                                final_only=True)
 
     def _run_engine(self, use_tqdm: bool) -> List[RequestOutput]:
         pbar = None

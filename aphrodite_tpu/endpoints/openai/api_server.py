@@ -41,7 +41,8 @@ from aphrodite_tpu.endpoints.openai.protocol import (
     CompletionResponseStreamChoice, CompletionStreamResponse,
     DeltaMessage, ErrorResponse, LogProbs, ModelCard, ModelList,
     ModelPermission, TokenizeRequest, TokenizeResponse, UsageInfo)
-from aphrodite_tpu.endpoints.utils import (install_lifecycle,
+from aphrodite_tpu.endpoints.utils import (final_output,
+                                           install_lifecycle,
                                            request_disconnected,
                                            resume_denied,
                                            resume_token_ids,
@@ -323,20 +324,16 @@ class OpenAIServer:
                 emitted=emitted)
 
         async def consume(i: int, prompt) -> Optional[RequestOutput]:
-            """Drain one generator; all prompts run CONCURRENTLY so the
-            engine continuous-batches them (a sequential drain would
-            serialize the batch)."""
+            """One prompt's finished output (nobody streams it: the
+            engine builds no other); all prompts run CONCURRENTLY so
+            the engine continuous-batches them (a sequential drain
+            would serialize the batch)."""
             kwargs = dict(prompt_token_ids=prompt) \
                 if isinstance(prompt, list) else dict()
             text = None if isinstance(prompt, list) else prompt
-            final: Optional[RequestOutput] = None
-            async for output in self.engine.generate(
-                    text, sampling_params, f"{request_id}-{i}", **kwargs):
-                if await request_disconnected(request):
-                    await self.engine.abort(f"{request_id}-{i}")
-                    return None
-                final = output
-            return final
+            return await final_output(request, await self.engine.add_request(
+                f"{request_id}-{i}", text, sampling_params,
+                final_only=True, **kwargs))
 
         try:
             finals = await asyncio.gather(
@@ -493,21 +490,17 @@ class OpenAIServer:
                                            prompt, request_id,
                                            emitted=emitted)
 
-        final: Optional[RequestOutput] = None
         try:
-            async for output in self.engine.generate(
-                    prompt, sampling_params, request_id):
-                if await request_disconnected(request):
-                    await self.engine.abort(request_id)
-                    return _error("Client disconnected", status=499)
-                final = output
+            final = await final_output(request, await self.engine.add_request(
+                request_id, prompt, sampling_params, final_only=True))
         except RequestRejectedError as e:
             return _overloaded(e)
         except RequestTimeoutError as e:
             return _timed_out(e)
         except EngineDrainingError as e:
             return _draining(e)
-        assert final is not None
+        if final is None:
+            return _error("Client disconnected", status=499)
         choices = [
             ChatCompletionResponseChoice(
                 index=i,

@@ -60,6 +60,35 @@ async def request_disconnected(request: web.Request) -> bool:
     return request.transport is None or request.transport.is_closing()
 
 
+#: How often a handler that waits for a request's one output looks
+#: whether its client is still there.
+DISCONNECT_POLL_S = 1.0
+
+
+async def final_output(request: web.Request, stream):
+    """The finished `RequestOutput` of a `final_only` request's stream.
+    None, and the request aborted, when the client hung up: the engine
+    sends such a stream nothing before the end, so no output wakes the
+    handler to look, and it looks every `DISCONNECT_POLL_S`. What the
+    stream was failed with is raised, and a handler that ends before
+    its stream aborts the request."""
+    pending = asyncio.ensure_future(stream.__anext__())
+    try:
+        while True:
+            done, _ = await asyncio.wait({pending},
+                                         timeout=DISCONNECT_POLL_S)
+            if done:
+                output = pending.result()
+                if output.finished:
+                    return output
+                pending = asyncio.ensure_future(stream.__anext__())
+            elif await request_disconnected(request):
+                return None
+    finally:
+        pending.cancel()
+        stream.cancel()         # nothing to do once the stream finished
+
+
 def retry_after_seconds(seconds: float) -> int:
     """`Retry-After` wire value: whole seconds, at least 1. The ONE
     place the rounding rule lives — every frontend emits through it

@@ -279,9 +279,14 @@ class AphroditeEngine:
         prefix_pos: Optional[int] = None,
         lora_request=None,
         emitted_token_ids: Optional[List[int]] = None,
+        final_only: bool = False,
     ) -> None:
         """Tokenize, build the seq group, hand to the scheduler
         (reference add_request :387-469).
+
+        `final_only`: nobody streams this request, so `step` returns
+        one `RequestOutput` for it, the finished one, and builds none
+        before (`_outputs_of`).
 
         `emitted_token_ids` is the CONTINUATION form (the mid-stream
         failover resume seam): the request previously generated these
@@ -354,7 +359,8 @@ class AphroditeEngine:
                                   arrival_time, prefix=prefix,
                                   lora_request=lora_request,
                                   deadline=self._deadline_of(
-                                      sampling_params, arrival_time))
+                                      sampling_params, arrival_time),
+                                  final_only=final_only)
         if emitted_token_ids:
             seq_group.resumed_tokens = len(emitted_token_ids)
             # The joint output may already satisfy a stop condition
@@ -1079,12 +1085,8 @@ class AphroditeEngine:
         self._record_latencies(touched, tokens_of=tokens_of)
         self.scheduler.free_finished_seq_groups()
 
-        request_outputs = [
-            RequestOutput.from_seq_group(g) for g in touched
-        ]
-        for seq_group in scheduler_outputs.ignored_seq_groups:
-            request_outputs.append(
-                RequestOutput.from_seq_group(seq_group))
+        request_outputs = self._outputs_of(
+            touched, scheduler_outputs.ignored_seq_groups)
         generation_tokens = sum(tokens_of[id(g)] for g in decode_groups)
         self.admission.observe_round(
             scheduler_outputs.num_prefill_tokens, generation_tokens)
@@ -1123,7 +1125,7 @@ class AphroditeEngine:
         decode_groups = scheduler_outputs.decode_groups
         if ahead:
             for group in scheduler_outputs.sampling_groups:
-                (seq,) = group.get_seqs()
+                (seq,) = group.seqs_dict.values()
                 if group.is_finished() or not seq.data.in_flight:
                     skipped.add(id(group))
                 seq.data.in_flight = 0
@@ -1153,11 +1155,8 @@ class AphroditeEngine:
         self._record_latencies(touched, tokens_of=tokens_of)
         self.scheduler.free_finished_seq_groups()
 
-        request_outputs = [
-            RequestOutput.from_seq_group(g) for g in touched
-        ]
-        for seq_group in scheduler_outputs.ignored_seq_groups:
-            request_outputs.append(RequestOutput.from_seq_group(seq_group))
+        request_outputs = self._outputs_of(
+            touched, scheduler_outputs.ignored_seq_groups)
         generation_tokens = sum(tokens_of[id(g)] for g in decode_groups)
         # Feed the admission controller's throughput EWMAs — the basis
         # of predicted-TTFT shedding and Retry-After estimates.
@@ -1171,6 +1170,18 @@ class AphroditeEngine:
                 scheduler_outputs,
                 generation_tokens=generation_tokens))
         return request_outputs
+
+    @staticmethod
+    def _outputs_of(touched, ignored) -> List[RequestOutput]:
+        """A round's outputs: one of every group it gave a token that
+        somebody streams, of a `final_only` group the finished one
+        alone, and one of every group the scheduler ignored (they are
+        finished). An output a row a round that its handler threw
+        away cost the step thread the building and the loop thread
+        the delivery, 128 times a round in a wide batch."""
+        return [RequestOutput.from_seq_group(g) for g in touched
+                if not g.final_only or g.is_finished()] + \
+            [RequestOutput.from_seq_group(g) for g in ignored]
 
     def _record_latencies(self, scheduled_seq_groups,
                           tokens_of=None) -> None:
@@ -1264,6 +1275,23 @@ class AphroditeEngine:
             seq_group.prompt_logprobs = outputs.prompt_logprobs
 
         samples = outputs.samples
+        params = seq_group.sampling_params
+        if len(samples) == 1 and len(seq_group.seqs_dict) == 1 and \
+                not params.use_beam_search:
+            # The common row, one running sequence given one token:
+            # what the general path below does for it, without its
+            # lists and maps.
+            (seq,) = seq_group.seqs_dict.values()
+            sample = samples[0]
+            if seq.status is SequenceStatus.RUNNING and \
+                    sample.parent_seq_id == seq.seq_id:
+                seq.append_token_id(sample.output_token, sample.logprobs)
+                seq.persistent_data = sample.persistent_data
+                self._decode_sequence(seq, params)
+                self._check_stop(seq, params)
+                if seq.is_finished():
+                    self.scheduler.free_seq(seq)
+                return
         parent_seqs = seq_group.get_seqs(status=SequenceStatus.RUNNING)
         existing_finished_seqs = seq_group.get_finished_seqs()
         parent_child_dict = {seq.seq_id: [] for seq in parent_seqs}
@@ -1404,7 +1432,10 @@ class AphroditeEngine:
         (new_tokens, new_output_text, prefix_offset,
          read_offset) = detokenize_incrementally(
              tokenizer,
-             all_input_ids=seq.get_token_ids(),
+             # once the window is seeded only the last id is read:
+             # no joint list of prompt and output a token
+             all_input_ids=seq.get_token_ids() if seq.tokens is None
+             else seq.get_output_token_ids(),
              prev_tokens=seq.tokens,
              prefix_offset=seq.prefix_offset,
              read_offset=seq.read_offset,

@@ -595,6 +595,7 @@ class AsyncAphrodite:
         arrival_time: Optional[float] = None,
         prefix_pos: Optional[int] = None,
         emitted_token_ids: Optional[List[int]] = None,
+        final_only: bool = False,
     ) -> AsyncStream:
         if self.log_requests:
             max_len = self.max_log_len if self.max_log_len is not None \
@@ -660,7 +661,8 @@ class AsyncAphrodite:
             # (token values derive from seed + output position alone)
             arrival_time=arrival_time or time.monotonic(),
             prefix_pos=prefix_pos,
-            emitted_token_ids=emitted_token_ids)
+            emitted_token_ids=emitted_token_ids,
+            final_only=final_only)
         self._idle_event.clear()     # no longer idle: work arrived
         return stream
 

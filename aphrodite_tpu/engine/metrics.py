@@ -189,6 +189,11 @@ _STAGE_COUNTERS = [
      "Prompt tokens the chunk scans of a model with state slots went "
      "over (a state layer's), summed over prompt steps.",
      lambda s, c: c["ssm.prefill_tokens"]),
+    ("aphrodite:ssm_slot_waits_total",
+     "Admission verdicts that put a prompt off for want of a state "
+     "slot while its pages were free (one each round the prompt at "
+     "the head of the queue waits so).",
+     lambda s, c: c["ssm.slot_waits"]),
     ("aphrodite:moe_tokens_routed_total",
      "Token-expert pairs the expert layers computed, counted in the "
      "step programs.", lambda s, c: c["moe.tokens_routed"]),
@@ -363,6 +368,7 @@ class StatLogger:
         self.num_generation_tokens: List[int] = []
         # Cumulative totals already exported, by counter, for deltas.
         self._exported: Dict[object, float] = {}
+        self._children: Dict[object, object] = {}
         self.metrics = Metrics(labelnames=list(self.labels.keys()))
         for counter, _ in self.metrics.stage_counters:
             # A labelled counter has no sample until it is first
@@ -370,14 +376,23 @@ class StatLogger:
             (counter.labels(**self.labels) if self.labels
              else counter).inc(0)
 
+    def _labeled(self, metric):
+        """`metric` under this logger's labels: looked up once a
+        metric, not at each of a round's hundred and more updates."""
+        if not self.labels:
+            return metric
+        child = self._children.get(metric)
+        if child is None:
+            child = self._children[metric] = metric.labels(**self.labels)
+        return child
+
     def _throughput(self, tracked: List[int], now: float) -> float:
         elapsed = now - self.last_local_log
         return sum(tracked) / elapsed if elapsed > 0 else 0.0
 
     def log(self, stats: Stats) -> None:
         m = self.metrics
-        labeled = (lambda metric: metric.labels(**self.labels)) \
-            if self.labels else (lambda metric: metric)
+        labeled = self._labeled
 
         def export(counter, total) -> None:
             """Raise `counter` to the cumulative `total` (a total that
@@ -414,12 +429,16 @@ class StatLogger:
             for counter, total in m.stage_counters:
                 export(counter, total(stats.stage_seconds,
                                       stats.stage_counts))
-        for t in stats.time_to_first_tokens:
-            labeled(m.histogram_time_to_first_token).observe(t)
-        for t in stats.time_per_output_tokens:
-            labeled(m.histogram_time_per_output_token).observe(t)
-        for t in stats.time_e2e_requests:
-            labeled(m.histogram_e2e_request_latency).observe(t)
+        for histogram, samples in (
+                (m.histogram_time_to_first_token,
+                 stats.time_to_first_tokens),
+                (m.histogram_time_per_output_token,
+                 stats.time_per_output_tokens),
+                (m.histogram_e2e_request_latency,
+                 stats.time_e2e_requests)):
+            observe = labeled(histogram).observe
+            for t in samples:
+                observe(t)
 
         self.num_prompt_tokens.append(stats.num_prompt_tokens)
         self.num_generation_tokens.append(stats.num_generation_tokens)

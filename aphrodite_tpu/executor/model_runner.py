@@ -80,6 +80,15 @@ def _bucket(value: int, buckets: List[int]) -> int:
 #: padded to that (`ModelRunner._work_length`).
 _WIDE_TABLE = 128
 _WIDE_PAGES_BUCKET = 64
+#: A decode batch of this many rows or more takes its tables as wide
+#: ones, and no narrower than `_WIDE_TABLE`: its widest row decides
+#: the width, many rows that joined together grow together, and in
+#: 8-page steps 128 rows growing from 512 tokens to 1,536 walked
+#: through eight programs of the largest bucket, each a stall of 7 s
+#: (17 s on an empty cache) with every row waiting. The tables' bytes
+#: are nothing beside that (`[128, 128]` int32 a step) and a dead item
+#: of the work list costs the kernel half a microsecond.
+_WIDE_ROWS = 64
 
 
 def _pow2_bucket(value: int, lo: int = 16) -> int:
@@ -324,8 +333,11 @@ class ModelRunner:
 
     # ---- jitted bodies ----
 
-    def _table_width(self, pages: int) -> int:
-        """A block table's padded width (a step program's key)."""
+    def _table_width(self, pages: int, rows: int = 1) -> int:
+        """A block table's padded width (a step program's key), for a
+        decode batch padded to `rows`."""
+        if rows >= _WIDE_ROWS:
+            pages = max(pages, _WIDE_TABLE)
         bucket = self.pages_bucket if pages <= _WIDE_TABLE \
             else _WIDE_PAGES_BUCKET
         return max(bucket, -(-pages // bucket) * bucket)
@@ -1001,8 +1013,9 @@ class ModelRunner:
                      ppc: int) -> int:
         """The length a decode work list is padded to (a step
         program's key): `padded_work_length`'s batch x 2^k; for a wide
-        table (`_WIDE_TABLE`) the dense count, a cell for every chunk
-        of every row, once the list is within a factor two of it. Rows
+        table (`_WIDE_TABLE`) or a batch of `_WIDE_ROWS` rows the dense
+        count, a cell for every chunk of every row, once the list is
+        within a factor two of it. Rows
         of long contexts are alike, so their lists lie just under the
         dense count, and on which side of the last power of two
         depends on how many rows of the bucket are padding: at 8k
@@ -1010,7 +1023,8 @@ class ModelRunner:
         joined, for a few dead items the kernel skips."""
         length = padded_work_length(items, padded_batch, max_pages, ppc)
         dense = padded_batch * -(-max_pages // ppc)
-        if max_pages > _WIDE_TABLE and 2 * length >= dense:
+        wide = max_pages > _WIDE_TABLE or padded_batch >= _WIDE_ROWS
+        if wide and 2 * length >= dense:
             return dense
         return length
 
@@ -1037,7 +1051,8 @@ class ModelRunner:
         pad_rows = [0] * (padded_batch - batch)
         if group_rows is None:
             layout, views = (), None
-            widths = [self._table_width(max(len(t) for t in tables_list))]
+            widths = [self._table_width(max(len(t) for t in tables_list),
+                                        padded_batch)]
         else:
             kinds = self.page_groups.kinds
             # (a window group's table is never narrower than the
@@ -1047,7 +1062,7 @@ class ModelRunner:
             floor = -(-(self.page_groups.window or 0) // self.page_size) + 1
             widths = [self._table_width(max(
                 [floor if kind == "window" else 1] +
-                [len(row[g][1]) for row in group_rows]))
+                [len(row[g][1]) for row in group_rows]), padded_batch)
                 for g, kind in enumerate(kinds)]
             layout, views = tuple(widths), []
 

@@ -61,6 +61,15 @@ def shard_along(x: jax.Array, axis: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def replicated_specs(params) -> Dict[str, SpecDict]:
+    """Every leaf of a parameter tree (`{bucket: {leaf: array}}`, or
+    its `jax.eval_shape`) replicated: the specs of a model that one
+    chip holds whole."""
+    return {key: {name: P(*([None] * leaf.ndim))
+                  for name, leaf in bucket.items()}
+            for key, bucket in params.items()}
+
+
 class LinearMethod:
     """Creates and applies the weights of a linear layer.
 

@@ -597,6 +597,8 @@ class Sampler:
         transfer just their own logprob rows from device."""
         outputs: List[SequenceGroupOutput] = []
         row = 0
+        # plain numbers once a step, not a numpy scalar a row
+        greedy, lp_greedy = greedy.tolist(), lp_greedy.tolist()
         for group_idx, (seq_ids, params) in enumerate(metadata.seq_groups):
             is_prompt = group_idx < len(metadata.prompt_lens)
 
@@ -620,9 +622,9 @@ class Sampler:
 
             samples: List[SequenceOutput] = []
             if params.sampling_type == SamplingType.GREEDY:
-                token = int(greedy[row])
+                token = greedy[row]
                 lp = self._topk_logprobs(topk_vals, topk_idx, row, params,
-                                         token, float(lp_greedy[row]))
+                                         token, lp_greedy[row])
                 samples.append(SequenceOutput(
                     seq_ids[0], token, lp,
                     metadata.output_metadata.get(seq_ids[0])))

@@ -24,6 +24,7 @@ _MODELS = {
     "OPTForCausalLM": ("opt", "OPTForCausalLM"),
     "GPTJForCausalLM": ("gpt_j", "GPTJForCausalLM"),
     "GPTNeoXForCausalLM": ("gpt_neox", "GPTNeoXForCausalLM"),
+    "JambaForCausalLM": ("jamba", "JambaForCausalLM"),
     "PhiForCausalLM": ("phi", "PhiForCausalLM"),
     "Phi4FlashForCausalLM": ("phi4flash", "Phi4FlashForCausalLM"),
     "Qwen2ForCausalLM": ("qwen2", "Qwen2ForCausalLM"),

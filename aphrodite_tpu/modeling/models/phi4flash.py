@@ -14,7 +14,8 @@ with no positional encoding anywhere, and the logits `LN_f(x) E^T` over
 the tied embedding. The mixer by layer index
 (`transformers_utils/configs/phi4flash.py::layer_kinds`):
 
-- **mamba** (`MambaMixer`): a selective state-space layer. Its state
+- **mamba** (`layers/mamba.py::MambaMixer`, shared with
+  `models/jamba.py`): a selective state-space layer. Its state
   `[d_state, d_inner]` (float32) and the last `d_conv - 1` inputs of its
   causal convolution live in the sequence's STATE SLOT, beside the KV
   pages (`common/config.py::StateSpec`): a prompt chunk starts from the
@@ -55,13 +56,10 @@ from aphrodite_tpu.modeling.layers.attention import PagedAttention
 from aphrodite_tpu.modeling.layers.layernorm import layer_norm, rms_norm
 from aphrodite_tpu.modeling.layers.linear import (
     ColumnParallelLinear, LinearMethod, MergedColumnParallelLinear,
-    QKVParallelLinear, RowParallelLinear)
+    QKVParallelLinear, RowParallelLinear, replicated_specs)
+from aphrodite_tpu.modeling.layers.mamba import MambaMixer
 from aphrodite_tpu.modeling.layers.vocab_embedding import (
     ParallelLMHead, VocabParallelEmbedding)
-from aphrodite_tpu.ops.pallas.ssm_scan import (selective_scan,
-                                               selective_update,
-                                               ssm_scan_ref,
-                                               ssm_update_ref)
 
 KVCache = Tuple[jax.Array, jax.Array]
 Params = Dict[str, Dict[str, jax.Array]]
@@ -75,121 +73,6 @@ PREFILL_BLOCKED_FROM = 1 << 21
 
 def lambda_init(layer_idx: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * layer_idx)
-
-
-def _replicated(params: Params) -> Dict[str, Dict[str, P]]:
-    return {key: {name: P(*([None] * leaf.ndim))
-                  for name, leaf in bucket.items()}
-            for key, bucket in params.items()}
-
-
-class MambaMixer:
-    """`[u ; z] = W_in h`; `u = silu(conv1d(u))`; `[dt ; B ; C] = W_x u`;
-    `delta = softplus(W_dt dt + b_dt)`; the selective scan with
-    `A = -exp(A_log)`; `W_out (y * silu(z))`. Returns the output and
-    `y`, the scan's result before the gate."""
-
-    def __init__(self, config, prefix: str, dtype,
-                 linear_method: Optional[LinearMethod]) -> None:
-        self.prefix = prefix
-        self.dtype = dtype
-        self.d_inner = config.mamba_d_inner
-        self.d_state = config.mamba_d_state
-        self.d_conv = config.mamba_d_conv
-        self.dt_rank = config.mamba_dt_rank
-        kw = dict(dtype=dtype, linear_method=linear_method)
-        self.in_proj = ColumnParallelLinear(
-            config.hidden_size, 2 * self.d_inner, bias=False, **kw)
-        self.x_proj = RowParallelLinear(
-            self.d_inner, self.dt_rank + 2 * self.d_state, bias=False, **kw)
-        self.dt_proj = ColumnParallelLinear(
-            self.dt_rank, self.d_inner, bias=True, **kw)
-        self.out_proj = RowParallelLinear(
-            self.d_inner, config.hidden_size, bias=False, **kw)
-
-    def init(self) -> Params:
-        p, d = self.prefix, self.d_inner
-        return {
-            f"{p}.in_proj": self.in_proj.init(),
-            f"{p}.conv1d": {
-                "weight": jnp.zeros((self.d_conv, d), dtype=self.dtype),
-                "bias": jnp.zeros((d,), dtype=self.dtype)},
-            f"{p}.x_proj": self.x_proj.init(),
-            f"{p}.dt_proj": self.dt_proj.init(),
-            f"{p}.ssm": {
-                "A_log": jnp.zeros((self.d_state, d), dtype=self.dtype),
-                "D": jnp.ones((d,), dtype=self.dtype)},
-            f"{p}.out_proj": self.out_proj.init(),
-        }
-
-    def __call__(self, params: Params, h: jax.Array, positions: jax.Array,
-                 cache: Optional[KVCache], metadata: InputMetadata):
-        """`cache`: the layer's `(tail, state)` arrays, `[slots + 1,
-        d_conv - 1 | d_state, d_inner]`; None runs a prompt from zeros
-        and keeps nothing."""
-        p = self.prefix
-        batch, seq = h.shape[:2]
-        x, z = jnp.split(self.in_proj(params[f"{p}.in_proj"], h), 2, axis=-1)
-        conv_w = params[f"{p}.conv1d"]["weight"].astype(jnp.float32)
-        conv_b = params[f"{p}.conv1d"]["bias"].astype(jnp.float32)
-        a = -jnp.exp(params[f"{p}.ssm"]["A_log"].astype(jnp.float32))
-        d = params[f"{p}.ssm"]["D"].astype(jnp.float32)
-        taps = self.d_conv - 1
-        slots = metadata.state_slots
-        if cache is None:
-            tail = jnp.zeros((1, taps, self.d_inner), self.dtype)
-            state = jnp.zeros((1, self.d_state, self.d_inner), jnp.float32)
-            slots = jnp.zeros((batch,), jnp.int32)
-        else:
-            tail, state = cache
-
-        # the convolution over [the slot's tail ; this step's inputs]
-        fresh = positions[:, 0] == 0
-        before = tail[slots]
-        if metadata.is_prompt:
-            before = jnp.where(fresh[:, None, None], 0, before)
-        window = jnp.concatenate([before, x], axis=1).astype(jnp.float32)
-        conv = conv_b + sum(conv_w[k] * window[:, k:k + seq]
-                            for k in range(self.d_conv))
-        u = jax.nn.silu(conv)                       # float32
-        dbc = self.x_proj(params[f"{p}.x_proj"], u.astype(self.dtype))
-        dt, b, c = jnp.split(
-            dbc, [self.dt_rank, self.dt_rank + self.d_state], axis=-1)
-        delta = jax.nn.softplus(self.dt_proj(
-            params[f"{p}.dt_proj"], dt).astype(jnp.float32))
-        b, c = b.astype(jnp.float32), c.astype(jnp.float32)
-
-        if metadata.is_prompt:
-            lens = metadata.prompt_lens if metadata.prompt_lens is not None \
-                else jnp.full((batch,), seq, jnp.int32)
-            # padding is passed over: delta 0 leaves the state as it is
-            live = jnp.arange(seq)[None, :] < lens[:, None]
-            delta = jnp.where(live[..., None], delta, 0.0)
-            # (the kernels are one chip's programs; the state arrays
-            # are too: `CacheEngine._allocate_state`)
-            if metadata.tp == 1:
-                y, state = selective_scan(u, delta, b, c, a, d, state,
-                                          slots, fresh)
-            else:
-                y, state = ssm_scan_ref(u, delta, b, c, a, d, state, slots,
-                                        fresh)
-            # the tail after the row's last live token
-            moved = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                w, n, taps, axis=0))(window, lens).astype(tail.dtype)
-            tail = tail.at[slots].set(moved)
-        else:
-            step = (x[:, 0], u[:, 0], delta[:, 0], b[:, 0], c[:, 0], a, d,
-                    state, tail, slots)
-            if metadata.tp == 1:
-                y, state, tail = selective_update(*step)
-            else:
-                y, state, tail = ssm_update_ref(*step)
-            y = y[:, None]
-        y = y.astype(self.dtype)
-        out = self.out_proj(params[f"{p}.out_proj"],
-                            y * jax.nn.silu(z.astype(jnp.float32)).astype(
-                                self.dtype))
-        return out, y, (None if cache is None else (tail, state))
 
 
 class DiffAttention:
@@ -403,7 +286,7 @@ class Phi4FlashForCausalLM:
         """One chip holds the model whole (the state arrays and the
         Pallas scan are single-device programs): every leaf
         replicated."""
-        return _replicated(jax.eval_shape(self.init_params))
+        return replicated_specs(jax.eval_shape(self.init_params))
 
     def __call__(self, params: Params, input_ids, positions,
                  kv_caches: Optional[List[KVCache]],

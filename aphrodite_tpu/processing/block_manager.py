@@ -41,7 +41,7 @@ from __future__ import annotations
 import enum
 from typing import Dict, List, Optional, Set, Tuple
 
-from aphrodite_tpu.common import faultinject
+from aphrodite_tpu.common import faultinject, tracing
 from aphrodite_tpu.common.block import (BlockTable, Device,
                                         PhysicalTokenBlock)
 from aphrodite_tpu.common.prefix import Prefix
@@ -119,13 +119,16 @@ class BlockSpaceManager:
         group_kinds: Optional[Tuple[str, ...]] = None,
         max_chunk_tokens: Optional[int] = None,
         num_state_slots: Optional[int] = None,
+        tracer: Optional[tracing.Tracer] = None,
     ) -> None:
         """`group_kinds`: "full" or "window" for each page group (one
         group by default: a window group where `sliding_window` is
         set). `max_chunk_tokens`: the longest prompt chunk the
         scheduler writes at once for a model with a window group
         (None: a prompt may come whole). `num_state_slots`: the state
-        slots of a model with recurrent state (None: it has none)."""
+        slots of a model with recurrent state (None: it has none).
+        `tracer`: the engine's, for `ssm.slot_waits`."""
+        self.tracer = tracer or tracing.Tracer()
         self.block_size = block_size
         self.num_total_gpu_blocks = num_gpu_blocks
         self.num_total_cpu_blocks = num_cpu_blocks
@@ -238,6 +241,9 @@ class BlockSpaceManager:
             if seqs > self.num_state_slots:
                 return AllocStatus.NEVER
             if seqs > len(self._free_state_slots):
+                if free - needed >= self.watermark_blocks + extra_reserved:
+                    # the pages were there: a want of slots alone
+                    self.tracer.add("ssm.slot_waits")
                 return AllocStatus.LATER
         if free - needed >= self.watermark_blocks + extra_reserved:
             return AllocStatus.OK

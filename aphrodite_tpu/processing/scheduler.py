@@ -166,7 +166,8 @@ class Scheduler:
             sliding_window=groups.window,
             group_kinds=groups.kinds,
             max_chunk_tokens=self.window_chunk_cap,
-            num_state_slots=cache_config.num_state_slots)
+            num_state_slots=cache_config.num_state_slots,
+            tracer=self.tracer)
         #: the most sequences admitted: a model with recurrent state
         #: has a slot for each, forks included
         self.max_num_seqs = min(

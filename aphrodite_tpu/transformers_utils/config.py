@@ -34,7 +34,8 @@ def get_config(model: str,
         from aphrodite_tpu.transformers_utils import configs
         cls = {"yi": configs.YiConfig, "qwen": configs.QWenConfig,
                "smallthinker": configs.SmallThinkerConfig,
-               "phi4flash": configs.Phi4FlashConfig}.get(declared)
+               "phi4flash": configs.Phi4FlashConfig,
+               "jamba": configs.JambaConfig}.get(declared)
         if cls is not None:
             return cls.from_pretrained(model, revision=revision)
     try:

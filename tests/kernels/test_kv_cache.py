@@ -120,32 +120,41 @@ def test_pallas_writer_interpret(distinct):
         np.array(got_v), exp_v.reshape(pages, page_size, hd))
 
 
-def test_pallas_prefill_page_writer_interpret():
+@pytest.mark.parametrize("hd,page_size,dtype", [
+    (256, 8, jnp.float32), (128, 16, jnp.bfloat16)],
+    ids=["two-heads-f32", "one-kv-head-bf16"])
+def test_pallas_prefill_page_writer_interpret(hd, page_size, dtype):
     """Whole-page prefill writer: full pages, a partial tail page,
-    prefix-offset pages, and OOB pad cells, vs a numpy oracle."""
+    prefix-offset pages, and OOB pad cells, vs a numpy oracle; at two
+    heads of 128 in float32, and at ONE KV head of 128 in bfloat16
+    pages of 16 tokens (AI21-Jamba2-3B's: a page's lane axis is one
+    lane tile)."""
     from aphrodite_tpu.ops.pallas.kv_write import write_kv_pages_prefill
     rng = np.random.default_rng(9)
-    pages, page_size, hd = 10, 8, 256
-    padded_len = 16                       # 2 page-blocks per sequence
+    pages = 10
+    padded_len = 2 * page_size            # 2 page-blocks per sequence
     B = 3
     k_pages = jnp.asarray(
-        rng.normal(size=(pages, page_size, hd)), jnp.float32)
+        rng.normal(size=(pages, page_size, hd)), dtype)
     v_pages = jnp.asarray(
-        rng.normal(size=(pages, page_size, hd)), jnp.float32)
-    knew = rng.normal(size=(B * padded_len, hd)).astype(np.float32)
-    vnew = rng.normal(size=(B * padded_len, hd)).astype(np.float32)
+        rng.normal(size=(pages, page_size, hd)), dtype)
+    knew = np.asarray(jnp.asarray(
+        rng.normal(size=(B * padded_len, hd)), dtype), np.float32)
+    vnew = np.asarray(jnp.asarray(
+        rng.normal(size=(B * padded_len, hd)), dtype), np.float32)
     # seq 0: 16 tokens -> pages 1,2 (both full)
     # seq 1: 11 tokens -> page 4 full, page 5 partial (3 rows)
     # seq 2: padded-out (no cells)
     pid = np.array([1, 2, 4, 5, pages, pages], dtype=np.int32)
     sblk = np.array([0, 1, 2, 3, 0, 0], dtype=np.int32)
-    vld = np.array([8, 8, 8, 3, 0, 0], dtype=np.int32)
+    vld = np.array([page_size, page_size, page_size, 3, 0, 0],
+                   dtype=np.int32)
     got_k, got_v = write_kv_pages_prefill(
-        jnp.asarray(knew), jnp.asarray(vnew), k_pages, v_pages,
-        jnp.asarray(pid), jnp.asarray(sblk), jnp.asarray(vld),
+        jnp.asarray(knew, dtype), jnp.asarray(vnew, dtype), k_pages,
+        v_pages, jnp.asarray(pid), jnp.asarray(sblk), jnp.asarray(vld),
         interpret=True)
-    exp_k = np.array(k_pages)
-    exp_v = np.array(v_pages)
+    exp_k = np.array(k_pages, np.float32)
+    exp_v = np.array(v_pages, np.float32)
     for c in range(6):
         if pid[c] >= pages:
             continue
@@ -153,8 +162,8 @@ def test_pallas_prefill_page_writer_interpret():
         rows_v = vnew[sblk[c] * page_size:(sblk[c] + 1) * page_size]
         exp_k[pid[c], :vld[c]] = rows[:vld[c]]
         exp_v[pid[c], :vld[c]] = rows_v[:vld[c]]
-    np.testing.assert_allclose(np.array(got_k), exp_k)
-    np.testing.assert_allclose(np.array(got_v), exp_v)
+    np.testing.assert_allclose(np.array(got_k, np.float32), exp_k)
+    np.testing.assert_allclose(np.array(got_v, np.float32), exp_v)
 
 
 def test_pallas_decode_writer_oob_first_and_last():

@@ -272,6 +272,63 @@ def test_decode_attention_compiles_at_phi4flash_shapes(
         on_v5e((B,), I32), new, new).compile()
 
 
+#: AI21-Jamba2-3B's attention layers: ONE KV head of 128 under 20 query
+#: heads, so the page's lane axis is one lane tile, a page copy is 4 KB
+#: and a row's packed query has 20 rows; 512-token items. (rows, table
+#: width, fused write): the cell's decode buckets with contexts of
+#: 513-1,536 tokens (a table 96 wide), the canary's one row, and 129
+#: rows (the decode bucket past the slots).
+MQA_CASES = [(rows, 96, True) for rows in (32, 64, 96, 128, 129)] + [
+    (1, 32, True), (128, 96, False), (128, 256, True)] + [
+    # a batch of `_WIDE_ROWS` rows or more: tables 128 wide
+    (rows, 128, True) for rows in (64, 96, 128)]
+
+
+@pytest.mark.parametrize("B,pps,fused", MQA_CASES)
+def test_decode_attention_compiles_at_jamba_shapes(on_v5e, B, pps, fused):
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk, head_block,
+        lane_bytes_of, padded_work_length, paged_decode_attention)
+    Hq, Hkv, d, page = 20, 1, 128, 16
+    assert head_block(Hkv, d, BF16) == 1
+    assert lane_bytes_of(Hkv, d, BF16) == 256
+    ppc = choose_pages_per_chunk(pps, page, 256)
+    assert ppc == 32
+    counts = [pps - 8 - i % 2 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    # 6.4 GB of pool in the two pairs of page arrays: 4 KB a page a side
+    pages = on_v5e((400000, page, Hkv * d), BF16)
+    new = on_v5e((B, Hkv, d), BF16)
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn if fused else None,
+            vn if fused else None, scale=128 ** -0.5, pages_per_chunk=ppc,
+            work_items=work, amla=True)
+
+    jax.jit(attend, donate_argnums=(1, 2) if fused else ()).lower(
+        on_v5e((B, Hq, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
+@pytest.mark.parametrize("tokens", [512, 4096])
+def test_kv_writer_compiles_at_jamba_shapes(on_v5e, tokens):
+    """The prefill page writer into pages of one KV head x 128 lanes:
+    one 512-token prompt, and a step of eight of them."""
+    from aphrodite_tpu.ops.pallas.kv_write import (can_use_pallas_writer,
+                                                   write_kv_pages_prefill)
+    page, hd = 16, 128
+    assert can_use_pallas_writer(BF16, page, hd)
+    pages = on_v5e((400000, page, hd), BF16)
+    cells = tokens // page
+    chunk = on_v5e((cells * page, hd), BF16)
+    ids = on_v5e((cells,), I32)
+    jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
+        chunk, chunk, pages, pages, ids, ids, ids).compile()
+
+
 def test_kv_writer_compiles_at_phi4flash_shapes(on_v5e):
     """The prefill page writer for a chunk of 2,048 tokens into pages
     of 10 KV heads x 128 lanes, once a page-holding layer."""
@@ -293,7 +350,7 @@ _SSM = dict(n=16, ch=5120, slots=129)
 
 
 @pytest.mark.parametrize("rows,tokens", [(1, 2048), (1, 1024), (2, 512),
-                                         (1, 128)])
+                                         (1, 128), (8, 512), (1, 512)])
 def test_ssm_chunk_scan_compiles(on_v5e, rows, tokens):
     """A prompt chunk's scan: channels in blocks of 512, time in blocks
     of 256 with the state in VMEM, the slot's state aliased in place.
@@ -309,7 +366,7 @@ def test_ssm_chunk_scan_compiles(on_v5e, rows, tokens):
         on_v5e((rows,), I32)).compile()
 
 
-@pytest.mark.parametrize("rows", [64, 8, 1])
+@pytest.mark.parametrize("rows", [64, 8, 1, 96, 128])
 def test_ssm_decode_update_compiles(on_v5e, rows):
     """A decode step's update: a row's state and convolution tail by
     its slot id, read, moved on and written in place."""

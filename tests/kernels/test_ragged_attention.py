@@ -438,6 +438,93 @@ def test_ten_heads_are_one_head_block(window, width, fused, ragged):
     np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
 
 
+#: AI21-Jamba2-3B's attention layers: ONE KV head of 128 under 20 query
+#: heads (a row's packed query has 20 rows, a page copy is 4 KB of one
+#: lane tile, a 512-token item 32 pages). Contexts a row, cycled: pad
+#: rows, a first token, a canary's 496, items that end partly live
+#: (513 is an item and a page), the cell's longest, 1,536.
+MQA_CTX = (700, 0, 1, 496, 513, 17, 1536, 64, 1030, 0, 300, 5)
+MQA_PAGE, MQA_HQ, MQA_WIDTH = 16, 20, 96
+
+
+def mqa_problem(rows, seed=13):
+    """(q, K pages, V pages, table, contexts, page counts, dead pages)
+    at one KV head: bf16 pages, NaN in every page no row holds (page 0,
+    which the table's pad entries point at, among them), pages in
+    shuffled order."""
+    rng = np.random.default_rng(seed)
+    ctx = np.array([MQA_CTX[b % len(MQA_CTX)] for b in range(rows)],
+                   dtype=np.int32)
+    counts = -(-ctx // MQA_PAGE)
+    pool = 1 + int(counts.sum())
+    perm = rng.permutation(pool - 1) + 1
+    bt = np.zeros((rows, MQA_WIDTH), dtype=np.int32)
+    taken = 0
+    for b, n in enumerate(counts):
+        bt[b, :n] = perm[taken:taken + n]
+        taken += n
+    dead = np.ones(pool + 4, bool)
+    dead[perm] = False
+    pages = []
+    for _ in range(2):
+        raw = rng.normal(size=(pool + 4, MQA_PAGE, 128)) * 0.3
+        raw[dead] = np.nan
+        pages.append(jnp.asarray(raw, jnp.bfloat16))
+    q = jnp.asarray(rng.normal(size=(rows, MQA_HQ, 128)) * 0.3,
+                    jnp.bfloat16)
+    return q, pages[0], pages[1], bt, ctx, counts, dead
+
+
+@pytest.mark.parametrize("rows,fused", [
+    (1, True), (96, True), (129, True), (96, False)],
+    ids=["one-row", "96-rows", "129-rows", "96-rows-read-only"])
+def test_one_kv_head_under_twenty_query_heads(rows, fused):
+    """The decode kernel and its fused K/V write at Jamba's attention
+    shape (`head_block` 1, the lane axis one tile, items of 32 pages),
+    at one row, a full bucket of 96 and the 129 past the slots, against
+    the jnp path: the output its, the pages written the slot writer's
+    exactly, no dead page read (NaN in every page no row holds), pad
+    rows zeros."""
+    from aphrodite_tpu.ops.attention import paged_decode_attention_ref
+    from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+    assert pa.head_block(1, 128, jnp.bfloat16) == 1
+    ppc = choose_pages_per_chunk(
+        MQA_WIDTH, MQA_PAGE, pa.lane_bytes_of(1, 128, jnp.bfloat16))
+    assert ppc == 32
+    q, kp, vp, bt, ctx, counts, dead = mqa_problem(rows)
+    dead = jnp.asarray(dead)[:, None, None]
+    new, (want_k, want_v) = (None, None), (kp, vp)
+    if fused:
+        rng = np.random.default_rng(9)
+        new = [jnp.asarray(rng.normal(size=(rows, 1, 128)) * 0.3,
+                           jnp.bfloat16) for _ in range(2)]
+        slots = np.where(
+            ctx > 0, bt[np.arange(rows), np.maximum(ctx - 1, 0) // MQA_PAGE]
+            * MQA_PAGE + (ctx - 1) % MQA_PAGE, kp.shape[0] * MQA_PAGE)
+        want_k, want_v = write_to_kv_cache(
+            new[0], new[1], kp, vp, jnp.asarray(slots, jnp.int32))
+    want = np.asarray(paged_decode_attention_ref(
+        q, jnp.where(dead, 0, want_k), jnp.where(dead, 0, want_v),
+        jnp.asarray(bt), jnp.asarray(np.maximum(ctx, 1)), 128 ** -0.5),
+        np.float32)
+    got = paged_decode_attention(
+        q, kp, vp, jnp.asarray(bt), jnp.asarray(ctx), None, *new,
+        scale=128 ** -0.5, pages_per_chunk=ppc,
+        work_items=build_decode_work_list(counts, ppc), interpret=True)
+    if fused:
+        got, got_k, got_v = got
+        np.testing.assert_array_equal(np.asarray(got_k, np.float32),
+                                      np.asarray(want_k, np.float32))
+        np.testing.assert_array_equal(np.asarray(got_v, np.float32),
+                                      np.asarray(want_v, np.float32))
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    live = ctx > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-2,
+                               atol=2e-2)
+    np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
+
+
 def test_a_pinned_head_block_reads_what_the_whole_one_reads():
     """`hb=` (benchmarks/attn_ab.py's arm) divides the ten heads into
     two lane-sliced blocks of five, as the policy did before a block
@@ -631,6 +718,7 @@ def test_head_block_is_the_whole_lane_axis_while_the_ring_affords_it(
 @pytest.mark.parametrize("heads,dtype,hb,item_tokens", [
     (8, jnp.bfloat16, 8, 512),      # mistral-7b-w4a8: as it was
     (4, jnp.bfloat16, 4, 512),      # smallthinker-21ba3b-bf16: as it was
+    (1, jnp.bfloat16, 1, 512),      # jamba2-3b-bf16: one head, one lane tile
     (10, jnp.bfloat16, 10, 384),    # phi-4-mini-flash-bf16: was 5, 512
     (9, jnp.bfloat16, 9, 384),      # was 3
     (11, jnp.bfloat16, 1, 512),     # past the threshold: divides

@@ -67,7 +67,8 @@ def test_the_jnp_scan_is_the_recurrence_written_out():
     np.testing.assert_allclose(state[2], s, **TOL)
 
 
-@pytest.mark.parametrize("rows,tokens", [(2, 256), (1, 512), (3, 128)])
+@pytest.mark.parametrize("rows,tokens", [(2, 256), (1, 512), (3, 128),
+                                         (2, 512)])
 def test_the_chunk_scan_kernel_against_the_jnp_side(rows, tokens):
     """Rows that start from their slot and rows that start from zeros
     (whatever their slot holds: NaN here); every slot no row holds is
@@ -166,6 +167,38 @@ def test_the_decode_update_kernel_against_the_jnp_side(tail_dtype):
     assert np.isnan(np.asarray(s)[[1, 2, 4]]).all()
     assert np.isnan(np.asarray(t, np.float32)[[1, 2, 4]]).all()
     assert not np.isnan(np.asarray(y)[:2]).any()
+
+
+def test_the_decode_update_kernel_at_128_rows():
+    """A full decode bucket of 128 rows over 128 slots and the scratch
+    one (AI21-Jamba2-3B's cell): 120 live rows on slots in shuffled
+    order and 8 pad rows on the scratch slot. Every live slot moves on
+    as the jnp side moves it, and the 8 slots no row holds are bit for
+    bit what they were, NaN and all."""
+    rows, slots_n = 128, 128
+    x = _inputs(rows, 1, seed=21)
+    rng = np.random.default_rng(6)
+    order = rng.permutation(slots_n)
+    slots = list(order[:120]) + [slots_n] * 8
+    unheld = sorted(int(s) for s in order[120:])
+    state = rng.normal(size=(slots_n + 1, N, CH)).astype(np.float32)
+    tail = np.array(jnp.asarray(
+        rng.normal(size=(slots_n + 1, 3, CH)), jnp.bfloat16))
+    state[unheld] = np.nan
+    tail[unheld] = np.nan
+    xnew = rng.normal(size=(rows, CH)).astype(np.float32)
+    y_ref, s_ref, t_ref = _update(S.ssm_update_ref, x, state, tail, slots,
+                                  xnew)
+    y, s, t = _update(None, x, state, tail, slots, xnew)
+    live = [int(v) for v in order[:120]]
+    np.testing.assert_allclose(y[:120], y_ref[:120], **TOL)
+    np.testing.assert_allclose(np.asarray(s)[live],
+                               np.asarray(s_ref)[live], **TOL)
+    np.testing.assert_array_equal(np.asarray(t, np.float32)[live],
+                                  np.asarray(t_ref, np.float32)[live])
+    assert np.isnan(np.asarray(s)[unheld]).all()
+    assert np.isnan(np.asarray(t, np.float32)[unheld]).all()
+    assert not np.isnan(np.asarray(y)[:120]).any()
 
 
 def test_a_decode_update_is_one_more_token_of_the_chunk_scan():

@@ -413,12 +413,12 @@ def _cross_layer_with_k_and_v_swapped(model):
 
 def _state_forgotten(model):
     """Every token starts from a zero state: `y = D u`, no memory."""
-    from aphrodite_tpu.modeling.models import phi4flash
+    from aphrodite_tpu.ops.pallas import ssm_scan
 
     def scan(u, delta, b, c, a, d, state, slots, fresh):
         return d[None, None] * u + jnp.einsum(
             "btc,btn,btn->btc", delta * u, b, c), state
-    phi4flash.selective_scan = scan
+    ssm_scan.selective_scan = scan
 
 
 @pytest.mark.parametrize("break_it", [
@@ -434,8 +434,9 @@ def test_each_mechanism_shows_in_the_logits(break_it, monkeypatch):
     on any of them."""
     from aphrodite_tpu.modeling.input_metadata import InputMetadata
     from aphrodite_tpu.modeling.models import phi4flash
-    monkeypatch.setattr(phi4flash, "selective_scan",
-                        phi4flash.selective_scan)
+    from aphrodite_tpu.ops.pallas import ssm_scan
+    monkeypatch.setattr(ssm_scan, "selective_scan",
+                        ssm_scan.selective_scan)
     config = _config()
     model = _program_model(config)
     have = jax.eval_shape(model.init_params)

@@ -146,6 +146,45 @@ def test_no_slot_no_admission():
         mgr._assign_state_slot(99_999)
 
 
+@pytest.mark.parametrize("slots,pages,third,waits", [
+    (2, 64, 8, True), (8, 16, 24, False)],
+    ids=["a-want-of-slots", "a-want-of-pages"])
+def test_slot_waits_count_a_want_of_slots_and_not_of_pages(slots, pages,
+                                                           third, waits):
+    """`ssm.slot_waits` (`aphrodite:ssm_slot_waits_total`) is raised
+    where `can_allocate` answers "later" for want of a slot while the
+    pages were there, once a round the prompt at the head of the queue
+    waits so; a prompt that waits for pages (slots free: its 24 tokens
+    take 12 of the 16 pages, and the two rows before it hold 8) raises
+    nothing."""
+    sched = make_scheduler(slots=slots, pages=pages, max_num_seqs=8)
+    mgr, counts = sched.block_manager, sched.tracer.counts
+    assert mgr.tracer is sched.tracer
+    groups = [make_group("0", 8), make_group("1", 8),
+              make_group("2", third)]
+    for group in groups:
+        sched.add_seq_group(group)
+    for _ in range(3):
+        _, out = sched.schedule()
+        sampled(out)
+    assert [g.request_id for g in sched.waiting] == ["2"]
+    before = counts["ssm.slot_waits"]
+    _, out = sched.schedule()
+    assert mgr.can_allocate(groups[2]) == AllocStatus.LATER
+    if waits:
+        assert mgr.get_num_free_state_slots() == 0
+        # one for the round it waited, and one for the ask above
+        assert before >= 1 and counts["ssm.slot_waits"] == before + 2
+    else:
+        assert mgr.get_num_free_state_slots() == slots - 2
+        assert counts["ssm.slot_waits"] == 0
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    exported = {name: total(sched.tracer.seconds, counts)
+                for name, _, total in _STAGE_COUNTERS}
+    assert exported["aphrodite:ssm_slot_waits_total"] == \
+        counts["ssm.slot_waits"]
+
+
 def test_a_fork_takes_a_slot_and_asks_for_the_copy():
     sched = make_scheduler(slots=4)
     mgr = sched.block_manager
