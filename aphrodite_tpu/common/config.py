@@ -141,10 +141,14 @@ class PageGroups:
 @dataclasses.dataclass(frozen=True)
 class StateSpec:
     """The constant-size recurrent state a model keeps for a sequence
-    beside its KV pages: `layers` layers, each with one array
-    `[slots + 1, *shape]` of `dtype` for every entry of `arrays`. A
-    sequence owns one STATE SLOT, the same row of all of them
-    (`processing/block_manager.py`); the last row is the pad rows'
+    beside its KV pages: `layers` layers, each holding `shape` values
+    of `dtype` a sequence for every entry of `arrays`. A sequence owns
+    one STATE SLOT (`processing/block_manager.py`).
+
+    `arrays` says what a slot HOLDS; `allocated` how the device keeps
+    it (`executor/cache_engine.py`): ONE array an entry for the whole
+    model, `[layers, slots + 1, *allocated shape]`, a slot the same
+    row of every layer of all of them and the last row the pad rows'
     scratch."""
     layers: int
     arrays: Tuple[Tuple[Tuple[int, ...], str], ...]   # (shape, dtype)
@@ -154,6 +158,25 @@ class StateSpec:
         return self.layers * sum(
             math.prod(shape) * _DTYPE_BYTES[dtype]
             for shape, dtype in self.arrays)
+
+    @property
+    def allocated(self) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
+        """`arrays` with each entry's rows (its second-minor axis)
+        rounded up to a power of two. The device tiles that axis 1, 2,
+        4 or 8 rows deep; at a count between them (the three inputs a
+        four-tap convolution keeps) its default layout puts the rows
+        OUTERMOST instead of padding them, a Pallas operand is
+        row-major, and the whole array is re-laid-out around every
+        kernel call (`tests/kernels/test_mosaic_compile.py`). The rows
+        added lead: the entry's own are the last."""
+        return tuple(
+            (shape[:-2] + (1 << (shape[-2] - 1).bit_length(), shape[-1]),
+             dtype) for shape, dtype in self.arrays)
+
+    @property
+    def allocated_slot_bytes(self) -> int:
+        """What a slot takes of the device's memory: the budget's."""
+        return dataclasses.replace(self, arrays=self.allocated).slot_bytes
 
 
 class ModelConfig:

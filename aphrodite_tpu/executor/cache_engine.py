@@ -14,11 +14,13 @@ it, each under its own group's page ids, so one page id is the same
 bytes whichever group holds it and one free list serves them all.
 A model that keeps recurrent state beside its pages
 (`common/config.py::StateSpec`) has, after those pairs in `kv_caches`,
-a tuple of state arrays for each state layer, `[slots + 1, ...]` each:
-a sequence's STATE SLOT is the same row of all of them, the last row
-the pad rows' scratch. They ride through the step programs with the
-pages, donated and updated in place; a slot is never zeroed from the
-host (the program of a sequence's first chunk starts from zeros).
+ONE tuple of state arrays for the model, `[state layers, slots + 1,
+...]` each (`StateSpec.allocated`): a sequence's STATE SLOT is the same
+row of every layer of all of them, the last row the pad rows' scratch.
+They ride through the step programs with the pages, donated and
+updated in place, a layer's kernel call indexing its layer of the
+whole array; a slot is never zeroed from the host (the program of a
+sequence's first chunk starts from zeros).
 Swap space is pinned host numpy; swap_in/out are `jax.device_put`/
 `device_get` of whole pages — JAX dispatches these asynchronously, which
 replaces the reference's dedicated CUDA stream + event machinery.
@@ -184,8 +186,9 @@ class CacheEngine:
                 for heads in self.kv_heads_per_layer]
 
     def _allocate_state(self) -> List[tuple]:
-        """The state arrays of a model with recurrent state: a tuple a
-        state layer, zeros (what a slot holds before its first owner
+        """The state arrays of a model with recurrent state: one tuple
+        for the model, an array an entry of the spec with the layers
+        leading, zeros (what a slot holds before its first owner
         matters to no program; the scratch slot's to none at all)."""
         spec = self.cache_config.state_spec
         if spec is None:
@@ -195,10 +198,9 @@ class CacheEngine:
                 "a model with recurrent state is served on one chip: "
                 "its state arrays and scan kernels are single-device")
         slots = self.cache_config.num_state_slots
-        return [tuple(jnp.zeros((slots + 1,) + shape,
+        return [tuple(jnp.zeros((spec.layers, slots + 1) + shape,
                                 dtype=jnp.dtype(dtype))
-                      for shape, dtype in spec.arrays)
-                for _ in range(spec.layers)]
+                      for shape, dtype in spec.allocated)]
 
     def _allocate_prefill_pool(self) -> List[KVCache]:
         """Prefill-group mirror of the device pool.

@@ -411,8 +411,9 @@ class TPUExecutor:
         with its slot, fit the budget; the rest of the budget is
         pages. So every slot can be fed pages to the longest context
         the engine admits, and no page waits for a slot that is not
-        there. Returns the bytes the slots take (the scratch slot
-        among them); 0 for a model without state."""
+        there. Returns the bytes the slots take as they are allocated
+        (`StateSpec.allocated_slot_bytes`; the scratch slot among
+        them); 0 for a model without state."""
         spec = self.cache_config.state_spec
         if spec is None:
             return 0
@@ -424,18 +425,19 @@ class TPUExecutor:
         held = -(-(groups.window or 0) // page) + 1
         row_pages = sum(min(longest, held) if kind == "window" else longest
                         for kind in groups.kinds)
-        row_bytes = row_pages * block_bytes + spec.slot_bytes
+        slot_bytes = spec.allocated_slot_bytes
+        row_bytes = row_pages * block_bytes + slot_bytes
         fitting = [b for b in _DECODE_BATCH_BUCKETS
                    if b <= self.scheduler_config.max_num_seqs and
                    (b + 1) * row_bytes <= budget]
         slots = max(fitting, default=1)
         self.cache_config.num_state_slots = slots
-        taken = (slots + 1) * spec.slot_bytes
+        taken = (slots + 1) * slot_bytes
         logger.info(
             "State slots: %d of %d bytes each (%.2f GiB with the scratch "
             "slot) from the KV budget of %.2f GiB; a row at %d tokens "
             "holds %d pages of %d bytes",
-            slots, spec.slot_bytes, taken / _GB, budget / _GB,
+            slots, slot_bytes, taken / _GB, budget / _GB,
             self.model_config.max_model_len, row_pages, block_bytes)
         return taken
 

@@ -502,12 +502,13 @@ class ModelRunner:
     def _copy_state(self, kv_caches, src, dst):
         pages = self.page_pairs
         return list(kv_caches[:pages]) + [
-            tuple(a.at[dst].set(a[src]) for a in arrays)
+            tuple(a.at[:, dst].set(a[:, src]) for a in arrays)
             for arrays in kv_caches[pages:]]
 
     def copy_state(self, kv_caches, copies: List[Tuple[int, int]]):
         """A fork's state: each child's slot takes its parent's rows
-        of every state array, before the round's steps. Padded to a
+        of every state array (every layer's: the slot axis follows the
+        layer axis), before the round's steps. Padded to a
         bucket with the scratch slot onto itself."""
         padded = _pow2_bucket(len(copies), lo=8)
         src = np.full((padded,), self.num_state_slots, dtype=np.int32)

@@ -14,7 +14,13 @@ inputs of the causal convolution live in the sequence's STATE SLOT,
 beside the KV pages (`common/config.py::StateSpec`): a prompt chunk
 starts from the slot (from zeros at position 0) and leaves its last
 token's state there, a decode step moves it on by one token in place
-(`ops/pallas/ssm_scan.py`).
+(`ops/pallas/ssm_scan.py`). Every Mamba layer of a model reads and
+writes its own layer of the model's ONE pair of state arrays
+(`executor/cache_engine.py`); a slot of the tail array keeps the last
+`d_conv` inputs, one more than the convolution reads, because that is
+the shape the device lays out as the update kernel takes it. A decode
+step's one token a row runs through the layer as `[rows, width]`
+arrays, the token axis taken off at the door and put back at the exit.
 """
 from __future__ import annotations
 
@@ -89,12 +95,22 @@ class MambaMixer:
         return params
 
     def __call__(self, params: Params, h: jax.Array, positions: jax.Array,
-                 cache: Optional[StateCache], metadata: InputMetadata):
-        """`cache`: the layer's `(tail, state)` arrays, `[slots + 1,
-        d_conv - 1 | d_state, d_inner]`; None runs a prompt from zeros
-        and keeps nothing."""
+                 cache: Optional[StateCache], metadata: InputMetadata,
+                 layer: int):
+        """`cache`: the model's `(tail, state)` arrays, `[state layers,
+        slots + 1, kept | d_state, d_inner]` with `kept >= d_conv - 1`
+        inputs a slot, and `layer` which of the state layers this is;
+        None runs a prompt from zeros and keeps nothing."""
         p = self.prefix
         batch, seq = h.shape[:2]
+        decode = not metadata.is_prompt
+        if decode:
+            # a decode step's one token a row, as `[rows, width]` arrays
+            # from here to the output: a `[rows, 1, width]` array beside
+            # the update kernel takes the kernel's row-major layout, a
+            # tile a row with one sublane in eight used, and every
+            # elementwise fusion over it costs eight times its work
+            h = h[:, 0]
         x, z = jnp.split(self.in_proj(params[f"{p}.in_proj"], h), 2, axis=-1)
         conv_w = params[f"{p}.conv1d"]["weight"].astype(jnp.float32)
         conv_b = params[f"{p}.conv1d"]["bias"].astype(jnp.float32)
@@ -103,20 +119,28 @@ class MambaMixer:
         taps = self.d_conv - 1
         slots = metadata.state_slots
         if cache is None:
-            tail = jnp.zeros((1, taps, self.d_inner), self.dtype)
-            state = jnp.zeros((1, self.d_state, self.d_inner), jnp.float32)
-            slots = jnp.zeros((batch,), jnp.int32)
+            tail = jnp.zeros((1, 1, taps, self.d_inner), self.dtype)
+            state = jnp.zeros((1, 1, self.d_state, self.d_inner),
+                              jnp.float32)
+            slots, layer = jnp.zeros((batch,), jnp.int32), 0
         else:
             tail, state = cache
+        # the inputs a slot keeps, of which the last `taps` are read
+        kept = tail.shape[2]
+        lead = kept - taps
 
         # the convolution over [the slot's tail ; this step's inputs]
         fresh = positions[:, 0] == 0
-        before = tail[slots]
+        before = tail[layer, slots]
         if metadata.is_prompt:
             before = jnp.where(fresh[:, None, None], 0, before)
-        window = jnp.concatenate([before, x], axis=1).astype(jnp.float32)
-        conv = conv_b + sum(conv_w[k] * window[:, k:k + seq]
-                            for k in range(self.d_conv))
+        window = jnp.concatenate(
+            [before, x[:, None] if decode else x], axis=1).astype(jnp.float32)
+        conv = conv_b + sum(
+            conv_w[k] * window[:, lead + k:lead + k + seq]
+            for k in range(self.d_conv))
+        if decode:
+            conv = conv[:, 0]
         u = jax.nn.silu(conv)                       # float32
         dbc = self.x_proj(params[f"{p}.x_proj"], u.astype(self.dtype))
         dt, b, c = jnp.split(
@@ -139,20 +163,21 @@ class MambaMixer:
             # are too: `CacheEngine._allocate_state`)
             scan = ssm_scan.selective_scan if metadata.tp == 1 \
                 else ssm_scan.ssm_scan_ref
-            y, state = scan(u, delta, b, c, a, d, state, slots, fresh)
+            y, state = scan(u, delta, b, c, a, d, state, slots, fresh,
+                            layer)
             # the tail after the row's last live token
             moved = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                w, n, taps, axis=0))(window, lens).astype(tail.dtype)
-            tail = tail.at[slots].set(moved)
+                w, n, kept, axis=0))(window, lens).astype(tail.dtype)
+            tail = tail.at[layer, slots].set(moved)
         else:
             update = ssm_scan.selective_update if metadata.tp == 1 \
                 else ssm_scan.ssm_update_ref
-            y, state, tail = update(
-                x[:, 0], u[:, 0], delta[:, 0], b[:, 0], c[:, 0], a, d,
-                state, tail, slots)
-            y = y[:, None]
+            y, state, tail = update(x, u, delta, b, c, a, d, state, tail,
+                                    slots, layer)
         y = y.astype(self.dtype)
         out = self.out_proj(params[f"{p}.out_proj"],
                             y * jax.nn.silu(z.astype(jnp.float32)).astype(
                                 self.dtype))
+        if decode:
+            out, y = out[:, None], y[:, None]
         return out, y, (None if cache is None else (tail, state))

@@ -268,11 +268,11 @@ class Phi4FlashForCausalLM:
             for i, kind in enumerate(kinds)]
         self.lm_head = ParallelLMHead(config.vocab_size,
                                       config.hidden_size, dtype=dtype)
-        #: the state arrays follow the page pairs in `kv_caches`, a
-        #: `(tail, state)` pair for each mamba layer in order
+        #: the model's one `(tail, state)` pair follows the page pairs
+        #: in `kv_caches`; a mamba layer's place on its leading axis
+        self.state_pair = self.groups.layers_per_group
         self.state_at = {
-            layer.prefix: self.groups.layers_per_group + i
-            for i, layer in enumerate(
+            layer.prefix: i for i, layer in enumerate(
                 l for l in self.layers if l.kind == "mamba")}
 
     def init_params(self) -> Params:
@@ -292,17 +292,19 @@ class Phi4FlashForCausalLM:
                  kv_caches: Optional[List[KVCache]],
                  metadata: InputMetadata):
         """`kv_caches`: a pair of page arrays for each place in a page
-        group, then a `(tail, state)` pair for each mamba layer."""
+        group, then the one `(tail, state)` pair of all the mamba
+        layers."""
         x = self.embed_tokens(params["model.embed_tokens"], input_ids)
         caches = list(kv_caches) if kv_caches is not None else None
         memory = shared_kv = None
         for layer in self.layers:
             h = layer.normed(params, x)
             if layer.kind == "mamba":
-                at = self.state_at[layer.prefix]
+                at = self.state_pair
                 out, memory, new = layer.mixer(
                     params, h, positions,
-                    caches[at] if caches is not None else None, metadata)
+                    caches[at] if caches is not None else None, metadata,
+                    self.state_at[layer.prefix])
                 if new is not None:
                     caches[at] = new
             elif layer.kind == "gmu":

@@ -99,9 +99,11 @@ class JambaConfig(PretrainedConfig):
                 for kind in self.layer_kinds]
 
     def state_spec(self, dtype: str):
-        """`StateSpec`'s (layers, arrays): a Mamba layer keeps the
-        last `d_conv - 1` inputs of its convolution in the model's
-        type and its scan's state `[d_state, d_inner]` in float32."""
+        """`StateSpec`'s (layers, arrays), what a slot HOLDS: a Mamba
+        layer keeps the last `d_conv - 1` inputs of its convolution in
+        the model's type and its scan's state `[d_state, d_inner]` in
+        float32. (How the device lays them out, the tail `d_conv` rows
+        a slot in one array for the model, is `StateSpec.allocated`.)"""
         return self.layer_kinds.count("mamba"), (
             ((self.mamba_d_conv - 1, self.mamba_d_inner), dtype),
             ((self.mamba_d_state, self.mamba_d_inner), "float32"))

@@ -345,8 +345,9 @@ def test_kv_writer_compiles_at_phi4flash_shapes(on_v5e):
 
 
 #: the selective-scan kernels at Phi-4-mini-flash's widths: 5,120
-#: channels, 16 states, 128 state slots and the scratch one.
-_SSM = dict(n=16, ch=5120, slots=129)
+#: channels, 16 states, 128 state slots and the scratch one, nine state
+#: layers in the arrays.
+_SSM = dict(n=16, ch=5120, slots=129, layers=9)
 
 
 @pytest.mark.parametrize("rows,tokens", [(1, 2048), (1, 1024), (2, 512),
@@ -362,20 +363,204 @@ def test_ssm_chunk_scan_compiles(on_v5e, rows, tokens):
         on_v5e((rows, n, tokens), f32)
     _ssm_scan_impl.lower(
         seq, seq, coeff, coeff, on_v5e((n, ch), f32), on_v5e((1, ch), f32),
-        on_v5e((slots, n, ch), f32), on_v5e((rows,), I32),
-        on_v5e((rows,), I32)).compile()
+        on_v5e((_SSM["layers"], slots, n, ch), f32), on_v5e((1,), I32),
+        on_v5e((rows,), I32), on_v5e((rows,), I32)).compile()
 
 
-@pytest.mark.parametrize("rows", [64, 8, 1, 96, 128])
+@pytest.mark.parametrize("rows", [64, 8, 1, 96, 128, 12, 4, 48])
 def test_ssm_decode_update_compiles(on_v5e, rows):
     """A decode step's update: a row's state and convolution tail by
-    its slot id, read, moved on and written in place."""
+    its layer and slot id, read, moved on and written in place; the
+    tail four rows a slot (`StateSpec.allocated`)."""
     from aphrodite_tpu.ops.pallas.ssm_scan import _ssm_update_impl
-    n, ch, slots = _SSM["n"], _SSM["ch"], _SSM["slots"]
+    n, ch, slots, layers = (_SSM[k] for k in ("n", "ch", "slots", "layers"))
     f32 = jnp.float32
-    row = on_v5e((rows, 1, ch), f32)
+    from aphrodite_tpu.ops.pallas.ssm_scan import _row_blocks
+    row = on_v5e(jax.eval_shape(
+        _row_blocks, jax.ShapeDtypeStruct((rows, ch), f32)).shape, f32)
     _ssm_update_impl.lower(
-        on_v5e((rows, 1, ch), BF16), row, row, on_v5e((n, rows), f32),
+        row, row, row, on_v5e((n, rows), f32),
         on_v5e((n, rows), f32), on_v5e((n, ch), f32), on_v5e((1, ch), f32),
-        on_v5e((slots, n, ch), f32), on_v5e((slots, 3, ch), BF16),
+        on_v5e((layers, slots, n, ch), f32),
+        on_v5e((layers, slots, 4, ch), BF16), on_v5e((1,), I32),
         on_v5e((rows,), I32)).compile()
+
+
+# ---- the state arrays through a step program's Mamba layers ----
+#
+# A kernel that compiles says nothing of what the compiler puts AROUND
+# it. Until PR 42 the convolution's tail was an array a layer,
+# `[slots + 1, 3, 5120]`: with 3 rows on its second-minor axis the
+# device's default layout has the rows outermost, a Pallas operand is
+# row-major, and an operand of 4 MB is small enough to be staged whole
+# in the compiler's alternate memory. So every layer of every decode
+# step sliced the WHOLE array in tap by tap, re-laid it out, and copied
+# it back out after the kernel: 7.6% of the device's time in Jamba's
+# cell, counted by no roofline. The optimised HLO shows all of it
+# without a chip, which is what these cases read.
+
+#: operations that move an operand whole (`ConcatBitcast` is the custom
+#: call that joins the slices of a staged operand)
+_WHOLE_ARRAY_MOVES = ("slice", "slice-start", "copy", "copy-start",
+                      "ConcatBitcast")
+
+
+def _instructions(hlo: str):
+    """(opcode, the shapes of its result and of its operands) of every
+    instruction of every computation of an HLO module's text. A custom
+    call goes by its target."""
+    import re
+    shapes, out = {}, []
+    pattern = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$")
+    for line in hlo.splitlines():
+        if line.rstrip().endswith("{"):         # a computation begins
+            shapes = {}
+        found = pattern.match(line)
+        if not found:
+            continue
+        name, result, opcode, rest = found.groups()
+        shapes[name] = result
+        target = re.search(r'custom_call_target="([^"]+)"', rest)
+        operands = [shapes.get(n, "") for n in
+                    re.findall(r"%([\w.\-]+)", rest.split("), ")[0])]
+        out.append((target.group(1) if target else opcode,
+                    [result] + operands))
+    return out
+
+
+def _whole_array_moves(hlo: str, array: str):
+    """The opcodes, in order, of the operations that read or write an
+    array of shape `array` (`bf16[26,129,4,5120]`) whole."""
+    return [opcode for opcode, shapes in _instructions(hlo)
+            if opcode in _WHOLE_ARRAY_MOVES and
+            any(array + "{" in s or s == array for s in shapes)]
+
+
+def _mixer_program(monkeypatch, sds, *, rows, run, tail, state, norms,
+                   tokens=1):
+    """The optimised HLO of `run`'s Mamba layers of a step, the real
+    `MambaMixer` at the served widths (hidden 2,560, 5,120 channels, 16
+    states, four taps, bfloat16) over state arrays of shapes `tail` and
+    `state` (a list of each: an array a layer, or the model's one),
+    donated as the step programs donate them; a decode step of `rows`
+    rows at `tokens` 1, else a prompt chunk."""
+    import types
+    from aphrodite_tpu.modeling.input_metadata import InputMetadata
+    from aphrodite_tpu.modeling.layers.mamba import MambaMixer
+    # (the dispatchers ask the backend, which is the CPU here)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = types.SimpleNamespace(
+        hidden_size=2560, mamba_d_inner=5120, mamba_d_state=16,
+        mamba_d_conv=4, mamba_dt_rank=160)
+    mixer = MambaMixer(config, "m", BF16, None, inner_norms=norms)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(mixer.init))
+
+    def step(params, h, positions, slots, tails, states):
+        meta = InputMetadata(
+            slot_mapping=slots, block_tables=slots[:, None],
+            context_lens=slots, state_slots=slots, is_prompt=tokens > 1,
+            prompt_lens=slots if tokens > 1 else None)
+        # (the parent's arrays had no layer axis: the control's get one
+        # here, a bitcast, and give it back)
+        tails, states = ([a.reshape((1,) * (4 - a.ndim) + a.shape)
+                          for a in arrays] for arrays in (tails, states))
+        for at, layer in run:
+            out, _, (tails[at], states[at]) = mixer(
+                params, h, positions, (tails[at], states[at]), meta, layer)
+            h = h + out
+        return h, [a.reshape(s) for a, s in zip(tails, tail)], \
+            [a.reshape(s) for a, s in zip(states, state)]
+
+    return jax.jit(step, donate_argnums=(4, 5)).lower(
+        params, sds((rows, tokens, 2560), BF16), sds((rows, tokens), I32),
+        sds((rows,), I32), [sds(s, BF16) for s in tail],
+        [sds(s, jnp.float32) for s in state]).compile().as_text()
+
+
+#: (rows of a decode step, state layers in the arrays, the layers run,
+#: inner norms): Jamba's cell runs 26 layers at 128 rows, three of them
+#: here (the first, one in the middle, the last); Phi's all nine at 48
+_STATE_CASES = {
+    "jamba": (128, 26, (0, 12, 25), True),
+    "phi": (48, 9, tuple(range(9)), False),
+}
+
+
+def _state_arrays(layers):
+    return f"bf16[{layers},129,4,5120]", f"f32[{layers},129,16,5120]"
+
+
+@pytest.mark.parametrize("model", list(_STATE_CASES))
+def test_a_decode_step_moves_no_state_array_whole(on_v5e, monkeypatch,
+                                                  model):
+    """The model's one tail array and its one state array go into the
+    first layer's kernel call and out of the last one's: nothing
+    between slices, copies, stages or re-lays-out either of them.
+    Found: at Jamba's geometry nothing at all (137 MB and 1.1 GB fit no
+    alternate memory); at Phi's the 48 MB tail array is staged ONCE a
+    program, one `copy-start` in before the first call and one out
+    after the last, since the kernel states its cost (`_update_cost`:
+    the compiler then counts its operands worth holding close); never
+    once a layer, and never re-laid-out."""
+    rows, layers, run, norms = _STATE_CASES[model]
+    hlo = _mixer_program(
+        monkeypatch, on_v5e, rows=rows, run=[(0, l) for l in run],
+        tail=[(layers, 129, 4, 5120)], state=[(layers, 129, 16, 5120)],
+        norms=norms)
+    calls = [shapes for op, shapes in _instructions(hlo)
+             if op == "tpu_custom_call"]
+    assert len(calls) == len(run)
+    tail, state = _state_arrays(layers)
+    # each call takes both arrays whole and gives both back
+    for shapes in calls:
+        assert sum(tail + "{" in s for s in shapes[1:]) == 1 and \
+            sum(state + "{" in s for s in shapes[1:]) == 1
+        assert tail + "{" in shapes[0] and state + "{" in shapes[0]
+    staged_once = ["copy-start"] * 2 if model == "phi" else []
+    assert _whole_array_moves(hlo, tail) in ([], staged_once)
+    assert _whole_array_moves(hlo, state) == []
+
+
+@pytest.mark.parametrize("model,rows,tokens", [("jamba", 8, 512),
+                                               ("phi", 1, 2048)])
+def test_a_prompt_step_moves_no_state_array_whole(on_v5e, monkeypatch,
+                                                  model, rows, tokens):
+    """A prompt chunk reads its rows' tails (`tail[layer, slots]`) and
+    writes them back (`.at[layer, slots].set`) in place, and the chunk
+    scan takes the state array as the update does."""
+    _, layers, run, norms = _STATE_CASES[model]
+    hlo = _mixer_program(
+        monkeypatch, on_v5e, rows=rows, tokens=tokens,
+        run=[(0, l) for l in run], tail=[(layers, 129, 4, 5120)],
+        state=[(layers, 129, 16, 5120)], norms=norms)
+    tail, state = _state_arrays(layers)
+    assert sum(op == "tpu_custom_call"
+               for op, _ in _instructions(hlo)) == len(run)
+    assert _whole_array_moves(hlo, tail) == []
+    assert _whole_array_moves(hlo, state) == []
+
+
+def test_the_check_sees_the_parents_layout_sliced_and_copied(on_v5e,
+                                                             monkeypatch):
+    """The control: the same mixer over the layout this replaced, a
+    `[129, 3, 5120]` tail and a `[129, 16, 5120]` state a layer. Every
+    layer's tail is re-laid-out whole before its kernel call and back
+    after it, and where the compiler also stages it in its alternate
+    memory it is sliced in tap by tap and joined (what the chip's trace
+    showed as `slice-done bf16[129,1,5120]` and `copy
+    bf16[129,3,5120]`; which layers are staged is the scheduler's
+    choice); the state array, row-major by default and too large to
+    stage, passes untouched, as both do now."""
+    hlo = _mixer_program(
+        monkeypatch, on_v5e, rows=128, run=[(l, 0) for l in range(3)],
+        tail=[(129, 3, 5120)] * 3, state=[(129, 16, 5120)] * 3, norms=True)
+    moves = _whole_array_moves(hlo, "bf16[129,3,5120]")
+    assert moves.count("copy") == 2 * 3
+    staged = moves.count("ConcatBitcast")
+    assert staged >= 1 and moves.count("slice-start") == 3 * staged
+    assert len(moves) == 6 + 4 * staged
+    # the parameter's layout is the cause: the three rows outermost
+    assert "bf16[129,3,5120]{2,0,1:T(8,128)(2,1)} parameter" in hlo
+    assert _whole_array_moves(hlo, "f32[129,16,5120]") == []

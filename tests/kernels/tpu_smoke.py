@@ -319,7 +319,9 @@ def main() -> int:
         #    1,024 through the slot, against the jnp scan; a decode
         #    step of 64 rows (60 live on scattered slots, 4 pad rows on
         #    the scratch slot) against the jnp update, NaN in every
-        #    slot no row holds. --
+        #    slot no row holds. The arrays are the model's, three
+        #    layers each, the calls naming the middle one: the other
+        #    two are NaN before and after. --
         from aphrodite_tpu.ops.pallas import ssm_scan as ssm
         n8, ch8, slots8, t8 = 16, 5120, 128, 2048
         f8 = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.float32)
@@ -328,27 +330,29 @@ def main() -> int:
         a8 = -jnp.exp(jnp.asarray(rs.uniform(-1.5, 1.5, (n8, ch8)),
                                   jnp.float32))
         d8 = jnp.asarray(rs.uniform(0, 0.5, (ch8,)), jnp.float32)
-        st8 = jnp.full((slots8 + 1, n8, ch8), jnp.nan, jnp.float32)
+        at8 = 1
+        st8 = jnp.full((3, slots8 + 1, n8, ch8), jnp.nan, jnp.float32)
         one, yes = jnp.asarray([7], jnp.int32), jnp.asarray([1], jnp.int32)
         y_ref, s_ref = ssm.ssm_scan_ref(u8, dl8, b8, c8, a8, d8, st8, one,
-                                        yes)
+                                        yes, at8)
         y_got, s_got = ssm.selective_scan(u8, dl8, b8, c8, a8, d8, st8,
-                                          one, yes)
+                                          one, yes, at8)
         check("chunk scan, 2,048 tokens", np.asarray(y_ref),
               np.asarray(y_got), tol=1e-4)
-        check("chunk scan, the slot's state", np.asarray(s_ref)[7],
-              np.asarray(s_got)[7], tol=1e-4)
+        check("chunk scan, the slot's state", np.asarray(s_ref)[at8, 7],
+              np.asarray(s_got)[at8, 7], tol=1e-4)
         half = t8 // 2
         y1, s1 = ssm.selective_scan(
             u8[:, :half], dl8[:, :half], b8[:, :half], c8[:, :half], a8, d8,
-            st8, one, yes)
+            st8, one, yes, at8)
         y2, s2 = ssm.selective_scan(
             u8[:, half:], dl8[:, half:], b8[:, half:], c8[:, half:], a8, d8,
-            s1, one, 0 * yes)
+            s1, one, 0 * yes, at8)
         check("two chunks through the slot",
               np.asarray(y_ref), np.concatenate([y1, y2], axis=1),
               tol=1e-4)
-        if not np.isnan(np.asarray(s2)[[0, 6, 8, slots8]]).all():
+        if not (np.isnan(np.asarray(s2)[at8, [0, 6, 8, slots8]]).all() and
+                np.isnan(np.asarray(s2)[[0, 2]]).all()):
             failures.append(("ssm scan touched a slot no row holds", 0))
         rows8, live8 = 64, 60
         owners = rs.permutation(slots8)[:live8]
@@ -357,27 +361,28 @@ def main() -> int:
         held = np.zeros(slots8 + 1, bool)
         held[owners] = True
         held[slots8] = True
-        st9 = jnp.where(held[:, None, None], f8(slots8 + 1, n8, ch8),
-                        jnp.nan)
-        tl9 = jnp.where(held[:, None, None],
-                        f8(slots8 + 1, 3, ch8), jnp.nan).astype(jnp.bfloat16)
+        mine = held[None, :, None, None] & \
+            (np.arange(3) == at8)[:, None, None, None]
+        st9 = jnp.where(mine, f8(3, slots8 + 1, n8, ch8), jnp.nan)
+        tl9 = jnp.where(mine, f8(3, slots8 + 1, 4, ch8),
+                        jnp.nan).astype(jnp.bfloat16)
         x9 = f8(rows8, ch8).astype(jnp.bfloat16)
         u9, b9, c9 = f8(rows8, ch8), f8(rows8, n8), f8(rows8, n8)
         dl9 = jax.nn.softplus(f8(rows8, ch8) - 4.0)
         want = ssm.ssm_update_ref(x9, u9, dl9, b9, c9, a8, d8, st9, tl9,
-                                  jnp.asarray(slot8))
+                                  jnp.asarray(slot8), at8)
         got = ssm.selective_update(x9, u9, dl9, b9, c9, a8, d8, st9, tl9,
-                                   jnp.asarray(slot8))
+                                   jnp.asarray(slot8), at8)
         check("decode update, the rows' outputs",
               np.asarray(want[0])[:live8], np.asarray(got[0])[:live8],
               tol=1e-4)
         check("decode update, the live slots' state",
-              np.asarray(want[1])[owners], np.asarray(got[1])[owners],
-              tol=1e-4)
+              np.asarray(want[1])[at8, owners],
+              np.asarray(got[1])[at8, owners], tol=1e-4)
         check("decode update, the live slots' tail",
-              np.asarray(want[2], np.float32)[owners],
-              np.asarray(got[2], np.float32)[owners], tol=1e-6)
-        free = ~held
+              np.asarray(want[2], np.float32)[at8, owners],
+              np.asarray(got[2], np.float32)[at8, owners], tol=1e-6)
+        free = ~mine[..., 0, 0]
         if not (np.isnan(np.asarray(got[1])[free]).all() and
                 np.isnan(np.asarray(got[2], np.float32)[free]).all()):
             failures.append(("ssm update touched a slot no row holds", 0))

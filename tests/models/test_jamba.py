@@ -165,18 +165,19 @@ def test_engine_logits_against_the_reference(chunk, tmp_path, monkeypatch):
     assert groups.group_of_layer == (-1, 0, -1, -1, 0, -1)
     assert groups.slot_of_layer == (-1, 0, -1, -1, 1, -1)
     assert groups.layers_per_group == 2 and groups.readers == (2,)
-    # a pair of page arrays for each attention layer, then (tail,
-    # state) for each Mamba layer: the recurrent state is float32
-    # whatever the model's type
+    # a pair of page arrays for each attention layer, then the one
+    # (tail, state) pair of the four Mamba layers: the recurrent state
+    # is float32 whatever the model's type, the tail kept four rows a
+    # slot where the convolution reads three
     caches = engine.executor.cache_engine.kv_caches
     slots = engine.cache_config.num_state_slots
-    assert slots == 4 and len(caches) == 2 + 4
+    assert slots == 4 and len(caches) == 2 + 1
     for k_pages, _ in caches[:2]:
         assert k_pages.shape[1:] == (PAGE, 128)     # one head, padded
-    for tail, state in caches[2:]:
-        assert state.dtype == jnp.float32 and \
-            state.shape == (slots + 1, 16, 128)
-        assert tail.shape == (slots + 1, 3, 128)
+    (tail, state), = caches[2:]
+    assert state.dtype == jnp.float32 and \
+        state.shape == (4, slots + 1, 16, 128)
+    assert tail.shape == (4, slots + 1, 4, 128)
     prompt, steps = _prompt(0), 60
     ((reply,),) = s.run([prompt], steps)
     assert len(reply) == steps
@@ -460,7 +461,7 @@ def _the_one_kv_heads_k_and_v_swapped(model, monkeypatch):
 
 def _state_forgotten(model, monkeypatch):
     """Every token starts from a zero state: `y = D u`, no memory."""
-    def scan(u, delta, b, c, a, d, state, slots, fresh):
+    def scan(u, delta, b, c, a, d, state, slots, fresh, layer):
         return d[None, None] * u + jnp.einsum(
             "btc,btn,btn->btc", delta * u, b, c), state
     from aphrodite_tpu.ops.pallas import ssm_scan

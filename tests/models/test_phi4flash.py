@@ -151,15 +151,17 @@ def test_engine_logits_against_the_reference(chunk, tmp_path, monkeypatch):
     assert groups.kinds == ("window", "window", "full")
     assert groups.group_of_layer == (-1, 0, -1, 1, -1, 2, -1, 2)
     assert groups.layers_per_group == 1 and groups.readers == (1, 1, 2)
-    # a pair of page arrays, then (tail, state) for each Mamba layer:
-    # the recurrent state is float32 whatever the model's type
+    # a pair of page arrays, then the one (tail, state) pair of the
+    # three Mamba layers: the recurrent state is float32 whatever the
+    # model's type, the tail kept four rows a slot where the
+    # convolution reads three
     caches = engine.executor.cache_engine.kv_caches
     slots = engine.cache_config.num_state_slots
-    assert slots == 4 and len(caches) == 1 + 3
-    for tail, state in caches[1:]:
-        assert state.dtype == jnp.float32 and \
-            state.shape == (slots + 1, 16, 128)
-        assert tail.shape == (slots + 1, 3, 128)
+    assert slots == 4 and len(caches) == 1 + 1
+    (tail, state), = caches[1:]
+    assert state.dtype == jnp.float32 and \
+        state.shape == (3, slots + 1, 16, 128)
+    assert tail.shape == (3, slots + 1, 4, 128)
     prompt, steps = _prompt(0), 60
     ((reply,),) = s.run([prompt], steps)
     assert len(reply) == steps
@@ -415,7 +417,7 @@ def _state_forgotten(model):
     """Every token starts from a zero state: `y = D u`, no memory."""
     from aphrodite_tpu.ops.pallas import ssm_scan
 
-    def scan(u, delta, b, c, a, d, state, slots, fresh):
+    def scan(u, delta, b, c, a, d, state, slots, fresh, layer):
         return d[None, None] * u + jnp.einsum(
             "btc,btn,btn->btc", delta * u, b, c), state
     ssm_scan.selective_scan = scan
