@@ -81,7 +81,11 @@ NAMES = (
                             # would hold live without a window
     "cache.window_pages_freed",  # pages the window groups let go of
     "moe.tokens_routed",    # token-expert pairs of the expert layers
-    "moe.experts_touched",  # experts with a pair, over layers and steps
+    "moe.experts_touched",  # held experts with a pair, over layers and
+                            # steps
+    "moe.pairs_held",       # of the pairs routed, those whose expert the
+                            # layer holds (counted by a model that may
+                            # hold a share of its experts)
     "moe.decode_experts_touched",  # of them, the decode steps'
     "moe.decode_expert_slots",     # experts x expert layers, a decode step
     "ssm.state_resets",     # prompt rows that start at position 0: the

@@ -198,9 +198,14 @@ _STAGE_COUNTERS = [
      "Token-expert pairs the expert layers computed, counted in the "
      "step programs.", lambda s, c: c["moe.tokens_routed"]),
     ("aphrodite:moe_experts_touched_total",
-     "Experts with at least one pair, summed over expert layers and "
-     "steps, counted in the step programs.",
+     "Held experts with at least one pair, summed over expert layers "
+     "and steps, counted in the step programs.",
      lambda s, c: c["moe.experts_touched"]),
+    ("aphrodite:moe_pairs_held_total",
+     "Of aphrodite:moe_tokens_routed_total, the pairs whose expert "
+     "the layer holds and computes, counted by a model that may hold "
+     "a share of its experts (the rest is left out, not stood in for).",
+     lambda s, c: c["moe.pairs_held"]),
     ("aphrodite:moe_decode_experts_touched_total",
      "Of aphrodite:moe_experts_touched_total, the decode steps'.",
      lambda s, c: c["moe.decode_experts_touched"]),

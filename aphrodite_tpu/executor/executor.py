@@ -378,7 +378,10 @@ class TPUExecutor:
             act_bytes = int(act_bytes * fudge)
             # MoE ragged dispatch materializes f32 gate/up/act tensors
             # at [tokens * top_k, moe_inter] (layers/fused_moe.py) —
-            # for Mixtral shapes that dwarfs the dense estimate.
+            # for Mixtral shapes that dwarfs the dense estimate. A layer
+            # that holds a share of its experts sorts every pair all the
+            # same (the pairs of experts held elsewhere lie behind the
+            # last group), so its rows are tokens * top_k too.
             if top_k:
                 moe_inter = getattr(cfg, "moe_intermediate_size", None) \
                     or getattr(cfg, "moe_ffn_hidden_size", inter)

@@ -51,15 +51,36 @@ class FusedMoE:
     `own_router=False`: the layer holds no router; the caller computes
     the router logits from whatever tensor its architecture routes on
     and passes them to `__call__` (SmallThinker routes on the layer's
-    input, before the norm and attention)."""
+    input, before the norm and attention).
+
+    **A share of the experts** (`routed_experts` over `num_experts`):
+    the layer HOLDS `num_experts` experts, `first_expert` and the ones
+    after it, of the `routed_experts` its router scores: one chip's
+    part of an expert-parallel layer. The stacked weights are
+    `[num_experts, ...]`, the router `routed_experts` wide, `route()`
+    what it always is (over all of them), and only the token-expert
+    pairs whose expert is held reach the grouped matmuls; what the
+    experts held elsewhere would add is left out, not stood in for.
+    A layer that holds every expert it routes over is the layer
+    without a share, operation for operation."""
 
     def __init__(self, num_experts: int, top_k: int, hidden_size: int,
                  intermediate_size: int, *,
                  renormalize: bool = True,
                  activation: str = "silu",
                  own_router: bool = True,
+                 routed_experts: Optional[int] = None,
+                 first_expert: int = 0,
                  dtype: jnp.dtype = jnp.bfloat16) -> None:
         self.num_experts = num_experts
+        #: the router's width; `num_experts` of them are held here
+        self.routed_experts = routed_experts or num_experts
+        self.first_expert = first_expert
+        if not 0 <= first_expert <= self.routed_experts - num_experts:
+            raise ValueError(
+                f"FusedMoE holds experts {first_expert} to "
+                f"{first_expert + num_experts - 1} of "
+                f"{self.routed_experts}")
         self.top_k = top_k
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
@@ -85,7 +106,8 @@ class FusedMoE:
             "w_down": jnp.zeros((e, i, h), dtype=self.dtype),
         }
         if self.own_router:
-            params["gate"] = jnp.zeros((h, e), dtype=self.dtype)
+            params["gate"] = jnp.zeros((h, self.routed_experts),
+                                       dtype=self.dtype)
         return params
 
     def specs(self) -> Dict[str, P]:
@@ -117,7 +139,8 @@ class FusedMoE:
         """hidden [..., hidden_size] -> same shape. `router_logits`
         [..., E]: the caller's, in the place of `hidden @ gate`.
         `counts`: a list that gains this call's `(token-expert pairs,
-        experts with a pair)`, int32 scalars counted in the program."""
+        held experts with a pair, pairs that met a held expert)`, int32
+        scalars counted in the program."""
         sharded = self.sharded
         orig_shape = hidden.shape
         x = hidden.reshape(-1, self.hidden_size)          # [T, H]
@@ -126,9 +149,22 @@ class FusedMoE:
             router_logits = (x.astype(jnp.float32) @
                              params["gate"].astype(jnp.float32))  # [T, E]
         probs, top_vals, top_idx = self.route(
-            router_logits.reshape(-1, self.num_experts).astype(
+            router_logits.reshape(-1, self.routed_experts).astype(
                 jnp.float32))
-        ragged = self.num_experts > 4 and not sharded
+        share = self.num_experts < self.routed_experts
+        if share:
+            if sharded:
+                raise NotImplementedError(
+                    "a share of the experts on a mesh: the exchange "
+                    "between shares is not written")
+            # A pair's expert by its place among the held ones; a pair
+            # of an expert held elsewhere gets the place after the
+            # last, so that it sorts behind every group and belongs to
+            # none (`_ragged_ffn` drops its row).
+            local = top_idx - self.first_expert
+            held = (local >= 0) & (local < self.num_experts)
+            top_idx = jnp.where(held, local, self.num_experts)
+        ragged = (share or self.num_experts > 4) and not sharded
         if ragged or counts is not None:
             # Pairs an expert: each pair's expert compared with every
             # expert id, summed over the pairs.
@@ -136,12 +172,15 @@ class FusedMoE:
                 top_idx.reshape(-1, 1) == jnp.arange(self.num_experts),
                 axis=0, dtype=jnp.int32)                  # [E]
         if counts is not None:
-            counts.append((jnp.int32(top_idx.size),
-                           jnp.sum(group_sizes > 0, dtype=jnp.int32)))
+            pairs = jnp.int32(top_idx.size)
+            counts.append((pairs,
+                           jnp.sum(group_sizes > 0, dtype=jnp.int32),
+                           jnp.sum(group_sizes) if share else pairs))
 
         if ragged:
             out = self._ragged_ffn(params, x, top_vals, top_idx,
-                                   group_sizes)
+                                   group_sizes,
+                                   held=held if share else None)
         else:
             out = self._dense_ffn(params, x, probs, top_vals, top_idx)
         return out.reshape(orig_shape).astype(hidden.dtype)
@@ -160,14 +199,18 @@ class FusedMoE:
         return jnp.einsum("eth,te->th", expert_out,
                           combine.astype(expert_out.dtype))
 
-    def _ragged_ffn(self, params, x, top_vals, top_idx, group_sizes):
+    def _ragged_ffn(self, params, x, top_vals, top_idx, group_sizes,
+                    held=None):
         """Grouped-GEMM dispatch: (token, slot) pairs sort by expert,
         each expert's contiguous group of rows multiplies its own
         weights (`jax.lax.ragged_dot`), and the rows return to their
         pairs' places by the inverse permutation, where a token's
         `top_k` rows are summed under its routing weights in float32 —
         the moe_align + fused-GEMM design, with the sort as the
-        alignment and no scatter on either side."""
+        alignment and no scatter on either side. `held` `[T, k]` (a
+        share of the experts): the pairs that have a group; the others
+        lie behind the last group, where the grouped matmuls write
+        nothing that is read."""
         T = x.shape[0]
         k = self.top_k
         # Pairs in slot-major order: pair p is token p % T in slot
@@ -196,7 +239,11 @@ class FusedMoE:
         for slot in range(k):
             rows = down.at[dest[slot * T:(slot + 1) * T]].get(
                 unique_indices=True, mode="promise_in_bounds")
-            out += rows.astype(jnp.float32) * weights[:, slot:slot + 1]
+            rows = rows.astype(jnp.float32)
+            if held is not None:
+                # a row of no group is whatever the matmul left there
+                rows = jnp.where(held[:, slot:slot + 1], rows, 0.0)
+            out += rows * weights[:, slot:slot + 1]
         return out
 
     # -- host-side weight placement --
@@ -205,7 +252,7 @@ class FusedMoE:
                            which: str, expert_id: int,
                            hf_tensor: np.ndarray) -> None:
         """Place one expert's HF [out, in] tensor into the stacked
-        [E, in, out] param."""
+        [E, in, out] param (`expert_id` counts among the held)."""
         e = self.num_experts
         if which in ("w_gate", "w_up"):
             full_shape = (e, self.hidden_size, self.intermediate_size)

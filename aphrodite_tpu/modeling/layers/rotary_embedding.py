@@ -41,7 +41,11 @@ class RotaryEmbedding:
     """Plain RoPE with a precomputed cos/sin cache (float32).
 
     The cache is a numpy array captured as a jit constant; shape
-    [max_positions, rot_dim] storing [cos | sin] halves.
+    [max_positions, rot_dim] storing [cos | sin] halves. `max_len`
+    caps its rows at the longest sequence the server admits: every
+    step program holds the table, and a model built for a million
+    positions would hold half a gigabyte of it (None: the model's own
+    range, as ever).
     """
 
     def __init__(
@@ -51,13 +55,20 @@ class RotaryEmbedding:
         max_position_embeddings: int,
         base: float,
         is_neox_style: bool,
+        max_len: Optional[int] = None,
     ) -> None:
         self.head_size = head_size
         self.rotary_dim = rotary_dim
         self.max_position_embeddings = max_position_embeddings
         self.base = base
         self.is_neox_style = is_neox_style
+        self.max_len = max_len
         self.cos_sin_cache = self._compute_cos_sin_cache()
+
+    def _rows(self, positions: int) -> int:
+        """The table's rows for a range of `positions`."""
+        return positions if self.max_len is None \
+            else min(positions, self.max_len)
 
     def _compute_inv_freq(self, base: float) -> np.ndarray:
         return 1.0 / (base ** (np.arange(0, self.rotary_dim, 2,
@@ -66,7 +77,8 @@ class RotaryEmbedding:
 
     def _compute_cos_sin_cache(self) -> np.ndarray:
         inv_freq = self._compute_inv_freq(self.base)
-        t = np.arange(self.max_position_embeddings, dtype=np.float32)
+        t = np.arange(self._rows(self.max_position_embeddings),
+                      dtype=np.float32)
         freqs = np.einsum("i,j->ij", t, inv_freq)
         return np.concatenate([np.cos(freqs), np.sin(freqs)],
                               axis=-1).astype(np.float32)
@@ -179,21 +191,29 @@ def _yarn_get_mscale(scale: float = 1.0) -> float:
 
 class YaRNScalingRotaryEmbedding(RotaryEmbedding):
     """YaRN: NTK-by-parts interpolation + attention mscale (reference
-    `rotary_embedding.py:268-328`)."""
+    `rotary_embedding.py:268-328`). `attention_factor` is HF's key:
+    the multiplier of cos and sin itself, where `attn_factor` multiplies
+    what `_yarn_get_mscale` gives. With `rotary_dim` under the head
+    size only the rotated dimensions meet cos and sin, so only they
+    carry the multiplier."""
 
     def __init__(self, head_size, rotary_dim, max_position_embeddings, base,
                  is_neox_style, scaling_factor: float, *,
                  extrapolation_factor: float = 1.0,
                  attn_factor: float = 1.0, beta_fast: int = 32,
-                 beta_slow: int = 1) -> None:
+                 beta_slow: int = 1,
+                 attention_factor: Optional[float] = None,
+                 max_len: Optional[int] = None) -> None:
         self.scaling_factor = scaling_factor
         self.extrapolation_factor = extrapolation_factor
         self.attn_factor = attn_factor
         self.beta_fast = beta_fast
         self.beta_slow = beta_slow
-        self.mscale = float(_yarn_get_mscale(scaling_factor) * attn_factor)
+        self.mscale = float(
+            _yarn_get_mscale(scaling_factor) * attn_factor
+            if attention_factor is None else attention_factor)
         super().__init__(head_size, rotary_dim, max_position_embeddings,
-                         base, is_neox_style)
+                         base, is_neox_style, max_len)
 
     def _compute_inv_freq(self, scaling_factor: float) -> np.ndarray:
         pos_freqs = self.base ** (np.arange(0, self.rotary_dim, 2,
@@ -211,7 +231,8 @@ class YaRNScalingRotaryEmbedding(RotaryEmbedding):
 
     def _compute_cos_sin_cache(self) -> np.ndarray:
         inv_freq = self._compute_inv_freq(self.scaling_factor)
-        max_len = int(self.max_position_embeddings * self.scaling_factor)
+        max_len = self._rows(
+            int(self.max_position_embeddings * self.scaling_factor))
         t = np.arange(max_len, dtype=np.float32)
         freqs = np.einsum("i,j->ij", t, inv_freq)
         return np.concatenate(
@@ -229,16 +250,21 @@ def get_rope(
     base: float,
     is_neox_style: bool = True,
     rope_scaling: Optional[Dict[str, Any]] = None,
+    max_len: Optional[int] = None,
 ) -> RotaryEmbedding:
-    """Factory + cache (reference `rotary_embedding.py:333-379`)."""
+    """Factory + cache (reference `rotary_embedding.py:333-379`).
+    `max_len`: the longest sequence the server admits, where the
+    caller knows it; the table then has no more rows (plain and YaRN
+    embeddings take it)."""
     key = (head_size, rotary_dim, max_position, base, is_neox_style,
-           tuple(sorted(rope_scaling.items())) if rope_scaling else None)
+           tuple(sorted(rope_scaling.items())) if rope_scaling else None,
+           max_len)
     if key in _ROPE_CACHE:
         return _ROPE_CACHE[key]
 
     if rope_scaling is None:
         rope = RotaryEmbedding(head_size, rotary_dim, max_position, base,
-                               is_neox_style)
+                               is_neox_style, max_len)
     else:
         scaling_type = rope_scaling.get("type",
                                         rope_scaling.get("rope_type"))
@@ -257,11 +283,12 @@ def get_rope(
             extra = {
                 k: v for k, v in rope_scaling.items()
                 if k in ("extrapolation_factor", "attn_factor", "beta_fast",
-                         "beta_slow")
+                         "beta_slow", "attention_factor")
             }
             rope = YaRNScalingRotaryEmbedding(head_size, rotary_dim,
                                               original_max, base,
-                                              is_neox_style, factor, **extra)
+                                              is_neox_style, factor, **extra,
+                                              max_len=max_len)
         else:
             raise ValueError(f"Unknown RoPE scaling type {scaling_type}")
     _ROPE_CACHE[key] = rope

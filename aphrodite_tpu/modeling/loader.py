@@ -69,8 +69,12 @@ def get_model(model_config: ModelConfig,
             max_loras=lora_config.max_loras,
             max_rank=lora_config.max_lora_rank)
 
+    # a model whose rotary tables would else span its whole trained
+    # range is told the longest sequence this server admits
+    bounds = {"max_model_len": model_config.max_model_len} \
+        if getattr(model_cls, "takes_max_model_len", False) else {}
     model = model_cls(model_config.hf_config, dtype=dtype,
-                      linear_method=linear_method)
+                      linear_method=linear_method, **bounds)
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         _mark_moe_sharded(model)
 

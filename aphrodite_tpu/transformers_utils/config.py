@@ -35,7 +35,8 @@ def get_config(model: str,
         cls = {"yi": configs.YiConfig, "qwen": configs.QWenConfig,
                "smallthinker": configs.SmallThinkerConfig,
                "phi4flash": configs.Phi4FlashConfig,
-               "jamba": configs.JambaConfig}.get(declared)
+               "jamba": configs.JambaConfig,
+               "laguna": configs.LagunaConfig}.get(declared)
         if cls is not None:
             return cls.from_pretrained(model, revision=revision)
     try:

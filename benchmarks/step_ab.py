@@ -3,12 +3,14 @@
 built from `perf/configs/<config>.json` with zero weights.
 
     python benchmarks/step_ab.py jamba2-3b-bf16 [--prompts N] [--aot]
+    python benchmarks/step_ab.py laguna-s-2.1-bf16 --prompts 1 \
+        --prompt-len 2048
 
 On the chip (2 minutes): milliseconds a step on the host's clock and
 on the device's, and every device operation by seconds, calls and
 microseconds a call, from a trace of ten steps. A decode step of the
-cell's rows by default, a prompt step of `N` x 512 tokens with
-`--prompts N`. With `--aot`, on the CPU and without a chip (10 s): the
+cell's rows by default, a prompt step of `N` x 512 tokens (or
+`--prompt-len`) with `--prompts N`. With `--aot`, on the CPU and without a chip (10 s): the
 same program compiled for a described v5e and a census of what the
 compiler put around the kernels (async copies and slices by shape:
 whole operands staged, re-layouts), the optimised HLO to `--hlo PATH`.
@@ -36,14 +38,15 @@ sys.path.insert(0, os.getcwd())
 CELLS = {
     "jamba2-3b-bf16": (128, 60000, 1000, 0),
     "phi-4-mini-flash-bf16": (48, 40000, 2500, 33),
+    "laguna-s-2.1-bf16": (64, 60000, 4400, 33),
 }
 SLOTS = 128
 
 
-def build(name: str, prompts: int, abstract):
+def build(name: str, prompts: int, abstract, prompt_len: int = 512):
     """(the jitted step, its arguments) for a decode step, or a prompt
-    step of `prompts` x 512 tokens; `abstract` maps an array to what
-    the program is lowered with (itself on the chip)."""
+    step of `prompts` x `prompt_len` tokens; `abstract` maps an array
+    to what the program is lowered with (itself on the chip)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -55,16 +58,16 @@ def build(name: str, prompts: int, abstract):
     with open(os.path.join("perf", "configs", name + ".json")) as f:
         config = json.load(f)
     rows, pages, ctx, window_pages = CELLS[name]
-    model_config = ModelConfig("x", dtype="bfloat16", max_model_len=4096,
+    model_config = ModelConfig("x", dtype="bfloat16", max_model_len=8192,
                                hf_config=_hf(config))
     model = _model(config, model_config)
     shapes = jax.eval_shape(model.init_params)
     params = jax.tree_util.tree_map(abstract, shapes) \
-        if abstract is not None else jax.jit(model.init_params)()
+        if abstract is not None else _routed(jax.jit(model.init_params)())
     make = (lambda s, d: abstract(jax.ShapeDtypeStruct(s, d))) \
         if abstract is not None else jnp.zeros
     runner = ModelRunner(model, params, model_config,
-                         SchedulerConfig(None, SLOTS, 4096, 256), 16,
+                         SchedulerConfig(None, SLOTS, 8192, 256), 16,
                          pages * 16, num_state_slots=SLOTS)
     groups = model_config.get_page_groups()
     spec = model_config.get_state_spec()
@@ -77,7 +80,9 @@ def build(name: str, prompts: int, abstract):
     kv = [tuple(make((pages, 16, h * model_config.get_head_size()),
                      jnp.bfloat16) for _ in range(2))
           for h in model_config.get_kv_heads_per_slot()]
-    if hasattr(spec, "allocated"):
+    if spec is None:        # (pages alone)
+        pass
+    elif hasattr(spec, "allocated"):
         kv.append(tuple(make((spec.layers, SLOTS + 1) + s, jnp.dtype(d))
                         for s, d in spec.allocated))
     else:       # (a tree from before PR 42: a pair a layer)
@@ -90,9 +95,10 @@ def build(name: str, prompts: int, abstract):
     if prompts:
         groups_n = len(groups.kinds)
         mds = [SequenceGroupMetadata(
-            str(i), True, {i: SequenceData([5 + j % 50 for j in range(512)])},
+            str(i), True,
+            {i: SequenceData([5 + j % 50 for j in range(prompt_len)])},
             SamplingParams(temperature=0.0, max_tokens=4), {}, {},
-            group_tables={i: [(0, table(32))] * groups_n},
+            group_tables={i: [(0, table(prompt_len // 16))] * groups_n},
             state_slots={i: i}) for i in range(prompts)]
         inputs, _ = runner._prepare_prompt(mds)
         args = (lowered(inputs["input_ids"]), lowered(inputs["positions"]),
@@ -112,10 +118,26 @@ def build(name: str, prompts: int, abstract):
     return step, params, args, dict(is_prompt=False, use_prefix=False)
 
 
+def _routed(params):
+    """Zero weights send every token to the first experts, and a step
+    then reads ten experts a layer where the cell's reads nine in ten
+    of them: a layer's own router (`gate` beside the experts) is drawn
+    at random, so that the pairs spread as random weights spread them."""
+    import jax
+    for i, (bucket, leaves) in enumerate(sorted(params.items())):
+        if "gate" in leaves and "w_gate" in leaves:
+            leaves["gate"] = jax.random.normal(
+                jax.random.PRNGKey(i), leaves["gate"].shape,
+                leaves["gate"].dtype)
+    return params
+
+
 def _hf(config):
     from aphrodite_tpu.transformers_utils import configs
     cls = {"jamba": configs.JambaConfig,
-           "phi4flash": configs.Phi4FlashConfig}[config["model_type"]]
+           "phi4flash": configs.Phi4FlashConfig,
+           "laguna": getattr(configs, "LagunaConfig", None)
+           }[config["model_type"]]
     return cls(**{k: v for k, v in config.items() if k not in (
         "perf", "architectures", "model_type", "torch_dtype")})
 
@@ -125,6 +147,10 @@ def _model(config, model_config):
     if config["model_type"] == "jamba":
         from aphrodite_tpu.modeling.models.jamba import \
             JambaForCausalLM as cls
+    elif config["model_type"] == "laguna":
+        from aphrodite_tpu.modeling.models.laguna import LagunaForCausalLM
+        return LagunaForCausalLM(model_config.hf_config, jnp.bfloat16,
+                                 max_model_len=model_config.max_model_len)
     else:
         from aphrodite_tpu.modeling.models.phi4flash import \
             Phi4FlashForCausalLM as cls
@@ -149,6 +175,7 @@ def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("config", choices=sorted(CELLS))
     parser.add_argument("--prompts", type=int, default=0)
+    parser.add_argument("--prompt-len", type=int, default=512)
     parser.add_argument("--steps", type=int, default=40)
     parser.add_argument("--aot", action="store_true")
     parser.add_argument("--hlo", default=None)
@@ -171,16 +198,19 @@ def main() -> None:
     elif jax.default_backend() != "tpu":
         sys.exit("step_ab.py times a chip: no TPU here (try --aot)")
     step, params, (ids, pos, kv, meta, sel), static = build(
-        args.config, args.prompts, abstract)
+        args.config, args.prompts, abstract, args.prompt_len)
     what = f"{args.config}, " + (
-        f"a prompt step of {args.prompts} x 512" if args.prompts
+        f"a prompt step of {args.prompts} x {args.prompt_len}"
+        if args.prompts
         else f"a decode step of {CELLS[args.config][0]} rows")
     if args.aot:
         t0 = time.time()
-        hlo = step.lower(params, ids, pos, kv, meta, sel,
-                         **static).compile().as_text()
+        compiled = step.lower(params, ids, pos, kv, meta, sel,
+                              **static).compile()
+        hlo = compiled.as_text()
         print(f"{what}: compiled for a described v5e in "
               f"{time.time() - t0:.1f} s")
+        print(f"  {compiled.memory_analysis()}")
         if args.hlo:
             with open(args.hlo, "w") as f:
                 f.write(hlo)

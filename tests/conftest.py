@@ -121,11 +121,14 @@ LATER_METRICS = (
     "dispatch_starved_pct.batch", "dispatch_starved_prompt_pct.batch",
     "host_dispatch_ms.batch", "host_hops_ms.batch")
 #: cells appended since the pinning tests were written, oldest first
-#: (PR 41's), each with its configuration and the metrics it alone
-#: reports
-NEWER_CELLS = ("jamba2-3b-bf16.reason-512",)
+#: (PR 41's, PR 43's), each with its configuration and the metrics it
+#: alone reports
+NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k")
 #: the modules that hold the manifest's metrics to a count
 _PINNED = ("test_perf_smallthinker", "test_perf_phi4flash")
+#: a module that holds the manifest's LAST entries to its own cell's:
+#: its `_bench()` leaves out the cells appended after that one
+_PINNED_TO_ITS_CELL = {"test_perf_jamba": NEWER_CELLS[1:]}
 #: the module that holds PR 38's six to the manifest's last places and
 #: to the list of cells, and reads `BENCHMARK.json` with `json.load`
 _PINNED_BY_FILE = "test_perf_host_lead"
@@ -143,12 +146,12 @@ def _perf_conftest():
     return module
 
 
-def _without_newer_cells(bench: dict) -> dict:
-    """`bench` as it read before `NEWER_CELLS` were appended: without
-    the cells, a configuration no other cell runs, their names on
-    every `workloads` list, and a metric that only they report
-    (`tests/perf/conftest.py::without_cells`)."""
-    return _perf_conftest().without_cells(bench, cells=NEWER_CELLS)
+def _without_newer_cells(bench: dict, cells=NEWER_CELLS) -> dict:
+    """`bench` as it read before `cells` (`NEWER_CELLS`) were
+    appended: without the cells, a configuration no other cell runs,
+    their names on every `workloads` list, and a metric that only they
+    report (`tests/perf/conftest.py::without_cells`)."""
+    return _perf_conftest().without_cells(bench, cells=cells)
 
 
 class _JsonWithoutNewerCells:
@@ -181,13 +184,22 @@ def _the_manifest_without_later_metrics(request, monkeypatch):
     wraps this). `tests/perf/test_perf_host_lead.py` holds the six to
     the manifest's last places and the cells to a list of three, and
     loads the file itself: its `json` gives it the manifest without
-    `NEWER_CELLS`. The metrics and the cells have tests of their own
-    (`tests/perf/test_perf_host_lead.py`,
-    `tests/perf/test_perf_jamba.py`)."""
+    `NEWER_CELLS`. `tests/perf/test_perf_jamba.py` holds the
+    manifest's last configuration, cell and two metrics to its own:
+    its `_bench()` leaves out the cells appended after it
+    (`_PINNED_TO_ITS_CELL`). The metrics and the cells have tests of
+    their own (`tests/perf/test_perf_host_lead.py`,
+    `tests/perf/test_perf_jamba.py`, `tests/perf/test_perf_laguna.py`)."""
     module = request.module
     name = module.__name__.rsplit(".", 1)[-1]
     if name == _PINNED_BY_FILE:
         monkeypatch.setattr(module, "json", _JsonWithoutNewerCells())
+        return
+    if name in _PINNED_TO_ITS_CELL:
+        later, its_bench = _PINNED_TO_ITS_CELL[name], module._bench
+        monkeypatch.setattr(
+            module, "_bench",
+            lambda: _without_newer_cells(its_bench(), cells=later))
         return
     if name not in _PINNED:
         return

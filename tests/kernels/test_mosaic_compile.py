@@ -272,6 +272,47 @@ def test_decode_attention_compiles_at_phi4flash_shapes(
         on_v5e((B,), I32), new, new).compile()
 
 
+#: Laguna-S-2.1's attention layers: 8 KV heads of 128 under 48 query
+#: heads (a full layer) and 72 (a window layer, `window=512`): 6 and 9
+#: query rows a KV head, where 4, 7 and 20 ran before. (query heads,
+#: rows, table width, window): the cell's decode buckets with contexts
+#: of 4,097-4,736 tokens (tables 320 wide; a window group holds 33-34
+#: pages of them), the canary's one row at 256 pages, and 65 rows.
+LAGUNA_CASES = [
+    (heads, rows, 320, window)
+    for heads, window in ((48, None), (72, 512))
+    for rows in (4, 16, 48, 64, 65)
+] + [(48, 1, 256, None), (72, 1, 256, 512)]
+
+
+@pytest.mark.parametrize("Hq,B,pps,window", LAGUNA_CASES)
+def test_decode_attention_compiles_at_laguna_shapes(on_v5e, Hq, B, pps,
+                                                    window):
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk, head_block,
+        lane_bytes_of, padded_work_length, paged_decode_attention)
+    Hkv, d, page = 8, 128, 16
+    assert head_block(Hkv, d, BF16) == 8 and Hq // Hkv in (6, 9)
+    ppc = choose_pages_per_chunk(pps, page, lane_bytes_of(Hkv, d, BF16))
+    held = 34 if window else pps - 24
+    counts = [held - i % 2 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    # a pool of 3.7 GB in the one pair of page arrays: 32 KB a page a side
+    pages = on_v5e((57000, page, Hkv * d), BF16)
+    new = on_v5e((B, Hkv, d), BF16)
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn, vn, scale=128 ** -0.5,
+            pages_per_chunk=ppc, work_items=work, amla=True, window=window)
+
+    jax.jit(attend, donate_argnums=(1, 2)).lower(
+        on_v5e((B, Hq, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
 #: AI21-Jamba2-3B's attention layers: ONE KV head of 128 under 20 query
 #: heads, so the page's lane axis is one lane tile, a page copy is 4 KB
 #: and a row's packed query has 20 rows; 512-token items. (rows, table

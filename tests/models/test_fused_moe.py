@@ -165,7 +165,8 @@ def test_gate_activation_and_the_callers_router(activation, num_experts,
     want = _plain_moe(x, elsewhere, experts, top_k, _ACTS[activation])
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
     # counted in the program: pairs routed and experts with a pair
-    (pairs, touched), = counts
+    (pairs, touched, held), = counts
+    assert int(held) == int(pairs)      # no share: every pair is held
     top = np.argsort(-np.asarray(elsewhere), axis=-1)[:, :top_k]
     assert int(pairs) == tokens * top_k
     assert int(touched) == len(np.unique(top))
@@ -240,7 +241,8 @@ def test_counts_are_what_numpy_counts(num_experts, top_k, tokens, routing):
     out = moe(params, x, router_logits=logits.reshape(
         x.shape[:2] + (num_experts,)), counts=counts)
     assert out.shape == x.shape
-    (pairs, touched), = counts
+    (pairs, touched, held), = counts
+    assert int(held) == int(pairs)      # no share: every pair is held
     top = np.argsort(-np.asarray(logits), axis=-1)[:, :top_k]
     assert pairs.dtype == touched.dtype == jnp.int32
     assert int(pairs) == top.size == x.shape[0] * x.shape[1] * top_k
