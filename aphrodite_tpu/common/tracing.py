@@ -100,6 +100,10 @@ NAMES = (
                             # blocked prompt attention visits, over a
                             # step's attention layers
     "attn.prefill_tiles_padded",   # the tiles of its padded rectangles
+    "attn.prefill_steps",   # prompt steps built (`_prepare_prompt`)
+    "attn.prefill_kernel_steps",   # of them, those whose attention is
+                            # the Pallas flash kernel
+                            # (`layers/attention.py::takes_prefill_kernel`)
     "runner.in_flight",
 )
 

@@ -154,6 +154,15 @@ _STAGE_COUNTERS = [
      "aphrodite:prefill_attn_tiles_visited_total would read if every "
      "key block were scored for every query.",
      lambda s, c: c["attn.prefill_tiles_padded"]),
+    ("aphrodite:prefill_attn_steps_total",
+     "Prompt steps built (a chunk of one or more rows each).",
+     lambda s, c: c["attn.prefill_steps"]),
+    ("aphrodite:prefill_attn_kernel_steps_total",
+     "Of aphrodite:prefill_attn_steps_total, the steps whose attention "
+     "is the Pallas flash kernel (ops/pallas/prefill_attention.py): one "
+     "TPU, K and V in bfloat16 or float32, no ALiBi; the rest take the "
+     "jnp functions.",
+     lambda s, c: c["attn.prefill_kernel_steps"]),
     ("aphrodite:kv_pages_live_full_total",
      "Of aphrodite:decode_attn_pages_live_total, the pages of the full "
      "page groups (a page holds a group's layers).",

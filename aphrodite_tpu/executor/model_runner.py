@@ -34,7 +34,8 @@ from aphrodite_tpu.common.sampling_params import SamplingType
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupMetadata)
 from aphrodite_tpu.modeling.input_metadata import GroupView, InputMetadata
-from aphrodite_tpu.modeling.layers.attention import takes_blocked_prefill
+from aphrodite_tpu.modeling.layers.attention import (takes_blocked_prefill,
+                                                     takes_prefill_kernel)
 from aphrodite_tpu.modeling.layers.rejection import delta_rejection_length
 from aphrodite_tpu.modeling.layers.sampler import (Sampler, fused_sample,
                                                    _fused_sample_jit)
@@ -260,6 +261,15 @@ class ModelRunner:
         #: goes in tiles (`PagedAttention.blocked_from`)
         self.prefill_blocked_from: int = getattr(
             model, "prefill_blocked_from", BLOCKED_FROM)
+        #: whether a prompt step's attention is the Pallas flash
+        #: kernel, by whether it reads a prefix: K and V are the
+        #: chunk's own, in the model's type, or gathered from the pages
+        #: (a model whose layers carry an ALiBi bias says `uses_alibi`;
+        #: none does today)
+        self._prefill_kernel = tuple(
+            takes_prefill_kernel(kv_dtype, self._tp, sp,
+                                 getattr(model, "uses_alibi", False))
+            for kv_dtype in (model_config.dtype, kv_cache_dtype))
 
         # LoRA: bucket keys carrying slot-stacked adapter tensors, and a
         # slot resolver installed by the executor's WorkerLoRAManager.
@@ -659,6 +669,9 @@ class ModelRunner:
                  for rows in group_rows]
         self._count_prefill_tiles(group_rows, views, ctx_lens, plens,
                                   padded_len, use_prefix)
+        self.tracer.add("attn.prefill_steps")
+        if self._prefill_kernel[use_prefix]:
+            self.tracer.add("attn.prefill_kernel_steps")
 
         state_slots = None
         if self.num_state_slots is not None:

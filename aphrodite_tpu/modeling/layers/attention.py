@@ -6,9 +6,11 @@ xformers prompt path `:104-161`, prefix path `:163-178`, decode dispatch
 
 - cache write  -> functional scatter `ops.kv_cache.write_to_kv_cache`
   (buffers donated by the engine, so XLA updates in place);
-- prompt path  -> dense causal attention in jnp (`ops.attention.
-  prefill_attention`) — XLA's fused attention is MXU-efficient for the
-  rectangular prefill shapes;
+- prompt path  -> on one TPU the Pallas flash kernel
+  (`ops/pallas/prefill_attention.py`; `takes_prefill_kernel` is the
+  rule); elsewhere, and under ALiBi or over quantised pages, dense
+  causal attention in jnp (`ops.attention.prefill_attention`, in tiles
+  from `blocked_from` queries x keys a row on);
 - prefix path  -> same prefill math over [gathered prefix ; chunk];
 - decode path  -> Pallas flash-decoding kernel over HBM pages
   (`ops/pallas/paged_attention.py`), with the jnp gather path as the
@@ -47,9 +49,23 @@ from aphrodite_tpu.ops.kv_cache import gather_pages, write_to_kv_cache
 def takes_blocked_prefill(seq_len: int, kv_len: int,
                           blocked_from: int) -> bool:
     """Whether a prompt step of `seq_len` queries against `kv_len` keys
-    a row takes `prefill_attention_blocked` (the layer below chooses by
-    it, and the runner counts the step's tiles by it)."""
+    a row takes `prefill_attention_blocked` where it takes a `jnp`
+    function (the layer below chooses by it, and the runner counts the
+    step's tiles by it, whichever path the step takes)."""
     return seq_len * kv_len >= blocked_from
+
+
+def takes_prefill_kernel(kv_dtype, tp: int, sp, alibi: bool) -> bool:
+    """Whether a prompt step's attention is the Pallas flash kernel
+    (`ops/pallas/prefill_attention.py`): on one TPU (the kernel is a
+    single-device program: no `tp`, no `sp` ring), K and V in bfloat16
+    or float32 as they arrive (quantised pages come with a
+    dequantising scale), no ALiBi bias. Everything else keeps the
+    `jnp` functions. The layer below chooses by it and the runner
+    counts the step by it (`attn.prefill_kernel_steps`)."""
+    return (jax.default_backend() == "tpu" and tp == 1 and sp is None
+            and not alibi and
+            jnp.dtype(kv_dtype) in (jnp.bfloat16, jnp.float32))
 
 
 class PagedAttention:
@@ -216,6 +232,11 @@ class PagedAttention:
         if prompt_lens is None:
             prompt_lens = jnp.full((batch,), seq_len, dtype=jnp.int32)
 
+        # (static, like every choice below: a function of the step
+        # program's shapes and types)
+        flash = self.use_pallas and takes_prefill_kernel(
+            k_pages.dtype if metadata.use_prefix else k.dtype,
+            metadata.tp, metadata.sp, self.alibi_slopes is not None)
         if metadata.use_prefix:
             # Attend over [cached prefix ; this chunk] gathered from pages
             # (reference prefix path, triton context_attention_fwd).
@@ -225,7 +246,7 @@ class PagedAttention:
                                 self.num_kv_heads)
             kv_v = gather_pages(v_pages, metadata.block_tables,
                                 self.num_kv_heads)
-            if self.padded_head != self.head_size:
+            if self.padded_head != self.head_size and not flash:
                 kv_k = kv_k[..., :self.head_size]
                 kv_v = kv_v[..., :self.head_size]
             if kv_s != 1.0:
@@ -243,7 +264,34 @@ class PagedAttention:
             if self._ring_eligible(metadata, seq_len):
                 return self._ring_prefill(q, k, v, metadata)
 
-        # (static: a function of the step program's shapes)
+        if flash:
+            # One kernel for both of the `jnp` branches below: its
+            # transient is a tile in VMEM whatever the context, which
+            # is all `blocked_from` was for.
+            from aphrodite_tpu.ops.pallas.prefill_attention import (
+                prefill_flash_attention)
+            note_kernel_path(
+                "prefill_attention", "pallas",
+                "prefill_flash_attention, "
+                f"{'gathered prefix' if metadata.use_prefix else 'own keys'}")
+
+            def lanes(x):
+                # A head's lanes up to the tile, as the pages hold
+                # them and as `_decode` pads: zero lanes add nothing
+                # to a score, and the output's slice off below.
+                short = self.padded_head - x.shape[-1]
+                return jnp.pad(x, ((0, 0),) * 3 + ((0, short),)) \
+                    if short else x
+
+            return prefill_flash_attention(
+                lanes(q), lanes(kv_k), lanes(kv_v), context_lens,
+                kv_valid, self.scale,
+                sliding_window=self.sliding_window)[..., :self.head_size]
+        note_kernel_path(
+            "prefill_attention", "reference",
+            f"jnp functions: backend={jax.default_backend()}, "
+            f"tp={metadata.tp}, K/V={kv_k.dtype}, "
+            f"alibi={self.alibi_slopes is not None}")
         attend = prefill_attention_blocked \
             if takes_blocked_prefill(seq_len, kv_k.shape[1],
                                      self.blocked_from) \

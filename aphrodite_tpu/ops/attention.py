@@ -111,20 +111,22 @@ BLOCKED_FROM = 1 << 23
 KEY_BLOCK = 512
 
 
-def _tile_grid(seq_len: int, kv_len: int,
-               key_block: int) -> Tuple[int, int, int]:
+def _tile_grid(seq_len: int, kv_len: int, key_block: int,
+               query_block: Optional[int] = None) -> Tuple[int, int, int]:
     """(queries a block, query blocks, key blocks) of the blocked
-    prefill: a query block is as long as a key block, or the chunk
-    where that is shorter."""
-    query_block = min(key_block, seq_len)
+    prefill: a query block is as long as a key block (or as the flash
+    kernel's `query_block`), or the chunk where that is shorter."""
+    query_block = min(query_block or key_block, seq_len)
     return query_block, -(-seq_len // query_block), -(-kv_len // key_block)
 
 
 def prefill_tile_ranges(context_lens, kv_valid_lens, seq_len: int,
                         kv_len: int, key_block: int,
-                        sliding_window: Optional[int] = None, xp=jnp):
+                        sliding_window: Optional[int] = None, xp=jnp,
+                        query_block: Optional[int] = None):
     """The key blocks `[first, stop)` that each query block of
-    `prefill_attention_blocked` visits: for every row the keys that the
+    `prefill_attention_blocked` (and, at its own `query_block`, of
+    `ops/pallas/prefill_attention.py`'s kernel) visits: for every row the keys that the
     block's queries can see, from its first query's window (or key 0)
     to its last query's own position or the row's last valid key, and
     over the rows the union. A row that can see nothing (a pad row)
@@ -132,8 +134,8 @@ def prefill_tile_ranges(context_lens, kv_valid_lens, seq_len: int,
     what the mask leaves, which decides every element still. `xp` is
     `jnp` inside the program and `numpy` for the host's count of the
     same tiles (`count_prefill_tiles`)."""
-    query_block, query_blocks, key_blocks = _tile_grid(seq_len, kv_len,
-                                                       key_block)
+    query_block, query_blocks, key_blocks = _tile_grid(
+        seq_len, kv_len, key_block, query_block)
     start = xp.arange(query_blocks, dtype=xp.int32)[:, None] * \
         query_block + context_lens[None, :]             # [blocks, batch]
     hi = xp.minimum(start + query_block, kv_valid_lens[None, :]) - 1

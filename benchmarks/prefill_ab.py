@@ -1,14 +1,19 @@
-"""A/B harness for the blocked prompt attention alone, at a served
-geometry.
+"""A/B harness for the prompt's attention alone, at a served geometry.
 
-Times `ops/attention.py::prefill_attention_blocked` as a step program
-calls it (`modeling/layers/attention.py::PagedAttention._prefill`):
-one row of `--queries` new tokens behind `--ctx` cached ones, against
+Times what a step program calls for its prompt rows
+(`modeling/layers/attention.py::PagedAttention._prefill`): `--rows`
+rows of `--queries` new tokens behind `--ctx` cached ones, against
 `--keys` keys (the chunk's own, or the padded table gathered from the
-pages), of which `ctx + queries` are valid. `--cells` runs the eight
-calls of the two benchmark cells that take the function (PERF.md §5):
+pages), of which `ctx + queries` are valid. Two sides: the `jnp`
+function such a step takes off the kernel's path
+(`ops/attention.py::prefill_attention_blocked` from `--blocked-from`
+queries x keys a row on, `prefill_attention` under it), and with
+`--kernel` the Pallas flash kernel beside it
+(`ops/pallas/prefill_attention.py`, its blocks `choose_blocks`' or
+pinned by `--blocks`, several at once to compare). `--cells`
+runs the calls of the benchmark's cells (PERF.md §5):
 
-    python benchmarks/prefill_ab.py --cells --check
+    python benchmarks/prefill_ab.py --cells --check --kernel
 
 `phi-4-mini-flash-bf16.reason-2k`: `[1, 2048, 40, 128]` queries on
 their own 2,048 keys of 10 KV heads, no window (the full layer and the
@@ -20,19 +25,31 @@ tokens (a full layer's chunks two to four), and under window 4,096 on
 three) and on 6,144 behind 4,096 (chunk four: a window group's table
 lets go of the pages its window has passed, and its context counts
 from the first page it keeps; the widths are those of the cell's
-traces).
-`--check` compares the call with `prefill_attention` on the same inputs
-first. A call is timed as `profile_step.device_bench` times a kernel (a
-loop on the device, the slope between two trip counts), and printed
-beside it are the tiles the function visits of the padded rectangle's,
-by its own rule (`count_prefill_tiles`) where the tree has one.
+traces);
+`laguna-s-2.1-bf16.agent-4k`: `[1, 2048, 48, 128]` on 8 KV heads over
+their own 2,048 keys and over 4,096 gathered behind 2,048 (the two
+full layers' two chunks), `[1, 2048, 72, 128]` under window 512 over
+their own 2,048 and over 3,072 gathered behind 512 (the three window
+layers: the table holds the window, the chunk and a page, 160-161
+pages, padded to 192);
+`mistral-7b-w4a8.batch`: `[rows, 1024, 32, 128]` on their own 1,024
+keys of 8 KV heads at 1, 2 and 4 rows, no window (the plain function
+on the `jnp` side).
+`--check` compares each side with `prefill_attention` on the same
+inputs first, and `--oracle` both with float64 numpy besides (a head at
+a time; a minute at the widest call). A call is timed as
+`profile_step.device_bench` times a kernel (a loop on the device, the
+slope between two trip counts), and printed beside it are the tiles
+the 512-rule visits of the padded rectangle's (`count_prefill_tiles`)
+where the tree has one.
 
 It times the tree it is run in: to compare two commits, copy this file
 into a `git archive` of the other and run both in one chip call (a
-tree before PR 39 scans every key block for all queries and has no
-rule to count by). It is no code a benchmark cell runs. On the CPU it
-checks and times nothing: `--queries 32 --keys 64 --ctx 16 --block 8
---check` is a rehearsal.
+tree before PR 44 has no kernel and says so; one before PR 39 scans
+every key block for all queries and has no rule to count by). It is no
+code a benchmark cell runs. On the CPU it checks (the kernel in
+interpret mode) and times nothing: `--queries 32 --keys 64 --ctx 16
+--block 8 --check --kernel` is a rehearsal.
 """
 from __future__ import annotations
 
@@ -45,7 +62,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks.profile_step import device_bench  # noqa: E402
 
-#: (name, queries, keys, ctx, window, heads, KV heads, scale)
+#: (name, queries, keys, ctx, window, heads, KV heads, scale[, rows])
 CELLS = (
     ("phi full/cross", 2048, 2048, 0, 0, 40, 10, 0.125),
     ("phi window 512", 2048, 2048, 0, 512, 40, 10, 0.125),
@@ -58,20 +75,55 @@ CELLS = (
      0.0884),
     ("smallthinker window, chunk 4", 2048, 6144, 4096, 4096, 28, 4,
      0.0884),
+    ("laguna full, chunk 1", 2048, 2048, 0, 0, 48, 8, 0.0884),
+    ("laguna full, chunk 2", 2048, 4096, 2048, 0, 48, 8, 0.0884),
+    ("laguna window 512, chunk 1", 2048, 2048, 0, 512, 72, 8, 0.0884),
+    ("laguna window 512, chunk 2", 2048, 3072, 512, 512, 72, 8, 0.0884),
+    ("mistral, 1 row", 1024, 1024, 0, 0, 32, 8, 0.0884, 1),
+    ("mistral, 2 rows", 1024, 1024, 0, 0, 32, 8, 0.0884, 2),
+    ("mistral, 4 rows", 1024, 1024, 0, 0, 32, 8, 0.0884, 4),
 )
 
 
+def _oracle(q, k, v, ctx, valid, scale, window):
+    """`prefill_attention` in float64 numpy, a head at a time."""
+    import numpy as np
+    q, k, v = (np.asarray(x.astype("float32"), np.float64)
+               for x in (q, k, v))
+    b, s, heads, _ = q.shape
+    group = heads // k.shape[2]
+    q_pos = ctx + np.arange(s)[:, None]
+    k_pos = np.arange(k.shape[1])[None, :]
+    live = (k_pos <= q_pos) & (k_pos < valid)
+    if window:
+        live &= k_pos > q_pos - window
+    out = np.zeros(q.shape)
+    for row in range(b):
+        for h in range(heads):
+            scores = np.where(
+                live, q[row, :, h] @ k[row, :, h // group].T * scale,
+                -np.inf)
+            top = scores.max(axis=1, keepdims=True)
+            p = np.exp(scores - np.where(np.isfinite(top), top, 0.0))
+            total = p.sum(axis=1, keepdims=True)
+            out[row, :, h] = p @ v[row, :, h // group] / \
+                np.where(total == 0.0, 1.0, total)
+    return out
+
+
 def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
-            args) -> None:
+            rows, args) -> None:
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from aphrodite_tpu.ops import attention as att
     window = window or None
     key = jax.random.PRNGKey(args.seed)
     dtype = jnp.bfloat16
-    q = jax.random.normal(key, (1, queries, heads, args.head_dim), dtype)
+    q = jax.random.normal(key, (rows, queries, heads, args.head_dim),
+                          dtype)
     k = jax.random.normal(jax.random.fold_in(key, 1),
-                          (1, keys, kv_heads, args.head_dim), dtype)
+                          (rows, keys, kv_heads, args.head_dim), dtype)
     v = jax.random.normal(jax.random.fold_in(key, 2), k.shape, dtype)
     valid = min(ctx + queries, keys)
     block = dict(key_block=args.block) if args.block else {}
@@ -79,38 +131,83 @@ def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
     tiles = "no rule in this tree" if count is None else \
         "{} of {} tiles".format(*count([ctx], [valid], queries, keys,
                                        window, **block))
-    ctx_lens = jnp.full((1,), ctx, jnp.int32)
-    valid = jnp.full((1,), valid, jnp.int32)
-    print(f"prefill_attn[{name}] q={queries} heads={heads}/{kv_heads}x"
-          f"{args.head_dim} keys={keys} ctx={ctx} window={window}: "
-          f"{tiles}", flush=True)
+    ctx_lens = jnp.full((rows,), ctx, jnp.int32)
+    valid_lens = jnp.full((rows,), valid, jnp.int32)
+    blocked = queries * keys >= args.blocked_from
+    print(f"prefill_attn[{name}] q={rows}x{queries} heads={heads}/"
+          f"{kv_heads}x{args.head_dim} keys={keys} ctx={ctx} "
+          f"window={window}: {tiles}", flush=True)
 
-    def attend(qq):
+    def plain(qq):
+        return att.prefill_attention(qq, k, v, ctx_lens, valid_lens,
+                                     scale, sliding_window=window)
+
+    def walk(qq):
         return att.prefill_attention_blocked(
-            qq, k, v, ctx_lens, valid, scale, sliding_window=window,
-            **block)
+            qq, k, v, ctx_lens, valid_lens, scale,
+            sliding_window=window, **block)
+
+    sides = [] if args.kernel == "only" else [
+        ("blocked" if blocked else "plain", walk if blocked else plain)]
+    if args.kernel:
+        try:
+            from aphrodite_tpu.ops.pallas import prefill_attention as pf
+        except ImportError:
+            print("  kernel: none in this tree", flush=True)
+        else:
+            for blocks in args.blocks.split(",") if args.blocks else [""]:
+                # (`QxK`: a copied block is a sub-block)
+                pinned = tuple(map(int, blocks.split("x"))) if blocks \
+                    else (args.block,) * 2 if args.block else None
+                if pinned is None:
+                    pad = -queries % pf.TOKEN_TILE, -keys % pf.TOKEN_TILE
+                    print("  kernel blocks (queries, keys, keys copied): "
+                          f"{pf.choose_blocks(queries + pad[0], keys + pad[1], heads // kv_heads, window)}",
+                          flush=True)
+                else:
+                    pinned = (pinned + pinned[-1:])[:3]
+
+                def kernel(qq, pinned=pinned):
+                    return pf.prefill_flash_attention(
+                        qq, k, v, ctx_lens, valid_lens, scale, window,
+                        interpret=jax.default_backend() != "tpu",
+                        blocks=pinned)
+                sides.append((f"kernel {blocks}".strip(), kernel))
 
     if args.check:
-        got = jax.jit(attend)(q).astype(jnp.float32)
-        want = jax.jit(lambda qq: att.prefill_attention(
-            qq, k, v, ctx_lens, valid, scale,
-            sliding_window=window))(q).astype(jnp.float32)
-        err = float(jnp.max(jnp.abs(got - want)))
-        print(f"  check: max |blocked - plain| = {err:.4g} "
-              f"(finite: {bool(jnp.isfinite(got).all())})", flush=True)
+        want = jax.jit(plain)(q).astype(jnp.float32)
+        exact = _oracle(q, k, v, ctx, valid, scale, window) \
+            if args.oracle else None
+        if exact is not None:
+            print("  oracle: max |plain - float64| = "
+                  f"{np.abs(np.asarray(want) - exact).max():.4g}",
+                  flush=True)
+        for side, attend in sides:
+            got = jax.jit(attend)(q).astype(jnp.float32)
+            err = float(jnp.max(jnp.abs(got - want)))
+            said = "" if exact is None else " |{} - float64| = {:.4g}".format(
+                side, np.abs(np.asarray(got) - exact).max())
+            print(f"  check: max |{side} - plain| = {err:.4g}{said} "
+                  f"(finite: {bool(jnp.isfinite(got).all())})",
+                  flush=True)
     if jax.default_backend() != "tpu":
         return
-    # (an output is no query for the next call: the dependency alone)
-    s, _ = device_bench(
-        lambda qq, i: qq + attend(qq) * jnp.bfloat16(1e-30), q, slow=True)
-    print(f"  whole call: {s * 1e3:.3f} ms", flush=True)
+    for side, attend in sides:
+        # (an output is no query for the next call: the dependency alone)
+        s, _ = device_bench(
+            lambda qq, i, attend=attend:
+            qq + attend(qq) * jnp.bfloat16(1e-30), q, slow=True)
+        print(f"  whole call, {side}: {s * 1e3:.3f} ms", flush=True)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", action="store_true",
-                    help="the calls of the two benchmark cells that "
-                         "take the function, in place of one geometry")
+                    help="the calls of the benchmark's cells, in place "
+                         "of one geometry")
+    ap.add_argument("--only", default="",
+                    help="with --cells: the cells whose name holds this")
+    ap.add_argument("--rows", type=int, default=1)
     ap.add_argument("--queries", type=int, default=2048)
     ap.add_argument("--keys", type=int, default=2048)
     ap.add_argument("--ctx", type=int, default=0,
@@ -126,12 +223,28 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check", action="store_true",
                     help="compare the output with prefill_attention")
+    ap.add_argument("--oracle", action="store_true",
+                    help="with --check: and with float64 numpy")
+    ap.add_argument("--blocked-from", type=int, default=1 << 21,
+                    help="queries x keys a row from which the jnp side "
+                         "is the blocked function (Phi's and Laguna's "
+                         "threshold; every cell's call falls on its "
+                         "model's side of it)")
+    ap.add_argument("--kernel", nargs="?", const="beside",
+                    choices=("beside", "only"),
+                    help="the Pallas flash kernel beside the function, "
+                         "or (`only`) in its place")
+    ap.add_argument("--blocks", default="",
+                    help="pin the kernel's blocks, `QxK[xM]` (queries a "
+                         "block, keys a sub-block, keys a copied block), "
+                         "several with commas, each a side of its own "
+                         "(default: the kernel's choice from the shapes)")
     args = ap.parse_args()
-    cells = CELLS if args.cells else (
+    cells = [c for c in CELLS if args.only in c[0]] if args.cells else [
         ("one geometry", args.queries, args.keys, args.ctx, args.window,
-         args.heads, args.kv_heads, args.scale),)
+         args.heads, args.kv_heads, args.scale, args.rows)]
     for cell in cells:
-        run_one(*cell, args)
+        run_one(*(cell + (1,))[:9], args)
 
 
 if __name__ == "__main__":

@@ -80,7 +80,8 @@ _CORPUS = [
 
 #: Kernel families whose dispatchers must have taken the compiled
 #: Pallas side on one chip (`note_kernel_path` lines in the server log).
-FAMILIES = ("decode_attention", "kv_write", "quant_matmul")
+FAMILIES = ("decode_attention", "kv_write", "prefill_attention",
+            "quant_matmul")
 
 
 class SmokeFailure(Exception):

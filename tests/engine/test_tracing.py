@@ -713,6 +713,38 @@ def test_a_lead_counter_reads_zero_before_its_first_event(counter):
     assert len(reads) == (2 if name.startswith("pull.blocked") else 1)
 
 
+#: the prompt attention's dispatch, counted where a prompt step is
+#: built (`ModelRunner._prepare_prompt`)
+PROMPT_KERNEL_COUNTERS = {
+    "aphrodite:prefill_attn_steps_total": "attn.prefill_steps",
+    "aphrodite:prefill_attn_kernel_steps_total":
+    "attn.prefill_kernel_steps",
+}
+
+
+@pytest.mark.parametrize("counter", sorted(PROMPT_KERNEL_COUNTERS))
+def test_a_prompt_kernel_counter_is_exported_and_described(counter):
+    """Each of the pair reads 0 before a prompt step, exports its own
+    accumulator and no other, and goes by one name in `NAMES`, the
+    README's operator section and PERF.md."""
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    name = PROMPT_KERNEL_COUNTERS[counter]
+    assert name in tracing.NAMES
+    labels = dict(model_name="tracing-test-" + counter.split(":")[1])
+    log = StatLogger(labels=labels)
+    assert _value(counter, labels) == 0.0
+    tracer = tracing.Tracer()
+    tracer.add(name, count=7)
+    log.log(_stats(stage_seconds=tracer.seconds,
+                   stage_counts=tracer.counts))
+    assert _value(counter, labels) == 7.0
+    assert [metric for metric, _, total in _STAGE_COUNTERS
+            if total(tracer.seconds, tracer.counts)] == [counter]
+    for text in ("README.md", "PERF.md"):
+        with open(os.path.join(ROOT, text)) as f:
+            assert counter in f.read(), text
+
+
 def test_steps_ahead_says_not_pulled_wherever_it_is_described():
     """`runner.ahead` counts a round that had not been pulled, not one
     that was still on the device; the help text, the comment in

@@ -555,6 +555,153 @@ def test_blocked_prefill_is_plain_prefill(case):
                                    rtol=2e-5, atol=2e-5)
 
 
+#: query heads a KV head of the kernel's cases, by turns: Mistral's
+#: and Phi's 4, Laguna's 6 and 9, SmallThinker's 7
+FLASH_GROUPS = (4, 6, 7, 9)
+
+
+@pytest.mark.parametrize("case", BLOCKED_CASES.values(),
+                         ids=list(BLOCKED_CASES))
+def test_flash_kernel_is_plain_prefill(case, monkeypatch):
+    """The same calls through `ops/pallas/prefill_attention.py`'s
+    kernel, interpreted on the CPU at blocks of 8 queries and 8 keys,
+    two sub-blocks a copied block: `prefill_attention` at every query
+    of the padded chunk and the numpy oracle at every query of the
+    prompt, with NaN in every key and value of the sub-blocks that no
+    query block of the row visits (a sub-block outside a row's range
+    is not read). The ALiBi cases are the dispatch's: a layer with
+    slopes, like one over quantised pages, keeps the `jnp` functions
+    on a TPU too."""
+    from aphrodite_tpu.modeling.input_metadata import InputMetadata
+    from aphrodite_tpu.modeling.layers import attention as layer_mod
+    from aphrodite_tpu.ops.attention import prefill_tile_ranges
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    rng = np.random.default_rng(5)
+    s_new, kv_len, window = case["s_new"], case["kv_len"], case["window"]
+    at = list(BLOCKED_CASES.values()).index(case)
+    group = FLASH_GROUPS[at % len(FLASH_GROUPS)]
+    b, Hkv, d = len(case["ctx"]), 1 + at % 2, 16
+    Hq = group * Hkv
+    q = rng.normal(size=(b, s_new, Hq, d)).astype(np.float32)
+    k = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
+    v = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
+    ctx = np.array(case["ctx"], dtype=np.int32)
+    kv_valid = ctx + np.array(case["new"], dtype=np.int32)
+    scale = 1 / np.sqrt(d)
+    if case["slopes"]:
+        monkeypatch.setattr(layer_mod.jax, "default_backend",
+                            lambda: "tpu")
+        monkeypatch.setattr(
+            flash, "prefill_flash_attention",
+            lambda *a, **kw: pytest.fail("the kernel takes no ALiBi"))
+        slopes = np.linspace(0.5, 0.05, Hq).astype(np.float32)
+        layer = layer_mod.PagedAttention(
+            Hq, d, scale, num_kv_heads=Hkv, alibi_slopes=slopes,
+            sliding_window=window)
+        meta = InputMetadata(
+            slot_mapping=jnp.zeros((b * s_new,), jnp.int32),
+            block_tables=jnp.zeros((b, 1), jnp.int32),
+            context_lens=jnp.zeros((b,), jnp.int32),
+            prompt_lens=jnp.array(case["new"], jnp.int32), is_prompt=True)
+        own = [jnp.array(x[:, :s_new]) for x in (k, v)]
+        got = layer._prefill(jnp.array(q), *own, None, None, meta)
+        np.testing.assert_array_equal(np.array(got), np.array(
+            prefill_attention(jnp.array(q), *own, jnp.zeros((b,), jnp.int32),
+                              meta.prompt_lens, scale,
+                              sliding_window=window,
+                              alibi_slopes=jnp.array(slopes))))
+        rule = layer_mod.takes_prefill_kernel
+        assert rule(jnp.float32, 1, None, False) and \
+            rule("bfloat16", 1, None, False)
+        assert not any((rule(jnp.float32, 1, None, True),
+                        rule(jnp.int8, 1, None, False),
+                        rule(jnp.float8_e5m2, 1, None, False),
+                        rule(jnp.bfloat16, 2, None, False),
+                        rule(jnp.bfloat16, 1, (None, 4096), False)))
+        return
+    block = 8
+    pad_q, pad_k = -s_new % block, -kv_len % block
+    keys = kv_len + pad_k
+    major = 2 * block if keys % (2 * block) == 0 else block
+    unseen = np.ones((b, keys), bool)
+    for row in range(b):
+        first, stop = prefill_tile_ranges(
+            ctx[row:row + 1], kv_valid[row:row + 1], s_new + pad_q, keys,
+            block, window, xp=np, query_block=block)
+        for lo, hi in zip(first, stop):
+            unseen[row, lo * block:max(hi, lo) * block] = False
+    for row in range(b):    # (no case's window reaches its first key)
+        assert unseen[row].any() == (window is not None or
+                                     kv_valid[row] <= keys - block)
+
+    def padded(x, pad, fill=0.0):
+        return np.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)),
+                      constant_values=fill)
+    dirty = [np.where(unseen[:, :, None, None], np.nan, padded(x, pad_k))
+             for x in (k, v)]
+    got = np.array(flash.prefill_flash_attention(
+        jnp.array(padded(q, pad_q)), *map(jnp.array, dirty),
+        jnp.array(ctx), jnp.array(kv_valid), scale, window,
+        blocks=(block, block, major), interpret=True))[:, :s_new]
+    plain = np.array(prefill_attention(
+        jnp.array(q), jnp.array(k), jnp.array(v), jnp.array(ctx),
+        jnp.array(kv_valid), scale, sliding_window=window))
+    expected = numpy_prefill(q, k, v, ctx, kv_valid, scale, window=window)
+    assert got.shape == plain.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
+    for bi, n in enumerate(kv_valid - ctx):
+        np.testing.assert_allclose(got[bi, :n], expected[bi, :n],
+                                   rtol=2e-5, atol=2e-5)
+    if pad_q or pad_k:
+        # left to itself the wrapper pads to its own tile and chooses
+        # the blocks
+        whole = np.array(flash.prefill_flash_attention(
+            jnp.array(q), jnp.array(k), jnp.array(v), jnp.array(ctx),
+            jnp.array(kv_valid), scale, window, interpret=True))
+        np.testing.assert_allclose(whole, plain, rtol=2e-5, atol=2e-5)
+
+
+#: (queries, keys, query heads a KV head, window) -> (queries a block,
+#: keys a sub-block, keys a copied block): the cells' calls
+#: (`benchmarks/prefill_ab.py::CELLS`) and the rule's edges
+FLASH_BLOCKS = {
+    "mistral": ((1024, 1024, 4, None), (512, 512, 1024)),
+    "phi-window": ((2048, 2048, 4, 512), (512, 256, 2048)),
+    "smallthinker-full": ((2048, 8192, 7, None), (512, 512, 2048)),
+    "smallthinker-window-7168": ((2048, 7168, 7, 4096), (512, 512, 1024)),
+    "laguna-full": ((2048, 4096, 6, None), (512, 512, 2048)),
+    "laguna-window": ((2048, 3072, 9, 512), (256, 256, 1536)),
+    "jamba-one-kv-head": ((512, 512, 20, None), (128, 512, 512)),
+    "64-heads-on-one": ((2048, 2048, 64, None), (64, 512, 2048)),
+    "falcons-71-on-one": ((1024, 1024, 71, None), (32, 512, 1024)),
+    "a-chunk-of-one-tile": ((128, 128, 4, None), (128, 128, 128)),
+    "lengths-of-odd-tiles": ((384, 1152, 4, None), (128, 128, 1152)),
+    "a-window-under-two-tiles": ((2048, 2048, 4, 200), (512, 128, 2048)),
+}
+
+
+@pytest.mark.parametrize("shape,want", FLASH_BLOCKS.values(),
+                         ids=list(FLASH_BLOCKS))
+def test_the_flash_kernels_blocks_follow_the_calls_shapes(shape, want):
+    """`choose_blocks` reads the chunk, the keys, `group` and the
+    window and nothing else: the longest query block within 4,096
+    rows, key sub-blocks of 512 or half a window, a copied block of at
+    most 2,048 keys, each dividing what it tiles; and what a
+    sub-block's scores, weights and bfloat16 weights take beside the
+    scratch and the copied blocks fits the VMEM the kernel states."""
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    queries, keys, group, window = shape
+    assert flash.choose_blocks(*shape) == want
+    query_block, key_block, major = want
+    assert queries % query_block == 0 and keys % major == 0 and \
+        major % key_block == 0
+    rows, d = group * query_block, 128
+    need = rows * key_block * (4 + 4 + 2) + \
+        rows * (d * 2 + 128 * 4 * 2 + d * 4) + \
+        2 * 2 * (query_block * group * d + 2 * major * d) * 2
+    assert need <= 0.8 * flash.VMEM_LIMIT
+
+
 def test_blocked_prefill_never_reads_a_tile_no_query_can_see():
     """NaN in every key and value of the key blocks that no query block
     of the chunk can see (before the first query's window, after the
@@ -668,7 +815,7 @@ def test_a_prompt_steps_tile_count_over_its_layers():
     from benchmarks.prefill_ab import CELLS
     tiles = np.array([count_prefill_tiles(
         [ctx], [ctx + queries], queries, keys, window or None)
-        for _, queries, keys, ctx, window, *_ in CELLS[2:]])
+        for _, queries, keys, ctx, window, *_ in CELLS[2:8]])
     assert tuple(3 * tiles[:3].sum(0) + 9 * tiles[3:].sum(0)) == \
         (1260, 2016)
 
@@ -683,14 +830,24 @@ def test_prefill_ab_check_arm_rehearses_on_the_cpu(monkeypatch, capsys):
     monkeypatch.setattr("sys.argv", [
         "prefill_ab.py", "--queries", "32", "--keys", "64", "--ctx", "16",
         "--window", "12", "--block", "8", "--heads", "4", "--kv-heads",
-        "2", "--head-dim", "16", "--check"])
+        "2", "--head-dim", "16", "--check", "--oracle", "--kernel",
+        "--blocked-from", "1024"])
     prefill_ab.main()
     said = capsys.readouterr().out
     assert "window=12: 12 of 32 tiles" in said
-    (check,) = [line for line in said.splitlines() if "check:" in line]
-    assert "finite: True" in check
-    assert float(check.split("=")[1].split()[0]) < 1e-2
+    checks = [line for line in said.splitlines() if "check:" in line]
+    assert ["|blocked - plain|" in c for c in checks] == [True, False]
+    assert "|kernel - plain|" in checks[1]
+    for check in checks:        # bfloat16 in, bfloat16 out
+        assert "finite: True" in check
+        assert float(check.split("=")[1].split()[0]) < 1e-2
+        assert float(check.split("=")[2].split()[0]) < 1e-2
     assert "whole call" not in said
+    # every cell's call falls on its model's side of the harness's
+    # threshold: Mistral's takes the plain function, the others' the walk
+    assert [c[1] * c[2] >= 1 << 21 for c in prefill_ab.CELLS] == \
+        [True] * 12 + [False] * 3
+    assert [(c + (1,))[8] for c in prefill_ab.CELLS[12:]] == [1, 2, 4]
 
 
 # ---- a causal window over a table that slides ----

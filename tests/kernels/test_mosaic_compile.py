@@ -605,3 +605,70 @@ def test_the_check_sees_the_parents_layout_sliced_and_copied(on_v5e,
     # the parameter's layout is the cause: the three rows outermost
     assert "bf16[129,3,5120]{2,0,1:T(8,128)(2,1)} parameter" in hlo
     assert _whole_array_moves(hlo, "f32[129,16,5120]") == []
+
+
+# ---- the prompt's attention as the flash kernel ----
+
+#: (rows, queries, query heads, KV heads, keys, window): the calls of
+#: the five cells' prompt steps (`benchmarks/prefill_ab.py::CELLS`,
+#: and Jamba's 512-token prompts on one KV head)
+PREFILL_CASES = {
+    "mistral-1-row": (1, 1024, 32, 8, 1024, None),
+    "mistral-2-rows": (2, 1024, 32, 8, 1024, None),
+    "mistral-4-rows": (4, 1024, 32, 8, 1024, None),
+    "smallthinker-chunk-1": (1, 2048, 28, 4, 2048, None),
+    "smallthinker-full-table": (1, 2048, 28, 4, 8192, None),
+    "smallthinker-window-table": (1, 2048, 28, 4, 7168, 4096),
+    "smallthinker-window-table-chunk-4": (1, 2048, 28, 4, 6144, 4096),
+    "laguna-full-chunk-1": (4, 2048, 48, 8, 2048, None),
+    "laguna-full-table": (1, 2048, 48, 8, 4096, None),
+    "laguna-window-chunk-1": (1, 2048, 72, 8, 2048, 512),
+    "laguna-window-table": (2, 2048, 72, 8, 3072, 512),
+    "phi-full": (1, 2048, 40, 10, 2048, None),
+    "phi-window": (1, 2048, 40, 10, 2048, 512),
+    "jamba": (8, 512, 20, 1, 512, None),
+}
+
+
+@pytest.mark.parametrize("case", PREFILL_CASES.values(),
+                         ids=list(PREFILL_CASES))
+def test_prefill_flash_attention_compiles(on_v5e, case):
+    """The prompt's flash kernel at each cell's prompt shapes, called
+    as `PagedAttention` calls it: q, k, v as the projections leave
+    them (`[rows, tokens, heads x 128]`), seen as `[rows, tokens,
+    heads, 128]`, and the output back as `o_proj` reads it. Mosaic
+    takes it at the blocks `choose_blocks` gives, under the scoped
+    VMEM the kernel states; and around the call the compiler puts
+    nothing: the three operands reach it and the output leaves it
+    without a copy, a slice or a staged move."""
+    import re
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    rows, s, Hq, Hkv, kv, window = case
+    d = 128
+
+    def attend(q, k, v, ctx, valid):
+        return flash.prefill_flash_attention(
+            q.reshape(rows, s, Hq, d), k.reshape(rows, kv, Hkv, d),
+            v.reshape(rows, kv, Hkv, d), ctx, valid, d ** -0.5,
+            window).reshape(rows, s, Hq * d)
+
+    hlo = jax.jit(attend).lower(
+        on_v5e((rows, s, Hq * d), BF16), on_v5e((rows, kv, Hkv * d), BF16),
+        on_v5e((rows, kv, Hkv * d), BF16), on_v5e((rows,), I32),
+        on_v5e((rows,), I32)).compile().as_text()
+    (call,) = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert "_prefill_flash_impl" in call.split(" = ")[0]
+    (stated,) = re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+        r'"size":"(\d+)"\}\]', call)
+    assert int(stated) == flash.VMEM_LIMIT <= 48 << 20
+    for array in (f"bf16[{rows},{s},{Hq * d}]",
+                  f"bf16[{rows},{kv},{Hkv * d}]"):
+        assert _whole_array_moves(hlo, array) == []
+    query_block, key_block, major = flash.choose_blocks(
+        s, kv, Hq // Hkv, window)
+    assert s % query_block == 0 and kv % major == 0 and \
+        major % key_block == 0 and major <= flash.KEY_MAJOR
+    assert Hq // Hkv * query_block * key_block * 4 <= flash.SCORE_BYTES \
+        or key_block == flash.TOKEN_TILE

@@ -473,6 +473,28 @@ def main() -> int:
                 if not (erro < 3e-2):
                     failures.append((name + " out", erro))
 
+    with section("prompt attention (the flash kernel)"):
+        # -- rows at different contexts, a pad row, a window, heads of
+        # 6 and 7 a KV head, a chunk and keys of odd tiles --
+        from aphrodite_tpu.ops.attention import prefill_attention
+        from aphrodite_tpu.ops.pallas.prefill_attention import (
+            prefill_flash_attention)
+        for fq, fkv, fkeys, fwin in ((28, 4, 1152, None), (12, 2, 1152, 200),
+                                     (32, 8, 384, None)):
+            fq_ = jnp.asarray(rs.randn(3, 384, fq, d), jnp.bfloat16)
+            fk_ = jnp.asarray(rs.randn(3, fkeys, fkv, d), jnp.bfloat16)
+            fv_ = jnp.asarray(rs.randn(3, fkeys, fkv, d), jnp.bfloat16)
+            fnew = np.array([384, 0, 301], np.int32)
+            fctx = np.array([fkeys - 384, 0, (fkeys - 384) // 3], np.int32)
+            fargs = (fq_, fk_, fv_, jnp.asarray(fctx),
+                     jnp.asarray(fctx + fnew), d ** -0.5)
+            check(f"prompt attention {fq}/{fkv} heads, {fkeys} keys, "
+                  f"window {fwin}",
+                  np.asarray(prefill_attention(
+                      *fargs, sliding_window=fwin), np.float32),
+                  np.asarray(prefill_flash_attention(
+                      *fargs, sliding_window=fwin), np.float32))
+
     with section("prefill page writer"):
         # -- prefill page writer (whole-page DMA, partial tail, OOB) --
         from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,

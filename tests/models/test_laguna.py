@@ -220,6 +220,78 @@ def test_engine_logits_against_the_reference(chunk, tmp_path, monkeypatch):
                          for j in range(steps)]) > 1e3 * LIMIT
 
 
+@pytest.mark.parametrize("chunk,past", [(CHUNK, False), (64, True)],
+                         ids=["under-blocked-from", "past-blocked-from"])
+def test_the_flash_kernel_serves_what_the_jnp_functions_serve(
+        chunk, past, tmp_path, monkeypatch):
+    """A prompt in chunks under `blocked_from` (`prefill_attention` on
+    the `jnp` side) and one in a chunk past it (the walk), window
+    layers of 9 and full layers of 6 query heads a KV head: with the
+    dispatch's rule answering as on one TPU and the kernel interpreted,
+    every prompt step's attention is the kernel's, the tokens are the
+    `jnp` path's and the logits its own to the order of the sums. The
+    counters say which path a step took; the tile counters count the
+    512-rule's tiles of a step past the threshold on either path."""
+    import functools
+    from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
+    from aphrodite_tpu.executor import model_runner
+    from aphrodite_tpu.modeling.layers import attention as layer_mod
+    from aphrodite_tpu.modeling.models import laguna
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    monkeypatch.setattr(laguna, "PREFILL_BLOCKED_FROM", 2048)
+    prompt, steps = _prompt(4), 6
+
+    def serve(name):
+        s = Served(tmp_path / name, monkeypatch, max_chunk_tokens=chunk)
+        ((reply,),) = s.run([prompt], steps)
+        counts = s.engine.tracer.counts
+        exported = {metric: total(s.engine.tracer.seconds, counts)
+                    for metric, _, total in _STAGE_COUNTERS}
+        with open(os.path.join(ROOT, "README.md")) as f:
+            readme = f.read()
+        for metric, counter in (
+                ("aphrodite:prefill_attn_steps_total",
+                 "attn.prefill_steps"),
+                ("aphrodite:prefill_attn_kernel_steps_total",
+                 "attn.prefill_kernel_steps")):
+            assert exported[metric] == counts[counter]
+            assert metric in readme
+        return reply, [r[0][:VOCAB] for r in s.rows[-steps:]], counts
+
+    reply, rows, counts = serve("jnp")
+    chunks = -(-len(prompt) // chunk)
+    assert counts["attn.prefill_steps"] == chunks
+    assert counts["attn.prefill_kernel_steps"] == 0
+    assert (counts["attn.prefill_tiles_padded"] > 0) == past
+
+    rule, kernel, calls = layer_mod.takes_prefill_kernel, \
+        flash.prefill_flash_attention, []
+
+    def on_one_tpu(*args):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            return rule(*args)
+
+    def interpreted(q, k, *args, **kwargs):
+        calls.append((q.shape, k.shape))
+        return kernel(q, k, *args, interpret=True, **kwargs)
+    monkeypatch.setattr(layer_mod, "takes_prefill_kernel", on_one_tpu)
+    monkeypatch.setattr(model_runner, "takes_prefill_kernel", on_one_tpu)
+    monkeypatch.setattr(flash, "prefill_flash_attention", interpreted)
+    flash_reply, flash_rows, flash_counts = serve("flash")
+    # traced once a layer and a step program, heads padded to the lanes
+    assert len(calls) % 5 == 0 and {q[3] for q, _ in calls} == {128}
+    assert {q[2] for q, _ in calls} == {12, 18}
+    assert flash_counts["attn.prefill_kernel_steps"] == \
+        flash_counts["attn.prefill_steps"] == chunks
+    assert [flash_counts[f"attn.prefill_tiles_{kind}"]
+            for kind in ("visited", "padded")] == \
+        [counts[f"attn.prefill_tiles_{kind}"]
+         for kind in ("visited", "padded")]
+    assert flash_reply == reply
+    assert _off(flash_rows, rows) <= LIMIT
+
+
 def test_a_fork_under_five_groups(served):
     """Two samples of one prompt: the child shares the parent's pages
     in all five tables and copies on its first write. Each row's

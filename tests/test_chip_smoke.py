@@ -103,6 +103,8 @@ _PALLAS_LOG = """
 INFO [x] kernel path: quant_matmul = pallas (gptq gptq_matmul_a8)
 INFO [x] kernel path: kv_write = pallas (prefill whole-page writer)
 INFO [x] kernel path: kv_write = pallas (slot-window writer)
+INFO [x] kernel path: prefill_attention = pallas (prefill_flash_attention, own keys)
+INFO [x] kernel path: prefill_attention = pallas (prefill_flash_attention, gathered prefix)
 INFO [x] kernel path: decode_attention = pallas (paged_decode_attention, fused KV write)
 INFO [x] kernel path: decode_attention = pallas (paged_decode_attention, read-only)
 """
@@ -110,6 +112,7 @@ INFO [x] kernel path: decode_attention = pallas (paged_decode_attention, read-on
 _MESH_LOG = """
 INFO [x] SPMD mesh {'dp': 1, 'pp': 1, 'sp': 1, 'tp': 4} over 4 tpu devices
 INFO [x] kernel path: kv_write = reference (XLA scatter: backend=tpu, tp=4, pages=bfloat16)
+INFO [x] kernel path: prefill_attention = reference (jnp functions: backend=tpu, tp=4, K/V=bfloat16, alibi=False)
 INFO [x] kernel path: decode_attention = reference (jnp gather path: backend=tpu, tp=4, pages=bfloat16)
 INFO [x] Device memory after load: bytes_in_use=[500, 510, 505, 500]
 INFO [x] Device memory at drain: bytes_in_use=[520, 530, 525, 520]
@@ -130,6 +133,13 @@ def test_smoke_accepts_pallas_on_one_chip_and_rejects_a_reference():
     with pytest.raises(chip_smoke.SmokeFailure, match="quant_matmul"):
         chip_smoke.check_kernel_paths(
             _PALLAS_LOG.replace("quant_matmul", "other"), tp=1)
+    # a prompt step whose attention fell back to the jnp functions
+    fell_back = _PALLAS_LOG.replace(
+        "pallas (prefill_flash_attention, gathered prefix)",
+        "reference (jnp functions: backend=tpu, tp=1, K/V=int8, "
+        "alibi=False)")
+    with pytest.raises(chip_smoke.SmokeFailure, match="prefill_attention"):
+        chip_smoke.check_kernel_paths(fell_back, tp=1)
 
 
 def test_smoke_reads_the_mesh_arm():
