@@ -68,6 +68,9 @@ NAMES = (
     "runner.starved",       # of them, those whose round in flight had
                             # finished at the dispatch: nothing queued
     "runner.starved.prompt",
+    "runner.prompt_late",   # of `round.ahead.prompt`, the rounds whose
+                            # decode program had already finished when
+                            # their prompt program went out behind it
     "pull.blocked",         # the blocking pull after a round went out
                             # ahead: the device's work still to do when
                             # the host had none left (seconds, pulls)

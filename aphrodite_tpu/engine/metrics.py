@@ -106,6 +106,12 @@ _STAGE_COUNTERS = [
      "Of aphrodite:dispatches_starved_total, the rounds that carry a "
      "prompt step (of aphrodite:rounds_ahead_prompt_total).",
      lambda s, c: c["runner.starved.prompt"]),
+    ("aphrodite:dispatches_prompt_late_total",
+     "Of aphrodite:rounds_ahead_prompt_total, the rounds whose decode "
+     "program had already finished when their prompt program was "
+     "enqueued behind it: the host prepared the prompt step for "
+     "longer than the device ran.",
+     lambda s, c: c["runner.prompt_late"]),
     ("aphrodite:pull_blocked_seconds_total",
      "Seconds the step thread blocked pulling the round in flight "
      "after it had dispatched the next: the host's lead over the "

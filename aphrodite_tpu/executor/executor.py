@@ -503,7 +503,10 @@ class TPUExecutor:
         `_runs_ahead`) enqueues its decode step, then its prompt
         step, both or neither: None, nothing enqueued, when a step is
         off the fused program and the caller must run the round
-        synced."""
+        synced. The prompt step is prepared on the host only after
+        the decode step's program is on the device's queue
+        (`ModelRunner.dispatch_steps`), under the device's work and
+        not in front of it."""
         self._pre_step(rnd.prompt + rnd.decode, rnd.blocks_to_swap_in,
                        rnd.blocks_to_swap_out)
         self._copy_blocks(rnd)

@@ -1361,42 +1361,63 @@ class ModelRunner:
     ) -> Tuple[Optional[List[StepHandle]],
                List[Tuple[jax.Array, jax.Array]]]:
         """Enqueue one step for each of `batches` (each all prompt
-        chunks or all decode rows), back to back and WITHOUT syncing:
-        all of them or none. Returns (None, kv_caches untouched) when
-        a batch is off the fused program (host logits processors,
+        chunks or all decode rows), WITHOUT syncing and in the order
+        the device runs them: a batch is prepared (its own
+        `runner.prepare` span) and its program enqueued before the
+        next batch is prepared, so that the host builds a round's
+        prompt batch while the device runs its decode step. All of
+        them or none. Returns (None, kv_caches untouched) when a
+        batch is off the fused program (host logits processors,
         logprobs, best_of>1) — or, `or_raw` and one batch, runs that
         batch through the raw-logits route at once: its handle has
-        its outputs. `fed_by` (decode batches): the handles of the
-        round whose results are still on the device, its decode step
-        first; rows with a token in flight take it from there
-        (`_prepare_decode`). `decode_steps`: what a decode batch's
-        program runs if not one plain step (`_prepare_step`:
-        `num_steps` and `extra_cap` of a burst, a verify round's
-        `drafts`)."""
-        with self.tracer.span("runner.prepare"):
-            prepared = [self._prepare_step(mds, fed_by, **decode_steps)
-                        for mds in batches]
-        if not all(self._fused(plan) or
-                   (plan is not None and "burst" in inputs)
-                   for inputs, _, _, plan in prepared):
-            if not or_raw:
-                return None, kv_caches
-            (step,) = prepared
-            handle, kv_caches = self._run_raw(*step, kv_caches)
-            return [handle], kv_caches
-        # The round in flight has finished and nothing is queued behind
-        # it: the device waited for this dispatch. Said on the round's
-        # first program; the second follows a program just enqueued.
-        facts = {}
-        if fed_by:
-            starved = all(handle.is_ready() for handle in fed_by)
-            if starved:
-                self.tracer.add_split("runner.starved")
-            facts["starved"] = int(starved)
-        handles = []
-        for step in prepared:
-            handle, kv_caches = self._enqueue(
-                *step, kv_caches, **({} if handles else facts))
+        its outputs. Of one batch the prepared plan says so
+        (`_fused`); of several the rows' parameters do, before
+        anything goes out (`SamplingParams.needs_raw_logits`, the
+        plan's mirror: a plan that then disagrees raises). `fed_by`
+        (decode batches): the handles of the round whose results are
+        still on the device, its decode step first; rows with a token
+        in flight take it from there (`_prepare_decode`).
+        `decode_steps`: what a decode batch's program runs if not one
+        plain step (`_prepare_step`: `num_steps` and `extra_cap` of a
+        burst, a verify round's `drafts`)."""
+        alone = len(batches) == 1
+        if not alone and any(md.sampling_params.needs_raw_logits
+                             for mds in batches for md in mds):
+            return None, kv_caches
+        handles: List[StepHandle] = []
+        for mds in batches:
+            with self.tracer.span("runner.prepare"):
+                step = self._prepare_step(mds, fed_by, **decode_steps)
+            inputs, _, _, plan = step
+            if not (self._fused(plan) or
+                    (plan is not None and "burst" in inputs)):
+                if not alone:
+                    raise RuntimeError(
+                        "a row's needs_raw_logits let through a step "
+                        "that its plan takes off the fused program")
+                if not or_raw:
+                    return None, kv_caches
+                handle, kv_caches = self._run_raw(*step, kv_caches)
+                return [handle], kv_caches
+            facts = {}
+            if handles:
+                # The program just enqueued has already finished: this
+                # one comes too late to follow it without a gap.
+                # (Counted over the rounds `round.ahead.prompt` counts:
+                # those that went out with a round in flight.)
+                late = handles[-1].is_ready()
+                if late and fed_by:
+                    self.tracer.add("runner.prompt_late")
+                facts["late"] = int(late)
+            elif fed_by:
+                # The round in flight has finished and nothing is
+                # queued behind it: the device waited for this
+                # dispatch. Said on the round's first program.
+                starved = all(handle.is_ready() for handle in fed_by)
+                if starved:
+                    self.tracer.add_split("runner.starved")
+                facts["starved"] = int(starved)
+            handle, kv_caches = self._enqueue(*step, kv_caches, **facts)
             handles.append(handle)
         return handles, kv_caches
 

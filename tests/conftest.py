@@ -120,15 +120,21 @@ LATER_METRICS = (
     "host_lead_ms.batch", "host_lead_decode_ms.batch",
     "dispatch_starved_pct.batch", "dispatch_starved_prompt_pct.batch",
     "host_dispatch_ms.batch", "host_hops_ms.batch")
+#: and those appended after them (PR 45's), which every module that
+#: pins the manifest is spared
+NEWER_METRICS = ("prompt_dispatch_late_pct.batch",)
 #: cells appended since the pinning tests were written, oldest first
 #: (PR 41's, PR 43's), each with its configuration and the metrics it
 #: alone reports
 NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k")
-#: the modules that hold the manifest's metrics to a count
-_PINNED = ("test_perf_smallthinker", "test_perf_phi4flash")
-#: a module that holds the manifest's LAST entries to its own cell's:
-#: its `_bench()` leaves out the cells appended after that one
-_PINNED_TO_ITS_CELL = {"test_perf_jamba": NEWER_CELLS[1:]}
+#: the modules that hold the manifest to a count, a set or its last
+#: places -> (the cells, the metrics) appended after what each holds
+_PINNED = {
+    "test_perf_smallthinker": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
+    "test_perf_phi4flash": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
+    "test_perf_jamba": (NEWER_CELLS[1:], NEWER_METRICS),
+    "test_perf_laguna": ((), NEWER_METRICS),
+}
 #: the module that holds PR 38's six to the manifest's last places and
 #: to the list of cells, and reads `BENCHMARK.json` with `json.load`
 _PINNED_BY_FILE = "test_perf_host_lead"
@@ -146,23 +152,29 @@ def _perf_conftest():
     return module
 
 
-def _without_newer_cells(bench: dict, cells=NEWER_CELLS) -> dict:
-    """`bench` as it read before `cells` (`NEWER_CELLS`) were
-    appended: without the cells, a configuration no other cell runs,
-    their names on every `workloads` list, and a metric that only they
-    report (`tests/perf/conftest.py::without_cells`)."""
-    return _perf_conftest().without_cells(bench, cells=cells)
+def _without(metrics: list, names) -> list:
+    return [m for m in metrics if m["name"] not in names]
 
 
-class _JsonWithoutNewerCells:
+def _before(bench: dict, cells=NEWER_CELLS, metrics=NEWER_METRICS) -> dict:
+    """`bench` as it read before `cells` and `metrics` were appended:
+    without the cells, a configuration no other cell runs, their names
+    on every `workloads` list, a metric that only they report
+    (`tests/perf/conftest.py::without_cells`), and the metrics."""
+    bench = _perf_conftest().without_cells(bench, cells=cells)
+    bench["per_layer"] = _without(bench["per_layer"], metrics)
+    return bench
+
+
+class _JsonBefore:
     """`json`, for a module that loads `BENCHMARK.json` itself: the
-    manifest comes back without `NEWER_CELLS`, anything else as it
-    is."""
+    manifest comes back without `NEWER_CELLS` and `NEWER_METRICS`,
+    anything else as it is."""
 
     def load(self, f, **kwargs):
         data = json.load(f, **kwargs)
         if isinstance(data, dict) and {"workloads", "per_layer"} <= set(data):
-            return _without_newer_cells(data)
+            return _before(data)
         return data
 
     def __getattr__(self, name):
@@ -178,44 +190,37 @@ def _the_manifest_without_later_metrics(request, monkeypatch):
     every cell, or a cell appended to those lists, breaks; and no PR
     but a `benchmark` PR may edit them. They read the manifest through
     their module's `_bench()` and what a cell reports through
-    `cells.load_cell()`; for them both leave `LATER_METRICS` out, and
-    `_bench()` leaves `NEWER_CELLS` out, as `tests/perf/conftest.py`
-    leaves the later cells out (this fixture runs first, so that one
-    wraps this). `tests/perf/test_perf_host_lead.py` holds the six to
-    the manifest's last places and the cells to a list of three, and
-    loads the file itself: its `json` gives it the manifest without
-    `NEWER_CELLS`. `tests/perf/test_perf_jamba.py` holds the
-    manifest's last configuration, cell and two metrics to its own:
-    its `_bench()` leaves out the cells appended after it
-    (`_PINNED_TO_ITS_CELL`). The metrics and the cells have tests of
-    their own (`tests/perf/test_perf_host_lead.py`,
-    `tests/perf/test_perf_jamba.py`, `tests/perf/test_perf_laguna.py`)."""
+    `cells.load_cell()`; for them both leave `LATER_METRICS` and
+    `NEWER_METRICS` out, and `_bench()` leaves `NEWER_CELLS` out, as
+    `tests/perf/conftest.py` leaves the later cells out (this fixture
+    runs first, so that one wraps this).
+    `tests/perf/test_perf_jamba.py` and
+    `tests/perf/test_perf_laguna.py` hold the manifest's last
+    configuration, cell and two metrics to their own, and what their
+    cell reports to a set: they are spared the cells appended after
+    theirs and `NEWER_METRICS` the same way (`_PINNED`).
+    `tests/perf/test_perf_host_lead.py` holds the six to the
+    manifest's last places and the cells to a list of three, and loads
+    the file itself: its `json` gives it the manifest without
+    `NEWER_CELLS` and `NEWER_METRICS`. The metrics and the cells have
+    tests of their own (`tests/perf/test_perf_host_lead.py`,
+    `tests/perf/test_perf_jamba.py`, `tests/perf/test_perf_laguna.py`,
+    `tests/perf/test_perf_prompt_late.py`)."""
     module = request.module
     name = module.__name__.rsplit(".", 1)[-1]
     if name == _PINNED_BY_FILE:
-        monkeypatch.setattr(module, "json", _JsonWithoutNewerCells())
-        return
-    if name in _PINNED_TO_ITS_CELL:
-        later, its_bench = _PINNED_TO_ITS_CELL[name], module._bench
-        monkeypatch.setattr(
-            module, "_bench",
-            lambda: _without_newer_cells(its_bench(), cells=later))
+        monkeypatch.setattr(module, "json", _JsonBefore())
         return
     if name not in _PINNED:
         return
-
-    def earlier(metrics):
-        return [m for m in metrics if m["name"] not in LATER_METRICS]
+    later_cells, later_metrics = _PINNED[name]
     own_bench, own_load = module._bench, module.cells.load_cell
-
-    def bench():
-        manifest = _without_newer_cells(own_bench())
-        manifest["per_layer"] = earlier(manifest["per_layer"])
-        return manifest
 
     def load_cell(*args, **kwargs):
         cell = own_load(*args, **kwargs)
-        cell.per_layer = earlier(cell.per_layer)
+        cell.per_layer = _without(cell.per_layer, later_metrics)
         return cell
-    monkeypatch.setattr(module, "_bench", bench)
+    monkeypatch.setattr(
+        module, "_bench",
+        lambda: _before(own_bench(), later_cells, later_metrics))
     monkeypatch.setattr(module.cells, "load_cell", load_cell)

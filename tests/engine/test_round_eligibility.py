@@ -107,19 +107,24 @@ def _engine_of(multi_step: int):
     return engine
 
 
-def _fused(md: SequenceGroupMetadata) -> bool:
+def _fused(*mds: SequenceGroupMetadata) -> bool:
     """`ModelRunner._fused` of the plan the runner makes for a step of
-    this one group (`_prepare_step`: no plan when a row has host
-    logits processors)."""
-    params = md.sampling_params
-    seq_ids = list(md.seq_data)
+    these groups, all prompts or all decode rows (`_prepare_step`: no
+    plan when a row has host logits processors)."""
+    is_prompt = mds[0].is_prompt
+    seq_groups, seq_data = [], {}
+    for i, md in enumerate(mds):
+        # (each group's sequences under ids of their own)
+        ids = [8 * i + seq_id for seq_id in md.seq_data]
+        seq_data.update(zip(ids, md.seq_data.values()))
+        seq_groups.append((ids[:1] if is_prompt else ids,
+                           md.sampling_params))
     sampling = SamplingMetadata(
-        seq_groups=[(seq_ids[:1] if md.is_prompt else seq_ids, params)],
-        seq_data=md.seq_data,
-        prompt_lens=[20] if md.is_prompt else [])
-    if params.logits_processors:
+        seq_groups=seq_groups, seq_data=seq_data,
+        prompt_lens=[20] * len(mds) if is_prompt else [])
+    if any(md.sampling_params.logits_processors for md in mds):
         return ModelRunner._fused(None)
-    return ModelRunner._fused(Sampler(VOCAB).plan(sampling, pad_to=8))
+    return ModelRunner._fused(Sampler(VOCAB).plan(sampling, pad_to=64))
 
 
 @pytest.mark.parametrize("case,fields,n_seqs,want", TABLE,
@@ -151,3 +156,38 @@ def test_one_row_off_the_fused_program_takes_its_round_with_it():
     assert not engine._spec_eligible([plain, penalised])
     assert AphroditeEngine._prompt_fast_path_ok([plain, penalised])
     assert not AphroditeEngine._prompt_fast_path_ok([plain, logprobs])
+
+
+# What `ModelRunner.dispatch_steps` decides a list of batches by before
+# any of them is prepared: (case, SamplingParams fields)
+MIRROR = [
+    ("greedy", dict(temperature=0.0)),
+    ("seeded", dict(temperature=0.8, top_p=0.9, seed=3)),
+    ("logprobs_0", dict(logprobs=0)),
+    ("logprobs_3", dict(logprobs=3)),
+    ("prompt_logprobs", dict(prompt_logprobs=2)),
+    ("best_of_2", dict(n=1, best_of=2, temperature=0.8)),
+    ("logits_processor", dict(logits_processors=[_bias])),
+    ("beam", dict(use_beam_search=True, best_of=2, temperature=0.0)),
+]
+
+
+@pytest.mark.parametrize("case,fields", MIRROR,
+                         ids=[row[0] for row in MIRROR])
+def test_the_rows_parameters_say_what_the_prepared_plan_will(case, fields):
+    """`needs_raw_logits` of a batch's rows is `_fused` of the plan
+    prepared for them, the row alone and beside a plain one: a round
+    of two batches goes out or not by the first, one program before
+    the second batch's plan exists. Of a decode step the plan may know
+    better (no prompt log-probabilities are owed any more): the rows
+    never let through what the plan refuses."""
+    fields = dict(dict(max_tokens=64, ignore_eos=True), **fields)
+    plain = SamplingParams(temperature=0.0, max_tokens=64)
+    for is_prompt in (True, False):
+        row = _metadata(SamplingParams(**fields), 1, is_prompt)
+        for mds in ([row], [_metadata(plain, 1, is_prompt), row]):
+            raw = any(md.sampling_params.needs_raw_logits for md in mds)
+            if is_prompt:
+                assert _fused(*mds) == (not raw)
+            else:
+                assert _fused(*mds) or raw

@@ -10,7 +10,9 @@ import pytest
 
 from aphrodite_tpu.common import faultinject
 from aphrodite_tpu.common.sampling_params import SamplingParams
-from aphrodite_tpu.common.sequence import Sequence, SequenceGroup
+from aphrodite_tpu.common.sequence import (Sequence, SequenceData,
+                                           SequenceGroup,
+                                           SequenceGroupMetadata)
 from aphrodite_tpu.executor.model_runner import ModelRunner
 from aphrodite_tpu.processing.admission import RequestTimeoutError
 
@@ -351,6 +353,50 @@ def test_a_row_off_the_fused_path_that_joins_drains_the_step_in_flight(
     # its prompt step (call 5) and its three decode steps
     assert counts[5] > counts[1] and counts[-1] > counts[10]
     assert counts[6] == counts[7] == counts[8] == counts[9]
+
+
+def _row(seq_id, params, pages, is_prompt):
+    """One hand-made row for the model runner: a prompt of 20 tokens,
+    and one token on if it is a decode row."""
+    data = SequenceData(_prompt(seq_id))
+    if not is_prompt:
+        data.append_token_id(33, 0.0)
+    return SequenceGroupMetadata(
+        request_id=f"row{seq_id}", is_prompt=is_prompt,
+        seq_data={seq_id: data}, sampling_params=params,
+        block_tables={seq_id: list(pages)},
+        persistent_data={seq_id: {}})
+
+
+@pytest.mark.parametrize("what", ["logprobs", "prompt_logprobs", "best_of",
+                                  "beam", "logits_processor"])
+def test_a_prompt_row_off_the_fused_path_sends_out_nothing_of_its_round(
+        engine, what):
+    """`[decode, prompt]` goes out a batch at a time, so all or none
+    is decided before the first: a prompt row that needs the raw
+    logits leaves the decode program unsent, nothing prepared and the
+    pool untouched. The same round with a plain prompt row goes out,
+    decode step first."""
+    runner = engine.executor.model_runner
+    kv_caches = engine.executor.cache_engine.kv_caches
+    tracer = engine.tracer
+    decode = [_row(1, greedy(4), [0, 1], False)]
+    odd = SamplingParams(max_tokens=4, ignore_eos=True, **INELIGIBLE[what])
+    before = (tracer.in_flight, tracer.counts["runner.prepare"],
+              tracer.counts["runner.dispatch"])
+    handles, same = runner.dispatch_steps(
+        [decode, [_row(2, odd, [2, 3], True)]], kv_caches)
+    assert handles is None and same is kv_caches
+    assert (tracer.in_flight, tracer.counts["runner.prepare"],
+            tracer.counts["runner.dispatch"]) == before
+    handles, kv_caches = runner.dispatch_steps(
+        [decode, [_row(2, greedy(4), [2, 3], True)]], kv_caches)
+    engine.executor.cache_engine.kv_caches = kv_caches
+    assert [h.is_prompt for h in handles] == [False, True]
+    assert tracer.in_flight == before[0] + 2
+    assert tracer.counts["runner.prepare"] == before[1] + 2
+    runner.pull(handles)
+    assert tracer.in_flight == before[0]
 
 
 @pytest.mark.parametrize("point", ["engine.step", "scheduler.schedule",

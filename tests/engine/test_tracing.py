@@ -150,6 +150,7 @@ def test_spanned_wraps_the_whole_call_of_a_method():
 def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
         tiny_llm, recorder, _annotating):
     engine = _annotating(tiny_llm.engine)
+    late_before = engine.tracer.counts["runner.prompt_late"]
     engine.add_request("a", None, GREEDY, prompt_token_ids=_prompt(1))
     engine.step()                       # prompt
     engine.step()                       # decode
@@ -182,11 +183,11 @@ def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
     assert _tree(rounds[0]) == [("engine.step", None)] + step[:4] + \
         [("sched.schedule", "engine.step")] + step[4:] + \
         [("engine.process", "engine.step")]
-    # a combined round is prepared whole, decode step and prompt step,
-    # then dispatched, both steps ahead of the pull of the round before
-    assert _tree(rounds[2]) == [("engine.step", None)] + step[:3] + \
-        [step[2], step[3], step[3]] + step[4:] + \
-        [("engine.process", "engine.step")]
+    # a combined round is prepared and dispatched a step at a time, in
+    # the device's order: the decode step is out before the prompt step
+    # is prepared, both ahead of the pull of the round before
+    assert _tree(rounds[2]) == [("engine.step", None)] + step[:4] + \
+        step[1:] + [("engine.process", "engine.step")]
     # the last call: the pull, and the processing of both rounds
     assert _tree(rounds[-1]) == [("engine.step", None), step[0]] + \
         step[4:] + [("engine.process", "engine.step")] * 2
@@ -214,6 +215,17 @@ def test_prompt_decode_and_combined_rounds_nest_as_the_table_says(
     assert not any(says_starved(rounds[0]))         # a synced round
     assert all(facts["starved"] in (0, 1) for r in rounds
                for name, _, facts in r if "starved" in facts)
+    # and its second program's whether the first had already finished
+
+    def says_late(r):
+        return [facts.get("late") for name, _, facts in r
+                if name == "aph.runner.dispatch"]
+    first, second = says_late(rounds[2])
+    assert first is None and second in (0, 1)
+    assert {late for r in rounds[:2] + rounds[3:]
+            for late in says_late(r)} == {None}
+    assert engine.tracer.counts["runner.prompt_late"] - late_before == \
+        second
 
 
 @pytest.fixture(scope="module")
@@ -677,8 +689,10 @@ def test_stage_counters_are_exported_from_the_tracers_totals():
     assert _value("aphrodite:preemptions_total", labels) == 1
 
 
-#: this PR's counters, each with the accumulator it exports
+#: PR 38's counters and the late prompt dispatch's (PR 45), each with
+#: the accumulator it exports
 LEAD_COUNTERS = {
+    "aphrodite:dispatches_prompt_late_total": ("c", "runner.prompt_late"),
     "aphrodite:pull_blocked_seconds_total": ("s", "pull.blocked"),
     "aphrodite:pulls_ahead_total": ("c", "pull.blocked"),
     "aphrodite:pull_blocked_decode_seconds_total":
