@@ -196,29 +196,10 @@ _register(Flag(
     minimum=1, strict=True))
 
 _register(Flag(
-    "APHRODITE_ATTN_RAGGED", "bool", True,
-    "Ragged work-list decode-attention grid; 0 pins the classic "
-    "padded (batch, head-block) grid for A/B runs."))
-
-_register(Flag(
-    "APHRODITE_ATTN_AMLA", "bool", True,
-    "AMLA mul-by-add online-softmax rescale in the decode-attention "
-    "kernels (base-2 scores, integer running max, exponent-bias adds "
-    "on the accumulator; arxiv 2509.25224); 0 pins the classic "
-    "per-chunk rescale multiply for A/B runs."))
-
-_register(Flag(
     "APHRODITE_W4A8", "bool", False,
     "GPTQ/AWQ int8-activation MXU path (weights stay int4 at rest; "
     "per-row activation rounding is the only approximation). The "
     "GPTQ/AWQ bench default; 0 selects the bit-exact W4A16 kernels."))
-
-_register(Flag(
-    "APHRODITE_QMM_DEFERRED", "str", "",
-    "Pin the W4A8 deferred-rescale kernel variant: 1 forces deferred "
-    "(int32 group accumulators, scales applied at k-tile flush), 0 "
-    "forces classic; unset autotunes by shape (deferred at m > 64).",
-    choices=("", "0", "1")))
 
 _register(Flag(
     "APHRODITE_QMM_BLOCK_M", "int", None,
@@ -239,24 +220,11 @@ _register(Flag(
     minimum=0, strict=True))
 
 _register(Flag(
-    "APHRODITE_QMM_STREAM", "bool", True,
-    "Streamed skinny-m quant-matmul path at m <= 64: the (n, k) tile "
-    "grid flattens into one work list and weight tiles stream through "
-    "an explicit cross-cell DMA ring; 0 pins the classic "
-    "compiler-managed grid for A/B runs."))
-
-_register(Flag(
     "APHRODITE_QMM_STREAM_PF", "int", 2,
     "Ring depth (VMEM tile slots) of the streamed quant-matmul weight "
     "DMA ring; cell i starts cell i+depth-1's tile loads. Malformed "
     "or < 2 values warn and fall back to the default.",
     minimum=2))
-
-_register(Flag(
-    "APHRODITE_QMM_DEFERRED_VMEM_MB", "int", 8,
-    "VMEM budget (MiB) for the deferred-rescale accumulator planes; "
-    "shapes that exceed it silently fall back to the classic kernel.",
-    minimum=1, strict=True))
 
 _register(Flag(
     "APHRODITE_KV_SCALE", "float", None,

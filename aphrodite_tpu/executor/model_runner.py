@@ -1002,8 +1002,8 @@ class ModelRunner:
         """The ragged decode work list of one page group, (its device
         copy, pages a chunk, chunks a row): (sequence, chunk) pairs
         flattened over each row's REAL reserved pages so the attention
-        grid has no padded cells for short contexts (the classic grid
-        pads every row to the batch-max context). Chunk counts come
+        grid has no padded cells for short contexts (a dense list
+        pads every row to the table's width). Chunk counts come
         from the reserved table lengths — a safe over-approximation of
         any context the burst scan reaches (pos_cap pins rows inside
         their reservation), so the list rides the whole burst. The

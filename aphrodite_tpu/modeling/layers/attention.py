@@ -364,9 +364,7 @@ class PagedAttention:
             # Chunk geometry: when the model runner built a ragged
             # work list it also fixed pages_per_chunk (the list and the
             # kernel's chunk walk must agree); otherwise fall back to
-            # the shared policy over the padded table width. The ragged
-            # work-list grid replaces the padded (batch, n_hb) grid
-            # unless APHRODITE_ATTN_RAGGED=0 pins the classic kernel.
+            # the shared policy over the padded table width.
             from aphrodite_tpu.ops.pallas.paged_attention import (
                 choose_pages_per_chunk, lane_bytes_of)
             work = metadata.decode_work

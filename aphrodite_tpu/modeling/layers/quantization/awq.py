@@ -126,11 +126,10 @@ class AWQLinearMethod(LinearMethod):
                 # APHRODITE_W4A8: int8 activations into the MXU int8
                 # mode — same opt-in/accuracy story as the GPTQ path
                 # (AWQ is always 4-bit, so no bits gate needed). The a8
-                # kernel auto-selects classic vs deferred-rescale per
-                # shape; APHRODITE_QMM_DEFERRED pins it for A/B runs.
-                # Decode-shaped calls (m <= 64) default to the
+                # kernel selects classic vs deferred-rescale per
+                # shape. Decode-shaped calls (m <= 64) take the
                 # streamed work-list grid with its explicit weight DMA
-                # ring; APHRODITE_QMM_STREAM=0 pins the classic grid.
+                # ring.
                 mm = awq_matmul_a8 if flags.get_bool(
                     "APHRODITE_W4A8") else awq_matmul
                 note_kernel_path("quant_matmul", "pallas",

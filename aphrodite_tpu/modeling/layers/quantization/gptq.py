@@ -141,15 +141,13 @@ class GPTQLinearMethod(LinearMethod):
             # no longer bit-identical to the W4A16 path. 4-bit only:
             # 8-bit codes minus their zero point span [-256, 254] and
             # would wrap on the kernel's int8 cast. The a8 kernel
-            # auto-selects between the classic and the deferred-rescale
-            # (int32 group accumulator) variants per shape;
-            # APHRODITE_QMM_DEFERRED=1/0 pins it for A/B runs (see the
+            # selects between the classic and the deferred-rescale
+            # (int32 group accumulator) variants per shape (see the
             # quant_matmul module docstring). At m <= 64 (decode and
-            # bs=1 bursts) both kernels default to the STREAMED
+            # bs=1 bursts) both kernels take the STREAMED
             # work-list grid — the activation block stays resident in
             # VMEM and weight tiles flow through an explicit
-            # cross-cell DMA ring — with APHRODITE_QMM_STREAM=0
-            # pinning the classic compiler-managed grid.
+            # cross-cell DMA ring.
             mm = gptq_matmul_a8 if (
                 flags.get_bool("APHRODITE_W4A8") and
                 cfg.weight_bits == 4) else gptq_matmul

@@ -13,7 +13,7 @@ round-6 "ragged work-list" grid):
   instead of 4 KB — and the layout has no Mosaic tile padding for ANY
   head count (lanes = H*d >= 128 always), so it survives tp-sharding
   down to one local head.
-- RAGGED WORK-LIST GRID (the default; "Ragged Paged Attention",
+- RAGGED WORK-LIST GRID (the one grid; "Ragged Paged Attention",
   arxiv 2604.15464): the caller flattens (sequence, chunk) pairs into
   a 1-D list of REAL work items — one item per pages_per_chunk pages a
   sequence actually reserved, not per batch-max-context cell — and
@@ -32,14 +32,11 @@ round-6 "ragged work-list" grid):
   all items: cell i issues cell i+pf_depth's K+V page copies
   back-to-back before waiting its own, so page-DMA latency overlaps
   several cells' compute regardless of how many chunks any sequence
-  has — subsuming the classic kernel's separate single-chunk
-  cross-cell path and its 2-slot multi-chunk double buffer.
-  Work items of one sequence are grid-adjacent, so cross-chunk
+  has. Work items of one sequence are grid-adjacent, so cross-chunk
   online-softmax state lives in persistent VMEM scratch (reset at
   chunk 0, finalized at the sequence's last item) — no inter-cell HBM
-  combine pass. The classic padded (batch, n_hb) grid remains below
-  and is selected by APHRODITE_ATTN_RAGGED=0 or by calling without
-  work_items.
+  combine pass. A call without work_items runs the same kernel over
+  the dense list of its table width (paged_decode_attention).
 - Head blocks: hb = Hkv, the page's whole lane axis (one contiguous
   descriptor a page, a page visited once), while the read ring
   affords four slots of a 384-token item (`head_block`: up to ten bf16
@@ -59,12 +56,12 @@ round-6 "ragged work-list" grid):
   (lane-sliced per head block, pages aliased in place) — replacing the
   separate page-writer kernel pass entirely: the page was being DMA'd
   in for attention anyway, so the write costs two extra page-sized
-  DMAs instead of a whole second kernel's round trips. Under the
-  ragged grid only ONE work item per (sequence, head block) issues a
-  write (the chunk holding position ctx-1), so the writeback ring is
-  keyed by an SMEM write counter — the n-th write waits the
-  (n-_WB_SLOTS)-th — instead of by grid cell, which would leave
-  gaps whenever a cell doesn't write.
+  DMAs instead of a whole second kernel's round trips. Only ONE
+  work item per (sequence, head block) issues a write (the chunk
+  holding position ctx-1), so the writeback ring is keyed by an SMEM
+  write counter — the n-th write waits the (n-_WB_SLOTS)-th — instead
+  of by grid cell, which would leave gaps whenever a cell doesn't
+  write.
   PRECONDITIONS (the engine's decode contract): pages are
   sequence-exclusive; position ctx-1 lies within the sequence's
   RESERVED block-table entries (burst reservation guarantees this —
@@ -88,9 +85,10 @@ per-chunk correction 2^(m_prev - m_new) is an exact power of two. The
 default path applies it to the l and [rows, d] accumulator planes as
 an exponent-bias ADD (`_mul_pow2`: bitcast, integer add, bitcast) —
 the per-chunk VPU multiplies FOLD002 flagged are gone. The classic
-multiply survives only as the APHRODITE_ATTN_AMLA=0 A/B arm; the two
-are bit-identical away from underflow (the correction is an exact
-power of two either way).
+multiply survives only behind the `amla=False` keyword, as the
+reference tests/kernels/test_amla_attention.py holds the add
+bit-equal to away from underflow (the correction is an exact power of
+two either way); no served call passes it.
 """
 from __future__ import annotations
 
@@ -111,13 +109,6 @@ _NEG_INF = -2.0**30  # large-but-finite: avoids inf-inf NaNs in corrections
 #: scale), so the online-softmax weights are exp2 and the running max
 #: quantizes to an integer — the AMLA precondition (arxiv 2509.25224).
 _LOG2E = 1.4426950408889634
-
-
-def amla_enabled() -> bool:
-    """APHRODITE_ATTN_AMLA=0 pins the classic online-softmax rescale
-    multiply (the A/B fallback); default on — the rescale runs as
-    exponent-bias adds (see _mul_pow2)."""
-    return flags.get_bool("APHRODITE_ATTN_AMLA")
 
 
 def _mul_pow2(x, delta):
@@ -178,13 +169,6 @@ def _pf_depth() -> int:
     return flags.get_int("APHRODITE_ATTN_PF")
 
 
-def ragged_enabled() -> bool:
-    """APHRODITE_ATTN_RAGGED=0 pins the classic padded-grid kernel
-    (the A/B fallback); anything else (or unset) allows the ragged
-    work-list grid when the caller supplies work_items."""
-    return flags.get_bool("APHRODITE_ATTN_RAGGED")
-
-
 def _item_tokens(lane_bytes: int) -> int:
     """Tokens of the largest work item that leaves the read ring
     `_MIN_RING_SLOTS` slots of `lane_bytes` a token (K and V) inside
@@ -222,20 +206,6 @@ def lane_bytes_of(num_kv_heads: int, head_dim: int, dtype) -> int:
     return hb * head_dim * jnp.dtype(dtype).itemsize
 
 
-def clamp_pages_per_chunk(pages_per_seq: int, requested: int) -> int:
-    """Largest divisor of the table width that is <= the requested
-    chunk size. The kernel iterates whole chunks over the table, so
-    pages_per_seq % pages_per_chunk must be 0 — but forcing every
-    caller to pre-pad (the old ValueError) punished odd table widths;
-    clamping down costs only smaller chunks."""
-    if requested < 1:
-        raise ValueError(f"pages_per_chunk must be >= 1, got {requested}")
-    for c in range(min(requested, pages_per_seq), 0, -1):
-        if pages_per_seq % c == 0:
-            return c
-    return 1
-
-
 def choose_pages_per_chunk(pages_per_seq: int, page_size: int,
                            lane_bytes: int) -> int:
     """The shared work-item policy (layer + model runner must agree —
@@ -254,7 +224,7 @@ def choose_pages_per_chunk(pages_per_seq: int, page_size: int,
     depth costs nothing down to two items ahead. A table narrower
     than an item is one item; a width that is no multiple of the item
     needs no divisor, because a row's last item copies only its live
-    pages (the classic grid still clamps to a divisor)."""
+    pages."""
     return max(1, min(_item_tokens(lane_bytes) // page_size,
                       pages_per_seq))
 
@@ -299,7 +269,7 @@ def build_decode_work_list(page_counts, pages_per_chunk: int,
     page_counts: per batch row (INCLUDING padded rows), the number of
     real block-table entries the row reserved; rows with 0 pages (pad
     lanes) still get one fully-masked item so their output lane is
-    written (zeros), preserving the classic kernel's ctx==0 contract.
+    written (zeros): the ctx==0 contract.
 
     Returns (wi_seq [NW+1] int32, wi_chunk [NW] int32) numpy arrays:
     wi_seq[w] is the batch row of item w, wi_chunk[w] its chunk index.
@@ -331,324 +301,6 @@ def _quantize_row(row, dtype, kv_scale):
     # (pure jnp — legal inside the kernel body).
     from aphrodite_tpu.ops.kv_quant import quantize_kv
     return quantize_kv(row, dtype, kv_scale)
-
-
-def _decode_kernel_tm(
-    # scalar prefetch
-    block_tables_ref,   # [batch, pages_per_seq] int32 (SMEM)
-    context_lens_ref,   # [batch] int32 (SMEM)
-    # inputs (slopes_ref [n_hb, rows, 128] only with has_alibi;
-    # knew_ref/vnew_ref [1, 1, 1, hb*d] only with fused_write —
-    # knew_ref[0, 0] is the (1, hb*d) row)
-    *refs,
-    hb: int,
-    group: int,
-    head_dim: int,
-    pages_per_chunk: int,
-    page_size: int,
-    scale: float,
-    kv_scale: float,
-    pf_depth: int,
-    chunk_slots: int,
-    has_alibi: bool = False,
-    single_chunk: bool = False,
-    fused_write: bool = False,
-    amla: bool = True,
-    window: int = None,
-):
-    refs = list(refs)
-    q_ref, k_hbm, v_hbm = refs[:3]
-    refs = refs[3:]
-    slopes_ref = refs.pop(0) if has_alibi else None
-    if fused_write:
-        knew_ref, vnew_ref = refs[:2]
-        out_ref, kp_out, vp_out = refs[2:5]
-        scratch = refs[5:]
-    else:
-        knew_ref = vnew_ref = kp_out = vp_out = None
-        out_ref = refs[0]
-        scratch = refs[1:]
-    if fused_write:
-        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr,
-         kwb, vwb, wbsem) = scratch
-        # reads and writes go through the aliased OUTPUT refs so in
-        # place semantics hold
-        k_hbm, v_hbm = kp_out, vp_out
-    else:
-        k_buf, v_buf, sems, acc_scr, m_scr, l_scr = scratch
-        kwb = vwb = wbsem = None
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_hb = pl.num_programs(1)
-    d = head_dim
-    rows = group * hb
-    chunk_tokens = pages_per_chunk * page_size
-    ctx = context_lens_ref[b]
-    num_chunks = (ctx + chunk_tokens - 1) // chunk_tokens
-
-    def lanes_of(cell_j):
-        return pl.ds(cell_j * hb * d, hb * d)
-
-    def chunk_dmas(c, slot, cell_b=None, cell_j=None):
-        cell_b = b if cell_b is None else cell_b
-        cell_j = j if cell_j is None else cell_j
-        lanes = lanes_of(cell_j)
-        copies = []
-        for p in range(pages_per_chunk):  # static unroll
-            page_idx = block_tables_ref[cell_b, c * pages_per_chunk + p]
-            dst = pl.ds(p * page_size, page_size)
-            copies.append(
-                pltpu.make_async_copy(k_hbm.at[page_idx, :, lanes],
-                                      k_buf.at[slot, dst, :],
-                                      sems.at[slot, 0]))
-            copies.append(
-                pltpu.make_async_copy(v_hbm.at[page_idx, :, lanes],
-                                      v_buf.at[slot, dst, :],
-                                      sems.at[slot, 1]))
-        return copies
-
-    def start_chunk(c, slot, cell_b=None, cell_j=None):
-        for dma in chunk_dmas(c, slot, cell_b, cell_j):
-            dma.start()
-
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-
-    # Block-diagonal q packing: row r (serving q head j*hb*group + r,
-    # kv head hh = r // group of this cell's block) carries q in lanes
-    # [hh*d, (hh+1)*d) and zeros elsewhere, so the single
-    # [rows, hb*d] x [hb*d, chunk] dot yields exact per-head scores.
-    # log2(e) folds into the static scale: scores land in the BASE-2
-    # domain the AMLA rescale needs (an exact-power-of-two correction).
-    q = q_ref[0, 0].astype(jnp.float32) * \
-        (scale * kv_scale * _LOG2E)                  # [rows, d]
-    q_rep = jax.lax.concatenate([q] * hb, 1)                  # [rows, hb*d]
-    lane_head = jax.lax.broadcasted_iota(
-        jnp.int32, (rows, hb * d), 1) // d
-    row_head = jax.lax.broadcasted_iota(
-        jnp.int32, (rows, hb * d), 0) // group
-    # bf16 operand for the MXU score dot: f32 matmuls run the MXU in
-    # multi-pass mode at ~1/6 the bf16 rate, and at this kernel's tiny
-    # per-cell FLOP count the f32 dots were the binding per-cell cost
-    # (399 -> ~330 us/layer measured when both dots take bf16 inputs).
-    # Accumulation stays f32 (preferred_element_type below); only the
-    # operands round to bf16 — standard flash-attention practice.
-    q_packed = jnp.where(lane_head == row_head, q_rep,
-                         0.0).astype(jnp.bfloat16)
-
-    # Fused write bookkeeping: the current token sits at position
-    # ctx-1, inside chunk c_star at in-chunk row r_star, page slot
-    # p_star of that chunk (and global page g_star of the table).
-    if fused_write:
-        pos_new = jnp.maximum(ctx - 1, 0)
-        c_star = pos_new // chunk_tokens
-        r_star = jax.lax.rem(pos_new, chunk_tokens)
-        p_star = r_star // page_size
-        g_star = block_tables_ref[b, pos_new // page_size]
-
-        # Free this cell's writeback buffer slot: cell i-_WB_SLOTS used
-        # it (a deeper ring than double-buffering — with 2 slots every
-        # cell stalled on a DMA issued only one cell earlier, ~200 us
-        # per layer at batch 512, PROFILE r04).
-        cell = b * n_hb + j
-        s_wb = jax.lax.rem(cell, _WB_SLOTS)
-
-        @pl.when(cell >= _WB_SLOTS)
-        def _():
-            pb = (cell - _WB_SLOTS) // n_hb
-
-            @pl.when(context_lens_ref[pb] > 0)
-            def _():
-                pj = jax.lax.rem(cell - _WB_SLOTS, n_hb)
-                pgs = block_tables_ref[
-                    pb, jnp.maximum(context_lens_ref[pb] - 1, 0)
-                    // page_size]
-                pltpu.make_async_copy(
-                    kwb.at[s_wb], k_hbm.at[pgs, :, lanes_of(pj)],
-                    wbsem.at[s_wb, 0]).wait()
-                pltpu.make_async_copy(
-                    vwb.at[s_wb], v_hbm.at[pgs, :, lanes_of(pj)],
-                    wbsem.at[s_wb, 1]).wait()
-
-    if single_chunk:
-        # Every sequence fits one chunk: pipeline ACROSS grid cells —
-        # cell i starts cell i+pf_depth's loads before waiting on its
-        # own, so page-DMA latency overlaps several cells' compute
-        # (depth 1 left attention at ~450-600 GB/s of the ~820 floor;
-        # depth 6 measures ~690; the buffer ring has pf_depth+2 slots
-        # so an in-flight load never lands in a slot still being
-        # read). Scratch/semaphores
-        # persist across cells, slots by cell index mod ring size.
-        cell = b * n_hb + j
-        total_cells = pl.num_programs(0) * n_hb
-
-        @pl.when(cell == 0)
-        def _():
-            # Cells 1..pf_depth have no predecessor pf_depth back;
-            # cell 0 seeds their loads (static unroll; NOT `d` — that
-            # name is the kernel-wide head_dim alias).
-            for seed_cell in range(min(pf_depth + 1, total_cells)):
-                start_chunk(0, seed_cell % chunk_slots,
-                            cell_b=seed_cell // n_hb,
-                            cell_j=seed_cell % n_hb)
-
-        @pl.when((cell >= 1) & (cell + pf_depth < total_cells))
-        def _():
-            nc = cell + pf_depth
-            start_chunk(0, jax.lax.rem(nc, chunk_slots),
-                        cell_b=nc // n_hb,
-                        cell_j=jax.lax.rem(nc, n_hb))
-    else:
-        @pl.when(num_chunks > 0)
-        def _():
-            start_chunk(0, 0)
-
-    def body(c, _):
-        if single_chunk:
-            slot = jax.lax.rem(b * n_hb + j, chunk_slots)
-        else:
-            slot = jax.lax.rem(c, 2)
-
-            @pl.when(c + 1 < num_chunks)
-            def _():
-                start_chunk(c + 1, jax.lax.rem(c + 1, 2))
-
-        for dma in chunk_dmas(c, slot):
-            dma.wait()
-
-        if fused_write:
-            # Inject the current token's K/V into the loaded chunk and
-            # write its page back (this cell's head-lane slice only).
-            @pl.when((ctx > 0) & (c == c_star))
-            def _():
-                # Inject only into the page being written back (the
-                # token lives there by construction) — a [page_size,
-                # hb*d] where instead of a whole-chunk pass.
-                pg = pl.ds(p_star * page_size, page_size)
-                rows_p = jax.lax.broadcasted_iota(
-                    jnp.int32, (page_size, k_buf.shape[2]), 0)
-                r_in_page = jax.lax.rem(r_star, page_size)
-                kq = _quantize_row(knew_ref[0, 0], k_buf.dtype,
-                                   kv_scale)
-                vq = _quantize_row(vnew_ref[0, 0], v_buf.dtype,
-                                   kv_scale)
-                kpage = jnp.where(rows_p == r_in_page, kq,
-                                  k_buf[slot, pg, :])
-                vpage = jnp.where(rows_p == r_in_page, vq,
-                                  v_buf[slot, pg, :])
-                k_buf[slot, pg, :] = kpage
-                v_buf[slot, pg, :] = vpage
-                kwb[s_wb] = kpage
-                vwb[s_wb] = vpage
-                pltpu.make_async_copy(
-                    kwb.at[s_wb], k_hbm.at[g_star, :, lanes_of(j)],
-                    wbsem.at[s_wb, 0]).start()
-                pltpu.make_async_copy(
-                    vwb.at[s_wb], v_hbm.at[g_star, :, lanes_of(j)],
-                    wbsem.at[s_wb, 1]).start()
-
-        k = k_buf[slot]                              # [chunk, hb*d]
-        if k.dtype != jnp.bfloat16:                  # int8/fp8 KV dequant
-            k = k.astype(jnp.bfloat16)
-        s = jax.lax.dot_general(
-            q_packed, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [rows, chunk]
-        pos = c * chunk_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        if slopes_ref is not None:
-            # ALiBi bias grows with kv absolute position (reference
-            # make_alibi_bias, layers/attention.py:196); scores are
-            # base-2, so the slopes carry the log2(e) factor too.
-            s = s + (slopes_ref[0, :, :1] * _LOG2E) * \
-                pos.astype(jnp.float32)
-        live = pos < ctx
-        if window is not None:
-            # a causal window: the newest `window` keys, the row's own
-            # among them
-            live = live & (pos >= ctx - window)
-        s = jnp.where(live, s, _NEG_INF)
-
-        m_prev = m_scr[:, :1]                        # [rows, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        # The running max quantizes UP to an integer, so the chunk
-        # correction 2^(m_prev - m_new) is an exact power of two:
-        # applied as an exponent-bias ADD (amla, the default) or as
-        # the classic VPU multiply (the pinned A/B arm) — bit-equal
-        # away from underflow.
-        m_new = jnp.maximum(m_prev, jnp.ceil(m_cur))
-        delta = m_prev - m_new                       # integer, <= 0
-        p_exp = jnp.where(live, jnp.exp2(s - m_new), 0.0)
-        l_prev = l_scr[:, :1]
-        if amla:
-            l_new = _mul_pow2(l_prev, delta) + \
-                jnp.sum(p_exp, axis=1, keepdims=True)
-        else:
-            corr = jnp.exp2(delta)
-            l_new = l_prev * corr + jnp.sum(p_exp, axis=1,
-                                            keepdims=True)
-
-        v = v_buf[slot]                              # [chunk, hb*d]
-        if v.dtype != jnp.bfloat16:                  # int8/fp8 KV dequant
-            v = v.astype(jnp.bfloat16)
-        pv = jax.lax.dot_general(
-            p_exp.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [rows, hb*d]
-        # Extract each row's own head block: hb static lane slices,
-        # masked adds (no in-register reshape).
-        rh = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) // group
-        pv_sel = jnp.zeros((rows, d), jnp.float32)
-        for h in range(hb):
-            pv_sel = pv_sel + jnp.where(rh == h,
-                                        pv[:, h * d:(h + 1) * d], 0.0)
-        if amla:
-            acc_scr[...] = _mul_pow2(acc_scr[...], delta) + pv_sel
-        else:
-            acc_scr[...] = acc_scr[...] * corr + pv_sel
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    if single_chunk:
-        # Unconditional: this cell's DMAs were started by the previous
-        # cell (or above for cell 0) and MUST be waited even for
-        # ctx==0 padding rows (masking zeroes their contribution).
-        body(0, None)
-    else:
-        jax.lax.fori_loop(0, num_chunks, body, None)
-
-    if fused_write:
-        # Drain: the LAST _WB_SLOTS cells' writebacks have no successor
-        # to wait them — the final cell waits each still-in-flight slot.
-        cell = b * n_hb + j
-        total = pl.num_programs(0) * n_hb
-
-        @pl.when(cell == total - 1)
-        def _():
-            for back in range(min(_WB_SLOTS, total)):
-                prev = total - 1 - back            # static
-                pb = prev // n_hb
-                pj = prev % n_hb
-                s_prev = prev % _WB_SLOTS
-
-                @pl.when(context_lens_ref[pb] > 0)
-                def _(pb=pb, pj=pj, s_prev=s_prev):
-                    pgs = block_tables_ref[
-                        pb,
-                        jnp.maximum(context_lens_ref[pb] - 1, 0)
-                        // page_size]
-                    pltpu.make_async_copy(
-                        kwb.at[s_prev],
-                        k_hbm.at[pgs, :, lanes_of(pj)],
-                        wbsem.at[s_prev, 0]).wait()
-                    pltpu.make_async_copy(
-                        vwb.at[s_prev],
-                        v_hbm.at[pgs, :, lanes_of(pj)],
-                        wbsem.at[s_prev, 1]).wait()
-
-    l_final = l_scr[:, :1]
-    l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
-    out_ref[0, 0] = (acc_scr[...] * (kv_scale / l_safe)).astype(
-        out_ref.dtype)
 
 
 def _decode_kernel_ragged(
@@ -822,9 +474,16 @@ def _decode_kernel_ragged(
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
-        # Block-diagonal q packing (see _decode_kernel_tm); log2(e)
-        # folds into the static scale — base-2 scores for the AMLA
-        # rescale.
+        # Block-diagonal q packing: row r (serving q head
+        # j*hb*group + r, kv head hh = r // group of this cell's
+        # block) carries q in lanes [hh*d, (hh+1)*d) and zeros
+        # elsewhere, so the single [rows, hb*d] x [hb*d, chunk] dot
+        # yields exact per-head scores. log2(e) folds into the static
+        # scale: scores land in the BASE-2 domain the AMLA rescale
+        # needs. The operand rounds to bf16 (accumulation stays f32):
+        # f32 matmuls run the MXU in multi-pass mode at ~1/6 the bf16
+        # rate, and at a cell's tiny FLOP count the f32 dots were its
+        # binding cost.
         q = q_ref[0, 0].astype(jnp.float32) * \
             (scale * kv_scale * _LOG2E)                  # [rows, d]
         q_rep = jax.lax.concatenate([q] * hb, 1)         # [rows, hb*d]
@@ -922,9 +581,11 @@ def _decode_kernel_ragged(
 
         m_prev = m_scr[:, :1]                        # [rows, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
-        # Integer-quantized running max -> exact-power-of-two chunk
-        # correction: exponent-bias ADD (amla) or classic multiply
-        # (the pinned A/B arm). See _decode_kernel_tm.
+        # The running max quantizes UP to an integer, so the chunk
+        # correction 2^(m_prev - m_new) is an exact power of two:
+        # applied as an exponent-bias ADD (amla) or as the classic
+        # VPU multiply (the tests' reference) — bit-equal away from
+        # underflow.
         m_new = jnp.maximum(m_prev, jnp.ceil(m_cur))
         delta = m_prev - m_new                       # integer, <= 0
         p_exp = jnp.where(live, jnp.exp2(s - m_new), 0.0)
@@ -1018,70 +679,40 @@ def _paged_decode_impl(
     rows = group * hb
     chunk_tokens = pages_per_chunk * page_size
     fused_write = knew is not None
-    ragged = wi_seq is not None
     lane_bytes = hb * head_dim * k_pages.dtype.itemsize
-    single_chunk = pages_per_seq == pages_per_chunk
 
     # q rows are kv-head-major, so the rows for head block j are the
     # contiguous slice [j*rows, (j+1)*rows).
     q_blocked = q.reshape(batch, n_hb, rows, head_dim)
 
-    if ragged:
-        # The dummy row (index batch): dead padding items and the last
-        # cell's out block land here; ctx 0 / page 0 keep its DMAs and
-        # masking inert, and the row is sliced off below.
-        q_blocked = jnp.concatenate(
-            [q_blocked, jnp.zeros((1,) + q_blocked.shape[1:],
-                                  q_blocked.dtype)])
-        block_tables = jnp.concatenate(
-            [block_tables, jnp.zeros((1, pages_per_seq), jnp.int32)])
-        context_lens = jnp.concatenate(
-            [context_lens, jnp.zeros((1,), jnp.int32)])
-        nw = wi_chunk.shape[0]
-        n_slots = _ring_slots(pf_depth, chunk_tokens, lane_bytes)
-        kernel = functools.partial(
-            _decode_kernel_ragged,
-            hb=hb, group=group, head_dim=head_dim,
-            pages_per_chunk=pages_per_chunk, page_size=page_size,
-            scale=scale, kv_scale=kv_scale,
-            pf_depth=min(pf_depth, n_slots - 2), chunk_slots=n_slots,
-            whole_lanes=n_hb == 1,
-            has_alibi=alibi_slopes is not None, fused_write=fused_write,
-            amla=amla, ablate=ablate, window=window)
-        grid = (n_hb, nw)
+    # The dummy row (index batch): dead padding items and the last
+    # cell's out block land here; ctx 0 / page 0 keep its DMAs and
+    # masking inert, and the row is sliced off below.
+    q_blocked = jnp.concatenate(
+        [q_blocked, jnp.zeros((1,) + q_blocked.shape[1:],
+                              q_blocked.dtype)])
+    block_tables = jnp.concatenate(
+        [block_tables, jnp.zeros((1, pages_per_seq), jnp.int32)])
+    context_lens = jnp.concatenate(
+        [context_lens, jnp.zeros((1,), jnp.int32)])
+    nw = wi_chunk.shape[0]
+    n_slots = _ring_slots(pf_depth, chunk_tokens, lane_bytes)
+    kernel = functools.partial(
+        _decode_kernel_ragged,
+        hb=hb, group=group, head_dim=head_dim,
+        pages_per_chunk=pages_per_chunk, page_size=page_size,
+        scale=scale, kv_scale=kv_scale,
+        pf_depth=min(pf_depth, n_slots - 2), chunk_slots=n_slots,
+        whole_lanes=n_hb == 1,
+        has_alibi=alibi_slopes is not None, fused_write=fused_write,
+        amla=amla, ablate=ablate, window=window)
 
-        def qmap(j, w, tbl, cl, ws, wc):
-            return (ws[w], j, 0, 0)
+    def qmap(j, w, tbl, cl, ws, wc):
+        return (ws[w], j, 0, 0)
 
-        def smap(j, w, *_):
-            return (j, 0, 0)
-        num_prefetch = 4
-        prefetch = [block_tables, context_lens, wi_seq, wi_chunk]
-        out_rows = batch + 1
-    else:
-        n_slots = _ring_slots(pf_depth, chunk_tokens, lane_bytes) \
-            if single_chunk else 2
-        kernel = functools.partial(
-            _decode_kernel_tm,
-            hb=hb, group=group, head_dim=head_dim,
-            pages_per_chunk=pages_per_chunk, page_size=page_size,
-            scale=scale, kv_scale=kv_scale,
-            pf_depth=min(pf_depth, n_slots - 2) if single_chunk
-            else pf_depth,
-            chunk_slots=n_slots,
-            has_alibi=alibi_slopes is not None,
-            single_chunk=single_chunk, fused_write=fused_write,
-            amla=amla, window=window)
-        grid = (batch, n_hb)
-
-        def qmap(b, j, *_):
-            return (b, j, 0, 0)
-
-        def smap(b, j, *_):
-            return (j, 0, 0)
-        num_prefetch = 2
-        prefetch = [block_tables, context_lens]
-        out_rows = batch
+    def smap(j, w, *_):
+        return (j, 0, 0)
+    prefetch = [block_tables, context_lens, wi_seq, wi_chunk]
 
     in_specs = [
         pl.BlockSpec((1, 1, rows, head_dim), qmap),
@@ -1101,11 +732,10 @@ def _paged_decode_impl(
         # [batch, n_hb>1, hb*d] is not a legal Mosaic tiling.
         kn = knew.reshape(batch, n_hb, 1, hb * head_dim)
         vn = vnew.reshape(batch, n_hb, 1, hb * head_dim)
-        if ragged:
-            kn = jnp.concatenate(
-                [kn, jnp.zeros((1,) + kn.shape[1:], kn.dtype)])
-            vn = jnp.concatenate(
-                [vn, jnp.zeros((1,) + vn.shape[1:], vn.dtype)])
+        kn = jnp.concatenate(
+            [kn, jnp.zeros((1,) + kn.shape[1:], kn.dtype)])
+        vn = jnp.concatenate(
+            [vn, jnp.zeros((1,) + vn.shape[1:], vn.dtype)])
 
         def nmap(*a):
             return qmap(*a)[:2] + (0, 0)
@@ -1122,11 +752,10 @@ def _paged_decode_impl(
         pltpu.VMEM((rows, head_dim), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
-    ]
-    if ragged:
         # a row's packed query, built at its first item
-        scratch.append(pltpu.VMEM((rows, hb * head_dim), jnp.bfloat16))
-    out_shape = [jax.ShapeDtypeStruct((out_rows, n_hb, rows, head_dim),
+        pltpu.VMEM((rows, hb * head_dim), jnp.bfloat16),
+    ]
+    out_shape = [jax.ShapeDtypeStruct((batch + 1, n_hb, rows, head_dim),
                                       q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, rows, head_dim), qmap)]
     io_aliases = {}
@@ -1137,11 +766,10 @@ def _paged_decode_impl(
             pltpu.VMEM((_WB_SLOTS, page_size, hb * head_dim),
                        v_pages.dtype),
             pltpu.SemaphoreType.DMA((_WB_SLOTS, 2)),
-        ])
-        if ragged:
             # SMEM write-counter + per-slot (page, head block) of the
             # outstanding writeback (see _decode_kernel_ragged).
-            scratch.append(pltpu.SMEM((1 + 2 * _WB_SLOTS,), jnp.int32))
+            pltpu.SMEM((1 + 2 * _WB_SLOTS,), jnp.int32),
+        ])
         out_shape.extend([
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
@@ -1149,13 +777,12 @@ def _paged_decode_impl(
         out_specs.extend([pl.BlockSpec(memory_space=pl.ANY),
                           pl.BlockSpec(memory_space=pl.ANY)])
         # Flattened input indices of k_pages/v_pages alias kernel
-        # outputs 1/2 (indices shift by the two extra work-list scalar
-        # inputs under the ragged grid).
+        # outputs 1/2 (after the four scalar-prefetch inputs and q).
         io_aliases = {kp_input_idx: 1, kp_input_idx + 1: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
-        grid=grid,
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_hb, nw),
         in_specs=in_specs,
         out_specs=out_specs if fused_write else out_specs[0],
         scratch_shapes=scratch,
@@ -1188,7 +815,7 @@ def paged_decode_attention(
     kv_scale: float = 1.0,
     pages_per_chunk: int = 8,
     work_items=None,          # (wi_seq [NW+1], wi_chunk [NW]) int32
-    amla=None,                # pin the rescale variant (A/B hook)
+    amla: bool = True,        # False: the tests' reference rescale
     ablate: str = None,       # benchmarks/attn_ab.py: time a part alone
     hb: int = None,           # benchmarks/attn_ab.py: pin the head block
     interpret: bool = False,
@@ -1201,29 +828,29 @@ def paged_decode_attention(
     (position ctx-1 per sequence) into its page in place and returns
     (attn_out, k_pages, v_pages) — the aliased, updated page arrays.
 
-    work_items selects the ragged work-list grid (unless pinned off by
-    APHRODITE_ATTN_RAGGED=0): arrays from build_decode_work_list,
-    which MUST have been built with this call's pages_per_chunk
-    (choose_pages_per_chunk is the policy both sides share). The table
-    width need be no multiple of it: an item copies only its pages
-    below the context length, and those lie inside the table. Without
-    work_items the classic padded (batch, n_hb) grid runs, which walks
-    whole chunks over the table: there pages_per_chunk is clamped DOWN
-    to the largest divisor of the table width, so callers need not
-    pre-pad block tables to a chunk multiple.
+    work_items is the grid's work list: arrays from
+    build_decode_work_list, which MUST have been built with this
+    call's pages_per_chunk (choose_pages_per_chunk is the policy both
+    sides share). The table width need be no multiple of it: an item
+    copies only its pages below the context length, and those lie
+    inside the table. Without work_items the call builds the dense
+    list of its static shapes, every row the items of its whole table:
+    the same over-approximation as a list of reserved pages, since an
+    item past a row's context copies and computes nothing.
 
-    `ablate` is the measurement hook of benchmarks/attn_ab.py (ragged
-    grid only; the output is then meaningless): "compute" skips a live
-    item's arithmetic and leaves its copies and waits, "copies" skips
-    the page copies and computes on whatever the ring holds.
+    `ablate` is the measurement hook of benchmarks/attn_ab.py (the
+    output is then meaningless): "compute" skips a live item's
+    arithmetic and leaves its copies and waits, "copies" skips the
+    page copies and computes on whatever the ring holds.
 
     `hb` is that harness's too: it pins the KV heads a grid cell holds
     (a divisor of the head count; `pages_per_chunk` and the work list
     are then the caller's to size for it) where `head_block` decides.
 
-    `amla` pins the online-softmax rescale variant: True = AMLA
-    exponent-bias adds, False = the classic per-chunk multiply (A/B);
-    None reads APHRODITE_ATTN_AMLA (default on).
+    `amla` is the online-softmax rescale: True = AMLA exponent-bias
+    adds, what every served call runs; False = the classic per-chunk
+    multiply, kept as the reference that
+    tests/kernels/test_amla_attention.py holds the adds bit-equal to.
 
     `window`: a row attends over positions `ctx - window` to `ctx - 1`
     of its table only (a causal window of `window` keys, its own
@@ -1241,25 +868,22 @@ def paged_decode_attention(
         raise ValueError(f"{hb=} does not divide {num_kv_heads=}")
     pages_per_seq = block_tables.shape[1]
     pf_depth = _pf_depth()      # call-time env read + validation
-    use_ragged = work_items is not None and ragged_enabled()
-    ppc = pages_per_chunk if use_ragged else \
-        clamp_pages_per_chunk(pages_per_seq, pages_per_chunk)
-    if ppc < 1:
-        raise ValueError(f"pages_per_chunk must be >= 1, got {ppc}")
-    if use_ragged:
-        wi_seq, wi_chunk = work_items
-        wi_seq = jnp.asarray(wi_seq, jnp.int32)
-        wi_chunk = jnp.asarray(wi_chunk, jnp.int32)
-        if wi_seq.shape[0] != wi_chunk.shape[0] + 1:
-            raise ValueError(
-                f"wi_seq must carry one trailing sentinel: "
-                f"{wi_seq.shape[0]=} != {wi_chunk.shape[0]=} + 1")
-    else:
-        wi_seq = wi_chunk = None
-    use_amla = amla_enabled() if amla is None else bool(amla)
+    if pages_per_chunk < 1:
+        raise ValueError(
+            f"pages_per_chunk must be >= 1, got {pages_per_chunk}")
+    if work_items is None:
+        work_items = build_decode_work_list(
+            [pages_per_seq] * batch, pages_per_chunk)
+    wi_seq, wi_chunk = work_items
+    wi_seq = jnp.asarray(wi_seq, jnp.int32)
+    wi_chunk = jnp.asarray(wi_chunk, jnp.int32)
+    if wi_seq.shape[0] != wi_chunk.shape[0] + 1:
+        raise ValueError(
+            f"wi_seq must carry one trailing sentinel: "
+            f"{wi_seq.shape[0]=} != {wi_chunk.shape[0]=} + 1")
     return _paged_decode_impl(
         q, k_pages, v_pages, block_tables, context_lens, wi_seq,
         wi_chunk, alibi_slopes, knew, vnew, scale=scale,
-        kv_scale=kv_scale, pages_per_chunk=ppc, pf_depth=pf_depth,
-        amla=use_amla, interpret=interpret,
-        ablate=ablate if use_ragged else None, window=window, hb=hb)
+        kv_scale=kv_scale, pages_per_chunk=pages_per_chunk,
+        pf_depth=pf_depth, amla=bool(amla), interpret=interpret,
+        ablate=ablate, window=window, hb=hb)

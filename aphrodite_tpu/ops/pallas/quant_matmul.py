@@ -37,13 +37,12 @@ interleave each group's depth-`gs` int8 MXU dot with a
 ~45% of its int8 microbench peak. The `*_a8` wrappers therefore carry
 a second kernel variant that lands every group's int32 dot in its OWN
 VMEM accumulator plane and applies all the scale rows ONCE, batched,
-at k-tile flush. A/B flag: `APHRODITE_QMM_DEFERRED=1/0` forces the
-deferred/classic path; unset, the default is autotune-by-shape
-(deferred for m > 64, classic for small-m decode where 2048-deep
-k-tiles matter more), with an automatic fallback to the classic path
-when the extra int32 planes don't fit the VMEM budget
-(`APHRODITE_QMM_DEFERRED_VMEM_MB`, default 8). The profile harness's
-`--only ab` mode measures both variants at the bench geometries.
+at k-tile flush. The choice is `m`'s (`_resolve_deferred`: deferred
+for m > 64, classic for small-m decode where 2048-deep k-tiles matter
+more), with an automatic fallback to the classic path when the extra
+int32 planes don't fit the VMEM budget (`_DEFERRED_VMEM_BYTES`). The
+`deferred=` keyword holds one variant against the other: the tests',
+and the profile harness's `--only ab` mode at the bench geometries.
 
 Streamed skinny-m grid: at m <= 64 the classic (m, n, k) grid is
 WEIGHT-STREAMING bound — every grid cell re-pays a fixed
@@ -61,8 +60,9 @@ DMA overlaps the current tile's dequant+dot across ALL cells (the
 PR-2 ragged-attention prefetch-ring design applied to the weight
 stream). Ring slots replace the per-cell double-buffered BlockSpec
 blocks in the VMEM budget, so deeper k-tiles fit (up to 4096 vs the
-classic 2048). Default at m <= 64; `APHRODITE_QMM_STREAM=0` pins the
-classic grid for A/B runs. Composes with deferred rescale: the int32
+classic 2048). Taken at m <= 64 (`_resolve_stream`; the `stream=`
+keyword is the tests' and the profile harness's way to hold one grid
+against the other). Composes with deferred rescale: the int32
 group accumulators ride as kernel scratch and the scale rows still
 apply once at k-flush.
 
@@ -188,17 +188,14 @@ _DEFERRED_K_CAP = 512
 
 
 def _resolve_deferred(deferred, m: int) -> bool:
-    """A/B selector for the deferred-rescale W4A8 kernels. An explicit
-    `deferred` (the profile harness's A/B hook) wins; then the
-    APHRODITE_QMM_DEFERRED env flag; the default is autotune-by-shape:
-    deferred at batch/prefill geometries (m > 64) where the per-group
-    scale FMAs gate the MXU, classic at small-m decode where the
-    2048-deep k-tiles' grid-cell savings dominate."""
+    """Selector for the deferred-rescale W4A8 kernels. An explicit
+    `deferred` (the tests' and the profile harness's way to hold one
+    kernel against the other) wins; otherwise `m` decides: deferred at
+    batch/prefill geometries (m > 64) where the per-group scale FMAs
+    gate the MXU, classic at small-m decode where the 2048-deep
+    k-tiles' grid-cell savings dominate."""
     if deferred is not None:
         return bool(deferred)
-    env = flags.get_str("APHRODITE_QMM_DEFERRED")
-    if env in ("0", "1"):
-        return env == "1"
     return m > 64
 
 
@@ -207,8 +204,7 @@ def _deferred_fits(block_m: int, block_n: int, gpt: int) -> bool:
     the f32 plane) fit the scoped-VMEM budget next to the streamed
     x/weight/zero/scale blocks; outside it the wrappers silently fall
     back to the classic kernel."""
-    budget_mb = flags.get_int("APHRODITE_QMM_DEFERRED_VMEM_MB")
-    return (gpt * 4 + 4) * block_m * block_n <= budget_mb << 20
+    return (gpt * 4 + 4) * block_m * block_n <= _DEFERRED_VMEM_BYTES
 
 
 # ------------------------------------------ streamed skinny-m path --
@@ -225,17 +221,19 @@ _STREAM_DEF_K_CAP = 1024     # deferred: int32 planes bound the k depth
 # block_k=4096 sweep point once failed to COMPILE instead of clamping).
 _QMM_VMEM_BYTES = 16 << 20
 
+# What the deferred-rescale accumulator planes may take of it
+# (_deferred_fits).
+_DEFERRED_VMEM_BYTES = 8 << 20
+
 
 def _resolve_stream(stream, m: int) -> bool:
-    """A/B selector for the streamed skinny-m grid: an explicit
-    `stream` (profile harness / tests) wins; otherwise the path is the
-    default at m <= 64 (decode and bs=1 bursts) unless pinned off by
-    APHRODITE_QMM_STREAM=0."""
+    """Selector for the streamed skinny-m grid: an explicit `stream`
+    (profile harness / tests) wins; otherwise `m` decides: the
+    streamed grid at m <= 64 (decode and bs=1 bursts), the compiler's
+    above."""
     if stream is not None:
         return bool(stream)
-    if m > _STREAM_M_MAX:
-        return False
-    return flags.get_bool("APHRODITE_QMM_STREAM")
+    return m <= _STREAM_M_MAX
 
 
 def _stream_pf() -> int:
@@ -663,8 +661,7 @@ def gptq_matmul(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
     tiled at <=512 rows; N tiled at 512 lanes (or N if smaller).
 
     `stream` pins the skinny-m work-list/DMA-ring grid (None =
-    default at m <= 64 unless APHRODITE_QMM_STREAM=0 — see
-    _resolve_stream)."""
+    taken at m <= 64 — see _resolve_stream)."""
     m, K = x.shape
     N = qweight.shape[1]
     gs = group_size if group_size != -1 else K
@@ -1370,11 +1367,10 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
     opt-in via APHRODITE_W4A8 (see GPTQLinearMethod.apply).
 
     `deferred` selects the int32-group-accumulator rescale-at-flush
-    kernel (None = APHRODITE_QMM_DEFERRED env, else autotune by shape
-    — see `_resolve_deferred`); both variants compute the same
-    integer dots and differ only in f32 summation order. `stream`
-    pins the skinny-m work-list/DMA-ring grid (None = default at
-    m <= 64 unless APHRODITE_QMM_STREAM=0); the two knobs compose —
+    kernel (None = by `m`, see `_resolve_deferred`); both variants
+    compute the same integer dots and differ only in f32 summation
+    order. `stream` pins the skinny-m work-list/DMA-ring grid (None =
+    taken at m <= 64); the two knobs compose —
     a streamed deferred call keeps its int32 planes in ring scratch."""
     m, K = x.shape
     N = qweight.shape[1]

@@ -1,6 +1,6 @@
 """Decode-attention kernel microbenchmark (reference
-`tests/benchmarks/attention.py:93`): Pallas kernels (classic padded
-grid AND the ragged work-list grid) vs the XLA gather path across
+`tests/benchmarks/attention.py:93`): the Pallas kernel (the ragged
+work-list grid) vs the XLA gather path across
 batch/context shapes, timed inside one jitted lax.scan so per-dispatch
 latency doesn't pollute the numbers.
 
@@ -116,8 +116,6 @@ def main() -> None:
             c, kp, vp, bt, cl, scale),
     }
     if jax.default_backend() == "tpu" and d % 128 == 0:
-        variants["pallas_classic"] = lambda c: paged_decode_attention(
-            c, kp, vp, bt, cl, scale=scale, pages_per_chunk=ppc)
         variants["pallas_ragged"] = lambda c: paged_decode_attention(
             c, kp, vp, bt, cl, scale=scale, pages_per_chunk=ppc,
             work_items=work)

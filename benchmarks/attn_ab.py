@@ -1,8 +1,8 @@
 """A/B harness for the decode-attention kernel alone, at a served
 geometry.
 
-Times `paged_decode_attention` (ragged or classic grid, with or without
-the fused KV write). The geometry is arguments; the defaults are
+Times `paged_decode_attention` (the rows' own work list or the call's
+dense one, with or without the fused KV write). The geometry is arguments; the defaults are
 Mistral-7B's heads at bench.py's old shape (batch 512, one context of
 128, pages of 32). A benchmark cell's decode call (PERF.md §5):
 
@@ -75,13 +75,14 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--fused", action="store_true")
     ap.add_argument("--ragged", action="store_true",
-                    help="use the ragged work-list grid (also "
-                         "gated by APHRODITE_ATTN_RAGGED)")
+                    help="hand the kernel the rows' own work list "
+                         "(without it the call builds the dense list "
+                         "of the table width)")
     ap.add_argument("--runner-pad", action="store_true",
                     help="pad the work list as the model runner does")
     ap.add_argument("--arms", action="store_true",
                     help="also time a call without its arithmetic and "
-                         "without its page copies (ragged grid)")
+                         "without its page copies")
     ap.add_argument("--check", action="store_true",
                     help="compare the output with the jnp reference")
     ap.add_argument("--interpret", action="store_true",
@@ -113,10 +114,8 @@ def main() -> None:
         raise SystemExit(f"--table {width} is narrower than a row's "
                          f"{int(counts.max())} pages")
     hb = args.hb or pa.head_block(KV_HEADS, HEAD_DIM, jnp.bfloat16)
-    ppc = args.ppc or (pa.choose_pages_per_chunk(width, PAGE,
-                                                 hb * HEAD_DIM * 2)
-                       if args.ragged else
-                       next(d for d in (8, 4, 2, 1) if width % d == 0))
+    ppc = args.ppc or pa.choose_pages_per_chunk(width, PAGE,
+                                                hb * HEAD_DIM * 2)
     work = None
     if args.ragged:
         items = int((-(-counts // ppc)).sum())
@@ -145,7 +144,7 @@ def main() -> None:
                            (B, KV_HEADS, HEAD_DIM), dtype=jnp.bfloat16)
     live_bytes = 2 * int(counts.sum()) * PAGE * KV_HEADS * HEAD_DIM * 2
     tag = "fused" if args.fused else "read-only"
-    tag += "/ragged" if args.ragged else "/classic"
+    tag += "/ragged" if args.ragged else "/dense list"
     nw = "" if work is None else f" items={work[1].shape[0]}" \
         f"({int((work[1] >= 0).sum())} live)"
     print(f"decode_attn[{tag}] b={B} heads={HEADS}/{KV_HEADS}x{HEAD_DIM} "

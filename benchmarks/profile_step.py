@@ -562,15 +562,14 @@ def main() -> None:
         row(f"decode_attn ragged b={B} ctx={ctx}", s * 1e3, LAYERS,
             f"{kv_bytes / s / 1e9:.0f} GB/s KV")
 
-        # Classic-vs-ragged grid A/B plus the round-7 AMLA-vs-classic
-        # RESCALE A/B at the bench page-32 geometry (mirrors the W4A8
-        # `--only ab` table): ctx 128 is the bench point
-        # (single-chunk), 512 and 2000 are the multi-chunk serving
-        # shapes the ragged grid targets. Batch shrinks with ctx so
+        # The round-7 AMLA-vs-classic RESCALE A/B at the bench
+        # page-32 geometry (mirrors the W4A8 `--only ab` table): ctx
+        # 128 is the bench point (one item a row), 512 and 2000 are
+        # the multi-item serving shapes. Batch shrinks with ctx so
         # the KV pool stays within HBM. The amla column pins the
         # exponent-bias-add rescale against the classic multiply on
-        # the same ragged grid (APHRODITE_ATTN_AMLA's two settings),
-        # with effective KV GB/s against the 820 GB/s floor.
+        # the same grid (the kernel's `amla` keyword), with effective
+        # KV GB/s against the 820 GB/s floor.
         ab_rows = []
         PAGE32 = 32
         for ab_ctx, ab_b in ((128, 512), (512, 256), (2000, 64)):
@@ -594,7 +593,6 @@ def main() -> None:
             ab_kv = 2 * ab_b * KV_HEADS * ab_ctx * HEAD_DIM * 2
             us = {}
             for label, wk, use_amla in (
-                    ("classic", None, True),
                     ("ragged", ab_work, True),
                     ("ragged-mulrescale", ab_work, False)):
                 def abstep(c, i, kpp=kp32, vpp=vp32, tb=tb32,
@@ -616,12 +614,12 @@ def main() -> None:
               f"amla = exponent-bias-add rescale vs the classic "
               f"multiply on the SAME ragged grid; KV GB/s vs the "
               f"{HBM_GBPS:.0f} GB/s floor) ===")
-        print(f"{'batch':>6s} {'ctx':>6s} {'classic':>10s} "
+        print(f"{'batch':>6s} {'ctx':>6s} "
               f"{'ragged':>10s} {'mul-resc':>10s} {'amla-x':>7s} "
               f"{'KV-GB/s':>8s} {'of-floor':>9s}")
         for ab_b, ab_ctx, ab_kv, us in ab_rows:
             gbs = ab_kv / (us["ragged"] * 1e-6) / 1e9
-            print(f"{ab_b:6d} {ab_ctx:6d} {us['classic']:10.1f} "
+            print(f"{ab_b:6d} {ab_ctx:6d} "
                   f"{us['ragged']:10.1f} "
                   f"{us['ragged-mulrescale']:10.1f} "
                   f"{us['ragged-mulrescale'] / us['ragged']:6.2f}x "

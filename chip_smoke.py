@@ -330,7 +330,7 @@ def check_kernel_paths(log: str, tp: int) -> None:
     """Print the side each kernel dispatcher took. On one chip every
     family must have run its compiled Pallas kernel and none the jnp
     reference; on a mesh every Pallas gate selects the reference by
-    design (ROADMAP S7/D4), and the report says so."""
+    design (ROADMAP S6/D5), and the report says so."""
     seen = {}
     for family, side, detail in re.findall(
             r"kernel path: (\S+) = (pallas|reference) \((.*)\)", log):

@@ -1,6 +1,8 @@
 """Behavioral tests for the central flag registry
 (aphrodite_tpu/common/flags.py): typed accessors, per-call reads,
 strict-raise vs warn-and-default, and the generated docs table."""
+import os
+import re
 import warnings
 
 import pytest
@@ -32,21 +34,21 @@ def test_strict_float_raises(monkeypatch):
 def test_bool_warns_and_defaults(monkeypatch):
     """Booleans never kill a serving step: bad values warn and fall
     back to the registered default."""
-    monkeypatch.setenv("APHRODITE_ATTN_RAGGED", "ture")
-    with pytest.warns(RuntimeWarning, match="APHRODITE_ATTN_RAGGED"):
-        assert flags.get_bool("APHRODITE_ATTN_RAGGED") is True
-    monkeypatch.setenv("APHRODITE_ATTN_RAGGED", "0")
-    assert flags.get_bool("APHRODITE_ATTN_RAGGED") is False
-    monkeypatch.setenv("APHRODITE_ATTN_RAGGED", "true")
-    assert flags.get_bool("APHRODITE_ATTN_RAGGED") is True
+    monkeypatch.setenv("APHRODITE_SPEC", "ture")
+    with pytest.warns(RuntimeWarning, match="APHRODITE_SPEC"):
+        assert flags.get_bool("APHRODITE_SPEC") is True
+    monkeypatch.setenv("APHRODITE_SPEC", "0")
+    assert flags.get_bool("APHRODITE_SPEC") is False
+    monkeypatch.setenv("APHRODITE_SPEC", "true")
+    assert flags.get_bool("APHRODITE_SPEC") is True
 
 
 def test_choices_warn_and_default(monkeypatch):
-    monkeypatch.setenv("APHRODITE_QMM_DEFERRED", "2")
-    with pytest.warns(RuntimeWarning, match="APHRODITE_QMM_DEFERRED"):
-        assert flags.get_str("APHRODITE_QMM_DEFERRED") == ""
-    monkeypatch.setenv("APHRODITE_QMM_DEFERRED", "1")
-    assert flags.get_str("APHRODITE_QMM_DEFERRED") == "1"
+    monkeypatch.setenv("APHRODITE_TPU_LOG_LEVEL", "LOUD")
+    with pytest.warns(RuntimeWarning, match="APHRODITE_TPU_LOG_LEVEL"):
+        assert flags.get_str("APHRODITE_TPU_LOG_LEVEL") == "INFO"
+    monkeypatch.setenv("APHRODITE_TPU_LOG_LEVEL", "ERROR")
+    assert flags.get_str("APHRODITE_TPU_LOG_LEVEL") == "ERROR"
 
 
 def test_uppercase_normalization(monkeypatch):
@@ -98,6 +100,44 @@ def test_markdown_table_covers_registry():
     for name, flag in flags.registry().items():
         assert name in md
         assert flag.description.strip(), f"{name} undocumented"
+
+
+#: The five flags that picked a kernel path until PR 46: the decode
+#: kernel's padded grid and rescale multiply, the W4A8 matmul's grid,
+#: rescale and the rescale's VMEM budget. The choice is the code's.
+RETIRED_KERNEL_FLAGS = (
+    "APHRODITE_ATTN_RAGGED", "APHRODITE_ATTN_AMLA",
+    "APHRODITE_QMM_STREAM", "APHRODITE_QMM_DEFERRED",
+    "APHRODITE_QMM_DEFERRED_VMEM_MB")
+
+
+def test_no_flag_picks_a_kernel_path():
+    names = set(flags.registry())
+    assert len(names) == 41
+    assert not names & set(RETIRED_KERNEL_FLAGS)
+
+
+def test_no_file_spells_a_retired_kernel_flag():
+    """The program, its harnesses, the chip smoke and the README name
+    none of them (`APHRODITE_QMM_STREAM_PF` stays a flag)."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    spelled = re.compile(
+        r"\b(" + "|".join(RETIRED_KERNEL_FLAGS) + r")\b")
+    paths = [os.path.join(root, "README.md"),
+             os.path.join(root, "tests", "kernels", "tpu_smoke.py")]
+    for top in ("aphrodite_tpu", "benchmarks"):
+        for dirpath, _, files in os.walk(os.path.join(root, top)):
+            paths.extend(os.path.join(dirpath, f) for f in files
+                         if f.endswith((".py", ".md")))
+    offenders = []
+    for path in paths:
+        with open(path, encoding="utf-8") as f:
+            for lineno, line in enumerate(f, 1):
+                if spelled.search(line):
+                    offenders.append(
+                        f"{os.path.relpath(path, root)}:{lineno}")
+    assert not offenders, offenders
 
 
 def test_registry_defaults_match_types():

@@ -2,14 +2,15 @@
 2509.25224): the decode-attention kernels track the running max as an
 INTEGER in the base-2 score domain, so the per-chunk correction
 2^(m_prev - m_new) is an exact power of two — applied as an
-exponent-bias ADD on the l/acc planes (the default) or as the classic
-VPU multiply (the APHRODITE_ATTN_AMLA=0 / amla=False A/B arm).
+exponent-bias ADD on the l/acc planes (what every served call runs)
+or as the classic VPU multiply (amla=False, kept as the reference of
+these tests).
 
 Because the correction is an exact power of two either way, the two
 arms are BIT-IDENTICAL away from underflow — the strongest possible
 A/B contract, pinned here at fp32 tolerance zero across the ragged
---ctx-mix geometries (multi-chunk, GQA, int8 KV, ALiBi) and the
-classic padded grid. `_mul_pow2` itself is unit-tested bit-exact
+--ctx-mix geometries (multi-chunk, GQA, int8 KV, ALiBi) and a call
+without a work list. `_mul_pow2` itself is unit-tested bit-exact
 against the multiply. All kernels run in interpret mode on CPU
 (tier-1)."""
 import jax.numpy as jnp
@@ -85,9 +86,9 @@ def test_amla_equals_classic_ragged_ctx_mix(num_q_heads, num_kv_heads,
                                atol=1e-2)
 
 
-def test_amla_equals_classic_on_classic_grid():
-    """Same contract on the padded (batch, head-block) grid — the tm
-    kernel carries the identical rewrite."""
+def test_amla_equals_classic_without_a_work_list():
+    """Same contract over the dense list a call without work_items
+    builds: items wholly beyond a row's context among them."""
     q, kp, vp, bt, ctx, _ = ragged_problem()
     a = _run(q, kp, vp, bt, ctx, True)
     c = _run(q, kp, vp, bt, ctx, False)
@@ -126,25 +127,3 @@ def test_amla_equals_classic_alibi():
     mask = ctx > 0
     np.testing.assert_allclose(a[mask], expected[mask], rtol=1e-2,
                                atol=1e-2)
-
-
-def test_amla_env_pin_selects_classic(monkeypatch):
-    """APHRODITE_ATTN_AMLA=0 pins the classic multiply for a default
-    (amla=None) call — unique geometry so the pinned call cannot share
-    a jit cache entry with an unpinned one (env is read at trace
-    time)."""
-    q, kp, vp, bt, _ = make_problem(
-        batch=3, num_q_heads=4, num_kv_heads=4, dim=128, page_size=8,
-        pages_per_seq=4, pages=16, seed=7)
-    ctx = np.array([9, 3, 25], np.int32)
-    work = build_decode_work_list([-(-int(c) // 8) for c in ctx], 1)
-    classic = np.asarray(paged_decode_attention(
-        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
-        jnp.array(ctx), scale=0.1, pages_per_chunk=1,
-        work_items=work, amla=False, interpret=True))
-    monkeypatch.setenv("APHRODITE_ATTN_AMLA", "0")
-    pinned = np.asarray(paged_decode_attention(
-        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
-        jnp.array(ctx), scale=0.1, pages_per_chunk=1,
-        work_items=work, interpret=True))
-    np.testing.assert_array_equal(classic, pinned)

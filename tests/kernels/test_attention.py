@@ -3,8 +3,8 @@ block tables in Python (mirrors the reference's
 ref_single_query_cached_kv_attention, tests/kernels/test_attention.py:45-99),
 plus the Pallas kernel in interpret mode vs the jnp reference.
 
-These tests pin the CLASSIC padded (batch, head-block) grid (the
-APHRODITE_ATTN_RAGGED=0 fallback); the ragged work-list grid and the
+The kernel's calls here pass no work list, so each runs the dense
+list of its table width; lists of the rows' own pages and the
 routing/config satellites are covered in test_ragged_attention.py.
 
 KV pages are TOKEN-MAJOR: [num_pages, page_size, Hkv * head_dim]
@@ -133,10 +133,10 @@ def test_pallas_decode_short_context():
     np.testing.assert_allclose(np.array(got), expected, rtol=1e-2, atol=1e-2)
 
 
-def test_pallas_decode_single_chunk_cross_cell():
-    """pages_per_seq == pages_per_chunk triggers the cross-cell
-    prefetch pipeline; ctx == 0 rows must stay zero (their DMAs are
-    started by the previous cell and must still be waited)."""
+def test_pallas_decode_one_item_a_row():
+    """pages_per_seq == pages_per_chunk: every row is one item, the
+    prefetch ring runs across rows; a ctx == 0 row must stay zero (its
+    one item copies nothing and still writes its output)."""
     q, k_pages, v_pages, bt, ctx = make_problem(batch=5, num_q_heads=8,
                                                 num_kv_heads=2, dim=128,
                                                 page_size=8,

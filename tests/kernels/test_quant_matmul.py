@@ -3,9 +3,12 @@
 reconstruct+gemm; correctness oracle here is `GPTQLinearMethod.dequantize`
 which is itself tested against AutoGPTQ layout in
 tests/quantization/test_quant_methods.py)."""
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from aphrodite_tpu.modeling.layers.quantization.gptq import (
@@ -200,25 +203,60 @@ def test_awq_a8_deferred_matches_dequant(m, K):
     assert rel_cd < 1e-5, rel_cd
 
 
-def test_deferred_resolution_and_vmem_fallback(monkeypatch):
-    """The deferred selector: explicit arg wins, then the env flag,
-    then autotune-by-shape (m > 64); the VMEM-fit check rejects tile
-    footprints the budget can't hold."""
+def test_deferred_resolution_and_vmem_fallback():
+    """The deferred selector: explicit arg wins, else the rule by m
+    (m > 64); the VMEM-fit check rejects tile footprints the budget
+    can't hold."""
     from aphrodite_tpu.ops.pallas.quant_matmul import (
         _deferred_fits, _resolve_deferred)
-    monkeypatch.delenv("APHRODITE_QMM_DEFERRED", raising=False)
     assert _resolve_deferred(True, 1) and not _resolve_deferred(False,
                                                                 8192)
     assert not _resolve_deferred(None, 64)      # decode keeps classic
     assert _resolve_deferred(None, 512)         # batch goes deferred
-    monkeypatch.setenv("APHRODITE_QMM_DEFERRED", "0")
-    assert not _resolve_deferred(None, 512)
-    monkeypatch.setenv("APHRODITE_QMM_DEFERRED", "1")
-    assert _resolve_deferred(None, 1)
-    # 4 int32 planes + f32 at 256x1024 = 5 MB fits the 8 MB default;
+    # 4 int32 planes + f32 at 256x1024 = 5 MB fits the 8 MB budget;
     # a 1024x2048 tile (40 MB) does not.
     assert _deferred_fits(256, 1024, 4)
     assert not _deferred_fits(1024, 2048, 4)
+
+
+@pytest.mark.parametrize("m,want", [
+    (1, "stream"), (48, "stream"), (64, "stream"),
+    (65, "_gptq_a8_deferred_kernel"),
+    (1024, "_gptq_a8_deferred_kernel"),
+    (2048, "_gptq_a8_deferred_kernel")])
+def test_the_w4a8_kernel_is_chosen_by_m_alone(monkeypatch, m, want):
+    """What `gptq_matmul_a8` traces at Mistral's cell's row counts (48
+    decode rows; 1,024 and 2,048 prompt tokens) and at the rule's
+    edges: the streamed grid with the plain rescale at m <= 64, the
+    compiler's grid with the deferred rescale above. The three
+    variables that could once overrule it are set against the rule
+    (and the budget to a size the deferred planes of a 256 x 1,024
+    tile, 3 MB, would not fit) and read by nothing."""
+    from aphrodite_tpu.ops.pallas import quant_matmul as qm
+    monkeypatch.setenv("APHRODITE_QMM_STREAM", "0" if m <= 64 else "1")
+    monkeypatch.setenv("APHRODITE_QMM_DEFERRED",
+                       "1" if m <= 64 else "0")
+    monkeypatch.setenv("APHRODITE_QMM_DEFERRED_VMEM_MB", "1")
+    chosen = []
+
+    def stream_call(*args, deferred, padded_m, N, out_dtype, **kw):
+        chosen.append("stream-deferred" if deferred else "stream")
+        return jnp.zeros((padded_m, N), out_dtype)
+    monkeypatch.setattr(qm, "_stream_call", stream_call)
+    for name in ("_gptq_a8_kernel", "_gptq_a8_deferred_kernel"):
+        def spy(*args, _name=name, _real=getattr(qm, name), **kw):
+            chosen.append(_name)
+            return _real(*args, **kw)
+        monkeypatch.setattr(qm, name, spy)
+    K, N = 256, 1024
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((m, K), jnp.bfloat16), ((K // 8, N), jnp.int32),
+        ((K // 128, N // 8), jnp.int32), ((K // 128, N), jnp.bfloat16))]
+    # the undecorated function: a trace another test left in the jit's
+    # cache would run none of this
+    jax.eval_shape(functools.partial(
+        qm.gptq_matmul_a8.__wrapped__, bits=4, group_size=128), *shapes)
+    assert chosen == [want]
 
 
 def test_awq_apply_fallback_on_cpu():

@@ -1,9 +1,9 @@
 """Ragged work-list decode attention: the flattened (sequence, chunk)
 grid vs the numpy oracle across ragged ctx mixes (multi-chunk, GQA head
 blocks, int8 KV, fused-write equivalence), plus the routing/config
-satellites: call-time APHRODITE_ATTN_PF validation, pages_per_chunk
-clamping, fused-write routing preconditions, and padded-table (page 0)
-masking."""
+satellites: call-time APHRODITE_ATTN_PF validation, the dense list of
+a call without one, fused-write routing preconditions, and
+padded-table (page 0) masking."""
 import os
 
 import jax
@@ -14,7 +14,7 @@ import pytest
 from aphrodite_tpu.ops.pallas import paged_attention as pa
 from aphrodite_tpu.ops.pallas.paged_attention import (
     build_decode_work_list, choose_pages_per_chunk,
-    clamp_pages_per_chunk, paged_decode_attention)
+    paged_decode_attention)
 
 from test_attention import make_problem, numpy_paged_attention
 
@@ -46,7 +46,7 @@ def test_ragged_matches_oracle_mixed_ctx(num_q_heads, num_kv_heads,
     """Ragged ctx mix incl. multi-chunk rows and a ctx=0 pad row (must
     output exact zeros — its single masked work item still writes its
     lane). Tolerance 1e-2: bf16 dot operands vs the f32 oracle, same
-    as the classic-kernel tests."""
+    as the tests of test_attention.py."""
     q, kp, vp, bt, ctx, work = ragged_problem(num_q_heads,
                                               num_kv_heads, ppc)
     expected = numpy_paged_attention(q, kp, vp, bt,
@@ -87,7 +87,7 @@ def test_ragged_reserved_pages_over_approximation():
 
 def test_ragged_int8_kv():
     """int8 KV pages under the ragged grid: scale folds into score and
-    epilogue exactly as on the classic grid."""
+    epilogue."""
     q, kp, vp, bt, ctx, work = ragged_problem()
     S = 0.05
     k8 = np.clip(np.round(kp / S), -127, 127).astype(np.int8)
@@ -199,37 +199,6 @@ def test_ragged_padded_table_page0_masked():
         np.testing.assert_allclose(got[mask], expected[mask],
                                    rtol=1e-2, atol=1e-2)
         np.testing.assert_allclose(got[~mask], 0.0, atol=1e-6)
-
-
-def test_ragged_env_pin_selects_classic(monkeypatch):
-    """APHRODITE_ATTN_RAGGED=0 pins the classic grid even when a work
-    list is passed (the A/B escape hatch) — and the result still
-    matches."""
-    q, kp, vp, bt, ctx, work = ragged_problem()
-    calls = {}
-    real_impl = pa._paged_decode_impl
-
-    def spy(*a, **kw):
-        calls["wi_seq"] = a[5]
-        return real_impl(*a, **kw)
-    monkeypatch.setattr(pa, "_paged_decode_impl", spy)
-    monkeypatch.setenv("APHRODITE_ATTN_RAGGED", "0")
-    got = pa.paged_decode_attention(
-        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
-        jnp.array(ctx), scale=0.1, pages_per_chunk=2,
-        work_items=work, interpret=True)
-    assert calls["wi_seq"] is None      # classic grid ran
-    monkeypatch.setenv("APHRODITE_ATTN_RAGGED", "1")
-    pa.paged_decode_attention(
-        jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
-        jnp.array(ctx), scale=0.1, pages_per_chunk=2,
-        work_items=work, interpret=True)
-    assert calls["wi_seq"] is not None  # ragged grid ran
-    expected = numpy_paged_attention(q, kp, vp, bt,
-                                     np.maximum(ctx, 1), 0.1)
-    mask = ctx > 0
-    np.testing.assert_allclose(np.array(got)[mask], expected[mask],
-                               rtol=1e-2, atol=1e-2)
 
 
 # ---- 256-token items: only live pages are copied ----
@@ -389,17 +358,17 @@ def _ten_head_write(kp, vp, bt, ctx):
     (512, 40, True, True), (512, 40, False, True),
     (None, 80, True, False),
 ], ids=["full-fused-write", "full-read-only", "window-fused-write",
-        "window-read-only", "classic-grid"])
+        "window-read-only", "no-work-list"])
 def test_ten_heads_are_one_head_block(window, width, fused, ragged):
     """The three calls of a Phi decode step (the full layer's with the
     fused write, a cross layer's read-only over the same pages, a
     window layer's) and a read-only window call, at ten KV heads in one
     block, against the jnp reference: the output its, the pages
     written the slot writer's exactly, no dead page read (NaN in every
-    page no row holds), items that end partly live. The classic padded
-    grid (APHRODITE_ATTN_RAGGED=0, or a call without a work list)
-    follows the same rule; it walks whole chunks over the table, pad
-    entries too, so there the pages no row holds are zeros."""
+    page no row holds), items that end partly live. A call without a
+    work list runs the dense list of the table width, whose items
+    beyond a row's context copy nothing: NaN stays in every page no
+    row holds there too."""
     from aphrodite_tpu.ops.attention import paged_decode_attention_ref
     assert pa.head_block(TEN_HKV, 128, jnp.bfloat16) == TEN_HKV
     ppc = choose_pages_per_chunk(
@@ -408,8 +377,6 @@ def test_ten_heads_are_one_head_block(window, width, fused, ragged):
     q, kp, vp, bt, ctx, counts, dead = ten_head_problem(window, width)
     assert any(0 < n % ppc < ppc for n in counts)   # partly live items
     dead = jnp.asarray(dead)[:, None, None]
-    if not ragged:
-        kp, vp = jnp.where(dead, 0, kp), jnp.where(dead, 0, vp)
     new, (want_k, want_v) = (None, None), (kp, vp)
     if fused:
         new, (want_k, want_v) = _ten_head_write(kp, vp, bt, ctx)
@@ -606,33 +573,80 @@ def test_pf_depth_read_at_call_time(monkeypatch):
     importlib.reload(pa)
 
 
-# ---- satellite: pages_per_chunk clamping ----
+# ---- satellite: a call without a work list ----
 
-def test_clamp_pages_per_chunk():
-    assert clamp_pages_per_chunk(12, 8) == 6
-    assert clamp_pages_per_chunk(8, 8) == 8
-    assert clamp_pages_per_chunk(7, 4) == 1
-    assert clamp_pages_per_chunk(64, 16) == 16
-    assert clamp_pages_per_chunk(6, 100) == 6
-    with pytest.raises(ValueError):
-        clamp_pages_per_chunk(8, 0)
-
-
-def test_non_divisor_ppc_clamps_instead_of_raising():
-    """pages_per_seq % pages_per_chunk != 0 used to raise; now the
-    chunk size clamps down to the largest divisor and the result still
-    matches the oracle."""
+def test_a_width_the_item_does_not_divide_runs_as_given(monkeypatch):
+    """pages_per_seq % pages_per_chunk != 0 and no work list: the item
+    is used as given (no clamp to a divisor), a row's last item copies
+    its live pages only, and the result matches the oracle."""
     q, kp, vp, bt, ctx = make_problem(batch=3, num_q_heads=8,
                                       num_kv_heads=2, dim=128,
                                       page_size=4, pages_per_seq=12,
                                       pages=64)
+    seen = {}
+    real_impl = pa._paged_decode_impl
+
+    def spy(*a, **kw):
+        seen.update(ppc=kw["pages_per_chunk"], items=a[6].tolist())
+        return real_impl(*a, **kw)
+    monkeypatch.setattr(pa, "_paged_decode_impl", spy)
     expected = numpy_paged_attention(q, kp, vp, bt, ctx, 0.1)
-    got = paged_decode_attention(
+    got = pa.paged_decode_attention(
         jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
-        jnp.array(ctx), scale=0.1, pages_per_chunk=8,  # -> clamps to 6
-        interpret=True)
+        jnp.array(ctx), scale=0.1, pages_per_chunk=8, interpret=True)
+    assert seen["ppc"] == 8
+    # two items a row (8 pages and the 4 left), padded to the bucket
+    assert seen["items"] == [0, 1] * 3 + [-1, -1]
     np.testing.assert_allclose(np.array(got), expected, rtol=1e-2,
                                atol=1e-2)
+    with pytest.raises(ValueError, match="pages_per_chunk"):
+        pa.paged_decode_attention(
+            jnp.array(q), jnp.array(kp), jnp.array(vp), jnp.array(bt),
+            jnp.array(ctx), scale=0.1, pages_per_chunk=0,
+            interpret=True)
+
+
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["read-only", "fused-write"])
+def test_no_work_list_equals_the_list_of_true_page_counts(fused):
+    """A call without a list builds the dense list of its table width
+    (three 16-page items a row on a table 40 wide) and equals, bit for
+    bit, the call with the list of the rows' true page counts: the
+    5-token row's and the 257-token row's later items, and all of the
+    pad row's, hold no live page, copy nothing (NaN in every page no
+    row holds) and leave the online-softmax state as it was. With the
+    fused write, the pages written are the same too."""
+    rng = np.random.default_rng(11)
+    q, kp, vp, bt, counts = item_problem()
+    assert -(-counts[5] // ITEM_PPC) == 1 < -(-ITEM_WIDTH // ITEM_PPC)
+    B = len(ITEM_CTX)
+    new = (None, None)
+    if fused:
+        new = tuple(jnp.asarray(rng.normal(size=(B, 2, 128)),
+                                jnp.float32) for _ in range(2))
+
+    def call(work):
+        return paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(ITEM_CTX), None, *new,
+            scale=0.1, pages_per_chunk=ITEM_PPC, work_items=work,
+            interpret=True)
+    listed = call(build_decode_work_list(counts, ITEM_PPC))
+    dense = call(None)
+    for a, b in zip(listed if fused else (listed,),
+                    dense if fused else (dense,)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    out = np.asarray(dense[0] if fused else dense)
+    assert np.isfinite(out).all() and np.abs(out).max() > 0
+
+
+def test_the_module_has_one_decode_kernel():
+    """One kernel body, and none of what selected between two."""
+    kernels = [n for n in vars(pa) if n.startswith("_decode_kernel")]
+    assert kernels == ["_decode_kernel_ragged"]
+    for gone in ("_decode_kernel_tm", "ragged_enabled", "amla_enabled",
+                 "clamp_pages_per_chunk"):
+        assert not hasattr(pa, gone), gone
 
 
 # ---- work-list builder ----

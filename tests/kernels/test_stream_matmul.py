@@ -8,8 +8,8 @@ and drives an explicit cross-cell weight DMA ring
 - parity at m in {1, 8, 64} for gptq AND awq, including the K=384
   tail (three single-group k-tiles at gs 128), group sizes 64/128,
   deferred rescale on/off, and int8 activations (the W4A8 kernels);
-- selection: default ON at m <= 64, OFF above, APHRODITE_QMM_STREAM=0
-  pins the classic grid;
+- selection: taken at m <= 64, not above, unless the call's keyword
+  says otherwise;
 - the APHRODITE_QMM_STREAM_PF per-call read warns-and-defaults on a
   malformed value (never kills the call, let alone the import);
 - the deep-k VMEM-fit guard: an oversized APHRODITE_QMM_BLOCK_K
@@ -156,35 +156,13 @@ def test_awq_a8_stream_parity(m, deferred, gs, K):
 
 # --------------------------------------------- selection + flags --
 
-def test_stream_resolution(monkeypatch):
-    """Explicit arg wins; then the env pin; default is ON at m <= 64
-    (decode / bs=1 bursts) and OFF above."""
-    monkeypatch.delenv("APHRODITE_QMM_STREAM", raising=False)
+def test_stream_resolution():
+    """Explicit arg wins; else the rule by m: taken at m <= 64
+    (decode / bs=1 bursts), not above."""
     assert _resolve_stream(True, 8192) and not _resolve_stream(False, 1)
     assert _resolve_stream(None, 1)
     assert _resolve_stream(None, 64)
     assert not _resolve_stream(None, 65)
-    monkeypatch.setenv("APHRODITE_QMM_STREAM", "0")
-    assert not _resolve_stream(None, 1)       # classic-grid A/B pin
-    assert _resolve_stream(True, 1)           # explicit still wins
-    monkeypatch.setenv("APHRODITE_QMM_STREAM", "1")
-    assert _resolve_stream(None, 64)
-
-
-def test_stream_env_pin_selects_classic(monkeypatch):
-    """APHRODITE_QMM_STREAM=0 reproduces the classic-grid result for
-    a default (stream=None) skinny-m call (unique shape: the env is
-    read at trace time, so the shape must not share a jit cache entry
-    with an unpinned default call)."""
-    params, x = make_gptq(4, 128, 256, 384, 6)
-    classic = np.asarray(gptq_matmul(
-        x, params["qweight"], params["qzeros"], params["scales"],
-        bits=4, group_size=128, interpret=True, stream=False))
-    monkeypatch.setenv("APHRODITE_QMM_STREAM", "0")
-    pinned = np.asarray(gptq_matmul(
-        x, params["qweight"], params["qzeros"], params["scales"],
-        bits=4, group_size=128, interpret=True))
-    np.testing.assert_allclose(classic, pinned, rtol=0, atol=0)
 
 
 def test_stream_pf_bad_value_warns_and_defaults(monkeypatch):

@@ -119,8 +119,8 @@ def main() -> int:
             pages_per_chunk=2,
             work_items=build_decode_work_list(pages_i, 2)), np.float32)
         check("ragged int8 KV", ref8, got8r)
-        # The classic online-softmax multiply (APHRODITE_ATTN_AMLA=0):
-        # the arm the default exponent-bias rescale is A/B'd against.
+        # The classic online-softmax multiply: the reference the
+        # exponent-bias rescale is held bit-equal to.
         gotm = np.asarray(paged_decode_attention(
             q, kp, vp, bt, ctx, scale=scale, pages_per_chunk=2,
             work_items=build_decode_work_list(pages_i, 2), amla=False),

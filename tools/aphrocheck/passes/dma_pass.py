@@ -16,9 +16,8 @@ Rules:
   matching every base — unresolvable code must not produce noise.
 - DMA002: one semaphore base indexed through ring-slot arithmetic
   with TWO DIFFERENT moduli that can be live together (branch-aware:
-  the classic kernel's `chunk_slots` vs 2-slot arms of
-  `if single_chunk:` do not conflict, but a genuine depth mismatch
-  within one path does). Mixed moduli mean the n-th start and the
+  a `chunk_slots` arm and a 2-slot arm of one `if` do not conflict,
+  but a genuine depth mismatch within one path does). Mixed moduli mean the n-th start and the
   matching wait disagree about which slot they share.
 - DMA003: at a pallas_call site, the largest statically-resolvable
   ring modulus in the kernel exceeds the largest resolvable
