@@ -44,11 +44,19 @@ _DTYPE_BYTES = {"float16": 2, "bfloat16": 2, "float32": 4}
 class PageGroups:
     """Which KV page group each layer's attention belongs to.
 
-    A layer is one of four kinds:
+    A layer is one of five kinds:
 
     - `full`: it writes K and V and needs every key of its sequence;
     - `window`: it writes K and V and needs only the newest `window`
       keys;
+    - `pooled`: it writes K and V and needs the exact keys of its
+      query's own aligned block of `pooled_window` positions and, for
+      every block behind that one, ONE pooled key and value for each
+      page of tokens (EVA's chunked attention; the chunk is the page).
+      A sequence holds two lists for such a group, the current block's
+      pages and the summary pages of the blocks behind, and the layer
+      attends over the one table `[summary pages ; block's pages]`
+      (`processing/block_manager.py`);
     - it **holds nothing** (a state-space layer, a gated unit, an MLP:
       whatever it keeps per sequence is no KV page): `group_of_layer`
       and `slot_of_layer` are -1;
@@ -67,7 +75,7 @@ class PageGroups:
     the pages its window has passed. A model whose layers are all of
     one kind has one group of all its layers, and its pool is what it
     always was: a pair a layer."""
-    kinds: Tuple[str, ...]              # a group: "full" | "window"
+    kinds: Tuple[str, ...]      # a group: "full" | "window" | "pooled"
     group_of_layer: Tuple[int, ...]     # -1: the layer holds nothing
     slot_of_layer: Tuple[int, ...]
     window: Optional[int] = None        # tokens; None: no window group
@@ -75,24 +83,39 @@ class PageGroups:
     #: (`StateSpec`): what follows the pages alone (swap, prefix pins,
     #: bursts, speculative rounds) does not carry it
     stateful: bool = False
+    #: tokens of a pooled group's aligned block; None: no pooled group
+    pooled_window: Optional[int] = None
+
+    def pooled_pages(self, block_size: int) -> Tuple[int, int]:
+        """(pages of a pooled group's full block, summary pages a
+        finished block leaves: a pooled key a page of tokens)."""
+        block = self.pooled_window // block_size
+        return block, block // block_size
 
     @classmethod
     def of(cls, layer_kinds: List[Union[bool, str, int, None]],
-           window: Optional[int], stateful: bool = False
-           ) -> "PageGroups":
+           window: Optional[int], stateful: bool = False,
+           pooled_window: Optional[int] = None) -> "PageGroups":
         """`layer_kinds[l]`: "window" (or True), "full" (or False),
-        None for a layer that holds nothing, or the index of the
-        earlier layer whose pages layer `l` reads."""
+        "pooled", None for a layer that holds nothing, or the index of
+        the earlier layer whose pages layer `l` reads."""
         def kind_of(entry):
             if entry is None or (isinstance(entry, int) and
                                  not isinstance(entry, bool)):
+                return entry
+            if entry == "pooled":
+                if not pooled_window:
+                    raise ValueError(
+                        "a pooled layer needs pooled_window")
                 return entry
             has_window = entry is True or entry == "window"
             return "window" if has_window and window is not None \
                 else "full"
         layer_kinds = [kind_of(entry) for entry in layer_kinds]
         n_window = layer_kinds.count("window")
-        per = math.gcd(n_window, layer_kinds.count("full"))
+        n_pooled = layer_kinds.count("pooled")
+        per = math.gcd(math.gcd(n_window, layer_kinds.count("full")),
+                       n_pooled)
         kinds, group_of, slot_of = [], [], []
         open_group = {}                 # kind -> (group, layers in it)
         for kind in layer_kinds:
@@ -116,7 +139,8 @@ class PageGroups:
             slot_of.append(filled)
             open_group[kind] = (group, filled + 1)
         return cls(tuple(kinds), tuple(group_of), tuple(slot_of),
-                   window if n_window else None, stateful)
+                   window if n_window else None, stateful,
+                   pooled_window if n_pooled else None)
 
     @property
     def layers_per_group(self) -> int:
@@ -306,7 +330,8 @@ class ModelConfig:
             # the config states each layer's kind itself
             return PageGroups.of(
                 kinds, self.get_sliding_window(),
-                stateful=self.get_state_spec() is not None)
+                stateful=self.get_state_spec() is not None,
+                pooled_window=getattr(cfg, "pooled_window", None))
         layout = getattr(cfg, "sliding_window_layout", None)
         if layout is not None:
             return PageGroups.of([bool(x) for x in layout],
@@ -443,6 +468,13 @@ class CacheConfig:
                 f"{self.gpu_memory_utilization}.")
         if self.block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {self.block_size}")
+        pooled = self.page_groups.pooled_window
+        if pooled is not None and pooled % self.block_size ** 2:
+            # a pooled key stands for one page of tokens, and a
+            # finished block's pooled keys fill whole pages
+            raise ValueError(
+                f"a pooled page group's window ({pooled}) has to be a "
+                f"multiple of block_size squared ({self.block_size}^2)")
 
     def _verify_cache_dtype(self) -> None:
         if self.cache_dtype not in ("auto", "fp8", "fp8_e5m2", "int8"):

@@ -57,6 +57,12 @@ NAMES = (
                              # (inside sched.schedule)
     "cache.state_assign",   # state slots given to admitted prompts
                             # (inside sched.schedule)
+    "cache.window_close",   # a pooled group closes a window: its
+                            # summary pages taken, its pages let go
+                            # (inside cache.window_release or the
+                            # decode rows' slots)
+    "runner.summarise",     # the dispatch of the program that pools
+                            # the closed windows into their summaries
     "queue_wait",
     "preemptions",
     "sampler.plan_reuse",
@@ -83,6 +89,12 @@ NAMES = (
     "attn.window_pages_unwindowed",  # what the window groups' rows
                             # would hold live without a window
     "cache.window_pages_freed",  # pages the window groups let go of
+    "attn.summary_pages_live",  # of `attn.pages_live.window`, a pooled
+                            # group's summary pages (its tables count
+                            # there whole: summaries and window)
+    "attn.windows_closed_prompt",  # windows pooled groups closed as a
+    "attn.windows_closed_decode",  # prompt chunk, a decode row passed
+                            # their edge
     "moe.tokens_routed",    # token-expert pairs of the expert layers
     "moe.experts_touched",  # held experts with a pair, over layers and
                             # steps

@@ -688,7 +688,8 @@ class AphroditeEngine:
             handles = self.executor.dispatch_steps(Round(
                 prompt=prompt_mds, decode=decode_mds, ahead=True,
                 fed_by=before.handles if before is not None else (),
-                state_copies=scheduler_outputs.state_copies))
+                state_copies=scheduler_outputs.state_copies,
+                window_closes=scheduler_outputs.window_closes))
             if handles is not None:
                 if before is not None:
                     self.tracer.add("runner.ahead", count=len(handles))
@@ -803,7 +804,8 @@ class AphroditeEngine:
             scheduler_outputs.blocks_to_swap_out,
             scheduler_outputs.blocks_to_copy, num_steps=burst,
             extra_cap=extra_cap, drafts=drafts,
-            state_copies=scheduler_outputs.state_copies))
+            state_copies=scheduler_outputs.state_copies,
+            window_closes=scheduler_outputs.window_closes))
         if prompt_mds and not decode_mds \
                 and not scheduler_outputs.blocks_to_swap_in \
                 and not scheduler_outputs.blocks_to_swap_out \
@@ -891,7 +893,8 @@ class AphroditeEngine:
             all_prompt_mds.extend(mds2)
             steps.append(self.executor.dispatch_steps(Round(
                 prompt=mds2, blocks_to_copy=outputs2.blocks_to_copy,
-                state_copies=outputs2.state_copies)))
+                state_copies=outputs2.state_copies,
+                window_closes=outputs2.window_closes)))
             if not self._prompt_fast_path_ok(mds2):
                 break
         # Disagg: hand off every final-chunk group of the batch-built

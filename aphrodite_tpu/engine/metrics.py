@@ -175,12 +175,34 @@ _STAGE_COUNTERS = [
      lambda s, c: c["attn.pages_live.full"]),
     ("aphrodite:kv_pages_live_window_total",
      "Of aphrodite:decode_attn_pages_live_total, the pages of the "
-     "window page groups.", lambda s, c: c["attn.pages_live.window"]),
+     "window page groups, and every page of a pooled group's table "
+     "(its window's pages behind its summary pages).",
+     lambda s, c: c["attn.pages_live.window"]),
+    ("aphrodite:kv_pages_live_summary_total",
+     "Of aphrodite:kv_pages_live_window_total, the summary pages of "
+     "the pooled page groups (a pooled key a page of tokens of the "
+     "windows behind), summed over decode steps.",
+     lambda s, c: c["attn.summary_pages_live"]),
     ("aphrodite:window_pages_unwindowed_total",
-     "Pages the window groups' rows would hold live without a window, "
-     "summed over decode steps (aphrodite:kv_pages_live_window_total "
-     "is what they hold).",
+     "Pages the window and pooled groups' rows would hold live with "
+     "every key kept, summed over decode steps "
+     "(aphrodite:kv_pages_live_window_total is what they hold).",
      lambda s, c: c["attn.window_pages_unwindowed"]),
+    ("aphrodite:eva_windows_closed_prompt_total",
+     "Windows that pooled page groups closed as a prompt chunk passed "
+     "their edge: summary pages taken, the window's pages let go.",
+     lambda s, c: c["attn.windows_closed_prompt"]),
+    ("aphrodite:eva_windows_closed_decode_total",
+     "Windows that pooled page groups closed as a decode row passed "
+     "their edge.", lambda s, c: c["attn.windows_closed_decode"]),
+    ("aphrodite:window_close_seconds_total",
+     "Seconds closing pooled groups' windows in the block manager "
+     "(inside the schedule seconds).",
+     lambda s, c: s["cache.window_close"]),
+    ("aphrodite:summarise_seconds_total",
+     "Seconds dispatching the program that pools closed windows into "
+     "their summary pages (inside the dispatch of a round).",
+     lambda s, c: s["runner.summarise"]),
     ("aphrodite:window_pages_freed_total",
      "KV pages that window page groups let go of, to the free list.",
      lambda s, c: c["cache.window_pages_freed"]),

@@ -116,6 +116,9 @@ class Round:
     fed_by: Tuple[StepHandle, ...] = ()
     #: (from, to) state slots of forks, copied before the steps
     state_copies: List[Tuple[int, int]] = field(default_factory=list)
+    #: (window's pages, summary pages) of the windows that pooled page
+    #: groups closed, pooled before the steps
+    window_closes: list = field(default_factory=list)
 
 
 class TPUExecutor:
@@ -152,6 +155,11 @@ class TPUExecutor:
                 raise NotImplementedError(
                     "disagg_split + a model with recurrent state is not "
                     "supported: kv_handoff carries pages, not state")
+            if cache_config.page_groups.pooled_window is not None:
+                raise NotImplementedError(
+                    "disagg_split + a pooled page group is not "
+                    "supported: a window's summaries would have to be "
+                    "pooled in both pools")
             if lora_config is not None:
                 raise NotImplementedError(
                     "disagg_split + LoRA is not supported: adapter "
@@ -469,6 +477,10 @@ class TPUExecutor:
         if rnd.state_copies:
             self.cache_engine.kv_caches = self.model_runner.copy_state(
                 self.cache_engine.kv_caches, rnd.state_copies)
+        if rnd.window_closes:
+            self.cache_engine.kv_caches = \
+                self.model_runner.summarise_windows(
+                    self.cache_engine.kv_caches, rnd.window_closes)
         if not rnd.blocks_to_copy:
             return
         if rnd.prompt:

@@ -90,6 +90,11 @@ _WIDE_PAGES_BUCKET = 64
 #: are nothing beside that (`[128, 128]` int32 a step) and a dead item
 #: of the work list costs the kernel half a microsecond.
 _WIDE_ROWS = 64
+#: windows a call of the summarise program pools (`summarise_windows`):
+#: one shape, so one program; more windows in a round take more calls
+#: (the callers of a group that joined together reach an edge together,
+#: four in the benchmark's cell; a pad row costs what a window costs)
+_SUMMARISE_ROWS = 4
 
 
 def _pow2_bucket(value: int, lo: int = 16) -> int:
@@ -307,6 +312,12 @@ class ModelRunner:
         self._copy_fn = jax.jit(self._copy_blocks, donate_argnums=(0,))
         self._copy_state_fn = jax.jit(self._copy_state,
                                       donate_argnums=(0,))
+        # The pooled keys and values of the windows that rows have
+        # finished (a model with a pooled page group): a program of
+        # its own, so that a step in which no row closes a window
+        # pays nothing.
+        self._summarise_fn = jax.jit(self._summarise,
+                                     donate_argnums=(1,))
         # Small, and apart from the step programs on purpose: a decode
         # step whose tokens are still on the device takes them through
         # this, so `_step_sample` keeps its signature and its compiled
@@ -515,6 +526,38 @@ class ModelRunner:
             tuple(a.at[:, dst].set(a[:, src]) for a in arrays)
             for arrays in kv_caches[pages:]]
 
+    def _summarise(self, params, kv_caches, src, dst):
+        pages = self.page_pairs
+        return self.model.summarise_windows(
+            params, kv_caches[:pages], src, dst) + list(kv_caches[pages:])
+
+    def summarise_windows(self, kv_caches,
+                          closes: List[Tuple[List[int], List[int]]]):
+        """The windows that pooled page groups closed this round
+        (`BlockSpaceManager.take_window_closes`): each window's pages
+        are pooled into the summary pages taken for them, in every
+        layer, before the round's steps and after every step already
+        sent (device order), `_SUMMARISE_ROWS` windows a call of the
+        one program (pad rows read page 0 and write the out-of-range
+        page, which the scatter drops)."""
+        with self.tracer.span("runner.summarise"), self._mesh_ctx():
+            for at in range(0, len(closes), _SUMMARISE_ROWS):
+                kv_caches = self._summarise_fn(
+                    self.params, kv_caches, *self._closed_block_lists(
+                        closes[at:at + _SUMMARISE_ROWS]))
+        return kv_caches
+
+    def _closed_block_lists(self, closes):
+        """`(src, dst)` of one call of the summarise program, on the
+        device: each closed window's pages and its summary pages, a
+        row a window, padded to `_SUMMARISE_ROWS` rows."""
+        src = np.zeros((_SUMMARISE_ROWS, len(closes[0][0])), dtype=np.int32)
+        dst = np.full((_SUMMARISE_ROWS, len(closes[0][1])),
+                      self.num_slots // self.page_size, dtype=np.int32)
+        for i, (window, summary) in enumerate(closes):
+            src[i], dst[i] = window, summary
+        return self._dev(src), self._dev(dst)
+
     def copy_state(self, kv_caches, copies: List[Tuple[int, int]]):
         """A fork's state: each child's slot takes its parent's rows
         of every state array (every layer's: the slot axis follows the
@@ -665,8 +708,9 @@ class ModelRunner:
                 md.group_tables[seq_id][g]
                 for md, seq_id in zip(seq_group_metadata_list, seq_ids)]
                 for g in range(len(self.page_groups.kinds))]
-        views = [self._prompt_view(rows, ctx_lens, plens, padded_len)
-                 for rows in group_rows]
+        views = [self._prompt_view(rows, ctx_lens, plens, padded_len,
+                                   self._table_floor(kind))
+                 for rows, kind in zip(group_rows, self.page_groups.kinds)]
         self._count_prefill_tiles(group_rows, views, ctx_lens, plens,
                                   padded_len, use_prefix)
         self.tracer.add("attn.prefill_steps")
@@ -751,15 +795,31 @@ class ModelRunner:
             self.tracer.add("attn.prefill_tiles_visited", count=visited)
             self.tracer.add("attn.prefill_tiles_padded", count=padded)
 
+    def _table_floor(self, kind: str, decode: bool = False) -> int:
+        """The least width a group of `kind` pads its tables to. A
+        pooled group's is past its full window, so that every table
+        of it is a wide one (`_WIDE_TABLE`) and the rows of a batch,
+        whose tables jump from a window and its summaries to the
+        summaries alone at an edge, share one program. A window
+        group's decode table is never narrower than the window and a
+        page: what it holds once a row has passed the window, but for
+        the one step in sixteen at which the window starts on a
+        page's edge, which is no program's key."""
+        if kind == "pooled":
+            return self.page_groups.pooled_pages(self.page_size)[0] + 1
+        if kind == "window" and decode:
+            return -(-self.page_groups.window // self.page_size) + 1
+        return 1
+
     def _prompt_view(self, rows: List[Tuple[int, List[int]]],
                      ctx_lens: np.ndarray, plens: np.ndarray,
-                     padded_len: int) -> GroupView:
+                     padded_len: int, floor: int = 1) -> GroupView:
         """What a prompt step writes to and reads from one page group:
         `rows[i]` is sequence i's (tokens its table has let go of, a
         multiple of the page; page numbers), `ctx_lens` and `plens`
         the padded batch's cached and new tokens. Slots, table and
         the page writer's cells, with the context counted from the
-        table's first page."""
+        table's first page; the table no narrower than `floor`."""
         batch, padded_batch = len(rows), len(ctx_lens)
         ps = self.page_size
         num_pages_oob = self.num_slots // ps
@@ -769,7 +829,7 @@ class ModelRunner:
         # Bucket the table width to the longest scheduled table (always
         # — long prompts exceed one bucket regardless of prefix use).
         max_pages = self._table_width(
-            max((len(table) for _, table in rows), default=1))
+            max([floor] + [len(table) for _, table in rows]))
         tables = np.full((padded_batch, max_pages), num_pages_oob,
                          dtype=np.int32)
         for i, (let_go, table) in enumerate(rows):
@@ -1069,13 +1129,8 @@ class ModelRunner:
                                         padded_batch)]
         else:
             kinds = self.page_groups.kinds
-            # (a window group's table is never narrower than the
-            # window and a page: what it holds once a row has passed
-            # the window, but for the one step in sixteen at which the
-            # window starts on a page's edge, which is no program's key)
-            floor = -(-(self.page_groups.window or 0) // self.page_size) + 1
             widths = [self._table_width(max(
-                [floor if kind == "window" else 1] +
+                [self._table_floor(kind, decode=True)] +
                 [len(row[g][1]) for row in group_rows]), padded_batch)
                 for g, kind in enumerate(kinds)]
             layout, views = tuple(widths), []
@@ -1121,14 +1176,27 @@ class ModelRunner:
                 got = count_decode_pages(rows[:, 3] - rows[:, at], chunks,
                                          ppc, self.page_size)
                 fetched, live = fetched + got[0], live + got[1]
-                self.tracer.add("attn.pages_live." + kinds[g],
-                                count=got[1])
+                # (a pooled group's table is a window's pages behind
+                # its summaries, and the kernel reads them all alike)
+                self.tracer.add(
+                    "attn.pages_live." +
+                    ("window" if kinds[g] == "pooled" else kinds[g]),
+                    count=got[1])
                 shared += got[1] * self.page_groups.readers[g]
-                if kinds[g] == "window":
+                if kinds[g] in ("window", "pooled"):
                     # (what the rows' whole contexts take in pages)
                     self.tracer.add(
                         "attn.window_pages_unwindowed",
                         count=int(np.sum(-(-rows[:, 3] // self.page_size))))
+                if kinds[g] == "pooled":
+                    # a window behind has let go of its pages less
+                    # its summaries: the summaries from what is gone
+                    held, kept = self.page_groups.pooled_pages(
+                        self.page_size)
+                    gone = (held - kept) * self.page_size
+                    self.tracer.add(
+                        "attn.summary_pages_live",
+                        count=int(np.sum(rows[:batch, at] // gone)) * kept)
                 at += 1 + width
             self.tracer.add("attn.page_reads_shared", count=shared)
             work, ppc = views[0].decode_work, views[0].decode_ppc

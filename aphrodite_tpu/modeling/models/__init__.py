@@ -22,6 +22,7 @@ _MODELS = {
     "MixtralForCausalLM": ("mixtral", "MixtralForCausalLM"),
     "DeepseekForCausalLM": ("deepseek", "DeepseekForCausalLM"),
     "OPTForCausalLM": ("opt", "OPTForCausalLM"),
+    "EvaByteForCausalLM": ("evabyte", "EvaByteForCausalLM"),
     "GPTJForCausalLM": ("gpt_j", "GPTJForCausalLM"),
     "GPTNeoXForCausalLM": ("gpt_neox", "GPTNeoXForCausalLM"),
     "JambaForCausalLM": ("jamba", "JambaForCausalLM"),

@@ -10,7 +10,16 @@ before the window of the oldest query still to come, in the round that
 passes it, and takes new pages at its end, so it holds the window, the
 chunk being written and a page at most. A model-wide window is the
 case of one group, a window group; a model without one has one full
-group and is served exactly as before. A model that keeps recurrent
+group and is served exactly as before. A POOLED group (EVA's chunked
+attention: exact keys inside a query's own aligned window of
+`pooled_window` tokens, one pooled key a page of tokens behind it)
+holds TWO lists a sequence, the current window's pages and the summary
+pages of the windows behind, and shows them as one table, `[summary
+pages ; window pages]`, counted from its first summary: when a
+sequence passes a window's edge the group takes the pages the
+window's pooled keys fill, hands both lists to the device
+(`take_window_closes`, whose program pools the one into the other
+before the round's steps), and lets the WHOLE window go. A model that keeps recurrent
 state beside its pages (`common/config.py::StateSpec`) has a second
 kind of per-sequence memory here, the STATE SLOT: one id a sequence,
 the same row of every state array, from a free list of its own; given
@@ -120,10 +129,12 @@ class BlockSpaceManager:
         max_chunk_tokens: Optional[int] = None,
         num_state_slots: Optional[int] = None,
         tracer: Optional[tracing.Tracer] = None,
+        pooled_window: Optional[int] = None,
     ) -> None:
-        """`group_kinds`: "full" or "window" for each page group (one
-        group by default: a window group where `sliding_window` is
-        set). `max_chunk_tokens`: the longest prompt chunk the
+        """`group_kinds`: "full", "window" or "pooled" for each page
+        group (one group by default: a window group where
+        `sliding_window` is set). `pooled_window`: the tokens of a
+        pooled group's aligned window, a multiple of the page squared. `max_chunk_tokens`: the longest prompt chunk the
         scheduler writes at once for a model with a window group
         (None: a prompt may come whole). `num_state_slots`: the state
         slots of a model with recurrent state (None: it has none).
@@ -152,6 +163,19 @@ class BlockSpaceManager:
                 -(-max_chunk_tokens // block_size) + 1
         #: pages that window groups have let go of (cumulative)
         self.window_pages_freed = 0
+        #: a pooled group's full window in pages, and the summary
+        #: pages its pooled keys fill (a key a page of tokens)
+        self.pooled_window = pooled_window
+        #: windows that pooled groups have closed (cumulative)
+        self.windows_closed = 0
+        self.window_blocks = self.summary_blocks = 0
+        if "pooled" in self.group_kinds:
+            if not pooled_window or pooled_window % block_size ** 2:
+                raise ValueError(
+                    "a pooled page group needs pooled_window, a "
+                    "multiple of block_size squared")
+            self.window_blocks = pooled_window // block_size
+            self.summary_blocks = self.window_blocks // block_size
 
         assert watermark >= 0.0
         self.watermark = watermark
@@ -174,6 +198,14 @@ class BlockSpaceManager:
         self.more_tables: Dict[int, List[BlockTable]] = {}
         # thread-safe: as `more_tables`.
         self.first_blocks: Dict[int, List[int]] = {}
+        # A sequence's summary pages, a list for each page group (a
+        # pooled group's fills as windows close, the others' stay
+        # empty), and the (window's pages, summary pages) of the
+        # windows closed since the device was last told.
+        # thread-safe: as `more_tables`.
+        self.summary_tables: Dict[int, List[BlockTable]] = {}
+        # thread-safe: as `more_tables`.
+        self._window_closes: List[Tuple[List[int], List[int]]] = []
         # State slots: the free ids, each sequence's, and the
         # (parent's, child's) of forks whose device copy is still to
         # be scheduled.
@@ -205,7 +237,7 @@ class BlockSpaceManager:
         seq = seq_group.get_seqs(status=SequenceStatus.WAITING)[0]
         needed = len(seq.logical_token_blocks)
         if not self.plain:
-            return sum(self._prompt_blocks_of(kind, needed)
+            return sum(self._prompt_peak_of(kind, needed)
                        for kind in self.group_kinds)
         prefix = seq_group.prefix
         if prefix is not None and prefix.allocated:
@@ -218,7 +250,20 @@ class BlockSpaceManager:
         the rest as its table slides (`prepare_chunk`)."""
         if kind == "window" and self.window_cap_blocks is not None:
             return min(prompt_blocks, self.window_cap_blocks)
+        if kind == "pooled":
+            return min(prompt_blocks, self.window_blocks)
         return prompt_blocks
+
+    def _prompt_peak_of(self, kind: str, prompt_blocks: int) -> int:
+        """The most pages a group of `kind` holds at once while a
+        prompt is written, which is what admission has to find free: a
+        pooled group's is met when its last full window closes, the
+        summaries of every window behind the prompt's last one beside
+        that whole window."""
+        if kind != "pooled" or prompt_blocks <= self.window_blocks:
+            return self._prompt_blocks_of(kind, prompt_blocks)
+        behind = (prompt_blocks - 1) // self.window_blocks
+        return behind * self.summary_blocks + self.window_blocks
 
     def can_allocate(self, seq_group: SequenceGroup,
                      extra_reserved: int = 0) -> AllocStatus:
@@ -299,6 +344,9 @@ class BlockSpaceManager:
         for seq in waiting:
             self.more_tables[seq.seq_id] = []
             self.first_blocks[seq.seq_id] = [0] * len(self.group_kinds)
+            if self.summary_blocks:
+                self.summary_tables[seq.seq_id] = [
+                    [] for _ in self.group_kinds]
         for g, kind in enumerate(self.group_kinds):
             block_table: BlockTable = []
             for _ in range(self._prompt_blocks_of(kind,
@@ -381,12 +429,60 @@ class BlockSpaceManager:
         self.window_pages_freed += freed
         return freed
 
+    def _close_windows(self, seq_id: int, pos: int) -> None:
+        """A pooled group whose window lies wholly before position
+        `pos`, the first still to be written: the pages its pooled
+        keys fill are taken, both lists go to `take_window_closes`
+        for the device, and the whole window is let go (the device
+        reads it before any step of the round can write it again: the
+        pooling program is first in device order). A prompt chunk
+        never crosses a window's edge (`Scheduler._fit_chunk`), so a
+        call closes one window at most."""
+        firsts = self.first_blocks[seq_id]
+        for g, table in enumerate(self._tables(seq_id)):
+            if self.group_kinds[g] != "pooled" or \
+                    pos // self.block_size < firsts[g] + self.window_blocks:
+                continue
+            if len(table) != self.window_blocks or \
+                    pos // self.block_size >= \
+                    firsts[g] + 2 * self.window_blocks:
+                raise AssertionError(
+                    f"sequence {seq_id} passes a window's edge at "
+                    f"{pos} with {len(table)} of {self.window_blocks} "
+                    "window pages")
+            with self.tracer.span("cache.window_close"):
+                summary = self.summary_tables[seq_id][g]
+                taken = []
+                for _ in range(self.summary_blocks):
+                    block = self.hbm_pool.allocate()
+                    summary.append(block)
+                    taken.append(block.block_number)
+                self._window_closes.append(
+                    ([b.block_number for b in table], taken))
+                for block in table:
+                    self.hbm_pool.free(block)
+                del table[:]
+                firsts[g] += self.window_blocks
+            self.window_pages_freed += self.window_blocks
+            self.windows_closed += 1
+
+    def take_window_closes(self) -> List[Tuple[List[int], List[int]]]:
+        """The (window's pages in order, summary pages) of the windows
+        closed since the last call: the device pools the one into the
+        other before the round's steps."""
+        closes, self._window_closes = self._window_closes, []
+        return closes
+
     def _cover(self, seq_id: int, last_pos: int) -> None:
-        """New pages at every table's end, up to position `last_pos`.
+        """New pages at every table's end, up to position `last_pos`;
+        a pooled group first closes the window that position has
+        left.
         (Here and in `append_slots` a table is read off its owned
         container by name and not through `_tables`: the ownership
         ledger, `OWNERSHIP.json`, follows a page from `allocate()` to
         the container it lands in.)"""
+        if self.summary_blocks:
+            self._close_windows(seq_id, last_pos)
         firsts = self.first_blocks.get(seq_id)
         table = self.block_tables[seq_id]
         for g in range(len(self.group_kinds)):
@@ -412,10 +508,18 @@ class BlockSpaceManager:
 
     def can_append_slot(self, seq_group: SequenceGroup) -> bool:
         # One new block per running sequence and page group is the
-        # worst case.
+        # worst case; a pooled group whose next token opens a window
+        # takes the closed one's summary pages before it lets the
+        # window go.
         num_seqs = seq_group.num_seqs(status=SequenceStatus.RUNNING)
-        return num_seqs * len(self.group_kinds) <= \
-            self.hbm_pool.get_num_free_blocks()
+        needed = num_seqs * len(self.group_kinds)
+        if self.summary_blocks:
+            pooled = self.group_kinds.count("pooled")
+            for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
+                pos = seq.get_len() - 1 + seq.data.in_flight
+                if pos % self.pooled_window == 0:
+                    needed += pooled * (self.summary_blocks - 1)
+        return needed <= self.hbm_pool.get_num_free_blocks()
 
     def append_slots(self, seq: Sequence) -> List[Tuple[int, int]]:
         """Reserve a slot for one new token in every page group.
@@ -431,6 +535,9 @@ class BlockSpaceManager:
         for g in range(len(self.group_kinds)):
             if g:
                 block_table = self.more_tables[seq.seq_id][g - 1]
+            # (a fork shares a pooled group's summary pages for good:
+            # they are never written again; its window's last page is
+            # copied on write like any other)
             last_block = block_table[-1]
             assert last_block.device == Device.TPU
             if last_block.ref_count == 1:
@@ -509,6 +616,13 @@ class BlockSpaceManager:
         for src_block_table in more:
             for block in src_block_table:
                 block.ref_count += 1
+        if self.summary_blocks:
+            shared = [t.copy()
+                      for t in self.summary_tables[parent_seq.seq_id]]
+            self.summary_tables[child_seq.seq_id] = shared
+            for src_block_table in shared:
+                for block in src_block_table:
+                    block.ref_count += 1
 
     # ------------------------------------------------------------------
     # Swap planning (preemption-by-swap)
@@ -621,6 +735,7 @@ class BlockSpaceManager:
             return
         self._free_block_table(self.block_tables.pop(seq.seq_id))
         self._free_group_tables(self.more_tables.pop(seq.seq_id, []))
+        self._free_group_tables(self.summary_tables.pop(seq.seq_id, []))
         self.first_blocks.pop(seq.seq_id, None)
         self._free_state_slot(seq.seq_id)
 
@@ -645,8 +760,12 @@ class BlockSpaceManager:
             self._free_block_table(block_table)
         for tables in self.more_tables.values():
             self._free_group_tables(tables)
+        for summaries in self.summary_tables.values():
+            self._free_group_tables(summaries)
         self.block_tables.clear()
         self.more_tables.clear()
+        self.summary_tables.clear()
+        self._window_closes.clear()
         self.first_blocks.clear()
         self._free_state_slots = list(
             range(self.num_state_slots or 0))[::-1]
@@ -660,12 +779,19 @@ class BlockSpaceManager:
                          ) -> Optional[List[Tuple[int, List[int]]]]:
         """For a model whose page groups are not plain: each group's
         (tokens its table has let go of at its start, page numbers);
-        None for a plain one, whose table is `get_block_table`'s."""
+        None for a plain one, whose table is `get_block_table`'s. A
+        pooled group's table is its two lists as one, `[summary pages ;
+        window pages]`: a summary page stands in the place of a page
+        of pages, so the table has let go of what the windows behind
+        held less what their summaries hold, and a position counted
+        from its start is `summaries + place in the window`."""
         if self.plain:
             return None
         firsts = self.first_blocks[seq.seq_id]
-        return [(firsts[g] * self.block_size,
-                 [b.block_number for b in table])
+        summaries = self.summary_tables.get(
+            seq.seq_id, [[]] * len(self.group_kinds))
+        return [((firsts[g] - len(summaries[g])) * self.block_size,
+                 [b.block_number for b in summaries[g] + table])
                 for g, table in enumerate(self._tables(seq.seq_id))]
 
     def block_numbers(self, seq_id: int) -> List[int]:

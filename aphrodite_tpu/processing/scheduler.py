@@ -78,6 +78,7 @@ class SchedulerOutputs:
         blocks_to_copy: Dict[int, List[int]],
         ignored_seq_groups: List[SequenceGroup],
         state_copies: Optional[List[Tuple[int, int]]] = None,
+        window_closes: Optional[list] = None,
     ) -> None:
         self.prompt_chunks = prompt_chunks
         self.decode_groups = decode_groups
@@ -88,6 +89,9 @@ class SchedulerOutputs:
         self.blocks_to_copy = blocks_to_copy
         #: (from, to) state slots of forks, copied on the device first
         self.state_copies = state_copies or []
+        #: (window's pages, summary pages) of the windows that pooled
+        #: page groups closed, pooled on the device first
+        self.window_closes = window_closes or []
         # Structural invariant: a step never swaps both directions.
         assert not (blocks_to_swap_in and blocks_to_swap_out)
         self.ignored_seq_groups = ignored_seq_groups
@@ -119,7 +123,7 @@ class SchedulerOutputs:
         return (not self.prompt_chunks and not self.decode_groups
                 and not self.blocks_to_swap_in
                 and not self.blocks_to_swap_out and not self.blocks_to_copy
-                and not self.state_copies)
+                and not self.state_copies and not self.window_closes)
 
 
 class Scheduler:
@@ -159,6 +163,10 @@ class Scheduler:
         self.window_chunk_cap: Optional[int] = \
             scheduler_config.window_chunk_cap \
             if groups.window is not None else None
+        #: a prompt chunk never crosses an edge of a pooled page
+        #: group's window: a chunk then closes one window at most, and
+        #: its rows sit in one window's pages
+        self.pooled_window: Optional[int] = groups.pooled_window
         self.block_manager = BlockSpaceManager(
             block_size=cache_config.block_size,
             num_gpu_blocks=cache_config.num_gpu_blocks,
@@ -167,7 +175,7 @@ class Scheduler:
             group_kinds=groups.kinds,
             max_chunk_tokens=self.window_chunk_cap,
             num_state_slots=cache_config.num_state_slots,
-            tracer=self.tracer)
+            tracer=self.tracer, pooled_window=groups.pooled_window)
         #: the most sequences admitted: a model with recurrent state
         #: has a slot for each, forks included
         self.max_num_seqs = min(
@@ -305,13 +313,17 @@ class Scheduler:
     # ------------------------------------------------------------------
 
     def _fit_chunk(self, remaining: int, seq_lens: List[int],
-                   budget: int) -> int:
-        """Largest chunk length for a new prompt row such that the
-        padded-batch cost (rows x longest row) stays within `budget`.
+                   budget: int, ctx: int = 0) -> int:
+        """Largest chunk length for a new prompt row, which starts at
+        position `ctx`, such that the padded-batch cost (rows x longest
+        row) stays within `budget`.
         Partial chunks stay page-aligned (the whole-page prefill writer
         requires every row's cached context to be a page multiple)."""
         if self.window_chunk_cap is not None:
             budget = min(budget, self.window_chunk_cap)
+        if self.pooled_window is not None:
+            remaining = min(remaining, self.pooled_window -
+                            ctx % self.pooled_window)
         rows = len(seq_lens) + 1
         longest = max(seq_lens) if seq_lens else 0
         limit = budget // rows
@@ -336,7 +348,7 @@ class Scheduler:
             seq = group.get_seqs(status=SequenceStatus.RUNNING)[0]
             ctx = seq.data.num_computed_tokens
             remaining = seq.get_len() - ctx
-            n = self._fit_chunk(remaining, seq_lens, budget)
+            n = self._fit_chunk(remaining, seq_lens, budget, ctx)
             if n <= 0:
                 still.append(group)
                 # Keep FCFS: rows behind an out-of-budget head wait too.
@@ -360,11 +372,21 @@ class Scheduler:
         manager = self.block_manager
         if manager.plain:
             return
-        freed = manager.window_pages_freed
+        freed, closed = manager.window_pages_freed, manager.windows_closed
         with self.tracer.span("cache.window_release"):
             manager.prepare_chunk(seq, ctx, n)
         self.tracer.add("cache.window_pages_freed",
                         count=manager.window_pages_freed - freed)
+        self._count_closes("prompt", closed)
+
+    def _count_closes(self, phase: str, since: int) -> int:
+        """Counts the windows that pooled page groups have closed since
+        the block manager's count read `since`
+        (`attn.windows_closed_<phase>`); returns how many."""
+        closed = self.block_manager.windows_closed - since
+        if closed:
+            self.tracer.add("attn.windows_closed_" + phase, count=closed)
+        return closed
 
     def _admit_prompts(self, seq_lens: List[int], budget: int,
                        chunks: List[PromptChunk],
@@ -443,7 +465,7 @@ class Scheduler:
                 ctx = min(group.prefix.get_length(),
                           (prompt_len - 1) // ps * ps)
             remaining = prompt_len - ctx
-            n = self._fit_chunk(remaining, seq_lens, budget)
+            n = self._fit_chunk(remaining, seq_lens, budget, ctx)
             if n <= 0:
                 break
             final = n == remaining
@@ -545,6 +567,7 @@ class Scheduler:
             raise
         if outputs is None:
             return None
+        outputs.window_closes = self.block_manager.take_window_closes()
         mds = [
             self._group_metadata(c.group, is_prompt=True, chunk=c)
             for c in outputs.prompt_chunks
@@ -593,6 +616,7 @@ class Scheduler:
         retiring: List[SequenceGroup] = []
         reclaimed = False
         self._release_window_pages()
+        closed = self.block_manager.windows_closed
         while self.running:
             seq_group = self.running.popleft()
             if self._last_token_in_flight(seq_group):
@@ -630,6 +654,13 @@ class Scheduler:
                 self._append_slot(seq_group, blocks_to_copy)
                 running.append(seq_group)
         self.running = running
+        # (a window group's pages went in `_release_window_pages`; a
+        # pooled group lets a whole window go where a row takes the
+        # slot past its edge)
+        self.tracer.add(
+            "cache.window_pages_freed",
+            count=self._count_closes("decode", closed) *
+            self.block_manager.window_blocks)
         decode_groups = list(self.running)
         # Deferred rows stay RUNNING (they keep their pages and their
         # priority) but are not decode rows this round; nor are the
@@ -808,6 +839,8 @@ class Scheduler:
             scheduler_outputs = self._schedule()
             scheduler_outputs.state_copies = \
                 self.block_manager.take_state_copies()
+            scheduler_outputs.window_closes = \
+                self.block_manager.take_window_closes()
             seq_group_metadata_list = [
                 self._group_metadata(c.group, is_prompt=True, chunk=c)
                 for c in scheduler_outputs.prompt_chunks

@@ -36,7 +36,8 @@ def get_config(model: str,
                "smallthinker": configs.SmallThinkerConfig,
                "phi4flash": configs.Phi4FlashConfig,
                "jamba": configs.JambaConfig,
-               "laguna": configs.LagunaConfig}.get(declared)
+               "laguna": configs.LagunaConfig,
+               "evabyte": configs.EvaByteConfig}.get(declared)
         if cls is not None:
             return cls.from_pretrained(model, revision=revision)
     try:

@@ -2,6 +2,7 @@
 model_type transformers doesn't ship (reference
 `aphrodite/transformers_utils/configs/`): loading them through these
 classes avoids trust_remote_code."""
+from aphrodite_tpu.transformers_utils.configs.evabyte import EvaByteConfig
 from aphrodite_tpu.transformers_utils.configs.jamba import JambaConfig
 from aphrodite_tpu.transformers_utils.configs.laguna import LagunaConfig
 from aphrodite_tpu.transformers_utils.configs.phi4flash import (
@@ -11,5 +12,6 @@ from aphrodite_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
 from aphrodite_tpu.transformers_utils.configs.yi import YiConfig
 
-__all__ = ["JambaConfig", "LagunaConfig", "Phi4FlashConfig", "QWenConfig",
-           "SmallThinkerConfig", "YiConfig"]
+__all__ = ["EvaByteConfig", "JambaConfig", "LagunaConfig",
+           "Phi4FlashConfig", "QWenConfig", "SmallThinkerConfig",
+           "YiConfig"]

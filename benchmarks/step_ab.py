@@ -39,6 +39,9 @@ CELLS = {
     "jamba2-3b-bf16": (128, 60000, 1000, 0),
     "phi-4-mini-flash-bf16": (48, 40000, 2500, 33),
     "laguna-s-2.1-bf16": (64, 60000, 4400, 33),
+    # (a pooled group: two windows behind as 16 summary pages, and the
+    # pages of the third up to the context)
+    "evabyte-6.5b-bf16": (24, 4780, 6000, 0),
 }
 SLOTS = 128
 
@@ -105,9 +108,16 @@ def build(name: str, prompts: int, abstract, prompt_len: int = 512):
                 kv, lowered(inputs["metadata"]), lowered(inputs["sel"]))
         return step, params, args, dict(is_prompt=True, use_prefix=False)
     pages_a_row = -(-ctx // 16)
+    def pooled_row():
+        window = groups.pooled_window
+        behind = ctx // window
+        let_go = behind * (window - window // 16)
+        return let_go, table(pages_a_row - let_go // 16)
+
     group_rows = [[
         (ctx - window_pages * 16, table(window_pages))
         if kind == "window" and pages_a_row > window_pages
+        else pooled_row() if kind == "pooled"
         else (0, table(pages_a_row)) for kind in groups.kinds]
         for _ in range(rows)]
     batch = runner._send_decode_batch(
@@ -136,7 +146,8 @@ def _hf(config):
     from aphrodite_tpu.transformers_utils import configs
     cls = {"jamba": configs.JambaConfig,
            "phi4flash": configs.Phi4FlashConfig,
-           "laguna": getattr(configs, "LagunaConfig", None)
+           "laguna": getattr(configs, "LagunaConfig", None),
+           "evabyte": getattr(configs, "EvaByteConfig", None),
            }[config["model_type"]]
     return cls(**{k: v for k, v in config.items() if k not in (
         "perf", "architectures", "model_type", "torch_dtype")})
@@ -151,6 +162,9 @@ def _model(config, model_config):
         from aphrodite_tpu.modeling.models.laguna import LagunaForCausalLM
         return LagunaForCausalLM(model_config.hf_config, jnp.bfloat16,
                                  max_model_len=model_config.max_model_len)
+    elif config["model_type"] == "evabyte":
+        from aphrodite_tpu.modeling.models.evabyte import \
+            EvaByteForCausalLM as cls
     else:
         from aphrodite_tpu.modeling.models.phi4flash import \
             Phi4FlashForCausalLM as cls

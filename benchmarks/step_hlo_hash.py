@@ -1,8 +1,8 @@
 """Do the other models' step programs lower as they did? The sha256 of
 the StableHLO of the REAL `ModelRunner._step` at a toy size, a prompt
-step and a decode step, float32 and bfloat16, for the four models the
-benchmark had before PR 43 (Mistral, SmallThinker, Phi-4-mini-flash,
-Jamba), in the tree given:
+step and a decode step, float32 and bfloat16, for the five models the
+benchmark had before PR 48 (Mistral, SmallThinker, Phi-4-mini-flash,
+Jamba and, in a tree that has it, Laguna), in the tree given:
 
     python benchmarks/step_hlo_hash.py <root of a tree> > a.txt
     python benchmarks/step_hlo_hash.py <root of its parent> > b.txt
@@ -37,6 +37,19 @@ def hf(arch):
             num_key_value_heads=2, head_dim=16, max_position_embeddings=512, moe_ffn_hidden_size=32,
             moe_num_primary_experts=16, moe_num_active_primary_experts=4, sliding_window_size=32)
         c.architectures = ["SmallThinkerForCausalLM"]; return c
+    if arch == "laguna":
+        kinds = ["full_attention", "sliding_attention", "sliding_attention", "full_attention"]
+        c = configs.LagunaConfig(vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=4,
+            num_attention_heads=12, num_key_value_heads=2, head_dim=16, max_position_embeddings=256, num_experts=8,
+            num_routed_experts=16, first_held_expert=0, num_experts_per_tok=4, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, mlp_only_layers=[0], gating="per-head", sliding_window=24,
+            rope_parameters={"full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 8,
+                "original_max_position_embeddings": 32, "beta_slow": 1, "beta_fast": 32, "attention_factor": 1.2,
+                "partial_rotary_factor": 0.5},
+                "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1}},
+            layer_types=kinds, mlp_layer_types=["dense"] + ["sparse"] * 3, gating_types=["per_head"] * 4,
+            num_attention_heads_per_layer=[12, 18, 18, 12], moe_routed_scaling_factor=2.5)
+        c.architectures = ["LagunaForCausalLM"]; return c
     if arch == "phi4flash":
         c = configs.Phi4FlashConfig(vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=8,
             num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512, sliding_window=32, mamba_d_state=8)
@@ -51,7 +64,7 @@ def programs(arch, dtype):
     cfg = hf(arch)
     mc = ModelConfig("x", dtype=dtype, max_model_len=256, hf_config=cfg)
     cls = ModelRegistry.load_model_cls(cfg.architectures[0])
-    model = cls(cfg, jnp.dtype(dtype))
+    model = cls(cfg, jnp.dtype(dtype), **({"max_model_len": 256} if getattr(cls, "takes_max_model_len", False) else {}))
     params = jax.eval_shape(model.init_params)
     spec = mc.get_state_spec(); groups = mc.get_page_groups()
     SLOTS, pages = 8, 64
@@ -77,7 +90,8 @@ def programs(arch, dtype):
     out["decode"] = step.lower(params, None, None, kv, batch["metadata"], None, is_prompt=False, use_prefix=False).as_text()
     return out
 
-for arch in ("mistral", "smallthinker", "phi4flash", "jamba"):
+for arch in ("mistral", "smallthinker", "phi4flash", "jamba") + (
+        ("laguna",) if hasattr(configs, "LagunaConfig") else ()):
     for dtype in ("float32", "bfloat16"):
         for name, text in programs(arch, dtype).items():
             print(arch, dtype, name, hashlib.sha256(text.encode()).hexdigest()[:16], len(text))

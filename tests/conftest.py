@@ -124,16 +124,17 @@ LATER_METRICS = (
 #: pins the manifest is spared
 NEWER_METRICS = ("prompt_dispatch_late_pct.batch",)
 #: cells appended since the pinning tests were written, oldest first
-#: (PR 41's, PR 43's), each with its configuration and the metrics it
-#: alone reports
-NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k")
+#: (PR 41's, PR 43's, PR 48's), each with its configuration and the
+#: metrics it alone reports
+NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k",
+               "evabyte-6.5b-bf16.doc-5k")
 #: the modules that hold the manifest to a count, a set or its last
 #: places -> (the cells, the metrics) appended after what each holds
 _PINNED = {
     "test_perf_smallthinker": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
     "test_perf_phi4flash": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
     "test_perf_jamba": (NEWER_CELLS[1:], NEWER_METRICS),
-    "test_perf_laguna": ((), NEWER_METRICS),
+    "test_perf_laguna": (NEWER_CELLS[2:], NEWER_METRICS),
 }
 #: the module that holds PR 38's six to the manifest's last places and
 #: to the list of cells, and reads `BENCHMARK.json` with `json.load`

@@ -313,6 +313,86 @@ def test_decode_attention_compiles_at_laguna_shapes(on_v5e, Hq, B, pps,
         on_v5e((B,), I32), new, new).compile()
 
 
+#: EvaByte's attention layers: 32 KV heads of 128 under 32 query heads,
+#: ONE query row a KV head and 4,096 lanes a token row (no cell has
+#: more than 20 KV heads or fewer than 4 query rows a head). A row's
+#: table is `[summary pages ; window pages]`, 24-152 pages, 192 wide.
+#: (rows, fused write): the cell's 24 rows and the canary's one.
+EVABYTE_CASES = [(rows, fused) for rows in (1, 24)
+                 for fused in (True, False)]
+
+
+@pytest.mark.parametrize("B,fused", EVABYTE_CASES)
+def test_decode_attention_compiles_at_evabyte_shapes(on_v5e, B, fused):
+    from aphrodite_tpu.ops.pallas.paged_attention import (
+        build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
+        padded_work_length, paged_decode_attention)
+    H, d, page, pps = 32, 128, 16, 192
+    ppc = choose_pages_per_chunk(pps, page, lane_bytes_of(H, d, BF16))
+    # rows just past an edge (24 summary pages and a page) beside rows
+    # about to reach one (16 summary pages and 128)
+    counts = [(25, 144)[i % 2] for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = build_decode_work_list(
+        counts, ppc, pad_to=padded_work_length(items, B, pps, ppc))
+    # a pool of 10 GB in 8 pairs of page arrays: 128 KB a page a side
+    pages = on_v5e((4780, page, H * d), BF16)
+    new = on_v5e((B, H, d), BF16) if fused else None
+
+    def attend(q, kp, vp, tables, ctx, kn, vn):
+        return paged_decode_attention(
+            q, kp, vp, tables, ctx, None, kn, vn, scale=d ** -0.5,
+            pages_per_chunk=ppc, work_items=work, amla=True)
+
+    jax.jit(attend, donate_argnums=(1, 2) if fused else ()).lower(
+        on_v5e((B, H, d), BF16), pages, pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), new, new).compile()
+
+
+def test_kv_writers_compile_at_evabyte_shapes(on_v5e):
+    """Both page writers into pages of 32 KV heads x 128 lanes: the
+    prompt's whole-page writer for four chunks of 2,048 bytes (512
+    cells) and for one, and the token writer (a prompt chunk that
+    starts inside a page)."""
+    from aphrodite_tpu.ops.pallas.kv_write import (can_use_pallas_writer,
+                                                   write_kv_pages,
+                                                   write_kv_pages_prefill)
+    page, hd = 16, 32 * 128
+    assert can_use_pallas_writer(BF16, page, hd)
+    pages = on_v5e((4780, page, hd), BF16)
+    for cells in (4 * 2048 // page, 2048 // page):
+        chunk = on_v5e((cells * page, hd), BF16)
+        ids = on_v5e((cells,), I32)
+        jax.jit(write_kv_pages_prefill, donate_argnums=(2, 3)).lower(
+            chunk, chunk, pages, pages, ids, ids, ids).compile()
+    rows = on_v5e((40, hd), BF16)
+    for distinct in (True, False):
+        jax.jit(functools.partial(write_kv_pages,
+                                  distinct_pages=distinct),
+                donate_argnums=(2, 3)).lower(
+            rows, rows, pages, pages, on_v5e((40,), I32)).compile()
+
+
+def test_the_summarise_program_compiles_at_evabyte_shapes(on_v5e):
+    """`summarise_pages` for eight closed windows of 128 pages of 32
+    heads x 128 lanes into 8 summary pages each, in a pool of the
+    cell's size; the pool is updated in place (donated) and the
+    program's temporaries stay under a quarter of a gigabyte
+    (a window at a time)."""
+    from aphrodite_tpu.modeling.layers.eva_attention import summarise_pages
+    page, heads, d = 16, 32, 128
+    pages = on_v5e((4780, page, heads * d), BF16)
+    vec = on_v5e((heads, d), BF16)
+
+    def pool(kp, vp, src, dst, phi, mu):
+        return summarise_pages(kp, vp, src, dst, phi, mu, d ** -0.5, heads)
+
+    compiled = jax.jit(pool, donate_argnums=(0, 1)).lower(
+        pages, pages, on_v5e((8, 128), I32), on_v5e((8, 8), I32), vec,
+        vec).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
 #: AI21-Jamba2-3B's attention layers: ONE KV head of 128 under 20 query
 #: heads, so the page's lane axis is one lane tile, a page copy is 4 KB
 #: and a row's packed query has 20 rows; 512-token items. (rows, table
@@ -627,6 +707,11 @@ PREFILL_CASES = {
     "phi-full": (1, 2048, 40, 10, 2048, None),
     "phi-window": (1, 2048, 40, 10, 2048, 512),
     "jamba": (8, 512, 20, 1, 512, None),
+    # 32 KV heads of ONE query row: a chunk's own keys (chunk 1) and a
+    # table 192 pages wide gathered (chunks 2 and 3), at 1 and 4 rows
+    "evabyte-chunk-1": (4, 2048, 32, 32, 2048, None),
+    "evabyte-table": (4, 2048, 32, 32, 3072, None),
+    "evabyte-table-1-row": (1, 2048, 32, 32, 3072, None),
 }
 
 

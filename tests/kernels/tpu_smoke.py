@@ -240,6 +240,136 @@ def main() -> int:
                   np.asarray(wk6, np.float32)[live6],
                   np.asarray(gk6, np.float32)[live6], tol=1e-6)
 
+    with section("EvaByte: 32 KV heads of one query row, summary pages"):
+        # -- the kernels as `evabyte-6.5b-bf16.doc-5k` calls them: 32
+        #    query heads over 32 KV heads of 128 (ONE query row a KV
+        #    head, 4,096 lanes a token row), pages of 16, tables 192
+        #    wide holding `[summary pages ; window pages]`: rows just
+        #    past an edge (24 summary pages and one or two of the new
+        #    window) beside rows about to reach one (16 and 128). The
+        #    decode kernel with the fused write and read-only at 1 and
+        #    24 rows; both page writers; the prompt's flash kernel over
+        #    a chunk's own keys and over a gathered table; the pooling
+        #    of a window's 128 pages into 8 against the definition. --
+        from aphrodite_tpu.modeling.layers.eva_attention import (
+            summarise_pages)
+        from aphrodite_tpu.ops.attention import prefill_attention
+        from aphrodite_tpu.ops.kv_cache import write_to_kv_cache
+        from aphrodite_tpu.ops.pallas.kv_write import (write_kv_pages,
+                                                       write_kv_pages_prefill)
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list, choose_pages_per_chunk, lane_bytes_of)
+        from aphrodite_tpu.ops.pallas.prefill_attention import (
+            prefill_flash_attention)
+        h9, w9 = 32, 192
+        ppc9 = choose_pages_per_chunk(w9, 16,
+                                      lane_bytes_of(h9, d, jnp.bfloat16))
+        for rows9 in (1, 24):
+            ctx9 = np.array([(24 * 16 + 5, 16 * 16 + 2040, 24 * 16 + 17,
+                              16 * 16 + 1283)[i % 4] for i in range(rows9)],
+                            np.int32)
+            cnt9 = -(-ctx9 // 16)
+            tbl9 = np.zeros((rows9, w9), np.int32)
+            used9 = 1
+            for i, n in enumerate(cnt9):
+                tbl9[i, :n] = np.arange(used9, used9 + n)
+                used9 += n
+            raw9 = rs.randn(used9 + 4, 16, h9 * d) * 0.1
+            kv9 = [jnp.asarray(raw9, jnp.bfloat16),
+                   jnp.asarray(raw9[::-1], jnp.bfloat16)]
+            q9 = jnp.asarray(rs.randn(rows9, h9, d) * 0.1, jnp.bfloat16)
+            new9 = [jnp.asarray(rs.randn(rows9, h9, d) * 0.1, jnp.bfloat16)
+                    for _ in range(2)]
+            slots9 = jnp.asarray(
+                tbl9[np.arange(rows9), (ctx9 - 1) // 16] * 16 +
+                (ctx9 - 1) % 16)
+            wk9, wv9 = write_to_kv_cache(new9[0], new9[1], kv9[0], kv9[1],
+                                         slots9)
+            work9 = build_decode_work_list(cnt9, ppc9)
+            common9 = (jnp.asarray(tbl9), jnp.asarray(ctx9), None)
+            ref9 = np.asarray(paged_decode_attention_ref(
+                q9, wk9, wv9, jnp.asarray(tbl9), jnp.asarray(ctx9), scale),
+                np.float32)
+            got9 = paged_decode_attention(
+                q9, wk9, wv9, *common9, None, None, scale=scale,
+                pages_per_chunk=ppc9, work_items=work9)
+            check(f"{rows9} rows read-only, table {w9}, ppc={ppc9}", ref9,
+                  np.asarray(got9, np.float32))
+            got9, gk9, _ = paged_decode_attention(
+                q9, kv9[0], kv9[1], *common9, new9[0], new9[1], scale=scale,
+                pages_per_chunk=ppc9, work_items=work9)
+            check(f"{rows9} rows, the fused write", ref9,
+                  np.asarray(got9, np.float32))
+            check(f"{rows9} rows, the fused write's pages",
+                  np.asarray(wk9, np.float32), np.asarray(gk9, np.float32),
+                  tol=1e-6)
+        # both writers into pages of 4,096 lanes
+        pool9 = jnp.zeros((136, 16, h9 * d), jnp.bfloat16)
+        chunk9 = jnp.asarray(rs.randn(2048, h9 * d) * 0.1, jnp.bfloat16)
+        pid9 = rs.permutation(136)[:128].astype(np.int32)
+        pk9, pv9 = write_kv_pages_prefill(
+            chunk9, chunk9, pool9, pool9, jnp.asarray(pid9),
+            jnp.asarray(np.arange(128, dtype=np.int32)),
+            jnp.full((128,), 16, jnp.int32))
+        check("prefill page writer, 128 cells of 4,096 lanes",
+              np.asarray(chunk9, np.float32),
+              np.asarray(pk9, np.float32)[pid9].reshape(2048, h9 * d),
+              tol=1e-6)
+        tok9 = jnp.asarray(rs.randn(3, h9 * d) * 0.1, jnp.bfloat16)
+        slot9 = np.array([5 * 16 + 3, 77 * 16 + 15, 130 * 16], np.int32)
+        dk9, _ = write_kv_pages(tok9, tok9, pk9, pv9, jnp.asarray(slot9),
+                                distinct_pages=True)
+        want9 = np.asarray(pk9, np.float32)
+        want9[slot9 // 16, slot9 % 16] = np.asarray(tok9, np.float32)
+        check("decode page writer, 4,096 lanes", want9,
+              np.asarray(dk9, np.float32), tol=1e-6)
+        # the prompt's kernel: a chunk's own keys, and a gathered table
+        # of two windows' summaries and a window behind the chunk
+        for keys9, ctx_of in ((2048, 0), (3072, 256 + 768)):
+            fq9 = jnp.asarray(rs.randn(2, 2048, h9, d), jnp.bfloat16)
+            fk9 = jnp.asarray(rs.randn(2, keys9, h9, d), jnp.bfloat16)
+            fv9 = jnp.asarray(rs.randn(2, keys9, h9, d), jnp.bfloat16)
+            fctx9 = np.array([ctx_of, ctx_of // 2], np.int32)
+            fnew9 = np.array([keys9 - ctx_of, 1280], np.int32) \
+                if ctx_of else np.array([2048, 1280], np.int32)
+            fargs9 = (fq9, fk9, fv9, jnp.asarray(fctx9),
+                      jnp.asarray(fctx9 + np.minimum(fnew9, 2048)),
+                      d ** -0.5)
+            check(f"prompt attention 32/32 heads, {keys9} keys",
+                  np.asarray(prefill_attention(*fargs9), np.float32),
+                  np.asarray(prefill_flash_attention(*fargs9), np.float32))
+        # the pooling: two windows of 128 pages into 8 pages each, and
+        # six pad rows whose writes are dropped
+        src9 = np.zeros((8, 128), np.int32)
+        dst9 = np.full((8, 8), used9 + 4, np.int32)
+        src9[0], src9[1] = np.arange(1, 129), np.arange(140, 268)
+        dst9[0], dst9[1] = np.arange(300, 308), np.arange(310, 318)
+        phi9 = jnp.asarray(rs.rand(h9, d) * 4 - 2, jnp.bfloat16)
+        mu9 = jnp.asarray(rs.rand(h9, d) * 8 - 4, jnp.bfloat16)
+        sk9, sv9 = jax.jit(lambda k, v, s, t: summarise_pages(
+            k, v, s, t, phi9, mu9, scale, h9))(
+            kv9[0], kv9[1], jnp.asarray(src9), jnp.asarray(dst9))
+        k32 = np.asarray(kv9[0], np.float32).reshape(-1, 16, h9, d)
+        v32 = np.asarray(kv9[1], np.float32).reshape(-1, 16, h9, d)
+        for r in (0, 1):
+            kk, vv = k32[src9[r]], v32[src9[r]]      # [128, 16, H, d]
+            sc9 = np.einsum("pjhd,hd->pjh", kk,
+                            np.asarray(phi9, np.float32)) * scale
+            pw9 = np.exp(sc9 - sc9.max(1, keepdims=True))
+            pw9 /= pw9.sum(1, keepdims=True)
+            kb9 = np.einsum("pjh,pjhd->phd", pw9, kk) + \
+                np.asarray(mu9, np.float32)
+            vb9 = np.einsum("pjh,pjhd->phd", pw9, vv)
+            for got, want, tag in ((sk9, kb9, "keys"), (sv9, vb9, "values")):
+                check(f"summarise window {r}, pooled {tag}",
+                      want.reshape(8, 16, h9 * d),
+                      np.asarray(got, np.float32)[dst9[r]], tol=3e-2)
+        untouched = np.ones(used9 + 4, bool)
+        untouched[dst9[:2].ravel()] = False
+        check("summarise leaves every other page alone",
+              np.asarray(kv9[0], np.float32)[untouched],
+              np.asarray(sk9, np.float32)[untouched], tol=1e-6)
+
     with section("decode attention (Phi-4-mini-flash: 10 KV heads, "
                  "one head block)"):
         # -- the decode kernel as `phi-4-mini-flash-bf16.reason-2k`

@@ -79,7 +79,8 @@ POOL_NAMES = {"hbm_pool", "host_pool", "gpu_allocator", "cpu_allocator",
               "allocator", "pool", "block_pool"}
 
 #: Owned-table attribute names LEAK004 guards removal of.
-OWNED_TABLES = {"block_tables", "more_tables", "prefixes"}
+OWNED_TABLES = {"block_tables", "more_tables", "summary_tables",
+                "prefixes"}
 
 #: Container-mutating call tails that store a block.
 _STORE_TAILS = {"append", "appendleft", "insert", "add", "extend"}
