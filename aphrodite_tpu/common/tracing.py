@@ -101,6 +101,12 @@ NAMES = (
     "moe.pairs_held",       # of the pairs routed, those whose expert the
                             # layer holds (counted by a model that may
                             # hold a share of its experts)
+    "moe.rows_walked",      # rows of the row tiles the expert kernels
+                            # visited (`ops/pallas/grouped_matmul.py`):
+                            # over `moe.pairs_held`, the padding a tile
+                            # taller than its group costs the MXU
+    "moe.kernel_steps",     # steps whose expert layers took those
+                            # kernels (`fused_moe.takes_expert_kernel`)
     "moe.decode_experts_touched",  # of them, the decode steps'
     "moe.decode_expert_slots",     # experts x expert layers, a decode step
     "ssm.state_resets",     # prompt rows that start at position 0: the

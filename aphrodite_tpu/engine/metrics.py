@@ -243,6 +243,16 @@ _STAGE_COUNTERS = [
      "the layer holds and computes, counted by a model that may hold "
      "a share of its experts (the rest is left out, not stood in for).",
      lambda s, c: c["moe.pairs_held"]),
+    ("aphrodite:moe_rows_walked_total",
+     "Rows of the row tiles the expert layers' Pallas kernels visited "
+     "(ops/pallas/grouped_matmul.py: every tile belongs to one expert), "
+     "counted in the step programs; over aphrodite:moe_pairs_held_total "
+     "it says how much of the matmuls' work is a tile's padding.",
+     lambda s, c: c["moe.rows_walked"]),
+    ("aphrodite:moe_kernel_steps_total",
+     "Steps whose expert layers took the Pallas kernels: one TPU, "
+     "weights in bfloat16 or float32; the rest take jax.lax.ragged_dot.",
+     lambda s, c: c["moe.kernel_steps"]),
     ("aphrodite:moe_decode_experts_touched_total",
      "Of aphrodite:moe_experts_touched_total, the decode steps'.",
      lambda s, c: c["moe.decode_experts_touched"]),

@@ -1509,9 +1509,12 @@ class ModelRunner:
     def _add_step_counts(self, handle: StepHandle, counts) -> None:
         """What a step's program counted goes to the tracer; of a
         decode step, the experts touched once more, beside the most it
-        could have touched."""
+        could have touched; a step whose expert layers walked rows took
+        the Pallas kernels (only such a program counts them)."""
         for name, value in zip(self.step_counters, counts):
             self.tracer.add(name, count=int(value))
+            if name == "moe.rows_walked":
+                self.tracer.add("moe.kernel_steps")
             if name == "moe.experts_touched" and not handle.is_prompt:
                 self.tracer.add("moe.decode_experts_touched",
                                 count=int(value))

@@ -15,21 +15,32 @@ a dense masked combine:
 
 computed as batched einsum over all experts — but ONLY when experts are
 few (<= 4) or sharded over a mesh. Above that, the (token, slot) pairs
-sort by assigned expert and run GROUPED matmuls via `jax.lax.ragged_dot`
-(the TPU-native equivalent of the reference's moe_align_block_size +
-fused expert GEMM: sorting IS the alignment, the ragged group sizes ARE
-the block boundaries), costing top_k/E of the dense path's FLOPs — 4x
-fewer for Mixtral's top-2-of-8 — with no capacity dropping. Pairs reach
-the matmuls and return to their tokens by a permutation and its inverse,
-two gathers: every token has exactly top_k pairs, so the sorted rows go
-back into a [T, top_k, H] block that is summed over top_k under the
-routing weights. Group sizes are a comparison against the expert ids,
-summed. Nothing in the grouped path scatters: XLA runs a row scatter on
-the TPU one update after another (77 ns a pair at 64 experts, top 6 and
-2,048 tokens, PERF.md §6 PR 34). The dense combine remains the mesh
-path: expert-axis sharding composes with it through plain GSPMD
-annotations, whereas a sharded ragged dispatch needs an all-to-all
-token exchange (future work).
+sort by assigned expert and run GROUPED matmuls, costing top_k/E of the
+dense path's FLOPs — 4x fewer for Mixtral's top-2-of-8 — with no
+capacity dropping. What runs where:
+
+- **On one TPU, weights in bfloat16 or float32** (`takes_expert_kernel`):
+  the two Pallas kernels of `ops/pallas/grouped_matmul.py` over the
+  reference's own `moe_align_block_size` layout: an expert's group
+  starts on a multiple of the row tile, every tile belongs to one
+  expert, and an expert's three matrices cross HBM once a call; gate,
+  up and the activation are one kernel, down the other. A pair whose
+  expert is held elsewhere gets no row at all.
+- **Everywhere else** (the CPU, widths no block of columns fits):
+  three `jax.lax.ragged_dot` over the sorted rows, the sort as the
+  alignment and the ragged group sizes as the block boundaries,
+  operation for operation what it was before the kernels.
+
+Either way pairs reach the matmuls and return to their tokens by
+gathers: every token has exactly top_k pairs, so the rows go back into
+a [T, top_k, H] block that is summed over top_k under the routing
+weights. Group sizes are a comparison against the expert ids, summed.
+Nothing in the grouped path scatters: XLA runs a row scatter on the TPU
+one update after another (77 ns a pair at 64 experts, top 6 and 2,048
+tokens, PERF.md §6 PR 34). The dense combine remains the mesh path:
+expert-axis sharding composes with it through plain GSPMD annotations,
+whereas a sharded grouped dispatch needs an all-to-all token exchange
+(future work).
 """
 from __future__ import annotations
 
@@ -39,6 +50,39 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from aphrodite_tpu.common.compat import context_tp
+from aphrodite_tpu.common.utils import note_kernel_path
+from aphrodite_tpu.ops.pallas import grouped_matmul
+
+#: what a call counts into `counts`, in the tuple's order (the last
+#: only where the kernels run): `tracing.NAMES`
+COUNTED = ("moe.tokens_routed", "moe.experts_touched", "moe.pairs_held",
+           "moe.rows_walked")
+
+
+def takes_expert_kernel(sharded: bool, dtype, hidden_size: int,
+                        intermediate_size: int) -> bool:
+    """Whether the grouped path is the Pallas kernels
+    (`ops/pallas/grouped_matmul.py`): one TPU (the expert axis not
+    partitioned over a mesh, no `tp` axis in the mesh the program is
+    traced under), weights in bfloat16 or float32, widths of
+    whole lanes for which a block of columns fits VMEM. Else it is
+    `jax.lax.ragged_dot`. The one predicate: the layer dispatches by
+    it, and a model says by it which counters its step programs
+    carry."""
+    return jax.default_backend() == "tpu" and not sharded and \
+        context_tp() == 1 and \
+        jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32) and \
+        grouped_matmul.takes_shapes(hidden_size, intermediate_size, dtype)
+
+
+def sum_counts(counts: list, names: Tuple[str, ...]) -> jax.Array:
+    """What a step's expert layers counted (`counts`, a tuple a layer
+    in `COUNTED`'s order), summed over the layers: int32 `[len(names)]`
+    in `names`' order, inside the same program."""
+    return jnp.stack([sum(c[COUNTED.index(name)] for c in counts)
+                      for name in names])
 
 
 _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -140,7 +184,9 @@ class FusedMoE:
         [..., E]: the caller's, in the place of `hidden @ gate`.
         `counts`: a list that gains this call's `(token-expert pairs,
         held experts with a pair, pairs that met a held expert)`, int32
-        scalars counted in the program."""
+        scalars counted in the program; where the kernels run
+        (`kernel_counters`) also the rows of the tiles they walked, in
+        `COUNTED`'s order."""
         sharded = self.sharded
         orig_shape = hidden.shape
         x = hidden.reshape(-1, self.hidden_size)          # [T, H]
@@ -173,17 +219,30 @@ class FusedMoE:
                 axis=0, dtype=jnp.int32)                  # [E]
         if counts is not None:
             pairs = jnp.int32(top_idx.size)
-            counts.append((pairs,
-                           jnp.sum(group_sizes > 0, dtype=jnp.int32),
-                           jnp.sum(group_sizes) if share else pairs))
+            counted = (pairs,
+                       jnp.sum(group_sizes > 0, dtype=jnp.int32),
+                       jnp.sum(group_sizes) if share else pairs)
 
         if ragged:
-            out = self._ragged_ffn(params, x, top_vals, top_idx,
-                                   group_sizes,
-                                   held=held if share else None)
+            out, walked = self._ragged_ffn(params, x, top_vals, top_idx,
+                                           group_sizes,
+                                           held=held if share else None)
+            if counts is not None and walked is not None:
+                counted += (walked,)
         else:
             out = self._dense_ffn(params, x, probs, top_vals, top_idx)
+        if counts is not None:
+            counts.append(counted)
         return out.reshape(orig_shape).astype(hidden.dtype)
+
+    @property
+    def kernel_counters(self) -> Tuple[str, ...]:
+        """What a call counts beyond the first three of `COUNTED`:
+        the rows walked, where its grouped path is the Pallas kernels
+        (a model adds them to its step programs' counters)."""
+        return COUNTED[3:] if takes_expert_kernel(
+            self.sharded, self.dtype, self.hidden_size,
+            self.intermediate_size) else ()
 
     def _dense_ffn(self, params, x, probs, top_vals, top_idx):
         # Dense per-token expert weights: [T, E].
@@ -202,23 +261,50 @@ class FusedMoE:
     def _ragged_ffn(self, params, x, top_vals, top_idx, group_sizes,
                     held=None):
         """Grouped-GEMM dispatch: (token, slot) pairs sort by expert,
-        each expert's contiguous group of rows multiplies its own
-        weights (`jax.lax.ragged_dot`), and the rows return to their
-        pairs' places by the inverse permutation, where a token's
+        each expert's group of rows multiplies its own weights, and the
+        rows return to their pairs' places by a gather, where a token's
         `top_k` rows are summed under its routing weights in float32 —
-        the moe_align + fused-GEMM design, with the sort as the
-        alignment and no scatter on either side. `held` `[T, k]` (a
-        share of the experts): the pairs that have a group; the others
-        lie behind the last group, where the grouped matmuls write
-        nothing that is read."""
+        the moe_align + fused-GEMM design, with no scatter on either
+        side. `held` `[T, k]` (a share of the experts): the pairs that
+        have a group. Returns the `[T, H]` float32 sum and, where the
+        kernels ran, the rows of the tiles they walked (an int32
+        scalar; else None).
+
+        On the kernels' path (`takes_expert_kernel`) the rows are laid
+        out so that every tile of `tile` rows belongs to one expert
+        and the tiles in use are walked once (`ops/pallas/
+        grouped_matmul.py`); a pair that is not `held` has no row (its
+        `dest` is row 0, and `_combine` masks it). Else the sort is
+        the alignment, `jax.lax.ragged_dot` takes the group sizes as
+        the block boundaries, and the pairs that are not `held` lie
+        behind the last group, where the grouped matmuls write nothing
+        that is read."""
         T = x.shape[0]
-        k = self.top_k
         # Pairs in slot-major order: pair p is token p % T in slot
         # p // T, so the rows that come back split into [k, T, H] on the
         # leading axis (a [T, k, H] block would pad k to the TPU's
-        # 8-row tile and copy itself into that layout). `order[i]` is
-        # the pair in sorted row i; `dest[p]` is the sorted row of
-        # pair p.
+        # 8-row tile and copy itself into that layout).
+        if takes_expert_kernel(self.sharded, self.dtype, self.hidden_size,
+                               self.intermediate_size):
+            note_kernel_path("expert_matmul", "pallas",
+                             "grouped_ffn over tile-aligned groups")
+            # rows a tile by the pairs a held expert can expect
+            tile = grouped_matmul.row_tile(
+                top_idx.size * self.num_experts // self.routed_experts,
+                self.num_experts)
+            source, dest, tile_expert, tiles_used = \
+                grouped_matmul.aligned_layout(top_idx.T.reshape(-1),
+                                              group_sizes, tile, T)
+            rows = x.at[source].get(mode="promise_in_bounds")
+            down = grouped_matmul.grouped_ffn(
+                rows, params["w_gate"], params["w_up"], params["w_down"],
+                tile_expert, tiles_used, tile=tile, act=self.act)
+            return self._combine(down, dest, top_vals, held,
+                                 unique=held is None), tiles_used * tile
+
+        note_kernel_path("expert_matmul", "reference", "jax.lax.ragged_dot")
+        # `order[i]` is the pair in sorted row i; `dest[p]` is the
+        # sorted row of pair p.
         order = jnp.argsort(top_idx.T.reshape(-1))        # [k*T]
         dest = jnp.argsort(order)
         x_sorted = x.at[order % T].get(
@@ -230,15 +316,19 @@ class FusedMoE:
         act = (self.act(gate.astype(jnp.float32)) *
                up.astype(jnp.float32)).astype(x.dtype)
         down = jax.lax.ragged_dot(act, params["w_down"], group_sizes)
+        return self._combine(down, dest, top_vals, held, unique=True), None
 
-        # A slot's T rows come back by one gather; the k slots are
-        # weighted and added in float32 with no [k*T, H] float32 array
-        # in between.
+    def _combine(self, down, dest, top_vals, held, unique: bool):
+        """The pairs' rows of `down` back at their tokens: a slot's T
+        rows come back by one gather (`dest[p]` is the row of pair p,
+        slot-major), and the k slots are weighted and added in float32
+        with no [k*T, H] float32 array in between."""
+        T = top_vals.shape[0]
         weights = top_vals.astype(jnp.float32)
         out = jnp.zeros((T, self.hidden_size), jnp.float32)
-        for slot in range(k):
+        for slot in range(self.top_k):
             rows = down.at[dest[slot * T:(slot + 1) * T]].get(
-                unique_indices=True, mode="promise_in_bounds")
+                unique_indices=unique, mode="promise_in_bounds")
             rows = rows.astype(jnp.float32)
             if held is not None:
                 # a row of no group is whatever the matmul left there

@@ -43,7 +43,8 @@ from aphrodite_tpu.common.config import PageGroups
 from aphrodite_tpu.modeling.input_metadata import InputMetadata
 from aphrodite_tpu.modeling.layers.activation import silu_and_mul
 from aphrodite_tpu.modeling.layers.attention import PagedAttention
-from aphrodite_tpu.modeling.layers.fused_moe import FusedMoE
+from aphrodite_tpu.modeling.layers.fused_moe import (FusedMoE,
+                                                     sum_counts)
 from aphrodite_tpu.modeling.layers.layernorm import (fused_add_rms_norm,
                                                      rms_norm)
 from aphrodite_tpu.modeling.layers.linear import (ColumnParallelLinear,
@@ -240,7 +241,13 @@ class LagunaDecoderLayer:
 
 class LagunaForCausalLM:
 
-    step_counters = STEP_COUNTERS
+    @property
+    def step_counters(self) -> Tuple[str, ...]:
+        """What a step program of this model counts: `STEP_COUNTERS`,
+        and the rows its expert kernels walk where they run."""
+        return STEP_COUNTERS + next(
+            layer.moe for layer in self.layers
+            if layer.moe is not None).kernel_counters
     #: `modeling/loader.py` hands the server's longest sequence to the
     #: constructor: the rotary tables reach it and no further
     takes_max_model_len = True
@@ -315,11 +322,10 @@ class LagunaForCausalLM:
         return hidden, caches
 
     def take_step_counts(self) -> jax.Array:
-        """`STEP_COUNTERS` of the step just traced, summed over its
-        expert layers: int32 `[3]`, inside the same program."""
+        """`step_counters` of the step just traced, summed over its
+        expert layers: int32, inside the same program."""
         counts, self._counts = self._counts, []
-        return jnp.stack([sum(c[i] for c in counts)
-                          for i in range(len(STEP_COUNTERS))])
+        return sum_counts(counts, self.step_counters)
 
     def compute_logits(self, params: Params, hidden):
         head = params["model.embed_tokens"] if self.tie_word_embeddings \

@@ -33,7 +33,8 @@ from jax.sharding import PartitionSpec as P
 from aphrodite_tpu.common.config import PageGroups
 from aphrodite_tpu.modeling.input_metadata import InputMetadata
 from aphrodite_tpu.modeling.layers.attention import PagedAttention
-from aphrodite_tpu.modeling.layers.fused_moe import FusedMoE
+from aphrodite_tpu.modeling.layers.fused_moe import (FusedMoE,
+                                                     sum_counts)
 from aphrodite_tpu.modeling.layers.layernorm import (fused_add_rms_norm,
                                                      rms_norm)
 from aphrodite_tpu.modeling.layers.linear import (LinearMethod,
@@ -158,7 +159,11 @@ class SmallThinkerDecoderLayer:
 
 class SmallThinkerForCausalLM:
 
-    step_counters = STEP_COUNTERS
+    @property
+    def step_counters(self) -> Tuple[str, ...]:
+        """What a step program of this model counts: `STEP_COUNTERS`,
+        and the rows its expert kernels walk where they run."""
+        return STEP_COUNTERS + self.layers[0].moe.kernel_counters
 
     def __init__(self, config, dtype: jnp.dtype = jnp.bfloat16,
                  linear_method: Optional[LinearMethod] = None) -> None:
@@ -228,11 +233,10 @@ class SmallThinkerForCausalLM:
         return hidden, caches
 
     def take_step_counts(self) -> jax.Array:
-        """`STEP_COUNTERS` of the step just traced, summed over its
-        expert layers: int32 `[2]`, inside the same program."""
+        """`step_counters` of the step just traced, summed over its
+        expert layers: int32, inside the same program."""
         counts, self._counts = self._counts, []
-        return jnp.stack([sum(c[i] for c in counts)
-                          for i in range(len(STEP_COUNTERS))])
+        return sum_counts(counts, self.step_counters)
 
     def compute_logits(self, params: Params, hidden):
         head = params["model.embed_tokens"] if self.tie_word_embeddings \

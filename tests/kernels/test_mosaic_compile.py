@@ -757,3 +757,43 @@ def test_prefill_flash_attention_compiles(on_v5e, case):
         major % key_block == 0 and major <= flash.KEY_MAJOR
     assert Hq // Hkv * query_block * key_block * 4 <= flash.SCORE_BYTES \
         or key_block == flash.TOKEN_TILE
+
+
+#: (tokens, top_k, held experts, routed experts, hidden, width): the
+#: decode step and the prompt chunk of SmallThinker's and Laguna's
+#: cells, and Mixtral's widths, whose matrices go in blocks of columns
+EXPERT_CALLS = [(24, 6, 64, 64, 2560, 768), (2048, 6, 64, 64, 2560, 768),
+                (64, 10, 128, 256, 3072, 1024),
+                (2048, 10, 128, 256, 3072, 1024),
+                (16, 2, 8, 8, 4096, 14336), (2048, 2, 8, 8, 4096, 14336)]
+
+
+@pytest.mark.parametrize(
+    "case,dtype", [(case, BF16) for case in EXPERT_CALLS] + [
+        (EXPERT_CALLS[0], jnp.float32), (EXPERT_CALLS[3], jnp.float32)],
+    ids=lambda c: c.__name__ if hasattr(c, "__name__") else
+    "x".join(map(str, c)))
+def test_grouped_ffn_compiles(on_v5e, case, dtype):
+    """The expert layer's two kernels at the served shapes, with the
+    row tile and the aligned row count `FusedMoE` gives them: Mosaic
+    takes both under the scoped VMEM they state, and both bear a name
+    that starts as the benchmark's readers expect of the layer."""
+    from aphrodite_tpu.ops.pallas import grouped_matmul as gm
+    tokens, top_k, experts, routed, hidden, width = case
+    assert gm.takes_shapes(hidden, width, dtype)
+    pairs = tokens * top_k
+    tile = gm.row_tile(pairs * experts // routed, experts)
+    tiles = gm.num_row_tiles(pairs, experts, tile)
+    hlo = gm.grouped_ffn.lower(
+        on_v5e((tiles * tile, hidden), dtype),
+        on_v5e((experts, hidden, width), dtype),
+        on_v5e((experts, hidden, width), dtype),
+        on_v5e((experts, width, hidden), dtype), on_v5e((tiles,), I32),
+        on_v5e((), I32), tile=tile, act=jax.nn.silu).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [call.split(" = ")[0].split()[-1].lstrip("%")
+             for call in calls]
+    assert [name.rsplit(".", 1)[0] for name in names] == [
+        "ragged-dot-aligned-gate-up", "ragged-dot-aligned-down"]
+    assert all(name.startswith(gm.DEVICE_OP_PREFIXES) for name in names)
