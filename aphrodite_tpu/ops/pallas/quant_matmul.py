@@ -66,6 +66,31 @@ against the other). Composes with deferred rescale: the int32
 group accumulators ride as kernel scratch and the scale rows still
 apply once at k-flush.
 
+What a group's int8 operand is made of (W4A8, GPTQ; PERF.md §5, PR
+51): the kernels feed the MXU `code - zero` as int8, a quantization
+group (`gs` rows of K) at a time. `_unpack_planes` makes it one code
+to a 32-bit lane: eight shifted and masked nibble planes, a subtract,
+a narrowing convert, four or more VPU operations a code, which at
+decode rows outlasted the weight tile's DMA (a streamed call read
+73-78% of its bytes' roofline, and 91% with the unpack taken out).
+`_unpack_bytes` does the same integer arithmetic four codes to an
+operation: two masks leave a word's even and odd nibbles in its four
+bytes, one add of 128 - zero in every byte (`_bias_zeros`, made in the
+XLA prologue in the place of the plain zeros: the same [G, 1, N] int32
+array) and one flip of the bytes' top bits subtract the zero with no
+borrow between bytes, and `pltpu.bitcast` reads the words as int8 rows
+with no narrowing. The values are bit-equal; the ROW ORDER inside a
+group is another (row 4i + b of a plane is byte b of word-row i: the
+even columns 8i + 2b of the group, then the odd ones), and x's columns
+are laid out to match in the prologue (`_permute_columns`,
+`plane_permutation(byte_rows=True)`). 4-bit GPTQ words on the streamed
+grid alone take it (`_resolve_unpack`): other widths do not split into
+bytes this way, the W4A16 kernel's operand is bfloat16 (an int8 plane
+would be widened again), AWQ packs along lanes, and on the compiler's
+grid (prompt rows) the dots and the rescale bind, not the unpack,
+while the byte order's split of odd from even columns of the INT8
+rows costs XLA more than the kernel gains.
+
 Round-7 closures of the two machine-flagged residuals (ROADMAP item
 1): (1) the streamed grid's f32 accumulator is now TWO column-parity
 planes — the run-final flush epilogue writes the parity plane while
@@ -92,6 +117,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from aphrodite_tpu.common import flags
 from aphrodite_tpu.common.logger import init_logger
+from aphrodite_tpu.common.utils import note_kernel_path
 
 logger = init_logger(__name__)
 
@@ -116,17 +142,119 @@ def _unpack_planes(q: jax.Array, bits: int) -> jax.Array:
     return jax.lax.concatenate(planes, 0)
 
 
-def plane_permutation(K: int, block_k: int, bits: int) -> np.ndarray:
+# Byte-lane constants of `_unpack_bytes`, as the int32 they are held in.
+_NIBBLE_OF_BYTES = np.int32(0x0F0F0F0F)
+_SIGN_OF_BYTES = np.int32(0x80808080 - (1 << 32))
+
+
+def _bias_zeros(z: jax.Array) -> jax.Array:
+    """A 4-bit zero point z (1..16) as `_unpack_bytes` takes it: 128 - z
+    in each of the word's four bytes (112..127, so no byte borrows)."""
+    return _SIGN_OF_BYTES - z * 0x01010101
+
+
+def _unpack_bytes(words: jax.Array, zbias: jax.Array) -> jax.Array:
+    """[r, c] int32 GPTQ 4-bit words (8 codes of consecutive K rows a
+    word) and the group's zeros as `_bias_zeros` made them, [1, c] ->
+    [r*8, c] int8 `code - zero`: the MXU's operand, four codes to a
+    32-bit lane operation.
+
+    Two masks leave the even and the odd nibbles of a word in its four
+    BYTES. Adding 128 - zero to every byte at once carries nothing from
+    byte to byte (a code is 0..15, the sum 112..142), flipping each
+    byte's top bit takes the 128 off again in two's complement, and
+    `pltpu.bitcast` reads the [r, c] words as [4r, c] int8 with no
+    narrowing: seven operations for a word's eight codes, where
+    `_unpack_planes`, a subtract and a convert to int8 spend four or
+    more a code, each code alone in a 32-bit lane. The values are the
+    same, `(_unpack_planes(words, 4) - zero).astype(int8)`; the ROW
+    ORDER differs and `plane_permutation(..., byte_rows=True)` states
+    it: byte b of word-row i is row 4i + b of its plane (Mosaic's order
+    and interpret mode's: four int8 rows share a sublane's word), the
+    even nibbles' plane lies over the odd nibbles'. r a multiple of 8
+    keeps the seam on an int8 tile (32 rows)."""
+    shifted = jax.lax.shift_right_logical(words, 4)
+    planes = [
+        pltpu.bitcast(
+            ((half & _NIBBLE_OF_BYTES) + zbias) ^ _SIGN_OF_BYTES,
+            jnp.int8)
+        for half in (words, shifted)
+    ]
+    return jax.lax.concatenate(planes, 0)
+
+
+def plane_permutation(K: int, block_k: int, bits: int,
+                      byte_rows: bool = False) -> np.ndarray:
     """Column permutation of x matching `_unpack_planes` row order:
     within each block_k-span, position j holds original column
-    (j % r) * pack + j // r with r = block_k // pack."""
+    (j % r) * pack + j // r with r = block_k // pack.
+
+    `byte_rows` gives `_unpack_bytes`' order instead (4-bit, block_k a
+    quantisation group): position j < block_k / 2 holds the EVEN
+    nibble 2b of word i, j = 4i + b, original column 8i + 2b; the
+    second half the odd nibbles, 8i + 2b + 1."""
     pack = 32 // bits
     r = block_k // pack
     j = np.arange(block_k)
-    within = (j % r) * pack + j // r
+    if byte_rows:
+        assert bits == 4
+        half = j % (block_k // 2)
+        within = half // 4 * 8 + half % 4 * 2 + j // (block_k // 2)
+    else:
+        within = (j % r) * pack + j // r
     blocks = np.arange(0, K, block_k)[:, None]
     return (blocks + within[None, :]).reshape(-1)
 
+
+def _permute_columns(x: jax.Array, gs: int, pack: int,
+                     byte_rows: bool) -> jax.Array:
+    """x's columns in the row order of a group's unpacked codes
+    (`plane_permutation` per group of `gs`, since the kernels unpack
+    each group chunk separately). Both orders are blockwise transposes,
+    which XLA lowers natively (an explicit index gather is ~100x slower
+    here)."""
+    m, K = x.shape
+    if byte_rows:
+        # [word, byte, even | odd] -> [even | odd, word, byte]. The
+        # plain [gs / 2, 2] -> [2, gs / 2] is the same permutation and
+        # costs a decode call's bfloat16 rows 4-14 us more on the chip
+        # (PERF.md §6, PR 51: XLA lays a two-wide axis out again).
+        return x.reshape(m, K // gs, gs // 8, 4, 2).transpose(
+            0, 1, 4, 2, 3).reshape(m, K)
+    return x.reshape(m, K // gs, gs // pack, pack).swapaxes(
+        2, 3).reshape(m, K)
+
+
+def _resolve_unpack(unpack, bits: int, streamed: bool) -> str:
+    """How a W4A8 GPTQ call makes a group's int8 operand: "bytes"
+    (`_unpack_bytes`) for 4-bit words on the streamed grid, "planes"
+    (`_unpack_planes`, a subtract and a narrowing convert) for every
+    other width and on the compiler's grid (the module docstring says
+    why). Read off the call at trace time: its `bits`, and the grid its
+    `m` takes. An explicit `unpack` is the tests' and
+    `benchmarks/qmm_ab.py`'s way to hold one against the other on
+    either grid."""
+    if unpack is None:
+        return "bytes" if bits == 4 and streamed else "planes"
+    if unpack not in ("bytes", "planes") or \
+            (unpack == "bytes" and bits != 4):
+        raise ValueError(f"{unpack=} at {bits=}")
+    return unpack
+
+
+def _a8_operand(words: jax.Array, z: jax.Array, bits: int, unpack: str,
+                ablate) -> jax.Array:
+    """One group's int8 MXU operand, `code - zero` [gs, c], from its
+    packed words [gs // pack, c] and its zero row z [1, c] (plain for
+    "planes", `_bias_zeros` for "bytes"). `ablate == "unpack"` is
+    `benchmarks/qmm_ab.py`'s arm: the words' own bytes read as int8 and
+    stacked to the operand's height, wrong numbers at no VPU work."""
+    if ablate == "unpack":
+        raw = pltpu.bitcast(words, jnp.int8)
+        return jax.lax.concatenate([raw] * (8 // bits), 0)
+    if unpack == "bytes":
+        return _unpack_bytes(words, z)
+    return (_unpack_planes(words, bits) - z).astype(jnp.int8)
 
 
 def _tile_mn(m: int, N: int, dtype, min_bn: int = 128,
@@ -298,7 +426,8 @@ def _clamp_k_vmem(block_k: int, gs: int, cell_bytes, tag: str) -> int:
 
 def _stream_kernel(*refs, layout: str, bits: int, k_tiles: int,
                    n_tiles: int, group_size: int, n_slots: int,
-                   a8: bool, deferred: bool):
+                   a8: bool, deferred: bool, unpack: str = "planes",
+                   ablate=None):
     """One work item w = n * k_tiles + k of the streamed skinny-m
     grid: wait on this item's weight-tile DMAs (started n_slots-1
     cells ago by the ring), start the item n_slots-1 ahead, then
@@ -430,26 +559,37 @@ def _stream_kernel(*refs, layout: str, bits: int, k_tiles: int,
             return w_pm[g * gs:(g + 1) * gs]
         return _unpack_planes(qw_t[g * rpg:(g + 1) * rpg], bits)
 
+    def w_int8(g):
+        """Group g's int8 operand, `code - zero` (a8)."""
+        if layout == "awq":
+            return (w_codes(g) - z_ring[slot, g]).astype(jnp.int8)
+        return _a8_operand(qw_t[g * rpg:(g + 1) * rpg],
+                           z_ring[slot, g], bits, unpack, ablate)
+
     x_tile = x8_scr[k] if a8 else x_ref[k]    # [block_m, block_k]
     if a8 and deferred:
         for g in range(gpt):
-            w8 = (w_codes(g) - z_ring[slot, g]).astype(jnp.int8)
             g32_ref[g] = jax.lax.dot_general(
-                x_tile[:, g * gs:(g + 1) * gs], w8,
+                x_tile[:, g * gs:(g + 1) * gs], w_int8(g),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
-        acc_ref[par] += jnp.sum(
-            g32_ref[...].astype(jnp.float32) *
-            s_ring[slot].astype(jnp.float32), axis=0)
+        if ablate == "rescale":
+            acc_ref[par] = pltpu.bitcast(g32_ref[0], jnp.float32)
+        else:
+            acc_ref[par] += jnp.sum(
+                g32_ref[...].astype(jnp.float32) *
+                s_ring[slot].astype(jnp.float32), axis=0)
     elif a8:
         for g in range(gpt):
-            w8 = (w_codes(g) - z_ring[slot, g]).astype(jnp.int8)
             d = jax.lax.dot_general(
-                x_tile[:, g * gs:(g + 1) * gs], w8,
+                x_tile[:, g * gs:(g + 1) * gs], w_int8(g),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
-            acc_ref[par] += d.astype(jnp.float32) * \
-                s_ring[slot, g].astype(jnp.float32)
+            if ablate == "rescale":
+                acc_ref[par] = pltpu.bitcast(d, jnp.float32)
+            else:
+                acc_ref[par] += d.astype(jnp.float32) * \
+                    s_ring[slot, g].astype(jnp.float32)
     else:
         chunks = []
         for g in range(gpt):
@@ -477,7 +617,8 @@ def _stream_kernel(*refs, layout: str, bits: int, k_tiles: int,
 def _stream_call(x, qweight, z3, s3, *, layout: str, bits: int,
                  gs: int, block_m: int, block_n: int, block_k: int,
                  padded_m: int, N: int, n_slots: int, a8: bool,
-                 deferred: bool, out_dtype, interpret: bool):
+                 deferred: bool, out_dtype, interpret: bool,
+                 unpack: str = "planes", ablate=None):
     """Launch _stream_kernel: x [padded_m, K] (already permuted and
     padded; RAW model dtype even for a8 — the kernel quantizes it in
     its prologue) goes resident as [k_tiles, block_m, block_k];
@@ -534,7 +675,8 @@ def _stream_call(x, qweight, z3, s3, *, layout: str, bits: int,
         functools.partial(
             _stream_kernel, layout=layout, bits=bits,
             k_tiles=k_tiles, n_tiles=n_tiles, group_size=gs,
-            n_slots=n_slots, a8=a8, deferred=deferred),
+            n_slots=n_slots, a8=a8, deferred=deferred, unpack=unpack,
+            ablate=ablate),
         grid=(n_tiles * k_tiles,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n),
@@ -596,7 +738,7 @@ def gptq_supported(in_features: int, out_features: int, bits: int,
 def _gptq_prologue(x, qzeros, scales, N: int, bits: int, gs: int,
                    tile_dtype, k_cap: int = 0, acc_planes: int = 1,
                    stream_slots: int = 0, deferred: bool = False,
-                   a8: bool = False):
+                   a8: bool = False, byte_rows: bool = False):
     """Shared GPTQ wrapper prologue (one copy of the layout logic for
     the W4A16 and W4A8 kernels): plane-permute and pad x, unpack the
     zero points (+1, AutoGPTQ convention), lift scales to the [G, 1, N]
@@ -605,7 +747,9 @@ def _gptq_prologue(x, qzeros, scales, N: int, bits: int, gs: int,
     padded_m, grid, groups_per_tile, k_tiles). stream_slots > 0 sizes
     for the streamed work-list grid (ring slots instead of per-cell
     weight blocks); deferred adds the int32 accumulator planes to the
-    VMEM pre-check."""
+    VMEM pre-check. `byte_rows` (the W4A8 wrappers at 4 bits) lays x's
+    columns and the zeros out for `_unpack_bytes`: the same arrays of
+    the same shapes in another order and another encoding."""
     m, K = x.shape
     pack = 32 // bits
     # Tile sizes: per-grid-step overhead (~5us) dominates when tiles
@@ -623,13 +767,7 @@ def _gptq_prologue(x, qzeros, scales, N: int, bits: int, gs: int,
             K=K, stream_slots=stream_slots, deferred=deferred,
             a16=x.dtype != jnp.int8 and not a8),
         tag="gptq")
-    # Plane-order unpack (see _unpack_planes): permute x's columns to
-    # match — per GROUP, since the kernels unpack each group chunk
-    # separately. The permutation is exactly a blockwise [R, pack]
-    # transpose, which XLA lowers natively (an explicit index gather
-    # is ~100x slower here).
-    R = gs // pack
-    x = x.reshape(m, K // gs, R, pack).swapaxes(2, 3).reshape(m, K)
+    x = _permute_columns(x, gs, pack, byte_rows)
     if padded_m != m:
         x = jnp.pad(x, ((0, padded_m - m), (0, 0)))
     k_tiles = K // block_k
@@ -643,6 +781,8 @@ def _gptq_prologue(x, qzeros, scales, N: int, bits: int, gs: int,
     z_all = jax.lax.bitwise_and(
         jax.lax.shift_right_logical(qzeros[:, :, None], shifts),
         (1 << bits) - 1).reshape(qzeros.shape[0], 1, N) + 1
+    if byte_rows:
+        z_all = _bias_zeros(z_all)
     scales3 = scales[:, None, :]
     tiles = (block_m, block_n, block_k, padded_m, grid,
              groups_per_tile, k_tiles)
@@ -1270,10 +1410,12 @@ def gguf_q8_matmul(x: jax.Array, qs: jax.Array, d: jax.Array, *,
 
 def _gptq_a8_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
                     acc_ref, *, bits: int, k_tiles: int,
-                    group_size: int):
+                    group_size: int, unpack: str, ablate):
     """W4A8 tile: int8 activations into the MXU's int8 mode. Per
-    quantization group: unpack the int4 codes plane-wise, subtract the
-    zero point IN INTEGERS (codes land exactly on the int8 grid — no
+    quantization group: the codes minus their zero point as int8
+    (`_a8_operand`: `unpack` says how, plane-wise one code a lane or
+    byte-wise four, and x's columns arrive in that order; IN INTEGERS
+    either way, codes land exactly on the int8 grid — no
     requantization), one int8 x int8 -> int32 dot per group, then scale
     the int32 partials by the group's fp scale row into the f32
     accumulator. The MXU's int8 mode has 2x the bf16 throughput, which
@@ -1290,14 +1432,17 @@ def _gptq_a8_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
     rows_per_group = gs // pack
     n_groups = z_ref.shape[0]
     for g in range(n_groups):
-        q = _unpack_planes(
-            qw_ref[g * rows_per_group:(g + 1) * rows_per_group], bits)
-        w8 = (q - z_ref[g]).astype(jnp.int8)          # exact: |w|<=2^bits
+        w8 = _a8_operand(                             # exact: |w|<=2^bits
+            qw_ref[g * rows_per_group:(g + 1) * rows_per_group],
+            z_ref[g], bits, unpack, ablate)
         x8 = x_ref[:, g * gs:(g + 1) * gs]
         d = jax.lax.dot_general(x8, w8, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.int32)
-        acc_ref[...] += d.astype(jnp.float32) * \
-            s_ref[g].astype(jnp.float32)
+        if ablate == "rescale":
+            acc_ref[...] = pltpu.bitcast(d, jnp.float32)
+        else:
+            acc_ref[...] += d.astype(jnp.float32) * \
+                s_ref[g].astype(jnp.float32)
 
     @pl.when(k == k_tiles - 1)
     def _flush():
@@ -1307,7 +1452,8 @@ def _gptq_a8_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
 
 def _gptq_a8_deferred_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
                              acc_ref, g32_ref, *, bits: int,
-                             k_tiles: int, group_size: int):
+                             k_tiles: int, group_size: int,
+                             unpack: str, ablate):
     """Deferred-rescale W4A8 tile (PROFILE_r05 item 1): each group's
     int8 x int8 dot lands in its OWN int32 VMEM accumulator plane, and
     the per-group scale rows multiply the int32 partials ONCE, batched,
@@ -1332,9 +1478,9 @@ def _gptq_a8_deferred_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
     # Phase 1 — MXU: unpack + exact integer dots only; nothing touches
     # the f32 accumulator between groups.
     for g in range(n_groups):
-        q = _unpack_planes(
-            qw_ref[g * rows_per_group:(g + 1) * rows_per_group], bits)
-        w8 = (q - z_ref[g]).astype(jnp.int8)          # exact: |w|<=2^bits
+        w8 = _a8_operand(                             # exact: |w|<=2^bits
+            qw_ref[g * rows_per_group:(g + 1) * rows_per_group],
+            z_ref[g], bits, unpack, ablate)
         x8 = x_ref[:, g * gs:(g + 1) * gs]
         g32_ref[g] = jax.lax.dot_general(
             x8, w8, (((1,), (0,)), ((), ())),
@@ -1342,9 +1488,12 @@ def _gptq_a8_deferred_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
     # Phase 2 — one batched rescale at tile flush: [gpt, bm, bn] int32
     # planes times the [gpt, 1, bn] scale rows, summed over the group
     # axis into the f32 accumulator.
-    acc_ref[...] += jnp.sum(
-        g32_ref[...].astype(jnp.float32) *
-        s_ref[...].astype(jnp.float32), axis=0)
+    if ablate == "rescale":
+        acc_ref[...] = pltpu.bitcast(g32_ref[0], jnp.float32)
+    else:
+        acc_ref[...] += jnp.sum(
+            g32_ref[...].astype(jnp.float32) *
+            s_ref[...].astype(jnp.float32), axis=0)
 
     @pl.when(k == k_tiles - 1)
     def _flush():
@@ -1354,11 +1503,13 @@ def _gptq_a8_deferred_kernel(x_ref, xs_ref, qw_ref, z_ref, s_ref, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("bits", "group_size", "interpret",
-                                    "deferred", "stream"))
+                                    "deferred", "stream", "unpack",
+                                    "ablate"))
 def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
                    scales: jax.Array, *, bits: int, group_size: int,
                    interpret: bool = False,
-                   deferred=None, stream=None) -> jax.Array:
+                   deferred=None, stream=None, unpack=None,
+                   ablate=None) -> jax.Array:
     """W4A8 variant of gptq_matmul: activations quantize to int8 with a
     per-row scale (absmax) in the XLA prologue, weights stay int4 at
     rest, and the kernel runs integer dots per quantization group. The
@@ -1371,13 +1522,25 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
     compute the same integer dots and differ only in f32 summation
     order. `stream` pins the skinny-m work-list/DMA-ring grid (None =
     taken at m <= 64); the two knobs compose —
-    a streamed deferred call keeps its int32 planes in ring scratch."""
+    a streamed deferred call keeps its int32 planes in ring scratch.
+
+    A group's int8 operand is made by `_unpack_bytes` for 4-bit words
+    on the streamed grid and by `_unpack_planes` otherwise
+    (`_resolve_unpack`; `unpack` pins one: the results are bit-equal).
+    `ablate` ("unpack", "rescale") is `benchmarks/qmm_ab.py`'s: a call
+    with that stretch of the kernel's VPU work taken out, and wrong
+    numbers."""
     m, K = x.shape
     N = qweight.shape[1]
     gs = group_size if group_size != -1 else K
     pack = 32 // bits
 
     use_stream = _resolve_stream(stream, m)
+    unpack = _resolve_unpack(unpack, bits, use_stream)
+    byte_rows = unpack == "bytes"
+    note_kernel_path("w4a8_unpack", unpack,
+                     "gptq_matmul_a8, the streamed grid" if use_stream
+                     else "gptq_matmul_a8, the compiler's grid")
     n_slots = _stream_pf() if use_stream else 0
     use_def = _resolve_deferred(deferred, m)
     if use_def:
@@ -1416,7 +1579,8 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
         xq, z_all, scales3, tiles = _gptq_prologue(
             x, qzeros, scales, N, bits, gs, jnp.bfloat16, k_cap=k_cap,
             acc_planes=(bk // gs) if use_def else 1,
-            stream_slots=n_slots, deferred=use_def, a8=True)
+            stream_slots=n_slots, deferred=use_def, a8=True,
+            byte_rows=byte_rows)
         (block_m, block_n, block_k, padded_m, grid,
          groups_per_tile, k_tiles) = tiles
         out = _stream_call(
@@ -1424,14 +1588,15 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
             bits=bits, gs=gs, block_m=block_m, block_n=block_n,
             block_k=block_k, padded_m=padded_m, N=N,
             n_slots=n_slots, a8=True, deferred=use_def,
-            out_dtype=x.dtype, interpret=interpret)
+            out_dtype=x.dtype, interpret=interpret, unpack=unpack,
+            ablate=ablate)
         return out[:m] if padded_m != m else out
 
     x8, xs = quantize_activations_int8(x, interpret=interpret)
     x8, z_all, scales3, tiles = _gptq_prologue(
         x8, qzeros, scales, N, bits, gs, jnp.bfloat16, k_cap=k_cap,
         acc_planes=(bk // gs) if use_def else 1,
-        stream_slots=0, deferred=use_def)
+        stream_slots=0, deferred=use_def, byte_rows=byte_rows)
     (block_m, block_n, block_k, padded_m, grid,
      groups_per_tile, k_tiles) = tiles
     if padded_m != m:
@@ -1439,7 +1604,8 @@ def gptq_matmul_a8(x: jax.Array, qweight: jax.Array, qzeros: jax.Array,
 
     kernel = functools.partial(
         _gptq_a8_deferred_kernel if use_def else _gptq_a8_kernel,
-        bits=bits, k_tiles=k_tiles, group_size=gs)
+        bits=bits, k_tiles=k_tiles, group_size=gs, unpack=unpack,
+        ablate=ablate)
     scratch = [pltpu.VMEM((block_m, block_n), jnp.float32)]
     if use_def:
         scratch.append(

@@ -3,6 +3,8 @@
 built from `perf/configs/<config>.json` with zero weights.
 
     python benchmarks/step_ab.py jamba2-3b-bf16 [--prompts N] [--aot]
+    python benchmarks/step_ab.py mistral-7b-w4a8 --prompts 1 \
+        --prompt-len 1024
     python benchmarks/step_ab.py laguna-s-2.1-bf16 --prompts 1 \
         --prompt-len 2048
 
@@ -36,6 +38,9 @@ sys.path.insert(0, os.getcwd())
 #: (rows of a decode step, pages of the pool, context of a row, window
 #: pages a window group holds) by configuration
 CELLS = {
+    # (GPTQ int4 with int8 activations: `perf.env` and
+    # `perf.reference_quant` say so, and `_model` follows them)
+    "mistral-7b-w4a8": (48, 5077, 1216, 0),
     "jamba2-3b-bf16": (128, 60000, 1000, 0),
     "phi-4-mini-flash-bf16": (48, 40000, 2500, 33),
     "laguna-s-2.1-bf16": (64, 60000, 4400, 33),
@@ -144,6 +149,10 @@ def _routed(params):
 
 def _hf(config):
     from aphrodite_tpu.transformers_utils import configs
+    if config["model_type"] == "mistral":
+        from transformers import MistralConfig
+        return MistralConfig(**{k: v for k, v in config.items()
+                                if k != "perf"})
     cls = {"jamba": configs.JambaConfig,
            "phi4flash": configs.Phi4FlashConfig,
            "laguna": getattr(configs, "LagunaConfig", None),
@@ -155,6 +164,16 @@ def _hf(config):
 
 def _model(config, model_config):
     import jax.numpy as jnp
+    if config["model_type"] == "mistral":
+        from aphrodite_tpu.modeling.layers.quantization.gptq import (
+            GPTQConfig, GPTQLinearMethod)
+        from aphrodite_tpu.modeling.models.llama import LlamaForCausalLM
+        os.environ.update(config["perf"]["env"])    # APHRODITE_W4A8
+        quant = config["perf"]["reference_quant"]
+        return LlamaForCausalLM(
+            model_config.hf_config, jnp.bfloat16,
+            linear_method=GPTQLinearMethod(GPTQConfig(
+                quant["bits"], quant["group_size"])))
     if config["model_type"] == "jamba":
         from aphrodite_tpu.modeling.models.jamba import \
             JambaForCausalLM as cls

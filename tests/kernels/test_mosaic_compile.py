@@ -81,6 +81,39 @@ def test_prefill_w4a8_compiles(on_v5e, K, N):
                          group_size=128, stream=False).compile()
 
 
+@pytest.mark.parametrize("m", [48, 1024])
+@pytest.mark.parametrize("K,N", LAYER_SHAPES)
+def test_w4a8_byte_unpack_compiles_at_the_cells_shapes(on_v5e, m, K, N):
+    """`mistral-7b-w4a8.batch`'s eight calls with the operand from
+    `_unpack_bytes` (an int32 -> int8 `pltpu.bitcast` of the biased
+    words, two int8 planes stacked on a 32-row tile seam): the 48
+    decode rows on the streamed grid, which take it by themselves, and
+    the 1,024 prompt rows on the compiler's grid with the deferred
+    rescale, which take it from `benchmarks/qmm_ab.py` alone."""
+    from aphrodite_tpu.ops.pallas import quant_matmul as qm
+    assert qm._resolve_unpack(None, 4, streamed=m <= 64) == (
+        "bytes" if m <= 64 else "planes")
+    qm.gptq_matmul_a8.lower(*_gptq_operands(on_v5e, m, K, N), bits=4,
+                            group_size=128, unpack="bytes").compile()
+
+
+@pytest.mark.parametrize("m,bits,unpack", [
+    (48, 8, None), (1024, 8, None), (48, 4, "planes")])
+def test_w4a8_plane_unpack_still_compiles(on_v5e, m, bits, unpack):
+    """The calls that keep `_unpack_planes`: 8-bit words on either
+    grid, and the 4-bit arm `benchmarks/qmm_ab.py` holds the byte
+    unpack against on the streamed grid (4-bit prompt rows take the
+    planes by themselves: `test_prefill_w4a8_compiles`)."""
+    from aphrodite_tpu.ops.pallas import quant_matmul as qm
+    K, N = LAYER_SHAPES[0]
+    pack = 32 // bits
+    assert qm._resolve_unpack(unpack, bits, streamed=m <= 64) == "planes"
+    qm.gptq_matmul_a8.lower(
+        on_v5e((m, K), BF16), on_v5e((K // pack, N), I32),
+        on_v5e((K // 128, N // pack), I32), on_v5e((K // 128, N), BF16),
+        bits=bits, group_size=128, unpack=unpack).compile()
+
+
 #: (rows, query heads, KV heads, table width in pages, page dtype,
 #: fused KV write). The first two are a small batch; the next six the
 #: benchmark cell's decode programs (48 rows, pages of 16, the three

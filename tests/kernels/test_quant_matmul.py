@@ -62,6 +62,73 @@ def test_plane_permutation_is_permutation():
     assert perm[0] == 0 and perm[1] == 8 and perm[16] == 1
 
 
+def test_byte_permutation_is_the_prologues_transpose():
+    """`plane_permutation(byte_rows=True)`: per group, the even
+    nibbles' columns 8i + 2b at position 4i + b, the odd ones' in the
+    second half; `_permute_columns` (what the wrappers apply, a
+    blockwise transpose) is that permutation, in both orders."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import _permute_columns
+    perm = plane_permutation(512, 128, 4, byte_rows=True)
+    assert sorted(perm.tolist()) == list(range(512))
+    assert perm[:6].tolist() == [0, 2, 4, 6, 8, 10]
+    assert perm[64:68].tolist() == [1, 3, 5, 7] and perm[128] == 128
+    cols = jnp.arange(512)[None, :]
+    for byte_rows in (False, True):
+        np.testing.assert_array_equal(
+            np.asarray(_permute_columns(cols, 128, 8, byte_rows))[0],
+            plane_permutation(512, 128, 4, byte_rows=byte_rows))
+
+
+#: (zero, codes): every zero 1-16 over words that hold every code, and
+#: the bytes' extremes alone (128 + 0 - 16 = 112, 128 + 15 - 1 = 142)
+_BYTE_CASES = [(z, None) for z in range(1, 17)] + [(16, 0), (1, 15)]
+
+
+@pytest.mark.parametrize("zero,code", _BYTE_CASES)
+def test_unpack_bytes_is_unpack_planes_minus_zero(zero, code):
+    """`_unpack_bytes` against `(_unpack_planes(words) - zero).astype(
+    int8)`, row for row under the byte order's permutation: the same
+    int8 operand from seven 32-bit operations a word."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import (
+        _bias_zeros, _unpack_bytes, _unpack_planes)
+    gs, lanes = 128, 256
+    if code is None:
+        words = rs.randint(0, 2**32, (gs // 8, lanes), dtype=np.uint32)
+        # every code, in all eight nibbles of a word
+        words[0, :16] = np.arange(16, dtype=np.uint32) * 0x11111111
+    else:
+        words = np.full((gs // 8, lanes), code * 0x11111111, np.uint32)
+    words = jnp.asarray(words.view(np.int32))
+    z = jnp.full((1, lanes), zero, jnp.int32)
+    want = np.asarray(_unpack_planes(words, 4) - z)
+    assert want.min() >= -16 and want.max() <= 14
+    if code is not None:
+        assert (want == code - zero).all()
+    natural = np.empty_like(want)
+    natural[plane_permutation(gs, gs, 4)] = want
+    got = jax.jit(_unpack_bytes)(words, _bias_zeros(z))
+    assert got.dtype == jnp.int8 and got.shape == (gs, lanes)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        natural[plane_permutation(gs, gs, 4, byte_rows=True)])
+
+
+def test_the_unpack_is_chosen_by_bits_and_grid():
+    """`_resolve_unpack`: bytes for 4-bit words on the streamed grid,
+    planes for every other width and on the compiler's grid; a keyword
+    pins one, and bytes at another width is refused."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import _resolve_unpack
+    assert _resolve_unpack(None, 4, True) == "bytes"
+    assert _resolve_unpack(None, 4, False) == "planes"
+    assert _resolve_unpack(None, 8, True) == "planes"
+    assert _resolve_unpack("bytes", 4, False) == "bytes"
+    assert _resolve_unpack("planes", 4, True) == "planes"
+    with pytest.raises(ValueError):
+        _resolve_unpack("bytes", 8, True)
+    with pytest.raises(ValueError):
+        _resolve_unpack("nibbles", 4, True)
+
+
 def test_supported_gate():
     assert gptq_supported(4096, 14336, 4, 128, False)
     assert gptq_supported(4096, 4096, 8, 128, False)
@@ -201,6 +268,59 @@ def test_awq_a8_deferred_matches_dequant(m, K):
     rel_cd = np.abs(got[True] - got[False]).max() / \
         (np.abs(got[False]).max() + 1e-9)
     assert rel_cd < 1e-5, rel_cd
+
+
+@pytest.mark.parametrize("m", [1, 48, 64, 65, 1024])
+@pytest.mark.parametrize("deferred", [False, True])
+@pytest.mark.parametrize("group_size,K", [(128, 384), (-1, 256)])
+def test_gptq_a8_bytes_bit_equal_planes_compilers_grid(m, deferred,
+                                                       group_size, K):
+    """`gptq_matmul_a8` with the operand from `_unpack_bytes` against
+    the `_unpack_planes` arm (the kernel as it was before the byte
+    unpack) on the compiler's grid, both rescales: the same int8
+    values into the same int32 dots in another order of a group's
+    rows, so not one bit of the result differs."""
+    from aphrodite_tpu.ops.pallas.quant_matmul import gptq_matmul_a8
+    params, x = make_inputs(4, group_size, K, 256, m)
+    got = [np.asarray(gptq_matmul_a8(
+        x, params["qweight"], params["qzeros"], params["scales"],
+        bits=4, group_size=group_size, interpret=True, stream=False,
+        deferred=deferred, unpack=unpack))
+        for unpack in ("planes", "bytes")]
+    assert np.isfinite(got[0]).all() and np.abs(got[0]).max() > 0.1
+    np.testing.assert_array_equal(got[0], got[1])
+
+
+def test_qmm_ab_check_rehearses_on_the_cpu(monkeypatch, capsys):
+    """`benchmarks/qmm_ab.py --interpret --arms --check`: the byte arm
+    held against the plane arm bit for bit at a toy size, the
+    wrapper's prologue put back, and nothing timed off the chip."""
+    from aphrodite_tpu.ops.pallas import quant_matmul as qm
+    from benchmarks import qmm_ab
+    prologue = qm._gptq_prologue
+    monkeypatch.setattr("sys.argv", ["qmm_ab.py", "--interpret", "--arms",
+                                     "--check"])
+    qmm_ab.main()
+    said = capsys.readouterr().out
+    assert said.count("bytes == planes bit for bit: True") == 4
+    assert "False" not in said and "time " not in said
+    assert qm._gptq_prologue is prologue
+    # a call's roofline is the benchmark's count where an N is one
+    # layer's, and this K's own where two layers share it
+    import json
+    from perf.rooflines import gptq_matmul_a8 as roofline
+    with open(qmm_ab.CONFIG) as f:
+        config = json.load(f)
+    peaks = {"hbm_bytes_per_s": 819e9, "int8_ops_per_s": 393e12}
+    for rows in (48, 1024):
+        moved, computed = roofline.count(config, rows, 28672)
+        assert qmm_ab.least_seconds(peaks, rows, 4096, 28672) == \
+            pytest.approx(max(moved / 819e9, computed / 393e12))
+        both = [qmm_ab.least_seconds(peaks, rows, K, 4096)
+                for K in (4096, 14336)]
+        moved, computed = roofline.count(config, rows, 4096)
+        assert sum(both) / 2 == pytest.approx(
+            max(moved / 819e9, computed / 393e12))
 
 
 def test_deferred_resolution_and_vmem_fallback():

@@ -137,6 +137,26 @@ def test_gptq_a8_stream_parity(m, deferred, gs, K):
     assert _rel(got[False], got[True]) < 1e-4
 
 
+@pytest.mark.parametrize("m", [1, 48, 64, 65])
+@pytest.mark.parametrize("deferred", [False, True])
+@pytest.mark.parametrize("gs,K", [(128, 384), (-1, 256)])
+def test_gptq_a8_bytes_bit_equal_planes_streamed(m, deferred, gs, K):
+    """The streamed grid's W4A8 call as it is served (4-bit words: the
+    int8 operand from `_unpack_bytes`) against its `_unpack_planes`
+    arm, the kernel as it was: bit-equal, both rescales, one group a
+    tile and one group a matrix (65 rows are one m tile too, so the
+    keyword can send them down the streamed grid)."""
+    params, x = make_gptq(4, K if gs == -1 else gs, K, 256, m)
+    got = {unpack: np.asarray(gptq_matmul_a8(
+        x, params["qweight"], params["qzeros"], params["scales"],
+        bits=4, group_size=gs, interpret=True, stream=True,
+        deferred=deferred, unpack=unpack))
+        for unpack in (None, "planes", "bytes")}
+    assert np.isfinite(got[None]).all() and np.abs(got[None]).max() > 0.1
+    np.testing.assert_array_equal(got["planes"], got["bytes"])
+    np.testing.assert_array_equal(got[None], got["bytes"])
+
+
 @pytest.mark.parametrize("m", [1, 8, 64])
 @pytest.mark.parametrize("deferred", [False, True])
 @pytest.mark.parametrize("gs,K", [(128, 384), (64, 384), (128, 512)])

@@ -769,6 +769,45 @@ def main() -> int:
                 print(f"{name}: rel err {rel:.2e}")
                 if not rel < 3e-2:
                     failures.append((name, rel))
+            # The byte unpack against the plane unpack at the rows of
+            # `mistral-7b-w4a8.batch` (48 on the streamed grid, which
+            # serves it; 1,024 on the compiler's, benchmarks/qmm_ab.py's
+            # arm): the same int8 operand, so not a bit differs.
+            for m7 in (48, 1024):
+                x7 = jnp.asarray(rs.randn(m7, K7), jnp.bfloat16)
+                planes7, bytes7 = (np.asarray(gptq_matmul_a8(
+                    x7, qw7, qz7, sc7, bits=4, group_size=128,
+                    unpack=u), np.float32) for u in ("planes", "bytes"))
+                same = bool(np.array_equal(planes7, bytes7))
+                name = f"gptq_matmul_a8 K={K7} N={N7} m={m7} bytes"
+                print(f"{name} == planes bit for bit: {same}")
+                if not same:
+                    failures.append((name, "differs"))
+
+    with section("the int32 -> int8 bitcast's row order"):
+        # `_unpack_bytes` reads a [r, c] int32 plane as [4r, c] int8 and
+        # `plane_permutation(byte_rows=True)` takes byte b of word-row
+        # i for row 4i + b, as interpret mode has it: words whose bytes
+        # say their own 4i + b, read back as int8 rows.
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        word_rows = np.arange(16, dtype=np.int64)[:, None]
+        byte = np.arange(4, dtype=np.int64)[None, :]
+        words = ((4 * word_rows + byte) << (8 * byte)).sum(1)
+
+        def bitcast_kernel(w_ref, o_ref):
+            o_ref[...] = pltpu.bitcast(w_ref[...], jnp.int8)
+        rows8 = np.asarray(pl.pallas_call(
+            bitcast_kernel,
+            out_shape=jax.ShapeDtypeStruct((64, 128), jnp.int8))(
+                jnp.asarray(np.broadcast_to(
+                    words.astype(np.int32)[:, None], (16, 128)))))
+        same = rows8[:, 0].tolist() == list(range(64)) and \
+            bool((rows8 == rows8[:, :1]).all())
+        print(f"bitcast int32[16,128] -> int8[64,128]: row 4i + b holds "
+              f"byte b of word-row i: {same} ({rows8[:8, 0].tolist()}...)")
+        if not same:
+            failures.append(("bitcast row order", rows8[:, 0].tolist()))
 
     with section("awq_matmul"):
         # -- fused AWQ dequant matmul --
