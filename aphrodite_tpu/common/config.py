@@ -44,7 +44,8 @@ _DTYPE_BYTES = {"float16": 2, "bfloat16": 2, "float32": 4}
 class PageGroups:
     """Which KV page group each layer's attention belongs to.
 
-    A layer is one of five kinds:
+    A layer is one of five kinds (and, whatever its kind, its pages
+    are K/V pairs or LATENT: `latent` below):
 
     - `full`: it writes K and V and needs every key of its sequence;
     - `window`: it writes K and V and needs only the newest `window`
@@ -85,6 +86,13 @@ class PageGroups:
     stateful: bool = False
     #: tokens of a pooled group's aligned block; None: no pooled group
     pooled_window: Optional[int] = None
+    #: the LATENT page kind (multi-head latent attention): a layer's
+    #: pages are ONE array `[pages, page, lanes]`, no K/V pair and no
+    #: head axis; a token's row is its key (`ModelConfig.
+    #: get_head_size()` lanes, padded to the lane tile) and its value
+    #: is the first `latent` lanes of that row. None: K/V pairs. What
+    #: such pages are refused is `LATENT_PAGE_REFUSALS`.
+    latent: Optional[int] = None
 
     def pooled_pages(self, block_size: int) -> Tuple[int, int]:
         """(pages of a pooled group's full block, summary pages a
@@ -95,7 +103,8 @@ class PageGroups:
     @classmethod
     def of(cls, layer_kinds: List[Union[bool, str, int, None]],
            window: Optional[int], stateful: bool = False,
-           pooled_window: Optional[int] = None) -> "PageGroups":
+           pooled_window: Optional[int] = None,
+           latent: Optional[int] = None) -> "PageGroups":
         """`layer_kinds[l]`: "window" (or True), "full" (or False),
         "pooled", None for a layer that holds nothing, or the index of
         the earlier layer whose pages layer `l` reads."""
@@ -140,7 +149,7 @@ class PageGroups:
             open_group[kind] = (group, filled + 1)
         return cls(tuple(kinds), tuple(group_of), tuple(slot_of),
                    window if n_window else None, stateful,
-                   pooled_window if n_pooled else None)
+                   pooled_window if n_pooled else None, latent)
 
     @property
     def layers_per_group(self) -> int:
@@ -156,10 +165,51 @@ class PageGroups:
 
     @property
     def plain(self) -> bool:
-        """One group that lets go of nothing, and no state beside it:
-        block tables, swap, prefix pins, bursts and speculative rounds
-        as ever."""
-        return self.kinds == ("full",) and not self.stateful
+        """One group of K/V pairs that lets go of nothing, and no state
+        beside it: block tables, swap, prefix pins, bursts and
+        speculative rounds as ever."""
+        return self.kinds == ("full",) and not self.stateful and \
+            self.latent is None
+
+    @property
+    def arrays_per_page(self) -> int:
+        """Arrays a place in a group holds: a K/V pair, or the one
+        array of a latent page."""
+        return 2 if self.latent is None else 1
+
+
+#: What a model whose pages are latent (`PageGroups.latent`) is
+#: refused, by name, and where: the one list. The first four are the
+#: refusals of every model that is not `PageGroups.plain`
+#: (`BlockSpaceManager._plain_only`, `AphroditeEngine.add_request`,
+#: the engine's burst and speculative eligibility); the last three are
+#: `TPUExecutor.__init__`'s (`refuse_for_latent_pages`).
+LATENT_PAGE_REFUSALS = (
+    "preemption by swap", "the prefix cache", "bursts",
+    "speculative rounds", "kv_handoff (disagg_split)",
+    "a mesh (tp > 1)", "--kv-cache-dtype fp8|int8")
+
+
+def refuse_for_latent_pages(groups: PageGroups, disagg: bool,
+                            world_size: int, cache_dtype: str) -> None:
+    """The refusals of `LATENT_PAGE_REFUSALS` that no block table
+    decides, at engine build: the handoff and a mesh partition K/V
+    pairs by heads, and the 8-bit page types scale a pair."""
+    if groups.latent is None:
+        return
+    for refused, what, why in (
+            (disagg, LATENT_PAGE_REFUSALS[4],
+             "kv_handoff carries K/V pairs"),
+            (world_size > 1, LATENT_PAGE_REFUSALS[5],
+             "a latent page has no head axis to partition, and the "
+             "decode kernel that reads it is a single-device program"),
+            (cache_dtype != "auto", LATENT_PAGE_REFUSALS[6],
+             "the latent and its rotary key would need scales of "
+             "their own")):
+        if refused:
+            raise NotImplementedError(
+                f"{what} is not supported for a model whose KV pages "
+                f"are latent (one array a layer, no K/V pair): {why}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -338,7 +388,8 @@ class ModelConfig:
                                  getattr(cfg, "sliding_window_size", None))
         window = self.get_sliding_window()
         return PageGroups.of(
-            [window is not None] * cfg.num_hidden_layers, window)
+            [window is not None] * cfg.num_hidden_layers, window,
+            latent=getattr(cfg, "latent_value_lanes", None))
 
     def get_vocab_size(self) -> int:
         return self.hf_config.vocab_size

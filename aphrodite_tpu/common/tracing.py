@@ -109,6 +109,12 @@ NAMES = (
                             # kernels (`fused_moe.takes_expert_kernel`)
     "moe.decode_experts_touched",  # of them, the decode steps'
     "moe.decode_expert_slots",     # experts x expert layers, a decode step
+    "mla.latent_tokens_read",  # a decode step's rows' context lengths,
+                            # summed: the latent rows each layer's
+                            # absorbed decode attention reads (host)
+    "mla.prefix_tokens_expanded",  # prefix tokens a prompt step read
+                            # back from latent pages and up-projected
+                            # to K and V (a layer's; in the program)
     "ssm.state_resets",     # prompt rows that start at position 0: the
                             # step's program starts them from zeros
     "ssm.decode_rows",      # decode rows of a model with state slots

@@ -1533,6 +1533,7 @@ class AphroditeEngine:
             num_waiting_tokens=self.scheduler.waiting_prefill_tokens(),
             prefix_pinned_pages=self.scheduler.prefix_pinned_pages(),
             ssm_slots_total=slots,
+            kv_bytes_per_token=self.executor.kv_bytes_per_token,
             ssm_slots_live=slots -
             self.scheduler.block_manager.get_num_free_state_slots(),
             sheds_total=self.admission.sheds_total,

@@ -231,6 +231,19 @@ _STAGE_COUNTERS = [
      "slot while its pages were free (one each round the prompt at "
      "the head of the queue waits so).",
      lambda s, c: c["ssm.slot_waits"]),
+    ("aphrodite:mla_latent_tokens_read_total",
+     "Context lengths of the decode rows of a model whose pages are "
+     "latent (multi-head latent attention), summed over decode steps: "
+     "the latent rows every layer's absorbed decode attention reads "
+     "(host arithmetic, from what the step is given).",
+     lambda s, c: c["mla.latent_tokens_read"]),
+    ("aphrodite:mla_prefix_tokens_expanded_total",
+     "Prefix tokens that prompt steps of such a model read back from "
+     "the latent pages and up-projected to keys and values (a "
+     "layer's), counted in the step programs; over "
+     "aphrodite:prompt_tokens_total it says what chunking a prompt "
+     "costs the up-projection.",
+     lambda s, c: c["mla.prefix_tokens_expanded"]),
     ("aphrodite:moe_tokens_routed_total",
      "Token-expert pairs the expert layers computed, counted in the "
      "step programs.", lambda s, c: c["moe.tokens_routed"]),
@@ -324,6 +337,11 @@ class Metrics:
             Gauge, "aphrodite:ssm_slots_total",
             "State slots of a model that keeps recurrent state beside "
             "its KV pages (0: it keeps none).", labelnames)
+        self.gauge_kv_bytes_per_token = _get_or_create(
+            Gauge, "aphrodite:kv_cache_bytes_per_token",
+            "Bytes a token takes of the KV pool, all layers: K/V pairs "
+            "of every KV head, or the one latent row a layer of a model "
+            "with latent pages.", labelnames)
         self.gauge_ssm_slots_live = _get_or_create(
             Gauge, "aphrodite:ssm_slots_live",
             "State slots that sequences hold.", labelnames)
@@ -389,6 +407,7 @@ class Stats:
     prefix_pinned_pages: int = 0
     ssm_slots_total: int = 0
     ssm_slots_live: int = 0
+    kv_bytes_per_token: int = 0
     sheds_total: int = 0
     expired_total: int = 0
     ewma_prefill_tok_s: float = 0.0
@@ -467,6 +486,7 @@ class StatLogger:
         labeled(m.gauge_prefix_pinned).set(stats.prefix_pinned_pages)
         labeled(m.gauge_ssm_slots_total).set(stats.ssm_slots_total)
         labeled(m.gauge_ssm_slots_live).set(stats.ssm_slots_live)
+        labeled(m.gauge_kv_bytes_per_token).set(stats.kv_bytes_per_token)
         labeled(m.gauge_ewma_prefill).set(stats.ewma_prefill_tok_s)
         labeled(m.gauge_ewma_decode).set(stats.ewma_decode_tok_s)
         export(m.counter_requests_shed, stats.sheds_total)

@@ -12,6 +12,12 @@ Where the model's layers lie in several page groups
 group, not for each layer: the layers at one place of the groups share
 it, each under its own group's page ids, so one page id is the same
 bytes whichever group holds it and one free list serves them all.
+A model whose pages are LATENT (`PageGroups.latent`: multi-head
+latent attention) has ONE array for each place instead of a pair,
+`(pages,)` with `pages` `[num_pages, page_size, lanes]`: a token's row
+is its key, its value the row's first `latent` lanes; nothing is
+swapped, handed off, partitioned or quantised there
+(`common/config.py::LATENT_PAGE_REFUSALS`).
 A model that keeps recurrent state beside its pages
 (`common/config.py::StateSpec`) has, after those pairs in `kv_caches`,
 ONE tuple of state arrays for the model, `[state layers, slots + 1,
@@ -134,6 +140,8 @@ class CacheEngine:
             self.kv_scale = flags.get_float(
                 "APHRODITE_KV_SCALE", default=DEFAULT_KV_SCALE)
 
+        #: arrays a place holds: a K/V pair, or a latent page's one
+        self.arrays_per_page = cache_config.page_groups.arrays_per_page
         #: pairs of page arrays at the head of `kv_caches`
         self.num_page_pairs = len(self.kv_heads_per_layer)
         self.kv_caches: List[KVCache] = self._allocate_device() + \
@@ -158,10 +166,14 @@ class CacheEngine:
         # commit on first write — so this fails fast on absurd sizes
         # without stalling startup or the first preemption.
         self._host_pool: Optional[List[np.ndarray]] = None
-        if self.num_host_pages > 0:
+        if self.num_host_pages > 0 and self.arrays_per_page == 2:
             self._ensure_host_pool()
 
     def _ensure_host_pool(self) -> None:
+        if self.arrays_per_page != 2:
+            raise NotImplementedError(
+                "preemption by swap is not supported for a model whose "
+                "KV pages are latent: the host pool holds K/V pairs")
         if self._host_pool is None:
             self._host_pool = [
                 np.zeros((2, self.num_host_pages, self.page_size,
@@ -182,7 +194,7 @@ class CacheEngine:
                     self.mesh, kv_partition_spec(num_heads, self.mesh)))
             return z
 
-        return [(alloc(heads), alloc(heads))
+        return [tuple(alloc(heads) for _ in range(self.arrays_per_page))
                 for heads in self.kv_heads_per_layer]
 
     def _allocate_state(self) -> List[tuple]:
@@ -343,4 +355,5 @@ class CacheEngine:
         else:
             elt = 2
         per_token = total_heads * head_size * elt
-        return 2 * cache_config.block_size * per_token
+        return cache_config.page_groups.arrays_per_page * \
+            cache_config.block_size * per_token

@@ -23,7 +23,8 @@ import jax
 from aphrodite_tpu.common import faultinject, tracing
 from aphrodite_tpu.common.config import (CacheConfig, DeviceConfig,
                                          ModelConfig, ParallelConfig,
-                                         SchedulerConfig)
+                                         SchedulerConfig,
+                                         refuse_for_latent_pages)
 from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.sequence import SequenceGroupMetadata
 from aphrodite_tpu.executor.cache_engine import CacheEngine
@@ -150,6 +151,9 @@ class TPUExecutor:
         # the params, and its own runner. Colocated engines keep the
         # classic single full mesh and `prefill_runner is model_runner`.
         self.prefill_mesh = None
+        refuse_for_latent_pages(
+            cache_config.page_groups, parallel_config.disagg,
+            parallel_config.world_size, cache_config.cache_dtype)
         if parallel_config.disagg:
             if cache_config.state_spec is not None:
                 raise NotImplementedError(
@@ -339,6 +343,10 @@ class TPUExecutor:
     def _profile_and_size_cache(self) -> None:
         block_bytes = CacheEngine.get_cache_block_size(
             self.cache_config, self.model_config, self.parallel_config)
+        #: what a token takes of the pool, all layers (the engine's
+        #: `aphrodite:kv_cache_bytes_per_token`)
+        self.kv_bytes_per_token = block_bytes // \
+            self.cache_config.block_size
         if self.cache_config.num_gpu_blocks is not None:
             # Device pool explicitly sized (tests); still derive the host
             # swap pool if unset.

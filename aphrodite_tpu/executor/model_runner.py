@@ -43,7 +43,7 @@ from aphrodite_tpu.modeling.sampling_metadata import (OutputMetadata,
                                                       PersistentMetadata,
                                                       SamplingMetadata)
 from aphrodite_tpu.ops.attention import BLOCKED_FROM, count_prefill_tiles
-from aphrodite_tpu.ops.kv_cache import copy_blocks as _copy_blocks_op
+from aphrodite_tpu.ops.kv_cache import copy_pages as _copy_pages_op
 from aphrodite_tpu.ops.kv_cache import padded_head_size
 from aphrodite_tpu.ops.pallas.paged_attention import (
     build_decode_work_list, choose_pages_per_chunk, count_decode_pages,
@@ -516,9 +516,10 @@ class ModelRunner:
         return rows.at[:, 0].set(jnp.where(token < 0, fed, token))
 
     def _copy_blocks(self, kv_caches, src, dst):
+        """(a place's arrays: a K/V pair, or a latent page's one)"""
         pages = self.page_pairs
-        return [_copy_blocks_op(k, v, src, dst)
-                for (k, v) in kv_caches[:pages]] + list(kv_caches[pages:])
+        return [tuple(_copy_pages_op(array, src, dst) for array in place)
+                for place in kv_caches[:pages]] + list(kv_caches[pages:])
 
     def _copy_state(self, kv_caches, src, dst):
         pages = self.page_pairs
@@ -1200,6 +1201,9 @@ class ModelRunner:
                 at += 1 + width
             self.tracer.add("attn.page_reads_shared", count=shared)
             work, ppc = views[0].decode_work, views[0].decode_ppc
+        if self.page_groups.latent is not None:
+            self.tracer.add("mla.latent_tokens_read",
+                            count=int(np.sum(rows[:batch, 3])))
         self.tracer.add("attn.pages_fetched", count=fetched)
         self.tracer.add("attn.pages_live", count=live)
         self.tracer.add("attn.decode_steps", count=1)

@@ -115,6 +115,8 @@ class FusedMoE:
                  own_router: bool = True,
                  routed_experts: Optional[int] = None,
                  first_expert: int = 0,
+                 scoring: str = "softmax",
+                 selection_bias: bool = False,
                  dtype: jnp.dtype = jnp.bfloat16) -> None:
         self.num_experts = num_experts
         #: the router's width; `num_experts` of them are held here
@@ -133,6 +135,14 @@ class FusedMoE:
             raise ValueError(f"FusedMoE gates with one of "
                              f"{sorted(_ACTIVATIONS)}, not {activation!r}")
         self.act = _ACTIVATIONS[activation]
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError("FusedMoE scores by 'softmax' or 'sigmoid', "
+                             f"not {scoring!r}")
+        #: the router's scoring form (`route`), and whether the top-k
+        #: is taken over the scores plus a bias an expert (`e_bias`,
+        #: float32: the selection's alone, never a weight's)
+        self.scoring = scoring
+        self.selection_bias = selection_bias
         self.own_router = own_router
         self.dtype = dtype
         # Set by the loader when the expert axis is actually partitioned
@@ -152,6 +162,9 @@ class FusedMoE:
         if self.own_router:
             params["gate"] = jnp.zeros((h, self.routed_experts),
                                        dtype=self.dtype)
+        if self.selection_bias:
+            params["e_bias"] = jnp.zeros((self.routed_experts,),
+                                         dtype=jnp.float32)
         return params
 
     def specs(self) -> Dict[str, P]:
@@ -162,16 +175,30 @@ class FusedMoE:
         }
         if self.own_router:
             specs["gate"] = P(None, None)
+        if self.selection_bias:
+            specs["e_bias"] = P(None)
         return specs
 
-    def route(self, router_logits: jax.Array
+    def route(self, router_logits: jax.Array,
+              e_bias: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """`(probs [T, E], top_vals [T, k], top_idx [T, k])` of float32
         logits: the softmax over all experts, its `top_k` largest and,
         renormalised, their weights (which is the softmax over the
-        `top_k` largest logits alone). The one routing function."""
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        top_vals, top_idx = jax.lax.top_k(probs, self.top_k)  # [T, k]
+        `top_k` largest logits alone). The one routing function, in
+        its two forms: `scoring="sigmoid"` scores each expert by the
+        sigmoid of its own logit, and with `e_bias` `[E]` the `top_k`
+        are the largest of score + bias while their weights are the
+        scores WITHOUT it (the Ling/Bailing-V2 and DeepSeek-V3 gate)."""
+        if self.scoring == "sigmoid":
+            probs = jax.nn.sigmoid(router_logits)
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)
+        if e_bias is not None:
+            _, top_idx = jax.lax.top_k(probs + e_bias, self.top_k)
+            top_vals = jnp.take_along_axis(probs, top_idx, axis=-1)
+        else:
+            top_vals, top_idx = jax.lax.top_k(probs, self.top_k)  # [T, k]
         if self.renormalize:
             top_vals = top_vals / jnp.sum(top_vals, axis=-1,
                                           keepdims=True)
@@ -196,7 +223,7 @@ class FusedMoE:
                              params["gate"].astype(jnp.float32))  # [T, E]
         probs, top_vals, top_idx = self.route(
             router_logits.reshape(-1, self.routed_experts).astype(
-                jnp.float32))
+                jnp.float32), params.get("e_bias"))
         share = self.num_experts < self.routed_experts
         if share:
             if sharded:

@@ -183,10 +183,20 @@ def _yarn_linear_ramp_mask(low: float, high: float,
     return np.clip(ramp, 0, 1)
 
 
-def _yarn_get_mscale(scale: float = 1.0) -> float:
+def _yarn_get_mscale(scale: float = 1.0, mscale: float = 1.0) -> float:
     if scale <= 1:
         return 1.0
-    return 0.1 * math.log(scale) + 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def deepseek_yarn_softmax_mscale(rope_scaling: Dict[str, Any]) -> float:
+    """What DeepSeek-V2's YaRN multiplies the softmax scale by, SQUARED
+    by the caller (`mscale_all_dim` over the whole head; 1 without the
+    key): the other of its two mscales, the one on cos and sin, is
+    `get_rope`'s."""
+    all_dim = rope_scaling.get("mscale_all_dim", 0)
+    return _yarn_get_mscale(rope_scaling["factor"], all_dim) \
+        if all_dim else 1.0
 
 
 class YaRNScalingRotaryEmbedding(RotaryEmbedding):
@@ -277,7 +287,7 @@ def get_rope(
             rope = DynamicNTKScalingRotaryEmbedding(head_size, rotary_dim,
                                                     max_position, base,
                                                     is_neox_style, factor)
-        elif scaling_type == "yarn":
+        elif scaling_type in ("yarn", "deepseek_yarn"):
             original_max = rope_scaling.get(
                 "original_max_position_embeddings", max_position)
             extra = {
@@ -285,6 +295,17 @@ def get_rope(
                 if k in ("extrapolation_factor", "attn_factor", "beta_fast",
                          "beta_slow", "attention_factor")
             }
+            if scaling_type == "deepseek_yarn":
+                # DeepSeek-V2's two mscales: cos and sin carry the
+                # ratio of `mscale` to `mscale_all_dim` (1 where they
+                # are equal), the softmax scale the latter squared
+                # (`deepseek_yarn_softmax_mscale`)
+                extra["attention_factor"] = _yarn_get_mscale(
+                    factor, rope_scaling.get("mscale", 1)) / \
+                    _yarn_get_mscale(
+                        factor, rope_scaling.get("mscale_all_dim", 0)) * \
+                    rope_scaling.get("attn_factor", 1.0)
+                extra.pop("attn_factor", None)
             rope = YaRNScalingRotaryEmbedding(head_size, rotary_dim,
                                               original_max, base,
                                               is_neox_style, factor, **extra,

@@ -30,6 +30,7 @@ _MODELS = {
     "PhiForCausalLM": ("phi", "PhiForCausalLM"),
     "Phi4FlashForCausalLM": ("phi4flash", "Phi4FlashForCausalLM"),
     "Qwen2ForCausalLM": ("qwen2", "Qwen2ForCausalLM"),
+    "SarvamMLAForCausalLM": ("sarvam_mla", "SarvamMLAForCausalLM"),
     "SmallThinkerForCausalLM": ("smallthinker", "SmallThinkerForCausalLM"),
 }
 

@@ -108,6 +108,35 @@ def write_to_kv_cache(
     return (k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape))
 
 
+def write_to_latent_cache(
+    rows: jax.Array,          # [num_tokens, lanes]
+    pages: jax.Array,         # [num_pages, page_size, lanes]
+    slot_mapping: jax.Array,  # [num_tokens] int32; pad with num_slots (OOB)
+) -> jax.Array:
+    """`write_to_kv_cache` for a LATENT page (`PageGroups.latent`):
+    each token's one row into its slot of the one array. The `jnp`
+    side of the latent writers: a prompt step on the chip takes the
+    whole-page Pallas writer, a decode step the decode kernel's fused
+    write (`modeling/layers/mla.py`)."""
+    num_pages, page_size, lanes = pages.shape
+    note_kernel_path(
+        "kv_write", "reference",
+        f"XLA scatter of latent rows: backend={jax.default_backend()}")
+    flat = pages.reshape(num_pages * page_size, lanes)
+    flat = flat.at[slot_mapping, :].set(rows.astype(pages.dtype),
+                                        mode="drop")
+    return flat.reshape(pages.shape)
+
+
+def copy_pages(pages: jax.Array, src_indices: jax.Array,
+               dst_indices: jax.Array) -> jax.Array:
+    """`copy_blocks` for one array of pages (one side of a K/V pair;
+    a latent page's one): a gather and a scatter, pad pairs
+    out of range on both sides."""
+    src = jnp.take(pages, src_indices, axis=0, mode="fill", fill_value=0)
+    return pages.at[dst_indices].set(src, mode="drop")
+
+
 def copy_blocks(
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -120,13 +149,8 @@ def copy_blocks(
     as one gather + one scatter per cache side instead of a kernel launch
     per pair.
     """
-    src_k = jnp.take(k_pages, src_indices, axis=0, mode="fill",
-                     fill_value=0)
-    src_v = jnp.take(v_pages, src_indices, axis=0, mode="fill",
-                     fill_value=0)
-    k_pages = k_pages.at[dst_indices].set(src_k, mode="drop")
-    v_pages = v_pages.at[dst_indices].set(src_v, mode="drop")
-    return k_pages, v_pages
+    return (copy_pages(k_pages, src_indices, dst_indices),
+            copy_pages(v_pages, src_indices, dst_indices))
 
 
 def gather_pages(

@@ -26,6 +26,7 @@ padding no-op.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -180,23 +181,16 @@ def _prefill_write_kernel(
     # scalar prefetch
     page_ids_ref,   # [cells] int32; >= num_pages skips the cell
     valids_ref,     # [cells] int32 tokens covered (1..page_size)
-    # inputs
-    kblk_ref,       # [C * page_size, H*d] VMEM (C cells' k rows)
-    vblk_ref,
-    k_in,           # [P, S, H*d] ANY/HBM (aliased)
-    v_in,
-    # outputs (aliased)
-    k_out,
-    v_out,
-    # scratch
-    kbuf,           # [2, page_size, H*d] VMEM tail staging
-    vbuf,
-    rsem,
-    wsem,           # [C, 2]
-    *,
+    # For each of the `sides` page arrays (K and V; a latent page's
+    # one), side by side: inputs `blk` [C * page_size, H*d] VMEM (C
+    # cells' rows) and `pages_in` [P, S, H*d] ANY/HBM (aliased), then
+    # outputs `pages_out` (aliased), then scratch `buf` [2, page_size,
+    # H*d] VMEM tail staging; after them `rsem` and `wsem` [C, 2].
+    *refs,
     page_size: int,
     num_pages: int,
     pages_per_cell: int,
+    sides: int = 2,
 ):
     """Prefill page writer: each grid cell writes `pages_per_cell`
     WHOLE pages with DMAs issued STRAIGHT from the (auto-pipelined)
@@ -207,7 +201,10 @@ def _prefill_write_kernel(
     are waited before the cell ends: the input buffer is recycled two
     cells later by the pipeline, so in-flight reads from it must not
     outlive the cell."""
-    del k_in, v_in
+    blks = refs[:sides]
+    outs = refs[2 * sides:3 * sides]
+    bufs = refs[3 * sides:3 * sides + 2]    # (always two: the caller's)
+    rsem, wsem = refs[3 * sides + 2:]
     i = pl.program_id(0)
     C = pages_per_cell
 
@@ -219,10 +216,10 @@ def _prefill_write_kernel(
 
         @pl.when((pg < num_pages) & (valid >= page_size))
         def _full():
-            pltpu.make_async_copy(kblk_ref.at[rows, :], k_out.at[pg],
-                                  wsem.at[c, 0]).start()
-            pltpu.make_async_copy(vblk_ref.at[rows, :], v_out.at[pg],
-                                  wsem.at[c, 1]).start()
+            for side in range(sides):
+                pltpu.make_async_copy(blks[side].at[rows, :],
+                                      outs[side].at[pg],
+                                      wsem.at[c, side]).start()
 
         @pl.when((pg < num_pages) & (valid < page_size))
         def _partial():
@@ -232,28 +229,27 @@ def _prefill_write_kernel(
             # tails (pages_per_cell up to 16), but at most one is ever
             # in flight; the alternating slot is incidental.
             s = c % 2
-            ck = pltpu.make_async_copy(k_out.at[pg], kbuf.at[s],
-                                       rsem.at[s, 0])
-            cv = pltpu.make_async_copy(v_out.at[pg], vbuf.at[s],
-                                       rsem.at[s, 1])
-            ck.start()
-            cv.start()
-            ck.wait()
-            cv.wait()
+            reads = [pltpu.make_async_copy(outs[side].at[pg],
+                                           bufs[side].at[s],
+                                           rsem.at[s, side])
+                     for side in range(sides)]
+            for copy in reads:
+                copy.start()
+            for copy in reads:
+                copy.wait()
             riota = jax.lax.broadcasted_iota(
                 jnp.int32, (page_size, 1), 0)
-            kbuf[s] = jnp.where(riota < valid, kblk_ref[rows, :],
-                                kbuf[s])
-            vbuf[s] = jnp.where(riota < valid, vblk_ref[rows, :],
-                                vbuf[s])
-            wk = pltpu.make_async_copy(kbuf.at[s], k_out.at[pg],
-                                       wsem.at[c, 0])
-            wv = pltpu.make_async_copy(vbuf.at[s], v_out.at[pg],
-                                       wsem.at[c, 1])
-            wk.start()
-            wv.start()
-            wk.wait()
-            wv.wait()
+            for side in range(sides):
+                bufs[side][s] = jnp.where(
+                    riota < valid, blks[side][rows, :], bufs[side][s])
+            writes = [pltpu.make_async_copy(bufs[side].at[s],
+                                            outs[side].at[pg],
+                                            wsem.at[c, side])
+                      for side in range(sides)]
+            for copy in writes:
+                copy.start()
+            for copy in writes:
+                copy.wait()
 
     # Drain the full-page writebacks issued above (tail pages waited
     # inline). Re-constructed copies wait the matching semaphores.
@@ -264,17 +260,17 @@ def _prefill_write_kernel(
 
         @pl.when((pg < num_pages) & (valids_ref[cell] >= page_size))
         def _():
-            pltpu.make_async_copy(kblk_ref.at[rows, :], k_out.at[pg],
-                                  wsem.at[c, 0]).wait()
-            pltpu.make_async_copy(vblk_ref.at[rows, :], v_out.at[pg],
-                                  wsem.at[c, 1]).wait()
+            for side in range(sides):
+                pltpu.make_async_copy(blks[side].at[rows, :],
+                                      outs[side].at[pg],
+                                      wsem.at[c, side]).wait()
 
 
 def write_kv_pages_prefill(
     knew: jax.Array,      # [B * padded_len, H*d]
-    vnew: jax.Array,
+    vnew: Optional[jax.Array],
     k_pages: jax.Array,   # [num_pages, page_size, H*d]
-    v_pages: jax.Array,
+    v_pages: Optional[jax.Array],
     page_ids: jax.Array,  # [cells] int32; >= num_pages skips
     src_blocks: jax.Array,  # [cells] int32; MUST equal arange(cells)
     valids: jax.Array,    # [cells] int32 valid rows (1..page_size)
@@ -288,11 +284,18 @@ def write_kv_pages_prefill(
     cell layout guarantees this (cell i*ppp+p reads block i*ppp+p, with
     a host-side assert there); the check below only fires for EAGER
     callers (tests) — under jit the args are tracers and the caller's
-    assert is the real guard."""
+    assert is the real guard.
+
+    `vnew` and `v_pages` None: the pages are LATENT (one array a
+    layer, `common/config.py::PageGroups.latent`); `knew` holds the
+    tokens' rows and the result is the one updated array."""
     tokens, hd = knew.shape
     num_pages, page_size, _ = k_pages.shape
     cells = page_ids.shape[0]
     dtype = k_pages.dtype
+    news = [knew] if vnew is None else [knew, vnew]
+    pages = [k_pages] if v_pages is None else [k_pages, v_pages]
+    sides = len(pages)
     import numpy as _np
     try:                      # tracers (jit callers) raise here and skip
         src_np = _np.asarray(src_blocks)
@@ -311,21 +314,22 @@ def write_kv_pages_prefill(
     # workloads have deep power-of-two factors).
     C = max(c for c in (16, 8, 4, 2, 1) if cells % c == 0)
 
+    # (specs and scratch written out entry by entry for
+    # `tools/aphrocheck`'s roofline pass; a latent page's one array
+    # drops its second entries and leaves the second buffer idle)
+    in_specs = [
+        pl.BlockSpec((C * page_size, hd), lambda i, pids, vld: (i, 0)),
+        pl.BlockSpec((C * page_size, hd), lambda i, pids, vld: (i, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    if sides == 1:
+        del in_specs[1], in_specs[-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(cells // C,),
-        in_specs=[
-            pl.BlockSpec((C * page_size, hd),
-                         lambda i, pids, vld: (i, 0)),
-            pl.BlockSpec((C * page_size, hd),
-                         lambda i, pids, vld: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * sides,
         scratch_shapes=[
             pltpu.VMEM((2, page_size, hd), dtype),
             pltpu.VMEM((2, page_size, hd), dtype),
@@ -338,20 +342,19 @@ def write_kv_pages_prefill(
         page_size=page_size,
         num_pages=num_pages,
         pages_per_cell=C,
+        sides=sides,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pages.shape, dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, dtype),
-        ],
-        # inputs: 0=page_ids, 1=valids, 2=knew, 3=vnew,
-        # 4=k_pages, 5=v_pages
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, dtype)] * sides,
+        # inputs: 0=page_ids, 1=valids, then the new rows (knew, vnew),
+        # then the pages (k_pages, v_pages), each aliased to its output
+        input_output_aliases={2 + sides + side: side
+                              for side in range(sides)},
         interpret=interpret,
-    )(page_ids, valids, knew.astype(dtype),
-      vnew.astype(dtype), k_pages, v_pages)
+    )(page_ids, valids, *(new.astype(dtype) for new in news), *pages)
+    return out[0] if sides == 1 else out
 
 
 def can_use_pallas_writer(dtype, page_size: int, hd: int) -> bool:

@@ -128,6 +128,15 @@ def _mul_pow2(x, delta):
     exp_field = jax.lax.shift_right_logical(bits, 23) & 0xFF
     return jnp.where(exp_field + d > 0, shifted, 0.0)
 
+#: What a device trace calls this kernel over LATENT pages
+#: (`_paged_decode_impl`'s `latent`), by the start of the operation's
+#: name: the call is NAMED so (`pallas_call`'s `name=`), apart from the
+#: K/V-pair calls, which appear under the jitted function's name
+#: (`_paged_decode_impl`). The benchmark's reader finds the latent
+#: calls' seconds by it (`perf/layers/decode_attn_latent_roofline_pct.py`
+#: reads this constant from this file).
+LATENT_DEVICE_OP_PREFIXES = ("paged-decode-latent",)
+
 # Fused-write writeback ring depth: write n reuses slot n % _WB_SLOTS and
 # waits write n-_WB_SLOTS's DMA, so deeper rings hide more write latency.
 _WB_SLOTS = 8
@@ -327,25 +336,44 @@ def _decode_kernel_ragged(
     amla: bool = True,
     ablate: str = None,
     window: int = None,
+    latent: int = None,
 ):
     refs = list(refs)
-    q_ref, k_hbm, v_hbm = refs[:3]
-    refs = refs[3:]
-    slopes_ref = refs.pop(0) if has_alibi else None
-    if fused_write:
-        knew_ref, vnew_ref = refs[:2]
-        out_ref, kp_out, vp_out = refs[2:5]
-        scratch = refs[5:]
-        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr,
-         kwb, vwb, wbsem, wb_meta) = scratch
-        # reads and writes go through the aliased OUTPUT refs so
-        # in-place semantics hold
-        k_hbm, v_hbm = kp_out, vp_out
+    if latent is not None:
+        # A LATENT page (one array, one ring, one copy a page): every
+        # `v_*` name below is None and the values are the first
+        # `latent` lanes of the keys.
+        q_ref, k_hbm = refs[:2]
+        v_hbm = v_buf = vnew_ref = vwb = None
+        if fused_write:
+            knew_ref, out_ref, kp_out = refs[2:5]
+            (k_buf, sems, acc_scr, m_scr, l_scr, qp_scr,
+             kwb, wbsem, wb_meta) = refs[5:]
+            k_hbm = kp_out
+        else:
+            knew_ref = None
+            out_ref = refs[2]
+            (k_buf, sems, acc_scr, m_scr, l_scr, qp_scr) = refs[3:]
+            kwb = wbsem = wb_meta = None
+        slopes_ref = None
     else:
-        knew_ref = vnew_ref = None
-        out_ref = refs[0]
-        (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr) = refs[1:]
-        kwb = vwb = wbsem = wb_meta = None
+        q_ref, k_hbm, v_hbm = refs[:3]
+        refs = refs[3:]
+        slopes_ref = refs.pop(0) if has_alibi else None
+        if fused_write:
+            knew_ref, vnew_ref = refs[:2]
+            out_ref, kp_out, vp_out = refs[2:5]
+            scratch = refs[5:]
+            (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr,
+             kwb, vwb, wbsem, wb_meta) = scratch
+            # reads and writes go through the aliased OUTPUT refs so
+            # in-place semantics hold
+            k_hbm, v_hbm = kp_out, vp_out
+        else:
+            knew_ref = vnew_ref = None
+            out_ref = refs[0]
+            (k_buf, v_buf, sems, acc_scr, m_scr, l_scr, qp_scr) = refs[1:]
+            kwb = vwb = wbsem = wb_meta = None
 
     j = pl.program_id(0)
     w = pl.program_id(1)
@@ -416,9 +444,11 @@ def _decode_kernel_ragged(
                 pltpu.make_async_copy(
                     page_of(k_hbm, page_idx, j2),
                     k_buf.at[slot2, dst, :], sems.at[slot2, 0]).start()
-                pltpu.make_async_copy(
-                    page_of(v_hbm, page_idx, j2),
-                    v_buf.at[slot2, dst, :], sems.at[slot2, 1]).start()
+                if v_hbm is not None:
+                    pltpu.make_async_copy(
+                        page_of(v_hbm, page_idx, j2),
+                        v_buf.at[slot2, dst, :],
+                        sems.at[slot2, 1]).start()
             over_live_pages(live_pages(seq2, c2), start_page)
 
     def wait_cell(slot, n_live):
@@ -429,9 +459,10 @@ def _decode_kernel_ragged(
             pltpu.make_async_copy(k_buf.at[slot, dst, :],
                                   k_buf.at[slot, dst, :],
                                   sems.at[slot, 0]).wait()
-            pltpu.make_async_copy(v_buf.at[slot, dst, :],
-                                  v_buf.at[slot, dst, :],
-                                  sems.at[slot, 1]).wait()
+            if v_buf is not None:
+                pltpu.make_async_copy(v_buf.at[slot, dst, :],
+                                      v_buf.at[slot, dst, :],
+                                      sems.at[slot, 1]).wait()
 
         over_live_pages(
             n_live, lambda p: wait_on(pl.ds(p * page_size, page_size)),
@@ -446,9 +477,12 @@ def _decode_kernel_ragged(
         # item's dead pages) meets p = 0 in the PV dot, and 0 x NaN is
         # NaN: the V ring starts clean, and from then on holds only
         # zeros and copies of live pages. (Stale K only makes scores
-        # that the context mask replaces.)
+        # that the context mask replaces; a latent page's ring is its
+        # values' too.)
+        val_buf = k_buf if v_buf is None else v_buf
+
         def clean(slot2, _):
-            v_buf[slot2] = jnp.zeros(v_buf.shape[1:], v_buf.dtype)
+            val_buf[slot2] = jnp.zeros(val_buf.shape[1:], val_buf.dtype)
         jax.lax.fori_loop(0, chunk_slots, clean, None)
         # Cells 1..pf_depth have no predecessor pf_depth back; cell 0
         # seeds their loads.
@@ -528,9 +562,10 @@ def _decode_kernel_ragged(
                     pltpu.make_async_copy(
                         kwb.at[s_wb], page_of(k_hbm, pgs, pj),
                         wbsem.at[s_wb, 0]).wait()
-                    pltpu.make_async_copy(
-                        vwb.at[s_wb], page_of(v_hbm, pgs, pj),
-                        wbsem.at[s_wb, 1]).wait()
+                    if vwb is not None:
+                        pltpu.make_async_copy(
+                            vwb.at[s_wb], page_of(v_hbm, pgs, pj),
+                            wbsem.at[s_wb, 1]).wait()
 
                 pg = pl.ds(p_star * page_size, page_size)
                 rows_p = jax.lax.broadcasted_iota(
@@ -538,22 +573,27 @@ def _decode_kernel_ragged(
                 r_in_page = jax.lax.rem(r_star, page_size)
                 kq = _quantize_row(knew_ref[0, 0], k_buf.dtype,
                                    kv_scale)
-                vq = _quantize_row(vnew_ref[0, 0], v_buf.dtype,
-                                   kv_scale)
+                if vwb is not None:
+                    vq = _quantize_row(vnew_ref[0, 0], v_buf.dtype,
+                                       kv_scale)
                 kpage = jnp.where(rows_p == r_in_page, kq,
                                   k_buf[slot, pg, :])
-                vpage = jnp.where(rows_p == r_in_page, vq,
-                                  v_buf[slot, pg, :])
+                if vwb is not None:
+                    vpage = jnp.where(rows_p == r_in_page, vq,
+                                      v_buf[slot, pg, :])
                 k_buf[slot, pg, :] = kpage
-                v_buf[slot, pg, :] = vpage
+                if vwb is not None:
+                    v_buf[slot, pg, :] = vpage
                 kwb[s_wb] = kpage
-                vwb[s_wb] = vpage
+                if vwb is not None:
+                    vwb[s_wb] = vpage
                 pltpu.make_async_copy(
                     kwb.at[s_wb], page_of(k_hbm, g_star, j),
                     wbsem.at[s_wb, 0]).start()
-                pltpu.make_async_copy(
-                    vwb.at[s_wb], page_of(v_hbm, g_star, j),
-                    wbsem.at[s_wb, 1]).start()
+                if vwb is not None:
+                    pltpu.make_async_copy(
+                        vwb.at[s_wb], page_of(v_hbm, g_star, j),
+                        wbsem.at[s_wb, 1]).start()
                 wb_meta[1 + s_wb] = g_star
                 wb_meta[1 + _WB_SLOTS + s_wb] = j
                 wb_meta[0] = n + 1
@@ -598,17 +638,24 @@ def _decode_kernel_ragged(
             l_new = l_prev * corr + jnp.sum(p_exp, axis=1,
                                             keepdims=True)
 
-        v = v_buf[slot]                              # [chunk, hb*d]
+        if v_buf is None:
+            v = k[:, :latent]                        # [chunk, latent]
+        else:
+            v = v_buf[slot]                          # [chunk, hb*d]
         if v.dtype != jnp.bfloat16:                  # int8/fp8 KV dequant
             v = v.astype(jnp.bfloat16)
         pv = jax.lax.dot_general(
             p_exp.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)      # [rows, hb*d]
-        rh = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) // group
-        pv_sel = jnp.zeros((rows, d), jnp.float32)
-        for h in range(hb):
-            pv_sel = pv_sel + jnp.where(rh == h,
-                                        pv[:, h * d:(h + 1) * d], 0.0)
+        if latent is not None:
+            pv_sel = pv             # one "head": every row's own
+        else:
+            rh = jax.lax.broadcasted_iota(jnp.int32, (rows, d),
+                                          0) // group
+            pv_sel = jnp.zeros((rows, d), jnp.float32)
+            for h in range(hb):
+                pv_sel = pv_sel + jnp.where(
+                    rh == h, pv[:, h * d:(h + 1) * d], 0.0)
         if amla:
             acc_scr[...] = _mul_pow2(acc_scr[...], delta) + pv_sel
         else:
@@ -643,9 +690,10 @@ def _decode_kernel_ragged(
                     pltpu.make_async_copy(
                         kwb.at[kslot], page_of(k_hbm, pgs, pj),
                         wbsem.at[kslot, 0]).wait()
-                    pltpu.make_async_copy(
-                        vwb.at[kslot], page_of(v_hbm, pgs, pj),
-                        wbsem.at[kslot, 1]).wait()
+                    if vwb is not None:
+                        pltpu.make_async_copy(
+                            vwb.at[kslot], page_of(v_hbm, pgs, pj),
+                            wbsem.at[kslot, 1]).wait()
 
 
 def _ring_slots(pf_depth: int, chunk_tokens: int, lane_bytes: int) -> int:
@@ -662,12 +710,20 @@ def _ring_slots(pf_depth: int, chunk_tokens: int, lane_bytes: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "kv_scale", "pages_per_chunk", "pf_depth",
-                     "amla", "interpret", "ablate", "window", "hb"))
+                     "amla", "interpret", "ablate", "window", "hb",
+                     "latent"))
 def _paged_decode_impl(
     q, k_pages, v_pages, block_tables, context_lens, wi_seq, wi_chunk,
     alibi_slopes, knew, vnew, *, scale, kv_scale, pages_per_chunk,
     pf_depth, amla, interpret, ablate=None, window=None, hb=None,
+    latent=None,
 ):
+    """`latent` (static; the value lanes of a LATENT page,
+    `common/config.py::PageGroups.latent`): `k_pages` is the one array
+    a layer has, `[num_pages, page_size, head_dim]` with one "head" a
+    token; `v_pages` and `vnew` are None, a row's values are the first
+    `latent` lanes of its keys, and the result is `[batch, heads,
+    latent]`. One ring, one copy a page, one written page."""
     batch, num_q_heads, head_dim = q.shape
     num_pages, page_size, hd = k_pages.shape
     num_kv_heads = hd // head_dim
@@ -680,6 +736,10 @@ def _paged_decode_impl(
     chunk_tokens = pages_per_chunk * page_size
     fused_write = knew is not None
     lane_bytes = hb * head_dim * k_pages.dtype.itemsize
+    #: the lanes of a value and of an output row, and the page arrays
+    #: (with each its ring, its new row and its written page)
+    out_dim = head_dim if latent is None else latent
+    sides = 2 if latent is None else 1
 
     # q rows are kv-head-major, so the rows for head block j are the
     # contiguous slice [j*rows, (j+1)*rows).
@@ -705,7 +765,7 @@ def _paged_decode_impl(
         pf_depth=min(pf_depth, n_slots - 2), chunk_slots=n_slots,
         whole_lanes=n_hb == 1,
         has_alibi=alibi_slopes is not None, fused_write=fused_write,
-        amla=amla, ablate=ablate, window=window)
+        amla=amla, ablate=ablate, window=window, latent=latent)
 
     def qmap(j, w, tbl, cl, ws, wc):
         return (ws[w], j, 0, 0)
@@ -720,6 +780,8 @@ def _paged_decode_impl(
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     inputs = prefetch + [q_blocked, k_pages, v_pages]
+    if latent is not None:
+        del in_specs[-1], inputs[-1]
     kp_input_idx = len(prefetch) + 1
     if alibi_slopes is not None:
         in_specs.append(pl.BlockSpec((1, rows, 128), smap))
@@ -730,24 +792,25 @@ def _paged_decode_impl(
         # The singleton axis keeps the block's last two dims equal to
         # the array's ((1, hb*d)) — a (1, 1, hb*d) block over
         # [batch, n_hb>1, hb*d] is not a legal Mosaic tiling.
-        kn = knew.reshape(batch, n_hb, 1, hb * head_dim)
-        vn = vnew.reshape(batch, n_hb, 1, hb * head_dim)
-        kn = jnp.concatenate(
-            [kn, jnp.zeros((1,) + kn.shape[1:], kn.dtype)])
-        vn = jnp.concatenate(
-            [vn, jnp.zeros((1,) + vn.shape[1:], vn.dtype)])
+        def with_dummy_row(new):
+            new = new.reshape(batch, n_hb, 1, hb * head_dim)
+            return jnp.concatenate(
+                [new, jnp.zeros((1,) + new.shape[1:], new.dtype)])
 
         def nmap(*a):
             return qmap(*a)[:2] + (0, 0)
         spec_new = pl.BlockSpec((1, 1, 1, hb * head_dim), nmap)
-        in_specs.extend([spec_new, spec_new])
-        inputs.extend([kn, vn])
+        for new in (knew, vnew)[:sides]:
+            in_specs.append(spec_new)
+            inputs.append(with_dummy_row(new))
 
+    # (written out entry by entry: `tools/aphrocheck`'s roofline pass
+    # reads the rings and their depth off this list)
     scratch = [
         pltpu.VMEM((n_slots, chunk_tokens, hb * head_dim),
                    k_pages.dtype),
         pltpu.VMEM((n_slots, chunk_tokens, hb * head_dim),
-                   v_pages.dtype),
+                   k_pages.dtype),
         pltpu.SemaphoreType.DMA((n_slots, 2)),
         pltpu.VMEM((rows, head_dim), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
@@ -755,30 +818,35 @@ def _paged_decode_impl(
         # a row's packed query, built at its first item
         pltpu.VMEM((rows, hb * head_dim), jnp.bfloat16),
     ]
-    out_shape = [jax.ShapeDtypeStruct((batch + 1, n_hb, rows, head_dim),
+    if latent is not None:
+        # one ring (the values are the keys' first lanes), and an
+        # accumulator as wide as a value
+        del scratch[1]
+        scratch[2] = pltpu.VMEM((rows, latent), jnp.float32)
+    out_shape = [jax.ShapeDtypeStruct((batch + 1, n_hb, rows, out_dim),
                                       q.dtype)]
-    out_specs = [pl.BlockSpec((1, 1, rows, head_dim), qmap)]
+    out_specs = [pl.BlockSpec((1, 1, rows, out_dim), qmap)]
     io_aliases = {}
     if fused_write:
         scratch.extend([
             pltpu.VMEM((_WB_SLOTS, page_size, hb * head_dim),
                        k_pages.dtype),
             pltpu.VMEM((_WB_SLOTS, page_size, hb * head_dim),
-                       v_pages.dtype),
+                       k_pages.dtype),
             pltpu.SemaphoreType.DMA((_WB_SLOTS, 2)),
             # SMEM write-counter + per-slot (page, head block) of the
             # outstanding writeback (see _decode_kernel_ragged).
             pltpu.SMEM((1 + 2 * _WB_SLOTS,), jnp.int32),
         ])
-        out_shape.extend([
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ])
-        out_specs.extend([pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)])
+        if latent is not None:
+            del scratch[-3]     # one written page a row
+        out_shape.extend([jax.ShapeDtypeStruct(k_pages.shape,
+                                               k_pages.dtype)] * sides)
+        out_specs.extend([pl.BlockSpec(memory_space=pl.ANY)] * sides)
         # Flattened input indices of k_pages/v_pages alias kernel
         # outputs 1/2 (after the four scalar-prefetch inputs and q).
-        io_aliases = {kp_input_idx: 1, kp_input_idx + 1: 2}
+        io_aliases = {kp_input_idx + side: 1 + side
+                      for side in range(sides)}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -793,12 +861,12 @@ def _paged_decode_impl(
         out_shape=out_shape if fused_write else out_shape[0],
         input_output_aliases=io_aliases,
         interpret=interpret,
+        **({} if latent is None else {"name": LATENT_DEVICE_OP_PREFIXES[0]}),
     )(*inputs)
     if fused_write:
-        out, kp, vp = result
-        return (out[:batch].reshape(batch, num_q_heads, head_dim),
-                kp, vp)
-    return result[:batch].reshape(batch, num_q_heads, head_dim)
+        out, *pages = result
+        return (out[:batch].reshape(batch, num_q_heads, out_dim), *pages)
+    return result[:batch].reshape(batch, num_q_heads, out_dim)
 
 
 def paged_decode_attention(
@@ -820,6 +888,7 @@ def paged_decode_attention(
     hb: int = None,           # benchmarks/attn_ab.py: pin the head block
     interpret: bool = False,
     window: int = None,       # attend over the newest `window` keys only
+    latent: int = None,       # LATENT pages: the value lanes (below)
 ):
     """Token-major flash-decoding attention (see module docstring).
 
@@ -852,6 +921,13 @@ def paged_decode_attention(
     multiply, kept as the reference that
     tests/kernels/test_amla_attention.py holds the adds bit-equal to.
 
+    `latent`: the pages are LATENT (`PageGroups.latent`): `k_pages`
+    `[num_pages, page_size, head_dim]` is the one array a layer has,
+    one "head" a token under every query row; `v_pages` and `vnew` are
+    None, a token's value is the first `latent` lanes of its key, and
+    the result is `[batch, Hq, latent]` (with `knew`, `(attn_out,
+    k_pages)`). A page is copied once.
+
     `window`: a row attends over positions `ctx - window` to `ctx - 1`
     of its table only (a causal window of `window` keys, its own
     among them). The caller's table starts at the page that holds the
@@ -866,6 +942,14 @@ def paged_decode_attention(
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=}")
     if hb is not None and (hb < 1 or num_kv_heads % hb != 0):
         raise ValueError(f"{hb=} does not divide {num_kv_heads=}")
+    if latent is not None and (
+            num_kv_heads != 1 or v_pages is not None or vnew is not None
+            or alibi_slopes is not None or not 0 < latent <= head_dim
+            or latent % 128):
+        raise ValueError(
+            "latent pages are one array of one head a token, their "
+            f"values whole lane tiles of it: {latent=}, {head_dim=}, "
+            f"{num_kv_heads=}")
     pages_per_seq = block_tables.shape[1]
     pf_depth = _pf_depth()      # call-time env read + validation
     if pages_per_chunk < 1:
@@ -886,4 +970,4 @@ def paged_decode_attention(
         wi_chunk, alibi_slopes, knew, vnew, scale=scale,
         kv_scale=kv_scale, pages_per_chunk=pages_per_chunk,
         pf_depth=pf_depth, amla=bool(amla), interpret=interpret,
-        ablate=ablate, window=window, hb=hb)
+        ablate=ablate, window=window, hb=hb, latent=latent)

@@ -97,15 +97,16 @@ BlockAllocator = BlockPool
 
 
 class PageGroupsUnsupported(RuntimeError):
-    """What a model with a window, with several page groups or with
-    recurrent state beside its pages is refused, rather than served
-    half-right."""
+    """What a model with a window, with several page groups, with
+    recurrent state beside its pages or with latent pages is refused,
+    rather than served half-right."""
 
     def __init__(self, what: str, instead: str) -> None:
         super().__init__(
             f"{what} is not supported for a model whose KV pages are "
-            "in window groups or in more than one group, or that keeps "
-            f"recurrent state beside them: {instead}")
+            "in window groups or in more than one group, that keeps "
+            "recurrent state beside them, or whose pages are latent: "
+            f"{instead}")
 
 
 class AllocStatus(enum.Enum):
@@ -130,8 +131,11 @@ class BlockSpaceManager:
         num_state_slots: Optional[int] = None,
         tracer: Optional[tracing.Tracer] = None,
         pooled_window: Optional[int] = None,
+        latent: bool = False,
     ) -> None:
-        """`group_kinds`: "full", "window" or "pooled" for each page
+        """`latent`: the pages are latent (`PageGroups.latent`): ids
+        are counted as ever, and what follows K/V pairs alone is
+        refused. `group_kinds`: "full", "window" or "pooled" for each page
         group (one group by default: a window group where
         `sliding_window` is set). `pooled_window`: the tokens of a
         pooled group's aligned window, a multiple of the page squared. `max_chunk_tokens`: the longest prompt chunk the
@@ -150,7 +154,7 @@ class BlockSpaceManager:
         #: one full group: block tables, swap, prefix pins and
         #: look-ahead reservations as ever
         self.plain = self.group_kinds == ("full",) and \
-            num_state_slots is None
+            num_state_slots is None and not latent
         self.sliding_window = sliding_window
         if "window" in self.group_kinds and not sliding_window:
             raise ValueError("a window page group needs sliding_window")

@@ -175,7 +175,8 @@ class Scheduler:
             group_kinds=groups.kinds,
             max_chunk_tokens=self.window_chunk_cap,
             num_state_slots=cache_config.num_state_slots,
-            tracer=self.tracer, pooled_window=groups.pooled_window)
+            tracer=self.tracer, pooled_window=groups.pooled_window,
+            latent=groups.latent is not None)
         #: the most sequences admitted: a model with recurrent state
         #: has a slot for each, forks included
         self.max_num_seqs = min(

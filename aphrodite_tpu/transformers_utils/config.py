@@ -37,7 +37,8 @@ def get_config(model: str,
                "phi4flash": configs.Phi4FlashConfig,
                "jamba": configs.JambaConfig,
                "laguna": configs.LagunaConfig,
-               "evabyte": configs.EvaByteConfig}.get(declared)
+               "evabyte": configs.EvaByteConfig,
+               "sarvam_mla": configs.SarvamMLAConfig}.get(declared)
         if cls is not None:
             return cls.from_pretrained(model, revision=revision)
     try:

@@ -8,10 +8,12 @@ from aphrodite_tpu.transformers_utils.configs.laguna import LagunaConfig
 from aphrodite_tpu.transformers_utils.configs.phi4flash import (
     Phi4FlashConfig)
 from aphrodite_tpu.transformers_utils.configs.qwen import QWenConfig
+from aphrodite_tpu.transformers_utils.configs.sarvam_mla import (
+    SarvamMLAConfig)
 from aphrodite_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
 from aphrodite_tpu.transformers_utils.configs.yi import YiConfig
 
 __all__ = ["EvaByteConfig", "JambaConfig", "LagunaConfig",
-           "Phi4FlashConfig", "QWenConfig", "SmallThinkerConfig",
-           "YiConfig"]
+           "Phi4FlashConfig", "QWenConfig", "SarvamMLAConfig",
+           "SmallThinkerConfig", "YiConfig"]

@@ -47,6 +47,8 @@ CELLS = {
     # (a pooled group: two windows behind as 16 summary pages, and the
     # pages of the third up to the context)
     "evabyte-6.5b-bf16": (24, 4780, 6000, 0),
+    # (latent pages: ONE array a layer of 640-lane rows)
+    "sarvam-105b-bf16": (64, 80000, 8900, 0),
 }
 SLOTS = 128
 
@@ -66,7 +68,8 @@ def build(name: str, prompts: int, abstract, prompt_len: int = 512):
     with open(os.path.join("perf", "configs", name + ".json")) as f:
         config = json.load(f)
     rows, pages, ctx, window_pages = CELLS[name]
-    model_config = ModelConfig("x", dtype="bfloat16", max_model_len=8192,
+    max_len = 8192 if ctx <= 8192 else 9216
+    model_config = ModelConfig("x", dtype="bfloat16", max_model_len=max_len,
                                hf_config=_hf(config))
     model = _model(config, model_config)
     shapes = jax.eval_shape(model.init_params)
@@ -74,20 +77,23 @@ def build(name: str, prompts: int, abstract, prompt_len: int = 512):
         if abstract is not None else _routed(jax.jit(model.init_params)())
     make = (lambda s, d: abstract(jax.ShapeDtypeStruct(s, d))) \
         if abstract is not None else jnp.zeros
-    runner = ModelRunner(model, params, model_config,
-                         SchedulerConfig(None, SLOTS, 8192, 256), 16,
-                         pages * 16, num_state_slots=SLOTS)
     groups = model_config.get_page_groups()
+    runner = ModelRunner(model, params, model_config,
+                         SchedulerConfig(None, SLOTS, max_len, 256), 16,
+                         pages * 16, num_state_slots=SLOTS)
     spec = model_config.get_state_spec()
     rng = np.random.default_rng(0)
 
     def table(count):
         return [int(p) for p in rng.integers(0, pages, count)]
 
-    # (K and V each an array of its own: the step donates them)
-    kv = [tuple(make((pages, 16, h * model_config.get_head_size()),
-                     jnp.bfloat16) for _ in range(2))
-          for h in model_config.get_kv_heads_per_slot()]
+    # (K and V each an array of its own: the step donates them; a
+    # latent page's one array, its rows padded to the lane tile)
+    from aphrodite_tpu.ops.kv_cache import padded_head_size
+    kv = [tuple(make((pages, 16, h * padded_head_size(
+        model_config.get_head_size())), jnp.bfloat16)
+        for _ in range(getattr(groups, "arrays_per_page", 2)))
+        for h in model_config.get_kv_heads_per_slot()]
     if spec is None:        # (pages alone)
         pass
     elif hasattr(spec, "allocated"):
@@ -157,6 +163,7 @@ def _hf(config):
            "phi4flash": configs.Phi4FlashConfig,
            "laguna": getattr(configs, "LagunaConfig", None),
            "evabyte": getattr(configs, "EvaByteConfig", None),
+           "sarvam_mla": getattr(configs, "SarvamMLAConfig", None),
            }[config["model_type"]]
     return cls(**{k: v for k, v in config.items() if k not in (
         "perf", "architectures", "model_type", "torch_dtype")})
@@ -181,6 +188,12 @@ def _model(config, model_config):
         from aphrodite_tpu.modeling.models.laguna import LagunaForCausalLM
         return LagunaForCausalLM(model_config.hf_config, jnp.bfloat16,
                                  max_model_len=model_config.max_model_len)
+    elif config["model_type"] == "sarvam_mla":
+        from aphrodite_tpu.modeling.models.sarvam_mla import (
+            SarvamMLAForCausalLM)
+        return SarvamMLAForCausalLM(
+            model_config.hf_config, jnp.bfloat16,
+            max_model_len=model_config.max_model_len)
     elif config["model_type"] == "evabyte":
         from aphrodite_tpu.modeling.models.evabyte import \
             EvaByteForCausalLM as cls

@@ -2,7 +2,8 @@
 the StableHLO of the REAL `ModelRunner._step` at a toy size, a prompt
 step and a decode step, float32 and bfloat16, for the five models the
 benchmark had before PR 48 (Mistral, SmallThinker, Phi-4-mini-flash,
-Jamba and, in a tree that has it, Laguna), in the tree given:
+Jamba and, in a tree that has it, Laguna) and, in a tree that has it,
+Sarvam MLA (PR 52: latent pages), in the tree given:
 
     python benchmarks/step_hlo_hash.py <root of a tree> > a.txt
     python benchmarks/step_hlo_hash.py <root of its parent> > b.txt
@@ -50,6 +51,13 @@ def hf(arch):
             layer_types=kinds, mlp_layer_types=["dense"] + ["sparse"] * 3, gating_types=["per_head"] * 4,
             num_attention_heads_per_layer=[12, 18, 18, 12], moe_routed_scaling_factor=2.5)
         c.architectures = ["LagunaForCausalLM"]; return c
+    if arch == "sarvam":
+        c = configs.SarvamMLAConfig(vocab_size=256, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=3, num_attention_heads=4, kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
+            q_head_dim=48, v_head_dim=32, head_dim=144, max_position_embeddings=256, num_experts=4, num_routed_experts=16,
+            first_held_expert=4, num_experts_per_tok=4, rope_scaling={"type": "deepseek_yarn", "factor": 8,
+                "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 32})
+        c.architectures = ["SarvamMLAForCausalLM"]; return c
     if arch == "phi4flash":
         c = configs.Phi4FlashConfig(vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=8,
             num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512, sliding_window=32, mamba_d_state=8)
@@ -70,7 +78,7 @@ def programs(arch, dtype):
     SLOTS, pages = 8, 64
     runner = ModelRunner(model, params, mc, SchedulerConfig(None, SLOTS, 256, 256), 16, pages * 16,
                          num_state_slots=SLOTS if spec else None)
-    kv = [tuple(jax.ShapeDtypeStruct((pages, 16, h * __import__("aphrodite_tpu.ops.kv_cache", fromlist=["x"]).padded_head_size(mc.get_head_size())), jnp.dtype(dtype)) for _ in range(2))
+    kv = [tuple(jax.ShapeDtypeStruct((pages, 16, h * __import__("aphrodite_tpu.ops.kv_cache", fromlist=["x"]).padded_head_size(mc.get_head_size())), jnp.dtype(dtype)) for _ in range(getattr(groups, "arrays_per_page", 2)))
           for h in mc.get_kv_heads_per_slot()]
     if spec is not None:
         kv.append(tuple(jax.ShapeDtypeStruct((spec.layers, SLOTS + 1) + s, jnp.dtype(d)) for s, d in spec.allocated))
@@ -91,7 +99,8 @@ def programs(arch, dtype):
     return out
 
 for arch in ("mistral", "smallthinker", "phi4flash", "jamba") + (
-        ("laguna",) if hasattr(configs, "LagunaConfig") else ()):
+        ("laguna",) if hasattr(configs, "LagunaConfig") else ()) + (
+        ("sarvam",) if hasattr(configs, "SarvamMLAConfig") else ()):
     for dtype in ("float32", "bfloat16"):
         for name, text in programs(arch, dtype).items():
             print(arch, dtype, name, hashlib.sha256(text.encode()).hexdigest()[:16], len(text))

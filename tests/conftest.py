@@ -124,10 +124,10 @@ LATER_METRICS = (
 #: pins the manifest is spared
 NEWER_METRICS = ("prompt_dispatch_late_pct.batch",)
 #: cells appended since the pinning tests were written, oldest first
-#: (PR 41's, PR 43's, PR 48's), each with its configuration and the
-#: metrics it alone reports
+#: (PR 41's, PR 43's, PR 48's, PR 52's), each with its configuration
+#: and the metrics it alone reports
 NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k",
-               "evabyte-6.5b-bf16.doc-5k")
+               "evabyte-6.5b-bf16.doc-5k", "sarvam-105b-bf16.doc-8k")
 #: the modules that hold the manifest to a count, a set or its last
 #: places -> (the cells, the metrics) appended after what each holds
 _PINNED = {
@@ -135,10 +135,16 @@ _PINNED = {
     "test_perf_phi4flash": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
     "test_perf_jamba": (NEWER_CELLS[1:], NEWER_METRICS),
     "test_perf_laguna": (NEWER_CELLS[2:], NEWER_METRICS),
+    "test_perf_evabyte": (NEWER_CELLS[3:], ()),
 }
-#: the module that holds PR 38's six to the manifest's last places and
-#: to the list of cells, and reads `BENCHMARK.json` with `json.load`
-_PINNED_BY_FILE = "test_perf_host_lead"
+#: the modules that hold the manifest's last places or a list's length
+#: to their own and read `BENCHMARK.json` with `json.load` (PR 38's six
+#: and three cells; PR 51's two shares and six cells) -> what was
+#: appended after what each holds
+_PINNED_BY_FILE = {
+    "test_perf_host_lead": (NEWER_CELLS, NEWER_METRICS),
+    "test_perf_w4a8_share": (NEWER_CELLS[3:], ()),
+}
 
 
 @functools.lru_cache(maxsize=None)
@@ -169,13 +175,16 @@ def _before(bench: dict, cells=NEWER_CELLS, metrics=NEWER_METRICS) -> dict:
 
 class _JsonBefore:
     """`json`, for a module that loads `BENCHMARK.json` itself: the
-    manifest comes back without `NEWER_CELLS` and `NEWER_METRICS`,
-    anything else as it is."""
+    manifest comes back without the `cells` and `metrics` appended
+    after what the module holds, anything else as it is."""
+
+    def __init__(self, cells=NEWER_CELLS, metrics=NEWER_METRICS):
+        self._later = (cells, metrics)
 
     def load(self, f, **kwargs):
         data = json.load(f, **kwargs)
         if isinstance(data, dict) and {"workloads", "per_layer"} <= set(data):
-            return _before(data)
+            return _before(data, *self._later)
         return data
 
     def __getattr__(self, name):
@@ -209,8 +218,9 @@ def _the_manifest_without_later_metrics(request, monkeypatch):
     `tests/perf/test_perf_prompt_late.py`)."""
     module = request.module
     name = module.__name__.rsplit(".", 1)[-1]
-    if name == _PINNED_BY_FILE:
-        monkeypatch.setattr(module, "json", _JsonBefore())
+    if name in _PINNED_BY_FILE:
+        monkeypatch.setattr(module, "json",
+                            _JsonBefore(*_PINNED_BY_FILE[name]))
         return
     if name not in _PINNED:
         return

@@ -830,3 +830,94 @@ def test_grouped_ffn_compiles(on_v5e, case, dtype):
     assert [name.rsplit(".", 1)[0] for name in names] == [
         "ragged-dot-aligned-gate-up", "ragged-dot-aligned-down"]
     assert all(name.startswith(gm.DEVICE_OP_PREFIXES) for name in names)
+
+
+#: Sarvam-105B's LATENT pages (`PageGroups.latent`): ONE array a
+#: layer, a token's row `[c 512 | k_r 64]` padded to 640 lanes under 64
+#: query rows, the values its first 512 lanes; 512-token items, tables
+#: 576 pages wide (contexts of 8,193-9,216 tokens). (rows, fused
+#: write): the cell's decode buckets, the canary's one row.
+LATENT_CASES = [(rows, True) for rows in (1, 4, 16, 48, 64)] + [
+    (64, False)]
+
+
+@pytest.mark.parametrize("B,fused", LATENT_CASES)
+def test_decode_attention_compiles_at_sarvam_shapes(on_v5e, B, fused):
+    """The decode kernel with `latent`: Mosaic takes it, the call
+    bears the name the benchmark's reader finds it by, and it has ONE
+    page operand (no second array of the pool's shape goes in)."""
+    import re
+    from aphrodite_tpu.ops.pallas import paged_attention as pa
+    Hq, lanes, latent, page, pps = 64, 640, 512, 16, 576
+    assert pa.head_block(1, lanes, BF16) == 1
+    ppc = pa.choose_pages_per_chunk(pps, page,
+                                    pa.lane_bytes_of(1, lanes, BF16))
+    assert ppc == 32
+    counts = [pps - 8 - i % 2 for i in range(B)]
+    items = sum(-(-n // ppc) for n in counts)
+    work = pa.build_decode_work_list(
+        counts, ppc, pad_to=pa.padded_work_length(items, B, pps, ppc))
+    # 1.6 GB a layer: 80,000 pages of 20 KB
+    pages = on_v5e((80000, page, lanes), BF16)
+
+    def attend(q, latent_pages, tables, ctx, row):
+        return pa.paged_decode_attention(
+            q, latent_pages, None, tables, ctx, None,
+            row if fused else None, None, scale=0.135,
+            pages_per_chunk=ppc, work_items=work, latent=latent)
+
+    hlo = jax.jit(attend, donate_argnums=(1,) if fused else ()).lower(
+        on_v5e((B, Hq, lanes), BF16), pages, on_v5e((B, pps), I32),
+        on_v5e((B,), I32), on_v5e((B, 1, lanes), BF16)).compile().as_text()
+    (call,) = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert call.split(" = ")[0].split()[-1].lstrip("%").startswith(
+        pa.LATENT_DEVICE_OP_PREFIXES)
+    # the four lists, the queries, the ONE array of pages (and the
+    # new rows)
+    operands = re.sub(r"/\*.*?\*/", "", call.split("custom-call(")[1]
+                      .split(")")[0]).split(", ")
+    assert len(operands) == 6 + fused
+    assert sum("latent_pages" in name for name in operands) == 1
+    assert _whole_array_moves(hlo, "bf16[80000,16,640]") == []
+
+
+@pytest.mark.parametrize("tokens", [2048, 8192])
+def test_kv_writer_compiles_at_sarvam_shapes(on_v5e, tokens):
+    """The prefill page writer into ONE array of 640-lane rows: a
+    chunk of 2,048 tokens, four of them, a whole prompt of 8,192."""
+    from aphrodite_tpu.ops.pallas.kv_write import (can_use_pallas_writer,
+                                                   write_kv_pages_prefill)
+    page, lanes = 16, 640
+    assert can_use_pallas_writer(BF16, page, lanes)
+    pages = on_v5e((80000, page, lanes), BF16)
+    cells = tokens // page
+    ids = on_v5e((cells,), I32)
+
+    def write(rows, latent_pages, ids, src, valid):
+        return write_kv_pages_prefill(rows, None, latent_pages, None, ids,
+                                      src, valid)
+    hlo = jax.jit(write, donate_argnums=(1,)).lower(
+        on_v5e((cells * page, lanes), BF16), pages, ids, ids,
+        ids).compile().as_text()
+    assert _whole_array_moves(hlo, "bf16[80000,16,640]") == []
+
+
+@pytest.mark.parametrize("rows,s,kv", [(1, 8192, 8192), (4, 2048, 2048),
+                                       (1, 2048, 9216), (4, 2048, 9216)],
+                         ids=["whole-prompt", "chunk-1-4-rows",
+                              "table-1-row", "table-4-rows"])
+def test_prefill_flash_attention_compiles_at_sarvam_shapes(on_v5e, rows, s,
+                                                          kv):
+    """The prompt's flash kernel at 64 heads of 192 + 64 pad lanes
+    over up-projected latent rows (`modeling/layers/mla.py`): one KV
+    head a query head, K and V `[rows, keys, 64, 256]`."""
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    H, d = 64, 256
+
+    def attend(q, k, v, ctx, valid):
+        return flash.prefill_flash_attention(q, k, v, ctx, valid, 0.135)
+    jax.jit(attend).lower(
+        on_v5e((rows, s, H, d), BF16), on_v5e((rows, kv, H, d), BF16),
+        on_v5e((rows, kv, H, d), BF16), on_v5e((rows,), I32),
+        on_v5e((rows,), I32)).compile()

@@ -370,6 +370,93 @@ def main() -> int:
               np.asarray(kv9[0], np.float32)[untouched],
               np.asarray(sk9, np.float32)[untouched], tol=1e-6)
 
+    with section("Sarvam: latent pages, one array a layer, 64 query rows"):
+        # Sarvam-105B's page: [c 512 | k_r 64] padded to 640 lanes, ONE
+        # array, the values its first 512 lanes. The kernel with
+        # `latent` against the jnp path over the array as K and as V:
+        # NaN in every page no row holds, the fused write of the one
+        # row, 64 rows at contexts of 8k-9k in a table 576 wide; and
+        # the prompt writer over one array.
+        from aphrodite_tpu.ops.kv_cache import write_to_latent_cache
+        from aphrodite_tpu.ops.pallas.kv_write import write_kv_pages_prefill
+        from aphrodite_tpu.ops.pallas.paged_attention import (
+            build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
+            padded_work_length)
+        lanes, latent, heads, page, width, rows = 640, 512, 64, 16, 576, 64
+        scale = 192 ** -0.5 * 1.3689 ** 2
+        ppc = choose_pages_per_chunk(
+            width, page, lane_bytes_of(1, lanes, jnp.bfloat16))
+        srng = np.random.default_rng(52)
+        ctx = np.array([8193 + (i * 131) % 1024 for i in range(rows)],
+                       np.int32)
+        ctx[5], ctx[11], ctx[40] = 0, 1, 513
+        counts = -(-ctx // page)
+        pool = 1 + int(counts.sum())
+        perm = srng.permutation(pool - 1) + 1
+        bt = np.zeros((rows, width), np.int32)
+        at = 0
+        for b, n in enumerate(counts):
+            bt[b, :n] = perm[at:at + n]
+            at += n
+        dead = np.ones(pool + 4, bool)
+        dead[perm] = False
+        raw = (srng.normal(size=(pool + 4, page, lanes)) * 0.3).astype(
+            np.float32)
+        raw[..., 576:] = 0.0
+        raw[dead] = np.nan
+        pages = jnp.asarray(raw, jnp.bfloat16)
+        q = srng.normal(size=(rows, heads, lanes)) * 0.3
+        q[..., 576:] = 0.0
+        q = jnp.asarray(q, jnp.bfloat16)
+        row = srng.normal(size=(rows, lanes)) * 0.3
+        row[..., 576:] = 0.0
+        row = jnp.asarray(row, jnp.bfloat16)
+        slots = np.where(
+            ctx > 0, bt[np.arange(rows), np.maximum(ctx - 1, 0) // page]
+            * page + (ctx - 1) % page, pages.shape[0] * page)
+        want_pages = write_to_latent_cache(row, pages,
+                                           jnp.asarray(slots, jnp.int32))
+        clean = jnp.where(jnp.asarray(dead)[:, None, None], 0, want_pages)
+        want = np.asarray(paged_decode_attention_ref(
+            q, clean, clean, jnp.asarray(bt),
+            jnp.asarray(np.maximum(ctx, 1)), scale)[..., :latent],
+            np.float32)
+        items = int(sum(max(1, -(-n // ppc)) for n in counts))
+        work = build_decode_work_list(
+            counts, ppc, pad_to=padded_work_length(items, rows, width, ppc))
+        got, got_pages = paged_decode_attention(
+            q, pages, None, jnp.asarray(bt), jnp.asarray(ctx), None,
+            row.reshape(rows, 1, lanes), None, scale=scale,
+            pages_per_chunk=ppc, work_items=work, latent=latent)
+        np.testing.assert_array_equal(np.asarray(got_pages, np.float32),
+                                      np.asarray(want_pages, np.float32))
+        got = np.asarray(got, np.float32)
+        assert got.shape == (rows, heads, latent) and np.isfinite(got).all()
+        live = ctx > 0
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-2,
+                                   atol=2e-2)
+        np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
+        print("Sarvam latent decode, 64 rows: max err "
+              f"{np.abs(got[live] - want[live]).max():.2e}")
+        # the prompt writer: three whole pages and a tail of five rows
+        chunk = jnp.asarray(srng.normal(size=(8 * page, lanes)),
+                            jnp.bfloat16)
+        ids = np.full((8,), pages.shape[0], np.int32)
+        ids[:4] = perm[:4]
+        valid = np.full((8,), page, np.int32)
+        valid[3] = 5
+        wrote = write_kv_pages_prefill(
+            chunk, None, clean, None, jnp.asarray(ids),
+            jnp.arange(8, dtype=jnp.int32), jnp.asarray(valid))
+        slots = np.full((8 * page,), pages.shape[0] * page, np.int32)
+        for t in range(3 * page + 5):
+            slots[t] = ids[t // page] * page + t % page
+        np.testing.assert_array_equal(
+            np.asarray(wrote, np.float32),
+            np.asarray(write_to_latent_cache(chunk, clean,
+                                             jnp.asarray(slots)),
+                       np.float32))
+
     with section("decode attention (Phi-4-mini-flash: 10 KV heads, "
                  "one head block)"):
         # -- the decode kernel as `phi-4-mini-flash-bf16.reason-2k`
