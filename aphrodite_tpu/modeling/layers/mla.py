@@ -14,9 +14,11 @@ pair and no head axis. With `W_kvb` split a head into `W_UK_h`
   gathered from the pages (`gather_pages`) are up-projected inside the
   step to `K_h = [c W_UK_h | k_r]` and `V_h = c W_UV_h` (a matmul each
   from the rows as they lie, `_up_weights`), and the prompt attention
-  the tree has runs over them (the Pallas flash kernel on one TPU, K
-  and V zero-padded to its one head width; the `jnp` functions
-  elsewhere);
+  the tree has runs over them (the Pallas flash kernel on one TPU, q
+  and K zero-padded to whole lane tiles a head, 192 -> 256 at Sarvam's
+  widths, and V at its OWN padded width, 128, which is the output's
+  and `o_proj`'s; the `jnp` functions elsewhere, which have one head
+  width, with V zero-padded to the keys' and the output sliced);
 - **a decode step** attends ABSORBED: `q~_h = [q_nope_h W_UK_h^T |
   q_rope_h]`, scores `q~_h . [c | k_r]` over the rows as the pages
   hold them, `o~_h = sum_t p_t c_t`, `o_h = o~_h W_UV_h`: one KV
@@ -120,14 +122,15 @@ class LatentAttention:
         return jax.lax.optimization_barrier(
             write_to_latent_cache(rows, pages, metadata.slot_mapping))
 
-    def _up_weights(self, w_uk, w_uv, width: int):
-        """`(W_K, W_V)`, each `[lanes, heads, width]`: a page's row
-        `[c | k_r | 0]` times `W_K` is the row's key of every head,
-        `[c W_UK_h | k_r | 0]` (`k_r` through an identity, which is
-        exact), times `W_V` its value `[c W_UV_h | 0]`, both at the one
-        head width `width` the prompt attention runs at: one matmul
-        each from the rows as the pages hold them, and no slice,
-        concatenation or pad of a `[keys, heads, width]` array."""
+    def _up_weights(self, w_uk, w_uv, width: int, v_width: int):
+        """`(W_K [lanes, heads, width], W_V [lanes, heads, v_width])`:
+        a page's row `[c | k_r | 0]` times `W_K` is the row's key of
+        every head, `[c W_UK_h | k_r | 0]` (`k_r` through an identity,
+        which is exact), times `W_V` its value `[c W_UV_h | 0]`, at
+        the head widths the prompt attention runs at (`v_width` =
+        `v_dim`: no zero column): one matmul each from the rows as
+        the pages hold them, and no slice, concatenation or pad of a
+        `[keys, heads, width]` array."""
         heads = self.num_heads
         eye = jnp.eye(self.rope, width, k=self.nope, dtype=w_uk.dtype)
         tail = self.lanes - self.latent - self.rope
@@ -136,7 +139,7 @@ class LatentAttention:
             jnp.broadcast_to(eye[:, None, :], (self.rope, heads, width)),
             jnp.zeros((tail, heads, width), w_uk.dtype)])
         w_v = jnp.pad(w_uv, ((0, self.lanes - self.latent), (0, 0),
-                             (0, width - self.v_dim)))
+                             (0, v_width - self.v_dim)))
         return w_k, w_v
 
     def _prefill(self, q_nope, q_rope, c, k_r, w_uk, w_uv, pages,
@@ -161,9 +164,12 @@ class LatentAttention:
             expanded = jnp.int32(0)
         flash = takes_prefill_kernel(rows.dtype, metadata.tp, metadata.sp,
                                      False)
-        # (the kernel's head width is whole lane tiles)
-        width = padded_head_size(q.shape[-1]) if flash else q.shape[-1]
-        w_k, w_v = self._up_weights(w_uk, w_uv, width)
+        # (the kernel's head widths are whole lane tiles, the values'
+        # their own; the `jnp` functions have one width)
+        width, v_width = (padded_head_size(q.shape[-1]),
+                          padded_head_size(self.v_dim)) \
+            if flash else (q.shape[-1],) * 2
+        w_k, w_v = self._up_weights(w_uk, w_uv, width, v_width)
         k = jnp.einsum("btl,lhd->bthd", rows, w_k)
         v = jnp.einsum("btl,lhd->bthd", rows, w_v)
         if flash:
@@ -172,7 +178,8 @@ class LatentAttention:
             note_kernel_path(
                 "prefill_attention", "pallas",
                 "prefill_flash_attention over up-projected latent rows, "
-                f"{'gathered prefix' if metadata.use_prefix else 'own keys'}")
+                f"{'gathered prefix' if metadata.use_prefix else 'own keys'}"
+                f", keys {width} lanes a head, values {v_width}")
             q = jnp.pad(q, ((0, 0),) * 3 + ((0, width - q.shape[-1]),))
             out = prefill_flash_attention(q, k, v, context_lens, kv_valid,
                                           self.scale)

@@ -10,10 +10,16 @@ array through HBM) and `prefill_attention_blocked` (an XLA `while` over
   query heads ride together as the rows of one matmul operand,
   `[group x query_block, d]`, packed once a query block into VMEM
   scratch with the score scale folded in, so a key block is read once
-  a group. `q`, `k`, `v` and the output are seen as `[b, tokens,
-  heads x d]` (a bitcast of the layouts the callers hold): a head, or a
-  KV head's group, is a lane block, and the output is written where
-  the next layer reads it. No transposing copy before or after.
+  a group. `q` and `k` are seen as `[b, tokens, heads x d]`, `v` and
+  the output as `[b, tokens, heads x dv]` (a bitcast of the layouts
+  the callers hold): a head, or a KV head's group, is a lane block, and
+  the output is written where the next layer reads it. No transposing
+  copy before or after. The values' head width `dv` is `v`'s own and
+  need not be the keys' `d` (multi-head latent attention's heads are
+  192 wide for q and k and 128 for v, `modeling/layers/mla.py`): V's
+  blocks, the accumulator and the output are `dv` wide, the packed
+  query, the scores and the statistics know nothing of it, and with
+  `dv == d` nothing differs.
 - The walk. The key sub-blocks a query block visits are `[first,
   stop)` of `ops/attention.py::prefill_tile_ranges`, the rule of the
   `jnp` walk and of the host's tile count, read here for each row by
@@ -144,18 +150,19 @@ def _flash_kernel(
     valid_ref,      # [b] a row's valid keys
     q_ref,          # [1, query_block, group * d]
     k_ref,          # [1, key_major, d]
-    v_ref,
-    o_ref,          # [1, query_block, group * d]
+    v_ref,          # [1, key_major, dv]
+    o_ref,          # [1, query_block, group * dv]
     qp_scr,         # [group * query_block, d], the model's type
     m_scr,          # [rows, lanes] float32 running maximum (base 2),
                     # the same in every lane of a row
     l_scr,          # [rows, lanes] float32 running sum, a part a lane
-    acc_scr,        # [rows, d] float32 weighted values
+    acc_scr,        # [rows, dv] float32 weighted values
     *, group: int, head_dim: int, query_block: int, key_block: int,
     scale: float, window: Optional[int],
 ):
     b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     d, bq, bk = head_dim, query_block, key_block
+    dv = acc_scr.shape[1]                   # the values' own head width
     rows = group * bq
     lanes = m_scr.shape[1]
     subs = k_ref.shape[1] // bk             # sub-blocks of a copied block
@@ -217,8 +224,8 @@ def _flash_kernel(
             p.astype(qp_scr.dtype),
             v_ref[0, keys, :].astype(qp_scr.dtype),
             (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [rows, d]
-        acc_scr[...] = acc_scr[...] * _lanes(fade, d) + pv
+            preferred_element_type=jnp.float32)         # [rows, dv]
+        acc_scr[...] = acc_scr[...] * _lanes(fade, dv) + pv
         m_scr[...] = m_new
 
     @pl.when(sub0 < stop)
@@ -249,7 +256,7 @@ def _flash_kernel(
         # (a query that saw no key: l = 0 and acc = 0, so zeros)
         out = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
         for g in range(group):
-            o_ref[0, :, g * d:(g + 1) * d] = \
+            o_ref[0, :, g * dv:(g + 1) * dv] = \
                 out[g * bq:(g + 1) * bq].astype(o_ref.dtype)
 
 
@@ -261,7 +268,7 @@ def _prefill_flash_impl(q, k, v, context_lens, kv_valid_lens, *, scale,
                         sliding_window, query_block, key_block,
                         key_major, interpret):
     b, s, num_q_heads, d = q.shape
-    kv_len, num_kv_heads = k.shape[1], k.shape[2]
+    kv_len, num_kv_heads, dv = k.shape[1], k.shape[2], v.shape[3]
     group = num_q_heads // num_kv_heads
     bq, bk = query_block, key_block
     subs = key_major // bk
@@ -298,13 +305,13 @@ def _prefill_flash_impl(q, k, v, context_lens, kv_valid_lens, *, scale,
             grid=(b, num_kv_heads, query_blocks, steps),
             in_specs=[pl.BlockSpec((1, bq, group * d), q_map),
                       pl.BlockSpec((1, key_major, d), kv_map),
-                      pl.BlockSpec((1, key_major, d), kv_map)],
-            out_specs=pl.BlockSpec((1, bq, group * d), q_map),
+                      pl.BlockSpec((1, key_major, dv), kv_map)],
+            out_specs=pl.BlockSpec((1, bq, group * dv), q_map),
             scratch_shapes=[pltpu.VMEM((rows, d), q.dtype),
                             pltpu.VMEM((rows, lanes), jnp.float32),
                             pltpu.VMEM((rows, lanes), jnp.float32),
-                            pltpu.VMEM((rows, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, s, num_q_heads * d), q.dtype),
+                            pltpu.VMEM((rows, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, s, num_q_heads * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -313,14 +320,14 @@ def _prefill_flash_impl(q, k, v, context_lens, kv_valid_lens, *, scale,
     )(first.reshape(-1), stop.reshape(-1), context_lens, kv_valid_lens,
       q.reshape(b, s, num_q_heads * d),
       k.reshape(b, kv_len, num_kv_heads * d),
-      v.reshape(b, kv_len, num_kv_heads * d))
-    return out.reshape(b, s, num_q_heads, d)
+      v.reshape(b, kv_len, num_kv_heads * dv))
+    return out.reshape(b, s, num_q_heads, dv)
 
 
 def prefill_flash_attention(
     q: jax.Array,                 # [batch, seq, num_q_heads, head_dim]
     k: jax.Array,                 # [batch, kv_len, num_kv_heads, head_dim]
-    v: jax.Array,
+    v: jax.Array,                 # [batch, kv_len, num_kv_heads, v_dim]
     context_lens: jax.Array,      # [batch] prefix lengths (0 for plain)
     kv_valid_lens: jax.Array,     # [batch] valid kv entries (rest padded)
     scale: float,
@@ -331,10 +338,11 @@ def prefill_flash_attention(
 ) -> jax.Array:
     """`ops/attention.py::prefill_attention` as the flash kernel of
     this module: the same arguments (but ALiBi, which the kernel does
-    not take), the same `[batch, seq, heads, head_dim]` output in `q`'s
-    type, zeros at a query that sees no key. Compiled, the head size
-    is a multiple of the 128 lanes (the layer pads it, as for its
-    decode kernel).
+    not take), the same `[batch, seq, heads, v_dim]` output in `q`'s
+    type, zeros at a query that sees no key. `v_dim` is read from `v`
+    and may be narrower or wider than `q`'s and `k`'s `head_dim`.
+    Compiled, both are multiples of the 128 lanes (the layer pads
+    them, as for its decode kernel).
 
     `blocks` (queries a block, keys a sub-block, keys a copied block)
     are `choose_blocks`' unless given (the tests', in interpret mode,
@@ -347,8 +355,9 @@ def prefill_flash_attention(
     num_kv_heads = k.shape[2]
     if num_q_heads % num_kv_heads:
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=}")
-    if not interpret and d % 128:
-        raise ValueError(f"{d=} is no multiple of the 128 lanes")
+    dv = v.shape[-1]
+    if not interpret and (d % 128 or dv % 128):
+        raise ValueError(f"{d=} or {dv=} is no multiple of the 128 lanes")
     if blocks is None:
         def tiled(x):
             pad = -x.shape[1] % TOKEN_TILE

@@ -34,14 +34,31 @@ layers: the table holds the window, the chunk and a page, 160-161
 pages, padded to 192);
 `mistral-7b-w4a8.batch`: `[rows, 1024, 32, 128]` on their own 1,024
 keys of 8 KV heads at 1, 2 and 4 rows, no window (the plain function
-on the `jnp` side).
+on the `jnp` side);
+`sarvam-105b-bf16.doc-8k` (PR 53): `[1, 8192, 64, 256]` queries on
+their own 8,192 keys, one KV head a query head, keys 256 lanes a head
+of which 192 are live and values 128, all live (multi-head latent
+attention's up-projected rows, `modeling/layers/mla.py`), and a chunk
+of 2,048 on a gathered table of 9,216 behind 2,048, 4,096 and 6,144
+cached tokens; `--only sarvam --v-dim 256` is the same calls as the
+tree made them before PR 53, values zero-padded to the keys' width.
+`--head-dim` is q's and k's head width and `--v-dim` the values'
+(default: the same); a cell that states its own keeps them unless the
+flag is given. Beside a side's milliseconds stand the call's LIVE
+multiply-adds (the pairs of a query and a key the mask leaves, times
+the heads, times the lanes a pair that are no padding: the head width
+and the values' unless the cell states fewer, Sarvam's 192 + 128 =
+320) and the share of `perf/peaks.json`'s bf16 peak that
+twice as many operations in that time are.
 `--check` compares each side with `prefill_attention` on the same
 inputs first, and `--oracle` both with float64 numpy besides (a head at
 a time; a minute at the widest call). A call is timed as
 `profile_step.device_bench` times a kernel (a loop on the device, the
 slope between two trip counts), and printed beside it are the tiles
 the 512-rule visits of the padded rectangle's (`count_prefill_tiles`)
-where the tree has one.
+where the tree has one. A side whose values are narrower than its
+keys is checked against `prefill_attention` on zero-padded values,
+sliced (the `jnp` functions have one head width).
 
 It times the tree it is run in: to compare two commits, copy this file
 into a `git archive` of the other and run both in one chip call (a
@@ -49,11 +66,12 @@ tree before PR 44 has no kernel and says so; one before PR 39 scans
 every key block for all queries and has no rule to count by). It is no
 code a benchmark cell runs. On the CPU it checks (the kernel in
 interpret mode) and times nothing: `--queries 32 --keys 64 --ctx 16
---block 8 --check --kernel` is a rehearsal.
+--block 8 --head-dim 32 --v-dim 16 --check --kernel` is a rehearsal.
 """
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -62,7 +80,8 @@ sys.path.insert(0, ROOT)
 
 from benchmarks.profile_step import device_bench  # noqa: E402
 
-#: (name, queries, keys, ctx, window, heads, KV heads, scale[, rows])
+#: (name, queries, keys, ctx, window, heads, KV heads, scale[, rows[,
+#: head width, values' width, live lanes a query-key pair]])
 CELLS = (
     ("phi full/cross", 2048, 2048, 0, 0, 40, 10, 0.125),
     ("phi window 512", 2048, 2048, 0, 512, 40, 10, 0.125),
@@ -82,6 +101,15 @@ CELLS = (
     ("mistral, 1 row", 1024, 1024, 0, 0, 32, 8, 0.0884, 1),
     ("mistral, 2 rows", 1024, 1024, 0, 0, 32, 8, 0.0884, 2),
     ("mistral, 4 rows", 1024, 1024, 0, 0, 32, 8, 0.0884, 4),
+    # (scale: 192^-0.5 times the yarn factor's square, 1.3689^2)
+    ("sarvam whole prompt", 8192, 8192, 0, 0, 64, 64, 0.13524, 1,
+     256, 128, 320),
+    ("sarvam chunk 2", 2048, 9216, 2048, 0, 64, 64, 0.13524, 1,
+     256, 128, 320),
+    ("sarvam chunk 3", 2048, 9216, 4096, 0, 64, 64, 0.13524, 1,
+     256, 128, 320),
+    ("sarvam chunk 4", 2048, 9216, 6144, 0, 64, 64, 0.13524, 1,
+     256, 128, 320),
 )
 
 
@@ -97,7 +125,7 @@ def _oracle(q, k, v, ctx, valid, scale, window):
     live = (k_pos <= q_pos) & (k_pos < valid)
     if window:
         live &= k_pos > q_pos - window
-    out = np.zeros(q.shape)
+    out = np.zeros(q.shape[:3] + v.shape[3:])
     for row in range(b):
         for h in range(heads):
             scores = np.where(
@@ -111,8 +139,20 @@ def _oracle(q, k, v, ctx, valid, scale, window):
     return out
 
 
+def live_pairs(queries, ctx, valid, window) -> int:
+    """Pairs of a query and a key that the mask leaves, a row and a
+    head: query `i` sits at `ctx + i` and sees the keys up to itself
+    that are valid and inside its window."""
+    import numpy as np
+    pos = ctx + np.arange(queries, dtype=np.int64)
+    seen = np.minimum(pos + 1, valid)
+    if window:
+        seen = seen - np.maximum(pos + 1 - window, 0)
+    return int(np.maximum(seen, 0).sum())
+
+
 def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
-            rows, args) -> None:
+            rows, head_dim, v_dim, live, args) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -120,12 +160,22 @@ def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
     window = window or None
     key = jax.random.PRNGKey(args.seed)
     dtype = jnp.bfloat16
-    q = jax.random.normal(key, (rows, queries, heads, args.head_dim),
-                          dtype)
+    head_dim = args.head_dim or head_dim
+    v_dim = args.v_dim or v_dim or head_dim
+    live = min(live or head_dim + v_dim, head_dim + v_dim)
+    if v_dim > head_dim:
+        raise SystemExit("the jnp side pads the values to the keys' "
+                         f"width: --v-dim {v_dim} > --head-dim {head_dim}")
+    q = jax.random.normal(key, (rows, queries, heads, head_dim), dtype)
     k = jax.random.normal(jax.random.fold_in(key, 1),
-                          (rows, keys, kv_heads, args.head_dim), dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 2), k.shape, dtype)
+                          (rows, keys, kv_heads, head_dim), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2),
+                          (rows, keys, kv_heads, v_dim), dtype)
+    # (the `jnp` functions have one head width: theirs is the values'
+    # zero-padded to the keys', and the result sliced)
+    v_wide = jnp.pad(v, ((0, 0),) * 3 + ((0, head_dim - v_dim),))
     valid = min(ctx + queries, keys)
+    macs = rows * heads * live * live_pairs(queries, ctx, valid, window)
     block = dict(key_block=args.block) if args.block else {}
     count = getattr(att, "count_prefill_tiles", None)
     tiles = "no rule in this tree" if count is None else \
@@ -135,20 +185,25 @@ def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
     valid_lens = jnp.full((rows,), valid, jnp.int32)
     blocked = queries * keys >= args.blocked_from
     print(f"prefill_attn[{name}] q={rows}x{queries} heads={heads}/"
-          f"{kv_heads}x{args.head_dim} keys={keys} ctx={ctx} "
-          f"window={window}: {tiles}", flush=True)
+          f"{kv_heads}x{head_dim} values x{v_dim} keys={keys} ctx={ctx} "
+          f"window={window}: {tiles}; {macs / 1e9:.4g} G live "
+          f"multiply-adds ({live} lanes a pair)", flush=True)
 
-    def plain(qq):
-        return att.prefill_attention(qq, k, v, ctx_lens, valid_lens,
-                                     scale, sliding_window=window)
+    # (K and V are arguments of a side, not constants of its program:
+    # at Sarvam's sizes a program that holds them compiles for minutes)
+    def plain(qq, kk, vv):
+        return att.prefill_attention(
+            qq, kk, vv, ctx_lens, valid_lens, scale,
+            sliding_window=window)[..., :v_dim]
 
-    def walk(qq):
+    def walk(qq, kk, vv):
         return att.prefill_attention_blocked(
-            qq, k, v, ctx_lens, valid_lens, scale,
-            sliding_window=window, **block)
+            qq, kk, vv, ctx_lens, valid_lens, scale,
+            sliding_window=window, **block)[..., :v_dim]
 
     sides = [] if args.kernel == "only" else [
-        ("blocked" if blocked else "plain", walk if blocked else plain)]
+        ("blocked" if blocked else "plain", walk if blocked else plain,
+         v_wide)]
     if args.kernel:
         try:
             from aphrodite_tpu.ops.pallas import prefill_attention as pf
@@ -167,23 +222,23 @@ def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
                 else:
                     pinned = (pinned + pinned[-1:])[:3]
 
-                def kernel(qq, pinned=pinned):
+                def kernel(qq, kk, vv, pinned=pinned):
                     return pf.prefill_flash_attention(
-                        qq, k, v, ctx_lens, valid_lens, scale, window,
+                        qq, kk, vv, ctx_lens, valid_lens, scale, window,
                         interpret=jax.default_backend() != "tpu",
                         blocks=pinned)
-                sides.append((f"kernel {blocks}".strip(), kernel))
+                sides.append((f"kernel {blocks}".strip(), kernel, v))
 
     if args.check:
-        want = jax.jit(plain)(q).astype(jnp.float32)
+        want = jax.jit(plain)(q, k, v_wide).astype(jnp.float32)
         exact = _oracle(q, k, v, ctx, valid, scale, window) \
             if args.oracle else None
         if exact is not None:
             print("  oracle: max |plain - float64| = "
                   f"{np.abs(np.asarray(want) - exact).max():.4g}",
                   flush=True)
-        for side, attend in sides:
-            got = jax.jit(attend)(q).astype(jnp.float32)
+        for side, attend, values in sides:
+            got = jax.jit(attend)(q, k, values).astype(jnp.float32)
             err = float(jnp.max(jnp.abs(got - want)))
             said = "" if exact is None else " |{} - float64| = {:.4g}".format(
                 side, np.abs(np.asarray(got) - exact).max())
@@ -192,12 +247,21 @@ def run_one(name, queries, keys, ctx, window, heads, kv_heads, scale,
                   flush=True)
     if jax.default_backend() != "tpu":
         return
-    for side, attend in sides:
-        # (an output is no query for the next call: the dependency alone)
+    kind = jax.devices()[0].device_kind
+    with open(os.path.join(ROOT, "perf", "peaks.json")) as f:
+        peaks = json.load(f)["devices"].get(kind)
+    if peaks is None:
+        raise SystemExit(f"perf/peaks.json has no peaks of {kind!r}")
+    for side, attend, values in sides:
+        # (an output is no query for the next call: the dependency
+        # alone, through one lane of it where the widths differ)
         s, _ = device_bench(
-            lambda qq, i, attend=attend:
-            qq + attend(qq) * jnp.bfloat16(1e-30), q, slow=True)
-        print(f"  whole call, {side}: {s * 1e3:.3f} ms", flush=True)
+            lambda c, i, attend=attend:
+            (c[0] + attend(*c)[..., :1] * jnp.bfloat16(1e-30),) + c[1:],
+            (q, k, values), slow=True)
+        print(f"  whole call, {side}: {s * 1e3:.3f} ms, "
+              f"{2 * macs / s / peaks['bf16_flops_per_s'] * 100:.1f}% of "
+              "the bf16 peak over the live multiply-adds", flush=True)
 
 
 def main() -> None:
@@ -216,7 +280,12 @@ def main() -> None:
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--heads", type=int, default=40)
     ap.add_argument("--kv-heads", type=int, default=10)
-    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--head-dim", type=int, default=0,
+                    help="q's and k's head width (default: the cell's, "
+                         "128 for one geometry)")
+    ap.add_argument("--v-dim", type=int, default=0,
+                    help="the values' head width (default: the cell's, "
+                         "else the head width)")
     ap.add_argument("--scale", type=float, default=0.125)
     ap.add_argument("--block", type=int, default=0,
                     help="keys a block (default: the function's)")
@@ -244,7 +313,8 @@ def main() -> None:
         ("one geometry", args.queries, args.keys, args.ctx, args.window,
          args.heads, args.kv_heads, args.scale, args.rows)]
     for cell in cells:
-        run_one(*(cell + (1,))[:9], args)
+        # (rows, head width, values' width, live lanes where stated)
+        run_one(*(cell + (1, 128, 0, 0)[len(cell) - 8:]), args)
 
 
 if __name__ == "__main__":

@@ -661,6 +661,77 @@ def test_flash_kernel_is_plain_prefill(case, monkeypatch):
         np.testing.assert_allclose(whole, plain, rtol=2e-5, atol=2e-5)
 
 
+#: (query heads a KV head, KV heads, d, dv, the case of `BLOCKED_CASES`
+#: whose rows, chunk, keys, contexts and window it takes): the values'
+#: head width is the values' own
+VALUE_WIDTH_CASES = {
+    # Sarvam's form: one KV head a query head, 192 + 64 pad lanes of
+    # keys to 128 of values, a prompt on its own keys
+    "narrower-at-group-1": (1, 4, 32, 16, "first-chunk-own-keys"),
+    "narrower-at-group-4": (4, 2, 32, 16, "first-chunk-own-keys"),
+    "narrower-under-a-window": (1, 3, 32, 16, "window-over-a-block"),
+    # a row's valid keys short of a table padded far past them
+    "narrower-behind-a-cached-prefix": (1, 2, 48, 16,
+                                        "chunk-3-of-a-padded-table"),
+    "narrower-rows-at-different-contexts": (6, 1, 32, 8,
+                                            "queries-past-the-prompt"),
+    # nothing in the kernel asks that the values be the narrower
+    "wider-than-the-keys": (2, 2, 16, 32, "rows-at-different-contexts"),
+}
+
+
+@pytest.mark.parametrize("group,Hkv,d,dv,name", VALUE_WIDTH_CASES.values(),
+                         ids=list(VALUE_WIDTH_CASES))
+def test_flash_kernel_takes_values_at_their_own_width(group, Hkv, d, dv,
+                                                      name):
+    """`v` `[b, keys, KV heads, dv]` with `dv` not `q`'s and `k`'s `d`
+    (`modeling/layers/mla.py`: 256 lanes of keys a head, 128 of
+    values): the output is `[b, s, heads, dv]` and, where `dv < d`,
+    what the call at one width gives on the values zero-padded to `d`,
+    sliced (the call a tree before PR 53 made: the padded lanes
+    multiplied zeros and were thrown away), to the last place of
+    float32: the CPU's matmul sums a column's terms in an order that
+    follows the column count, the MXU does not, and
+    `tests/kernels/tpu_smoke.py` holds the two EQUAL on the chip.
+    Against `prefill_attention`, which has one head width, on the
+    wider of the two zero-padded, sliced."""
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    case = BLOCKED_CASES[name]
+    rng = np.random.default_rng(53)
+    s_new, kv_len, window = case["s_new"], case["kv_len"], case["window"]
+    b, Hq = len(case["ctx"]), group * Hkv
+    q = rng.normal(size=(b, s_new, Hq, d)).astype(np.float32)
+    k = rng.normal(size=(b, kv_len, Hkv, d)).astype(np.float32)
+    v = rng.normal(size=(b, kv_len, Hkv, dv)).astype(np.float32)
+    ctx = jnp.array(case["ctx"], jnp.int32)
+    kv_valid = ctx + jnp.array(case["new"], jnp.int32)
+    scale = 1 / np.sqrt(d)
+    wide = max(d, dv)
+
+    def lanes(x):
+        return jnp.pad(jnp.array(x), ((0, 0),) * 3 +
+                       ((0, wide - x.shape[-1]),))
+
+    def kernel(q, k, v):
+        return np.array(flash.prefill_flash_attention(
+            jnp.array(q), jnp.array(k), jnp.array(v), ctx, kv_valid, scale,
+            window, blocks=(8, 8, 16), interpret=True))
+    got = kernel(q, k, v)
+    assert got.shape == (b, s_new, Hq, dv) and np.isfinite(got).all()
+    plain = np.array(prefill_attention(
+        lanes(q), lanes(k), lanes(v), ctx, kv_valid, scale,
+        sliding_window=window))[..., :dv]
+    np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
+    if dv < d:
+        np.testing.assert_allclose(got, kernel(q, k, lanes(v))[..., :dv],
+                                   rtol=0, atol=1e-6)
+    # left to itself the wrapper pads the tokens and chooses the blocks
+    whole = np.array(flash.prefill_flash_attention(
+        jnp.array(q), jnp.array(k), jnp.array(v), ctx, kv_valid, scale,
+        window, interpret=True))
+    np.testing.assert_allclose(whole, plain, rtol=2e-5, atol=2e-5)
+
+
 #: (queries, keys, query heads a KV head, window) -> (queries a block,
 #: keys a sub-block, keys a copied block): the cells' calls
 #: (`benchmarks/prefill_ab.py::CELLS`) and the rule's edges
@@ -846,8 +917,20 @@ def test_prefill_ab_check_arm_rehearses_on_the_cpu(monkeypatch, capsys):
     # every cell's call falls on its model's side of the harness's
     # threshold: Mistral's takes the plain function, the others' the walk
     assert [c[1] * c[2] >= 1 << 21 for c in prefill_ab.CELLS] == \
-        [True] * 12 + [False] * 3
-    assert [(c + (1,))[8] for c in prefill_ab.CELLS[12:]] == [1, 2, 4]
+        [True] * 12 + [False] * 3 + [True] * 4
+    assert [(c + (1,))[8] for c in prefill_ab.CELLS[12:15]] == [1, 2, 4]
+    # Sarvam's calls state their widths: keys 256 lanes a head, values
+    # 128, of which 192 + 128 are no padding
+    assert [c[9:] for c in prefill_ab.CELLS[15:]] == [(256, 128, 320)] * 4
+    assert [c[:9] for c in prefill_ab.CELLS if len(c) > 9] == [
+        ("sarvam whole prompt", 8192, 8192, 0, 0, 64, 64, 0.13524, 1),
+        ("sarvam chunk 2", 2048, 9216, 2048, 0, 64, 64, 0.13524, 1),
+        ("sarvam chunk 3", 2048, 9216, 4096, 0, 64, 64, 0.13524, 1),
+        ("sarvam chunk 4", 2048, 9216, 6144, 0, 64, 64, 0.13524, 1)]
+    assert prefill_ab.live_pairs(8192, 0, 8192, None) == 8192 * 8193 // 2
+    assert prefill_ab.live_pairs(2048, 2048, 4096, None) == \
+        2048 * 2048 + 2048 * 2049 // 2
+    assert prefill_ab.live_pairs(4, 2, 5, 3) == 3 + 3 + 3 + 2
 
 
 # ---- a causal window over a table that slides ----

@@ -722,9 +722,10 @@ def test_the_check_sees_the_parents_layout_sliced_and_copied(on_v5e,
 
 # ---- the prompt's attention as the flash kernel ----
 
-#: (rows, queries, query heads, KV heads, keys, window): the calls of
-#: the five cells' prompt steps (`benchmarks/prefill_ab.py::CELLS`,
-#: and Jamba's 512-token prompts on one KV head)
+#: (rows, queries, query heads, KV heads, keys, window[, lanes a head
+#: of q and k, of v: 128 both unless stated]): the calls of the cells'
+#: prompt steps (`benchmarks/prefill_ab.py::CELLS`, and Jamba's
+#: 512-token prompts on one KV head)
 PREFILL_CASES = {
     "mistral-1-row": (1, 1024, 32, 8, 1024, None),
     "mistral-2-rows": (2, 1024, 32, 8, 1024, None),
@@ -745,6 +746,11 @@ PREFILL_CASES = {
     "evabyte-chunk-1": (4, 2048, 32, 32, 2048, None),
     "evabyte-table": (4, 2048, 32, 32, 3072, None),
     "evabyte-table-1-row": (1, 2048, 32, 32, 3072, None),
+    # multi-head latent attention's up-projected rows, one KV head a
+    # query head: 192 + 64 pad lanes of keys, 128 of values (PR 53); a
+    # prompt whole on its own keys, and a chunk on a gathered table
+    "sarvam-whole-prompt": (1, 8192, 64, 64, 8192, None, 256, 128),
+    "sarvam-chunk-table": (1, 2048, 64, 64, 9216, None, 256, 128),
 }
 
 
@@ -752,37 +758,44 @@ PREFILL_CASES = {
                          ids=list(PREFILL_CASES))
 def test_prefill_flash_attention_compiles(on_v5e, case):
     """The prompt's flash kernel at each cell's prompt shapes, called
-    as `PagedAttention` calls it: q, k, v as the projections leave
-    them (`[rows, tokens, heads x 128]`), seen as `[rows, tokens,
-    heads, 128]`, and the output back as `o_proj` reads it. Mosaic
-    takes it at the blocks `choose_blocks` gives, under the scoped
-    VMEM the kernel states; and around the call the compiler puts
-    nothing: the three operands reach it and the output leaves it
-    without a copy, a slice or a staged move."""
+    as `PagedAttention` (and `LatentAttention`) calls it: q, k, v as
+    the projections leave them (`[rows, tokens, heads x lanes]`), seen
+    as `[rows, tokens, heads, lanes]`, and the output, as wide a head
+    as the values, back as `o_proj` reads it. Mosaic takes it at the
+    blocks `choose_blocks` gives, under the scoped VMEM the kernel
+    states; and around the call the compiler puts nothing: the three
+    operands reach it and the output leaves it without a copy, a slice
+    or a staged move. (Of Sarvam's cases that says that the kernel's
+    own view of operands given token-major costs nothing; its step's
+    up-projection hands K and V over tokens-minor and the compiler
+    copies them, `PERF.md` section 7.)"""
     import re
     from aphrodite_tpu.ops.pallas import prefill_attention as flash
-    rows, s, Hq, Hkv, kv, window = case
-    d = 128
+    rows, s, Hq, Hkv, kv, window, d, dv = (case + (128, 128))[:8]
 
     def attend(q, k, v, ctx, valid):
         return flash.prefill_flash_attention(
             q.reshape(rows, s, Hq, d), k.reshape(rows, kv, Hkv, d),
-            v.reshape(rows, kv, Hkv, d), ctx, valid, d ** -0.5,
-            window).reshape(rows, s, Hq * d)
+            v.reshape(rows, kv, Hkv, dv), ctx, valid, d ** -0.5,
+            window).reshape(rows, s, Hq * dv)
 
     hlo = jax.jit(attend).lower(
         on_v5e((rows, s, Hq * d), BF16), on_v5e((rows, kv, Hkv * d), BF16),
-        on_v5e((rows, kv, Hkv * d), BF16), on_v5e((rows,), I32),
+        on_v5e((rows, kv, Hkv * dv), BF16), on_v5e((rows,), I32),
         on_v5e((rows,), I32)).compile().as_text()
     (call,) = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert "_prefill_flash_impl" in call.split(" = ")[0]
+    # the output a head as wide as the values, whatever the keys' width
+    assert call.split(" = ")[1].startswith(f"bf16[{rows},{s},{Hq * dv}]{{")
     (stated,) = re.findall(
         r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
         r'"size":"(\d+)"\}\]', call)
     assert int(stated) == flash.VMEM_LIMIT <= 48 << 20
     for array in (f"bf16[{rows},{s},{Hq * d}]",
-                  f"bf16[{rows},{kv},{Hkv * d}]"):
+                  f"bf16[{rows},{kv},{Hkv * d}]",
+                  f"bf16[{rows},{kv},{Hkv * dv}]",
+                  f"bf16[{rows},{s},{Hq * dv}]"):
         assert _whole_array_moves(hlo, array) == []
     query_block, key_block, major = flash.choose_blocks(
         s, kv, Hq // Hkv, window)
@@ -911,13 +924,17 @@ def test_prefill_flash_attention_compiles_at_sarvam_shapes(on_v5e, rows, s,
                                                           kv):
     """The prompt's flash kernel at 64 heads of 192 + 64 pad lanes
     over up-projected latent rows (`modeling/layers/mla.py`): one KV
-    head a query head, K and V `[rows, keys, 64, 256]`."""
+    head a query head, K `[rows, keys, 64, 256]` and V at its own 128
+    lanes a head (`[rows, keys, 64, 128]`, PR 53), which are the
+    output's."""
     from aphrodite_tpu.ops.pallas import prefill_attention as flash
-    H, d = 64, 256
+    H, d, dv = 64, 256, 128
 
     def attend(q, k, v, ctx, valid):
         return flash.prefill_flash_attention(q, k, v, ctx, valid, 0.135)
-    jax.jit(attend).lower(
+    compiled = jax.jit(attend).lower(
         on_v5e((rows, s, H, d), BF16), on_v5e((rows, kv, H, d), BF16),
-        on_v5e((rows, kv, H, d), BF16), on_v5e((rows,), I32),
+        on_v5e((rows, kv, H, dv), BF16), on_v5e((rows,), I32),
         on_v5e((rows,), I32)).compile()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (rows, s, H, dv)

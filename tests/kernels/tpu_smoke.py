@@ -371,6 +371,8 @@ def main() -> int:
               np.asarray(sk9, np.float32)[untouched], tol=1e-6)
 
     with section("Sarvam: latent pages, one array a layer, 64 query rows"):
+        # (its names carry an `l`: the sections after it read `page`,
+        # `pages`, `bt`, `ctx`, `scale` and `q` of the first ones)
         # Sarvam-105B's page: [c 512 | k_r 64] padded to 640 lanes, ONE
         # array, the values its first 512 lanes. The kernel with
         # `latent` against the jnp path over the array as K and as V:
@@ -382,75 +384,76 @@ def main() -> int:
         from aphrodite_tpu.ops.pallas.paged_attention import (
             build_decode_work_list, choose_pages_per_chunk, lane_bytes_of,
             padded_work_length)
-        lanes, latent, heads, page, width, rows = 640, 512, 64, 16, 576, 64
-        scale = 192 ** -0.5 * 1.3689 ** 2
+        lanes, latent, heads, lpage, width, rows = 640, 512, 64, 16, 576, 64
+        lscale = 192 ** -0.5 * 1.3689 ** 2
         ppc = choose_pages_per_chunk(
-            width, page, lane_bytes_of(1, lanes, jnp.bfloat16))
+            width, lpage, lane_bytes_of(1, lanes, jnp.bfloat16))
         srng = np.random.default_rng(52)
-        ctx = np.array([8193 + (i * 131) % 1024 for i in range(rows)],
+        lctx = np.array([8193 + (i * 131) % 1024 for i in range(rows)],
                        np.int32)
-        ctx[5], ctx[11], ctx[40] = 0, 1, 513
-        counts = -(-ctx // page)
+        lctx[5], lctx[11], lctx[40] = 0, 1, 513
+        counts = -(-lctx // lpage)
         pool = 1 + int(counts.sum())
         perm = srng.permutation(pool - 1) + 1
-        bt = np.zeros((rows, width), np.int32)
+        lbt = np.zeros((rows, width), np.int32)
         at = 0
         for b, n in enumerate(counts):
-            bt[b, :n] = perm[at:at + n]
+            lbt[b, :n] = perm[at:at + n]
             at += n
         dead = np.ones(pool + 4, bool)
         dead[perm] = False
-        raw = (srng.normal(size=(pool + 4, page, lanes)) * 0.3).astype(
+        raw = (srng.normal(size=(pool + 4, lpage, lanes)) * 0.3).astype(
             np.float32)
         raw[..., 576:] = 0.0
         raw[dead] = np.nan
-        pages = jnp.asarray(raw, jnp.bfloat16)
-        q = srng.normal(size=(rows, heads, lanes)) * 0.3
-        q[..., 576:] = 0.0
-        q = jnp.asarray(q, jnp.bfloat16)
+        lpages = jnp.asarray(raw, jnp.bfloat16)
+        lq = srng.normal(size=(rows, heads, lanes)) * 0.3
+        lq[..., 576:] = 0.0
+        lq = jnp.asarray(lq, jnp.bfloat16)
         row = srng.normal(size=(rows, lanes)) * 0.3
         row[..., 576:] = 0.0
         row = jnp.asarray(row, jnp.bfloat16)
         slots = np.where(
-            ctx > 0, bt[np.arange(rows), np.maximum(ctx - 1, 0) // page]
-            * page + (ctx - 1) % page, pages.shape[0] * page)
-        want_pages = write_to_latent_cache(row, pages,
+            lctx > 0,
+            lbt[np.arange(rows), np.maximum(lctx - 1, 0) // lpage] * lpage
+            + (lctx - 1) % lpage, lpages.shape[0] * lpage)
+        want_pages = write_to_latent_cache(row, lpages,
                                            jnp.asarray(slots, jnp.int32))
         clean = jnp.where(jnp.asarray(dead)[:, None, None], 0, want_pages)
         want = np.asarray(paged_decode_attention_ref(
-            q, clean, clean, jnp.asarray(bt),
-            jnp.asarray(np.maximum(ctx, 1)), scale)[..., :latent],
+            lq, clean, clean, jnp.asarray(lbt),
+            jnp.asarray(np.maximum(lctx, 1)), lscale)[..., :latent],
             np.float32)
         items = int(sum(max(1, -(-n // ppc)) for n in counts))
         work = build_decode_work_list(
             counts, ppc, pad_to=padded_work_length(items, rows, width, ppc))
         got, got_pages = paged_decode_attention(
-            q, pages, None, jnp.asarray(bt), jnp.asarray(ctx), None,
-            row.reshape(rows, 1, lanes), None, scale=scale,
+            lq, lpages, None, jnp.asarray(lbt), jnp.asarray(lctx), None,
+            row.reshape(rows, 1, lanes), None, scale=lscale,
             pages_per_chunk=ppc, work_items=work, latent=latent)
         np.testing.assert_array_equal(np.asarray(got_pages, np.float32),
                                       np.asarray(want_pages, np.float32))
         got = np.asarray(got, np.float32)
         assert got.shape == (rows, heads, latent) and np.isfinite(got).all()
-        live = ctx > 0
+        live = lctx > 0
         np.testing.assert_allclose(got[live], want[live], rtol=2e-2,
                                    atol=2e-2)
         np.testing.assert_allclose(got[~live], 0.0, atol=1e-6)
         print("Sarvam latent decode, 64 rows: max err "
               f"{np.abs(got[live] - want[live]).max():.2e}")
         # the prompt writer: three whole pages and a tail of five rows
-        chunk = jnp.asarray(srng.normal(size=(8 * page, lanes)),
+        chunk = jnp.asarray(srng.normal(size=(8 * lpage, lanes)),
                             jnp.bfloat16)
-        ids = np.full((8,), pages.shape[0], np.int32)
+        ids = np.full((8,), lpages.shape[0], np.int32)
         ids[:4] = perm[:4]
-        valid = np.full((8,), page, np.int32)
+        valid = np.full((8,), lpage, np.int32)
         valid[3] = 5
         wrote = write_kv_pages_prefill(
             chunk, None, clean, None, jnp.asarray(ids),
             jnp.arange(8, dtype=jnp.int32), jnp.asarray(valid))
-        slots = np.full((8 * page,), pages.shape[0] * page, np.int32)
-        for t in range(3 * page + 5):
-            slots[t] = ids[t // page] * page + t % page
+        slots = np.full((8 * lpage,), lpages.shape[0] * lpage, np.int32)
+        for t in range(3 * lpage + 5):
+            slots[t] = ids[t // lpage] * lpage + t % lpage
         np.testing.assert_array_equal(
             np.asarray(wrote, np.float32),
             np.asarray(write_to_latent_cache(chunk, clean,
@@ -711,6 +714,36 @@ def main() -> int:
                       *fargs, sliding_window=fwin), np.float32),
                   np.asarray(prefill_flash_attention(
                       *fargs, sliding_window=fwin), np.float32))
+
+    with section("prompt attention (values at their own head width)"):
+        # -- Sarvam's form (PR 53): one KV head a query head, 256 lanes
+        # of keys a head (192 live) and 128 of values; a prompt on its
+        # own keys and a chunk behind a prefix in a padded table.
+        # Against `prefill_attention` (one head width: the values
+        # zero-padded, the result sliced), and EQUAL to the call that
+        # hands the kernel those padded values, which is the call a
+        # tree before PR 53 made: a column of `p @ V` does not know how
+        # many columns ride beside it --
+        for vs, vkeys, vctx in ((1024, 1024, 0), (512, 1536, 640)):
+            vq_ = jnp.asarray(rs.randn(2, vs, 16, 256), jnp.bfloat16)
+            vk_ = jnp.asarray(rs.randn(2, vkeys, 16, 256), jnp.bfloat16)
+            vv_ = jnp.asarray(rs.randn(2, vkeys, 16, 128), jnp.bfloat16)
+            vwide = jnp.pad(vv_, ((0, 0),) * 3 + ((0, 128),))
+            vlens = (jnp.asarray([vctx, vctx // 2], jnp.int32),
+                     jnp.asarray([vctx + vs, vctx // 2 + vs - 77], jnp.int32),
+                     0.135)
+            got_v = prefill_flash_attention(vq_, vk_, vv_, *vlens)
+            name = f"prompt attention 256/128 lanes, {vs} on {vkeys} keys"
+            if got_v.shape != (2, vs, 16, 128):
+                failures.append((name + " shape", got_v.shape))
+            check(name,
+                  np.asarray(prefill_attention(vq_, vk_, vwide, *vlens),
+                             np.float32)[..., :128],
+                  np.asarray(got_v, np.float32))
+            check(name + ", against the values zero-padded to 256",
+                  np.asarray(prefill_flash_attention(
+                      vq_, vk_, vwide, *vlens), np.float32)[..., :128],
+                  np.asarray(got_v, np.float32), tol=1e-30)
 
     with section("prefill page writer"):
         # -- prefill page writer (whole-page DMA, partial tail, OOB) --

@@ -351,6 +351,102 @@ def test_absorbed_decode_is_the_not_absorbed_layer():
                           np.asarray(c[0, -1]))
 
 
+# ---- a prompt step: K at the keys' padded width, V at its own ----
+
+def _cell_layer(heads):
+    """`LatentAttention` at the cell's widths a head (128 + 64 query
+    lanes, values of 128, latent 512: a row of 640 lanes), seeded."""
+    from aphrodite_tpu.modeling.layers.mla import LatentAttention
+    nope, rope, v_dim, latent = 128, 64, 128, 512
+    attn = LatentAttention(heads, nope, rope, v_dim, latent,
+                           scale=(nope + rope) ** -0.5)
+    keys = jax.random.split(jax.random.PRNGKey(53), 2)
+    w_uk = jax.random.normal(keys[0], (latent, heads, nope)) * 0.05
+    w_uv = jax.random.normal(keys[1], (latent, heads, v_dim)) * 0.05
+    return attn, w_uk, w_uv
+
+
+def test_the_up_weights_hold_values_at_their_own_width():
+    """`_up_weights` at the cell's widths, for the flash kernel: `W_K`
+    `[640, heads, 256]` (a head's 128 nope columns, `k_r` through an
+    identity into the next 64, 64 zero columns: 192 is a lane tile and
+    a half) and `W_V` `[640, heads, 128]`, `W_UV` over zero rows for
+    `k_r` and the pad, with NO zero column: the kernel multiplies no
+    lane that the layer filled with zeros (PR 53; it was 256 columns a
+    head, half of them zero)."""
+    attn, w_uk, w_uv = _cell_layer(3)
+    w_k, w_v = attn._up_weights(w_uk, w_uv, 256, 128)
+    assert w_k.shape == (640, 3, 256) and w_v.shape == (640, 3, 128)
+    w_k, w_v = np.asarray(w_k), np.asarray(w_v)
+    assert np.array_equal(w_v[:512], np.asarray(w_uv))
+    assert not w_v[512:].any() and np.abs(w_v).max(axis=0).min() > 0
+    assert np.array_equal(w_k[:512, :, :128], np.asarray(w_uk))
+    assert not w_k[:512, :, 128:].any() and not w_k[576:].any()
+    for head in range(3):
+        assert np.array_equal(w_k[512:576, head, 128:192], np.eye(64))
+        assert not w_k[512:576, head, :128].any() and \
+            not w_k[512:576, head, 192:].any()
+    # the `jnp` functions have one head width: the values padded to it
+    w_k, w_v = attn._up_weights(w_uk, w_uv, 192, 192)
+    assert w_k.shape == w_v.shape == (640, 3, 192)
+    assert not np.asarray(w_v)[..., 128:].any()
+
+
+@pytest.mark.parametrize("ctx", [0, 24], ids=["own-keys", "gathered-prefix"])
+def test_a_prompt_step_hands_the_kernel_values_128_lanes_a_head(
+        ctx, monkeypatch):
+    """A prompt step of `LatentAttention` at the cell's widths with
+    the dispatch's rule answering as on one TPU and the flash kernel
+    interpreted: q and K reach the kernel at 256 lanes a head, V at
+    128, the kernel's result is `[b, s, heads, 128]` and is the
+    layer's as it comes (nothing to slice); and it is what the `jnp`
+    side gives (one head width, 192: the path a tree before PR 53 took
+    off a TPU too), on a prompt's own keys and behind a prefix
+    gathered from the pages."""
+    from aphrodite_tpu.modeling.input_metadata import InputMetadata
+    from aphrodite_tpu.ops.pallas import prefill_attention as flash
+    heads, n = 2, 21
+    attn, w_uk, w_uv = _cell_layer(heads)
+    keys = jax.random.split(jax.random.PRNGKey(ctx), 4)
+    q_nope = jax.random.normal(keys[0], (1, n, heads, 128))
+    q_rope = jax.random.normal(keys[1], (1, n, heads, 64))
+    c = jax.random.normal(keys[2], (1, ctx + n, 512))
+    k_r = jax.random.normal(keys[3], (1, ctx + n, 64))
+    table = jnp.asarray([[5, 2, 7, 1, 3, 6]], jnp.int32)
+    slots = jnp.asarray([int(table[0, p // PAGE]) * PAGE + p % PAGE
+                         for p in range(ctx + n)], jnp.int32)
+    pages = jnp.zeros((8, PAGE, attn.lanes))
+    if ctx:     # the prefix's rows, as an earlier chunk left them
+        pages = pages.reshape(-1, attn.lanes).at[slots[:ctx]].set(
+            attn._rows(c[0, :ctx], k_r[0, :ctx])).reshape(pages.shape)
+    meta = InputMetadata(
+        slot_mapping=slots[ctx:], block_tables=table,
+        context_lens=jnp.asarray([ctx], jnp.int32),
+        prompt_lens=jnp.asarray([n], jnp.int32), is_prompt=True,
+        use_prefix=bool(ctx))
+
+    def step():
+        out, _, expanded = attn(q_nope, q_rope, c[:, ctx:], k_r[:, ctx:],
+                                w_uk, w_uv, pages, meta)
+        assert int(expanded) == ctx
+        return np.asarray(out)
+    want = step()
+    assert want.shape == (1, n, heads * 128)
+    kernel, calls = flash.prefill_flash_attention, []
+
+    def interpreted(q, k, v, *args, **kwargs):
+        out = kernel(q, k, v, *args, interpret=True, **kwargs)
+        calls.append((q.shape, k.shape, v.shape, out.shape))
+        return out
+    monkeypatch.setattr(flash, "prefill_flash_attention", interpreted)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = step()
+    keys_seen = (ctx + n if not ctx else table.shape[1] * PAGE)
+    assert calls == [((1, n, heads, 256), (1, keys_seen, heads, 256),
+                      (1, keys_seen, heads, 128), (1, n, heads, 128))]
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
 # ---- the shares add up to the uncut layer ----
 
 def test_the_shares_add_up_to_the_uncut_layer():
