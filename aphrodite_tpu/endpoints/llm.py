@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Union
 
+from aphrodite_tpu.common import tracing
 from aphrodite_tpu.common.outputs import RequestOutput
 from aphrodite_tpu.common.sampling_params import SamplingParams
 from aphrodite_tpu.common.utils import Counter
@@ -39,6 +40,7 @@ class LLM:
         max_context_len_to_capture: int = 8192,
         **kwargs,
     ) -> None:
+        tracer = tracing.Tracer.at_entry()
         if "disable_log_stats" not in kwargs:
             kwargs["disable_log_stats"] = True
         engine_args = EngineArgs(
@@ -58,8 +60,11 @@ class LLM:
             max_context_len_to_capture=max_context_len_to_capture,
             **kwargs,
         )
-        self.engine = AphroditeEngine.from_engine_args(engine_args)
-        self.request_counter = Counter()
+        self.engine = AphroditeEngine.from_engine_args(engine_args,
+                                                       tracer=tracer)
+        with tracer.phase("setup.frontend"):
+            self.request_counter = Counter()
+        tracer.ready()
 
     def get_tokenizer(self):
         return self.engine.tokenizer.tokenizer

@@ -29,6 +29,7 @@ from aiohttp import web
 from prometheus_client import generate_latest, CONTENT_TYPE_LATEST
 from pydantic import ValidationError
 
+from aphrodite_tpu.common import tracing
 from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.logits_processor import BiasLogitsProcessor
 from aphrodite_tpu.common.outputs import RequestOutput
@@ -610,6 +611,7 @@ def build_app(engine: AsyncAphrodite, served_model: str,
 
 
 def main() -> None:
+    tracer = tracing.Tracer.at_entry()
     parser = argparse.ArgumentParser(
         description="Aphrodite-TPU OpenAI-compatible API server")
     parser.add_argument("--host", type=str, default=None)
@@ -628,7 +630,10 @@ def main() -> None:
     args = parser.parse_args()
 
     engine_args = AsyncEngineArgs.from_cli_args(args)
-    engine = AsyncAphrodite.from_engine_args(engine_args)
+    engine = AsyncAphrodite.from_engine_args(engine_args, tracer=tracer)
+    # Left by hand where the application has started (`ready`), with
+    # aiohttp about to open the sockets.
+    frontend = tracer.phase("setup.frontend").__enter__()
     served_model = args.served_model_name or args.model
     chat_template = None
     if args.chat_template:
@@ -641,6 +646,11 @@ def main() -> None:
         api_keys=args.api_keys.split(",") if args.api_keys else None,
         admin_keys=args.admin_key.split(",") if args.admin_key
         else None)
+
+    async def ready(_app: web.Application) -> None:
+        frontend.__exit__(None, None, None)
+        tracer.ready()
+    app.on_startup.append(ready)
     logger.info("Starting OpenAI-compatible server on %s:%d",
                 args.host or "0.0.0.0", args.port)
     web.run_app(app, host=args.host, port=args.port)

@@ -31,6 +31,7 @@ from typing import List, Optional, Sequence
 
 from aiohttp import web
 
+from aphrodite_tpu.common import tracing
 from aphrodite_tpu.common.logger import init_logger
 
 logger = init_logger(__name__)
@@ -307,6 +308,7 @@ async def _drain_then_exit(engine) -> None:
     engine.start_drain(reason="SIGTERM")
     clean = await engine.drained()
     engine.engine.executor.log_device_memory("at drain")
+    logger.info(tracing.BUILDS.summary())
     logger.info("Drain %s; exiting.",
                 "complete" if clean
                 else "deadline-forced (stragglers got typed errors)")

@@ -117,8 +117,16 @@ class AphroditeEngine:
         lora_config: Optional[LoRAConfig],
         log_stats: bool = False,
         skip_tokenizer_init: bool = False,
+        tracer: Optional[tracing.Tracer] = None,
     ) -> None:
-        dev = jax.devices()[0]
+        # The round's spans and per-stage accumulators, and those of
+        # set-up: the entry point's, which has spanned the imports by
+        # now (its own, when the engine is built alone). One for the
+        # engine's life, shared with every executor and scheduler it
+        # builds (a rebuild keeps the counts).
+        self.tracer = tracer or tracing.Tracer()
+        with self.tracer.phase("setup.backend"):
+            dev = jax.devices()[0]
         logger.info(
             "Initializing engine on platform=%s device_kind=%r "
             "device_count=%d: model=%r dtype=%s max_len=%d "
@@ -143,13 +151,10 @@ class AphroditeEngine:
         if skip_tokenizer_init:
             self.tokenizer = None
         else:
-            self._init_tokenizer()
+            with self.tracer.phase("setup.tokenizer"):
+                self._init_tokenizer()
         self.seq_counter = Counter()
 
-        # The round's spans and per-stage accumulators: one for the
-        # engine's life, shared with every executor and scheduler it
-        # builds (a rebuild keeps the counts).
-        self.tracer = tracing.Tracer()
         self.executor = TPUExecutor(model_config, cache_config,
                                     parallel_config, scheduler_config,
                                     device_config, lora_config,
@@ -249,10 +254,13 @@ class AphroditeEngine:
     # -- construction --
 
     @classmethod
-    def from_engine_args(cls, engine_args: EngineArgs) -> "AphroditeEngine":
+    def from_engine_args(
+            cls, engine_args: EngineArgs,
+            tracer: Optional[tracing.Tracer] = None) -> "AphroditeEngine":
         configs = engine_args.create_engine_configs()
         engine = cls(*configs, log_stats=not engine_args.disable_log_stats,
-                     skip_tokenizer_init=engine_args.skip_tokenizer_init)
+                     skip_tokenizer_init=engine_args.skip_tokenizer_init,
+                     tracer=tracer)
         return engine
 
     def _init_tokenizer(self, **kwargs) -> None:
@@ -1516,6 +1524,7 @@ class AphroditeEngine:
                 # Stats must never kill a step; the gauges just skip
                 # one tick.
                 logger.debug("lifecycle stats unavailable: %s", e)
+        self.tracer.fold_builds()
         return Stats(
             **lifecycle,
             now=now,
@@ -1540,5 +1549,6 @@ class AphroditeEngine:
             expired_total=self.admission.expired_total,
             ewma_prefill_tok_s=self.admission.ewma_prefill_tok_s,
             ewma_decode_tok_s=self.admission.ewma_decode_tok_s,
+            startup_seconds=self.tracer.startup_seconds,
             stage_seconds=self.tracer.seconds,
             stage_counts=self.tracer.counts)

@@ -333,10 +333,11 @@ class AsyncAphrodite:
 
     @classmethod
     def from_engine_args(cls, engine_args: AsyncEngineArgs,
-                         start_engine_loop: bool = True
+                         start_engine_loop: bool = True,
+                         tracer: Optional[tracing.Tracer] = None
                          ) -> "AsyncAphrodite":
         configs = engine_args.create_engine_configs()
-        return cls(*configs,
+        return cls(*configs, tracer=tracer,
                    log_stats=not engine_args.disable_log_stats,
                    skip_tokenizer_init=engine_args.skip_tokenizer_init,
                    log_requests=not engine_args.disable_log_requests,

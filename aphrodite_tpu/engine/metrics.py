@@ -129,7 +129,8 @@ _STAGE_COUNTERS = [
      "summed over.", lambda s, c: c["pull.blocked.decode"]),
     ("aphrodite:host_dispatch_seconds_total",
      "Seconds in the jitted calls that enqueue step programs, up to "
-     "their return (a compile shows here).",
+     "their return (a program built inside one lies here too; the "
+     "aphrodite:program_*_seconds_total counters say how long).",
      lambda s, c: s["runner.dispatch"]),
     ("aphrodite:step_call_seconds_total",
      "Seconds of the async loop's calls of AphroditeEngine.step: the "
@@ -195,20 +196,9 @@ _STAGE_COUNTERS = [
     ("aphrodite:eva_windows_closed_decode_total",
      "Windows that pooled page groups closed as a decode row passed "
      "their edge.", lambda s, c: c["attn.windows_closed_decode"]),
-    ("aphrodite:window_close_seconds_total",
-     "Seconds closing pooled groups' windows in the block manager "
-     "(inside the schedule seconds).",
-     lambda s, c: s["cache.window_close"]),
-    ("aphrodite:summarise_seconds_total",
-     "Seconds dispatching the program that pools closed windows into "
-     "their summary pages (inside the dispatch of a round).",
-     lambda s, c: s["runner.summarise"]),
     ("aphrodite:window_pages_freed_total",
      "KV pages that window page groups let go of, to the free list.",
      lambda s, c: c["cache.window_pages_freed"]),
-    ("aphrodite:window_release_seconds_total",
-     "Seconds letting window groups' passed pages go (inside the "
-     "schedule seconds).", lambda s, c: s["cache.window_release"]),
     ("aphrodite:kv_page_reads_shared_total",
      "Live KV pages of each page group times the layers whose "
      "attention reads them (a group's own and those that read "
@@ -273,6 +263,62 @@ _STAGE_COUNTERS = [
      "Experts times expert layers, summed over decode steps: what "
      "aphrodite:moe_decode_experts_touched_total cannot pass.",
      lambda s, c: c["moe.decode_expert_slots"]),
+    ("aphrodite:program_trace_seconds_total",
+     "Seconds the process spent tracing jitted functions, outermost "
+     "traces alone (a nested jit's trace lies inside its caller's), "
+     "whichever thread built; from jax.monitoring.",
+     lambda s, c: s["program.trace"]),
+    ("aphrodite:program_lower_seconds_total",
+     "Seconds the process spent lowering traced functions to MLIR "
+     "modules.", lambda s, c: s["program.lower"]),
+    ("aphrodite:program_compile_seconds_total",
+     "Seconds in the backend's compile stage: compiling, or on a hit in "
+     "the persistent cache loading the executable.",
+     lambda s, c: s["program.compile"]),
+    ("aphrodite:program_cache_load_seconds_total",
+     "Of aphrodite:program_compile_seconds_total, the seconds reading "
+     "executables from the persistent compilation cache.",
+     lambda s, c: s["program.cache_load"]),
+    ("aphrodite:programs_built_total",
+     "Programs the process built (compile stages ended), every jitted "
+     "function and every one-operation program of an eager call; one "
+     "that grows under load is a recompile, logged as `program "
+     "built:` with the round that met it.",
+     lambda s, c: c["program.compile"]),
+    ("aphrodite:program_cache_hits_total",
+     "Compile requests the persistent compilation cache answered.",
+     lambda s, c: c["program.cache_hit"]),
+    ("aphrodite:program_cache_misses_total",
+     "Compile requests the persistent compilation cache could not "
+     "answer: the backend compiled.",
+     lambda s, c: c["program.cache_miss"]),
+    ("aphrodite:setup_import_seconds_total",
+     "Set-up phase, seconds: from the start of the process to the "
+     "entry point's first line: the interpreter and the imports.",
+     lambda s, c: s["setup.import"]),
+    ("aphrodite:setup_backend_seconds_total",
+     "Set-up phase, seconds: the first jax.devices(): the "
+     "accelerator's runtime coming up.",
+     lambda s, c: s["setup.backend"]),
+    ("aphrodite:setup_tokenizer_seconds_total",
+     "Set-up phase, seconds: loading the tokenizer.",
+     lambda s, c: s["setup.tokenizer"]),
+    ("aphrodite:setup_weights_seconds_total",
+     "Set-up phase, seconds: loading or making the weights (and the "
+     "prefill group's copy of them).",
+     lambda s, c: s["setup.weights"]),
+    ("aphrodite:setup_kv_pool_seconds_total",
+     "Set-up phase, seconds: sizing the KV pool and the state slots "
+     "and allocating their arrays.",
+     lambda s, c: s["setup.kv_pool"]),
+    ("aphrodite:setup_runner_seconds_total",
+     "Set-up phase, seconds: building the model runner (and the LoRA "
+     "manager).",
+     lambda s, c: s["setup.runner"]),
+    ("aphrodite:setup_frontend_seconds_total",
+     "Set-up phase, seconds: from the engine built to the server's "
+     "start-up hooks done, its sockets about to open.",
+     lambda s, c: s["setup.frontend"]),
 ]
 
 
@@ -342,6 +388,13 @@ class Metrics:
             "Bytes a token takes of the KV pool, all layers: K/V pairs "
             "of every KV head, or the one latent row a layer of a model "
             "with latent pages.", labelnames)
+        self.gauge_startup = _get_or_create(
+            Gauge, "aphrodite:startup_seconds",
+            "Seconds from the start of the process to the server ready "
+            "for connections (an offline engine: to the end of its "
+            "construction); 0 until then. The "
+            "aphrodite:setup_*_seconds_total counters are its phases.",
+            labelnames)
         self.gauge_ssm_slots_live = _get_or_create(
             Gauge, "aphrodite:ssm_slots_live",
             "State slots that sequences hold.", labelnames)
@@ -408,6 +461,7 @@ class Stats:
     ssm_slots_total: int = 0
     ssm_slots_live: int = 0
     kv_bytes_per_token: int = 0
+    startup_seconds: float = 0.0
     sheds_total: int = 0
     expired_total: int = 0
     ewma_prefill_tok_s: float = 0.0
@@ -487,6 +541,7 @@ class StatLogger:
         labeled(m.gauge_ssm_slots_total).set(stats.ssm_slots_total)
         labeled(m.gauge_ssm_slots_live).set(stats.ssm_slots_live)
         labeled(m.gauge_kv_bytes_per_token).set(stats.kv_bytes_per_token)
+        labeled(m.gauge_startup).set(stats.startup_seconds)
         labeled(m.gauge_ewma_prefill).set(stats.ewma_prefill_tok_s)
         labeled(m.gauge_ewma_decode).set(stats.ewma_decode_tok_s)
         export(m.counter_requests_shed, stats.sheds_total)

@@ -188,53 +188,56 @@ class TPUExecutor:
                     dict(self.mesh.shape), self.mesh.size,
                     jax.devices()[0].platform)
         logger.info("Loading model %s ...", model_config.model)
-        self.model, self.params = get_model(model_config, self.mesh,
-                                            lora_config)
-        self.prefill_params = None
-        if self.prefill_mesh is not None:
-            self.prefill_params = self._stage_prefill_params()
+        with self.tracer.phase("setup.weights"):
+            self.model, self.params = get_model(model_config, self.mesh,
+                                                lora_config)
+            self.prefill_params = None
+            if self.prefill_mesh is not None:
+                self.prefill_params = self._stage_prefill_params()
 
-        self._profile_and_size_cache()
-        self.cache_engine = CacheEngine(cache_config, model_config,
-                                        parallel_config, self.mesh,
-                                        prefill_mesh=self.prefill_mesh)
+        with self.tracer.phase("setup.kv_pool"):
+            self._profile_and_size_cache()
+            self.cache_engine = CacheEngine(
+                cache_config, model_config, parallel_config, self.mesh,
+                prefill_mesh=self.prefill_mesh)
         self.log_device_memory("after load")
-        sp = None
-        if self.mesh is not None and \
-                parallel_config.sequence_parallel_size > 1:
-            sp = (self.mesh, parallel_config.sp_prefill_threshold)
-        self.model_runner = ModelRunner(
-            self.model, self.params, model_config, scheduler_config,
-            page_size=cache_config.block_size,
-            num_slots=self.cache_engine.num_slots,
-            mesh=self.mesh,
-            kv_scale=self.cache_engine.kv_scale,
-            sp=sp,
-            kv_cache_dtype=self.cache_engine.dtype,
-            tracer=self.tracer,
-            num_state_slots=cache_config.num_state_slots)
-        self.prefill_runner = self.model_runner
-        if self.prefill_mesh is not None:
-            self.prefill_runner = ModelRunner(
-                self.model, self.prefill_params, model_config,
-                scheduler_config,
+        with self.tracer.phase("setup.runner"):
+            sp = None
+            if self.mesh is not None and \
+                    parallel_config.sequence_parallel_size > 1:
+                sp = (self.mesh, parallel_config.sp_prefill_threshold)
+            self.model_runner = ModelRunner(
+                self.model, self.params, model_config, scheduler_config,
                 page_size=cache_config.block_size,
                 num_slots=self.cache_engine.num_slots,
-                mesh=self.prefill_mesh,
+                mesh=self.mesh,
                 kv_scale=self.cache_engine.kv_scale,
-                sp=None,
+                sp=sp,
                 kv_cache_dtype=self.cache_engine.dtype,
-                tracer=self.tracer)
+                tracer=self.tracer,
+                num_state_slots=cache_config.num_state_slots)
+            self.prefill_runner = self.model_runner
+            if self.prefill_mesh is not None:
+                self.prefill_runner = ModelRunner(
+                    self.model, self.prefill_params, model_config,
+                    scheduler_config,
+                    page_size=cache_config.block_size,
+                    num_slots=self.cache_engine.num_slots,
+                    mesh=self.prefill_mesh,
+                    kv_scale=self.cache_engine.kv_scale,
+                    sp=None,
+                    kv_cache_dtype=self.cache_engine.dtype,
+                    tracer=self.tracer)
 
-        self.lora_manager = None
-        if lora_config is not None:
-            from aphrodite_tpu.lora.models import layouts_from_model
-            from aphrodite_tpu.lora.worker_manager import WorkerLoRAManager
-            self.lora_manager = WorkerLoRAManager(
-                lora_config,
-                write_slot_fn=self.model_runner.write_lora_slot,
-                clear_slot_fn=self.model_runner.clear_lora_slot,
-                module_layouts=layouts_from_model(self.model))
+            self.lora_manager = None
+            if lora_config is not None:
+                from aphrodite_tpu.lora.models import layouts_from_model
+                from aphrodite_tpu.lora.worker_manager import WorkerLoRAManager
+                self.lora_manager = WorkerLoRAManager(
+                    lora_config,
+                    write_slot_fn=self.model_runner.write_lora_slot,
+                    clear_slot_fn=self.model_runner.clear_lora_slot,
+                    module_layouts=layouts_from_model(self.model))
 
     @property
     def mesh_shape(self) -> Optional[Tuple[int, int, int, int]]:

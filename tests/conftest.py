@@ -120,9 +120,16 @@ LATER_METRICS = (
     "host_lead_ms.batch", "host_lead_decode_ms.batch",
     "dispatch_starved_pct.batch", "dispatch_starved_prompt_pct.batch",
     "host_dispatch_ms.batch", "host_hops_ms.batch")
-#: and those appended after them (PR 45's), which every module that
-#: pins the manifest is spared
-NEWER_METRICS = ("prompt_dispatch_late_pct.batch",)
+#: PR 54's ten, which read the program's account of its own set-up
+#: and which every cell reports
+SETUP_METRICS = (
+    "startup_ready_s", "startup_backend_s", "startup_weights_s",
+    "startup_kv_pool_s", "program_trace_s", "program_lower_s",
+    "program_compile_s", "program_cache_hit_pct", "programs_built",
+    "program_build_in_window_pct.batch")
+#: and those appended after them (PR 45's, PR 54's), which every
+#: module that pins the manifest is spared
+NEWER_METRICS = ("prompt_dispatch_late_pct.batch",) + SETUP_METRICS
 #: cells appended since the pinning tests were written, oldest first
 #: (PR 41's, PR 43's, PR 48's, PR 52's), each with its configuration
 #: and the metrics it alone reports
@@ -135,7 +142,8 @@ _PINNED = {
     "test_perf_phi4flash": (NEWER_CELLS, LATER_METRICS + NEWER_METRICS),
     "test_perf_jamba": (NEWER_CELLS[1:], NEWER_METRICS),
     "test_perf_laguna": (NEWER_CELLS[2:], NEWER_METRICS),
-    "test_perf_evabyte": (NEWER_CELLS[3:], ()),
+    "test_perf_evabyte": (NEWER_CELLS[3:], SETUP_METRICS),
+    "test_perf_sarvam": ((), SETUP_METRICS),
 }
 #: the modules that hold the manifest's last places or a list's length
 #: to their own and read `BENCHMARK.json` with `json.load` (PR 38's six
@@ -143,7 +151,7 @@ _PINNED = {
 #: appended after what each holds
 _PINNED_BY_FILE = {
     "test_perf_host_lead": (NEWER_CELLS, NEWER_METRICS),
-    "test_perf_w4a8_share": (NEWER_CELLS[3:], ()),
+    "test_perf_w4a8_share": (NEWER_CELLS[3:], SETUP_METRICS),
 }
 
 
@@ -212,8 +220,13 @@ def _the_manifest_without_later_metrics(request, monkeypatch):
     `tests/perf/test_perf_host_lead.py` holds the six to the
     manifest's last places and the cells to a list of three, and loads
     the file itself: its `json` gives it the manifest without
-    `NEWER_CELLS` and `NEWER_METRICS`. The metrics and the cells have
-    tests of their own (`tests/perf/test_perf_host_lead.py`,
+    `NEWER_CELLS` and `NEWER_METRICS`. PR 54's ten (`SETUP_METRICS`)
+    are reported by every cell, so the modules written since then that
+    hold a cell's metrics to a count or the manifest's last places to
+    their own (`test_perf_evabyte.py`, `test_perf_sarvam.py`,
+    `test_perf_w4a8_share.py`) are spared them the same way; their own
+    tests are `tests/perf/test_perf_setup.py`. The metrics and the
+    cells have tests of their own (`tests/perf/test_perf_host_lead.py`,
     `tests/perf/test_perf_jamba.py`, `tests/perf/test_perf_laguna.py`,
     `tests/perf/test_perf_prompt_late.py`)."""
     module = request.module

@@ -87,9 +87,13 @@ def _drain(engine):
 
 
 def _tree(round_spans):
-    """[(name, parent)] of one round, without the `aph.` prefix."""
+    """[(name, parent)] of one round, without the `aph.` prefix: the
+    round's own stages (a program that a stage meets for the first
+    time is built inside it and named there, `aph.program.*`:
+    `test_tracing_setup.py`)."""
     return [(name[4:], parent and parent[4:])
-            for name, parent, _ in round_spans]
+            for name, parent, _ in round_spans
+            if not name.startswith("aph.program.")]
 
 
 # ---- the module ----
@@ -708,10 +712,29 @@ LEAD_COUNTERS = {
 }
 
 
-@pytest.mark.parametrize("counter", sorted(LEAD_COUNTERS))
+#: PR 54's: the stages of the programs the process builds and the
+#: phases from process start to ready
+SETUP_COUNTERS = {
+    "aphrodite:program_trace_seconds_total": ("s", "program.trace"),
+    "aphrodite:program_lower_seconds_total": ("s", "program.lower"),
+    "aphrodite:program_compile_seconds_total": ("s", "program.compile"),
+    "aphrodite:program_cache_load_seconds_total":
+        ("s", "program.cache_load"),
+    "aphrodite:programs_built_total": ("c", "program.compile"),
+    "aphrodite:program_cache_hits_total": ("c", "program.cache_hit"),
+    "aphrodite:program_cache_misses_total": ("c", "program.cache_miss"),
+    **{f"aphrodite:setup_{phase[len('setup.'):]}_seconds_total":
+       ("s", phase) for phase in tracing.SETUP_PHASES},
+}
+#: the accumulators of which two counters export a side each
+TWO_SIDED = ("pull.blocked", "pull.blocked.decode", "program.compile")
+
+
+@pytest.mark.parametrize("counter",
+                         sorted({**LEAD_COUNTERS, **SETUP_COUNTERS}))
 def test_a_lead_counter_reads_zero_before_its_first_event(counter):
     from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
-    kind, name = LEAD_COUNTERS[counter]
+    kind, name = {**LEAD_COUNTERS, **SETUP_COUNTERS}[counter]
     labels = dict(model_name="tracing-test-" + counter.split(":")[1])
     log = StatLogger(labels=labels)
     assert _value(counter, labels) == 0.0
@@ -723,8 +746,9 @@ def test_a_lead_counter_reads_zero_before_its_first_event(counter):
     # one name each: no two counters export the same accumulator
     reads = [doc for metric, doc, total in _STAGE_COUNTERS
              if total(tracer.seconds, tracer.counts)]
-    # (the lead's seconds and its pulls are one accumulator's two sides)
-    assert len(reads) == (2 if name.startswith("pull.blocked") else 1)
+    # (the lead's seconds and its pulls are one accumulator's two
+    # sides, as a compile stage's seconds and the programs built are)
+    assert len(reads) == (2 if name in TWO_SIDED else 1)
 
 
 #: the prompt attention's dispatch, counted where a prompt step is
@@ -736,15 +760,24 @@ PROMPT_KERNEL_COUNTERS = {
 }
 
 
-@pytest.mark.parametrize("counter", sorted(PROMPT_KERNEL_COUNTERS))
+#: the counts among `SETUP_COUNTERS`
+BUILD_COUNTS = {counter: name for counter, (kind, name)
+                in SETUP_COUNTERS.items() if kind == "c"}
+
+
+@pytest.mark.parametrize(
+    "counter", sorted({**PROMPT_KERNEL_COUNTERS, **BUILD_COUNTS}))
 def test_a_prompt_kernel_counter_is_exported_and_described(counter):
     """Each of the pair reads 0 before a prompt step, exports its own
     accumulator and no other, and goes by one name in `NAMES`, the
-    README's operator section and PERF.md."""
+    README's operator section and PERF.md; the counts of the programs
+    built and of the persistent cache's answers the same."""
     from aphrodite_tpu.engine.metrics import _STAGE_COUNTERS
-    name = PROMPT_KERNEL_COUNTERS[counter]
+    name = {**PROMPT_KERNEL_COUNTERS, **BUILD_COUNTS}[counter]
     assert name in tracing.NAMES
-    labels = dict(model_name="tracing-test-" + counter.split(":")[1])
+    # (the counts are cases of the test above too: a label of its own)
+    labels = dict(model_name="tracing-test-described-" +
+                  counter.split(":")[1])
     log = StatLogger(labels=labels)
     assert _value(counter, labels) == 0.0
     tracer = tracing.Tracer()
