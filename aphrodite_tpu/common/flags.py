@@ -235,9 +235,10 @@ _register(Flag(
 
 _register(Flag(
     "APHRODITE_COMPILE_CACHE", "str", "",
-    "JAX persistent compilation cache: 0 disables, a path redirects; "
-    "unset uses $XDG_CACHE_HOME/aphrodite_tpu/jax_cache (TPU backend "
-    "only — CPU test runs skip persisting)."))
+    "JAX persistent compilation cache, and under it (programs/) the "
+    "store of the step programs' executables: 0 disables both, a path "
+    "redirects; unset uses $XDG_CACHE_HOME/aphrodite_tpu/jax_cache "
+    "(TPU backend only — CPU test runs skip persisting)."))
 
 _register(Flag(
     "APHRODITE_DEBUG_KV", "bool", False,

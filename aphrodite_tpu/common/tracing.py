@@ -174,6 +174,13 @@ NAMES = (
                             # executables from the persistent cache
     "program.cache_hit",    # compile requests the persistent cache
     "program.cache_miss",   # answered, and those it could not
+    "program.store_hit",    # step programs the program store had
+                            # (`executor/program_store.py`): loaded, never
+                            # traced or lowered; each also one
+                            # `program.compile` and one `program.cache_hit`
+    "program.store_miss",   # step programs it had not: built, and kept
+    "program.store_load",   # seconds reading and loading the hits (also
+                            # in `program.compile` and `program.cache_load`)
 )
 
 #: the phases from process start to ready, in the order a server
@@ -238,6 +245,12 @@ class Builds:
                 row[0] += 1
             self.nested_traces += nested
 
+    def count(self, name: str, secs: float = 0.0) -> None:
+        """One more of `name`, which is no stage of a build."""
+        with self.lock:
+            self.seconds[name] += secs
+            self.counts[name] += 1
+
     def add_nested(self, fun: str, secs: float) -> None:
         """A trace of `fun`, one of `KERNEL_JITS`, inside another."""
         with self.lock:
@@ -271,8 +284,11 @@ class Builds:
             f"s, compile or load {s['program.compile']:.3f} s, of it "
             f"{s['program.cache_load']:.3f} s reading the cache: "
             f"{c['program.cache_hit']} hits, {c['program.cache_miss']} "
-            f"misses), {self.nested_traces} nested traces; by function, "
-            "builds x trace/lower/compile s: " + "; ".join(parts))
+            f"misses; the program store {c['program.store_hit']} hits "
+            f"in {s['program.store_load']:.3f} s, "
+            f"{c['program.store_miss']} misses), {self.nested_traces} "
+            "nested traces; by function, builds x trace/lower/compile s: "
+            + "; ".join(parts))
 
 
 #: the one account of the process's builds
@@ -300,7 +316,7 @@ class _Building:
     and the tracer whose round it works for."""
 
     __slots__ = ("depth", "nested", "generation", "tracer", "trace_s",
-                 "lower_s", "cache", "open")
+                 "lower_s", "cache", "last_cache", "open")
 
     def __init__(self) -> None:
         self.depth = 0
@@ -308,7 +324,9 @@ class _Building:
         self.generation = _generation
         self.tracer: Optional["Tracer"] = None
         self.trace_s = self.lower_s = 0.0
-        self.cache = "off"
+        #: what the persistent cache said of the build in the middle,
+        #: and of the last one that ended
+        self.cache = self.last_cache = "off"
         #: (stage, annotation) of the stages open under the profiler
         self.open: list = []
 
@@ -382,16 +400,44 @@ def _stage_ended(event: str, secs: float, fun_name: str = "",
     if name == "program.lower":
         b.lower_s = secs
     elif name == "program.compile":
-        facts = b.tracer.facts if b.tracer is not None else {}
-        logger.info(
-            "program built: fun=%s round=%s path=%s rows=%s "
-            "prompt_tokens=%s trace=%.3f lower=%.3f compile=%.3f "
-            "cache=%s", fun_name, facts.get("round", "-"),
-            facts.get("path", "-"), facts.get("rows", "-"),
-            facts.get("prompt_tokens", "-"), b.trace_s, b.lower_s, secs,
-            b.cache)
+        _log_built(fun_name, b.tracer.facts if b.tracer is not None
+                   else {}, b.trace_s, b.lower_s, secs, b.cache)
         b.trace_s = b.lower_s = 0.0
-        b.cache = "off"
+        b.last_cache, b.cache = b.cache, "off"
+
+
+def _log_built(fun: str, facts: dict, trace_s: float, lower_s: float,
+               compile_s: float, cache: str) -> None:
+    logger.info(
+        "program built: fun=%s round=%s path=%s rows=%s "
+        "prompt_tokens=%s trace=%.3f lower=%.3f compile=%.3f "
+        "cache=%s", fun, facts.get("round", "-"),
+        facts.get("path", "-"), facts.get("rows", "-"),
+        facts.get("prompt_tokens", "-"), trace_s, lower_s, compile_s,
+        cache)
+
+
+def last_build_cache() -> str:
+    """What the persistent cache said of the last program this thread
+    built: "hit", "miss", or "off" where none is configured."""
+    return _building().last_cache
+
+
+def store_loaded(fun: str, secs: float) -> None:
+    """This thread took `fun` from the program store in `secs`: no
+    trace, no lowering. Filed under the store's own names and as what
+    it stands in the place of, a program made ready by a load from
+    disk (`program.compile`, `program.cache_load`, `program.cache_hit`),
+    and logged as a build is: `JAX_LOG_COMPILES` says nothing of it."""
+    BUILDS.add("program.compile", fun, secs)
+    with BUILDS.lock:
+        for name in ("program.cache_load", "program.store_load"):
+            BUILDS.seconds[name] += secs
+        for name in ("program.cache_hit", "program.store_hit"):
+            BUILDS.counts[name] += 1
+    tracer = _building().tracer
+    _log_built(fun, tracer.facts if tracer is not None else {}, 0.0, 0.0,
+               secs, "store")
 
 
 def _cache_answered(event: str, **_) -> None:

@@ -5,8 +5,10 @@
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import socket
+import threading
 import uuid
 from collections import OrderedDict
 from typing import Generic, Hashable, Optional, TypeVar
@@ -113,11 +115,37 @@ def pad_to_multiple(x: int, multiple: int) -> int:
     return cdiv(x, multiple) * multiple
 
 
-@functools.lru_cache(maxsize=None)
+_noting = threading.local()
+
+
 def note_kernel_path(family: str, side: str, detail: str) -> None:
     """Log, once per distinct choice, which side of a kernel dispatcher
     a step program was traced with: `side` is "pallas" (the compiled
     Mosaic kernel) or "reference" (the jnp/XLA path). Dispatchers call
     this at trace time; `chip_smoke.py` reads the lines back from the
-    server log and fails when a tp=1 family took the reference."""
+    server log and fails when a tp=1 family took the reference. A
+    program that is loaded and not traced (`executor/program_store.py`)
+    says again what its trace said (`kernel_paths_noted`)."""
+    notes = getattr(_noting, "notes", None)
+    if notes is not None and (family, side, detail) not in notes:
+        notes.append((family, side, detail))
+    _log_kernel_path(family, side, detail)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_kernel_path(family: str, side: str, detail: str) -> None:
     logger.info("kernel path: %s = %s (%s)", family, side, detail)
+
+
+@contextlib.contextmanager
+def kernel_paths_noted():
+    """The `(family, side, detail)` of every `note_kernel_path` this
+    thread makes inside the block, logged before or not, in order. (A
+    note inside a nested jit whose trace the process already has is
+    not made again: `w4a8_unpack` is the one such.)"""
+    before = getattr(_noting, "notes", None)
+    _noting.notes = notes = []
+    try:
+        yield notes
+    finally:
+        _noting.notes = before

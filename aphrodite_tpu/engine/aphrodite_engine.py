@@ -39,6 +39,7 @@ from aphrodite_tpu.engine.supervisor import (FaultClass,
                                              RequestLostOnRebuild,
                                              StaleEngineStepError,
                                              classify_failure)
+from aphrodite_tpu.executor import program_store
 from aphrodite_tpu.executor.executor import Round, TPUExecutor
 from aphrodite_tpu.processing.admission import (AdmissionController,
                                                 AdmissionSnapshot,
@@ -76,22 +77,11 @@ def _enable_compilation_cache() -> None:
     disk instead of compiling it again: a cold bucket lattice is the
     dominant term in cold-start TTFT. Opt out with
     APHRODITE_COMPILE_CACHE=0 or redirect with
-    APHRODITE_COMPILE_CACHE=<dir>."""
-    loc = flags.get_str("APHRODITE_COMPILE_CACHE")
-    if loc == "0":
+    APHRODITE_COMPILE_CACHE=<dir> (`program_store.cache_dir`, which
+    the step programs' own store follows)."""
+    loc = program_store.cache_dir()
+    if loc is None:
         return
-    if not loc:
-        if jax.default_backend() == "cpu":
-            # CPU compiles are fast (tests/dev): persisting every tiny
-            # program would just grow the cache unboundedly.
-            return
-        loc = os.path.join(
-            os.environ.get("XDG_CACHE_HOME",
-                           os.path.expanduser("~/.cache")),
-            "aphrodite_tpu", "jax_cache")
-    # Executables are compiled for one backend; keep each backend's
-    # entries in its own subdirectory.
-    loc = os.path.join(loc, jax.default_backend())
     try:
         os.makedirs(loc, exist_ok=True)
     except OSError as e:    # the cache is an optimization, never fatal

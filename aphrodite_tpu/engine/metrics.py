@@ -292,6 +292,21 @@ _STAGE_COUNTERS = [
      "Compile requests the persistent compilation cache could not "
      "answer: the backend compiled.",
      lambda s, c: c["program.cache_miss"]),
+    ("aphrodite:program_store_hits_total",
+     "Step programs the program store had (executor/program_store.py): "
+     "loaded, never traced or lowered. Each is also one of "
+     "aphrodite:programs_built_total and of "
+     "aphrodite:program_cache_hits_total.",
+     lambda s, c: c["program.store_hit"]),
+    ("aphrodite:program_store_misses_total",
+     "Step programs the program store had not: built as before, and "
+     "kept for the next process.",
+     lambda s, c: c["program.store_miss"]),
+    ("aphrodite:program_store_load_seconds_total",
+     "Seconds reading and loading the store's hits (also in "
+     "aphrodite:program_compile_seconds_total and "
+     "aphrodite:program_cache_load_seconds_total).",
+     lambda s, c: s["program.store_load"]),
     ("aphrodite:setup_import_seconds_total",
      "Set-up phase, seconds: from the start of the process to the "
      "entry point's first line: the interpreter and the imports.",

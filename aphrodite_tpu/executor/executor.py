@@ -30,6 +30,7 @@ from aphrodite_tpu.common.sequence import SequenceGroupMetadata
 from aphrodite_tpu.executor.cache_engine import CacheEngine
 from aphrodite_tpu.executor.model_runner import (_DECODE_BATCH_BUCKETS,
                                                   ModelRunner, StepHandle)
+from aphrodite_tpu.executor.program_store import ProgramStore
 from aphrodite_tpu.modeling.loader import get_model
 
 logger = init_logger(__name__)
@@ -215,7 +216,8 @@ class TPUExecutor:
                 sp=sp,
                 kv_cache_dtype=self.cache_engine.dtype,
                 tracer=self.tracer,
-                num_state_slots=cache_config.num_state_slots)
+                num_state_slots=cache_config.num_state_slots,
+                program_store=self._open_program_store())
             self.prefill_runner = self.model_runner
             if self.prefill_mesh is not None:
                 self.prefill_runner = ModelRunner(
@@ -238,6 +240,26 @@ class TPUExecutor:
                     write_slot_fn=self.model_runner.write_lora_slot,
                     clear_slot_fn=self.model_runner.clear_lora_slot,
                     module_layouts=layouts_from_model(self.model))
+
+    def _open_program_store(self):
+        """The store of this engine's step programs, keyed by what
+        their traces read of the engine: the published configuration,
+        the engine's own, and the quantisation's (read as `get_model`
+        read it). None under a mesh, and wherever
+        `ProgramStore.open` finds none to keep."""
+        if self.mesh is not None or self.prefill_mesh is not None:
+            return None
+        quantisation = None
+        if self.model_config.quantization is not None:
+            from aphrodite_tpu.modeling.layers.quantization import (
+                get_quantization_config)
+            quantisation = get_quantization_config(self.model_config)
+        return ProgramStore.open(
+            self.model, published=self.model_config.hf_config,
+            engine=self.model_config, cache=self.cache_config,
+            parallel=self.parallel_config,
+            scheduler=self.scheduler_config, lora=self.lora_config,
+            quantisation=quantisation)
 
     @property
     def mesh_shape(self) -> Optional[Tuple[int, int, int, int]]:

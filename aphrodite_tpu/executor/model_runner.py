@@ -33,6 +33,7 @@ from aphrodite_tpu.common.logger import init_logger
 from aphrodite_tpu.common.sampling_params import SamplingType
 from aphrodite_tpu.common.sequence import (SamplerOutput,
                                            SequenceGroupMetadata)
+from aphrodite_tpu.executor.program_store import StoredProgram
 from aphrodite_tpu.modeling.input_metadata import GroupView, InputMetadata
 from aphrodite_tpu.modeling.layers.attention import (takes_blocked_prefill,
                                                      takes_prefill_kernel)
@@ -189,9 +190,15 @@ class ModelRunner:
         kv_cache_dtype=jnp.bfloat16,
         tracer: Optional[tracing.Tracer] = None,
         num_state_slots: Optional[int] = None,
+        program_store=None,     # a ProgramStore, or None
     ) -> None:
         # The engine's span accumulators (its own, when built alone).
         self.tracer = tracer or tracing.Tracer()
+        # Where the step programs' executables are kept between
+        # processes (`executor/program_store.py`); never under a mesh,
+        # whose jitted path is left as it is.
+        self.program_store = program_store \
+            if mesh is None and sp is None else None
         self.model = model
         self.params = params
         self.model_config = model_config
@@ -288,27 +295,27 @@ class ModelRunner:
 
         # One jitted program per (is_prompt, use_prefix); shape buckets
         # land in XLA's compile cache keyed by array shapes.
-        self._step_fn = jax.jit(
+        self._step_fn = self._stored(jax.jit(
             self._step,
             static_argnames=("is_prompt", "use_prefix"),
             donate_argnums=(3,),      # kv_caches
-        )
+        ), donated=(3,))
         # Single-dispatch step+sample: one device program and ONE host
         # sync per scheduling round (the two-program split pays a
         # second dispatch and a second sync every round). Routes
         # needing raw logits (host logits processors, logprobs) use
         # _step_fn instead.
-        self._step_sample_fn = jax.jit(
+        self._step_sample_fn = self._stored(jax.jit(
             self._step_sample,
             static_argnames=("is_prompt", "use_prefix", "max_best_of",
                              "num_topk"),
             donate_argnums=(3,),      # kv_caches
-        )
-        self._burst_scan_fn = jax.jit(
+        ), donated=(3,))
+        self._burst_scan_fn = self._stored(jax.jit(
             self._burst_scan,
             static_argnames=("max_best_of", "num_topk", "num_steps"),
             donate_argnums=(3,),      # kv_caches
-        )
+        ), donated=(3,))
         self._copy_fn = jax.jit(self._copy_blocks, donate_argnums=(0,))
         self._copy_state_fn = jax.jit(self._copy_state,
                                       donate_argnums=(0,))
@@ -316,16 +323,36 @@ class ModelRunner:
         # finished (a model with a pooled page group): a program of
         # its own, so that a step in which no row closes a window
         # pays nothing.
-        self._summarise_fn = jax.jit(self._summarise,
-                                     donate_argnums=(1,))
+        self._summarise_fn = self._stored(jax.jit(
+            self._summarise, donate_argnums=(1,)), donated=(1,))
         # Small, and apart from the step programs on purpose: a decode
         # step whose tokens are still on the device takes them through
         # this, so `_step_sample` keeps its signature and its compiled
         # programs.
-        self._feed_fn = jax.jit(self._feed,
-                                out_shardings=self._input_sharding)
+        self._feed_fn = self._stored(jax.jit(
+            self._feed, out_shardings=self._input_sharding))
         # The prompt-step source of a feed that has none, by shape.
         self._no_source: Dict[tuple, jax.Array] = {}
+
+    def _stored(self, jitted, donated: Tuple[int, ...] = ()):
+        """`jitted`, one of this runner's programs, behind the program
+        store where there is one: a process that asks for a program
+        the store has loads it and neither traces nor lowers.
+        `donated`: its `donate_argnums`, the page arrays. They and the
+        parameters before them keep their shapes for the process's
+        life: the key holds them, a round's lookup skips them. What
+        the function reads of this runner beside the engine's
+        configurations goes into the key with them."""
+        if self.program_store is None:
+            return jitted
+        return StoredProgram(
+            self.program_store, jitted, jitted.__name__,
+            closes_over=dict(
+                page_size=self.page_size, num_slots=self.num_slots,
+                num_state_slots=self.num_state_slots,
+                kv_scale=self.kv_scale),
+            donate_argnums=donated,
+            stable_argnums=(0,) + donated if donated else ())
 
     # ---- mesh placement helpers ----
 

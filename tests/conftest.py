@@ -40,6 +40,21 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import pytest  # noqa: E402
 
+# The step programs' store (`executor/program_store.py`) keeps an
+# executable under what a trace of it READS: source files, flags,
+# configurations, shapes. A test that puts a spy or a stub in the place
+# of a function and builds an engine changes none of those, so under
+# the store its engine would load the program an earlier test traced
+# and the stub would never run. Engines built inside the suite's own
+# processes therefore keep no store (the servers the suite starts as
+# children, which inherit `APHRODITE_COMPILE_CACHE`, do);
+# `tests/executor/test_program_store.py` puts `open_program_store`
+# back for its own engines, on a directory of their own.
+from aphrodite_tpu.executor.program_store import ProgramStore  # noqa: E402
+
+open_program_store = ProgramStore.__dict__["open"]
+ProgramStore.open = classmethod(lambda cls, *args, **configs: None)
+
 
 @pytest.fixture(scope="session")
 def cpu_devices():
@@ -111,6 +126,30 @@ def tiny_llm(tiny_model_dir):
     return LLM(model=tiny_model_dir, load_format="dummy", dtype="float32",
                block_size=16, max_model_len=256, max_num_seqs=16,
                swap_space=0.01)
+
+
+@pytest.fixture(scope="module")
+def program_store_dir(tmp_path_factory):
+    """A compile cache directory of the module's own, cold: JAX's
+    persistent cache and the step programs' store under it, which the
+    engines the module builds keep. Yields the store's directory."""
+    from jax.experimental.compilation_cache import compilation_cache
+    root = tmp_path_factory.mktemp("compile-cache")
+    patch = pytest.MonkeyPatch()
+    patch.setenv("APHRODITE_COMPILE_CACHE", str(root))
+    patch.setattr(ProgramStore, "open", open_program_store)
+    before = jax.config.jax_compilation_cache_dir
+    # (JAX opens its cache once a process; point it where an engine
+    # built under this directory would, so that a test which builds
+    # none meets a cold cache too)
+    jax.config.update("jax_compilation_cache_dir", str(root / "cpu"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    yield str(root / "cpu" / "programs")
+    patch.undo()
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
 
 
 # ---- the benchmark's manifest, for the tests that pin it ----
