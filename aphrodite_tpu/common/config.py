@@ -58,9 +58,13 @@ class PageGroups:
       pages and the summary pages of the blocks behind, and the layer
       attends over the one table `[summary pages ; block's pages]`
       (`processing/block_manager.py`);
-    - it **holds nothing** (a state-space layer, a gated unit, an MLP:
-      whatever it keeps per sequence is no KV page): `group_of_layer`
-      and `slot_of_layer` are -1;
+    - it **holds nothing** (a state-space layer, a delta-rule layer, a
+      gated unit, an MLP: whatever it keeps per sequence is no KV
+      page): `group_of_layer` and `slot_of_layer` are -1. Such a layer
+      may keep recurrent state in the sequence's state slot
+      (`stateful`) while the model's other layers hold pages of either
+      kind, latent ones too: a model states `latent` and `stateful`
+      together, and is refused what either is refused;
     - it **reads layer k's pages** (a cross-attention layer over
       another layer's K and V): it writes no page, and its group and
       place are layer k's.
@@ -182,8 +186,13 @@ class PageGroups:
 #: refused, by name, and where: the one list. The first four are the
 #: refusals of every model that is not `PageGroups.plain`
 #: (`BlockSpaceManager._plain_only`, `AphroditeEngine.add_request`,
-#: the engine's burst and speculative eligibility); the last three are
-#: `TPUExecutor.__init__`'s (`refuse_for_latent_pages`).
+#: the engine's burst and speculative eligibility), so a model that
+#: keeps recurrent state beside its pages (`PageGroups.stateful`) is
+#: refused them at the same places and by the same names, and what
+#: such a model is refused beyond them joins at `TPUExecutor.__init__`
+#: (`disagg_split`) and `CacheEngine._allocate_state` (a mesh); the
+#: last three are `TPUExecutor.__init__`'s (`refuse_for_latent_pages`).
+#: A model with both is refused the union, and there is no second list.
 LATENT_PAGE_REFUSALS = (
     "preemption by swap", "the prefix cache", "bursts",
     "speculative rounds", "kv_handoff (disagg_split)",
@@ -381,7 +390,8 @@ class ModelConfig:
             return PageGroups.of(
                 kinds, self.get_sliding_window(),
                 stateful=self.get_state_spec() is not None,
-                pooled_window=getattr(cfg, "pooled_window", None))
+                pooled_window=getattr(cfg, "pooled_window", None),
+                latent=getattr(cfg, "latent_value_lanes", None))
         layout = getattr(cfg, "sliding_window_layout", None)
         if layout is not None:
             return PageGroups.of([bool(x) for x in layout],

@@ -231,6 +231,9 @@ class SequenceGroup:
         # finished output and none before
         # (`AphroditeEngine._outputs_of`).
         self.final_only = final_only
+        # Its text is made when it ends and not a token at a time
+        # (`AphroditeEngine._text_at_end`, set where it is admitted).
+        self.text_at_end = False
         # Mid-stream continuation (engine resume seam): how many
         # output tokens were already emitted to the client by a prior
         # incarnation of this request, and the text they detokenized

@@ -142,6 +142,13 @@ NAMES = (
     "ssm.prefill_tokens",   # prompt tokens its chunk scans went over
     "ssm.slot_waits",       # admission verdicts of "later" for want of a
                             # state slot while the pages were there
+    "kda.decode_rows",      # decode rows of a model with delta-rule
+                            # (KDA) layers: one-token state updates a
+                            # layer (`ops/pallas/kda.py::kda_update`)
+    "kda.prompt_tokens",    # live prompt tokens its chunk kernel went
+                            # over (a layer's), padding apart
+    "kda.prompt_chunks",    # and the chunks of `kda.CHUNK` tokens that
+                            # held at least one of them
     "attn.page_reads_shared",  # a decode step's live pages, each group's
                             # times the layers that read them
     "attn.prefill_tiles_visited",  # (query block, key block) tiles the

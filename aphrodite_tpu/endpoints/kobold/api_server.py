@@ -24,6 +24,7 @@ from aphrodite_tpu.endpoints.utils import (install_lifecycle,
                                            resume_denied,
                                            resume_token_ids,
                                            retry_after_headers,
+                                           settle_collector,
                                            stream_journal)
 from aphrodite_tpu.engine.args_tools import AsyncEngineArgs
 from aphrodite_tpu.engine.async_aphrodite import AsyncAphrodite
@@ -349,6 +350,7 @@ def main() -> None:
     app = build_app(engine, args.served_model_name or args.model,
                     admin_keys=args.admin_key.split(",")
                     if args.admin_key else None)
+    settle_collector()
     web.run_app(app, host=args.host, port=args.port)
 
 

@@ -48,6 +48,7 @@ from aphrodite_tpu.endpoints.utils import (final_output,
                                            resume_denied,
                                            resume_token_ids,
                                            retry_after_headers,
+                                           settle_collector,
                                            stream_journal)
 from aphrodite_tpu.engine.args_tools import AsyncEngineArgs
 from aphrodite_tpu.engine.async_aphrodite import AsyncAphrodite
@@ -648,6 +649,7 @@ def main() -> None:
         else None)
 
     async def ready(_app: web.Application) -> None:
+        settle_collector()
         frontend.__exit__(None, None, None)
         tracer.ready()
     app.on_startup.append(ready)

@@ -221,6 +221,21 @@ _STAGE_COUNTERS = [
      "slot while its pages were free (one each round the prompt at "
      "the head of the queue waits so).",
      lambda s, c: c["ssm.slot_waits"]),
+    ("aphrodite:kda_decode_rows_total",
+     "Decode rows of a model with delta-rule (KDA) layers: one-token "
+     "updates of a row's matrix state a KDA layer, summed over decode "
+     "steps.",
+     lambda s, c: c["kda.decode_rows"]),
+    ("aphrodite:kda_prompt_tokens_total",
+     "Live prompt tokens the chunk kernel of a model with KDA layers "
+     "went over (a KDA layer's), padding apart, summed over prompt "
+     "steps.",
+     lambda s, c: c["kda.prompt_tokens"]),
+    ("aphrodite:kda_prompt_chunks_total",
+     "Chunks of the KDA chunk kernel's size (64 tokens) that held at "
+     "least one live prompt token (a KDA layer's), summed over prompt "
+     "steps.",
+     lambda s, c: c["kda.prompt_chunks"]),
     ("aphrodite:mla_latent_tokens_read_total",
      "Context lengths of the decode rows of a model whose pages are "
      "latent (multi-head latent attention), summed over decode steps: "

@@ -269,6 +269,12 @@ class ModelRunner:
         #: (`tracing.NAMES`), pulled with a step's result
         self.step_counters: Tuple[str, ...] = tuple(
             getattr(model, "step_counters", ()))
+        #: tokens a chunk of the model's delta-rule layers takes
+        #: (`ops/pallas/kda.py`), None for every other model: such a
+        #: model's state layers count as `kda.*`, a Mamba model's as
+        #: `ssm.*`
+        self.kda_chunk_tokens: Optional[int] = getattr(
+            model, "kda_chunk_tokens", None)
         #: queries x keys a row from which the model's prompt attention
         #: goes in tiles (`PagedAttention.blocked_from`)
         self.prefill_blocked_from: int = getattr(
@@ -755,7 +761,13 @@ class ModelRunner:
             # (a row at position 0 starts from zeros in the program)
             self.tracer.add("ssm.state_resets",
                             count=sum(c == 0 for c in ctxs))
-            self.tracer.add("ssm.prefill_tokens", count=sum(prompt_lens))
+            if self.kda_chunk_tokens:
+                self.tracer.add("kda.prompt_tokens", count=sum(prompt_lens))
+                self.tracer.add("kda.prompt_chunks", count=sum(
+                    -(-n // self.kda_chunk_tokens) for n in prompt_lens))
+            else:
+                self.tracer.add("ssm.prefill_tokens",
+                                count=sum(prompt_lens))
         metadata = InputMetadata(
             slot_mapping=views[0].slot_mapping,
             block_tables=views[0].block_tables,
@@ -1170,7 +1182,8 @@ class ModelRunner:
         if state_slots is not None:
             rows[:, -1] = self.num_state_slots
             rows[:batch, -1] = state_slots
-            self.tracer.add("ssm.decode_rows", count=batch)
+            self.tracer.add("kda.decode_rows" if self.kda_chunk_tokens
+                            else "ssm.decode_rows", count=batch)
         rows[:batch, 0] = tokens
         rows[:batch, 1] = positions
         rows[:batch, 2] = slot_list

@@ -43,6 +43,17 @@ chip) and a `jax.numpy` side (the CPU, a mesh):
 A position where `delta` is 0 leaves the state as it is (exp(0) = 1,
 no input), which is how a chunk's padding is passed over: the caller
 zeroes `delta` there.
+
+**The slot conventions**, stated here once for every kernel over state
+slots (`ops/pallas/kda.py`'s two point here): (1) the layer is a
+prefetched scalar beside the slot ids, never a static argument, so a
+model's state layers share one trace; (2) a call's blocks are `(layer,
+slot)` of the model's WHOLE array, which is aliased to the result
+(`input_output_aliases`) and updated in place, and no other slot or
+layer is touched; (3) a decode step's rows reach the kernel eight a
+block (`_row_blocks`), a row a grid cell; (4) pad rows hold the last
+slot, the scratch one; (5) a fresh row's slot is never read into the
+state (a select on `fresh`, not a multiply).
 """
 from __future__ import annotations
 

@@ -36,6 +36,7 @@ def get_config(model: str,
                "smallthinker": configs.SmallThinkerConfig,
                "phi4flash": configs.Phi4FlashConfig,
                "jamba": configs.JambaConfig,
+               "kimi_linear": configs.KimiLinearConfig,
                "laguna": configs.LagunaConfig,
                "evabyte": configs.EvaByteConfig,
                "sarvam_mla": configs.SarvamMLAConfig}.get(declared)

@@ -4,6 +4,8 @@ model_type transformers doesn't ship (reference
 classes avoids trust_remote_code."""
 from aphrodite_tpu.transformers_utils.configs.evabyte import EvaByteConfig
 from aphrodite_tpu.transformers_utils.configs.jamba import JambaConfig
+from aphrodite_tpu.transformers_utils.configs.kimi_linear import (
+    KimiLinearConfig)
 from aphrodite_tpu.transformers_utils.configs.laguna import LagunaConfig
 from aphrodite_tpu.transformers_utils.configs.phi4flash import (
     Phi4FlashConfig)
@@ -14,6 +16,6 @@ from aphrodite_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
 from aphrodite_tpu.transformers_utils.configs.yi import YiConfig
 
-__all__ = ["EvaByteConfig", "JambaConfig", "LagunaConfig",
-           "Phi4FlashConfig", "QWenConfig", "SarvamMLAConfig",
+__all__ = ["EvaByteConfig", "JambaConfig", "KimiLinearConfig",
+           "LagunaConfig", "Phi4FlashConfig", "QWenConfig", "SarvamMLAConfig",
            "SmallThinkerConfig", "YiConfig"]

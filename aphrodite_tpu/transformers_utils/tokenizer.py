@@ -255,6 +255,79 @@ def _special_ids(tokenizer: AnyTokenizer) -> frozenset:
     return ids
 
 
+def decodes_bytes(tokenizer: AnyTokenizer) -> bool:
+    """Whether this tokenizer's text is its tokens' BYTES, joined and
+    then read as UTF-8: a fast tokenizer with the `ByteLevel` decoder
+    (GPT-2's, and that of the families that followed it). Read once a
+    tokenizer. For such a tokenizer `detokenize_whole` is
+    `detokenize_incrementally`'s text."""
+    known = getattr(tokenizer, "_aphrodite_decodes_bytes", None)
+    if known is None:
+        from tokenizers import decoders
+        known = bool(getattr(tokenizer, "is_fast", False)) and isinstance(
+            tokenizer.backend_tokenizer.decoder, decoders.ByteLevel)
+        tokenizer._aphrodite_decodes_bytes = known
+    return known
+
+
+def detokenize_whole(
+    tokenizer: AnyTokenizer,
+    prompt_ids: List[int],
+    output_ids: List[int],
+    skip_special_tokens: bool = False,
+) -> str:
+    """The text `detokenize_incrementally` gives over `output_ids`, a
+    token at a time from the first, all its pieces joined: in two or
+    three calls of the decoder and not two a token. For a tokenizer
+    that `decodes_bytes`, and for a row that nobody reads before it
+    ends (no stream, no stop string).
+
+    Why it is the same text. A step emits what its window's text has
+    beyond the text of the part already read, and only where the
+    window's text is longer and does not end in a replacement
+    character; it then reads on from there. Text is bytes read as
+    UTF-8 with replacement, left to right, so a window's text that
+    does not end in a replacement character ends on a character's
+    last byte, and what follows reads the same with or without what
+    came before. The pieces therefore join to the text of the FIRST
+    window (the prompt's last tokens and every output token) beyond
+    the text of its prompt part, up to the last token at which a
+    step emitted: the last one that has bytes and leaves the text
+    on a whole character. What lies behind it, an unfinished
+    character, no step ever emits.
+    (`tests/test_detokenize.py` holds the two against each other.)"""
+    if not output_ids:
+        return ""
+    tokens = tokenizer.convert_ids_to_tokens(
+        list(prompt_ids) + output_ids[:1],
+        skip_special_tokens=skip_special_tokens)
+    num_tokens = len(tokens)
+    # the first step's window and what of it counts as read
+    prefix_offset = max(
+        num_tokens - _INITIAL_INCREMENTAL_DETOKENIZATION_OFFSET, 0)
+    if skip_special_tokens and output_ids[0] in _special_ids(tokenizer):
+        read_offset = num_tokens
+    else:
+        read_offset = max(num_tokens - 1, 0)
+    window = tokens[prefix_offset:] + tokenizer.convert_ids_to_tokens(
+        output_ids[1:], skip_special_tokens=skip_special_tokens)
+    # an id past the vocabulary has no token and no bytes
+    window = [t if t is not None else "" for t in window]
+    read = read_offset - prefix_offset
+    prefix_text = tokenizer.convert_tokens_to_string(window[:read])
+    end = len(window)
+    while end > read:
+        if not window[end - 1]:
+            end -= 1
+            continue
+        text = tokenizer.convert_tokens_to_string(window[:end])
+        if not text.endswith("\ufffd"):
+            return text[len(prefix_text):] \
+                if len(text) > len(prefix_text) else ""
+        end -= 1
+    return ""
+
+
 def detokenize_incrementally(
     tokenizer: AnyTokenizer,
     all_input_ids: List[int],

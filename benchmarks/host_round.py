@@ -58,6 +58,12 @@ async def main(args) -> None:
     from aphrodite_tpu.common.sampling_params import SamplingParams
     from aphrodite_tpu.engine.args_tools import AsyncEngineArgs
     from aphrodite_tpu.engine.async_aphrodite import AsyncAphrodite
+    from aphrodite_tpu.executor import executor
+    # The CPU backend's pool (256 MiB) holds 128 rows of 1,536 tokens
+    # of this toy; more rows, or longer ones, never all run, and the
+    # wait below for a full batch would not end.
+    executor._CPU_CACHE_BYTES = max(executor._CPU_CACHE_BYTES, int(
+        1.5 * 2 ** 28 * args.rows * (args.prompt + 1024) / (128 * 1536)))
     model_dir = tempfile.mkdtemp()
     srv.write_model_dir(model_dir, toy_config(args.vocab))
     engine = AsyncAphrodite.from_engine_args(AsyncEngineArgs(

@@ -49,8 +49,11 @@ CELLS = {
     "evabyte-6.5b-bf16": (24, 4780, 6000, 0),
     # (latent pages: ONE array a layer of 640-lane rows)
     "sarvam-105b-bf16": (64, 80000, 8900, 0),
+    # (state slots AND latent pages: two MLA layers' arrays beside the
+    # six KDA layers' one (tail, state) pair)
+    "kimi-linear-48b-a3b-bf16": (192, 100000, 1536, 0),
 }
-SLOTS = 128
+SLOTS = 192
 
 
 def build(name: str, prompts: int, abstract, prompt_len: int = 512):
@@ -164,6 +167,7 @@ def _hf(config):
            "laguna": getattr(configs, "LagunaConfig", None),
            "evabyte": getattr(configs, "EvaByteConfig", None),
            "sarvam_mla": getattr(configs, "SarvamMLAConfig", None),
+           "kimi_linear": getattr(configs, "KimiLinearConfig", None),
            }[config["model_type"]]
     return cls(**{k: v for k, v in config.items() if k not in (
         "perf", "architectures", "model_type", "torch_dtype")})
@@ -197,6 +201,9 @@ def _model(config, model_config):
     elif config["model_type"] == "evabyte":
         from aphrodite_tpu.modeling.models.evabyte import \
             EvaByteForCausalLM as cls
+    elif config["model_type"] == "kimi_linear":
+        from aphrodite_tpu.modeling.models.kimi_linear import \
+            KimiLinearForCausalLM as cls
     else:
         from aphrodite_tpu.modeling.models.phi4flash import \
             Phi4FlashForCausalLM as cls

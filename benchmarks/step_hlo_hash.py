@@ -58,6 +58,13 @@ def hf(arch):
             first_held_expert=4, num_experts_per_tok=4, rope_scaling={"type": "deepseek_yarn", "factor": 8,
                 "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 32})
         c.architectures = ["SarvamMLAForCausalLM"]; return c
+    if arch == "kimi":
+        c = configs.KimiLinearConfig(vocab_size=256, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=128, qk_nope_head_dim=32,
+            qk_rope_head_dim=16, v_head_dim=32, model_max_length=256, num_experts=4, num_routed_experts=16,
+            first_held_expert=4, num_experts_per_token=4, linear_attn_config={"kda_layers": [1, 2, 3, 5, 6, 7],
+                "full_attn_layers": [4, 8], "num_heads": 2, "head_dim": 32, "short_conv_kernel_size": 4})
+        c.architectures = ["KimiLinearForCausalLM"]; return c
     if arch == "phi4flash":
         c = configs.Phi4FlashConfig(vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=8,
             num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512, sliding_window=32, mamba_d_state=8)
@@ -100,7 +107,8 @@ def programs(arch, dtype):
 
 for arch in ("mistral", "smallthinker", "phi4flash", "jamba") + (
         ("laguna",) if hasattr(configs, "LagunaConfig") else ()) + (
-        ("sarvam",) if hasattr(configs, "SarvamMLAConfig") else ()):
+        ("sarvam",) if hasattr(configs, "SarvamMLAConfig") else ()) + (
+        ("kimi",) if hasattr(configs, "KimiLinearConfig") else ()):
     for dtype in ("float32", "bfloat16"):
         for name, text in programs(arch, dtype).items():
             print(arch, dtype, name, hashlib.sha256(text.encode()).hexdigest()[:16], len(text))

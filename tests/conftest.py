@@ -170,10 +170,11 @@ SETUP_METRICS = (
 #: module that pins the manifest is spared
 NEWER_METRICS = ("prompt_dispatch_late_pct.batch",) + SETUP_METRICS
 #: cells appended since the pinning tests were written, oldest first
-#: (PR 41's, PR 43's, PR 48's, PR 52's), each with its configuration
-#: and the metrics it alone reports
+#: (PR 41's, PR 43's, PR 48's, PR 52's, PR 56's), each with its
+#: configuration and the metrics it alone reports
 NEWER_CELLS = ("jamba2-3b-bf16.reason-512", "laguna-s-2.1-bf16.agent-4k",
-               "evabyte-6.5b-bf16.doc-5k", "sarvam-105b-bf16.doc-8k")
+               "evabyte-6.5b-bf16.doc-5k", "sarvam-105b-bf16.doc-8k",
+               "kimi-linear-48b-a3b-bf16.reason-1k")
 #: the modules that hold the manifest to a count, a set or its last
 #: places -> (the cells, the metrics) appended after what each holds
 _PINNED = {
@@ -182,7 +183,8 @@ _PINNED = {
     "test_perf_jamba": (NEWER_CELLS[1:], NEWER_METRICS),
     "test_perf_laguna": (NEWER_CELLS[2:], NEWER_METRICS),
     "test_perf_evabyte": (NEWER_CELLS[3:], SETUP_METRICS),
-    "test_perf_sarvam": ((), SETUP_METRICS),
+    "test_perf_sarvam": (NEWER_CELLS[4:], SETUP_METRICS),
+    "test_perf_kimi_linear": ((), SETUP_METRICS),
 }
 #: the modules that hold the manifest's last places or a list's length
 #: to their own and read `BENCHMARK.json` with `json.load` (PR 38's six

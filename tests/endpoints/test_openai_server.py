@@ -343,3 +343,55 @@ def test_profile_endpoints(server_ctx, tmp_path):
     assert glob.glob(trace_dir + "/**/*.pb", recursive=True) or \
         glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True) or \
         glob.glob(trace_dir + "/*", recursive=False)
+
+
+def test_settle_collector_freezes_and_spaces_full_collections():
+    """A frontend's `main()` settles the collector once its engine is
+    built: what start-up left is frozen (a full collection no longer
+    walks it), the young thresholds stay Python's and the third rises.
+    Objects made afterwards are still collected, cycles included."""
+    import gc
+    import weakref
+
+    from aphrodite_tpu.endpoints import utils
+
+    before, frozen = gc.get_threshold(), gc.get_freeze_count()
+    try:
+        utils.settle_collector()
+        assert gc.get_freeze_count() > frozen + 10_000
+        assert gc.get_threshold() == utils.COLLECTOR_THRESHOLDS
+        assert utils.COLLECTOR_THRESHOLDS[:2] == before[:2]
+        assert utils.COLLECTOR_THRESHOLDS[2] >= 100 * before[2]
+
+        class Node:
+            pass
+        a, b = Node(), Node()
+        a.other, b.other = b, a
+        gone = weakref.ref(a)
+        del a, b
+        gc.collect()
+        assert gone() is None
+    finally:
+        gc.unfreeze()
+        gc.set_threshold(*before)
+
+
+@pytest.mark.parametrize("frontend", ["openai", "kobold", "ooba"])
+def test_every_frontend_main_settles_the_collector(frontend):
+    """Each server entry point calls `settle_collector` once, between
+    the engine's construction and the sockets; nothing else in the
+    package does (an engine built inside a test or `endpoints/llm.py`
+    leaves the collector alone)."""
+    import ast
+    import importlib
+    import inspect
+
+    module = importlib.import_module(
+        f"aphrodite_tpu.endpoints.{frontend}.api_server")
+    calls = [n for n in ast.walk(ast.parse(inspect.getsource(module)))
+             if isinstance(n, ast.Call) and
+             getattr(n.func, "id", None) == "settle_collector"]
+    assert len(calls) == 1
+    main_src = inspect.getsource(module.main) if hasattr(module, "main") \
+        else inspect.getsource(module)
+    assert "settle_collector()" in main_src

@@ -5,6 +5,8 @@ sees its client hang up."""
 import asyncio
 import types
 
+import pytest
+
 from aphrodite_tpu.common.sampling_params import SamplingParams
 from aphrodite_tpu.endpoints import utils
 from aphrodite_tpu.engine.aphrodite_engine import AphroditeEngine
@@ -46,6 +48,46 @@ def test_one_output_the_finished_one_and_the_twins_text(tiny_model_dir,
     assert len(whole.outputs[0].token_ids) == 12
     assert whole.outputs[0].text == last.outputs[0].text
     assert whole.outputs[0].finish_reason == "length"
+
+
+@pytest.mark.parametrize("case,extra,steps", [
+    ("text at the end", {}, 0),
+    ("a stop string reads the text a token", {"stop": ["\x00never"]}, 12),
+    ("special tokens kept", {"skip_special_tokens": False}, 0),
+])
+def test_the_text_nobody_reads_is_made_when_the_row_ends(
+        tiny_model_dir, monkeypatch, case, extra, steps):
+    """An unstreamed row with no stop string runs no detokeniser step
+    (two decoder calls a token a row): its text is made once, when it
+    ends, and is its streamed twin's. A continuation, whose tokens
+    are replayed a step at a time on arrival, goes on by steps."""
+    from aphrodite_tpu.engine import aphrodite_engine
+    engine = _engine(tiny_model_dir)
+    stepped = []
+    step = aphrodite_engine.detokenize_incrementally
+    monkeypatch.setattr(
+        aphrodite_engine, "detokenize_incrementally",
+        lambda *a, **k: stepped.append(1) or step(*a, **k))
+    params = SamplingParams(temperature=0.0, max_tokens=12,
+                            ignore_eos=True, **extra)
+    prompt = [7, 300, 131, 40, 200, 222]      # ends inside a character
+    engine.add_request("whole", None, params, prompt_token_ids=prompt,
+                       final_only=True)
+    (whole,) = [o for _ in range(40) if engine.has_unfinished_requests()
+                for o in engine.step()]
+    assert len(stepped) == steps, case
+    del stepped[:]
+    engine.add_request("streamed", None, params, prompt_token_ids=prompt)
+    engine.add_request("resumed", None, params, prompt_token_ids=prompt,
+                       emitted_token_ids=whole.outputs[0].token_ids[:5],
+                       final_only=True)
+    last = {}
+    while engine.has_unfinished_requests():
+        last.update((o.request_id, o) for o in engine.step())
+    assert len(stepped) == 12 + 12
+    for twin in last.values():
+        assert twin.outputs[0].token_ids == whole.outputs[0].token_ids
+        assert twin.outputs[0].text == whole.outputs[0].text
 
 
 def test_an_ignored_prompt_still_answers(tiny_model_dir):

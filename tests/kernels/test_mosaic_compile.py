@@ -938,3 +938,52 @@ def test_prefill_flash_attention_compiles_at_sarvam_shapes(on_v5e, rows, s,
         on_v5e((rows,), I32)).compile()
     (out,) = jax.tree_util.tree_leaves(compiled.out_info)
     assert out.shape == (rows, s, H, dv)
+
+
+# ---- the delta-rule (KDA) kernels at Kimi Linear's widths ----
+#: 32 heads of 128 x 128, 12,288 convolution channels, 192 state slots
+#: and the scratch one, six KDA layers in the arrays
+_KDA = dict(heads=32, d=128, slots=193, layers=6)
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (4, 1024), (1, 64),
+                                         (8, 1024), (1, 2048)])
+def test_kda_chunk_compiles(on_v5e, rows, tokens):
+    """A prompt chunk: a grid cell a (row, head, 64 tokens), the head's
+    state in VMEM across a row's chunks, the slot's state aliased in
+    place; float32 products at `Precision.HIGHEST`, the transposed
+    ones among them."""
+    from aphrodite_tpu.ops.pallas.kda import _kda_chunk_impl
+    heads, d, slots, layers = (_KDA[k] for k in
+                               ("heads", "d", "slots", "layers"))
+    f32 = jnp.float32
+    seq = on_v5e((rows, tokens, heads * d), f32)
+    _kda_chunk_impl.lower(
+        seq, seq, seq, seq, on_v5e((rows, tokens, heads), f32),
+        on_v5e((layers, slots, heads, d, d), f32), on_v5e((1,), I32),
+        on_v5e((rows,), I32), on_v5e((rows,), I32)).compile()
+
+
+@pytest.mark.parametrize("rows", [192, 128, 64, 24, 8, 1, 12])
+def test_kda_decode_update_compiles(on_v5e, rows):
+    """A decode step's update: a row's 2 MiB of matrices and its
+    convolution tail by layer and slot id, read, moved on and written
+    in place (8 MiB of VMEM for the state's blocks, over the 16 MiB a
+    kernel has by default: the call states its limit); a head's
+    `exp(g)`, `k` and `q` taken as columns of the `[128, 96]` block
+    the caller hands in transposed."""
+    from aphrodite_tpu.ops.pallas.kda import _kda_update_impl, _row_blocks
+    heads, d, slots, layers = (_KDA[k] for k in
+                               ("heads", "d", "slots", "layers"))
+    f32 = jnp.float32
+
+    def blocks(width):
+        return on_v5e(jax.eval_shape(
+            _row_blocks, jax.ShapeDtypeStruct((rows, width), f32)).shape,
+            f32)
+    _kda_update_impl.lower(
+        blocks(3 * heads * d), on_v5e((rows, d, 3 * heads), f32),
+        blocks(heads * d), blocks(heads * d),
+        on_v5e((layers, slots, heads, d, d), f32),
+        on_v5e((layers, slots, 4, 3 * heads * d), BF16),
+        on_v5e((1,), I32), on_v5e((rows,), I32)).compile()

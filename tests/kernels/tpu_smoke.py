@@ -15,6 +15,85 @@ import numpy as np
 sys.path.insert(0, ".")
 
 
+def kda_checks(check, failures, rs) -> None:
+    """`ops/pallas/kda.py` at the served shape (32 heads of 128 x 128,
+    12,288 convolution channels, float32 state): a prompt chunk of
+    1,024 tokens from zeros and the same tokens as two calls through
+    the slot (a 64-token kernel chunk's boundary inside each), against
+    the recurrence a token at a time; decays from `exp(-4.5)` a token
+    down, so that `exp(-G)` over a chunk would overflow; a decode step
+    of 64 rows (60 live on scattered slots, 4 pad rows on the scratch
+    one) against the one-step update, NaN in every slot no row holds.
+    The arrays hold three layers, the calls name the middle one."""
+    import jax
+    import jax.numpy as jnp
+    from aphrodite_tpu.ops.pallas import kda
+    heads, d, slots, t, at = 32, 128, 96, 1024, 1
+
+    def f(*shape):
+        return jnp.asarray(rs.randn(*shape), jnp.float32)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    def inputs(*lead):
+        return (unit(f(*lead, heads, d)) * d ** -0.5,
+                unit(f(*lead, heads, d)), f(*lead, heads, d),
+                -jnp.exp(jnp.asarray(rs.uniform(-9.0, 1.5,
+                                                lead + (heads, d)),
+                                     jnp.float32)),
+                jnp.asarray(rs.uniform(0, 1, lead + (heads,)),
+                            jnp.float32))
+    args = inputs(1, t)
+    state = jnp.full((3, slots + 1, heads, d, d), jnp.nan, jnp.float32)
+    one, yes = jnp.asarray([7], jnp.int32), jnp.asarray([1], jnp.int32)
+    o_ref, s_ref = kda.kda_chunk_ref(*args, state, one, yes, at)
+    o_got, s_got = kda.kda_chunk(*args, state, one, yes, at)
+    scale = float(jnp.abs(o_ref).max())
+    check("kda chunk, 1,024 tokens (of the largest output)",
+          np.asarray(o_ref) / scale, np.asarray(o_got) / scale, tol=1e-4)
+    check("kda chunk, the slot's state", np.asarray(s_ref)[at, 7],
+          np.asarray(s_got)[at, 7], tol=1e-4)
+    half = 576          # nine kernel chunks, then seven
+    o1, s1 = kda.kda_chunk(*(a[:, :half] for a in args), state, one, yes,
+                           at)
+    o2, s2 = kda.kda_chunk(*(a[:, half:] for a in args), s1, one, 0 * yes,
+                           at)
+    check("kda chunk, two calls through the slot",
+          np.asarray(o_ref) / scale,
+          np.concatenate([o1, o2], axis=1) / scale, tol=1e-4)
+    if not (np.isnan(np.asarray(s2)[at, [0, 6, 8, slots]]).all() and
+            np.isnan(np.asarray(s2)[[0, 2]]).all()):
+        failures.append(("kda chunk touched a slot no row holds", 0))
+    rows, live = 64, 60
+    owners = rs.permutation(slots)[:live]
+    slot = np.full((rows,), slots, np.int32)
+    slot[:live] = owners
+    held = np.zeros(slots + 1, bool)
+    held[owners] = True
+    held[slots] = True
+    mine = held[None, :] & (np.arange(3) == at)[:, None]
+    st = jnp.where(mine[..., None, None, None],
+                   f(3, slots + 1, heads, d, d), jnp.nan)
+    tl = jnp.where(mine[..., None, None], f(3, slots + 1, 4, 3 * heads * d),
+                   jnp.nan).astype(jnp.bfloat16)
+    x = f(rows, 3 * heads * d).astype(jnp.bfloat16)
+    step = inputs(rows)
+    want = kda.kda_update_ref(x, *step, st, tl, jnp.asarray(slot), at)
+    got = kda.kda_update(x, *step, st, tl, jnp.asarray(slot), at)
+    check("kda update, the rows' outputs", np.asarray(want[0])[:live],
+          np.asarray(got[0])[:live], tol=1e-4)
+    check("kda update, the live slots' state",
+          np.asarray(want[1])[at, owners], np.asarray(got[1])[at, owners],
+          tol=1e-4)
+    check("kda update, the live slots' tail",
+          np.asarray(want[2], np.float32)[at, owners],
+          np.asarray(got[2], np.float32)[at, owners], tol=1e-6)
+    if not (np.isnan(np.asarray(got[1])[~mine]).all() and
+            np.isnan(np.asarray(got[2], np.float32)[~mine]).all()):
+        failures.append(("kda update touched a slot no row holds", 0))
+
+
 def main() -> int:
     import jax
     import jax.numpy as jnp
@@ -606,6 +685,9 @@ def main() -> int:
         if not (np.isnan(np.asarray(got[1])[free]).all() and
                 np.isnan(np.asarray(got[2], np.float32)[free]).all()):
             failures.append(("ssm update touched a slot no row holds", 0))
+
+    with section("delta rule (Kimi Linear: chunk kernel, decode update)"):
+        kda_checks(check, failures, rs)
 
     with section("decode attention (padded heads)"):
         # -- head 64/80: padded-lane decode (pages pad head_dim to 128) --

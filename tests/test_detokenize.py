@@ -98,3 +98,49 @@ def test_special_ids_are_read_once_a_tokenizer(tokenizer, monkeypatch):
         detokenize_incrementally(tokenizer, [1], tokens, 0, 2,
                                  skip_special_tokens=True)
     assert len(reads) == 1
+
+
+# the same ranges, and prompts that end inside a character: the whole
+# text in one pass is the steps' pieces joined
+@pytest.mark.parametrize("skip", [False, True])
+@pytest.mark.parametrize("low,high", [(3, 512), (0, 6), (0, 512),
+                                      (400, 2000), (3, 259), (130, 259),
+                                      (0, 40960)])
+def test_the_whole_text_is_the_steps_joined(tokenizer, skip, low, high):
+    from aphrodite_tpu.transformers_utils.tokenizer import (
+        decodes_bytes, detokenize_whole)
+    assert decodes_bytes(tokenizer)
+    rng = np.random.default_rng(high * 2 + skip + 7)
+    said = 0
+    for trial in range(60):
+        prompt = rng.integers(low, high, int(rng.integers(1, 12))).tolist()
+        outputs = rng.integers(low, high,
+                               int(rng.integers(1, 90))).tolist()
+        ids, state, text = list(prompt), (None, 0, 0), ""
+        for n, token in enumerate(outputs, 1):
+            ids.append(token)
+            new, piece, prefix, read = detokenize_incrementally(
+                tokenizer, ids if state[0] is None else [token], *state,
+                skip_special_tokens=skip)
+            state = ((state[0] or []) + new, prefix, read)
+            text += piece
+            if trial % 6 == 0 or n == len(outputs):
+                assert detokenize_whole(
+                    tokenizer, prompt, outputs[:n],
+                    skip_special_tokens=skip) == text
+        said += bool(text)
+    assert said or (high > 512 and low >= 400) or (skip and high <= 6)
+    assert detokenize_whole(tokenizer, prompt, [], skip) == ""
+
+
+def test_a_decoder_that_joins_words_is_read_a_step_at_a_time():
+    """`decodes_bytes` is the `ByteLevel` decoder alone: a decoder
+    that puts spaces between its tokens gives a window's text that is
+    no join of the tokens' own."""
+    from tokenizers import Tokenizer, decoders, models
+    from transformers import PreTrainedTokenizerFast
+    from aphrodite_tpu.transformers_utils.tokenizer import decodes_bytes
+    tok = Tokenizer(models.WordPiece({"[UNK]": 0, "a": 1, "##b": 2},
+                                     unk_token="[UNK]"))
+    tok.decoder = decoders.WordPiece()
+    assert not decodes_bytes(PreTrainedTokenizerFast(tokenizer_object=tok))
